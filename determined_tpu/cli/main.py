@@ -32,6 +32,19 @@ def _client(args):
     return Determined(url, user=getattr(args, "user", None) or None)
 
 
+def _log_to_stderr() -> None:
+    """The commands that run the harness in THIS process (masterless
+    training, a serving replica) show its log the way a cluster task's log
+    does (``exec/run_trial.py``): device, cache, compile, step and
+    checkpoint lines.  stdout stays the command's own output."""
+    import logging
+
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s [%(levelname)s] %(name)s: %(message)s",
+    )
+
+
 def _print_json(obj: Any) -> None:
     print(json.dumps(obj, indent=2, sort_keys=True, default=str))
 
@@ -1073,6 +1086,7 @@ def serve_cmd(args) -> int:
     drain replicas on older versions.  A master-requested drain exits 75
     exactly like a signal drain.
     """
+    _log_to_stderr()  # before the harness imports: the first root handler wins
     import signal as _signal
     import time as _time
 
@@ -1382,6 +1396,7 @@ def exp_run(args) -> int:
     across agents and launches one ``run_trial`` process per rank with
     ``jax.distributed`` rendezvous env (docs/cluster.md).
     """
+    _log_to_stderr()  # before the harness imports: the first root handler wins
     import yaml
 
     from determined_tpu.config.experiment import ExperimentConfig

@@ -359,9 +359,10 @@ class OptimizationsConfig:
     prefetch_depth: int = 2
     device_prefetch: int = 2
     fetch_workers: int = 0
-    # Persistent XLA compilation cache directory (also DTPU_COMPILATION_CACHE
-    # env): a supervised restart after a crash re-jits from disk instead of
-    # paying the full compile.  None disables.
+    # Persistent XLA compilation cache directory: a supervised restart
+    # after a crash re-jits from disk instead of paying the full compile.
+    # JAX_COMPILATION_CACHE_DIR, where set, overrides it; None means the
+    # fixed in-checkout default (utils/compilation_cache.py).
     compilation_cache_dir: Optional[str] = None
     # Cross-trial jit-reuse cache (train/_jit_cache.py): same-architecture
     # trials in one process share compiled train/eval steps instead of

@@ -447,11 +447,6 @@ def main() -> int:
 
     import jax
 
-    # some TPU PJRT plugins ignore the JAX_PLATFORMS env var; the config
-    # flag always wins (same workaround as tests/conftest.py)
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     # join the multi-host rendezvous before touching devices.  The wait is
     # timed here (the tracer is not configured yet — that needs the parsed
     # exp config) and recorded as a rendezvous.wait span once the tracer
@@ -477,13 +472,7 @@ def main() -> int:
             # configures the CPU client, so TPU/GPU gangs are unaffected.
             platforms = os.environ.get("JAX_PLATFORMS", "")
             if not platforms or "cpu" in platforms.split(","):
-                try:
-                    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-                except (AttributeError, ValueError):
-                    logger.warning(
-                        "jax %s has no gloo CPU collectives; multi-process "
-                        "CPU gangs may fail to compile", jax.__version__,
-                    )
+                jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
             logger.info(
                 "rendezvous: joining as rank %s/%s via coordinator %s",
@@ -539,8 +528,7 @@ def main() -> int:
             )
 
     # persistent XLA compilation cache: a supervised restart (or a relaunch
-    # after a crash) re-jits from disk instead of paying the full compile;
-    # from optimizations.compilation_cache_dir or DTPU_COMPILATION_CACHE
+    # after a crash) re-jits from disk instead of paying the full compile
     from determined_tpu.utils.compilation_cache import setup_compilation_cache
 
     setup_compilation_cache(exp_config.optimizations.compilation_cache_dir)

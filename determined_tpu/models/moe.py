@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from determined_tpu.parallel._compat import axis_size
-
 
 def _top2_dispatch(
     gates: jax.Array, capacity: int
@@ -148,7 +146,7 @@ class MoE(nn.Module):
         e_param = e
         my_expert0 = None
         if self.expert_axis_name is not None:
-            n_exp = axis_size(self.expert_axis_name)
+            n_exp = jax.lax.axis_size(self.expert_axis_name)
             if e % n_exp:
                 raise ValueError(f"num_experts={e} not divisible by axis {n_exp}")
             e_param = e // n_exp

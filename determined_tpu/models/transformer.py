@@ -190,7 +190,9 @@ class Attention(nn.Module):
                 raise ValueError("ring attention requires the mesh")
             out = ring_attention(q, k, v, self.mesh, causal=True)
         else:
-            out = dot_product_attention(q, k, v, causal=True, impl=impl)
+            out = dot_product_attention(
+                q, k, v, causal=True, impl=impl, mesh=self.mesh
+            )
         out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
         out = nn.DenseGeneral(
             cfg.d_model,
@@ -1100,12 +1102,7 @@ class LMTrial(JaxTrial):
         return (jnp.asarray(batch["tokens"])[:, :-1],)
 
     def restructure_params(self, params: Any) -> Any:
-        # pipe > 1: restack per-layer blocks into pipeline stages.  Kept
-        # OUT of init_params so the trainer can stage it on jax versions
-        # where a jitted stack into pipe-sharded out_shardings SUMS the
-        # replicated operands (parallel/_compat.py sharded_restack_safe):
-        # pipe>1 trials used to start from doubled block weights — the
-        # whole ~1.5% pipe-parity drift ROADMAP tracked.
+        # pipe > 1: restack per-layer blocks into pipeline stages
         pipe = self._pipe_stages()
         if pipe > 1:
             _, vstages = self._pipe_schedule()

@@ -92,10 +92,18 @@ PEAK_FLOPS_BY_KIND = {
 }
 
 
-def chip_peak_flops(device_kind: str, default: float = 197e12) -> float:
+def chip_peak_flops(device_kind: str, default: Optional[float] = None) -> float:
+    """bf16 peak of one chip.  A kind the table does not know is an error
+    wherever a utilization is printed against it; a caller that reports no
+    roofline for such a device says so with ``default=0.0``."""
     for prefix in sorted(PEAK_FLOPS_BY_KIND, key=len, reverse=True):
         if device_kind.startswith(prefix):
             return PEAK_FLOPS_BY_KIND[prefix]
+    if default is None:
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}: add it to "
+            "PEAK_FLOPS_BY_KIND (observability/_goodput.py)"
+        )
     return default
 
 

@@ -17,10 +17,15 @@ head_dim] k/v (GQA when kv_heads < heads).
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from determined_tpu.parallel.mesh import MeshAxes
 
 NEG_INF = -1e30
 
@@ -71,8 +76,15 @@ def dot_product_attention(
     causal: bool = True,
     impl: str = "auto",
     scale: Optional[float] = None,
+    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
-    """Dispatcher: 'auto' picks flash on TPU for seqs worth tiling."""
+    """Dispatcher: 'auto' picks flash on TPU for seqs worth tiling.
+
+    ``mesh``: the mesh the (global) operands are sharded over.  With more
+    than one device the flash kernel runs per device inside ``shard_map``
+    (:func:`sharded_flash_attention`); leave it None where the caller is
+    already inside a manual region (pipeline stages).
+    """
     if impl == "auto":
         on_tpu = jax.default_backend() == "tpu"
         impl = "flash" if on_tpu and q.shape[-2] >= 256 else "reference"
@@ -81,5 +93,51 @@ def dot_product_attention(
     if impl == "flash":
         from determined_tpu.ops.flash_attention import flash_attention
 
+        if mesh is not None and mesh.size > 1:
+            return sharded_flash_attention(q, k, v, mesh, causal=causal, scale=scale)
         return flash_attention(q, k, v, causal=causal, scale=scale)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def sharded_flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mesh: Mesh,
+    *,
+    causal: bool = True,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Flash attention over global arrays on a multi-device mesh.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under GSPMD a bare ``pallas_call`` on
+    sharded operands fails to compile on real chips — while the CPU
+    interpreter lowers it to plain ops that do partition, which is how
+    every virtual-mesh test passed.  Attention is independent per (batch,
+    head), so each device runs the kernel on its own block: batch over the
+    ``(dcn, data, fsdp)`` axes and heads over ``tensor``, the layout the
+    sharding rules already give q/k/v.  A dim its axes do not divide stays
+    whole on every device (still the kernel, never another
+    implementation); kv heads ``tensor`` does not divide are expanded to
+    full heads first, as ring attention does.
+    """
+    from determined_tpu.ops.flash_attention import flash_attention
+
+    b, h = q.shape[0], q.shape[1]
+    batch_axes = tuple(a for a in MeshAxes.BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if b % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    tp = mesh.shape.get(MeshAxes.TENSOR, 1)
+    head_axis = MeshAxes.TENSOR if tp > 1 and h % tp == 0 else None
+    if head_axis is not None and k.shape[1] % tp:
+        n_rep = h // k.shape[1]
+        k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
+    spec = P(batch_axes or None, head_axis, None, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

@@ -28,12 +28,14 @@ adamw(lr, b1, b2, eps, weight_decay=wd, mu_dtype=...))`` exactly
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import PartitionSpec as P
 
 # Per-ref block budget.  7 refs (p/m/v/g in, p/m/v out) x double-buffered
 # must fit the 16 MiB scoped-VMEM budget; 1 MiB blocks measured 16.84M > 16M
@@ -99,8 +101,6 @@ def _plan_blocks(shape):
     split to keep >=8 rows per block); 3D+ leaves keep trailing dims whole
     and split the leading dim.  All dims here are powers of two.
     """
-    import math
-
     budget = _BLOCK_BYTES // 4  # f32 elements per ref
     d0, dk = shape[0], shape[-1]
     mid = math.prod(shape[1:-1]) if len(shape) > 2 else 1
@@ -151,6 +151,21 @@ def _leaf_pallas(p, m, v, g, scalars, *, b1, b2, eps, wd):
     return po, mo, vo
 
 
+def _leaf_pallas_sharded(p, m, v, g, scalars, sharding, **kw):
+    """The sweep on a leaf sharded over a multi-device mesh: XLA cannot
+    partition a Mosaic kernel, so each device sweeps its own shard inside
+    ``shard_map`` over the leaf's ``PartitionSpec`` (the update is
+    elementwise; the clip scale in ``scalars`` was reduced outside)."""
+    spec = sharding.spec
+    return jax.shard_map(
+        partial(_leaf_pallas, **kw),
+        mesh=sharding.mesh,
+        in_specs=(spec, spec, spec, spec, P()),
+        out_specs=(spec, spec, spec),
+        check_vma=False,
+    )(p, m, v, g, scalars)
+
+
 def _leaf_jnp(p, m, v, g, scalars, *, b1, b2, eps, wd):
     lr, cs, bc1, bc2 = (scalars[0, i] for i in range(4))
     gf = g.astype(jnp.float32) * cs
@@ -194,23 +209,42 @@ class FusedAdamW:
         return jnp.stack([jnp.asarray(lr, jnp.float32), cs.astype(jnp.float32),
                           bc1, bc2]).reshape(1, 4)
 
-    def apply_step(self, grads: Any, state: FusedAdamWState, params: Any):
+    def apply_step(
+        self,
+        grads: Any,
+        state: FusedAdamWState,
+        params: Any,
+        shardings: Optional[Any] = None,
+    ):
+        """``shardings``: the ``NamedSharding`` pytree (matching ``params``)
+        the update runs in — the Trainer's param shardings, or the
+        reduce-scattered grad layout under ``overlap_grad_sync``.  Required
+        knowledge on a multi-device mesh: the kernel is planned on each
+        leaf's LOCAL shard shape and run per device; None means
+        single-device arrays."""
         scalars = self._scalars(state.count, grads)
         kw = dict(b1=self.b1, b2=self.b2, eps=self.eps, wd=self.weight_decay)
 
         min_size = _min_pallas_size()
 
-        def leaf(p, m, v, g):
+        def leaf(p, m, v, g, sharding=None):
+            multi = sharding is not None and sharding.mesh.size > 1
+            # the launch-overhead threshold and the block plan are about
+            # what ONE device sweeps
+            shape = sharding.shard_shape(p.shape) if multi else p.shape
             if (
-                p.size >= min_size
+                math.prod(shape) >= min_size
                 and p.dtype == jnp.float32
                 and p.ndim >= 2
-                and _plan_blocks(p.shape) is not None
+                and _plan_blocks(shape) is not None
             ):
+                if multi:
+                    return _leaf_pallas_sharded(p, m, v, g, scalars, sharding, **kw)
                 return _leaf_pallas(p, m, v, g, scalars, **kw)
             return _leaf_jnp(p, m, v, g, scalars, **kw)
 
-        out = jax.tree.map(leaf, params, state.mu, state.nu, grads)
+        trees = (params, state.mu, state.nu, grads)
+        out = jax.tree.map(leaf, *trees, *(() if shardings is None else (shardings,)))
         # out leaves are (p, m, v) triples; re-split into three trees
         is_triple = lambda x: isinstance(x, tuple) and len(x) == 3  # noqa: E731
         new_p = jax.tree.map(lambda t: t[0], out, is_leaf=is_triple)

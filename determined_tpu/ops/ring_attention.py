@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from determined_tpu.ops.attention import _repeat_kv
-from determined_tpu.parallel._compat import axis_size, shard_map
 from determined_tpu.parallel.mesh import MeshAxes
 
 NEG_INF = -1e30
@@ -83,7 +82,7 @@ def _ring_fwd_local(q, k, v, *, axis_name, causal, scale, n_rep):
     what actually ran (``ring_block_counts`` surfaces it; the vjp
     wrappers drop it).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     # positions (and the rank index feeding them) exist only for the causal
     # mask; on the non-causal path axis_index must not be emitted at all —
     # its dead value survives into the custom_vjp residual jaxpr and older
@@ -145,7 +144,7 @@ def _ring_bwd_local(q, k, v, out, lse, do, *, axis_name, causal, scale, n_rep):
     """Backward ring sweep: dk/dv rotate WITH their k/v shards, arriving
     home after n steps; no per-step residuals are kept.  dk/dv travel with
     ``h_kv`` heads (group-summed from the expanded gradient each step)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     # see _ring_fwd_local: no dead axis_index on the non-causal path
     idx = jax.lax.axis_index(axis_name) if causal else 0
     b, h, sl, d = q.shape
@@ -257,7 +256,7 @@ def zigzag_redistribute(x, axis_name, inverse: bool = False):
     decompose into exactly two ``ppermute``s — one carrying the even chunks,
     one the odd — plus a parity select on arrival.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     sl = x.shape[-2]
@@ -314,7 +313,7 @@ def _zz_fwd_local(q, k, v, *, axis_name, scale, n_rep):
     lo-q × lo-k (iff src ≤ idx), hi-q × hi-k (iff src ≥ idx) — so every
     rank executes 2 half-computes per step (3 on the diagonal), vs the
     contiguous sweep's rank-(n-1) doing 4 per step."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, sl, d = q.shape
     hc = sl // 2
@@ -410,7 +409,7 @@ def _attn_bwd_half(qf, k_half, v_half, lse_h, do_f, delta_h, q_pos, k_pos,
 def _zz_bwd_local(q, k, v, out, lse, do, *, axis_name, scale, n_rep):
     """Zigzag causal backward: same balanced pair schedule as the forward;
     dk/dv rotate with their k/v shards and are home after n steps."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, sl, d = q.shape
     hc = sl // 2
@@ -589,7 +588,7 @@ def ring_attention(
     spec = P(batch_axes or None, head_axis, seq_axis, None)
     assignment = _resolve_assignment(assignment, causal, q.shape[-2] // n_seq)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention_local(
             q, k, v, axis_name=seq_axis, causal=causal, scale=scale,
             assignment=assignment,
@@ -639,7 +638,7 @@ def ring_block_counts(
             )
         return out, cnt[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),

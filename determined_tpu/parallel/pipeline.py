@@ -543,8 +543,6 @@ def pipeline_apply(
     for a in MeshAxes.BATCH_AXES:
         bshards *= mesh.shape.get(a, 1)
 
-    from determined_tpu.parallel._compat import shard_map
-
     expert_ax = (
         MeshAxes.EXPERT if mesh.shape.get(MeshAxes.EXPERT, 1) > 1 else None
     )
@@ -602,7 +600,7 @@ def pipeline_apply(
             aux = jax.lax.pmean(aux, norm_axes)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(pspec, xspec),

@@ -137,19 +137,16 @@ def with_sharding_constraint(
 
 
 def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src.mesh import thread_resources
+    """The mesh of an enclosing ``with mesh:`` block, else the abstract
+    mesh (``jax.set_mesh``, or the manual mesh inside ``shard_map``)."""
+    # the legacy ``with mesh:`` context has no public reader in jax 0.9
+    from jax._src.mesh import thread_resources
 
-        env_mesh = thread_resources.env.physical_mesh
-        if env_mesh and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
-    return None
+    env_mesh = thread_resources.env.physical_mesh
+    if not env_mesh.empty:
+        return env_mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def batch_sharding(mesh: Mesh, rules: Optional[LogicalAxisRules] = None, extra_dims: int = 1) -> NamedSharding:

@@ -84,8 +84,16 @@ class DecodeKernels:
             transformer_prefill,
             transformer_prefill_suffix,
         )
+        from determined_tpu.utils.compilation_cache import (
+            setup_compilation_cache,
+            timed_first_call,
+        )
 
         _check_decodable(model_cfg)
+        # a relaunched replica loads its three kernels from disk; keeps the
+        # directory ``train.init`` applied when the engine came from a
+        # checkpoint (``from_checkpoint``)
+        setup_compilation_cache()
         if "params" in params:  # accept the full TrainState tree or its inner dict
             params = params["params"]
         self.model_cfg = model_cfg
@@ -122,9 +130,16 @@ class DecodeKernels:
             ),
             allowed=1,
         )
-        self._prefill = jax.jit(prefill, donate_argnums=(4,))
-        self._prefill_suffix = jax.jit(prefill_suffix, donate_argnums=(5,))
-        self._decode = jax.jit(decode, donate_argnums=(4,))
+        self._prefill = timed_first_call(
+            jax.jit(prefill, donate_argnums=(4,)), "jit.compile.serve.prefill"
+        )
+        self._prefill_suffix = timed_first_call(
+            jax.jit(prefill_suffix, donate_argnums=(5,)),
+            "jit.compile.serve.prefill_suffix",
+        )
+        self._decode = timed_first_call(
+            jax.jit(decode, donate_argnums=(4,)), "jit.compile.serve.decode"
+        )
 
     # -- kernel entry points (device round trips happen HERE) ---------------
 

@@ -1,9 +1,5 @@
 """Training engine: JaxTrial + Trainer boundary loop + serialization."""
 
-from determined_tpu.train import _flax_compat
-
-_flax_compat.install()
-
 from determined_tpu.train._jit_cache import (
     clear_step_cache,
     get_step_cache,

@@ -43,37 +43,12 @@ import hashlib
 import json
 import logging
 import threading
-import time
 from collections import OrderedDict
 from typing import Any, Dict, Optional, Tuple
 
 from determined_tpu.observability import get_tracer
 
 logger = logging.getLogger("determined_tpu.train.jit_cache")
-
-
-def timed_first_call(fn: Any, label: str) -> Any:
-    """Wrap a jitted callable so its FIRST invocation — the one that pays
-    trace + compile — is recorded as a ``compile`` span and a
-    ``jit_cache.compile_s`` counter.  Every later call pays one list
-    index.  A cache-hit trial shares the wrapper, so its first step is
-    correctly NOT marked as compile time."""
-    done = [False]
-
-    def wrapped(*args: Any, **kwargs: Any) -> Any:
-        if done[0]:
-            return fn(*args, **kwargs)
-        done[0] = True  # benign race: two concurrent first-callers both record
-        t0 = time.monotonic()
-        try:
-            return fn(*args, **kwargs)
-        finally:
-            t1 = time.monotonic()
-            tracer = get_tracer()
-            tracer.record_span(label, "compile", t0, t1)
-            tracer.counter("jit_cache.compile_s", t1 - t0)
-
-    return wrapped
 
 
 @dataclasses.dataclass

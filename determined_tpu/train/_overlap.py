@@ -84,7 +84,10 @@ ICI_BW_BY_KIND = {
     "TPU v6 lite": 2 * 2 * 90e9,
     "TPU v6e": 2 * 2 * 90e9,
 }
-_DEFAULT_BW = 10e9  # unknown chip (CPU virtual mesh): placeholder, labeled
+# A CPU virtual mesh has no interconnect at all: the model's arithmetic is
+# exercised there against a nominal figure.  A TPU kind missing from the
+# table is an error (link_bandwidths), never this number.
+_EMULATED_BW = 10e9
 
 # Per-chip cross-slice (DCN) bandwidth: host NIC share per chip.  Order of
 # magnitude below ICI — which is the whole point of the hierarchical sync.
@@ -96,7 +99,7 @@ DCN_BW_BY_KIND = {
     "TPU v6 lite": 12.5e9,
     "TPU v6e": 12.5e9,
 }
-_DEFAULT_DCN_BW = 1e9  # unknown chip (CPU virtual mesh): placeholder, labeled
+_EMULATED_DCN_BW = 1e9  # CPU virtual mesh only, as _EMULATED_BW
 
 
 def _parse_bw_env(raw: str) -> Dict[str, float]:
@@ -140,25 +143,25 @@ def _parse_bw_env(raw: str) -> Dict[str, float]:
     return out
 
 
-def _table_bw(device_kind: str, table: Dict[str, float], default: float) -> float:
+def _table_bw(device_kind: str, table: Dict[str, float], emulated: float) -> float:
     for prefix in sorted(table, key=len, reverse=True):
         if device_kind.startswith(prefix):
             return table[prefix]
-    return default
+    if device_kind.startswith("TPU"):
+        raise ValueError(
+            f"no interconnect bandwidth known for device kind {device_kind!r}: "
+            "add it to the tables in train/_overlap.py or set DTPU_COMM_BW_GBPS"
+        )
+    return emulated
 
 
 def link_bandwidths(device_kind: str) -> Tuple[float, float]:
     """(ici_bw, dcn_bw) in bytes/s for the comm model, env-overridable."""
     env = os.environ.get("DTPU_COMM_BW_GBPS")
     override = _parse_bw_env(env) if env else {}
-    ici = override.get("ici") or _table_bw(device_kind, ICI_BW_BY_KIND, _DEFAULT_BW)
-    dcn = override.get("dcn") or _table_bw(device_kind, DCN_BW_BY_KIND, _DEFAULT_DCN_BW)
+    ici = override.get("ici") or _table_bw(device_kind, ICI_BW_BY_KIND, _EMULATED_BW)
+    dcn = override.get("dcn") or _table_bw(device_kind, DCN_BW_BY_KIND, _EMULATED_DCN_BW)
     return ici, dcn
-
-
-def _chip_bw(device_kind: str) -> float:
-    """ICI bandwidth only (back-compat shim for older callers)."""
-    return link_bandwidths(device_kind)[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,7 +180,7 @@ class CommModel:
     bandwidth: float         # ICI bytes/s
     bwd_frac: float = 0.6    # share of a step that is backward compute
     dcn_bytes_per_step: int = 0   # cross-slice hop payload bytes
-    dcn_bandwidth: float = _DEFAULT_DCN_BW
+    dcn_bandwidth: float = _EMULATED_DCN_BW
 
     def split_hops(self, avg_step_s: float) -> Dict[str, Tuple[float, float]]:
         """Per-hop ``{hop: (exposed_s, hidden_s)}`` under the bucket
@@ -305,6 +308,16 @@ class GradSyncPlan:
             if self.sync_shardings[i] is not None:
                 leaves[i] = jax.lax.with_sharding_constraint(leaves[i], s)
         return jax.tree.unflatten(self.treedef, leaves)
+
+    def update_shardings(self) -> Any:
+        """Per-leaf layout the optimizer update runs in: the sync
+        (reduce-scattered) sharding where a leaf has one, else the param's
+        own — what a per-device optimizer kernel (``ops/fused_adamw.py``)
+        maps over."""
+        return jax.tree.unflatten(
+            self.treedef,
+            [s or p for s, p in zip(self.sync_shardings, self.param_shardings)],
+        )
 
     def _sharding_for_shape(self, shape: Tuple[int, ...]) -> Optional[NamedSharding]:
         return self._shape_map.get(tuple(shape))

@@ -53,9 +53,14 @@ class TrainState:
         )
 
     def reset_metrics(self) -> "TrainState":
+        # zeros LIKE the accumulators, i.e. placed where they are: a fresh
+        # ``jnp.zeros(())`` is an uncommitted single-device array, and on
+        # jax 0.9 the step then sees another argument type than the
+        # mesh-replicated scalar it was traced with — it traces and
+        # compiles a second time after the first report boundary
         return self.replace(
-            metric_acc={k: jnp.zeros((), jnp.float32) for k in self.metric_acc},
-            metric_count=jnp.zeros((), jnp.float32),
+            metric_acc={k: jnp.zeros_like(v) for k, v in self.metric_acc.items()},
+            metric_count=jnp.zeros_like(self.metric_count),
         )
 
     def fetch_metrics(self) -> Dict[str, float]:

@@ -44,6 +44,7 @@ from determined_tpu.train._state import TrainState
 from determined_tpu.train._trial import Callback, JaxTrial, TrialContext
 from determined_tpu.train import serialization
 from determined_tpu.utils import faults
+from determined_tpu.utils.chip import device_facts
 from determined_tpu.utils.errors import CheckpointCorruptError, CheckpointNotFoundError
 
 logger = logging.getLogger("determined_tpu.train")
@@ -101,6 +102,15 @@ def init(
     )
     core = core_context or core_context_mod.init()
     mesh = make_mesh(mesh_config or MeshConfig.data_parallel(-1), devices=devices)
+    # every task log names the device it ran on, as jax reports it
+    logger.info(
+        "jax devices: platform=%(platform)s kind=%(kind)r count=%(count)d; "
+        "trial mesh %(mesh)s",
+        {
+            **device_facts(),
+            "mesh": {k: v for k, v in mesh.shape.items() if v > 1} or {"devices": 1},
+        },
+    )
     return TrialContext(
         core=core,
         mesh=mesh,
@@ -109,6 +119,11 @@ def init(
         seed=seed or 0,
         exp_config=exp_config,
     )
+
+
+def _fmt(metrics: Dict[str, float]) -> str:
+    """``loss=10.4 lr=3e-06``: one task-log line's worth of metrics."""
+    return " ".join(f"{k}={v:.6g}" for k, v in sorted(metrics.items()))
 
 
 def _collapse(tree: Dict[str, Any]) -> Dict[str, Any]:
@@ -267,47 +282,21 @@ class Trainer:
         #    propagates the param shardings into mirror leaves (adam mu/nu);
         # 3. replicate every remaining leaf (scalars, rng) over the mesh so
         #    the whole TrainState lives on one consistent device set.
-        # NO ambient mesh here: flax >= 0.10 applies each Partitioned box's
-        # LOGICAL names as a sharding constraint whenever a global mesh is
-        # active, and logical names are not mesh axes.  out_shardings carry
-        # the mesh explicitly, so init still materializes directly sharded
-        # (no single-device materialization at FSDP scale).
-        from determined_tpu.parallel._compat import sharded_restack_safe
-
-        # process_count first: the probe itself jits over a 2x2 mesh of
-        # jax.devices()[:4], which on a multi-host gang spans
-        # non-addressable devices and cannot be fetched
-        if jax.process_count() > 1 or sharded_restack_safe():
-            params = jax.jit(
-                lambda r: flax_meta.unbox(
-                    self.trial.restructure_params(
-                        self.trial.init_params(self.model, r, sample)
-                    )
-                ),
-                out_shardings=shardings,
-            )(init_rng)
-        else:
-            # Affected jax (see _compat.sharded_restack_safe): a restack
-            # (jnp.stack) into sharded out_shardings over a multi-axis
-            # mesh SUMS the replicated operands, so a pipe>1 trial would
-            # start from doubled block weights.  Stage the init: the
-            # RNG-bearing phase materializes fully replicated (measured
-            # correct), the restructure runs eagerly, and the reshard
-            # goes through device_put (an honest transfer, not a GSPMD
-            # resharding).  Single-process only — device_put refuses
-            # non-addressable shardings, and the multiprocess CPU gangs
-            # that would care run one device per host (< 4 devices never
-            # hits the bug).
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            repl = NamedSharding(self.mesh, PartitionSpec())
-            raw = jax.jit(
-                lambda r: self.trial.init_params(self.model, r, sample),
-                out_shardings=jax.tree.map(lambda _: repl, abstract_raw_boxed),
-            )(init_rng)
-            params = jax.device_put(
-                flax_meta.unbox(self.trial.restructure_params(raw)), shardings
-            )
+        # No ``jax.set_mesh`` here or around the steps: flax applies each
+        # Partitioned box's LOGICAL names as a sharding constraint whenever
+        # that global mesh is set, and logical names are not mesh axes (the
+        # legacy ``with mesh:`` the loops use does not set it).
+        # out_shardings carry the mesh explicitly, so init still
+        # materializes directly sharded (no single-device materialization
+        # at FSDP scale).
+        params = jax.jit(
+            lambda r: flax_meta.unbox(
+                self.trial.restructure_params(
+                    self.trial.init_params(self.model, r, sample)
+                )
+            ),
+            out_shardings=shardings,
+        )(init_rng)
 
         # ---- overlapped gradient sync plan (train/_overlap.py) -----------
         # Built whenever the mesh has gradient-reduction axes: with the
@@ -369,6 +358,8 @@ class Trainer:
             opt_state = jax.jit(self.tx.init)(params)
         self.state = TrainState.create(params, opt_state, state_rng, metric_keys)
         self.state = self._place_on_mesh(self.state)
+        # what a checkpoint restores into (_restore_tail)
+        self._restore_template = serialization.abstract_like(self._array_state())
 
         # ---- jitted steps -------------------------------------------------
         trial, model, tx = self.trial, self.model, self.tx
@@ -376,6 +367,9 @@ class Trainer:
         average_grads = opt.average_aggregated_gradients if opt else True
         self.agg = agg
         overlap = self._overlap_plan if sync_on else None
+        # the layout the optimizer update runs in, for optimizers that run
+        # a per-device kernel (ops/fused_adamw.py) and so must know it
+        update_shardings = overlap.update_shardings() if sync_on else shardings
 
         def train_step(state: TrainState, batch):
             step_rng = jax.random.fold_in(state.rng, state.step)
@@ -430,7 +424,9 @@ class Trainer:
                 # fused full-step optimizer (ops/fused_adamw.py): produces
                 # new params directly — materializing an updates tree would
                 # cost two extra HBM passes on a bandwidth-bound step
-                new_params, new_opt = tx.apply_step(grads, state.opt_state, state.params)
+                new_params, new_opt = tx.apply_step(
+                    grads, state.opt_state, state.params, shardings=update_shardings
+                )
             else:
                 updates, new_opt = tx.update(grads, state.opt_state, state.params)
                 new_params = optax.apply_updates(state.params, updates)
@@ -501,6 +497,7 @@ class Trainer:
         # programs — see train/_jit_cache.py for exactly what keys the
         # signature and why sharing is sound.
         from determined_tpu.train import _jit_cache
+        from determined_tpu.utils.compilation_cache import timed_first_call
 
         use_cache = opt.jit_cache if opt is not None else True
         if use_cache:
@@ -538,10 +535,10 @@ class Trainer:
                 entry = cache.insert(
                     key,
                     _jit_cache.CachedSteps(
-                        train_step=_jit_cache.timed_first_call(
+                        train_step=timed_first_call(
                             train_jit, "jit.compile.train"
                         ),
-                        eval_step=_jit_cache.timed_first_call(
+                        eval_step=timed_first_call(
                             jax.jit(eval_step, donate_argnums=2), "jit.compile.eval"
                         ),
                         trial_class=f"{type(trial).__module__}:{type(trial).__qualname__}",
@@ -560,10 +557,10 @@ class Trainer:
             self._train_step_jit = entry.train_jit
         else:
             self._train_step_jit = jax.jit(train_step, donate_argnums=0)
-            self._train_step = _jit_cache.timed_first_call(
+            self._train_step = timed_first_call(
                 self._train_step_jit, "jit.compile.train"
             )
-            self._eval_step = _jit_cache.timed_first_call(
+            self._eval_step = timed_first_call(
                 jax.jit(eval_step, donate_argnums=2), "jit.compile.eval"
             )
 
@@ -685,6 +682,47 @@ class Trainer:
             return False
         return enabled
 
+    def _snapshot_fits(self, tree: Any) -> bool:
+        """Whether an on-device copy of ``tree`` fits beside what each
+        device already holds AND the scratch the step programs reserve
+        while they run.  An overlapped save holds that copy while training
+        goes on; for a state over half the device's memory (the const.yaml
+        LM on one v5e: 7.5 of 15.75 GiB held, 5.0 GiB of step scratch) the
+        copy is the out-of-memory error of the next step — measured on the
+        chip: "Error loading program 'jit_train_step': Attempting to
+        reserve 5.04G" — and the save has to block instead.  The scratch is
+        not in the allocator's statistics; it is read from the compiled
+        programs.  A backend that reports no limit (the CPU) is taken to
+        fit.  The answer is agreed across ranks: the two save paths run
+        different collectives."""
+        need: Dict[Any, int] = {}
+        for leaf in jax.tree.leaves(tree):
+            if jnp.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+                continue  # a few words
+            for shard in leaf.addressable_shards:
+                need[shard.device] = need.get(shard.device, 0) + shard.data.nbytes
+        scratch = max(
+            getattr(self._train_step, "temp_bytes", 0),
+            getattr(self._eval_step, "temp_bytes", 0),
+        )
+        fits = True
+        for dev, nbytes in need.items():
+            stats = dev.memory_stats() or {}
+            limit = stats.get("bytes_limit")
+            held = stats.get("bytes_in_use", 0)
+            reserve = max(scratch, stats.get("peak_bytes_reserved", 0))
+            if limit and held + nbytes + reserve > limit:
+                logger.info(
+                    "checkpoint: a %.1f GiB on-device snapshot does not fit on %s "
+                    "beside %.1f GiB held and %.1f GiB of step scratch (limit "
+                    "%.1f GiB); saving synchronously",
+                    nbytes / 2**30, dev, held / 2**30, reserve / 2**30, limit / 2**30,
+                )
+                fits = False
+                break
+        dist = self.core.distributed
+        return all(dist.allgather(fits)) if dist.size > 1 else fits
+
     def _snapshot_arrays(self, tree: Any) -> Any:
         """On-device copy of the array state.  The train step donates its
         input state (``donate_argnums=0``), so the buffers a background
@@ -759,16 +797,20 @@ class Trainer:
         logger.info("checkpoint %s at step %d", p.storage_id, p.step)
         return p.storage_id
 
-    def _save_checkpoint(self, asynchronous: bool = True) -> str:
-        self._drain_pending_save()  # at most one save in flight
-        dist = self.core.distributed
-        shard = dist.size > 1
-        array_state = {
+    def _array_state(self) -> Dict[str, Any]:
+        """The arrays a checkpoint holds."""
+        return {
             "step": self.state.step,
             "params": self.state.params,
             "opt_state": self.state.opt_state,
             "rng": self.state.rng,
         }
+
+    def _save_checkpoint(self, asynchronous: bool = True) -> str:
+        self._drain_pending_save()  # at most one save in flight
+        dist = self.core.distributed
+        shard = dist.size > 1
+        array_state = self._array_state()
         trainer_state = {
             "steps_completed": self.steps_completed,
             "train_loader": self.train_loader.state_dict(),
@@ -793,7 +835,11 @@ class Trainer:
             # carries a copy; this survives a kill before the manifest)
             "parent_storage_id": self.latest_checkpoint,
         }
-        if not (asynchronous and self._async_checkpointing()):
+        if not (
+            asynchronous
+            and self._async_checkpointing()
+            and self._snapshot_fits(array_state)
+        ):
             with get_tracer().span(
                 "checkpoint.save", cat="checkpoint", mode="sync", step=self.steps_completed
             ):
@@ -933,16 +979,16 @@ class Trainer:
             self._restore_tail(path, tstate)
 
     def _restore_tail(self, path: str, tstate: Dict[str, Any]) -> None:
-        abstract = serialization.abstract_like(
-            {
-                "step": self.state.step,
-                "params": self.state.params,
-                "opt_state": self.state.opt_state,
-                "rng": self.state.rng,
-            }
-        )
-        fresh_opt_state = self.state.opt_state
-        restored = serialization.restore_arrays(path, abstract)
+        # A restore needs the fresh init's shapes and shardings (kept by
+        # _setup), not its arrays.  Let go of those BEFORE the restore
+        # allocates: holding both, a state over half the device's memory
+        # (the const.yaml LM: 7.5 of 15.75 GiB on a v5e) cannot be resumed
+        # or served at all.  Only a trial with runtime hparams still needs
+        # the fresh optimizer state (below).
+        runtime = getattr(self.trial, "compile_cache_runtime_hparams", tuple)() or ()
+        fresh_opt_state = self.state.opt_state if runtime else None
+        self.state = self.state.replace(params=None, opt_state=None)
+        restored = serialization.restore_arrays(path, self._restore_template)
         self.state = self.state.replace(**restored).reset_metrics()
         # declared-runtime hyperparameters (compile_cache_runtime_hparams,
         # e.g. an inject_hyperparams lr) live in opt_state, so a restore
@@ -950,11 +996,12 @@ class Trainer:
         # resume (same hparams), wrong for a PBT clone whose explore step
         # just perturbed them.  The trial's own hparams are authoritative:
         # graft the freshly-built hyperparams back over the restored tree.
-        self.state = self.state.replace(
-            opt_state=self._reinject_runtime_hparams(
-                fresh_opt_state, self.state.opt_state
+        if runtime:
+            self.state = self.state.replace(
+                opt_state=self._reinject_runtime_hparams(
+                    fresh_opt_state, self.state.opt_state
+                )
             )
-        )
         self.steps_completed = int(tstate["steps_completed"])
         self.train_loader.load_state_dict(tstate["train_loader"])
         for k, cb in self.callbacks.items():
@@ -966,11 +1013,8 @@ class Trainer:
     def _reinject_runtime_hparams(self, fresh: Any, restored: Any) -> Any:
         """Replace ``hyperparams`` nodes (optax ``InjectHyperparamsState``)
         in a restored opt_state with the freshly-initialized ones, which
-        were built from THIS trial's hparams.  No-op unless the trial
-        declares runtime hparams."""
-        runtime = getattr(self.trial, "compile_cache_runtime_hparams", tuple)() or ()
-        if not runtime:
-            return restored
+        were built from THIS trial's hparams.  Only called for a trial
+        that declares runtime hparams."""
 
         def graft(f: Any, r: Any) -> Any:
             if type(f) is not type(r):
@@ -1021,6 +1065,9 @@ class Trainer:
         )
         if self.core.distributed.is_chief:
             self.core.train.report_validation_metrics(self.steps_completed, metrics)
+            logger.info(
+                "validation at step %d: %s", self.steps_completed, _fmt(metrics)
+            )
         for cb in self.callbacks.values():
             cb.on_validation_end(metrics)
         return metrics
@@ -1307,6 +1354,9 @@ class Trainer:
                 if self.core.distributed.is_chief:
                     self.core.train.report_training_metrics(self.steps_completed, metrics)
                     self.core.train.report_progress(self.steps_completed / max_steps)
+                    logger.info(
+                        "step %d/%d: %s", self.steps_completed, max_steps, _fmt(metrics)
+                    )
                 for cb in self.callbacks.values():
                     cb.on_training_workload_end(self.steps_completed, metrics)
 

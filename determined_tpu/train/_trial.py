@@ -207,14 +207,8 @@ class JaxTrial(abc.ABC):
         (e.g. restacking per-layer blocks into pipeline stages — see
         ``models/transformer.py`` ``split_pipeline_params``).
 
-        Runs under jit right after ``init_params``.  It is a SEPARATE hook
-        (rather than part of ``init_params``) so the Trainer can stage the
-        two on affected jax versions: a jitted restack into sharded
-        out_shardings over a multi-axis mesh SUMS its replicated operands
-        there, so the RNG-bearing init materializes replicated and only
-        this RNG-free restructure is resharded — see
-        ``parallel/_compat.py`` ``sharded_restack_safe``.  Default:
-        identity.
+        Runs under jit right after ``init_params``, inside the same
+        sharded-init program.  Default: identity.
         """
         return params
 

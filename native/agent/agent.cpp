@@ -18,7 +18,10 @@
 #include <sys/wait.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <atomic>
+#include <cctype>
+#include <cstdlib>
 #include <cstring>
 #include <filesystem>
 #include <fstream>
@@ -40,7 +43,7 @@ struct Options {
   std::string advertised_host = "127.0.0.1";
   std::string pool = "default";
   int slots = 1;
-  std::string slot_type = "cpu";  // tpu when /dev/accel*/vfio chips found
+  std::string slot_type = "cpu";  // tpu when detect_slots() finds chips
   // Topology label: agents sharing a slice_id are ICI-reachable; crossing
   // labels means DCN.  On real TPU VMs this is the multislice slice name
   // (MEGASCALE_SLICE_ID); empty = unlabeled, master falls back to
@@ -472,22 +475,68 @@ class Agent {
 }  // namespace dtpu
 
 // TPU chip enumeration (reference agent/internal/detect/: nvidia-smi for
-// cuda slots; here /dev/accel* — how libtpu exposes chips on TPU VMs —
-// else one CPU slot).  --slots overrides for tests, CPU hosts, and
-// vfio-bound TPU VMs (see the NOTE below on why vfio is not counted).
+// cuda slots).  libtpu reaches a chip through one of two kinds of device
+// node, and a host has one kind or the other:
+//   /dev/accel<N>        the accel driver's nodes (older TPU VM images);
+//   /dev/vfio/<group>    vfio-bound chips — what the v5e machines this repo
+//                        is measured on have, and the only kind they have.
+// A vfio group can just as well be a passed-through NIC or GPU, so a group
+// counts as a chip only on evidence: its PCI device carries Google's vendor
+// id in sysfs, or — where sysfs shows no PCI devices at all (sealed VMs) —
+// the host declares itself a TPU VM through libtpu's own environment
+// (TPU_ACCELERATOR_TYPE / TPU_CHIPS_PER_HOST_BOUNDS, which the TPU VM
+// image sets).  --slots overrides all of it (tests, CPU hosts).
+static bool is_number(const std::string& s) {
+  return !s.empty() && std::all_of(s.begin(), s.end(),
+                                   [](unsigned char c) { return std::isdigit(c); });
+}
+
+// 1 = a Google (0x1ae0) device sits in this iommu group, 0 = only other
+// vendors' devices do, -1 = sysfs says nothing about the group
+static int vfio_group_is_google(const std::string& group) {
+  namespace fs = std::filesystem;
+  std::error_code ec;
+  int verdict = -1;
+  fs::path devices = fs::path("/sys/kernel/iommu_groups") / group / "devices";
+  for (fs::directory_iterator it(devices, ec), end; !ec && it != end; it.increment(ec)) {
+    std::ifstream f(it->path() / "vendor");
+    std::string vendor;
+    if (!(f >> vendor)) continue;
+    if (vendor == "0x1ae0") return 1;
+    verdict = 0;
+  }
+  return verdict;
+}
+
 static int detect_slots(std::string* slot_type) {
+  namespace fs = std::filesystem;
   int n = 0;
   for (int i = 0; i < 16; ++i) {
-    if (std::filesystem::exists("/dev/accel" + std::to_string(i))) ++n;
+    if (fs::exists("/dev/accel" + std::to_string(i))) ++n;
+  }
+  if (n == 0) {
+    const bool tpu_vm = std::getenv("TPU_ACCELERATOR_TYPE") != nullptr ||
+                        std::getenv("TPU_CHIPS_PER_HOST_BOUNDS") != nullptr;
+    std::error_code ec;
+    for (fs::directory_iterator it("/dev/vfio", ec), end; !ec && it != end;
+         it.increment(ec)) {
+      const std::string group = it->path().filename().string();
+      if (!is_number(group)) continue;  // /dev/vfio/vfio is the container node
+      const int google = vfio_group_is_google(group);
+      if (google == 1 || (google == -1 && tpu_vm)) ++n;
+    }
   }
   if (n > 0) {
     *slot_type = "tpu";
     return n;
   }
-  // NOTE: /dev/vfio/N deliberately NOT counted — vfio groups also cover
-  // passthrough NICs/GPUs, so claiming them as TPU slots would schedule
-  // TPU trials onto hosts without chips.  Pass --slots on vfio-bound
-  // TPU VMs.
+  // No quiet CPU agent on what was meant to be a TPU host: a cpu slot takes
+  // trials and runs them on the CPU, which looks like a very slow success.
+  fprintf(stderr,
+          "agent: NO TPU CHIP FOUND (no /dev/accel<N>, no TPU vfio group under "
+          "/dev/vfio) and no --slots given: registering ONE slot of type cpu. "
+          "Trials placed on it run on the CPU. On a TPU host this detection "
+          "cannot see, pass --slots <chips>.\n");
   *slot_type = "cpu";
   return 1;
 }

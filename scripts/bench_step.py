@@ -47,38 +47,23 @@ from __future__ import annotations
 import json
 import os
 import statistics
-import subprocess
 import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
-_RESPAWN = "DTPU_BENCH_STEP_RESPAWNED"
-
-
-def _maybe_respawn() -> None:
-    """CPU needs the virtual 8-device platform, which must be set before
-    jax initializes — respawn once with the flag if we're short."""
-    import jax
-
-    if (
-        jax.default_backend() == "cpu"
-        and len(jax.devices()) < 8
-        and os.environ.get(_RESPAWN) != "1"
-    ):
-        env = dict(os.environ)
-        flags = [
-            f
-            for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")
-        ]
-        flags.append("--xla_force_host_platform_device_count=8")
-        env["XLA_FLAGS"] = " ".join(flags)
-        env["JAX_PLATFORMS"] = "cpu"
-        env[_RESPAWN] = "1"
-        raise SystemExit(
-            subprocess.call([sys.executable, os.path.abspath(__file__), *sys.argv[1:]], env=env)
-        )
+def _cpu_virtual_devices() -> None:
+    """These A/Bs need 8 devices.  Where the run is held to the CPU
+    (``JAX_PLATFORMS=cpu``), ask for the virtual 8-device platform — decided
+    from the environment, BEFORE jax is imported: a process that asks jax
+    what it has already holds the chip, and no child could then use it."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_force_host_platform_device_count=8"
+        ).strip()
 
 
 HP = {
@@ -413,7 +398,7 @@ def main() -> None:
                 modes.append(mode)
     if not modes:
         modes = list(_MODES)
-    _maybe_respawn()
+    _cpu_virtual_devices()
     ok = True
     for mode in modes:
         if mode == "overlap":

@@ -67,10 +67,6 @@ EXP_RAW = {
 def child_main(args) -> int:
     """One driver attempt: fresh run or journal resume; exits 0 when the
     search completes, 75 when preempted-resumable."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from determined_tpu.config import ExperimentConfig
     from determined_tpu.experiment import PREEMPTED_EXIT_CODE, LocalExperiment
     from determined_tpu.models.mnist import MnistTrial

@@ -29,7 +29,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # a chaos drill, never a chip run
 
 
 def main() -> int:
@@ -41,10 +41,6 @@ def main() -> int:
     ap.add_argument("--max-restarts", type=int, default=10)
     ap.add_argument("--seed", type=int, default=None, help="fault-schedule seed (default: time)")
     args = ap.parse_args()
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from determined_tpu import core, train
     from determined_tpu.config import ExperimentConfig, Length

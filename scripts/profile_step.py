@@ -32,13 +32,15 @@ def main():
     from determined_tpu.data import to_global
     from determined_tpu.models.transformer import LMTrial
     from determined_tpu.parallel.mesh import MeshConfig
+    from determined_tpu.utils.chip import require_tpu
 
+    print("device:", require_tpu("scripts/profile_step.py"))
     fused = os.environ.get("DTPU_BENCH_OPT", "auto")
     hp = {
         "lr": 3e-4, "global_batch_size": 8, "seq_len": 1024,
         "vocab_size": 32768, "d_model": 2048, "n_layers": 8, "n_heads": 16,
         "dataset_size": 64, "bf16": True,
-        "attention": "flash" if jax.default_backend() == "tpu" else "reference",
+        "attention": "flash",
         "warmup_steps": 10,
         "fused_adamw": {"auto": "auto", "fused": True, "ref": False}[fused],
         "adam_mu_bf16": os.environ.get("DTPU_BENCH_MU_BF16", "0") == "1",

@@ -67,7 +67,6 @@ def child() -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     n = int(os.environ["_DTPU_SCALING_N"])
     steps = int(os.environ["_DTPU_SCALING_STEPS"])
 
@@ -131,26 +130,18 @@ def child() -> None:
         #    sharding inserts around each matmul)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        try:
-            shard_map = jax.shard_map
-            smap_kw = {"check_vma": False}
-        except AttributeError:  # pragma: no cover - older jax flag name
-            from jax.experimental.shard_map import shard_map
-
-            smap_kw = {"check_rep": False}
-
         params = trainer.state.params
         jmesh = trainer.mesh
         rep = jax.tree.map(lambda _: P(), params)
         psum_fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda t: jax.tree.map(
                     lambda a: jax.lax.psum(a, ("data", "fsdp")), t
                 ),
                 mesh=jmesh,
                 in_specs=(rep,),
                 out_specs=rep,
-                **smap_kw,
+                check_vma=False,
             )
         )
         rep_params = jax.device_put(

@@ -266,6 +266,9 @@ def test_link_bandwidth_env_per_link_and_back_compat(monkeypatch):
     ici, dcn = _overlap.link_bandwidths("TPU v5p")
     assert ici == _overlap.ICI_BW_BY_KIND["TPU v5p"]
     assert dcn == _overlap.DCN_BW_BY_KIND["TPU v5p"]
+    # a TPU the tables do not know is an error, not a default
+    with pytest.raises(ValueError, match="TPU v99"):
+        _overlap.link_bandwidths("TPU v99")
 
 
 def test_hierarchical_requires_overlap():

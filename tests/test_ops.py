@@ -51,6 +51,44 @@ def test_flash_gradients_match():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4)
 
 
+@pytest.mark.parametrize(
+    "mesh_cfg,hkv,batch",
+    [
+        (MeshConfig(fsdp=4), 4, 4),
+        (MeshConfig(fsdp=2, tensor=2), 4, 4),
+        (MeshConfig(data=2, fsdp=2, tensor=2), 2, 4),   # GQA, kv heads split too
+        (MeshConfig(fsdp=2, tensor=4), 2, 4),           # tensor does not divide kv heads
+        (MeshConfig(fsdp=4), 4, 2),                     # batch axes do not divide the batch
+    ],
+    ids=["fsdp4", "fsdp2_tp2", "dp2_fsdp2_tp2_gqa", "tp4_expands_kv", "batch_stays_whole"],
+)
+def test_sharded_flash_matches_reference_fwd_and_bwd(devices8, mesh_cfg, hkv, batch):
+    """On a multi-device mesh the kernel runs per device inside shard_map
+    (XLA cannot partition a Mosaic kernel: tests/test_tpu_compile.py); the
+    values and all three gradients must still be the reference's."""
+    from determined_tpu.ops.attention import dot_product_attention
+
+    mesh = make_mesh(mesh_cfg, devices8[: mesh_cfg.num_devices])
+    q, k, v = make_qkv(b=batch, h=4, s=128, d=32, hkv=hkv)
+    spec = P(("data", "fsdp"), None, None, None) if batch == 4 else P()
+    qg, kg, vg = (jax.device_put(t, NamedSharding(mesh, spec)) for t in (q, k, v))
+
+    def flash(q, k, v):
+        return dot_product_attention(q, k, v, causal=True, impl="flash", mesh=mesh)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    out = jax.jit(flash)(qg, kg, vg)
+    ref = reference_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(qg, kg, vg)
+    gr = jax.grad(loss(lambda q, k, v: reference_attention(q, k, v, causal=True)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4)
+
+
 def test_flash_rejects_nothing_on_small_seq():
     # odd seq sizes fall back to smaller blocks via _pick_block
     q, k, v = make_qkv(s=96)
@@ -219,7 +257,7 @@ def test_fused_ce_bf16_residual_grads_close():
     np.testing.assert_allclose(gw16, gwr, rtol=0.1, atol=5e-3)
 
 
-def test_fused_adamw_matches_optax_chain():
+def test_fused_adamw_matches_optax_chain(monkeypatch):
     """The single-sweep fused optimizer must be bit-compatible (to f32
     rounding) with optax.chain(clip_by_global_norm, adamw) over a
     multi-step trajectory; the big leaf takes the pallas path (interpret
@@ -231,6 +269,8 @@ def test_fused_adamw_matches_optax_chain():
 
     from determined_tpu.ops.fused_adamw import fused_adamw
 
+    # the shipped threshold (8 Mi elements) would send both leaves down jnp
+    monkeypatch.setenv("DTPU_FUSED_MIN_SIZE", "1024")
     rng = np.random.default_rng(0)
     params = {
         "w": jnp.asarray(rng.standard_normal((512, 1024)), jnp.float32),
@@ -259,6 +299,51 @@ def test_fused_adamw_matches_optax_chain():
                 np.asarray(fp[k]), np.asarray(rp[k]), rtol=2e-6, atol=2e-7,
                 err_msg=f"step {step} leaf {k}",
             )
+
+
+def test_fused_adamw_sharded_leaves_match_optax_chain(devices8, monkeypatch):
+    """With the Trainer's shardings named, every big leaf is planned on
+    its LOCAL shard and swept per device inside shard_map (the Pallas path,
+    interpret mode here), the clip scale still global: same trajectory as
+    the optax chain, same layouts out as in."""
+    import optax
+
+    from determined_tpu.ops.fused_adamw import fused_adamw
+
+    monkeypatch.setenv("DTPU_FUSED_MIN_SIZE", "1024")
+    mesh = make_mesh(MeshConfig(fsdp=2, tensor=2), devices8[:4])
+    rng = np.random.default_rng(0)
+    shapes = {"w": (512, 1024), "h": (256, 4, 128), "r": (256, 512), "b": (64,)}
+    specs = {"w": P("fsdp", "tensor"), "h": P(None, "tensor", None), "r": P(), "b": P()}
+    params = {k: jnp.asarray(rng.standard_normal(s), jnp.float32) for k, s in shapes.items()}
+    shardings = {k: NamedSharding(mesh, s) for k, s in specs.items()}
+    sched = optax.warmup_cosine_decay_schedule(0.0, 1e-2, 2, 100)
+    fused = fused_adamw(sched, weight_decay=0.01, clip_norm=1.0)
+    ref = optax.chain(
+        optax.clip_by_global_norm(1.0), optax.adamw(sched, weight_decay=0.01)
+    )
+    step = jax.jit(lambda g, s, p: fused.apply_step(g, s, p, shardings=shardings))
+    fp, rp = jax.device_put(params, shardings), params
+    fs, rs = jax.jit(fused.init)(fp), ref.init(rp)
+    # three of the four leaves take the kernel (one replicated over the mesh)
+    assert str(jax.make_jaxpr(step)(fp, fs, fp)).count("pallas_call") == 3
+    for i in range(3):
+        grads = jax.tree.map(
+            lambda p: jnp.asarray(
+                rng.standard_normal(p.shape) * (10.0 if i == 0 else 0.1), jnp.float32
+            ),
+            rp,
+        )
+        fp, fs = step(jax.device_put(grads, shardings), fs, fp)
+        updates, rs = jax.jit(ref.update)(grads, rs, rp)
+        rp = optax.apply_updates(rp, updates)
+        for k in params:
+            np.testing.assert_allclose(
+                np.asarray(fp[k]), np.asarray(rp[k]), rtol=2e-6, atol=2e-7,
+                err_msg=f"step {i} leaf {k}",
+            )
+            assert fp[k].sharding.is_equivalent_to(shardings[k], fp[k].ndim)
+            assert fs.mu[k].sharding.is_equivalent_to(shardings[k], fp[k].ndim)
 
 
 def test_fused_adamw_bf16_mu():

@@ -85,10 +85,9 @@ def test_pipe_trains_through_trainer(tmp_path, mesh_config):
 # was never GPipe numerics — jax 0.4.37's SPMD partitioner SUMS replicated
 # operands of a jitted stack whose output is sharded over a multi-axis
 # mesh, so the pipe trial's restacked block params initialized to exactly
-# 2x the pipe=1 comparator's weights.  The Trainer now stages init on
-# affected jax (replicated RNG phase -> eager restack -> device_put
-# reshard; parallel/_compat.py sharded_restack_safe), and parity is
-# bit-exact.
+# 2x the pipe=1 comparator's weights.  The installed jax (0.9.0) does not
+# have that fault, the Trainer's staged-init work-around is gone, and
+# parity is bit-exact.
 
 
 def test_pipe2_loss_parity_vs_pipe1(tmp_path):

@@ -4,6 +4,7 @@ injection through the supervised-restart path, validation parity, and the
 sharding/compilation caches.  Tier-1 (no markers), CPU-fast.
 """
 
+import os
 import threading
 import time
 
@@ -370,39 +371,82 @@ def test_optimizations_knobs_parse_and_validate():
         ExperimentConfig.parse({"optimizations": {"fetch_workers": -2}})
 
 
-def test_compilation_cache_setup_cold_then_warm(tmp_path, caplog, monkeypatch):
-    cache_dir = str(tmp_path / "xla-cache")
+@pytest.fixture()
+def fresh_cache_setup(monkeypatch):
+    """setup_compilation_cache as a new process sees it: nothing applied
+    yet, no ``JAX_COMPILATION_CACHE_DIR``; jax's own setting is put back."""
     prev = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    prev_configured = compilation_cache._configured
-    try:
-        compilation_cache._configured = None
-        with caplog.at_level("INFO", logger="determined_tpu.utils.compilation_cache"):
-            path = compilation_cache.setup_compilation_cache(cache_dir)
-        assert path == cache_dir
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        assert any("cold" in r.message for r in caplog.records)
+    prev_tb = jax.config.jax_include_full_tracebacks_in_locations
+    monkeypatch.delenv(compilation_cache.ENV_VAR, raising=False)
+    monkeypatch.setattr(compilation_cache, "_configured", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+    jax.config.update("jax_include_full_tracebacks_in_locations", prev_tb)
 
-        # repeat setup in the same process is a no-op (no duplicate logs)
-        n = len(caplog.records)
-        assert compilation_cache.setup_compilation_cache(cache_dir) == cache_dir
-        assert len(caplog.records) == n
 
-        # a restarted process with a populated dir reports warm
-        (tmp_path / "xla-cache" / "entry").write_bytes(b"x")
-        compilation_cache._configured = None
-        with caplog.at_level("INFO", logger="determined_tpu.utils.compilation_cache"):
-            compilation_cache.setup_compilation_cache(cache_dir)
-        assert any("warm" in r.message for r in caplog.records)
+def test_compilation_cache_setup_cold_then_warm(tmp_path, caplog, fresh_cache_setup):
+    cache_dir = str(tmp_path / "xla-cache")
+    log = "determined_tpu.utils.compilation_cache"
+    with caplog.at_level("INFO", logger=log):
+        path = compilation_cache.setup_compilation_cache(cache_dir)
+    assert path == cache_dir
+    assert jax.config.jax_compilation_cache_dir == cache_dir
+    assert any("cold" in r.message for r in caplog.records)
 
-        # jax's min-compile-time default is preserved unless the env
-        # explicitly overrides it (sub-second CPU entries are not cached)
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == prev_min
-        monkeypatch.setenv("DTPU_COMPILATION_CACHE_MIN_COMPILE_SECS", "5")
-        compilation_cache._configured = None
+    # repeat setup in the same process is a no-op (no duplicate logs), and
+    # a later caller that declares nothing (the server's kernels after
+    # train.init) keeps the declared directory
+    n = len(caplog.records)
+    assert compilation_cache.setup_compilation_cache(cache_dir) == cache_dir
+    assert compilation_cache.setup_compilation_cache(None) == cache_dir
+    assert len(caplog.records) == n
+
+    # a restarted process with a populated dir reports warm
+    (tmp_path / "xla-cache" / "entry").write_bytes(b"x")
+    compilation_cache._configured = None
+    with caplog.at_level("INFO", logger=log):
         compilation_cache.setup_compilation_cache(cache_dir)
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 5.0
-    finally:
-        compilation_cache._configured = prev_configured
-        jax.config.update("jax_compilation_cache_dir", prev)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
+    assert any("warm (1 entries)" in r.message for r in caplog.records)
+    # and the key of a program no longer depends on its caller's stack
+    # (tests/test_tpu_compile.py shows what that is about)
+    assert jax.config.jax_include_full_tracebacks_in_locations is False
+
+
+@pytest.mark.parametrize("declared", [None, "knob"])
+def test_compilation_cache_env_dir_wins_and_nothing_else_is_set(
+    tmp_path, caplog, monkeypatch, fresh_cache_setup, declared
+):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, the program sets
+    no directory in code, and the experiment knob is only logged."""
+    env_dir = str(tmp_path / "from-env")
+    knob = str(tmp_path / declared) if declared else None
+    monkeypatch.setenv(compilation_cache.ENV_VAR, env_dir)
+    # what jax holds must come back untouched: a sentinel stands in for the
+    # value it read from the variable at import
+    jax.config.update("jax_compilation_cache_dir", "/sentinel/untouched")
+    with caplog.at_level("INFO", logger="determined_tpu.utils.compilation_cache"):
+        path = compilation_cache.setup_compilation_cache(knob)
+    assert path == env_dir
+    assert jax.config.jax_compilation_cache_dir == "/sentinel/untouched"
+    overridden = [r for r in caplog.records if "overridden" in r.message]
+    assert len(overridden) == (1 if declared else 0)
+    assert not (declared and os.path.exists(knob))
+
+
+def test_compilation_cache_default_is_one_fixed_path_in_the_checkout(
+    fresh_cache_setup, monkeypatch
+):
+    """Nothing declared: the fixed git-ignored path inside the checkout —
+    the same in every process, so a second run finds the first's entries."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expect = os.path.join(repo, ".dtpu_cache", "xla")
+    assert compilation_cache.DEFAULT_CACHE_DIR == expect
+    assert compilation_cache.resolve_cache_dir() == expect
+    made = []
+    monkeypatch.setattr(compilation_cache.os, "makedirs", lambda p, **kw: made.append(p))
+    monkeypatch.setattr(compilation_cache.os, "scandir", lambda p: iter(()))
+    assert compilation_cache.setup_compilation_cache() == expect
+    assert made == [expect]
+    assert jax.config.jax_compilation_cache_dir == expect
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".dtpu_cache/" in f.read().split()

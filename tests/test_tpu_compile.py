@@ -1,0 +1,281 @@
+"""What the TPU's own compiler says of the main path's kernels — no chip.
+
+The sandbox has no accelerator, but libtpu compiles for a chip that is
+described and not attached (``jax.experimental.topologies``; the
+``on-chip-measurement`` guide, section 2).  Interpret mode cannot show what
+this does: a Mosaic kernel the compiler refuses (tiling, VMEM), a program
+XLA cannot partition, a step that does not fit the device.  Every case is
+a compile at the flagship's real widths (``examples/transformer_lm/
+const.yaml``: d2048, 16 heads x 128, vocab 32768, bf16); nothing runs, so
+nothing here is a result or a time.
+
+The two 4-device-mesh cases are the ones that would have caught the fault
+this file was added with: both Pallas kernels were called bare under GSPMD
+and every multi-chip LM trial failed to compile with "Mosaic kernels cannot
+be automatically partitioned", while every virtual-CPU-mesh test passed
+(the interpreter lowers the kernels to plain ops, which partition).
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the
+kernels' ``_interpret`` is steered from the test.
+"""
+
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from determined_tpu.ops.attention import dot_product_attention
+from determined_tpu.parallel.mesh import MeshConfig, make_mesh
+
+# the package re-exports functions under the modules' names
+flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")
+adamw_mod = importlib.import_module("determined_tpu.ops.fused_adamw")
+
+TOPOLOGY = "v5e:2x2"
+
+
+@pytest.fixture(scope="module")
+def tpu_devices():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY)
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it cannot describe the chip
+        pytest.skip(f"cannot describe a {TOPOLOGY} topology here: {e}")
+    return list(topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def _real_kernels_no_cache(monkeypatch):
+    """Mosaic, not the interpreter; and no persistent cache around the
+    compiles (an entry compiled for a described chip cannot be read back
+    without one, and the next run would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
+    monkeypatch.setattr(adamw_mod, "_interpret", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, *avals) -> str:
+    """The optimized program's text; raises what the chip's compiler would."""
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _kernels(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _qkv(shape, sharding, kv_heads=None):
+    b, h, s, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, kv_heads or h, s, d), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+def _attn_loss(mesh, q, k, v):
+    out = dot_product_attention(q, k, v, causal=True, impl="flash", mesh=mesh)
+    return jnp.sum(out.astype(jnp.float32) ** 2)
+
+
+# -- flash attention ---------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(8, 16, 1024, 128), (1, 16, 4096, 128)],
+    ids=["const_yaml_b8_s1024", "long_b1_s4096"],
+)
+def test_flash_fwd_bwd_compiles_on_one_chip(tpu_devices, shape):
+    one = SingleDeviceSharding(tpu_devices[0])
+    grad = jax.grad(functools.partial(_attn_loss, None), argnums=(0, 1, 2))
+    text = _compile(grad, *_qkv(shape, one))
+    assert _kernels(text) == 3  # fwd, dq, dkv
+
+
+@pytest.mark.parametrize(
+    "mesh_cfg,kv_heads",
+    [(MeshConfig(fsdp=4), 16), (MeshConfig(fsdp=2, tensor=2), 16),
+     (MeshConfig(fsdp=2, tensor=2), 1)],
+    ids=["fsdp4", "fsdp2_tensor2", "fsdp2_tensor2_mqa"],
+)
+def test_flash_fwd_bwd_compiles_over_a_four_chip_mesh(tpu_devices, mesh_cfg, kv_heads):
+    """Global batch 8 sharded over fsdp, heads over tensor: each device
+    compiles the kernel on its own block inside shard_map."""
+    mesh = make_mesh(mesh_cfg, tpu_devices)
+    sh = NamedSharding(mesh, P("fsdp", None, None, None))
+    grad = jax.grad(functools.partial(_attn_loss, mesh), argnums=(0, 1, 2))
+    text = _compile(grad, *_qkv((8, 16, 1024, 128), sh, kv_heads))
+    assert _kernels(text) == 3
+
+
+def test_bare_flash_under_gspmd_is_what_the_compiler_refuses(tpu_devices):
+    """The fault itself, pinned: without the mesh (so without shard_map)
+    sharded operands reach a bare pallas_call and XLA cannot partition it."""
+    mesh = make_mesh(MeshConfig(fsdp=4), tpu_devices)
+    sh = NamedSharding(mesh, P("fsdp", None, None, None))
+    grad = jax.grad(functools.partial(_attn_loss, None), argnums=(0, 1, 2))
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        _compile(grad, *_qkv((8, 16, 1024, 128), sh))
+
+
+def test_a_kernel_compiles_to_the_same_program_whoever_calls_it(tpu_devices):
+    """Mosaic serializes a kernel with its debug locations, and XLA's
+    compile-cache key hashes that payload.  With jax's default ten frames
+    of Python call stack in every location, the same train step had one key
+    under ``dtpu experiment run``, another under ``run_trial``, and a new
+    one at every restart of a cluster trial (code unpacked to a fresh temp
+    directory) — on the chip: 28 s compiled again each time.
+    ``setup_compilation_cache`` turns the call stack off; then the lowered
+    program is the same text from any caller."""
+    one = SingleDeviceSharding(tpu_devices[0])
+
+    def lowered_from(filename: str) -> str:
+        ns: dict = {}
+        exec(compile("def call(fn, *a):\n    return fn(*a)\n", filename, "exec"), ns)
+        loss = functools.partial(_attn_loss, None)
+        return jax.jit(lambda q, k, v: ns["call"](loss, q, k, v)).lower(
+            *_qkv((1, 16, 1024, 128), one)
+        ).as_text()
+
+    prev = jax.config.jax_include_full_tracebacks_in_locations
+    try:
+        jax.config.update("jax_include_full_tracebacks_in_locations", True)
+        assert lowered_from("/tmp/ctx-aaaa/model_def.py") != lowered_from(
+            "/tmp/ctx-bbbb/model_def.py"
+        )
+        jax.config.update("jax_include_full_tracebacks_in_locations", False)
+        assert lowered_from("/tmp/ctx-aaaa/model_def.py") == lowered_from(
+            "/tmp/ctx-bbbb/model_def.py"
+        )
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", prev)
+
+
+# -- fused AdamW -------------------------------------------------------------
+
+# every >=2-d leaf shape of the const.yaml model (embed, lm_head, swiglu
+# up/gate + down, q/k/v and out projections)
+LEAF_SHAPES = [
+    (32768, 2048), (2048, 32768), (2048, 8192), (8192, 2048),
+    (2048, 16, 128), (16, 128, 2048),
+]
+
+
+@pytest.mark.parametrize("shape", LEAF_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_fused_adamw_leaf_compiles_on_one_chip(tpu_devices, shape):
+    one = SingleDeviceSharding(tpu_devices[0])
+    assert adamw_mod._plan_blocks(shape) is not None
+    kw = dict(b1=0.9, b2=0.999, eps=1e-8, wd=0.01)
+    fn = lambda p, m, v, g, s: adamw_mod._leaf_pallas(p, m, v, g, s, **kw)  # noqa: E731
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+    scalars = jax.ShapeDtypeStruct((1, 4), jnp.float32, sharding=one)
+    text = _compile(fn, leaf, leaf, leaf, leaf, scalars)
+    assert _kernels(text) == 1
+
+
+def test_fused_adamw_step_compiles_over_a_four_chip_mesh(tpu_devices, monkeypatch):
+    """The whole optimizer step on a tensor-sharded param tree (what
+    ``fsdp: 2, tensor: 2`` gives the const.yaml model's leaves): every big
+    leaf is planned on its LOCAL shard and swept per device."""
+    monkeypatch.setenv("DTPU_FUSED_MIN_SIZE", str(1024 * 1024))
+    mesh = make_mesh(MeshConfig(fsdp=2, tensor=2), tpu_devices)
+    specs = {
+        "embed": ((32768, 2048), P("tensor", None)),
+        "w_up": ((2048, 8192), P(None, "tensor")),
+        "w_down": ((8192, 2048), P("tensor", None)),
+        "wq": ((2048, 16, 128), P(None, "tensor", None)),
+        "wk": ((2048, 16, 128), P()),            # replicated over the mesh
+        "ln": ((2048,), P()),                    # small: the jnp path
+    }
+    shardings = {k: NamedSharding(mesh, s) for k, (_, s) in specs.items()}
+    params = {
+        k: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=shardings[k])
+        for k, (shape, _) in specs.items()
+    }
+    opt = adamw_mod.fused_adamw(3e-4, clip_norm=1.0)
+    state = jax.eval_shape(opt.init, params)
+    state = adamw_mod.FusedAdamWState(
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
+        *(
+            {k: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=shardings[k])
+             for k, a in tree.items()}
+            for tree in (state.mu, state.nu)
+        ),
+    )
+    step = lambda g, s, p: opt.apply_step(g, s, p, shardings=shardings)  # noqa: E731
+    text = _compile(step, params, state, params)
+    assert _kernels(text) == 5  # all but the norm scale
+
+    # the same step with no shardings named is the bare call GSPMD refuses
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        _compile(lambda g, s, p: opt.apply_step(g, s, p), params, state, params)
+
+
+# -- the serving programs ----------------------------------------------------
+
+
+@pytest.mark.parametrize("which", ["prefill", "prefill_suffix", "decode"])
+def test_serve_programs_compile_on_one_chip(tpu_devices, which):
+    """``dtpu serve``'s three jitted programs with the default ServeConfig
+    at d2048 / 16 heads / vocab 32768 — paged scatters and gathers, the
+    donated cache — depth cut to 2 layers (depth repeats, it does not
+    change what the compiler must accept)."""
+    from determined_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+        kv_cache_shape,
+        transformer_decode,
+        transformer_prefill,
+        transformer_prefill_suffix,
+    )
+    from determined_tpu.serve.config import ServeConfig
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=32768, d_model=2048, n_layers=2, n_heads=16, max_seq_len=1024,
+        attention_impl="flash",
+    )
+    sc = ServeConfig()
+    boxed = jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )
+    from flax.core import meta as flax_meta
+
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cshape = kv_cache_shape(cfg, sc.num_blocks, sc.block_size)
+    cache = {"k": aval(cshape, cfg.dtype), "v": aval(cshape, cfg.dtype)}
+    table = aval((1, sc.blocks_per_seq))
+    if which == "prefill":
+        fn = jax.jit(functools.partial(transformer_prefill, cfg), donate_argnums=(4,))
+        args = (params, aval((1, sc.max_prompt_len)), aval((1,)), table, cache)
+    elif which == "prefill_suffix":
+        fn = jax.jit(
+            functools.partial(transformer_prefill_suffix, cfg), donate_argnums=(5,)
+        )
+        pad = sc.blocks_for(sc.max_prompt_len) * sc.block_size
+        args = (params, aval((1, pad)), aval((1,)), aval((1,)), table, cache)
+    else:
+        fn = jax.jit(
+            functools.partial(
+                transformer_decode, cfg, chunk_blocks=sc.decode_chunk_blocks
+            ),
+            donate_argnums=(4,),
+        )
+        b = sc.max_batch
+        args = (params, aval((b,)), aval((b,)), aval((b, sc.blocks_per_seq)), cache)
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 1024**3
