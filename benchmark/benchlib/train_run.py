@@ -1,0 +1,354 @@
+"""A training cell: the program's ``Trainer.fit`` on packed sequences.
+
+The system under test is ``train.Trainer(trial).fit(...)`` as a user runs
+it: its own loop dispatches the steps, its own prefetching input pipeline
+feeds them.  The trial is the program's ``LMTrial`` with three things a user
+would also write in a subclass: the rotary base of the configuration, a
+dataset made from ``--seed``, and a callback.  The callback is the
+benchmark's only hold on the loop: the Trainer calls it at every report
+boundary, right after it has fetched the loss (a value fetch: every step
+dispatched so far has run), and the window opens and closes at such calls.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import os
+import time
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import numpy as np
+
+from . import model
+from .observe import Observations, Profiler, tracer_epoch
+
+mono = time.monotonic
+
+
+class WindowClosed(Exception):
+    """Raised from the callback to end ``fit`` when the window has closed."""
+
+
+class Window:
+    """Report boundaries, as the callback sees them.
+
+    Boundary 1 ends the steps that compiled, boundary ``warm_boundaries``
+    opens the window, and the first boundary at or after ``seconds`` closes
+    it.  A traced run profiles exactly one report segment in the middle.
+    """
+
+    def __init__(self, seconds: float, warm_boundaries: int, profiler: Optional[Profiler], seg_steps: int) -> None:
+        self.seconds = seconds
+        self.warm = warm_boundaries
+        self.profiler = profiler
+        self.seg_steps = seg_steps
+        self.marks: List[Tuple[float, int, float]] = []  # (time, steps, loss)
+        self.traced_steps = 0
+        self._tracing = False
+
+    def boundary(self, steps: int, metrics: Dict[str, float]) -> None:
+        now = mono()
+        self.marks.append((now, steps, float(metrics.get("loss", float("nan")))))
+        n = len(self.marks)
+        if n < self.warm:
+            return
+        t_open = self.marks[self.warm - 1][0]
+        if self.profiler is not None:
+            if self._tracing:
+                self.profiler.stop()
+                self._tracing = False
+                self.traced_steps = self.seg_steps
+            elif self.profiler.started_at is None and now - t_open >= 0.4 * self.seconds:
+                self.profiler.start()
+                self._tracing = True
+        if now - t_open >= self.seconds and not self._tracing:
+            raise WindowClosed()
+
+    @property
+    def open_close(self) -> Tuple[Tuple[float, int, float], Tuple[float, int, float]]:
+        return self.marks[self.warm - 1], self.marks[-1]
+
+
+def _hparams(config: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
+    batch = int(config["train_batch"]["global_batch_sequences"])
+    return {
+        "lr": float(traffic["lr"]),
+        "warmup_steps": int(traffic["warmup_steps"]),
+        "decay_steps": int(traffic["decay_steps"]),
+        "weight_decay": float(traffic["weight_decay"]),
+        "grad_clip": float(traffic["grad_clip"]),
+        "global_batch_size": batch,
+        "seq_len": int(traffic["seq_len"]),
+        "dataset_size": batch * int(traffic["dataset_batches"]),
+        "vocab_size": int(config["vocab_size"]),
+        "d_model": int(config["hidden_size"]),
+        "n_layers": int(config["num_hidden_layers"]),
+        "n_heads": int(config["num_attention_heads"]),
+        "n_kv_heads": int(config["num_key_value_heads"]),
+        "d_ff": int(config["intermediate_size"]),
+        "bf16": config["dtypes"]["compute"] == "bfloat16",
+        "attention": traffic["attention"],
+        "fused_ce": bool(traffic["fused_ce"]),
+        "fused_adamw": bool(traffic["fused_adamw"]),
+        "adam_mu_bf16": False,
+        "remat": False,
+    }
+
+
+def build_trainer(cell: Any, seed: int, window: Optional[Window], ckpt_dir: str) -> Any:
+    import jax
+
+    from determined_tpu import core, train
+    from determined_tpu.data import SyntheticDataset
+    from determined_tpu.models.transformer import LMTrial
+    from determined_tpu.parallel.mesh import MeshConfig
+    from determined_tpu.train._trial import Callback
+
+    config, traffic = cell.config, cell.traffic
+    model.check_as_run(config)
+    if cell.chips != 1:
+        raise ValueError("this runner lays a cell on one chip: a cell over several brings its mesh with it")
+    rope_theta = float(config["rope_theta"])
+
+    class Boundary(Callback):
+        def on_training_workload_end(self, steps_completed: int, metrics: Dict[str, float]) -> None:
+            if window is not None:
+                window.boundary(steps_completed, metrics)
+
+    class BenchTrial(LMTrial):
+        def _cfg(self) -> Any:
+            return dataclasses.replace(super()._cfg(), rope_theta=rope_theta)
+
+        def _dataset(self, split: int) -> Any:
+            g = self.context.get_hparam
+            # packed: every sequence is full, seq_len + 1 tokens, no padding
+            return SyntheticDataset(
+                {"tokens": ((int(g("seq_len")) + 1,), np.int32, int(g("vocab_size")))},
+                size=int(g("dataset_size")),
+                seed=np.random.SeedSequence([int(seed), split]).generate_state(1)[0],
+            )
+
+        def build_callbacks(self) -> Dict[str, Any]:
+            return {"bench": Boundary()}
+
+    ctx = train.init(
+        hparams=_hparams(config, traffic),
+        mesh_config=MeshConfig(data=1),
+        core_context=core._dummy_init(checkpoint_dir=ckpt_dir),
+        seed=model.seed32(seed),
+        devices=jax.devices()[:1],
+    )
+    return train.Trainer(BenchTrial(ctx))
+
+
+def run(
+    cell: Any, seed: int, seconds: float, traced: bool,
+    t_start: float, say: Callable[..., None], trace_dir: str,
+) -> Dict[str, Any]:
+    import jax
+
+    from determined_tpu.observability import get_tracer
+
+    config, traffic = cell.config, cell.traffic
+    seg = int(traffic["report_every_steps"])
+    profiler = Profiler(trace_dir) if traced else None
+    window = Window(seconds, int(traffic["warm_boundaries"]), profiler, seg)
+    tracer = get_tracer()
+    tracer.configure(enabled=traced)
+    epoch = tracer_epoch(tracer) if traced else 0.0
+    ckpt_dir = os.path.join(os.path.dirname(trace_dir), "ckpt")
+    trainer = build_trainer(cell, seed, window, ckpt_dir)
+    say("setup", stage="trainer_built", seconds_since_start=mono() - t_start)
+    try:
+        trainer.fit(
+            {"batches": 10**9},
+            report_period={"batches": seg},
+            checkpoint_policy="none",
+        )
+    except WindowClosed:
+        pass
+    finally:
+        if profiler is not None:
+            profiler.close()
+    (t_open, s_open, _), (t_close, s_close, _) = window.open_close
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.devices()[: cell.chips])
+    tokens_per_step = int(config["train_batch"]["global_batch_sequences"]) * int(traffic["seq_len"])
+    steps = s_close - s_open
+    rate = steps * tokens_per_step / (t_close - t_open)
+    losses = [loss for _, _, loss in window.marks]
+    seg_rates = [
+        (b[1] - a[1]) * tokens_per_step / (b[0] - a[0])
+        for a, b in zip(window.marks[window.warm - 1:], window.marks[window.warm:])
+    ]
+    say(
+        "train.window", window_s=t_close - t_open, steps=steps,
+        tokens_per_step=tokens_per_step, segment_tokens_per_s=seg_rates,
+        boundary_losses=losses, first_boundary_s=window.marks[0][0] - t_start,
+    )
+    obs = Observations(
+        window=(t_open, t_close), spans=[],
+        counters={
+            "train.steps": float(steps),
+            "train.steps_traced": float(window.traced_steps),
+            "train.tokens_per_s": rate,
+        },
+        program_events=tracer.chrome_events() if traced else [],
+        profiler=profiler, config=config, traffic=traffic, chips=cell.chips,
+        program_epoch=epoch,
+    )
+    ok, detail = _check(trainer, cell, seed)
+    say("train.check", **detail)
+    finite = all(math.isfinite(x) for x in losses)
+    return {
+        "values": {"train_tokens_per_s": rate, "setup_s": t_open - t_start},
+        "attempted": steps,
+        "failed": 0 if finite else steps,
+        "correct": bool(ok and finite and steps > 0),
+        "memory_peak_bytes": peak,
+        "observations": obs,
+    }
+
+
+def _probe(weights: Dict[str, Any], embed_rows: np.ndarray) -> Dict[str, Any]:
+    """A few leaves (or their first rows) of a tree under the reference's
+    names: what one step's update is compared on.  The embedding's rows are
+    given: some that the batch holds and some that it does not."""
+    first, last = weights["layers"][0], weights["layers"][-1]
+    return {
+        "embed": weights["embed"][embed_rows],
+        "first.wq": first["wq"][:256],
+        "first.w_gate": first["w_gate"][:256],
+        "last.wo": last["wo"][:8],
+        "last.w_down": last["w_down"][:256],
+        "last.mlp_norm": last["mlp_norm"],
+        "final_norm": weights["final_norm"],
+        "head": weights["head"][:256],
+    }
+
+
+def _adam_state(opt_state: Any) -> Any:
+    """The part of the program's optimizer state that has ``count``, ``mu``
+    and ``nu``, whichever optimizer the trial built."""
+    import jax
+
+    is_adam = lambda x: hasattr(x, "mu") and hasattr(x, "nu")  # noqa: E731
+    return next(x for x in jax.tree_util.tree_leaves(opt_state, is_leaf=is_adam) if is_adam(x))
+
+
+def _rel(got: Any, want: Any) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.sum((got - want) ** 2)) / max(np.sqrt(np.sum(want**2)), 1e-30))
+
+
+def _check(trainer: Any, cell: Any, seed: int) -> Tuple[bool, Dict[str, Any]]:
+    """The program against the reference on one seeded sequence, from the
+    parameters and moments as the window left them.
+
+    Forward: the program's logits at every position (its model in the
+    configuration's compute dtype, flash kernel) and its loss (fused
+    cross-entropy) against the reference's.  One whole step of the program
+    (``Trainer``'s own jitted step: backward pass, fused cross-entropy's
+    gradient, clipping, fused AdamW) on that sequence against the reference's
+    ``jax.grad`` and its plain AdamW, on a few leaves: the clipped gradient
+    the program used (read back from its first moment), the second moment it
+    wrote, and the change of the parameters.
+    """
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from flax.core import meta
+
+    from reference import adamw, dense_decoder
+
+    config, traffic = cell.config, cell.traffic
+    tol = config["tolerance"]["train_step"]
+    n, layers = int(tol["sequence_tokens"]), int(config["num_hidden_layers"])
+    opt = {**traffic["adam"], "weight_decay": float(traffic["weight_decay"])}
+    rng = np.random.default_rng([int(seed), 0xC0FFEE])
+    seq = rng.integers(1, int(config["vocab_size"]), size=n + 1, dtype=np.int64).astype(np.int32)
+    present = np.unique(seq[:-1])
+    absent = np.setdiff1d(np.arange(int(config["vocab_size"])), present)
+    rows = np.concatenate([present[:192], absent[:64]])
+    batch = {"tokens": jnp.asarray(seq[None, :])}
+    trial = trainer.trial
+
+    def named(tree: Any) -> Dict[str, Any]:
+        return model.reference_weights(meta.unbox(tree)["params"], layers)
+
+    probe = jax.jit(lambda tree: _probe(named(tree), rows))
+
+    def reference(weights: Dict[str, Any], tokens: jax.Array):
+        (loss, logits), grads = jax.value_and_grad(
+            functools.partial(
+                dense_decoder.loss_and_logits,
+                rope_theta=float(config["rope_theta"]), eps=model.eps_as_run(config),
+            ),
+            has_aux=True,
+        )(weights, tokens)
+        norm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads)))
+        return loss, logits, norm, _probe(grads, rows)
+
+    def program(params: Any, tokens: jax.Array):
+        loss, _ = trial.loss(trainer.model, params, {"tokens": tokens}, jax.random.key(0))
+        return loss, trainer.model.apply(params, tokens[:, :-1])[0]
+
+    took = {}
+
+    def lap(what: str, t0: float) -> float:
+        took[what] = round(mono() - t0, 2)
+        return mono()
+
+    t = mono()
+    with trainer.mesh:
+        state = trainer.state
+        adam = _adam_state(state.opt_state)
+        count = int(adam.count)
+        before = {"p": probe(state.params), "m": probe(adam.mu), "v": probe(adam.nu)}
+        want_loss, want_logits, norm, grads = jax.jit(reference)(named(state.params), jnp.asarray(seq))
+        norm = float(norm)
+        t = lap("reference_s", t)
+        got_loss, got_logits = jax.jit(program)(state.params, batch["tokens"])
+        logits_rel = float(jax.jit(
+            lambda got, want: jnp.sqrt(jnp.sum((got - want) ** 2) / jnp.sum(want * want))
+        )(got_logits, want_logits))
+        del got_logits, want_logits
+        t = lap("program_forward_s", t)
+        # one step of the program, as Trainer.fit dispatches it (the state is donated)
+        trainer.state = state = trainer._train_step(state, batch)
+        adam = _adam_state(state.opt_state)
+        after = {"p": probe(state.params), "m": probe(adam.mu), "v": probe(adam.nu)}
+        jax.block_until_ready(after)
+        t = lap("program_step_s", t)
+    got_loss, want_loss = float(got_loss), float(want_loss)
+    lr = adamw.warmup_cosine_lr(
+        count, peak=float(traffic["lr"]), warmup_steps=int(traffic["warmup_steps"]),
+        decay_steps=int(traffic["decay_steps"]),
+    )
+    scale = adamw.clip_scale(norm, float(traffic["grad_clip"]))
+    b1, b2 = float(opt["b1"]), float(opt["b2"])
+    before, after, grads = (
+        jax.tree.map(lambda x: np.asarray(x, np.float64), tree) for tree in (before, after, grads)
+    )
+    worst = {"grad_rel": 0.0, "moment2_rel": 0.0, "update_rel": 0.0}
+    for name, g in grads.items():
+        g = scale * g
+        p0, m0, v0 = (before[k][name] for k in "pmv")
+        p1, m1, v1 = (after[k][name] for k in "pmv")
+        want_p, _, want_v = adamw.adamw_step(p0, m0, v0, g, count=count, lr=lr, **opt)
+        errs = {
+            # the gradient the program used, read back from its first moment
+            "grad_rel": _rel((m1 - b1 * m0) / (1.0 - b1), g),
+            "moment2_rel": _rel(v1 - b2 * v0, want_v - b2 * v0),
+            "update_rel": _rel(p1 - p0, want_p - p0),
+        }
+        worst = {k: max(worst[k], errs[k]) for k in worst}
+    lap("compare_s", t)
+    found = {"loss_rel": abs(got_loss - want_loss) / abs(want_loss), "logits_rel_rms": logits_rel, **worst}
+    ok = bool(all(math.isfinite(v) and v <= float(tol[k]) for k, v in found.items()))
+    return ok, {
+        **found, "tolerance": {k: tol[k] for k in found}, "program_loss": got_loss,
+        "reference_loss": want_loss, "reference_grad_norm": norm, "clip_scale": scale,
+        "updates_before": count, "lr": lr, "seconds": took, "ok": ok,
+    }
