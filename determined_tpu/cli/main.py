@@ -1092,6 +1092,7 @@ def serve_cmd(args) -> int:
 
     from determined_tpu.experiment import PREEMPTED_EXIT_CODE
     from determined_tpu.serve import ServeConfig, ServeEngine, ServeWorker
+    from determined_tpu.serve.tracing import finish_tracing, start_tracing
 
     try:
         serve_cfg = ServeConfig(
@@ -1105,6 +1106,7 @@ def serve_cmd(args) -> int:
             decode_chunk_blocks=args.decode_chunk_blocks,
             host=args.host,
             port=args.port,
+            trace_dir=args.trace_dir,
         )
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
@@ -1146,6 +1148,8 @@ def serve_cmd(args) -> int:
               file=sys.stderr)
         return 2
     print(f"loading checkpoint {checkpoint} ...", flush=True)
+    # the tracer is this process's to own: off, or shipped into trace_dir
+    start_tracing(serve_cfg.trace_dir)
     engine = ServeEngine.from_checkpoint(checkpoint, serve_cfg)
     # listing label precedence: explicit --model-name, then the registry
     # ref (name@vN), then the trial class name for raw-path launches
@@ -1191,6 +1195,7 @@ def serve_cmd(args) -> int:
               flush=True)
         return PREEMPTED_EXIT_CODE
     finally:
+        finish_tracing(serve_cfg.trace_dir)
         for sig, handler in prev.items():
             _signal.signal(sig, handler)
 
@@ -1844,6 +1849,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "full-table gather; must divide the table width)")
     sv.add_argument("--model-name", default=None,
                     help="label shown in the master's replica listing")
+    sv.add_argument("--trace-dir", default=None,
+                    help="record the replica's serve.* spans: events.jsonl "
+                         "there while it runs, trace.json once drained "
+                         "(default: tracer off; docs/serving.md)")
     sv.set_defaults(fn=serve_cmd)
 
     ln = sub.add_parser(

@@ -20,7 +20,8 @@ task ready, and then polls for drain:
   version          registry version number
   checkpoint_uuid  the version's checkpoint uuid (label only)
   storage_path     checkpoint directory to load
-  serve            optional ServeConfig overrides (``ServeConfig.from_dict``)
+  serve            optional ServeConfig overrides (``ServeConfig.from_dict``;
+                   ``trace_dir`` there turns the replica's tracer on)
   env              optional {name: value} environment overrides, applied
                    before anything else — the chaos hook (an injected
                    ``DTPU_SERVE_ERROR_RATE`` manufactures 5xxs on a canary
@@ -117,6 +118,7 @@ def main() -> int:
 
     from determined_tpu.api.session import Session
     from determined_tpu.serve import ServeConfig, ServeEngine, ServeWorker
+    from determined_tpu.serve.tracing import finish_tracing, start_tracing
 
     try:
         serve_cfg = ServeConfig.from_dict(
@@ -131,6 +133,8 @@ def main() -> int:
         return 2
 
     print(f"serve replica: loading {model}@v{version} from {storage}", flush=True)
+    # off unless the task's serve config names a trace_dir
+    start_tracing(serve_cfg.trace_dir)
     try:
         engine = ServeEngine.from_checkpoint(storage, serve_cfg)
     except Exception as e:  # noqa: BLE001 - any load failure is a crash-loop input
@@ -207,6 +211,7 @@ def main() -> int:
               flush=True)
         return DRAIN_EXIT_CODE
     finally:
+        finish_tracing(serve_cfg.trace_dir)
         for sig, handler in prev.items():
             signal.signal(sig, handler)
 

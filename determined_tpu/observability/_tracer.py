@@ -419,6 +419,12 @@ class Tracer:
             self._shipper.start()
         return self
 
+    @property
+    def shipping(self) -> bool:
+        """True while the background shipper runs (someone called start())."""
+        with self._lock:
+            return self._shipper is not None
+
     def stop(self) -> None:
         """Stop the shipper and perform a final drain.  Idempotent."""
         with self._lock:

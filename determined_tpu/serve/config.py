@@ -51,6 +51,11 @@ class ServeConfig:
     heartbeat_interval_s: float = 2.0
     #: how long a SIGTERM drain waits for in-flight work before giving up
     drain_grace_s: float = 30.0
+    # ---- observability ---------------------------------------------------
+    #: where ``dtpu serve`` / ``exec/serve_replica`` write the replica's
+    #: span timeline (``events.jsonl`` while it runs, ``trace.json`` once
+    #: drained); None leaves the process tracer off (``serve/tracing.py``)
+    trace_dir: Optional[str] = None
 
     def __post_init__(self) -> None:
         if self.block_size < 1:

@@ -16,15 +16,24 @@ prompt padding, block-table width), so a mixed stream of request lengths
 compiles exactly once per kernel — enforced by wrapping the pre-jit
 callables in the PR-4 RetraceSentinel (``lint/_runtime.py``), the same
 compile-count guard the Trainer runs under.
+
+The engine times itself.  One set of ``time.monotonic()`` stamps — a
+handful a step, one a token — is taken always and feeds two sinks: the
+cumulative ``step_seconds`` and the window of recent requests' latencies
+that ``stats()`` (``/stats``, the heartbeat) reports, and, only while the
+process tracer is enabled, the ``serve.*`` spans (``docs/serving.md``
+"Observability" names each with what reads it).  Request spans carry
+``request=<id>``, step spans ``step=<n>``.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -45,6 +54,40 @@ from determined_tpu.serve.scheduler import (
 )
 
 logger = logging.getLogger("determined_tpu.serve")
+
+mono = time.monotonic
+
+#: finished requests whose latencies ``/stats`` summarizes
+LATENCY_WINDOW = 512
+
+
+def _percentile(ordered: List[float], q: float) -> float:
+    """``q``-th percentile (0..100) of a sorted list, linear between ranks."""
+    k = (len(ordered) - 1) * q / 100.0
+    lo = int(k)
+    hi = min(lo + 1, len(ordered) - 1)
+    return ordered[lo] + (ordered[hi] - ordered[lo]) * (k - lo)
+
+
+def _summary_ms(seconds: List[float]) -> Dict[str, Any]:
+    if not seconds:
+        return {"p50": None, "p90": None, "n": 0}
+    ordered = sorted(seconds)
+    return {
+        "p50": round(1000.0 * _percentile(ordered, 50), 3),
+        "p90": round(1000.0 * _percentile(ordered, 90), 3),
+        "n": len(ordered),
+    }
+
+
+def _ms(seconds: Optional[float]) -> Optional[float]:
+    return None if seconds is None else round(1000.0 * seconds, 3)
+
+
+def _named(fn: Any, name: str) -> Any:
+    """The name the jitted program carries in a device trace (``jit_<name>``)."""
+    fn.__name__ = name
+    return fn
 
 
 def sample_token(logits: np.ndarray, temperature: float, rng: Any) -> int:
@@ -90,6 +133,8 @@ class DecodeKernels:
         )
 
         _check_decodable(model_cfg)
+        tracer = get_tracer()
+        t_setup = mono()
         # a relaunched replica loads its three kernels from disk; keeps the
         # directory ``train.init`` applied when the engine came from a
         # checkpoint (``from_checkpoint``)
@@ -98,10 +143,28 @@ class DecodeKernels:
             params = params["params"]
         self.model_cfg = model_cfg
         self.serve_cfg = serve_cfg
-        self.params = jax.device_put(params)
-        self.cache = init_kv_cache(
-            model_cfg, serve_cfg.num_blocks, serve_cfg.block_size
+        # both are waited for so that each span holds its own transfer; the
+        # first kernel call would have waited for them anyway
+        t_params = mono()
+        self.params = jax.block_until_ready(jax.device_put(params))
+        t_pool = mono()
+        self.cache = jax.block_until_ready(
+            init_kv_cache(model_cfg, serve_cfg.num_blocks, serve_cfg.block_size)
         )
+        t_pooled = mono()
+        param_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(self.params))
+        pool_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(self.cache))
+        tracer.record_span(
+            "serve.setup.params_to_device", "serve", t_params, t_pool,
+            {"bytes": param_bytes},
+        )
+        tracer.record_span(
+            "serve.setup.kv_pool", "serve", t_pool, t_pooled, {"bytes": pool_bytes}
+        )
+        #: (call, jitted call returned, logits ready, logits on the host) of
+        #: the newest ``decode``: the engine, which knows the step, turns
+        #: them into the ``serve.decode.*`` spans
+        self.last_decode_stamps: Optional[Tuple[float, float, float, float]] = None
         #: suffix-prefill token width: the prompt padded up to whole blocks
         #: so the chunked walk slices full blocks only (one trace)
         self._suffix_pad = (
@@ -131,14 +194,20 @@ class DecodeKernels:
             allowed=1,
         )
         self._prefill = timed_first_call(
-            jax.jit(prefill, donate_argnums=(4,)), "jit.compile.serve.prefill"
+            jax.jit(_named(prefill, "serve_prefill"), donate_argnums=(4,)),
+            "jit.compile.serve.prefill",
         )
         self._prefill_suffix = timed_first_call(
-            jax.jit(prefill_suffix, donate_argnums=(5,)),
+            jax.jit(_named(prefill_suffix, "serve_prefill_suffix"), donate_argnums=(5,)),
             "jit.compile.serve.prefill_suffix",
         )
         self._decode = timed_first_call(
-            jax.jit(decode, donate_argnums=(4,)), "jit.compile.serve.decode"
+            jax.jit(_named(decode, "serve_decode"), donate_argnums=(4,)),
+            "jit.compile.serve.decode",
+        )
+        tracer.record_span(
+            "serve.setup", "serve", t_setup, mono(),
+            {"param_bytes": param_bytes, "kv_pool_bytes": pool_bytes},
         )
 
     # -- kernel entry points (device round trips happen HERE) ---------------
@@ -175,11 +244,25 @@ class DecodeKernels:
     def decode(
         self, tokens: np.ndarray, positions: np.ndarray, tables: np.ndarray
     ) -> np.ndarray:
-        """One decode step over every lane; returns f32 logits [B, vocab]."""
+        """One decode step over every lane; returns f32 logits [B, vocab].
+
+        The call is stamped where its three parts end — the jitted call
+        returns (enqueued), the logits are ready on the device, they are
+        on the host — and the stamps left in ``last_decode_stamps``.  The
+        copy is queued behind the program at once, as a bare ``np.asarray``
+        has it: waiting for the logits first and only then asking for them
+        cost a host round trip, ~0.5 ms a step on the chip."""
+        t0 = mono()
         logits, self.cache = self._decode(
             self.params, tokens, positions, tables, self.cache
         )
-        return np.asarray(logits)
+        logits.copy_to_host_async()
+        t1 = mono()
+        logits.block_until_ready()
+        t2 = mono()
+        out = np.asarray(logits)
+        self.last_decode_stamps = (t0, t1, t2, mono())
+        return out
 
 
 class _EngineBase:
@@ -217,6 +300,22 @@ class _EngineBase:
         #: catches handler-level failures the engine never sees
         self._http_5xx = 0
         self._latency_ms_total = 0.0
+        #: (ttft_s, tpot_s, queue_wait_s) of the newest finished requests;
+        #: appended at retire, summarized by stats() on the caller's thread
+        self._recent: "collections.deque[Tuple[Optional[float], ...]]" = (
+            collections.deque(maxlen=LATENCY_WINDOW)
+        )
+        #: (``_completed`` when summarized, the summary): stats()'s cache
+        self._latency_summary: Tuple[int, Dict[str, Any]] = (-1, {})
+        #: cumulative seconds of the parts of a step, and the steps counted
+        self._step_seconds = {
+            "decode_wait": 0.0, "d2h": 0.0, "sample": 0.0, "admission": 0.0,
+        }
+        #: the engine's own step counter: decode steps (and, for the
+        #: continuous engine, admit-only iterations) that did work
+        self._steps = 0
+        #: true while THIS engine runs the tracer's shipper (it started it)
+        self._owns_shipper = False
         self._started_at = time.monotonic()
 
     # -- admission (HTTP threads) -------------------------------------------
@@ -233,43 +332,41 @@ class _EngineBase:
         """Admit one request or raise :class:`AdmissionRejected` — 413 for
         requests no drained replica could ever serve, 429 under queue
         backpressure, 503 while draining."""
-        with self._tracer.span("serve.admit", cat="serve"):
-            if not prompt:
-                raise AdmissionRejected(400, "empty prompt")
-            if len(prompt) > self.cfg.max_prompt_len:
-                raise AdmissionRejected(
-                    413,
-                    f"prompt of {len(prompt)} tokens exceeds max_prompt_len="
-                    f"{self.cfg.max_prompt_len}",
-                )
-            new = (
-                self.cfg.max_new_tokens
-                if max_new_tokens is None
-                else min(int(max_new_tokens), self.cfg.max_new_tokens)
+        if not prompt:
+            raise AdmissionRejected(400, "empty prompt")
+        if len(prompt) > self.cfg.max_prompt_len:
+            raise AdmissionRejected(
+                413,
+                f"prompt of {len(prompt)} tokens exceeds max_prompt_len="
+                f"{self.cfg.max_prompt_len}",
             )
-            if new < 1:  # 0 is a client error, not "use the default"
-                raise AdmissionRejected(400, "max_new_tokens must be >= 1")
-            if self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
-                # permanent: this request can NEVER fit this replica's cache
-                raise AdmissionRejected(
-                    413, "request exceeds kv cache capacity (kv_cache_oom)"
-                )
-            req = GenRequest(
-                prompt=list(prompt),
-                max_new_tokens=new,
-                temperature=float(temperature),
-                seed=seed,
-                stop_token=stop_token,
+        new = (
+            self.cfg.max_new_tokens
+            if max_new_tokens is None
+            else min(int(max_new_tokens), self.cfg.max_new_tokens)
+        )
+        if new < 1:  # 0 is a client error, not "use the default"
+            raise AdmissionRejected(400, "max_new_tokens must be >= 1")
+        if self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
+            # permanent: this request can NEVER fit this replica's cache
+            raise AdmissionRejected(
+                413, "request exceeds kv cache capacity (kv_cache_oom)"
             )
-            try:
-                self.queue.submit(req)
-            except AdmissionRejected:
-                with self._stats_lock:
-                    self._rejected += 1
-                raise
+        req = GenRequest(
+            prompt=list(prompt),
+            max_new_tokens=new,
+            temperature=float(temperature),
+            seed=seed,
+            stop_token=stop_token,
+        )
+        try:
+            self.queue.submit(req)
+        except AdmissionRejected:
+            with self._stats_lock:
+                self._rejected += 1
+            raise
         with self._stats_lock:
             self._submitted += 1
-        self._tracer.gauge("serve.queue_depth", float(self.queue.depth()))
         self._wake.set()
         return req
 
@@ -284,8 +381,20 @@ class _EngineBase:
 
     def start(self) -> "_EngineBase":
         if not self._thread.is_alive() and not self._finished.is_set():
+            if self._tracer.enabled and not self._tracer.shipping:
+                # a step writes half a dozen spans: without the shipper the
+                # engine thread's ring fills within minutes and every later
+                # event is dropped.  An entry point that runs the shipper
+                # itself (dtpu serve with trace_dir, a trial) keeps it.
+                self._tracer.start()
+                self._owns_shipper = True
             self._thread.start()
         return self
+
+    def _release_shipper(self) -> None:
+        if self._owns_shipper:
+            self._owns_shipper = False
+            self._tracer.stop()  # joins the shipper, then drains once more
 
     @property
     def healthy(self) -> bool:
@@ -327,6 +436,7 @@ class _EngineBase:
         if self._thread.is_alive():
             self.stop()
             return False
+        self._release_shipper()
         return True
 
     def stop(self) -> None:
@@ -336,6 +446,7 @@ class _EngineBase:
         if self._thread.is_alive():
             self._thread.join(timeout=10.0)
         self._fail_outstanding("engine stopped")
+        self._release_shipper()
 
     def _finish_error(self, req: GenRequest, reason: str) -> None:
         """Fail one request AND count it: every error-finish goes through
@@ -343,6 +454,7 @@ class _EngineBase:
         req.finish(error=reason)
         with self._stats_lock:
             self._errored += 1
+        self._record_request(req)
 
     def note_http_response(self, status: int) -> None:
         """HTTP layer callback: count 5xx responses (handler failures the
@@ -350,6 +462,24 @@ class _EngineBase:
         if status >= 500:
             with self._stats_lock:
                 self._http_5xx += 1
+
+    def _record_request(self, req: GenRequest) -> None:
+        """One ``serve.request`` span a finished request, arrival to finish:
+        the line an operator's export holds for it."""
+        if self._tracer.enabled:
+            self._tracer.record_span(
+                "serve.request", "serve", req.arrival, req.finished_at,
+                {
+                    "request": req.id,
+                    "prompt_tokens": len(req.prompt),
+                    "output_tokens": len(req.output),
+                    "queue_wait_ms": _ms(req.queue_wait_s),
+                    "ttft_ms": _ms(req.ttft_s),
+                    "tpot_ms": _ms(req.tpot_s),
+                    "itl_max_ms": _ms(req.itl_max_s),
+                    "error": req.error,
+                },
+            )
 
     def _fail_outstanding(self, reason: str) -> None:
         while True:
@@ -375,9 +505,32 @@ class _EngineBase:
                 if self._completed
                 else 0.0,
             }
+            # summarized again only after another request finished: the
+            # sorts cost ~0.15 ms, and a caller may poll every step
+            at, latency = self._latency_summary
+            completed = self._completed
+            recent = list(self._recent) if at != completed else None
+            step_seconds = {
+                **{k: round(v, 6) for k, v in self._step_seconds.items()},
+                "steps": self._steps,
+            }
+        if recent is not None:
+            latency = {
+                name: _summary_ms([r[i] for r in recent if r[i] is not None])
+                for i, name in enumerate(("ttft_ms", "tpot_ms", "queue_wait_ms"))
+            }
+            with self._stats_lock:
+                self._latency_summary = (completed, latency)
         kv = self.allocator.stats()
         return {
             **counters,
+            # what a caller feels, over the newest LATENCY_WINDOW finished
+            # requests (tpot: those with two tokens or more)
+            "latency": {name: dict(v) for name, v in latency.items()},
+            # where the engine thread's time went, cumulative since start:
+            # waiting for the decode program, copying its logits to the
+            # host, sampling, admitting (prefill and first sample)
+            "step_seconds": step_seconds,
             "queue_depth": self.queue.depth(),
             # static queue bound: the router's saturation signal — at
             # queue_depth >= queue_capacity the next submit would 429
@@ -404,7 +557,7 @@ class _EngineBase:
     def _padded_table(self, blocks: List[int]) -> List[int]:
         return blocks + [0] * (self.cfg.blocks_per_seq - len(blocks))
 
-    def _start_sequence(self, req: GenRequest) -> Optional[ActiveSeq]:
+    def _start_sequence(self, req: GenRequest, step: int) -> Optional[ActiveSeq]:
         """Allocate + prefill + sample the first token.  Returns the live
         sequence, or None when the request finished at prefill (wanted a
         single token).  Raises CacheOOM without side effects.
@@ -417,7 +570,15 @@ class _EngineBase:
         blocks into this sequence's table with a reference each, and
         prefills only the un-cached suffix.  Afterwards every full prompt
         block is registered as cached content for future admissions.
+
+        ``step`` is the engine step this admission belongs to.  The stamps
+        taken here are the request's own (``admitted_at``, the first
+        ``token_at``) and the edges of its ``serve.queue_wait`` and
+        ``serve.admission`` spans: the wait ends where the admission
+        starts, the admission where the first token is out.
         """
+        tracer = self._tracer
+        t_admit = mono()  # just off the queue
         total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens)
         shared: List[int] = []
         cached_tokens = 0
@@ -430,18 +591,24 @@ class _EngineBase:
             cached_tokens = len(shared) * self.cfg.block_size
         needed = total - len(shared)
         try:
-            with self._tracer.span("serve.kv_alloc", cat="serve", blocks=needed):
+            with tracer.span(
+                "serve.kv_alloc", cat="serve", request=req.id, step=step, blocks=needed
+            ):
                 private = self.allocator.alloc(needed)
         except CacheOOM:
             if shared:
                 self.allocator.free(shared)
             raise
+        # this attempt holds its blocks: the wait in the queue is over
+        req.admitted_at = t_admit
+        tracer.record_span(
+            "serve.queue_wait", "serve", req.arrival, t_admit, {"request": req.id}
+        )
         blocks = shared + private
-        self._tracer.gauge("serve.kv_utilization", self.allocator.utilization())
         table = self._padded_table(blocks)
         try:
-            with self._tracer.span(
-                "serve.prefill", cat="serve", request=req.id,
+            with tracer.span(
+                "serve.prefill", cat="serve", request=req.id, step=step,
                 cached_tokens=cached_tokens,
             ):
                 if cached_tokens:
@@ -462,11 +629,26 @@ class _EngineBase:
             # already in the trie — first writer wins)
             self.allocator.register_prefix(chain, blocks[: len(chain)])
         rng = np.random.default_rng(req.seed)
+        t_sample = mono()
         tok = sample_token(logits, req.temperature, rng)
-        req.first_token_at = time.monotonic()
+        t_first = mono()
+        req.first_token_at = t_first
         req.output.append(tok)
+        req.token_at.append(t_first)
         with self._stats_lock:
             self._tokens_generated += 1
+            self._step_seconds["admission"] += t_first - t_admit
+        if tracer.enabled:
+            tracer.record_span(
+                "serve.first_sample", "serve", t_sample, t_first, {"request": req.id}
+            )
+            tracer.record_span(
+                "serve.admission", "serve", t_admit, t_first,
+                {
+                    "request": req.id, "step": step,
+                    "prompt_tokens": len(req.prompt), "cached_tokens": cached_tokens,
+                },
+            )
         seq = ActiveSeq(
             request=req,
             blocks=blocks,
@@ -488,16 +670,23 @@ class _EngineBase:
 
     def _retire_seq(self, seq: ActiveSeq) -> None:
         self.allocator.free(seq.blocks)
-        self._tracer.gauge("serve.kv_utilization", self.allocator.utilization())
-        seq.request.finish()
-        latency = seq.request.latency_s
+        req = seq.request
+        req.finish()
+        latency = req.latency_s
         with self._stats_lock:
             self._completed += 1
             if latency is not None:
                 self._latency_ms_total += latency * 1000.0
+            self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s))
+        self._record_request(req)
 
-    def _decode_batch(self, lanes: List[Optional[ActiveSeq]]) -> np.ndarray:
-        """One jitted decode step over the full (static) lane table."""
+    def _decode_batch(
+        self, lanes: List[Optional[ActiveSeq]], step: int
+    ) -> Tuple[np.ndarray, float, float]:
+        """One jitted decode step over the full (static) lane table.
+        Returns the logits and the seconds the call waited for the device
+        and copied the logits to the host (0.0 where the kernels left no
+        stamps)."""
         b = self.cfg.max_batch
         t = self.cfg.blocks_per_seq
         tokens = np.zeros(b, np.int32)
@@ -511,19 +700,74 @@ class _EngineBase:
             positions[i] = seq.pos
             tables[i] = seq.block_table
             n_active += 1
-        with self._tracer.span("serve.decode", cat="serve", active=n_active):
-            logits = self.kernels.decode(tokens, positions, tables)
-        return logits
+        t0 = mono()
+        logits = self.kernels.decode(tokens, positions, tables)
+        t1 = mono()
+        # the kernels stamp the parts of their own call (whatever wraps
+        # ``kernels.decode`` from outside); a stand-in that leaves no stamps,
+        # or stale ones, leaves the step with its whole ``serve.decode`` only
+        stamps = getattr(self.kernels, "last_decode_stamps", None)
+        if stamps is not None and stamps[0] < t0:
+            stamps = None
+        tracer = self._tracer
+        if tracer.enabled:
+            tracer.record_span(
+                "serve.decode", "serve", t0, t1, {"step": step, "active": n_active}
+            )
+            if stamps is not None:
+                at = {"step": step}
+                for name, lo, hi in zip(("dispatch", "wait", "d2h"), stamps, stamps[1:]):
+                    tracer.record_span("serve.decode." + name, "serve", lo, hi, at)
+        if stamps is None:
+            return logits, 0.0, 0.0
+        return logits, stamps[2] - stamps[1], stamps[3] - stamps[2]
 
     def _advance_lane(self, seq: ActiveSeq, logits_row: np.ndarray) -> bool:
         """Sample the next token for one lane; True when the seq finished."""
         tok = sample_token(logits_row, seq.request.temperature, seq.rng)
         seq.request.output.append(tok)
+        seq.request.token_at.append(mono())
         seq.pos += 1
         seq.next_token = tok
         with self._stats_lock:
             self._tokens_generated += 1
         return self._sequence_finished(seq, tok)
+
+    def _decode_and_sample(
+        self,
+        lanes: List[Optional[ActiveSeq]],
+        step: int,
+        on_finished: Callable[[int, ActiveSeq], None],
+    ) -> int:
+        """One decode step over the lane table, then one sampled token for
+        every live lane, in lane order; a sequence that finished is handed
+        to ``on_finished`` at once (its response must not wait for the
+        other lanes' sampling).  Returns how many finished.
+
+        ``serve.sample`` runs from before the first lane's sample to the
+        last lane's token stamp: first ``sample_token`` call to last."""
+        logits, wait_s, d2h_s = self._decode_batch(lanes, step)
+        finished = live = 0
+        t0 = t1 = mono()
+        for i, seq in enumerate(lanes):
+            if seq is None:
+                continue
+            live += 1
+            done = self._advance_lane(seq, logits[i])
+            t1 = seq.request.token_at[-1]
+            if done:
+                on_finished(i, seq)
+                finished += 1
+        with self._stats_lock:
+            seconds = self._step_seconds
+            seconds["decode_wait"] += wait_s
+            seconds["d2h"] += d2h_s
+            seconds["sample"] += t1 - t0
+            self._steps = step
+        self._tracer.record_span(
+            "serve.sample", "serve", t0, t1, {"step": step, "lanes": live}
+        )
+        return finished
 
     def _run(self) -> None:  # pragma: no cover - subclasses implement
         raise NotImplementedError
@@ -573,7 +817,7 @@ class ServeEngine(_EngineBase):
         engine.model_label = type(trial).__name__  # e.g. "LMTrial"
         return engine
 
-    def _admit_one(self) -> bool:
+    def _admit_one(self, step: int) -> bool:
         """Try to move one queued request into a lane.  False when nothing
         was admitted (empty queue, or the head request must wait for cache
         blocks — it is parked at the front so FIFO order holds)."""
@@ -581,7 +825,7 @@ class ServeEngine(_EngineBase):
         if req is None:
             return False
         try:
-            seq = self._start_sequence(req)
+            seq = self._start_sequence(req, step)
         except CacheOOM:
             self.queue.requeue_head(req)
             return False
@@ -591,28 +835,46 @@ class ServeEngine(_EngineBase):
             return True
         if seq is not None:
             self.lanes.join(seq)
-        self._tracer.gauge("serve.queue_depth", float(self.queue.depth()))
         return True
+
+    def _retire_lane(self, lane: int, seq: ActiveSeq) -> None:
+        self.lanes.retire(lane)
+        self._retire_seq(seq)
 
     def step_once(self) -> bool:
         """One scheduler iteration: admit whatever fits, run one decode
         step, retire what finished.  Returns True when any work happened.
         The engine thread loops this; tests drive it directly for
         deterministic join/retire assertions (no wall-clock races)."""
-        worked = False
+        t0 = mono()
+        step = self._steps + 1  # this iteration's number, kept if it works
+        admitted = 0
         while self.lanes.has_free_lane() and not self._stop.is_set():
-            if not self._admit_one():
+            if not self._admit_one(step):
                 break
-            worked = True
-        snapshot = self.lanes.snapshot()
-        if any(seq is not None for seq in snapshot):
-            logits = self._decode_batch(list(snapshot))
-            for i, seq in enumerate(snapshot):
-                if seq is not None and self._advance_lane(seq, logits[i]):
-                    self.lanes.retire(i)
-                    self._retire_seq(seq)
-            worked = True
-        return worked
+            admitted += 1
+        snapshot = list(self.lanes.snapshot())
+        active = sum(1 for seq in snapshot if seq is not None)
+        retired = 0
+        if active:
+            retired = self._decode_and_sample(snapshot, step, self._retire_lane)
+        elif admitted:
+            with self._stats_lock:
+                self._steps = step  # admitted, and all finished at prefill
+        else:
+            return False
+        if self._tracer.enabled:
+            # the step's own line: what it did, and the queue and the pool
+            # as it left them (the two gauges nobody read rode here)
+            self._tracer.record_span(
+                "serve.step", "serve", t0, mono(),
+                {
+                    "step": step, "active": active, "admitted": admitted,
+                    "retired": retired, "queued": self.queue.depth(),
+                    "kv_used_blocks": self.allocator.used_blocks,
+                },
+            )
+        return True
 
     def _run(self) -> None:
         while not self._stop.is_set():
@@ -663,7 +925,7 @@ class StaticBatchEngine(_EngineBase):
             if req is None:
                 break
             try:
-                seq = self._start_sequence(req)
+                seq = self._start_sequence(req, self._steps + 1)
             except CacheOOM:
                 self.queue.requeue_head(req)
                 break
@@ -686,19 +948,19 @@ class StaticBatchEngine(_EngineBase):
             lanes: List[Optional[ActiveSeq]] = list(batch)
             lanes += [None] * (self.cfg.max_batch - len(lanes))
             live = [seq is not None for seq in lanes]
+            def respond(i: int, seq: ActiveSeq) -> None:
+                # the RESPONSE completes now, but the lane stays occupied
+                # until the whole batch drains — that gap is exactly what
+                # continuous batching removes
+                live[i] = False
+                self._retire_seq(seq)
+
             while any(live) and not self._stop.is_set():
-                logits = self._decode_batch(
-                    [seq if live[i] else None for i, seq in enumerate(lanes)]
+                self._decode_and_sample(
+                    [seq if live[i] else None for i, seq in enumerate(lanes)],
+                    self._steps + 1,
+                    respond,
                 )
-                for i, seq in enumerate(lanes):
-                    if seq is None or not live[i]:
-                        continue
-                    if self._advance_lane(seq, logits[i]):
-                        # the RESPONSE completes now, but the lane stays
-                        # occupied until the whole batch drains — that gap
-                        # is exactly what continuous batching removes
-                        live[i] = False
-                        self._retire_seq(seq)
             if self._stop.is_set():
                 for i, seq in enumerate(lanes):
                     if seq is not None and live[i]:

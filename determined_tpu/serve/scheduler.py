@@ -47,14 +47,40 @@ class GenRequest:
     # -- results (engine-written) -------------------------------------------
     output: List[int] = dataclasses.field(default_factory=list)
     error: Optional[str] = None
+    #: taken off the queue for the admission attempt that succeeded (one
+    #: requeued for want of cache blocks keeps waiting until then)
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None  # monotonic, for TTFT
+    #: one monotonic stamp per emitted token, appended with ``output``
+    token_at: List[float] = dataclasses.field(default_factory=list)
     finished_at: Optional[float] = None
+
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        if self.admitted_at is None:
+            return None
+        return self.admitted_at - self.arrival
 
     @property
     def ttft_s(self) -> Optional[float]:
         if self.first_token_at is None:
             return None
         return self.first_token_at - self.arrival
+
+    @property
+    def tpot_s(self) -> Optional[float]:
+        """Mean time per output token after the first; None under two tokens."""
+        if self.finished_at is None or self.first_token_at is None or len(self.output) < 2:
+            return None
+        return (self.finished_at - self.first_token_at) / (len(self.output) - 1)
+
+    @property
+    def itl_max_s(self) -> Optional[float]:
+        """Largest gap between two consecutive tokens: the stall an
+        admission's prefill (or anything else) put into this request."""
+        if len(self.token_at) < 2:
+            return None
+        return max(b - a for a, b in zip(self.token_at, self.token_at[1:]))
 
     @property
     def latency_s(self) -> Optional[float]:

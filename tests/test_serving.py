@@ -671,15 +671,40 @@ def test_retrace_sentinel_one_decode_trace(lm_setup):
     sentinel.reset()
 
 
-def test_serve_spans_reach_tracer(lm_setup):
-    """serve.admit/prefill/decode/kv_alloc spans + queue/kv gauges land in
-    the process tracer (the profile CLI's input)."""
+@pytest.fixture()
+def tracer():
+    """The process tracer, empty and on; left as a fresh process has it."""
+    from determined_tpu.observability import _tracer as tracer_mod
     from determined_tpu.observability import get_tracer
 
+    t = get_tracer()
+    t.reset()
+    t.configure(enabled=True)
+    yield t
+    t.close()  # stops a shipper a test left running, closes an export
+    t.configure(enabled=True, flush_interval=tracer_mod.DEFAULT_FLUSH_INTERVAL)
+    t.reset()
+
+
+def _spans(tracer, name=None):
+    return [
+        e for e in tracer.chrome_events()
+        if e.get("ph") == "X" and (name is None or e["name"] == name)
+    ]
+
+
+def _inside(inner, outer, slack_us=0.2):
+    """Both ends of ``inner`` within ``outer`` (events round to 0.1 us)."""
+    return (
+        inner["ts"] >= outer["ts"] - slack_us
+        and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + slack_us
+    )
+
+
+def test_serve_spans_reach_tracer(lm_setup, tracer):
+    """The engine's own spans (docs/serving.md "Observability") land in the
+    process tracer; the span and the two gauges nothing read are gone."""
     cfg, _model, variables = lm_setup
-    tracer = get_tracer()
-    tracer.reset()
-    tracer.configure(enabled=True)
     eng = ServeEngine(DecodeKernels(cfg, variables, SERVE_CFG)).start()
     try:
         req = eng.generate([1, 2, 3], max_new_tokens=3)
@@ -687,10 +712,218 @@ def test_serve_spans_reach_tracer(lm_setup):
     finally:
         eng.stop()
     names = {e["name"] for e in tracer.chrome_events()}
-    for expected in ("serve.admit", "serve.prefill", "serve.decode",
-                     "serve.kv_alloc", "serve.queue_depth",
-                     "serve.kv_utilization"):
+    for expected in (
+        "serve.setup", "serve.setup.params_to_device", "serve.setup.kv_pool",
+        "serve.queue_wait", "serve.step", "serve.admission", "serve.kv_alloc",
+        "serve.prefill", "serve.first_sample", "serve.decode",
+        "serve.decode.dispatch", "serve.decode.wait", "serve.decode.d2h",
+        "serve.sample", "serve.request",
+    ):
         assert expected in names, f"missing {expected} in {sorted(names)}"
+    for gone in ("serve.admit", "serve.queue_depth", "serve.kv_utilization"):
+        assert gone not in names
+    assert {e["cat"] for e in _spans(tracer) if e["name"].startswith("serve.")} == {"serve"}
+    sizes = _spans(tracer, "serve.setup")[0]["args"]
+    assert sizes["param_bytes"] > 0 and sizes["kv_pool_bytes"] > 0
+
+
+def test_request_spans_share_an_id_and_add_up(kernels, tracer):
+    """One request's timeline can be read back by its id, and its parts are
+    the whole: the queue wait ends where the admission starts, the
+    admission where the first token is out, and the rest is decoding."""
+    eng = ServeEngine(kernels).start()
+    try:
+        reqs = [
+            _submit_retry(eng, [5, 6, 7, 8 + i], max_new_tokens=6 + i, seed=i,
+                          temperature=0.5)
+            for i in range(3)
+        ]
+        for r in reqs:
+            assert r.done.wait(60) and r.error is None
+    finally:
+        eng.stop()
+    for r in reqs:
+        mine = {
+            e["name"]: e for e in _spans(tracer)
+            if (e.get("args") or {}).get("request") == r.id
+        }
+        assert set(mine) == {
+            "serve.queue_wait", "serve.admission", "serve.kv_alloc",
+            "serve.prefill", "serve.first_sample", "serve.request",
+        }
+        wait, adm, whole = (
+            mine["serve.queue_wait"], mine["serve.admission"], mine["serve.request"]
+        )
+        args = whole["args"]
+        assert args["output_tokens"] == len(r.output) and args["error"] is None
+        assert args["prompt_tokens"] == 4
+        # the same stamps on both sides of each joint (0.1 us rounding)
+        assert wait["ts"] == pytest.approx(whole["ts"], abs=0.2)
+        assert adm["ts"] == pytest.approx(wait["ts"] + wait["dur"], abs=0.2)
+        for child in ("serve.kv_alloc", "serve.prefill", "serve.first_sample"):
+            assert _inside(mine[child], adm), child
+        decode_ms = args["tpot_ms"] * (args["output_tokens"] - 1)
+        assert (wait["dur"] + adm["dur"]) / 1e3 + decode_ms == pytest.approx(
+            whole["dur"] / 1e3, abs=0.02
+        )
+        assert args["queue_wait_ms"] == pytest.approx(wait["dur"] / 1e3, abs=0.002)
+        assert args["ttft_ms"] == pytest.approx((wait["dur"] + adm["dur"]) / 1e3, abs=0.002)
+        assert args["itl_max_ms"] == pytest.approx(1000.0 * r.itl_max_s, abs=0.002)
+
+
+def test_step_spans_nest_and_carry_their_step(kernels, tracer):
+    """A step's anatomy lies inside its ``serve.step`` and names it."""
+    eng = ServeEngine(kernels)
+    for i in range(3):
+        eng.submit([1 + i, 2, 3], max_new_tokens=4)
+    while eng.step_once():  # no engine thread: deterministic
+        pass
+    steps = {e["args"]["step"]: e for e in _spans(tracer, "serve.step")}
+    assert sorted(steps) == list(range(1, len(steps) + 1))
+    assert eng.stats()["step_seconds"]["steps"] == len(steps)
+    assert sum(s["args"]["admitted"] for s in steps.values()) == 3
+    assert sum(s["args"]["retired"] for s in steps.values()) == 3
+    last = steps[len(steps)]["args"]
+    assert last["queued"] == 0 and last["kv_used_blocks"] == 0
+    seen = set()
+    for e in _spans(tracer):
+        if e["name"] in ("serve.sample", "serve.decode", "serve.decode.dispatch",
+                         "serve.decode.wait", "serve.decode.d2h", "serve.admission",
+                         "serve.prefill", "serve.kv_alloc"):
+            assert _inside(e, steps[e["args"]["step"]]), e
+            seen.add(e["name"])
+    assert len(seen) == 8
+    decodes = {e["args"]["step"]: e for e in _spans(tracer, "serve.decode")}
+    for part in ("dispatch", "wait", "d2h"):
+        for e in _spans(tracer, "serve.decode." + part):
+            assert _inside(e, decodes[e["args"]["step"]])
+    for e in _spans(tracer, "serve.sample"):
+        assert e["args"]["lanes"] == steps[e["args"]["step"]]["args"]["active"]
+
+
+def test_token_stamps_follow_the_output(engine):
+    req = engine.generate([3, 1, 4, 1, 5], max_new_tokens=7, temperature=0.8, seed=3)
+    assert req.error is None
+    assert len(req.token_at) == len(req.output) == 7
+    assert req.token_at == sorted(req.token_at)
+    assert req.arrival <= req.admitted_at <= req.first_token_at == req.token_at[0]
+    assert req.token_at[-1] <= req.finished_at
+    assert req.queue_wait_s == req.admitted_at - req.arrival
+    assert req.tpot_s == (req.finished_at - req.token_at[0]) / 6
+    assert req.itl_max_s == max(b - a for a, b in zip(req.token_at, req.token_at[1:]))
+    one = engine.generate([2, 7], max_new_tokens=1)
+    assert len(one.token_at) == 1 and one.tpot_s is None and one.itl_max_s is None
+
+
+OLD_STATS_KEYS = {
+    "submitted", "completed", "rejected", "tokens_generated", "errored",
+    "http_5xx", "latency_ms_avg", "queue_depth", "queue_capacity", "draining",
+    "failed", "kv_cache", "kv_utilization", "prefix_hits",
+    "prefix_tokens_saved", "prefix_hit_rate", "uptime_s", "lanes",
+}
+
+
+def test_stats_carries_latency_and_step_seconds(kernels, tracer):
+    """/stats gains what an operator lacked, keeps every key it had, and
+    fills whether or not anything is traced."""
+    tracer.configure(enabled=False)
+    eng = ServeEngine(kernels).start()
+    try:
+        empty = eng.stats()
+        assert empty["latency"]["ttft_ms"] == {"p50": None, "p90": None, "n": 0}
+        for i in range(5):
+            assert eng.generate([1, 2, 3 + i], max_new_tokens=1 + i).error is None
+        st = eng.stats()
+    finally:
+        eng.stop()
+    assert OLD_STATS_KEYS <= set(st)
+    lat = st["latency"]
+    assert set(lat) == {"ttft_ms", "tpot_ms", "queue_wait_ms"}
+    assert lat["ttft_ms"]["n"] == lat["queue_wait_ms"]["n"] == 5
+    assert lat["tpot_ms"]["n"] == 4  # the one-token request has no gap
+    for v in lat.values():
+        assert 0 <= v["p50"] <= v["p90"]
+    assert lat["queue_wait_ms"]["p50"] <= lat["ttft_ms"]["p50"]
+    ss = st["step_seconds"]
+    assert set(ss) == {"decode_wait", "d2h", "sample", "admission", "steps"}
+    assert ss["steps"] >= 4 and all(ss[k] > 0 for k in ss)
+    assert ss["decode_wait"] + ss["d2h"] + ss["sample"] + ss["admission"] < st["uptime_s"]
+    # the disabled tracer recorded nothing, and no shipper was started for it
+    assert tracer.stats()["events"] == 0 and not tracer.shipping
+
+
+def test_latency_window_is_bounded(kernels):
+    from determined_tpu.serve import engine as engine_mod
+
+    eng = ServeEngine(kernels)
+    for i in range(engine_mod.LATENCY_WINDOW + 40):
+        eng.submit([1, 2], max_new_tokens=1)
+        assert eng.step_once()
+    st = eng.stats()
+    assert st["completed"] == engine_mod.LATENCY_WINDOW + 40
+    assert st["latency"]["ttft_ms"]["n"] == engine_mod.LATENCY_WINDOW
+
+
+def test_2000_steps_drop_no_event(kernels, tracer):
+    """ServeEngine.start() runs the tracer's shipper: a step's half a dozen
+    spans would otherwise fill the engine thread's 8,192-slot ring within
+    ~1,200 steps and every later event would be dropped."""
+    # a CPU step of this toy model is far shorter than a real one: drain
+    # often enough that the ring holds an interval's events here too
+    tracer.configure(flush_interval=0.02)
+    assert not tracer.shipping
+    eng = ServeEngine(kernels).start()
+    assert tracer.shipping
+    try:
+        pending = []
+        while eng.stats()["step_seconds"]["steps"] < 2000:
+            pending.append(_submit_retry(eng, [1, 2, 3], max_new_tokens=32))
+            pending = [r for r in pending if not r.done.is_set()]
+        for r in pending:
+            assert r.done.wait(60)
+    finally:
+        eng.stop()
+    assert not tracer.shipping  # the engine started it, the engine stopped it
+    st = tracer.stats()
+    assert st["dropped"] == 0
+    assert len(_spans(tracer, "serve.step")) == eng.stats()["step_seconds"]["steps"] >= 2000
+    assert st["events"] > 8192
+
+
+def test_trace_dir_leaves_a_readable_timeline(kernels, tracer, tmp_path):
+    """What ``dtpu serve --trace-dir`` does round the engine's life: off by
+    default, and with a directory ``events.jsonl`` and ``trace.json`` whose
+    ``serve.request`` spans number the requests served."""
+    import json
+
+    from determined_tpu.serve.tracing import finish_tracing, start_tracing
+
+    start_tracing(None)
+    assert not tracer.enabled and not tracer.shipping
+    finish_tracing(None)
+    assert ServeConfig().trace_dir is None
+    out = str(tmp_path / "trace")
+    cfg = ServeConfig.from_dict({"trace_dir": out})
+    start_tracing(cfg.trace_dir)
+    assert tracer.enabled and tracer.shipping
+    eng = ServeEngine(kernels).start()
+    try:
+        for i in range(4):
+            assert eng.generate([9, 8, 7 + i], max_new_tokens=3).error is None
+        assert eng.drain(timeout=30)
+    finally:
+        eng.stop()
+        finish_tracing(cfg.trace_dir)
+    assert not tracer.shipping
+    with open(os.path.join(out, "events.jsonl")) as f:
+        lines = [json.loads(x) for x in f]
+    assert sum(1 for e in lines if e.get("name") == "serve.request") == 4
+    assert lines[0]["name"] == "clock_sync"
+    with open(os.path.join(out, "trace.json")) as f:
+        trace = json.load(f)
+    served = [e for e in trace["traceEvents"] if e.get("name") == "serve.request"]
+    assert len(served) == 4 and trace["otherData"]["dropped_events"] == 0
+    assert all(e["args"]["output_tokens"] == 3 for e in served)
 
 
 # ---------------------------------------------------------------------------
