@@ -1843,10 +1843,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="disable prefix sharing: every request prefills "
                          "private blocks")
     sv.add_argument("--decode-chunk-blocks", type=int, default=1,
-                    help="lazy decode: gather the block table this many "
-                         "columns per attention pass, skipping columns "
-                         "past the longest live sequence (0 = legacy "
-                         "full-table gather; must divide the table width)")
+                    help="paged decode attention: any value above 0 reads "
+                         "each lane's live KV blocks straight from the pool "
+                         "(the tile width is chosen from the shapes); 0 = "
+                         "full-table gather every step (must divide the "
+                         "table width)")
     sv.add_argument("--model-name", default=None,
                     help="label shown in the master's replica listing")
     sv.add_argument("--trace-dir", default=None,

@@ -32,6 +32,7 @@ from determined_tpu.ops.attention import (
     dot_product_attention,
     reference_attention,
 )
+from determined_tpu.ops.paged_attention import paged_decode_attention
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
 from determined_tpu.parallel.sharding import with_sharding_constraint
@@ -511,9 +512,12 @@ def pipeline_forward(
 # autoregressive form: prefill the prompt once, then one-token decode steps
 # reading/writing a **paged** KV cache (vLLM's PagedAttention layout, Kwon
 # et al., SOSP '23).  The cache is a pool of fixed-size blocks
-# ``[n_layers, num_blocks, block_size, kv_heads, head_dim]``; each sequence
-# owns a *block table* mapping its logical block index to a physical block
-# id.  Everything below is a pure function over the UNBOXED param tree that
+# ``[n_layers, num_blocks, block_size, kv_heads * head_dim]`` (a block is one
+# contiguous ``[block_size, kv_heads * head_dim]`` slab: what the decode
+# kernel in ``ops/paged_attention.py`` copies in one DMA and feeds the MXU
+# as it lies); each sequence owns a *block table* mapping its logical block
+# index to a physical block id.  Everything below is a pure function over
+# the UNBOXED param tree that
 # ``TransformerLM.init`` produces (the ``["params"]`` subtree), so the
 # serve engine can jit prefill/decode with static shapes — batch lanes,
 # table width, and prompt padding are fixed by ServeConfig, and the decode
@@ -528,7 +532,7 @@ def pipeline_forward(
 def kv_cache_shape(
     cfg: TransformerConfig, num_blocks: int, block_size: int
 ) -> Tuple[int, ...]:
-    return (cfg.n_layers, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    return (cfg.n_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
 
 
 def init_kv_cache(
@@ -624,8 +628,8 @@ def transformer_prefill(
         q, k, v = _attn_proj(blk["attn"], h, dt)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-        k_cache = k_cache.at[i, phys, slots].set(k.transpose(0, 2, 1, 3))
-        v_cache = v_cache.at[i, phys, slots].set(v.transpose(0, 2, 1, 3))
+        k_cache = k_cache.at[i, phys, slots].set(_pool_rows(k))
+        v_cache = v_cache.at[i, phys, slots].set(_pool_rows(v))
         att = reference_attention(q, k, v, causal=True)
         att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
         x = x + jnp.einsum(
@@ -637,67 +641,22 @@ def transformer_prefill(
     return logits, {"k": k_cache, "v": v_cache}
 
 
-def _paged_attention_chunked(
-    q: jax.Array,
-    k_cache_i: jax.Array,
-    v_cache_i: jax.Array,
-    block_tables: jax.Array,
-    pos: jax.Array,
-    active: jax.Array,
-    n_rep: int,
-    scale: float,
-    chunk_blocks: int,
-    n_chunks: jax.Array,
+def _pool_rows(x: jax.Array) -> jax.Array:
+    """Projected k or v ``[b, kv_heads, s, head_dim]`` as the pool stores a
+    token: ``[b, s, kv_heads * head_dim]``."""
+    b, kv_heads, s, head_dim = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, kv_heads * head_dim)
+
+
+def _gather_table(
+    pool: jax.Array, layer: int, block_tables: jax.Array, kv_heads: int, n_rep: int
 ) -> jax.Array:
-    """Lazy paged attention for one decode step of one layer.
-
-    Instead of gathering the whole block table (``[b, T*block_size, ...]``
-    per layer even when a lane holds 3 tokens), slide a static-width window
-    of ``chunk_blocks`` table columns and fold each chunk into an online
-    softmax (flash-decoding style: running max / denominator / weighted
-    accumulator, all f32).  ``n_chunks`` — ``ceil((max_pos+1)/chunk)`` — is
-    a traced scalar, so the loop lowers to a single ``while`` and the step
-    keeps exactly one trace no matter how long the active lanes are.
-    Masked positions use the same finite ``NEG_INF`` the full path uses;
-    their ``exp`` underflows to zero, so chunked and full attention agree
-    to f32 reassociation error.  Returns ``[b, n_heads, 1, head_dim]``.
-    """
+    """Every token of every table column of one layer, ``[b, n_heads,
+    T * block_size, head_dim]`` with the KV heads repeated: the full-table
+    read of the suffix prefill and of ``chunk_blocks=0`` decode."""
     b, t = block_tables.shape
-    block_size = k_cache_i.shape[1]
-    chunk_tokens = chunk_blocks * block_size
-    n_heads, head_dim = q.shape[1], q.shape[3]
-    kv_heads = k_cache_i.shape[2]
-
-    def body(c, carry):
-        m, l, acc = carry
-        tbl = jax.lax.dynamic_slice(block_tables, (0, c * chunk_blocks), (b, chunk_blocks))
-        keys = k_cache_i[tbl].reshape(b, chunk_tokens, kv_heads, head_dim)
-        vals = v_cache_i[tbl].reshape(b, chunk_tokens, kv_heads, head_dim)
-        keys = _repeat_kv(keys.transpose(0, 2, 1, 3), n_rep)
-        vals = _repeat_kv(vals.transpose(0, 2, 1, 3), n_rep)
-        s = (
-            jnp.einsum("bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32)
-            * scale
-        )  # [b, h, 1, chunk_tokens]
-        k_idx = c * chunk_tokens + jnp.arange(chunk_tokens)
-        msk = (k_idx[None, :] <= pos[:, None]) & active[:, None]  # [b, chunk_tokens]
-        s = jnp.where(msk[:, None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jnp.einsum(
-            "bhqk,bhkd->bhqd", p, vals.astype(jnp.float32)
-        )
-        return m_new, l_new, acc_new
-
-    init = (
-        jnp.full((b, n_heads, 1, 1), NEG_INF, jnp.float32),
-        jnp.zeros((b, n_heads, 1, 1), jnp.float32),
-        jnp.zeros((b, n_heads, 1, head_dim), jnp.float32),
-    )
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, init)
-    return acc / jnp.maximum(l, 1e-30)
+    rows = pool[layer, block_tables].reshape(b, t * pool.shape[2], kv_heads, -1)
+    return _repeat_kv(rows.transpose(0, 2, 1, 3), n_rep)
 
 
 def transformer_decode(
@@ -720,17 +679,19 @@ def transformer_decode(
     continuous batcher joins and retires sequences by editing lane state,
     not by reshaping the batch.
 
-    ``chunk_blocks`` > 0 selects the lazy paged path: gather the table in
-    static windows of that many columns and only run
-    ``ceil((max_pos+1)/(chunk_blocks*block_size))`` attention passes
-    (:func:`_paged_attention_chunked`), instead of materializing the full
-    ``[b, T*block_size, kv_heads, head_dim]`` gather every step.  0 keeps
-    the original full-table gather.  Both paths share every projection and
-    the cache-write scatter, and agree to f32 tolerance.
+    ``chunk_blocks`` > 0 selects the paged path
+    (:func:`determined_tpu.ops.paged_attention.paged_decode_attention`):
+    each lane's live blocks are read from the pool where it lies and folded
+    into a float32 online softmax, by the Pallas kernel where the shapes
+    tile on a TPU and by the same mathematics in ``jax.numpy`` elsewhere.
+    The value only selects: the width of a pass is chosen from the shapes
+    (it still has to divide the table width, as it always had to).  0 keeps
+    the full-table gather ``[b, T*block_size, kv_heads, head_dim]`` every
+    step, the oracle of the parity tests.  Both paths share every
+    projection and the cache-write scatter, and agree to f32 tolerance.
     """
     _check_decodable(cfg)
     block_size = cache["k"].shape[2]
-    b = tokens.shape[0]
     t = block_tables.shape[1]
     kv_len = t * block_size
     dt = cfg.dtype
@@ -749,15 +710,9 @@ def transformer_decode(
     k_cache, v_cache = cache["k"], cache["v"]
     n_rep = cfg.n_heads // cfg.kv_heads
     scale = cfg.head_dim ** -0.5
-    n_chunks = None
-    if chunk_blocks:
-        if t % chunk_blocks:
-            raise ValueError(
-                f"chunk_blocks={chunk_blocks} must divide the table width {t}"
-            )
-        chunk_tokens = chunk_blocks * block_size
-        n_chunks = jnp.minimum(
-            jnp.max(jnp.where(active, pos, 0)) // chunk_tokens + 1, t // chunk_blocks
+    if chunk_blocks and t % chunk_blocks:
+        raise ValueError(
+            f"chunk_blocks={chunk_blocks} must divide the table width {t}"
         )
     for i in range(cfg.n_layers):
         blk = params[f"block_{i}"]
@@ -767,18 +722,16 @@ def transformer_decode(
         k = _rope_batched(k, pos, cfg.rope_theta)
         # write this token's k/v, then attend against the updated pool so
         # the step sees its own key (standard causal self-attention)
-        k_cache = k_cache.at[i, phys, slot].set(k[:, :, 0, :])
-        v_cache = v_cache.at[i, phys, slot].set(v[:, :, 0, :])
+        k_cache = k_cache.at[i, phys, slot].set(_pool_rows(k)[:, 0])
+        v_cache = v_cache.at[i, phys, slot].set(_pool_rows(v)[:, 0])
         if chunk_blocks:
-            att = _paged_attention_chunked(
-                q, k_cache[i], v_cache[i], block_tables, pos, active,
-                n_rep, scale, chunk_blocks, n_chunks,
-            ).astype(dt)
+            att = paged_decode_attention(
+                q[:, :, 0, :], k_cache, v_cache, i, block_tables, positions,
+                scale=scale,
+            ).astype(dt)[:, :, None, :]
         else:
-            keys = k_cache[i][block_tables].reshape(b, kv_len, cfg.kv_heads, -1)
-            vals = v_cache[i][block_tables].reshape(b, kv_len, cfg.kv_heads, -1)
-            keys = _repeat_kv(keys.transpose(0, 2, 1, 3), n_rep)
-            vals = _repeat_kv(vals.transpose(0, 2, 1, 3), n_rep)
+            keys = _gather_table(k_cache, i, block_tables, cfg.kv_heads, n_rep)
+            vals = _gather_table(v_cache, i, block_tables, cfg.kv_heads, n_rep)
             logits = (
                 jnp.einsum(
                     "bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32
@@ -869,12 +822,10 @@ def transformer_prefill_suffix(
             # the block's own causal keys and the cached prefix are read
             # from the same pool, so warm and cold prefills see identical
             # stored bits
-            k_cache = k_cache.at[i, phys, slots].set(k.transpose(0, 2, 1, 3))
-            v_cache = v_cache.at[i, phys, slots].set(v.transpose(0, 2, 1, 3))
-            keys = k_cache[i][block_tables].reshape(b, kv_len, cfg.kv_heads, -1)
-            vals = v_cache[i][block_tables].reshape(b, kv_len, cfg.kv_heads, -1)
-            keys = _repeat_kv(keys.transpose(0, 2, 1, 3), n_rep)
-            vals = _repeat_kv(vals.transpose(0, 2, 1, 3), n_rep)
+            k_cache = k_cache.at[i, phys, slots].set(_pool_rows(k))
+            v_cache = v_cache.at[i, phys, slots].set(_pool_rows(v))
+            keys = _gather_table(k_cache, i, block_tables, cfg.kv_heads, n_rep)
+            vals = _gather_table(v_cache, i, block_tables, cfg.kv_heads, n_rep)
             logits = (
                 jnp.einsum(
                     "bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32

@@ -1,0 +1,361 @@
+"""Paged decode attention: one query token a lane against the lane's own
+blocks of the paged KV pool, read where the pool lies.
+
+The pool is ``[n_layers, num_blocks, block_size, kv_heads * head_dim]``
+(``models/transformer.py kv_cache_shape``): one block of one layer is a
+contiguous ``[block_size, kv_heads * head_dim]`` slab holding every KV head
+of ``block_size`` tokens.  Both forms below index it with the layer and the
+block ids together, so no step ever materializes a layer's pool, and both
+run ONE algorithm: a blockwise online softmax (running maximum, denominator
+and accumulator in float32, K and V in the pool's dtype) over tiles of
+``tile_blocks`` table columns.
+
+* :func:`_paged_attention_kernel` — the Pallas TPU kernel.  Grid: one
+  program a lane.  Block tables, lengths and the layer arrive as
+  scalar-prefetch arguments; a lane walks its own
+  ``ceil(length / tile_tokens)`` tiles (an empty lane none, a short lane
+  does not wait for the longest), copying each tile's blocks HBM -> VMEM
+  with the next tile's copies in flight.  The ``n_rep`` query heads of a KV
+  head are served from ONE copy of its K/V: the wrapper lays the query out
+  block-diagonally (``[heads, kv_heads * head_dim]``, a head's vector in its
+  KV head's columns, zeros elsewhere), so a tile costs two wide MXU calls
+  (``q_bd @ K^T``, ``p @ V``) and the result is the diagonal blocks of the
+  accumulator.  Taken on a TPU when the shapes tile: ``head_dim`` a multiple
+  of 128 and ``block_size`` a multiple of the pool dtype's sublane packing.
+* :func:`_paged_attention_jnp` — the same mathematics in plain
+  ``jax.numpy`` for every other shape and backend, batched over lanes (it
+  walks to the longest lane's last tile), and the kernel's parity reference.
+
+The tile width is chosen here from the shapes (:func:`_tile_blocks`), not
+by the caller.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from determined_tpu.ops.flash_attention import NEG_INF
+
+# Tokens a tile aims for.  A tile is one trip of the walk: its matmuls,
+# its softmax update and its 2 * tile_blocks block copies.  Wide tiles
+# amortize the serial chain wait -> QK -> max -> exp -> PV of a trip; the
+# last tile of a lane is copied whole, so the width also bounds the waste.
+# On a v5e at InternLM2's shapes (16 x 128 heads, 8 KV, blocks of 16, ~27 k
+# live tokens over 32 lanes) 24 layers took 6.9 / 5.2 / 5.1 / 5.9 / 7.6 ms at
+# 64 / 128 / 256 / 512 / 1024 tokens a tile (my chip run, PR 25).
+TILE_TOKENS = 256
+# VMEM the kernel's four tile buffers (K and V, two slots each) may take
+TILE_BUFFER_BYTES = 4 * 1024 * 1024
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile_blocks(block_size: int, table_width: int, token_bytes: int) -> int:
+    """Blocks a tile: TILE_TOKENS, less where a token's K (or V) row is so
+    wide that four such tiles would not fit TILE_BUFFER_BYTES, and never
+    more than the table holds."""
+    tokens = min(TILE_TOKENS, TILE_BUFFER_BYTES // (4 * token_bytes))
+    return max(1, min(table_width, tokens // block_size))
+
+
+def _sublane_packing(dtype) -> int:
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def kernel_takes(head_dim: int, block_size: int, dtype) -> bool:
+    """Whether the shapes tile for the Pallas kernel: per-head column
+    slices must be lane-aligned and a block must fill whole sublane tiles."""
+    return (
+        jnp.dtype(dtype).itemsize in (2, 4)
+        and head_dim % 128 == 0
+        and block_size % _sublane_packing(dtype) == 0
+    )
+
+
+def paged_decode_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    layer,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    scale: float,
+    tile_blocks: Optional[int] = None,
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Attention of one decode step of one layer over the paged pool.
+
+    ``q`` [b, n_heads, head_dim]; ``k_pool`` / ``v_pool`` the whole pools
+    ``[n_layers, num_blocks, block_size, kv_heads * head_dim]``; ``layer``
+    the layer to read; ``block_tables`` [b, T] int32; ``positions`` [b]
+    int32, the position of the lane's query token (it attends to
+    ``0..position``), -1 for an empty lane, whose output is zeros.  Returns
+    ``[b, n_heads, head_dim]`` float32.
+
+    ``impl`` is ``"kernel"``, ``"kernel_interpret"`` (the kernel in the
+    Pallas TPU interpreter: tests), ``"jnp"`` or None: the kernel on a TPU
+    when :func:`kernel_takes` the shapes, else the ``jax.numpy`` form.
+    ``tile_blocks`` overrides the tile width (tests).
+    """
+    block_size = k_pool.shape[2]
+    head_dim = q.shape[-1]
+    if tile_blocks is None:
+        tile_blocks = _tile_blocks(
+            block_size, block_tables.shape[1], k_pool.shape[3] * k_pool.dtype.itemsize
+        )
+    tiles = kernel_takes(head_dim, block_size, k_pool.dtype)
+    if impl is None:
+        impl = "kernel" if _on_tpu() and tiles else "jnp"
+    if impl != "jnp" and not tiles:
+        raise ValueError(
+            f"the paged-attention kernel needs head_dim % 128 == 0 and whole "
+            f"sublane tiles a block (got head_dim={head_dim}, "
+            f"block_size={block_size}, {k_pool.dtype})"
+        )
+    return _paged_attention(
+        q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
+        scale=scale, tile_blocks=tile_blocks, impl=impl,
+    )
+
+
+# The layer is an ARGUMENT of one jitted function, so a model's layers share
+# one trace and one lowering of it: lowering the kernel to Mosaic takes the
+# host ~0.2 s, and a 24-layer decode program that inlined it paid that 24
+# times at every start, cached program or not (3.4 s of ``setup_s`` on the
+# chip: my chip run, PR 25).
+@functools.partial(jax.jit, static_argnames=("scale", "tile_blocks", "impl"))
+def _paged_attention(
+    q, k_pool, v_pool, layer, block_tables, positions, *, scale, tile_blocks, impl
+):
+    lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
+    if impl == "jnp":
+        return _paged_attention_jnp(
+            q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks
+        )
+    return _paged_attention_pallas(
+        q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks,
+        interpret=impl == "kernel_interpret",
+    )
+
+
+# ---------------------------------------------------------------------------
+# jax.numpy form
+# ---------------------------------------------------------------------------
+
+
+def _paged_attention_jnp(
+    q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks
+):
+    b, n_heads, head_dim = q.shape
+    block_size = k_pool.shape[2]
+    kv_heads = k_pool.shape[3] // head_dim
+    n_rep = n_heads // kv_heads
+    t = block_tables.shape[1]
+    tile_tokens = tile_blocks * block_size
+    qg = q.reshape(b, kv_heads, n_rep, head_dim)
+    n_tiles = (jnp.max(lengths) + tile_tokens - 1) // tile_tokens
+
+    def body(i, carry):
+        m, l, acc = carry
+        # columns past the table's end re-read its last column, masked below
+        cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        tbl = jnp.take(block_tables, cols, axis=1)  # [b, tile_blocks]
+        # layer and block ids in ONE gather: the pool is never sliced
+        keys = k_pool[layer, tbl].reshape(b, tile_tokens, kv_heads, head_dim)
+        vals = v_pool[layer, tbl].reshape(b, tile_tokens, kv_heads, head_dim)
+        s = (
+            jnp.einsum("bgrd,btgd->bgrt", qg, keys, preferred_element_type=jnp.float32)
+            * scale
+        )
+        k_idx = i * tile_tokens + jnp.arange(tile_tokens)
+        live = k_idx[None, :] < lengths[:, None]  # [b, tile_tokens]
+        s = jnp.where(live[:, None, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # the where is for an empty lane alone (m still NEG_INF, so exp
+        # gives 1): it must come out as zeros, as the kernel leaves it
+        p = jnp.where(live[:, None, None, :], jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.einsum(
+            "bgrt,btgd->bgrd", p, vals, preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc_new
+
+    init = (
+        jnp.full((b, kv_heads, n_rep, 1), NEG_INF, jnp.float32),
+        jnp.zeros((b, kv_heads, n_rep, 1), jnp.float32),
+        jnp.zeros((b, kv_heads, n_rep, head_dim), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
+    return (acc / jnp.maximum(l, 1e-30)).reshape(b, n_heads, head_dim)
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU kernel
+# ---------------------------------------------------------------------------
+
+
+def _split_terms(p: jax.Array, dtype) -> jax.Array:
+    """``p`` (float32) as a stack of ``dtype`` terms whose sum is ``p`` to
+    float32 precision, along axis 0.  The MXU multiplies in the pool's
+    dtype; three bf16 terms carry all 24 mantissa bits, so ``p @ V`` keeps
+    float32 probabilities against V as stored and pays one pass over V."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return p
+    terms = []
+    rest = p
+    for _ in range(3):
+        hi = rest.astype(dtype)
+        terms.append(hi)
+        rest = rest - hi.astype(jnp.float32)
+    return jnp.concatenate(terms, axis=0)
+
+
+def _paged_attention_kernel(
+    layer_ref, lengths_ref, tables_ref,           # scalar prefetch (SMEM)
+    q_ref, k_hbm, v_hbm,                          # inputs
+    o_ref,                                        # output
+    k_buf, v_buf, sems,                           # scratch
+    *, scale: float, tile_blocks: int, block_size: int, table_width: int,
+    kv_heads: int, head_dim: int, n_rep: int,
+):
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    length = lengths_ref[b]
+    tile_tokens = tile_blocks * block_size
+    n_tiles = (length + tile_tokens - 1) // tile_tokens
+    rows = q_ref.shape[0]
+
+    def copies(tile, slot):
+        """The tile's 2 * tile_blocks block copies into buffer ``slot``.
+        Every tile is copied whole: columns past the lane's last block hold
+        block ids all the same (the allocator's scratch block 0, or the
+        table's last column), and their scores are masked."""
+        out = []
+        for j in range(tile_blocks):
+            col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
+            blk = tables_ref[b * table_width + col]
+            dst = pl.ds(j * block_size, block_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[layer, blk], k_buf.at[slot, dst], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[layer, blk], v_buf.at[slot, dst], sems.at[1, slot]))
+        return out
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    q = q_ref[...]                                    # [rows, kv_heads*head_dim]
+
+    def body(i, carry):
+        m, l, acc = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < n_tiles)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        k = k_buf[slot]                               # [tile_tokens, kv_heads*head_dim]
+        v = v_buf[slot]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                     # [rows, tile_tokens]
+        k_idx = i * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_idx < length, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # masked: exp(NEG_INF - m) = 0
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            _split_terms(p, v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # [terms*rows, kv_heads*head_dim]
+        total = pv[:rows]
+        for t in range(1, pv.shape[0] // rows):
+            total = total + pv[t * rows:(t + 1) * rows]
+        return m_new, l_new, acc * alpha + total
+
+    init = (
+        jnp.full((rows, 1), NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32),
+        jnp.zeros((rows, kv_heads * head_dim), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
+    acc = acc / jnp.maximum(l, 1e-30)
+    # row h holds head h's result in the columns of its KV head
+    row_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, head_dim), 0) // n_rep
+    out = jnp.zeros((rows, head_dim), jnp.float32)
+    for g in range(kv_heads):
+        out = jnp.where(row_kv == g, acc[:, g * head_dim:(g + 1) * head_dim], out)
+    o_ref[...] = out
+
+
+def _paged_attention_pallas(
+    q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks,
+    *, interpret: bool,
+):
+    b, n_heads, head_dim = q.shape
+    _, _, block_size, kvd = k_pool.shape
+    kv_heads = kvd // head_dim
+    n_rep = n_heads // kv_heads
+    t = block_tables.shape[1]
+    # whole sublane tiles of rows for the MXU's left operand; padding rows
+    # are zero queries, dropped below
+    packing = _sublane_packing(q.dtype)
+    rows = -(-n_heads // packing) * packing
+    # block-diagonal query: head h's vector in the columns of KV head h // n_rep
+    kv_of_head = jnp.arange(n_heads) // n_rep
+    q_bd = jnp.where(
+        (kv_of_head[:, None] == jnp.arange(kv_heads)[None, :])[None, :, :, None],
+        q[:, :, None, :],
+        jnp.zeros((), q.dtype),
+    ).reshape(b, n_heads, kvd)
+    q_bd = jnp.pad(q_bd, ((0, 0), (0, rows - n_heads), (0, 0)))
+    tile_tokens = tile_blocks * block_size
+    kernel = functools.partial(
+        _paged_attention_kernel,
+        scale=scale, tile_blocks=tile_blocks, block_size=block_size,
+        table_width=t, kv_heads=kv_heads, head_dim=head_dim, n_rep=n_rep,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, rows, kvd), lambda bi, *_: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, rows, head_dim), lambda bi, *_: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tile_tokens, kvd), k_pool.dtype),
+                pltpu.VMEM((2, tile_tokens, kvd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, head_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_decode_attention",
+    )(
+        layer.reshape(1),
+        lengths,
+        block_tables.reshape(-1).astype(jnp.int32),
+        q_bd, k_pool, v_pool,
+    )
+    return out[:, :n_heads, :]

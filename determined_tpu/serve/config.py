@@ -38,11 +38,15 @@ class ServeConfig:
     #: (content-addressed hash trie in the allocator); admission then only
     #: prefills the un-cached suffix.  Off restores the PR-9 data path.
     prefix_cache: bool = True
-    #: lazy paged decode: gather the block table in chunks of this many
-    #: columns per attention pass, running only ceil((pos+1)/chunk) passes
-    #: instead of materializing the whole table every step.  0 = legacy
-    #: full-table gather.  Must divide blocks_per_seq so every chunk is a
-    #: full dynamic slice of the table.
+    #: paged decode attention (``ops/paged_attention.py``): any value above
+    #: 0 makes a decode step read each lane's live blocks from the pool
+    #: where it lies and fold them into a float32 online softmax: the
+    #: Pallas kernel on a TPU when head_dim is a multiple of 128 and
+    #: block_size fills the cache dtype's sublane tile (16 for bf16), the
+    #: same mathematics in ``jax.numpy`` otherwise.  The value only
+    #: selects; how many blocks a pass takes is chosen from the shapes.
+    #: 0 = gather the whole table every step (the parity tests' oracle).
+    #: Must divide blocks_per_seq, as it had to when it was the pass width.
     decode_chunk_blocks: int = 1
     # ---- http / replica ---------------------------------------------------
     host: str = "127.0.0.1"
@@ -82,9 +86,8 @@ class ServeConfig:
                 f"decode_chunk_blocks must be >= 0, got {self.decode_chunk_blocks}"
             )
         if self.decode_chunk_blocks and self.blocks_per_seq % self.decode_chunk_blocks:
-            # the lazy decode slides a fixed-width window over the table;
-            # a chunk that doesn't divide the pool would leave a ragged
-            # final slice the static trace can't express
+            # kept from when the value was the width of a pass over the
+            # table: a config that was refused then is refused now
             raise InvalidExperimentConfig(
                 f"decode_chunk_blocks={self.decode_chunk_blocks} does not divide "
                 f"the block-table width ({self.blocks_per_seq} blocks per "

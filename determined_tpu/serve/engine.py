@@ -711,8 +711,17 @@ class _EngineBase:
             stamps = None
         tracer = self._tracer
         if tracer.enabled:
+            # what the step's attention had to read: with the two a trace
+            # says whether device time follows what is live (the kernel
+            # walks each lane's own blocks) or the longest lane
             tracer.record_span(
-                "serve.decode", "serve", t0, t1, {"step": step, "active": n_active}
+                "serve.decode", "serve", t0, t1,
+                {
+                    "step": step,
+                    "active": n_active,
+                    "live_kv_tokens": int((positions + 1).sum()),
+                    "max_context": int(positions.max()) + 1,
+                },
             )
             if stamps is not None:
                 at = {"step": step}

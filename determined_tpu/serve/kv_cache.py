@@ -1,7 +1,8 @@
 """Paged KV-cache block allocator: the host-side half of PagedAttention.
 
 The device arrays (``models/transformer.py init_kv_cache``) are a flat pool
-of fixed-size blocks; this module owns WHICH blocks belong to WHOM.  A
+of fixed-size blocks (``[n_layers, num_blocks, block_size, kv_heads *
+head_dim]``; this module deals in block ids only); this module owns WHICH blocks belong to WHOM.  A
 ref-counted free-list allocator hands out physical block ids all-or-nothing
 per sequence (admission either fits a whole worst-case request or rejects
 it — no mid-flight OOM aborting a half-generated response), and releases

@@ -23,8 +23,8 @@ Two more A/B sections ride the same JSON line (ISSUE 17 fast path):
   workload where ``--shared-frac`` of requests open with one shared system
   prompt — warm admissions map the cached blocks and prefill only the
   unique tail, so TTFT is the number to watch;
-- **lazy_decode**: per-step decode latency, chunked table gather
-  (``decode_chunk_blocks``) vs the legacy full-table gather, at a live
+- **lazy_decode**: per-step decode latency, paged decode attention
+  (``decode_chunk_blocks`` > 0) vs the full-table gather, at a live
   context a fraction of the table width (where laziness pays) and at full
   context (where it must not lose).
 

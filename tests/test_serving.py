@@ -801,6 +801,32 @@ def test_step_spans_nest_and_carry_their_step(kernels, tracer):
         assert e["args"]["lanes"] == steps[e["args"]["step"]]["args"]["active"]
 
 
+def test_decode_span_says_what_the_step_had_to_read(kernels, tracer):
+    """``serve.decode`` carries the live KV tokens (sum of pos + 1 over
+    active lanes) and the longest context of its step: with the step's
+    device time a trace says which of the two the attention follows."""
+    eng = ServeEngine(kernels)
+    prompts = [[1, 2, 3], [4, 5, 6, 7, 8, 9, 10]]
+    for p in prompts:
+        eng.submit(p, max_new_tokens=4)
+    while eng.step_once():
+        pass
+    decodes = sorted(_spans(tracer, "serve.decode"), key=lambda e: e["args"]["step"])
+    first = decodes[0]["args"]
+    # both lanes decode their first token at position len(prompt)
+    assert first["active"] == 2
+    assert first["live_kv_tokens"] == sum(len(p) + 1 for p in prompts)
+    assert first["max_context"] == max(len(p) for p in prompts) + 1
+    for before, after in zip(decodes, decodes[1:]):
+        if after["args"]["active"] == before["args"]["active"]:
+            assert after["args"]["live_kv_tokens"] == (
+                before["args"]["live_kv_tokens"] + after["args"]["active"]
+            )
+            assert after["args"]["max_context"] == before["args"]["max_context"] + 1
+    for e in decodes:
+        assert e["args"]["max_context"] <= e["args"]["live_kv_tokens"]
+
+
 def test_token_stamps_follow_the_output(engine):
     req = engine.generate([3, 1, 4, 1, 5], max_new_tokens=7, temperature=0.8, seed=3)
     assert req.error is None

@@ -21,7 +21,9 @@ kernels' ``_interpret`` is steered from the test.
 
 import functools
 import importlib
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -36,6 +38,7 @@ from determined_tpu.parallel.mesh import MeshConfig, make_mesh
 # the package re-exports functions under the modules' names
 flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")
 adamw_mod = importlib.import_module("determined_tpu.ops.fused_adamw")
+paged_mod = importlib.import_module("determined_tpu.ops.paged_attention")
 
 TOPOLOGY = "v5e:2x2"
 
@@ -60,6 +63,7 @@ def _real_kernels_no_cache(monkeypatch):
 
     monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
     monkeypatch.setattr(adamw_mod, "_interpret", lambda: False)
+    monkeypatch.setattr(paged_mod, "_on_tpu", lambda: True)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -279,3 +283,88 @@ def test_serve_programs_compile_on_one_chip(tpu_devices, which):
     compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 1024**3
+    if which == "decode":  # the paged-attention kernel, once a layer
+        assert _kernels(compiled.as_text()) == cfg.n_layers
+
+
+def _pool_sized_results(text: str, pool_shape) -> list:
+    """Instructions of an optimized HLO module whose result is at least one
+    layer's K pool in size and is not the pool itself on its way through the
+    program: a parameter, the donated pool's scatter (bare or as the root of
+    a fusion), or plumbing that copies nothing (tuples and their elements,
+    bitcasts)."""
+    layer_elems = math.prod(pool_shape[1:])
+    roots = {}  # computation -> opcode of its ROOT
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%(\S+)\s+\(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+        root = re.match(r"\s*ROOT\s+%\S+ = \S+ ([\w-]+)\(", line)
+        if root and name:
+            roots[name] = root.group(1)
+    passes = {"parameter", "scatter", "tuple", "get-tuple-element", "bitcast"}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if not m or math.prod(int(d) for d in m.group(1).split(",")) < layer_elems:
+            continue
+        op = m.group(2)
+        if op == "fusion":
+            called = re.search(r"calls=%(\S+?)[,\s]", line)
+            op = roots.get(called.group(1), op) if called else op
+        if op not in passes:
+            found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("form", ["kernel", "jnp"])
+def test_decode_step_holds_no_copy_of_a_layers_pool(tpu_devices, monkeypatch, form):
+    """The copy that took a third of a decode step cannot come back
+    unseen: ``jit_serve_decode`` for a small ServeConfig, paged path, holds
+    no instruction as large as one layer's K pool
+    (``[num_blocks, block_size, kv_heads * head_dim]``) except the pools
+    themselves: parameters, their in-place scatters, the result.  (The
+    parent read ``k_cache[i]`` into a ``while`` loop, and XLA materialized
+    48 such slices a step.)  Both forms, since either may serve a shape."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+        kv_cache_shape,
+        transformer_decode,
+    )
+    from determined_tpu.serve.config import ServeConfig
+
+    monkeypatch.setattr(paged_mod, "_on_tpu", lambda: form == "kernel")
+    one = SingleDeviceSharding(tpu_devices[0])
+    # a pool much larger than anything else in the program (weights,
+    # logits), so that size alone tells a copy of it from other work
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=3, n_heads=2, n_kv_heads=1,
+        d_ff=512, max_seq_len=512,
+    )
+    sc = ServeConfig(
+        num_blocks=1024, block_size=16, max_batch=4, max_prompt_len=128,
+        max_new_tokens=128, decode_chunk_blocks=1,
+    )
+    boxed = jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cshape = kv_cache_shape(cfg, sc.num_blocks, sc.block_size)
+    cache = {"k": aval(cshape, cfg.dtype), "v": aval(cshape, cfg.dtype)}
+    b = sc.max_batch
+    fn = jax.jit(
+        functools.partial(transformer_decode, cfg, chunk_blocks=sc.decode_chunk_blocks),
+        donate_argnums=(4,),
+    )
+    compiled = fn.lower(
+        params, aval((b,)), aval((b,)), aval((b, sc.blocks_per_seq)), cache
+    ).compile()
+    text = compiled.as_text()
+    assert _kernels(text) == (cfg.n_layers if form == "kernel" else 0)
+    assert _pool_sized_results(text, cshape) == []
