@@ -14,7 +14,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
-from . import readers, spec as spec_mod, trace as trace_mod
+from . import model, readers, spec as spec_mod, trace as trace_mod
 
 #: compile cache and traces: inside the checkout, listed in .gitignore, fixed
 SCRATCH = os.path.join(spec_mod.CHECKOUT, ".dtpu_cache")
@@ -89,9 +89,6 @@ def run_cell(
     t_start = time.monotonic() if t_start is None else t_start
     spec = spec_mod.Spec(root)
     cell = spec.cell(workload)
-    facts = _setup_jax(require_tpu, cell.chips)
-    peak = spec.peak(facts["kind"]) if require_tpu else next(iter(spec.peaks["device_kinds"].values()))
-    compiles = CompileCounter()
     kind = cell.traffic["kind"]
     if kind == "train":
         from . import train_run as runner
@@ -99,12 +96,20 @@ def run_cell(
         from . import serve_run as runner
     else:
         raise spec_mod.SpecError(f"traffic {cell.traffic_name}: unknown kind {kind!r}")
+    # everything the cell names is found, and fits together, before a device is touched
+    arch = model.adapter(cell)
+    for m in cell.per_layer:
+        readers.check(m, cell.data_dir)
+    runner.check(cell)
+    facts = _setup_jax(require_tpu, cell.chips)
+    peak = spec.peak(facts["kind"]) if require_tpu else next(iter(spec.peaks["device_kinds"].values()))
+    compiles = CompileCounter()
     _say(
         "start", workload=workload, seed=seed, seconds=seconds, trace=int(traced), device=facts,
         seconds_since_start=time.monotonic() - t_start,
     )
     trace_dir = os.path.join(SCRATCH, "bench", workload, "trace")
-    out = runner.run(cell, seed, float(seconds), traced, t_start, _say, trace_dir)
+    out = runner.run(cell, arch, seed, float(seconds), traced, t_start, _say, trace_dir)
     obs = out["observations"]
     lo, hi = obs.window
     compiled = compiles.inside(lo, hi)
@@ -146,7 +151,9 @@ def run_cell(
     return line
 
 
-def main(argv: Optional[List[str]] = None, t_start: Optional[float] = None) -> int:
+def main(argv: Optional[List[str]] = None, t_start: Optional[float] = None, **cell_kwargs: Any) -> int:
+    """``cell_kwargs`` (``root``, ``require_tpu``) are the tests': the
+    command passes none."""
     import argparse
 
     t_start = time.monotonic() if t_start is None else t_start
@@ -157,7 +164,7 @@ def main(argv: Optional[List[str]] = None, t_start: Optional[float] = None) -> i
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
     args = ap.parse_args(argv)
     try:
-        line = run_cell(args.workload, args.seed, args.seconds, bool(args.trace), t_start=t_start)
+        line = run_cell(args.workload, args.seed, args.seconds, bool(args.trace), t_start=t_start, **cell_kwargs)
     except (NoDevice, spec_mod.SpecError) as e:
         print(f"benchmark: {e}", file=sys.stderr)
         return 3

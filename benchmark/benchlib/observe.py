@@ -107,6 +107,8 @@ class Observations:
     traffic: Dict[str, Any]
     chips: int
     program_epoch: float = 0.0                  # monotonic time of tracer ts 0
+    arch: Any = None                            # the configuration's adapter (benchmark/archs/)
+    data_dir: str = ""                          # the benchmark directory: readers/ and costs/ are looked up there
 
     def all_spans(self) -> List[Span]:
         """The benchmark's spans and the program's (``ph == "X"`` events of

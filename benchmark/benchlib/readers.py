@@ -4,9 +4,11 @@ A metric is a small file of its own, ``benchmark/metrics/<name>.json``:
 ``{"reader": <name>, "args": {...}}``.  A reader takes the run's
 :class:`~benchlib.observe.Observations` and the arguments, and returns the
 number, or ``None`` where it finds nothing to read (the harness then leaves
-the metric out of the line).  No reader knows a cell or a model.  A name
-that is not one of ``READERS`` is a file a later PR added,
-``benchmark/readers/<name>.py``, whose ``read(obs, args, peak)`` is called.
+the metric out of the line).  No reader knows a cell or a model: what a
+reader needs of the architecture it asks the cell's adapter (``obs.arch``).
+A name that is not one of ``READERS`` is a file a later PR added,
+``benchmark/readers/<name>.py``, whose ``read(obs, args, peak)`` is called;
+a ``cost`` is found the same way (``costs.find``).
 """
 
 from __future__ import annotations
@@ -15,7 +17,7 @@ import os
 import statistics
 from typing import Any, Callable, Dict, Optional
 
-from . import costs, trace as trace_mod
+from . import costs, model, trace as trace_mod
 from .observe import Observations
 
 Reader = Callable[[Observations, Dict[str, Any], Dict[str, Any]], Optional[float]]
@@ -87,14 +89,16 @@ def device_idle_share(obs: Observations, args: Dict[str, Any], peak: Dict[str, A
     return 100.0 * trace_mod.idle_share(data)
 
 
-def _least_seconds(cost: Dict[str, float], peak: Dict[str, Any]) -> float:
-    return max(cost["flops"] / peak["bf16_flops_per_s"], cost["bytes"] / peak["hbm_bytes_per_s"])
+def _least_seconds(obs: Observations, cost: str, peak: Dict[str, Any]) -> float:
+    """The least time the chip could take for what the cost function counts."""
+    need = costs.find(cost, obs.data_dir)(obs.config, obs.traffic, obs.chips, obs.counters, obs.arch)
+    return max(need["flops"] / peak["bf16_flops_per_s"], need["bytes"] / peak["hbm_bytes_per_s"])
 
 
 def op_roofline(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any]) -> Optional[float]:
     """Percent of its roofline bound that a kernel reaches: the least time
     the chip could take for one call's operations and bytes (``cost``, a
-    function of ``costs.py``) over the trace time of the operations whose
+    function of ``costs.py`` or a file of ``costs/``) over the trace time of the operations whose
     name matches ``pattern``, per ``per`` (a counter: calls traced)."""
     data, n = obs.trace(), obs.counters.get(args["per"])
     if data is None or not data.devices or not n:
@@ -102,8 +106,7 @@ def op_roofline(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any]) -
     measured = trace_mod.op_seconds(data, args["pattern"]) / n
     if measured <= 0.0:
         return None
-    cost = getattr(costs, args["cost"])(obs.config, obs.traffic, obs.chips, obs.counters)
-    return 100.0 * _least_seconds(cost, peak) / measured
+    return 100.0 * _least_seconds(obs, args["cost"], peak) / measured
 
 
 def span_roofline(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any]) -> Optional[float]:
@@ -112,8 +115,7 @@ def span_roofline(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any])
     measured_ms = device_ms_in_span(obs, args, peak)
     if not measured_ms:
         return None
-    cost = getattr(costs, args["cost"])(obs.config, obs.traffic, obs.chips, obs.counters)
-    return 100.0 * _least_seconds(cost, peak) / (measured_ms / 1e3)
+    return 100.0 * _least_seconds(obs, args["cost"], peak) / (measured_ms / 1e3)
 
 
 def mfu(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any]) -> Optional[float]:
@@ -122,7 +124,7 @@ def mfu(obs: Observations, args: Dict[str, Any], peak: Dict[str, Any]) -> Option
     rate = obs.counters.get(args["rate"])
     if not rate:
         return None
-    per_token = costs.train_flops_per_token(obs.config, int(obs.traffic["seq_len"]))
+    per_token = costs.train_flops_per_token(obs.config, int(obs.traffic["seq_len"]), obs.arch)
     return 100.0 * per_token * rate / (obs.chips * peak["bf16_flops_per_s"])
 
 
@@ -135,25 +137,20 @@ READERS: Dict[str, Reader] = {
 }
 
 
-def _reader_file(path: str) -> Reader:
-    import importlib.util
+def find(metric: Dict[str, Any], data_dir: str) -> Reader:
+    """The reader a metric file names: one of ``READERS``, or the ``read``
+    of ``<data_dir>/readers/<name>.py``."""
+    name = str(metric["reader"].get("reader"))
+    return model.named(READERS, name, os.path.join(data_dir, "readers", name + ".py"), "read", f"metric {metric['name']}: reader")
 
-    mod_spec = importlib.util.spec_from_file_location("bench_reader_" + os.path.basename(path)[:-3], path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
-    return module.read
+
+def check(metric: Dict[str, Any], data_dir: str) -> None:
+    """Before a run: the metric's reader and its cost function exist."""
+    find(metric, data_dir)
+    cost = metric["reader"].get("args", {}).get("cost")
+    if cost is not None:
+        costs.find(cost, data_dir)
 
 
 def read(metric: Dict[str, Any], obs: Observations, peak: Dict[str, Any]) -> Optional[float]:
-    reader = metric["reader"]
-    name = str(reader.get("reader"))
-    fn = READERS.get(name)
-    if fn is None:
-        path = os.path.join(metric.get("readers_dir", ""), name + ".py")
-        if not os.path.isfile(path):
-            raise ValueError(
-                f"metric {metric['name']}: unknown reader {name!r} "
-                f"(have: {', '.join(sorted(READERS))}, and no {path})"
-            )
-        fn = _reader_file(path)
-    return fn(obs, reader.get("args", {}), peak)
+    return find(metric, obs.data_dir)(obs, metric["reader"].get("args", {}), peak)

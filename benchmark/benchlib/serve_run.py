@@ -19,8 +19,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import model, stats, traffic as traffic_mod
+from . import stats, traffic as traffic_mod
 from .observe import Observations, Profiler, tracer_epoch
+from .spec import SpecError
 
 mono = time.monotonic
 
@@ -148,20 +149,29 @@ def _record(req: Any, due: Optional[float] = None) -> Dict[str, Any]:
     }
 
 
-def build_engine(cell: Any, seed: int) -> Tuple[Any, Any, Any]:
+def check(cell: Any) -> None:
+    """What can be refused before a device is touched."""
+    if cell.chips != 1:
+        raise SpecError(
+            f"cell {cell.name}: a serving cell runs one engine on one chip; {cell.chips} chips "
+            "(replicas behind a router, a sharded model) need a runner that benchlib/serve_run.py is not"
+        )
+
+
+def build_engine(cell: Any, arch: Any, seed: int) -> Tuple[Any, Any, Any]:
     """The engine as ``dtpu serve`` builds it, on weights made on the device
     from the seed (no checkpoint, so no Trainer is built to reach them)."""
     from determined_tpu.serve.config import ServeConfig
     from determined_tpu.serve.engine import DecodeKernels, ServeEngine
 
     serve_cfg = ServeConfig(**cell.traffic["engine"])
-    model_cfg = model.transformer_config(cell.config, serve_cfg.max_seq_len)
-    params = model.init_params(model_cfg, seed)
+    model_cfg = arch.model_config(cell.config, serve_cfg.max_seq_len)
+    params = arch.init_params(model_cfg, seed)
     return ServeEngine(DecodeKernels(model_cfg, params, serve_cfg)), params, model_cfg
 
 
 def run(
-    cell: Any, seed: int, seconds: float, traced: bool,
+    cell: Any, arch: Any, seed: int, seconds: float, traced: bool,
     t_start: float, say: Callable[..., None], trace_dir: str,
 ) -> Dict[str, Any]:
     import jax
@@ -172,7 +182,7 @@ def run(
 
     say("setup", stage="program_imported", seconds_since_start=mono() - t_start)
     config, traffic = cell.config, cell.traffic
-    engine, params, model_cfg = build_engine(cell, seed)
+    engine, params, model_cfg = build_engine(cell, arch, seed)
     jax.block_until_ready(params)
     say("setup", stage="weights_on_device", seconds_since_start=mono() - t_start)
     serve_cfg = engine.cfg
@@ -274,9 +284,9 @@ def run(
         window=(t_open, t_close), spans=spans, counters=counters,
         program_events=tracer.chrome_events() if traced else [],
         profiler=profiler, config=config, traffic=traffic, chips=cell.chips,
-        program_epoch=epoch,
+        program_epoch=epoch, arch=arch, data_dir=cell.data_dir,
     )
-    correct, detail = _check(engine, params, config, model_cfg, seed)
+    correct, detail = _check(engine, params, config, arch, vocab, seed)
     say("serve.check", **detail)
     return {
         "values": values,
@@ -431,21 +441,18 @@ def _open_loop(
 # ---------------------------------------------------------------------------
 
 
-def _check(engine: Any, params: Any, config: Dict[str, Any], model_cfg: Any, seed: int) -> Tuple[bool, Dict[str, Any]]:
+def _check(engine: Any, params: Any, config: Dict[str, Any], arch: Any, vocab: int, seed: int) -> Tuple[bool, Dict[str, Any]]:
     """One seeded sequence: prefill its first half, decode the second half
     token by token through the paged cache (teacher-forced), and hold the
-    logits to the reference's full forward at the published widths."""
-    import functools
-
+    logits to the full forward of the architecture's reference at the
+    published widths."""
     import jax
     import jax.numpy as jnp
-
-    from reference import dense_decoder
 
     tol = config["tolerance"]["serve_logits"]
     n, half = int(tol["sequence_tokens"]), int(tol["sequence_tokens"]) // 2
     rng = np.random.default_rng([int(seed), 0xC0FFEE])
-    seq = rng.integers(1, model_cfg.vocab_size, size=n, dtype=np.int64)
+    seq = rng.integers(1, vocab, size=n, dtype=np.int64)
     cfg = engine.cfg
     blocks = engine.allocator.alloc(engine.allocator.blocks_for(n))
     table = blocks + [0] * (cfg.blocks_per_seq - len(blocks))
@@ -459,11 +466,8 @@ def _check(engine: Any, params: Any, config: Dict[str, Any], model_cfg: Any, see
         tokens[0], positions[0] = seq[t], t
         rows.append(k.decode(tokens, positions, tables)[0])
     got = np.stack(rows)  # predictions after positions half-1 .. n-1
-    ref_fn = jax.jit(functools.partial(
-        dense_decoder.forward, rope_theta=float(config["rope_theta"]), eps=model.eps_as_run(config),
-    ))
-    weights = model.reference_weights(params, model_cfg.n_layers)
-    want = np.asarray(ref_fn(weights, jnp.asarray(seq, jnp.int32)))[half - 1:]
+    ref_fn = jax.jit(lambda weights, tokens: arch.reference_forward(weights, tokens, config))
+    want = np.asarray(ref_fn(arch.reference_weights(params, config), jnp.asarray(seq, jnp.int32)))[half - 1:]
     diff = got.astype(np.float64) - want.astype(np.float64)
     rel_rms = float(np.sqrt(np.mean(diff**2)) / np.sqrt(np.mean(want.astype(np.float64) ** 2)))
     max_abs = float(np.max(np.abs(diff)))

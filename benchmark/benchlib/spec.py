@@ -48,12 +48,14 @@ class Cell:
 
     name: str
     chips: int
+    data_dir: str  # holds archs/, costs/ and readers/ beside the data files
     config_name: str
+    config_file: str  # as BENCHMARK.json gives it, for messages
     config: Dict[str, Any]
     traffic_name: str
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
-    per_layer: List[Dict[str, Any]]  # each: the BENCHMARK.json entry + "reader", "readers_dir"
+    per_layer: List[Dict[str, Any]]  # each: the BENCHMARK.json entry + "reader" (its metric file)
 
 
 class Spec:
@@ -89,13 +91,13 @@ class Spec:
         for m in self.doc["per_layer"]:
             if self._applies(m, name):
                 reader = _load(os.path.join(self.data_dir, "metrics", m["name"] + ".json"))
-                per_layer.append(
-                    {**m, "reader": reader, "readers_dir": os.path.join(self.data_dir, "readers")}
-                )
+                per_layer.append({**m, "reader": reader})
         return Cell(
             name=name,
             chips=int(entry["chips"]),
+            data_dir=self.data_dir,
             config_name=entry["config"],
+            config_file=cfg_entry["file"],
             config=config,
             traffic_name=entry["traffic"],
             traffic=traffic,

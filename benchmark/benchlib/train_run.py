@@ -3,8 +3,10 @@
 The system under test is ``train.Trainer(trial).fit(...)`` as a user runs
 it: its own loop dispatches the steps, its own prefetching input pipeline
 feeds them.  The trial is the program's ``LMTrial`` with three things a user
-would also write in a subclass: the rotary base of the configuration, a
-dataset made from ``--seed``, and a callback.  The callback is the
+would also write in a subclass: what the configuration's adapter overrides
+on the model config (the rotary base), a dataset made from ``--seed``, and a
+callback.  The mesh and the experiment's ``optimizations`` block come with
+the configuration's ``train_batch`` (absent: one chip, none).  The callback is the
 benchmark's only hold on the loop: the Trainer calls it at every report
 boundary, right after it has fetched the loss (a value fetch: every step
 dispatched so far has run), and the window opens and closes at such calls.
@@ -22,6 +24,7 @@ import numpy as np
 
 from . import model
 from .observe import Observations, Profiler, tracer_epoch
+from .spec import SpecError
 
 mono = time.monotonic
 
@@ -70,7 +73,7 @@ class Window:
         return self.marks[self.warm - 1], self.marks[-1]
 
 
-def _hparams(config: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
+def _hparams(config: Dict[str, Any], traffic: Dict[str, Any], arch: Any) -> Dict[str, Any]:
     batch = int(config["train_batch"]["global_batch_sequences"])
     return {
         "lr": float(traffic["lr"]),
@@ -81,12 +84,7 @@ def _hparams(config: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
         "global_batch_size": batch,
         "seq_len": int(traffic["seq_len"]),
         "dataset_size": batch * int(traffic["dataset_batches"]),
-        "vocab_size": int(config["vocab_size"]),
-        "d_model": int(config["hidden_size"]),
-        "n_layers": int(config["num_hidden_layers"]),
-        "n_heads": int(config["num_attention_heads"]),
-        "n_kv_heads": int(config["num_key_value_heads"]),
-        "d_ff": int(config["intermediate_size"]),
+        **arch.trial_hparams(config),
         "bf16": config["dtypes"]["compute"] == "bfloat16",
         "attention": traffic["attention"],
         "fused_ce": bool(traffic["fused_ce"]),
@@ -96,20 +94,43 @@ def _hparams(config: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
-def build_trainer(cell: Any, seed: int, window: Optional[Window], ckpt_dir: str) -> Any:
+def _mesh(cell: Any) -> Dict[str, int]:
+    """``train_batch.mesh`` of the configuration: axis name -> size, the
+    names of the program's ``MeshConfig``.  Absent, one chip."""
+    mesh = {str(k): int(v) for k, v in cell.config["train_batch"].get("mesh", {"data": 1}).items()}
+    if math.prod(mesh.values()) != cell.chips:
+        raise SpecError(
+            f"{cell.config_file}: train_batch.mesh {mesh} spans {math.prod(mesh.values())} "
+            f"chips and cell {cell.name} has {cell.chips}"
+        )
+    return mesh
+
+
+def check(cell: Any) -> None:
+    """What can be refused before a device is touched."""
+    _mesh(cell)
+
+
+def build_trainer(cell: Any, arch: Any, seed: int, window: Optional[Window], ckpt_dir: str) -> Any:
     import jax
 
     from determined_tpu import core, train
+    from determined_tpu.config.experiment import ExperimentConfig, InvalidExperimentConfig
     from determined_tpu.data import SyntheticDataset
     from determined_tpu.models.transformer import LMTrial
     from determined_tpu.parallel.mesh import MeshConfig
     from determined_tpu.train._trial import Callback
 
     config, traffic = cell.config, cell.traffic
-    model.check_as_run(config)
-    if cell.chips != 1:
-        raise ValueError("this runner lays a cell on one chip: a cell over several brings its mesh with it")
-    rope_theta = float(config["rope_theta"])
+    arch.check_as_run(config)
+    overrides = arch.trial_overrides(config)
+    try:
+        mesh_config = MeshConfig(**_mesh(cell))
+        # an experiment's `optimizations:` block, as `dtpu experiment run` would hand it over
+        optimizations = config["train_batch"].get("optimizations")
+        exp_config = ExperimentConfig.parse({"optimizations": optimizations}) if optimizations else None
+    except (TypeError, InvalidExperimentConfig) as e:
+        raise SpecError(f"{cell.config_file}: train_batch: {e}") from None
 
     class Boundary(Callback):
         def on_training_workload_end(self, steps_completed: int, metrics: Dict[str, float]) -> None:
@@ -118,7 +139,7 @@ def build_trainer(cell: Any, seed: int, window: Optional[Window], ckpt_dir: str)
 
     class BenchTrial(LMTrial):
         def _cfg(self) -> Any:
-            return dataclasses.replace(super()._cfg(), rope_theta=rope_theta)
+            return dataclasses.replace(super()._cfg(), **overrides)
 
         def _dataset(self, split: int) -> Any:
             g = self.context.get_hparam
@@ -133,17 +154,18 @@ def build_trainer(cell: Any, seed: int, window: Optional[Window], ckpt_dir: str)
             return {"bench": Boundary()}
 
     ctx = train.init(
-        hparams=_hparams(config, traffic),
-        mesh_config=MeshConfig(data=1),
+        hparams=_hparams(config, traffic, arch),
+        mesh_config=mesh_config,
+        exp_config=exp_config,
         core_context=core._dummy_init(checkpoint_dir=ckpt_dir),
         seed=model.seed32(seed),
-        devices=jax.devices()[:1],
+        devices=jax.devices()[: cell.chips],
     )
     return train.Trainer(BenchTrial(ctx))
 
 
 def run(
-    cell: Any, seed: int, seconds: float, traced: bool,
+    cell: Any, arch: Any, seed: int, seconds: float, traced: bool,
     t_start: float, say: Callable[..., None], trace_dir: str,
 ) -> Dict[str, Any]:
     import jax
@@ -158,7 +180,7 @@ def run(
     tracer.configure(enabled=traced)
     epoch = tracer_epoch(tracer) if traced else 0.0
     ckpt_dir = os.path.join(os.path.dirname(trace_dir), "ckpt")
-    trainer = build_trainer(cell, seed, window, ckpt_dir)
+    trainer = build_trainer(cell, arch, seed, window, ckpt_dir)
     say("setup", stage="trainer_built", seconds_since_start=mono() - t_start)
     try:
         trainer.fit(
@@ -195,9 +217,9 @@ def run(
         },
         program_events=tracer.chrome_events() if traced else [],
         profiler=profiler, config=config, traffic=traffic, chips=cell.chips,
-        program_epoch=epoch,
+        program_epoch=epoch, arch=arch, data_dir=cell.data_dir,
     )
-    ok, detail = _check(trainer, cell, seed)
+    ok, detail = _check(trainer, cell, arch, seed)
     say("train.check", **detail)
     finite = all(math.isfinite(x) for x in losses)
     return {
@@ -207,23 +229,6 @@ def run(
         "correct": bool(ok and finite and steps > 0),
         "memory_peak_bytes": peak,
         "observations": obs,
-    }
-
-
-def _probe(weights: Dict[str, Any], embed_rows: np.ndarray) -> Dict[str, Any]:
-    """A few leaves (or their first rows) of a tree under the reference's
-    names: what one step's update is compared on.  The embedding's rows are
-    given: some that the batch holds and some that it does not."""
-    first, last = weights["layers"][0], weights["layers"][-1]
-    return {
-        "embed": weights["embed"][embed_rows],
-        "first.wq": first["wq"][:256],
-        "first.w_gate": first["w_gate"][:256],
-        "last.wo": last["wo"][:8],
-        "last.w_down": last["w_down"][:256],
-        "last.mlp_norm": last["mlp_norm"],
-        "final_norm": weights["final_norm"],
-        "head": weights["head"][:256],
     }
 
 
@@ -241,7 +246,7 @@ def _rel(got: Any, want: Any) -> float:
     return float(np.sqrt(np.sum((got - want) ** 2)) / max(np.sqrt(np.sum(want**2)), 1e-30))
 
 
-def _check(trainer: Any, cell: Any, seed: int) -> Tuple[bool, Dict[str, Any]]:
+def _check(trainer: Any, cell: Any, arch: Any, seed: int) -> Tuple[bool, Dict[str, Any]]:
     """The program against the reference on one seeded sequence, from the
     parameters and moments as the window left them.
 
@@ -250,45 +255,49 @@ def _check(trainer: Any, cell: Any, seed: int) -> Tuple[bool, Dict[str, Any]]:
     cross-entropy) against the reference's.  One whole step of the program
     (``Trainer``'s own jitted step: backward pass, fused cross-entropy's
     gradient, clipping, fused AdamW) on that sequence against the reference's
-    ``jax.grad`` and its plain AdamW, on a few leaves: the clipped gradient
-    the program used (read back from its first moment), the second moment it
-    wrote, and the change of the parameters.
+    ``jax.grad`` and its plain AdamW, on the leaves the adapter's ``probe``
+    names: the clipped gradient the program used (read back from its first
+    moment), the second moment it wrote, and the change of the parameters.
+    Over a mesh whose batch axes span several chips the program is given that
+    many copies of the sequence: their mean loss and gradient are the one
+    sequence's.
     """
-    import functools
-
     import jax
     import jax.numpy as jnp
     from flax.core import meta
 
-    from reference import adamw, dense_decoder
+    from reference import adamw
 
     config, traffic = cell.config, cell.traffic
     tol = config["tolerance"]["train_step"]
-    n, layers = int(tol["sequence_tokens"]), int(config["num_hidden_layers"])
+    n = int(tol["sequence_tokens"])
+    trial = trainer.trial
+    vocab = int(trial.context.get_hparam("vocab_size"))
     opt = {**traffic["adam"], "weight_decay": float(traffic["weight_decay"])}
     rng = np.random.default_rng([int(seed), 0xC0FFEE])
-    seq = rng.integers(1, int(config["vocab_size"]), size=n + 1, dtype=np.int64).astype(np.int32)
+    seq = rng.integers(1, vocab, size=n + 1, dtype=np.int64).astype(np.int32)
     present = np.unique(seq[:-1])
-    absent = np.setdiff1d(np.arange(int(config["vocab_size"])), present)
+    absent = np.setdiff1d(np.arange(vocab), present)
     rows = np.concatenate([present[:192], absent[:64]])
-    batch = {"tokens": jnp.asarray(seq[None, :])}
-    trial = trainer.trial
+    copies = int(trial.context.batch_axis_size)
+    if copies == 1:
+        batch = {"tokens": jnp.asarray(seq[None, :])}
+    else:
+        from determined_tpu.data._loader import to_global
+
+        batch = to_global({"tokens": np.tile(seq[None, :], (copies, 1))}, trainer.mesh)
 
     def named(tree: Any) -> Dict[str, Any]:
-        return model.reference_weights(meta.unbox(tree)["params"], layers)
+        return arch.reference_weights(meta.unbox(tree)["params"], config)
 
-    probe = jax.jit(lambda tree: _probe(named(tree), rows))
+    probe = jax.jit(lambda tree: arch.probe(named(tree), rows))
 
     def reference(weights: Dict[str, Any], tokens: jax.Array):
         (loss, logits), grads = jax.value_and_grad(
-            functools.partial(
-                dense_decoder.loss_and_logits,
-                rope_theta=float(config["rope_theta"]), eps=model.eps_as_run(config),
-            ),
-            has_aux=True,
+            lambda w, t: arch.reference_loss_and_logits(w, t, config), has_aux=True
         )(weights, tokens)
         norm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads)))
-        return loss, logits, norm, _probe(grads, rows)
+        return loss, logits, norm, arch.probe(grads, rows)
 
     def program(params: Any, tokens: jax.Array):
         loss, _ = trial.loss(trainer.model, params, {"tokens": tokens}, jax.random.key(0))

@@ -29,13 +29,13 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=25.0)
     ap.add_argument("--seed", type=int, default=2_147_483_000)
     args = ap.parse_args()
-    from benchlib import harness, serve_run, spec as spec_mod, stats
+    from benchlib import harness, model, serve_run, spec as spec_mod, stats
 
     cell = spec_mod.Spec().cell(args.workload)
     harness._setup_jax(True, cell.chips)
     from determined_tpu.serve.scheduler import AdmissionRejected
 
-    engine, _, model_cfg = serve_run.build_engine(cell, args.seed)
+    engine, _, model_cfg = serve_run.build_engine(cell, model.adapter(cell), args.seed)
     temperature = float(cell.traffic["temperature"])
     engine.start()
     try:

@@ -39,13 +39,13 @@ def ev(name, start, dur, **args):
 def obs(events=(), spans=()):
     return Observations(
         window=WINDOW, spans=list(spans), counters={}, program_events=list(events),
-        profiler=None, config={}, traffic={}, chips=1, program_epoch=EPOCH,
+        profiler=None, config={}, traffic={}, chips=1, program_epoch=EPOCH, data_dir=B.BENCH,
     )
 
 
 def metric(name):
     with open(os.path.join(B.BENCH, "metrics", name + ".json")) as f:
-        return {"name": name, "reader": json.load(f), "readers_dir": os.path.join(B.BENCH, "readers")}
+        return {"name": name, "reader": json.load(f)}
 
 
 def read(name, o):
