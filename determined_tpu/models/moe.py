@@ -22,6 +22,7 @@ Shapes (g = tokens per group, e = experts, c = capacity, d/f = model/ff):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Tuple
 
 import jax
@@ -188,3 +189,237 @@ class MoE(nn.Module):
             y = jax.lax.psum(y, self.expert_axis_name)
         y = y.reshape(n_groups * grp, d)[:g]
         return y.reshape(b, s, d), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing over the experts one device holds
+# ---------------------------------------------------------------------------
+#
+# The layer above builds dense [g, e, c] dispatch tensors: fine for a few
+# experts and top-2, unusable at 64 experts and top-8 (one group of 4,096
+# tokens would need a 168 M-element tensor and more FLOPs to dispatch than
+# to run the experts), and it drops tokens past a capacity.  The layer below
+# routes over ALL experts, keeps the picks that land on the experts it holds,
+# sorts those rows by expert into a tile-aligned buffer sized for the worst
+# case, runs gate/up/down as grouped products (ops/grouped_matmul.py) and
+# gathers the weighted rows back (one custom VJP, ``_held_experts``: both
+# directions of the dispatch are gathers, and the backward pass keeps the two
+# hidden products only).  No capacity, no dropped token, and nothing
+# stands in for experts that live elsewhere: what they would add is simply
+# not added here (under ``expert_axis_name`` the ``psum`` adds it).
+
+
+def _round_up_pow2(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def _rows_of_tokens(x: jax.Array, row_pick: jax.Array, row_live: jax.Array, k: int) -> jax.Array:
+    """``rows[r] = x[token of r]`` for the rows a held pick owns, ZERO
+    elsewhere (a zero row adds nothing to a weight's gradient).  ``row_pick
+    [rows]``: the flat pick (token * k + j) of a row."""
+    rows = jnp.take(x, row_pick // k, axis=0)
+    return jnp.where(row_live[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+def _row_weights(weights: jax.Array, row_pick: jax.Array, row_live: jax.Array) -> jax.Array:
+    """The routing weight ``[T, k]`` of each row's pick, zero for rows no pick owns."""
+    return jnp.where(row_live, jnp.take(weights.reshape(-1), row_pick), 0.0)
+
+
+def _tokens_of_rows(rows: jax.Array, pick_row: jax.Array, pick_held: jax.Array) -> jax.Array:
+    """``out[t] = sum over t's held picks of rows[row of the pick]``, float32:
+    the transpose of :func:`_rows_of_tokens` written as a gather (``pick_row
+    [T, k]``: the row of a pick).  XLA would make a scatter-add of rows into
+    tokens of it, which a TPU runs serially."""
+    picked = jnp.take(rows, pick_row, axis=0)                          # [T, k, d]
+    # where, not times: rows that no pick owns may hold anything
+    return jnp.sum(jnp.where(pick_held[:, :, None], picked, 0).astype(jnp.float32), axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(12, 13))
+def _held_experts(
+    x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held,
+    group_start, tile_group, live_tiles, rows, tile,
+):
+    """``y[t] = sum over t's held picks of weights[t, j] * W_down,e (silu(W_gate,e
+    x[t]) * W_up,e x[t])``, float32, through the tile-aligned buffer that
+    ``gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)`` lays out."""
+    return _held_experts_fwd(
+        x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held,
+        group_start, tile_group, live_tiles, rows, tile,
+    )[0]
+
+
+def _hidden(gate, up, scale):
+    """(silu(gate) * up in float32, the same times a row's routing weight in
+    the compute dtype: the down projection's input)."""
+    act = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return act, (act * scale[:, None]).astype(gate.dtype)
+
+
+def _held_experts_fwd(
+    x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held,
+    group_start, tile_group, live_tiles, rows, tile,
+):
+    from determined_tpu.ops import grouped_matmul as gm
+
+    layout = gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)
+    dt = x.dtype
+    with jax.named_scope("moe.dispatch"):
+        xr = _rows_of_tokens(x, row_pick, row_live, weights.shape[1])
+        scale = _row_weights(weights, row_pick, row_live)
+    with jax.named_scope("moe.experts"):
+        gate = gm.gmm(xr, w_gate.astype(dt), layout)
+        up = gm.gmm(xr, w_up.astype(dt), layout)
+        # the routing weight goes in before the down projection: the combine is then a plain sum
+        out = gm.gmm(_hidden(gate, up, scale)[1], w_down.astype(dt), layout)
+    with jax.named_scope("moe.combine"):
+        y = _tokens_of_rows(out, pick_row, pick_held)
+    # kept for the backward pass: the two hidden products.  The rows are
+    # gathered again there and silu(gate) * up is an elementwise pass: no
+    # matrix product is recomputed, and a layer keeps rows x 2 d_ff, not
+    # rows x (2 d_model + 3 d_ff)
+    return y, (x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held,
+               group_start, tile_group, live_tiles, gate, up)
+
+
+def _held_experts_bwd(rows, tile, res, d_y):
+    from determined_tpu.ops import grouped_matmul as gm
+
+    (x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held,
+     group_start, tile_group, live_tiles, gate, up) = res
+    layout = gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)
+    dt, k, count = x.dtype, weights.shape[1], w_gate.shape[0]
+    with jax.named_scope("moe.combine"):
+        d_out = _rows_of_tokens(d_y.astype(dt), row_pick, row_live, k)
+    with jax.named_scope("moe.dispatch"):
+        xr = _rows_of_tokens(x, row_pick, row_live, k)
+        scale = _row_weights(weights, row_pick, row_live)
+    with jax.named_scope("moe.experts"):
+        act, hidden = _hidden(gate, up, scale)
+        d_w_down = gm.tgmm(hidden, d_out, layout, count).astype(w_down.dtype)
+        d_hidden = gm.gmm(d_out, w_down.astype(dt), layout, transpose_rhs=True).astype(jnp.float32)
+        d_scale = jnp.sum(d_hidden * act, axis=-1)                      # [rows]
+        d_act = d_hidden * scale[:, None]
+        g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
+        sig = jax.nn.sigmoid(g32)
+        d_gate = (d_act * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dt)
+        d_up = (d_act * g32 * sig).astype(dt)
+        d_w_gate = gm.tgmm(xr, d_gate, layout, count).astype(w_gate.dtype)
+        d_w_up = gm.tgmm(xr, d_up, layout, count).astype(w_up.dtype)
+        d_xr = gm.gmm(d_gate, w_gate.astype(dt), layout, transpose_rhs=True) + gm.gmm(
+            d_up, w_up.astype(dt), layout, transpose_rhs=True
+        )
+    with jax.named_scope("moe.dispatch"):
+        d_x = _tokens_of_rows(d_xr, pick_row, pick_held).astype(dt)
+    with jax.named_scope("moe.combine"):
+        d_weights = jnp.where(pick_held, jnp.take(d_scale, pick_row), 0.0).astype(weights.dtype)
+    return (d_x, d_w_gate, d_w_up, d_w_down, d_weights) + (None,) * 7
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Dropless softmax top-k SwiGLU experts, for the experts held here.
+
+    The router scores all ``num_experts``; the ``top_k`` largest
+    probabilities are renormalised to sum to one; ``y = sum over the picks
+    that land on a held expert of w_e * W_down,e (silu(W_gate,e x) * W_up,e
+    x)``.  ``held = (first, count)`` says which experts the parameters hold
+    (absent: all of them, or this device's share of ``expert_axis_name``
+    inside ``shard_map``, where the ``psum`` adds the other devices' picks).
+    The auxiliary loss is Switch's (eq. 4) over all experts and all picks:
+    ``num_experts * sum_e (share of picks on e) * (mean probability of e)``.
+
+    One algorithm for every expert count and top-k; the tile of the grouped
+    product follows the rows an expert can expect.  ``sow``s
+    ``intermediates/picks [T, k]`` (the experts each token chose) and
+    ``intermediates/load [count]`` (picks that landed on each held expert).
+    """
+
+    num_experts: int
+    top_k: int
+    d_ff: int
+    held: Any = None              # (first, count) | None
+    dtype: Any = jnp.bfloat16
+    partition: bool = True
+    expert_axis_name: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """[batch, seq, d] -> ([batch, seq, d], aux_loss)."""
+        from determined_tpu.models.transformer import _maybe_partition
+        from determined_tpu.ops import grouped_matmul as gm
+
+        b, s, d = x.shape
+        tokens, e, k = b * s, self.num_experts, self.top_k
+        if self.expert_axis_name is not None:
+            n = jax.lax.axis_size(self.expert_axis_name)
+            if self.held is not None or e % n:
+                raise ValueError(
+                    f"experts over axis {self.expert_axis_name!r}: {e} experts must "
+                    f"divide by its size {n}, and `held` is the axis's to say"
+                )
+            count = e // n
+            first = jax.lax.axis_index(self.expert_axis_name) * count
+        else:
+            first, count = self.held if self.held is not None else (0, e)
+
+        def param(name, shape, logical):
+            init = _maybe_partition(self.partition, nn.initializers.lecun_normal(), logical)
+            return self.param(name, init, shape, jnp.float32)
+
+        router = param("router", (d, e), ("embed", None))
+        w_gate = param("w_gate", (count, d, self.d_ff), ("expert", "embed", "mlp"))
+        w_up = param("w_up", (count, d, self.d_ff), ("expert", "embed", "mlp"))
+        w_down = param("w_down", (count, self.d_ff, d), ("expert", "mlp", "embed"))
+
+        xf = x.reshape(tokens, d)
+        with jax.named_scope("moe.route"):
+            # routing in float32: a bf16 softmax ties and misroutes tokens
+            probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)   # [T, E]
+            top_p, picks = jax.lax.top_k(probs, k)                              # [T, k]
+            weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            share = jnp.sum(
+                picks.reshape(-1, 1) == jnp.arange(e)[None, :], axis=0, dtype=jnp.float32
+            ) / (tokens * k)
+            aux = e * jnp.sum(share * jnp.mean(probs, axis=0))
+        self.sow("intermediates", "picks", picks)
+
+        with jax.named_scope("moe.dispatch"):
+            local = picks - first
+            pick_held = (local >= 0) & (local < count)
+            key = jnp.where(pick_held, local, count).reshape(-1)               # [T*k]
+            # held picks first, by expert; gathers both ways, no scatter
+            order = jnp.argsort(key, stable=True)                              # sorted place -> pick
+            place = jnp.argsort(order)                                         # pick -> sorted place
+            load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+            # a token picks a held expert at most min(k, count) times
+            max_rows = tokens * min(k, count)
+            tile = min(gm.DEFAULT_TILE, max(8, _round_up_pow2(max_rows // count)))
+            layout = gm.tile_layout(load, max_rows, tile)
+            sorted_start = jnp.cumsum(load) - load                             # [count]
+            group = jnp.minimum(key, count - 1)
+            pick_row = jnp.where(
+                key < count,
+                jnp.take(layout.group_start, group) + place - jnp.take(sorted_start, group),
+                layout.rows - 1,                                               # not held: never read
+            ).astype(jnp.int32).reshape(tokens, k)
+            row = jnp.arange(layout.rows)
+            group = jnp.take(layout.tile_group, row // tile)
+            offset = row - jnp.take(layout.group_start, group)
+            row_live = gm.live_rows_mask(layout) & (offset < jnp.take(load, group))
+            row_pick = jnp.take(
+                order, jnp.clip(jnp.take(sorted_start, group) + offset, 0, tokens * k - 1)
+            ).astype(jnp.int32)
+        self.sow("intermediates", "load", load)
+
+        y = _held_experts(
+            xf.astype(self.dtype), w_gate, w_up, w_down, weights,
+            row_pick, row_live, pick_row, pick_held, *layout,
+        )
+        if self.expert_axis_name is not None:
+            with jax.named_scope("moe.combine"):
+                y = jax.lax.psum(y, self.expert_axis_name)
+        return y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)

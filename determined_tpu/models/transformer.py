@@ -8,7 +8,11 @@ MeshConfig alone, with:
 - logical-axis partitioning on every kernel (embed/heads/kv/mlp/vocab),
   resolved by LogicalAxisRules -> XLA inserts the collectives;
 - activation sharding constraints (batch over dp/fsdp, seq over sp);
-- rotary position embeddings, GQA, RMSNorm, SwiGLU;
+- rotary position embeddings (one base, or parameters per layer type with
+  YaRN), GQA with a stated ``head_dim``, RMSNorm, SwiGLU;
+- a type per layer: full causal attention or a sliding window;
+- experts: the top-2 capacity layer or dropless top-k over the experts a
+  device holds (models/moe.py);
 - attention dispatch: ring attention when the mesh has a "seq" axis,
   Pallas flash attention on TPU otherwise, reference for tiny seqs;
 - bf16 compute with f32 params, per-block remat for long-context memory.
@@ -17,7 +21,8 @@ MeshConfig alone, with:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+import math
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +44,12 @@ from determined_tpu.parallel.sharding import with_sharding_constraint
 from determined_tpu.train._trial import JaxTrial
 
 
+#: the kinds of layer, under the names published configurations give them
+FULL, SLIDING = "full_attention", "sliding_attention"
+LAYER_TYPES = (FULL, SLIDING)
+_YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
@@ -52,12 +63,31 @@ class TransformerConfig:
     attention_impl: str = "auto"              # auto|reference|flash|ring
     remat: bool = False
     rope_theta: float = 10000.0
+    # width of one attention head; None -> d_model // n_heads
+    head_dim: Optional[int] = None
+    # one of LAYER_TYPES per block; None -> full causal attention everywhere.
+    # A sliding layer's query i sees keys i - sliding_window < j <= i.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    # rotary parameters per layer type (the published `rope_parameters`
+    # group): {"full_attention": {"rope_type": "yarn", "rope_theta": ...,
+    # "factor": ..., ...}, "sliding_attention": {"rope_type": "default",
+    # "rope_theta": ...}}.  A type it does not name rotates by rope_theta.
+    rope_parameters: Any = None
     # MoE (models/moe.py): every moe_every-th block swaps its dense MLP
-    # for top-2 expert-parallel experts; 0 = dense everywhere
+    # for experts; 0 = dense everywhere.  moe_top_k == 0 is the top-2
+    # capacity layer (MoE, moe_capacity_factor); moe_top_k > 0 is dropless
+    # top-k over the held experts (RoutedExperts): moe_experts is then the
+    # router's width, moe_experts_held = (first, count) the range this
+    # model's parameters hold (None: all) and moe_intermediate_size an
+    # expert's width (None: d_ff)
     moe_experts: int = 0
     moe_every: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    moe_top_k: int = 0
+    moe_intermediate_size: Optional[int] = None
+    moe_experts_held: Optional[Tuple[int, int]] = None
     # Quantized matmul arithmetic (train/_quant.py): none|int8|fp8 routes
     # every dense/attention projection matmul (and the logits-path
     # lm_head) through per-channel dynamically-scaled reduced-precision
@@ -84,6 +114,43 @@ class TransformerConfig:
                 "moe_every must be >= 1 when moe_experts > 0 "
                 f"(got moe_every={self.moe_every})"
             )
+        setattr_ = lambda k, v: object.__setattr__(self, k, v)  # noqa: E731 (frozen)
+        if self.head_dim is None:
+            setattr_("head_dim", self.d_model // self.n_heads)
+        if self.layer_types is not None:
+            setattr_("layer_types", tuple(self.layer_types))
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types needs one of {LAYER_TYPES} for each of the "
+                    f"{self.n_layers} layers (got {len(self.layer_types)}: {self.layer_types})"
+                )
+            if SLIDING in self.layer_types and not (self.sliding_window or 0) >= 1:
+                raise ValueError("a sliding_attention layer needs sliding_window >= 1")
+        if isinstance(self.rope_parameters, Mapping):
+            # hashable, so that the config can stay a static argument
+            setattr_(
+                "rope_parameters",
+                tuple(sorted((t, tuple(sorted(p.items()))) for t, p in self.rope_parameters.items())),
+            )
+        for layer_type, params in self.rope_parameters or ():
+            if layer_type not in LAYER_TYPES or dict(params).get("rope_type", "default") not in ("default", "yarn"):
+                raise ValueError(f"rope_parameters: unknown layer type or rope_type in {layer_type}: {dict(params)}")
+        if self.moe_top_k:
+            if not 1 <= self.moe_top_k <= self.moe_experts:
+                raise ValueError(
+                    f"moe_top_k={self.moe_top_k} needs 1 <= moe_top_k <= moe_experts ({self.moe_experts})"
+                )
+            if self.moe_experts_held is not None:
+                first, count = (int(v) for v in self.moe_experts_held)
+                setattr_("moe_experts_held", (first, count))
+                if first < 0 or count < 1 or first + count > self.moe_experts:
+                    raise ValueError(
+                        f"moe_experts_held={(first, count)} (first, count) must lie inside "
+                        f"[0, {self.moe_experts})"
+                    )
+        elif self.moe_experts_held is not None or self.moe_intermediate_size is not None:
+            raise ValueError("moe_experts_held and moe_intermediate_size belong to moe_top_k > 0")
 
     @property
     def kv_heads(self) -> int:
@@ -93,17 +160,74 @@ class TransformerConfig:
     def ff_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
 
+    def layer_type(self, i: int) -> str:
+        return FULL if self.layer_types is None else self.layer_types[i]
+
+    def window(self, layer_type: str) -> Optional[int]:
+        return self.sliding_window if layer_type == SLIDING else None
+
+    def rope(self, layer_type: str) -> "Rope":
+        """How a layer of this type rotates q and k."""
+        params = dict(dict(self.rope_parameters or ()).get(layer_type, ()))
+        theta = float(params.get("rope_theta", self.rope_theta))
+        if params.get("rope_type", "default") == "default":
+            return Rope(theta)
+        return Rope(
+            theta,
+            tuple(yarn_inv_freq(self.head_dim, theta, **{k: params[k] for k in _YARN_KEYS})),
+            float(params["attention_factor"]),
+        )
+
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def uses_layer_kinds(self) -> bool:
+        """Whether any layer departs from full attention under one rotary base."""
+        return (self.layer_types is not None and SLIDING in self.layer_types) or bool(self.rope_parameters)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings on [b, h, s, d]."""
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary parameters of one layer type: the base, or stated inverse
+    frequencies (YaRN) with the factor cos and sin are multiplied by."""
+
+    theta: float
+    inv_freq: Optional[Tuple[float, ...]] = None
+    attention_factor: float = 1.0
+
+
+def yarn_inv_freq(
+    head_dim: int, theta: float, *, factor: float, original_max_position_embeddings: int,
+    beta_fast: float, beta_slow: float,
+) -> np.ndarray:
+    """YaRN's inverse frequencies (Peng et al. 2023, as the published
+    configurations compute them), static: applied at every length.  Pair i
+    blends the interpolated frequency ``theta^(-2i/d) / factor`` (slow pairs,
+    past ``high``) with the plain one (fast pairs, before ``low``), where
+    ``corr(n) = d ln(original / (2 pi n)) / (2 ln theta)`` is the pair that
+    turns n times over the original context."""
+    half = head_dim // 2
+    plain = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+
+    def corr(rotations: float) -> float:
+        return head_dim * math.log(original_max_position_embeddings / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope(x: jax.Array, positions: jax.Array, rope: Rope) -> jax.Array:
+    """Rotary embeddings on [b, h, s, d], as a layer type's ``rope`` states
+    them: its base, or its frequencies and the factor on cos and sin."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if rope.inv_freq is not None:
+        freqs = jnp.asarray(rope.inv_freq, jnp.float32)
+    else:
+        freqs = rope.theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [s, d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if rope.attention_factor != 1.0:
+        cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
     x1, x2 = x[..., 0::2], x[..., 1::2]
     rx1 = x1 * cos - x2 * sin
     rx2 = x1 * sin + x2 * cos
@@ -134,6 +258,7 @@ class RMSNorm(nn.Module):
 class Attention(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None  # jax.sharding.Mesh when ring attention is in play
+    layer_type: str = FULL
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -166,8 +291,10 @@ class Attention(nn.Module):
             # manual SPMD inside a pipeline stage: s is the LOCAL shard
             # length; rope positions are global (contiguous assignment)
             positions = positions + jax.lax.axis_index(cfg.seq_axis_name) * s
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        rope = cfg.rope(self.layer_type)
+        q = _rope(q, positions, rope)
+        k = _rope(k, positions, rope)
+        window = cfg.window(self.layer_type)
 
         impl = cfg.attention_impl
         use_ring = (
@@ -178,6 +305,11 @@ class Attention(nn.Module):
                 and self.mesh.shape.get(MeshAxes.SEQUENCE, 1) > 1
             )
         )
+        if window is not None and (cfg.seq_axis_name is not None or use_ring):
+            raise ValueError(
+                "ring attention (a `seq` mesh axis, attention: ring) knows no "
+                f"sliding window: layer type {self.layer_type!r} cannot run under it"
+            )
         if cfg.seq_axis_name is not None:
             # already inside shard_map over the seq axis: run the ring on
             # local shards (zigzag-balanced for causal)
@@ -191,9 +323,11 @@ class Attention(nn.Module):
                 raise ValueError("ring attention requires the mesh")
             out = ring_attention(q, k, v, self.mesh, causal=True)
         else:
-            out = dot_product_attention(
-                q, k, v, causal=True, impl=impl, mesh=self.mesh
-            )
+            # named for the device trace: the two kinds cost differently
+            with jax.named_scope("attn.window" if window is not None else "attn.full"):
+                out = dot_product_attention(
+                    q, k, v, causal=True, impl=impl, mesh=self.mesh, window=window
+                )
         out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
         out = nn.DenseGeneral(
             cfg.d_model,
@@ -245,13 +379,28 @@ class Block(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None
     use_moe: bool = False
+    layer_type: str = FULL
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        x = x + Attention(self.cfg, self.mesh, name="attn")(
+        x = x + Attention(self.cfg, self.mesh, self.layer_type, name="attn")(
             RMSNorm(partition=self.cfg.partition_params, name="ln1")(x)
         )
-        if self.use_moe:
+        if self.use_moe and self.cfg.moe_top_k:
+            from determined_tpu.models.moe import RoutedExperts
+
+            y, aux = RoutedExperts(
+                num_experts=self.cfg.moe_experts,
+                top_k=self.cfg.moe_top_k,
+                d_ff=self.cfg.moe_intermediate_size or self.cfg.ff_dim,
+                held=self.cfg.moe_experts_held,
+                dtype=self.cfg.dtype,
+                partition=self.cfg.partition_params,
+                expert_axis_name=self.cfg.expert_axis_name,
+                name="moe",
+            )(RMSNorm(partition=self.cfg.partition_params, name="ln2")(x))
+            x = x + y
+        elif self.use_moe:
             from determined_tpu.models.moe import MoE
 
             y, aux = MoE(
@@ -309,7 +458,7 @@ class TransformerLM(nn.Module):
             use_moe = (
                 cfg.moe_experts > 0 and (i % cfg.moe_every) == cfg.moe_every - 1
             )
-            x, aux = block_cls(cfg, self.mesh, use_moe, name=f"block_{i}")(x)
+            x, aux = block_cls(cfg, self.mesh, use_moe, cfg.layer_type(i), name=f"block_{i}")(x)
             aux_total = aux_total + aux
         x = RMSNorm(partition=cfg.partition_params, name="ln_f")(x)
         from determined_tpu.train._quant import make_dot_general
@@ -466,8 +615,8 @@ def pipeline_forward(
         expert_axis_name=MeshAxes.EXPERT if exp_n > 1 else None,
     )
 
-    def make_block_step(use_moe: bool):
-        blk = Block(stage_cfg, use_moe=use_moe)
+    def make_block_step(use_moe: bool, layer_type: str):
+        blk = Block(stage_cfg, use_moe=use_moe, layer_type=layer_type)
 
         def block_step(p, h):
             return blk.apply({"params": p}, h)
@@ -476,7 +625,9 @@ def pipeline_forward(
             block_step = jax.checkpoint(block_step, prevent_cse=False)
         return block_step
 
-    steps = [make_block_step(m) for m in has_moe]
+    # layer j of every chunk is one stacked leaf: LMTrial._cfg has checked
+    # that the period of layer_types divides layers-per-chunk
+    steps = [make_block_step(m, cfg.layer_type(j)) for j, m in enumerate(has_moe)]
     want_aux = any(has_moe)
 
     def stage_fn(stage_params, h):
@@ -583,6 +734,12 @@ def _rope_batched(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array
 def _check_decodable(cfg: TransformerConfig) -> None:
     if cfg.moe_experts > 0:
         raise ValueError("KV-cache serving does not support MoE configs yet")
+    if cfg.uses_layer_kinds:
+        raise ValueError(
+            "KV-cache serving runs full attention under one rotary base: it has no "
+            "sliding-window layers (layer_types, sliding_window) and no per-layer-type "
+            "rotary parameters (rope_parameters, YaRN) yet"
+        )
     if cfg.seq_axis_name is not None or cfg.expert_axis_name is not None:
         raise ValueError("KV-cache serving runs outside pipeline stages")
 
@@ -626,8 +783,8 @@ def transformer_prefill(
         blk = params[f"block_{i}"]
         h = _rms_apply(x, blk["ln1"]["scale"])
         q, k, v = _attn_proj(blk["attn"], h, dt)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, Rope(cfg.rope_theta))
+        k = _rope(k, positions, Rope(cfg.rope_theta))
         k_cache = k_cache.at[i, phys, slots].set(_pool_rows(k))
         v_cache = v_cache.at[i, phys, slots].set(_pool_rows(v))
         att = reference_attention(q, k, v, causal=True)
@@ -816,8 +973,8 @@ def transformer_prefill_suffix(
             blk = params[f"block_{i}"]
             h = _rms_apply(x, blk["ln1"]["scale"])
             q, k, v = _attn_proj(blk["attn"], h, dt)  # [b, heads|kv, bs, hd]
-            q = _rope(q, p, cfg.rope_theta)
-            k = _rope(k, p, cfg.rope_theta)
+            q = _rope(q, p, Rope(cfg.rope_theta))
+            k = _rope(k, p, Rope(cfg.rope_theta))
             # write this block's k/v first, then attend through the cache:
             # the block's own causal keys and the cached prefix are read
             # from the same pool, so warm and cold prefills see identical
@@ -863,7 +1020,13 @@ class LMTrial(JaxTrial):
 
     Hyperparameters: lr, global_batch_size, seq_len, vocab_size, d_model,
     n_layers, n_heads, n_kv_heads, d_ff, attention (auto/flash/ring/
-    reference), remat, warmup_steps, dataset_size, pipe_microbatches.
+    reference), remat, warmup_steps, dataset_size, pipe_microbatches;
+    head_dim (where it is not d_model / n_heads), layer_types (one of
+    full_attention / sliding_attention a layer) with sliding_window,
+    rope_theta and rope_parameters (per layer type; YaRN); moe_experts with
+    moe_every, and either moe_capacity_factor (top-2, capacity) or moe_top_k
+    (dropless) with moe_intermediate_size and moe_experts_held = [first,
+    count]; moe_aux_weight.
 
     When the context mesh has a ``pipe`` axis of size P > 1, the trial
     restructures its params into stacked pipeline stages and trains through
@@ -941,16 +1104,31 @@ class LMTrial(JaxTrial):
     def _cfg(self) -> TransformerConfig:
         g = self.context.get_hparam
         pipe = self._pipe_stages()
-        if pipe > 1 and int(g("moe_experts", 0)) > 0:
-            # MoE composes with pipe when every chunk sees the same layer
-            # pattern: the MoE period must divide layers-per-chunk
+        layer_types = g("layer_types", None)
+        if pipe > 1 and (int(g("moe_experts", 0)) > 0 or layer_types):
+            # MoE and layer types compose with pipe when every chunk sees the
+            # same layer pattern: their periods must divide layers-per-chunk
             _, vstages = self._pipe_schedule()
             lps = int(g("n_layers", 2)) // (pipe * vstages)
-            if lps == 0 or lps % int(g("moe_every", 2)):
+            if int(g("moe_experts", 0)) > 0 and (lps == 0 or lps % int(g("moe_every", 2))):
                 raise ValueError(
                     f"pipe={pipe} with MoE needs moe_every ({g('moe_every', 2)}) "
                     f"to divide layers-per-chunk ({lps})"
                 )
+            if layer_types and (lps == 0 or list(layer_types) != list(layer_types[:lps]) * (len(layer_types) // max(lps, 1))):
+                raise ValueError(
+                    f"pipe={pipe} needs the period of layer_types to divide "
+                    f"layers-per-chunk ({lps}): layer j of every chunk is one stacked leaf"
+                )
+        mesh = self.context.mesh
+        if int(g("moe_top_k", 0)) and pipe <= 1 and mesh is not None and mesh.size > 1:
+            raise ValueError(
+                "dropless experts (moe_top_k > 0) train on one device or inside "
+                "pipeline stages (shard_map; each device of the `expert` axis holds "
+                "its share): their grouped product is a Mosaic kernel, which GSPMD "
+                f"cannot partition over a mesh of {mesh.size} devices"
+            )
+        held = g("moe_experts_held", None)
         return TransformerConfig(
             vocab_size=int(g("vocab_size", 2048)),
             d_model=int(g("d_model", 256)),
@@ -966,8 +1144,42 @@ class LMTrial(JaxTrial):
             moe_every=int(g("moe_every", 2)),
             moe_capacity_factor=float(g("moe_capacity_factor", 1.25)),
             moe_aux_weight=float(g("moe_aux_weight", 0.01)),
+            moe_top_k=int(g("moe_top_k", 0)),
+            moe_intermediate_size=g("moe_intermediate_size", None),
+            moe_experts_held=None if held is None else tuple(held),
+            rope_theta=float(g("rope_theta", 10000.0)),
+            head_dim=g("head_dim", None),
+            layer_types=None if layer_types is None else tuple(layer_types),
+            sliding_window=g("sliding_window", None),
+            rope_parameters=g("rope_parameters", None),
             quantized_matmul=self._quant_mode(),
         )
+
+    #: step metrics the Trainer also pushes as tracer counters at each report
+    #: (train/_trainer.py): what the dropless expert layers saw
+    step_counters = (
+        "moe.held_picks", "moe.picks", "moe.expert_load_max", "moe.expert_load_mean",
+        "moe_aux_loss",
+    )
+
+    @staticmethod
+    def _apply(model: TransformerLM, params: Any, inputs: jax.Array, **kw: Any) -> Tuple[Any, Dict[str, jax.Array]]:
+        """``model.apply`` and, where the model has dropless experts, a
+        step's expert load from what the layers ``sow`` (no second forward):
+        picks that landed on a held expert, all picks, and the fullest held
+        expert against the mean, over the layers."""
+        if not model.cfg.moe_top_k:
+            return model.apply(params, inputs, **kw), {}
+        out, state = model.apply(params, inputs, mutable=["intermediates"], **kw)
+        sown = jax.tree_util.tree_leaves_with_path(state["intermediates"])
+        load = jnp.stack([x for path, x in sown if "load" in jax.tree_util.keystr(path)]).astype(jnp.float32)
+        picks = load.shape[0] * inputs.size * model.cfg.moe_top_k
+        return out, {
+            "moe.held_picks": jnp.sum(load),
+            "moe.picks": jnp.asarray(picks, jnp.float32),
+            "moe.expert_load_max": jnp.max(load),
+            "moe.expert_load_mean": jnp.mean(load),
+        }
 
     @property
     def tokens_per_sample(self) -> int:
@@ -979,11 +1191,24 @@ class LMTrial(JaxTrial):
     def flops_per_token(self) -> float:
         """Fwd+bwd matmul FLOPs per token by the standard 6N + attention
         convention (same accounting as bench.py), for the ledger's MFU
-        estimate."""
+        estimate: N counts the projections at the stated head_dim, a gated
+        MLP or a token's active experts (its expected picks among the held
+        ones) with the router, and the head; attention counts the keys a
+        layer's queries can see (the window of a sliding layer)."""
         cfg = self._cfg()
-        d, L, V = cfg.d_model, cfg.n_layers, cfg.vocab_size
-        n_params = L * (4 * d * d + 12 * d * d) + V * d
-        return float(6 * n_params + 12 * L * cfg.max_seq_len * d)
+        d, width = cfg.d_model, cfg.n_heads * cfg.head_dim
+        attn = d * cfg.head_dim * (2 * cfg.n_heads + 2 * cfg.kv_heads)
+        n_params, seen = cfg.vocab_size * d, 0
+        for i in range(cfg.n_layers):
+            n_params += attn
+            if cfg.moe_experts > 0 and (i % cfg.moe_every) == cfg.moe_every - 1:
+                held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
+                active = cfg.moe_top_k * held / cfg.moe_experts if cfg.moe_top_k else 2
+                n_params += d * cfg.moe_experts + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)
+            else:
+                n_params += 3 * d * cfg.ff_dim
+            seen += min(cfg.window(cfg.layer_type(i)) or cfg.max_seq_len, cfg.max_seq_len)
+        return float(6 * n_params + 12 * seen * width)
 
     def build_model(self) -> TransformerLM:
         return TransformerLM(self._cfg(), mesh=self.context.mesh)
@@ -1101,8 +1326,8 @@ class LMTrial(JaxTrial):
 
             from determined_tpu.ops.cross_entropy import fused_cross_entropy
 
-            hidden, moe_aux = model.apply(
-                params, inputs, return_hidden=True, return_aux=True
+            (hidden, moe_aux), moe_load = self._apply(
+                model, params, inputs, return_hidden=True, return_aux=True
             )
             kernel = flax_meta.unbox(params["params"]["lm_head"]["kernel"])
             chunk = g("ce_chunk", None)
@@ -1117,9 +1342,9 @@ class LMTrial(JaxTrial):
                 bf16_residual=bool(g("ce_bf16_residual", False)),
             )
         else:
-            logits, moe_aux = model.apply(params, inputs, return_aux=True)
+            (logits, moe_aux), moe_load = self._apply(model, params, inputs, return_aux=True)
             loss = optax.softmax_cross_entropy_with_integer_labels(logits, targets).mean()
-        metrics = {"perplexity": jnp.exp(loss)}
+        metrics = {"perplexity": jnp.exp(loss), **moe_load}
         if model.cfg.moe_experts > 0:
             metrics["moe_aux_loss"] = moe_aux
             loss = loss + model.cfg.moe_aux_weight * moe_aux

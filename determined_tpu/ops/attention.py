@@ -46,12 +46,16 @@ def reference_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     q_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain softmax attention; the semantics every other impl must match.
 
     ``q_offset``: global position of q[0] relative to k[0] (used by ring
-    attention shards and KV-cache decoding).
+    attention shards and KV-cache decoding).  ``window`` (causal only):
+    query i sees keys ``i - window < j <= i``.
     """
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     *_, q_len, head_dim = q.shape
     kv_len = k.shape[-2]
     n_rep = q.shape[-3] // k.shape[-3]
@@ -63,7 +67,10 @@ def reference_attention(
     if causal:
         q_pos = q_offset + jnp.arange(q_len)[:, None]
         k_pos = jnp.arange(kv_len)[None, :]
-        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen &= q_pos - k_pos < window
+        logits = jnp.where(seen, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -77,8 +84,10 @@ def dot_product_attention(
     impl: str = "auto",
     scale: Optional[float] = None,
     mesh: Optional[Mesh] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dispatcher: 'auto' picks flash on TPU for seqs worth tiling.
+    ``window`` is honoured by every implementation it can pick.
 
     ``mesh``: the mesh the (global) operands are sharded over.  With more
     than one device the flash kernel runs per device inside ``shard_map``
@@ -89,13 +98,15 @@ def dot_product_attention(
         on_tpu = jax.default_backend() == "tpu"
         impl = "flash" if on_tpu and q.shape[-2] >= 256 else "reference"
     if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, scale=scale)
+        return reference_attention(q, k, v, causal=causal, scale=scale, window=window)
     if impl == "flash":
         from determined_tpu.ops.flash_attention import flash_attention
 
         if mesh is not None and mesh.size > 1:
-            return sharded_flash_attention(q, k, v, mesh, causal=causal, scale=scale)
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+            return sharded_flash_attention(
+                q, k, v, mesh, causal=causal, scale=scale, window=window
+            )
+        return flash_attention(q, k, v, causal=causal, scale=scale, window=window)
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
@@ -107,6 +118,7 @@ def sharded_flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention over global arrays on a multi-device mesh.
 
@@ -135,7 +147,7 @@ def sharded_flash_attention(
         k, v = _repeat_kv(k, n_rep), _repeat_kv(v, n_rep)
     spec = P(batch_axes or None, head_axis, None, None)
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal, scale=scale),
+        functools.partial(flash_attention, causal=causal, scale=scale, window=window),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

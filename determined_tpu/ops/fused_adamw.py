@@ -90,16 +90,32 @@ def _adamw_kernel(b1, b2, eps, wd, scal_ref, p_ref, m_ref, v_ref, g_ref,
     vo_ref[...] = v
 
 
+def _plan_matrix_rows(shape, budget):
+    """The plan of a stack of matrices whose last dim halves to no multiple
+    of 128 (experts: [held, 2304, 896]): a matrix at a time, its rows split
+    and its last dim whole.  None (the jnp sweep) where that does not fit."""
+    if len(shape) != 3:
+        return None
+    d0, mid, dk = shape
+    rows = max(
+        (r for r in range(8, mid + 1, 8) if mid % r == 0 and r * dk <= budget),
+        default=None,
+    )
+    return None if rows is None else ((d0, mid // rows), (1, rows, dk), 1)
+
+
 def _plan_blocks(shape):
-    """(grid, block) tiling a leaf IN ITS NATIVE SHAPE, or None to fall
-    back to jnp.  Native-shape blocks are the point: flatten/reshape
+    """(grid, block, axis) tiling a leaf IN ITS NATIVE SHAPE, or None to fall
+    back to jnp: grid index 0 walks dim 0 and grid index 1 walks ``axis``.  Native-shape blocks are the point: flatten/reshape
     changes the TPU tiled layout and XLA then physically copies every
     operand around the kernel — the flattened first cut of this kernel
     measured ~3x slower than optax purely from those copies.
 
     2D leaves tile both dims (wide lm_head/vocab arrays need a column
     split to keep >=8 rows per block); 3D+ leaves keep trailing dims whole
-    and split the leading dim.  All dims here are powers of two.
+    and split the leading dim; a stack of matrices whose ONE matrix is past
+    the budget (experts: [held, d_model, d_ff]) is swept a matrix at a time,
+    its rows split and its last dim whole.
     """
     budget = _BLOCK_BYTES // 4  # f32 elements per ref
     d0, dk = shape[0], shape[-1]
@@ -113,22 +129,24 @@ def _plan_blocks(shape):
     while bc % 2 == 0 and bc > 128 and br_min * mid * bc > budget:
         bc //= 2
     if bc != dk and bc % 128:
-        return None
+        return _plan_matrix_rows(shape, budget)
     br = br_min
     while br * 2 * mid * bc <= budget and d0 % (br * 2) == 0:
         br *= 2
     if br * mid * bc > budget:
-        return None  # middle dims alone exceed the budget: jnp fallback
-    return (d0 // br, dk // bc), (br,) + tuple(shape[1:-1]) + (bc,)
+        # middle dims alone exceed the budget
+        return _plan_matrix_rows(shape, budget)
+    return (d0 // br, dk // bc), (br,) + tuple(shape[1:-1]) + (bc,), len(shape) - 1
 
 
 def _leaf_pallas(p, m, v, g, scalars, *, b1, b2, eps, wd):
     """One fused sweep over a large leaf in its native shape."""
     from jax.experimental import pallas as pl
 
-    grid, block = _plan_blocks(p.shape)
-    zeros = (0,) * (p.ndim - 2)
-    index_map = lambda i, j: (i,) + zeros + (j,)  # noqa: E731
+    grid, block, axis = _plan_blocks(p.shape)
+    index_map = lambda i, j: tuple(  # noqa: E731
+        i if d == 0 else j if d == axis else 0 for d in range(p.ndim)
+    )
     scal_map = lambda i, j: (0, 0)  # noqa: E731
     bspec = lambda: pl.BlockSpec(block, index_map)  # noqa: E731
     po, mo, vo = pl.pallas_call(

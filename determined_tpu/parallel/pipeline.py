@@ -73,7 +73,8 @@ from determined_tpu.parallel.mesh import MeshAxes
 
 # MoE expert-weight param names: leading dim (after the stage stack) is the
 # expert dim, shardable over the expert mesh axis.
-_EXPERT_PARAM_NAMES = frozenset({"w_in", "w_gate", "w_out"})
+# MoE's (w_in, w_gate, w_out) and RoutedExperts' (w_gate, w_up, w_down)
+_EXPERT_PARAM_NAMES = frozenset({"w_in", "w_gate", "w_out", "w_up", "w_down"})
 
 
 def _path_has_expert_leaf(path) -> bool:

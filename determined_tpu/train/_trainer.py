@@ -1299,6 +1299,13 @@ class Trainer:
                             "train.tokens",
                             float(steps_since_report * gbs * self._tokens_per_sample),
                         )
+                    # step metrics the trial wants on the trace's timeline
+                    # (an expert layer's load): the period's mean x its steps,
+                    # so that sums over any stretch divide by train.steps.
+                    # From the metrics just fetched: no fetch of their own.
+                    for name in getattr(self.trial, "step_counters", ()):
+                        if name in metrics:
+                            tracer.counter(name, metrics[name] * steps_since_report)
                     if self._comm_model is not None:
                         # step.comm ledger rows (observability/_goodput.py):
                         # measured payload bytes, exposed/hidden split from

@@ -33,7 +33,7 @@ import logging
 import os
 import re
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger("determined_tpu.utils.compilation_cache")
 
@@ -137,6 +137,25 @@ def program_facts(hlo_text: str) -> Dict[str, int]:
     return facts
 
 
+#: a `jax.named_scope` of this repo: dotted, as its spans are (`moe.route`, `attn.window`)
+_SCOPE_RE = re.compile(r"^[a-z_][a-z0-9_]*(\.[a-z0-9_]+)+$")
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*metadata=\{[^}]*op_name=\"([^\"]*)\"", re.M)
+
+
+def program_scopes(hlo_text: str) -> Dict[str, List[str]]:
+    """``jax.named_scope`` name -> the optimized module's instructions whose
+    ``op_name`` passes through it.  A device trace names an operation by its
+    instruction and keeps no scope, so this table is what lets a reader of a
+    trace say which part of a jitted step an operation belongs to (a fusion
+    belongs to the scope of the instruction it is named after)."""
+    scopes: Dict[str, List[str]] = {}
+    for name, op_name in _INSTRUCTION_RE.findall(hlo_text):
+        for part in set(op_name.split("/")):
+            if _SCOPE_RE.match(part):
+                scopes.setdefault(part, []).append(name)
+    return scopes
+
+
 def timed_first_call(fn: Any, label: str) -> Any:
     """Wrap a jitted callable so its FIRST invocation — the one that pays
     trace + compile — is recorded as a ``compile`` span and a
@@ -149,7 +168,9 @@ def timed_first_call(fn: Any, label: str) -> Any:
     that follows runs that same executable instead of compiling again.
     The wrapper's ``temp_bytes`` is then the scratch memory the program
     reserves on each device while it runs (0 until the first call) — what
-    the allocator's own statistics do not count.
+    the allocator's own statistics do not count.  While the tracer is on,
+    the program's named scopes go to it as one ``jit.scopes`` instant
+    (``program_scopes``: scope -> instruction names).
     """
     done = [False]
 
@@ -164,7 +185,12 @@ def timed_first_call(fn: Any, label: str) -> Any:
         try:
             if hasattr(fn, "lower"):
                 compiled = fn.lower(*args, **kwargs).compile()
-                facts = program_facts(compiled.as_text())
+                text = compiled.as_text()
+                facts = program_facts(text)
+                if get_tracer().enabled:
+                    scopes = program_scopes(text)
+                    if scopes:
+                        get_tracer().instant("jit.scopes", cat="compile", program=label, scopes=scopes)
                 wrapped.temp_bytes = int(
                     getattr(compiled.memory_analysis(), "temp_size_in_bytes", 0)
                 )

@@ -39,6 +39,7 @@ from determined_tpu.parallel.mesh import MeshConfig, make_mesh
 flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")
 adamw_mod = importlib.import_module("determined_tpu.ops.fused_adamw")
 paged_mod = importlib.import_module("determined_tpu.ops.paged_attention")
+grouped_mod = importlib.import_module("determined_tpu.ops.grouped_matmul")
 
 TOPOLOGY = "v5e:2x2"
 
@@ -63,6 +64,7 @@ def _real_kernels_no_cache(monkeypatch):
 
     monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
     monkeypatch.setattr(adamw_mod, "_interpret", lambda: False)
+    monkeypatch.setattr(grouped_mod, "_interpret", lambda: False)
     monkeypatch.setattr(paged_mod, "_on_tpu", lambda: True)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -105,6 +107,46 @@ def test_flash_fwd_bwd_compiles_on_one_chip(tpu_devices, shape):
     grad = jax.grad(functools.partial(_attn_loss, None), argnums=(0, 1, 2))
     text = _compile(grad, *_qkv(shape, one))
     assert _kernels(text) == 3  # fwd, dq, dkv
+
+
+def test_flash_with_a_window_compiles_at_the_mellum_cells_shape(tpu_devices):
+    """One 8,192-token sequence, 32 heads of 128 over 4 KV heads, window
+    1,024 (benchmark/configs/mellum2-12b-a2.5b-l4-ep4.json): three kernels
+    with names of their own, and the kernels without a window keep theirs."""
+    one = SingleDeviceSharding(tpu_devices[0])
+
+    def loss(window, q, k, v):
+        out = dot_product_attention(q, k, v, causal=True, impl="flash", window=window)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    avals = _qkv((1, 32, 8192, 128), one, kv_heads=4)
+    text = _compile(jax.grad(functools.partial(loss, 1024), argnums=(0, 1, 2)), *avals)
+    assert _kernels(text) == 3 and all(f"flash_window_{k}" in text for k in ("fwd", "dq", "dkv"))
+    plain = _compile(jax.grad(functools.partial(loss, None), argnums=(0, 1, 2)), *avals)
+    assert _kernels(plain) == 3 and "flash_window" not in plain
+
+
+@pytest.mark.parametrize("tile", [256, 512], ids=["tile256", "tile512"])
+def test_grouped_expert_products_compile_at_the_mellum_cells_shape(tpu_devices, tile):
+    """16 held experts of 2304 x 896, the worst-case buffer of one 8,192-token
+    sequence's picks (8 a token): the product, its transpose and the gradient
+    to the matrices (a [2304, 896] float32 block resident in VMEM)."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    rows = grouped_mod.buffer_rows(8192 * 8, 16, tile)
+
+    def fn(x, g, w, sizes):
+        layout = grouped_mod.tile_layout(sizes, 8192 * 8, tile)
+        wb = w.astype(x.dtype)
+        return (grouped_mod.gmm(x, wb, layout), grouped_mod.gmm(g, wb, layout, transpose_rhs=True),
+                grouped_mod.tgmm(x, g, layout, 16))
+
+    text = _compile(
+        fn, jax.ShapeDtypeStruct((rows, 2304), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((rows, 896), jnp.bfloat16, sharding=one),
+        jax.ShapeDtypeStruct((16, 2304, 896), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one),
+    )
+    assert _kernels(text) == 3 and "moe_gmm" in text and "moe_tgmm" in text
 
 
 @pytest.mark.parametrize(
@@ -173,6 +215,8 @@ def test_a_kernel_compiles_to_the_same_program_whoever_calls_it(tpu_devices):
 LEAF_SHAPES = [
     (32768, 2048), (2048, 32768), (2048, 8192), (8192, 2048),
     (2048, 16, 128), (16, 128, 2048),
+    # stacks of expert matrices: one matrix is past a block's budget, and 896 halves to no multiple of 128
+    (16, 2304, 896), (16, 896, 2304),
 ]
 
 
