@@ -90,22 +90,56 @@ def _named(fn: Any, name: str) -> Any:
     return fn
 
 
+#: entries a block sum covers in ``sample_token``
+_SAMPLE_BLOCK = 128
+
+
 def sample_token(logits: np.ndarray, temperature: float, rng: Any) -> int:
     """Sample one token from f32 logits [vocab]: greedy at temperature 0,
-    softmax sampling otherwise.  Shared by the serving engines and the
-    full-forward oracle in the parity tests, so 'sampling matches' reduces
-    to 'logits match'."""
+    otherwise one draw from the exact categorical distribution
+    ``softmax(logits / temperature)`` over the whole vocabulary.  Shared by
+    the serving engines and the full-forward oracle in the parity tests, so
+    'sampling matches' reduces to 'logits match'.
+
+    One float32 pass (a fresh array: ``logits`` is a view into the step's
+    logits and is not written to), sums of ``_SAMPLE_BLOCK`` entries
+    accumulated in float64, and exactly ONE ``rng.random()``, searched first
+    over the blocks' cumulative sum and then inside the block it lands in:
+    the inverse-CDF draw ``Generator.choice(p=...)`` makes, without its
+    float64 passes over every entry."""
     if temperature <= 0.0:
         return int(np.argmax(logits))
-    z = logits.astype(np.float64) / float(temperature)
-    z -= z.max()
-    p = np.exp(z)
-    total = p.sum()
+    with np.errstate(invalid="ignore", over="ignore"):
+        # the maximum comes off BEFORE the scaling: a difference of nearby
+        # float32 values is exact, so the likely tokens round at the size of
+        # their distance from the maximum, not at the size of the logits
+        z = np.asarray(logits, dtype=np.float32)
+        z = z - z.max()
+        z *= np.float32(1.0 / temperature)
+        np.exp(z, out=z)
+    # one sum a block, the last one short where the vocabulary is no multiple
+    starts = np.arange(0, z.size, _SAMPLE_BLOCK)
+    cum = np.cumsum(np.add.reduceat(z, starts, dtype=np.float64))
+    total = cum[-1]
     if not np.isfinite(total) or total <= 0.0:
         # NaN/inf logits (a numerically degenerate model) must degrade to
         # a bad TOKEN, not a ValueError that kills the scheduler loop
         return int(np.argmax(np.nan_to_num(logits, nan=-np.inf)))
-    return int(rng.choice(len(p), p=p / total))
+    u = rng.random() * total
+    block = _first_above(cum, u)
+    if block:
+        u -= cum[block - 1]
+    start = block * _SAMPLE_BLOCK
+    inner = np.cumsum(z[start : start + _SAMPLE_BLOCK], dtype=np.float64)
+    return start + _first_above(inner, u)
+
+
+def _first_above(cum: np.ndarray, u: float) -> int:
+    """First index whose cumulative sum exceeds ``u``.  ``u`` is held just
+    below the last sum, so one that rounding put at or past the end picks
+    the last entry that adds anything, never an index out of range or an
+    entry of probability zero."""
+    return int(np.searchsorted(cum, min(u, np.nextafter(cum[-1], -np.inf)), side="right"))
 
 
 class DecodeKernels:
