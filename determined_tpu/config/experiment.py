@@ -608,8 +608,8 @@ class ObservabilityConfig:
 
     ``enabled``: record spans/counters from every subsystem (trainer loop,
     prefetch workers, scheduler, journal, checkpoint writers, restarts)
-    into per-thread ring buffers — lock-free, non-blocking, <2% step-time
-    overhead (the DTPU_BENCH_TRACE A/B).  ``trace_export``: additionally
+    into per-thread ring buffers — lock-free, non-blocking (its cost on the
+    chip: ``PERF.md`` section 6).  ``trace_export``: additionally
     stream the events as Chrome trace JSON under
     ``checkpoint_dir/traces/`` (Perfetto-loadable; feeds
     ``dtpu experiment profile``).  ``ring_capacity``: events buffered per

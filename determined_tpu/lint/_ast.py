@@ -470,8 +470,8 @@ def analyze_paths(
 
     Per-module rules run file by file; the program-level concurrency pass
     runs once over the union, so a lock bound in one target and acquired
-    under another target's lock still forms a graph edge (``scripts/`` and
-    ``bench.py`` import ``determined_tpu`` — their lock use belongs in the
+    under another target's lock still forms a graph edge (``scripts/``
+    imports ``determined_tpu`` — its lock use belongs in the
     package's graph, which is why ``scripts/lint.sh`` passes every target
     in a single invocation).
 

@@ -521,8 +521,8 @@ class CollectiveSequenceSentinel:
 
     The exchange rides the collective that was already happening, so the
     sentinel adds no extra round trips; overhead per collective is one
-    crc32 of a short string plus a small dict (``DTPU_BENCH_SENTINEL=1``
-    in ``bench.py`` tracks the number).  Divergences where one rank calls
+    crc32 of a short string plus a small dict
+    (``scripts/bench_sentinel.py`` tracks the number).  Divergences where one rank calls
     a DIFFERENT op on a compatible transport (allgather vs barrier, the
     common wrong-branch case) are caught in-band; a rank that issues NO
     collective still parks its peers until the control-plane deadline,

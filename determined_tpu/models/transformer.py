@@ -218,16 +218,20 @@ def yarn_inv_freq(
 
 def _rope(x: jax.Array, positions: jax.Array, rope: Rope) -> jax.Array:
     """Rotary embeddings on [b, h, s, d], as a layer type's ``rope`` states
-    them: its base, or its frequencies and the factor on cos and sin."""
+    them: its base, or its frequencies and the factor on cos and sin.
+    ``positions`` is [s] where every row of the batch sits at the same ones,
+    or [b, s] where each row has its own (the decode step's lanes)."""
     d = x.shape[-1]
     if rope.inv_freq is not None:
         freqs = jnp.asarray(rope.inv_freq, jnp.float32)
     else:
         freqs = rope.theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [s, d/2]
+    angles = positions[..., None].astype(jnp.float32) * freqs[None, :]  # [(b,) s, d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     if rope.attention_factor != 1.0:
         cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
+    if positions.ndim == 2:  # [b, s, d/2] against x's [b, h, s, d/2]
+        cos, sin = cos[:, None], sin[:, None]
     x1, x2 = x[..., 0::2], x[..., 1::2]
     rx1 = x1 * cos - x2 * sin
     rx2 = x1 * sin + x2 * cos
@@ -680,15 +684,11 @@ def pipeline_forward(
 # the scatter shape static without masking arithmetic inside the kernel.
 
 
-def kv_cache_shape(
-    cfg: TransformerConfig, num_blocks: int, block_size: int
-) -> Tuple[int, ...]:
+def kv_cache_shape(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Tuple[int, ...]:
     return (cfg.n_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
 
 
-def init_kv_cache(
-    cfg: TransformerConfig, num_blocks: int, block_size: int
-) -> Dict[str, jax.Array]:
+def init_kv_cache(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Dict[str, jax.Array]:
     """Zeroed paged K/V pool in the model's compute dtype (keys are stored
     post-rope, i.e. exactly what attention consumes)."""
     shape = kv_cache_shape(cfg, num_blocks, block_size)
@@ -701,9 +701,7 @@ def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
 
 
-def _attn_proj(
-    p: Dict[str, Any], x: jax.Array, dtype: Any
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+def _attn_proj(p: Dict[str, Any], x: jax.Array, dtype: Any) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q/k/v projections as ``Attention`` computes them, to [b, heads, s, d]."""
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"]["kernel"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"]["kernel"].astype(dtype))
@@ -715,20 +713,6 @@ def _mlp_apply(p: Dict[str, Any], x: jax.Array, dtype: Any) -> jax.Array:
     gate = x @ p["w_gate"]["kernel"].astype(dtype)
     up = x @ p["w_up"]["kernel"].astype(dtype)
     return (nn.silu(gate) * up) @ p["w_down"]["kernel"].astype(dtype)
-
-
-def _rope_batched(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embeddings on [b, h, 1, d] with a per-sequence position [b]
-    (the decode step: every lane sits at its own offset)."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [b, d/2]
-    cos = jnp.cos(angles)[:, None, None, :]
-    sin = jnp.sin(angles)[:, None, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rx1 = x1 * cos - x2 * sin
-    rx2 = x1 * sin + x2 * cos
-    return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def _check_decodable(cfg: TransformerConfig) -> None:
@@ -744,13 +728,103 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         raise ValueError("KV-cache serving runs outside pipeline stages")
 
 
+def _pool_rows(x: jax.Array, lead: Tuple[int, ...]) -> jax.Array:
+    """Projected k or v ``[b, kv_heads, s, head_dim]`` as the pool stores a
+    token, ``[*lead, kv_heads * head_dim]``: ``lead`` is (b, s), or (b,) where s is 1."""
+    return x.transpose(0, 2, 1, 3).reshape(*lead, x.shape[1] * x.shape[3])
+
+
+def _gather_table(cfg: TransformerConfig, pool: jax.Array, layer: int, block_tables: jax.Array) -> jax.Array:
+    """Every token of every table column of one layer, the KV heads
+    repeated: ``[b, n_heads, T * block_size, head_dim]``."""
+    b, t = block_tables.shape
+    rows = pool[layer, block_tables].reshape(b, t * pool.shape[2], cfg.kv_heads, -1)
+    return _repeat_kv(rows.transpose(0, 2, 1, 3), cfg.n_heads // cfg.kv_heads)
+
+
+def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Array:
+    """Embedding rows of ``tokens`` in the compute dtype."""
+    return jnp.take(params["embed"]["embedding"].astype(dtype), tokens, axis=0)
+
+
+def _head(params: Dict[str, Any], x: jax.Array, dtype: Any, row: Optional[int] = None) -> jax.Array:
+    """Final norm and ``lm_head``: float32 logits at every position of ``x``, or at ``row`` alone."""
+    x = _rms_apply(x, params["ln_f"]["scale"])
+    x = x if row is None else x[:, row, :]
+    return (x @ params["lm_head"]["kernel"].astype(dtype)).astype(jnp.float32)
+
+
+# The attention backends of the serving layer: ``attend(q, k, v, cache, i)`` with
+# q [b, n_heads, s, head_dim], this call's own k, v [b, kv_heads, s, head_dim]
+# and the pool that already holds them; returns [b, n_heads, s, head_dim].  An
+# entry point picks one before its layer loop.
+
+
+def _attend_local(q, k, v, cache, i):
+    """Causal, over this call's own keys: the wide prefill's prompts start at position 0."""
+    return reference_attention(q, k, v, causal=True)
+
+
+def _attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array):
+    """One query a lane against the lane's live blocks, read where they lie
+    in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle."""
+
+    def attend(q, k, v, cache, i):
+        att = paged_decode_attention(
+            q[:, :, 0, :], cache["k"], cache["v"], i, block_tables, positions, scale=cfg.head_dim ** -0.5
+        )
+        return att.astype(cfg.dtype)[:, :, None, :]
+
+    return attend
+
+
+def _attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
+    """Queries against every token of every table column, gathered from the
+    pool, under ``mask`` (True = may see: ``[s, T * block_size]`` where the
+    lanes are alike, else ``[b, s, T * block_size]``) and a float32 softmax:
+    the suffix prefill's read, and the oracle the paged path is tested against."""
+
+    def attend(q, k, v, cache, i):
+        keys = _gather_table(cfg, cache["k"], i, block_tables)
+        vals = _gather_table(cfg, cache["v"], i, block_tables)
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32)
+        logits = logits * cfg.head_dim ** -0.5
+        seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+        probs = jax.nn.softmax(jnp.where(seen, logits, NEG_INF), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
+
+    return attend
+
+
+def _serve_layer(cfg, i, blk, x, positions, write, attend, cache):
+    """Layer ``i`` of the serving forward, stated once under the three entry
+    points below: norm, q/k/v, rope at ``positions`` ([s], or [b, s]), this
+    call's K,V rows into the pool at ``write`` = (physical block, slot), each
+    [b, s] (or [b] where s is 1), then ``attend`` against the updated pool, so
+    that a token sees its own key, then ``wo``, the MLP and both residuals."""
+    dt = cfg.dtype
+    rope = cfg.rope(cfg.layer_type(i))
+    q, k, v = _attn_proj(blk["attn"], _rms_apply(x, blk["ln1"]["scale"]), dt)
+    q, k = _rope(q, positions, rope), _rope(k, positions, rope)
+    phys, slots = write
+    cache = {
+        "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
+        "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
+    }
+    att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
+    x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
+    return x + _mlp_apply(blk["mlp"], _rms_apply(x, blk["ln2"]["scale"]), dt), cache
+
+
+def _serve_layers(cfg, params, x, positions, write, attend, cache):
+    for i in range(cfg.n_layers):
+        x, cache = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache)
+    return x, cache
+
+
 def transformer_prefill(
-    cfg: TransformerConfig,
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    prompt_lens: jax.Array,
-    block_tables: jax.Array,
-    cache: Dict[str, jax.Array],
+    cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, prompt_lens: jax.Array,
+    block_tables: jax.Array, cache: Dict[str, jax.Array],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Full-prompt forward that also populates the paged cache.
 
@@ -765,66 +839,24 @@ def transformer_prefill(
     _check_decodable(cfg)
     block_size = cache["k"].shape[2]
     b, s = tokens.shape
-    dt = cfg.dtype
-    x = jnp.take(params["embed"]["embedding"].astype(dt), tokens, axis=0)
+    x = _embed_rows(params, tokens, cfg.dtype)
     positions = jnp.arange(s)
     # physical destination of every (lane, position): padded tail -> scratch
     phys = jnp.where(
         positions[None, :] < prompt_lens[:, None],
         jnp.take_along_axis(
-            block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)),
-            axis=1,
+            block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)), axis=1
         ),
         0,
     )
     slots = jnp.broadcast_to((positions % block_size)[None, :], (b, s))
-    k_cache, v_cache = cache["k"], cache["v"]
-    for i in range(cfg.n_layers):
-        blk = params[f"block_{i}"]
-        h = _rms_apply(x, blk["ln1"]["scale"])
-        q, k, v = _attn_proj(blk["attn"], h, dt)
-        q = _rope(q, positions, Rope(cfg.rope_theta))
-        k = _rope(k, positions, Rope(cfg.rope_theta))
-        k_cache = k_cache.at[i, phys, slots].set(_pool_rows(k))
-        v_cache = v_cache.at[i, phys, slots].set(_pool_rows(v))
-        att = reference_attention(q, k, v, causal=True)
-        att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
-        x = x + jnp.einsum(
-            "bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt)
-        )
-        x = x + _mlp_apply(blk["mlp"], _rms_apply(x, blk["ln2"]["scale"]), dt)
-    x = _rms_apply(x, params["ln_f"]["scale"])
-    logits = (x @ params["lm_head"]["kernel"].astype(dt)).astype(jnp.float32)
-    return logits, {"k": k_cache, "v": v_cache}
-
-
-def _pool_rows(x: jax.Array) -> jax.Array:
-    """Projected k or v ``[b, kv_heads, s, head_dim]`` as the pool stores a
-    token: ``[b, s, kv_heads * head_dim]``."""
-    b, kv_heads, s, head_dim = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b, s, kv_heads * head_dim)
-
-
-def _gather_table(
-    pool: jax.Array, layer: int, block_tables: jax.Array, kv_heads: int, n_rep: int
-) -> jax.Array:
-    """Every token of every table column of one layer, ``[b, n_heads,
-    T * block_size, head_dim]`` with the KV heads repeated: the full-table
-    read of the suffix prefill and of ``chunk_blocks=0`` decode."""
-    b, t = block_tables.shape
-    rows = pool[layer, block_tables].reshape(b, t * pool.shape[2], kv_heads, -1)
-    return _repeat_kv(rows.transpose(0, 2, 1, 3), n_rep)
+    x, cache = _serve_layers(cfg, params, x, positions, (phys, slots), _attend_local, cache)
+    return _head(params, x, cfg.dtype), cache
 
 
 def transformer_decode(
-    cfg: TransformerConfig,
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    positions: jax.Array,
-    block_tables: jax.Array,
-    cache: Dict[str, jax.Array],
-    *,
-    chunk_blocks: int = 0,
+    cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, positions: jax.Array,
+    block_tables: jax.Array, cache: Dict[str, jax.Array], *, chunk_blocks: int = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step over the paged cache for every lane at once.
 
@@ -850,72 +882,27 @@ def transformer_decode(
     _check_decodable(cfg)
     block_size = cache["k"].shape[2]
     t = block_tables.shape[1]
-    kv_len = t * block_size
-    dt = cfg.dtype
+    if chunk_blocks and t % chunk_blocks:
+        raise ValueError(f"chunk_blocks={chunk_blocks} must divide the table width {t}")
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
-    x = jnp.take(params["embed"]["embedding"].astype(dt), tokens[:, None], axis=0)
+    x = _embed_rows(params, tokens[:, None], cfg.dtype)
     phys = jnp.where(
-        active,
-        jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0],
-        0,
+        active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
     )
-    slot = pos % block_size
-    k_pos = jnp.arange(kv_len)
-    # attend to every cache position up to and including the current token
-    mask = (k_pos[None, :] <= pos[:, None]) & active[:, None]  # [B, kv_len]
-    k_cache, v_cache = cache["k"], cache["v"]
-    n_rep = cfg.n_heads // cfg.kv_heads
-    scale = cfg.head_dim ** -0.5
-    if chunk_blocks and t % chunk_blocks:
-        raise ValueError(
-            f"chunk_blocks={chunk_blocks} must divide the table width {t}"
-        )
-    for i in range(cfg.n_layers):
-        blk = params[f"block_{i}"]
-        h = _rms_apply(x, blk["ln1"]["scale"])
-        q, k, v = _attn_proj(blk["attn"], h, dt)  # [b, heads|kv, 1, hd]
-        q = _rope_batched(q, pos, cfg.rope_theta)
-        k = _rope_batched(k, pos, cfg.rope_theta)
-        # write this token's k/v, then attend against the updated pool so
-        # the step sees its own key (standard causal self-attention)
-        k_cache = k_cache.at[i, phys, slot].set(_pool_rows(k)[:, 0])
-        v_cache = v_cache.at[i, phys, slot].set(_pool_rows(v)[:, 0])
-        if chunk_blocks:
-            att = paged_decode_attention(
-                q[:, :, 0, :], k_cache, v_cache, i, block_tables, positions,
-                scale=scale,
-            ).astype(dt)[:, :, None, :]
-        else:
-            keys = _gather_table(k_cache, i, block_tables, cfg.kv_heads, n_rep)
-            vals = _gather_table(v_cache, i, block_tables, cfg.kv_heads, n_rep)
-            logits = (
-                jnp.einsum(
-                    "bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32
-                )
-                * scale
-            )
-            logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1)
-            att = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
-        att = att.transpose(0, 2, 1, 3)  # [b, 1, h, hd]
-        x = x + jnp.einsum(
-            "bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt)
-        )
-        x = x + _mlp_apply(blk["mlp"], _rms_apply(x, blk["ln2"]["scale"]), dt)
-    x = _rms_apply(x, params["ln_f"]["scale"])
-    logits = (x[:, 0, :] @ params["lm_head"]["kernel"].astype(dt)).astype(jnp.float32)
-    return logits, {"k": k_cache, "v": v_cache}
+    if chunk_blocks:
+        attend = _attend_paged(cfg, block_tables, positions)
+    else:
+        # every cache position up to and including the current token
+        mask = (jnp.arange(t * block_size)[None, :] <= pos[:, None]) & active[:, None]  # [B, kv_len]
+        attend = _attend_table(cfg, block_tables, mask[:, None, :])
+    x, cache = _serve_layers(cfg, params, x, pos[:, None], (phys, pos % block_size), attend, cache)
+    return _head(params, x, cfg.dtype, row=0), cache
 
 
 def transformer_prefill_suffix(
-    cfg: TransformerConfig,
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    start_lens: jax.Array,
-    prompt_lens: jax.Array,
-    block_tables: jax.Array,
-    cache: Dict[str, jax.Array],
+    cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, start_lens: jax.Array,
+    prompt_lens: jax.Array, block_tables: jax.Array, cache: Dict[str, jax.Array],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Prefill only the un-cached suffix of each prompt (prefix caching).
 
@@ -945,75 +932,32 @@ def transformer_prefill_suffix(
     b, s = tokens.shape
     if s % block_size:
         raise ValueError(
-            f"suffix prefill needs tokens padded to the block size "
-            f"(got S={s}, block_size={block_size})"
+            f"suffix prefill needs tokens padded to the block size (got S={s}, block_size={block_size})"
         )
-    t = block_tables.shape[1]
-    kv_len = t * block_size
-    dt = cfg.dtype
-    n_rep = cfg.n_heads // cfg.kv_heads
-    scale = cfg.head_dim ** -0.5
     c_lo = jnp.min(start_lens) // block_size
     c_hi = (jnp.max(prompt_lens) + block_size - 1) // block_size
-    k_pos = jnp.arange(kv_len)
+    k_pos = jnp.arange(block_tables.shape[1] * block_size)
 
     def body(c, carry):
-        k_cache, v_cache, last_logits = carry
+        cache, last_logits = carry
         toks = jax.lax.dynamic_slice(tokens, (0, c * block_size), (b, block_size))
         p = c * block_size + jnp.arange(block_size)  # absolute positions [bs]
-        valid = (p[None, :] >= start_lens[:, None]) & (
-            p[None, :] < prompt_lens[:, None]
-        )  # [b, bs]
-        tbl_col = jax.lax.dynamic_slice(block_tables, (0, c), (b, 1))  # [b, 1]
-        phys = jnp.where(valid, tbl_col, 0)
+        valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, bs]
+        phys = jnp.where(valid, jax.lax.dynamic_slice(block_tables, (0, c), (b, 1)), 0)
         slots = jnp.broadcast_to(jnp.arange(block_size)[None, :], (b, block_size))
-        att_mask = k_pos[None, :] <= p[:, None]  # [bs, kv_len]
-        x = jnp.take(params["embed"]["embedding"].astype(dt), toks, axis=0)
-        for i in range(cfg.n_layers):
-            blk = params[f"block_{i}"]
-            h = _rms_apply(x, blk["ln1"]["scale"])
-            q, k, v = _attn_proj(blk["attn"], h, dt)  # [b, heads|kv, bs, hd]
-            q = _rope(q, p, Rope(cfg.rope_theta))
-            k = _rope(k, p, Rope(cfg.rope_theta))
-            # write this block's k/v first, then attend through the cache:
-            # the block's own causal keys and the cached prefix are read
-            # from the same pool, so warm and cold prefills see identical
-            # stored bits
-            k_cache = k_cache.at[i, phys, slots].set(_pool_rows(k))
-            v_cache = v_cache.at[i, phys, slots].set(_pool_rows(v))
-            keys = _gather_table(k_cache, i, block_tables, cfg.kv_heads, n_rep)
-            vals = _gather_table(v_cache, i, block_tables, cfg.kv_heads, n_rep)
-            logits = (
-                jnp.einsum(
-                    "bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32
-                )
-                * scale
-            )
-            logits = jnp.where(att_mask[None, None, :, :], logits, NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1)
-            att = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
-            att = att.transpose(0, 2, 1, 3)  # [b, bs, h, hd]
-            x = x + jnp.einsum(
-                "bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt)
-            )
-            x = x + _mlp_apply(blk["mlp"], _rms_apply(x, blk["ln2"]["scale"]), dt)
-        x = _rms_apply(x, params["ln_f"]["scale"])
-        logits = (x @ params["lm_head"]["kernel"].astype(dt)).astype(jnp.float32)
+        attend = _attend_table(cfg, block_tables, k_pos[None, :] <= p[:, None])
+        x = _embed_rows(params, toks, cfg.dtype)
+        x, cache = _serve_layers(cfg, params, x, p, (phys, slots), attend, cache)
+        logits = _head(params, x, cfg.dtype)
         sel = prompt_lens - 1 - c * block_size  # [b]
         contains = (sel >= 0) & (sel < block_size)
         idx = jnp.clip(sel, 0, block_size - 1)
         row = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0, :]
-        last_logits = jnp.where(contains[:, None], row, last_logits)
-        return k_cache, v_cache, last_logits
+        return cache, jnp.where(contains[:, None], row, last_logits)
 
-    init = (
-        cache["k"],
-        cache["v"],
-        jnp.zeros((b, cfg.vocab_size), jnp.float32),
-    )
-    k_cache, v_cache, last_logits = jax.lax.fori_loop(c_lo, c_hi, body, init)
-    return last_logits, {"k": k_cache, "v": v_cache}
-
+    init = (cache, jnp.zeros((b, cfg.vocab_size), jnp.float32))
+    cache, last_logits = jax.lax.fori_loop(c_lo, c_hi, body, init)
+    return last_logits, cache
 
 class LMTrial(JaxTrial):
     """Language-model trial over synthetic (or user-supplied) token data.
@@ -1190,7 +1134,7 @@ class LMTrial(JaxTrial):
     @property
     def flops_per_token(self) -> float:
         """Fwd+bwd matmul FLOPs per token by the standard 6N + attention
-        convention (same accounting as bench.py), for the ledger's MFU
+        convention (``benchmark/benchlib/costs.py`` counts the same), for the ledger's MFU
         estimate: N counts the projections at the stated head_dim, a gated
         MLP or a token's active experts (its expected picks among the held
         ones) with the router, and the head; attention counts the keys a

@@ -80,8 +80,8 @@ PRODUCTIVE_CATS = ("step",)
 _WRAPPER_CATS = ("trial", "experiment")
 
 # bf16 peak FLOP/s by TPU generation (public spec sheets); longest-prefix
-# matched so "TPU v5 lite" beats the "TPU v5" catch-all.  bench.py uses
-# this table for its MFU line; the ledger uses it for mfu_estimate.
+# matched so "TPU v5 lite" beats the "TPU v5" catch-all.  The ledger uses
+# it for mfu_estimate.
 PEAK_FLOPS_BY_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,  # v5e reports device_kind "TPU v5 lite"

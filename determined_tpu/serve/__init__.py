@@ -20,8 +20,9 @@ KV-cache decode path), and serves ``POST /v1/generate`` with:
   pruned on heartbeat loss, so replicas scale and discover like NTSC
   tasks.
 
-See ``docs/serving.md`` for the architecture and request lifecycle, and
-``scripts/bench_serve.py`` for the continuous-vs-static A/B.
+See ``docs/serving.md`` for the architecture and request lifecycle; the
+cells ``serve-internlm2-decode`` and ``serve-internlm2-chat`` of
+``BENCHMARK.json`` measure it.
 """
 
 from __future__ import annotations
@@ -33,7 +34,6 @@ from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import (
     DecodeKernels,
     ServeEngine,
-    StaticBatchEngine,
     sample_token,
 )
 from determined_tpu.serve.http import ServeHTTPServer
@@ -66,7 +66,6 @@ __all__ = [
     "ServeEngine",
     "ServeHTTPServer",
     "ServeWorker",
-    "StaticBatchEngine",
     "sample_token",
 ]
 

@@ -1,17 +1,12 @@
-"""The serving engine: jitted prefill/decode kernels + the batching loops.
+"""The serving engine: jitted prefill/decode kernels + the batching loop.
 
-Two engines share one set of compiled kernels:
+:class:`ServeEngine` does iteration-level **continuous batching** over one
+set of compiled kernels (:class:`DecodeKernels`): every decode step,
+finished sequences retire (blocks freed, response completed) and queued
+requests join the freed lanes immediately.  It is what ``dtpu serve``, a
+replica and the benchmark's serving cells run.
 
-- :class:`ServeEngine` — iteration-level **continuous batching**: every
-  decode step, finished sequences retire (blocks freed, response
-  completed) and queued requests join the freed lanes immediately.  This
-  is the production path ``dtpu serve`` runs.
-- :class:`StaticBatchEngine` — the naive baseline the A/B in
-  ``scripts/bench_serve.py`` measures against: a batch is formed, decoded
-  until EVERY member finishes, and only then replaced.  Short requests
-  idle their lane while the longest member runs.
-
-Both jitted steps are shaped entirely by :class:`ServeConfig` (lane count,
+The jitted steps are shaped entirely by :class:`ServeConfig` (lane count,
 prompt padding, block-table width), so a mixed stream of request lengths
 compiles exactly once per kernel — enforced by wrapping the pre-jit
 callables in the PR-4 RetraceSentinel (``lint/_runtime.py``), the same
@@ -33,7 +28,7 @@ import functools
 import logging
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -98,7 +93,7 @@ def sample_token(logits: np.ndarray, temperature: float, rng: Any) -> int:
     """Sample one token from f32 logits [vocab]: greedy at temperature 0,
     otherwise one draw from the exact categorical distribution
     ``softmax(logits / temperature)`` over the whole vocabulary.  Shared by
-    the serving engines and the full-forward oracle in the parity tests, so
+    the serving engine and the full-forward oracle in the parity tests, so
     'sampling matches' reduces to 'logits match'.
 
     One float32 pass (a fresh array: ``logits`` is a view into the step's
@@ -299,12 +294,15 @@ class DecodeKernels:
         return out
 
 
-class _EngineBase:
-    """Admission, sampling, stats, and lifecycle shared by both engines."""
+class ServeEngine:
+    """Continuous batching: join between any two steps, retire instantly."""
 
-    def __init__(self, kernels: DecodeKernels, thread_name: str) -> None:
+    def __init__(self, kernels: DecodeKernels) -> None:
         self.kernels = kernels
         self.cfg = kernels.serve_cfg
+        self.lanes = LaneTable(self.cfg.max_batch)
+        #: trial/model label surfaced in the master's replica listing
+        self.model_label = type(kernels.model_cfg).__name__
         self.allocator = BlockAllocator(
             self.cfg.num_blocks,
             self.cfg.block_size,
@@ -319,7 +317,7 @@ class _EngineBase:
         #: reports it so a crashed engine never keeps serving 'ok'
         self.failed: Optional[str] = None
         self._thread = threading.Thread(
-            target=self._run_guarded, name=thread_name, daemon=True
+            target=self._run_guarded, name="dtpu-serve-engine", daemon=True
         )
         self._stats_lock = threading.Lock()
         self._submitted = 0
@@ -345,12 +343,42 @@ class _EngineBase:
         self._step_seconds = {
             "decode_wait": 0.0, "d2h": 0.0, "sample": 0.0, "admission": 0.0,
         }
-        #: the engine's own step counter: decode steps (and, for the
-        #: continuous engine, admit-only iterations) that did work
+        #: the engine's own step counter: decode steps (and admit-only
+        #: iterations) that did work
         self._steps = 0
         #: true while THIS engine runs the tracer's shipper (it started it)
         self._owns_shipper = False
         self._started_at = time.monotonic()
+
+    @classmethod
+    def from_checkpoint(
+        cls,
+        path: str,
+        serve_cfg: Optional[ServeConfig] = None,
+        trial_class: Optional[type] = None,
+    ) -> "ServeEngine":
+        """Load a trial checkpoint (``train.load_trial_from_checkpoint``)
+        and serve its model.  The trial's ``build_model()`` must return a
+        module exposing ``cfg`` (a TransformerConfig) — the LMTrial
+        contract."""
+        from determined_tpu import train
+
+        trial, trainer = train.load_trial_from_checkpoint(path, trial_class=trial_class)
+        model_cfg = getattr(trainer.model, "cfg", None)
+        if model_cfg is None:
+            raise ValueError(
+                "checkpointed trial does not build a decoder-only transformer "
+                "(model has no .cfg); only TransformerLM-style trials serve"
+            )
+        params = trainer.state.params
+        if "params" not in params:
+            raise ValueError(
+                "checkpoint params are in a pipeline-stage layout; serving "
+                "loads single-host (pipe=1) checkpoints only"
+            )
+        engine = cls(DecodeKernels(model_cfg, params, serve_cfg or ServeConfig()))
+        engine.model_label = type(trial).__name__  # e.g. "LMTrial"
+        return engine
 
     # -- admission (HTTP threads) -------------------------------------------
 
@@ -413,7 +441,7 @@ class _EngineBase:
 
     # -- lifecycle ----------------------------------------------------------
 
-    def start(self) -> "_EngineBase":
+    def start(self) -> "ServeEngine":
         if not self._thread.is_alive() and not self._finished.is_set():
             if self._tracer.enabled and not self._tracer.shipping:
                 # a step writes half a dozen spans: without the shipper the
@@ -456,8 +484,10 @@ class _EngineBase:
             self._finished.set()
 
     def _abort_active(self, reason: str) -> None:
-        """Fail in-flight sequences on a crash; subclasses know where
-        their live lanes are."""
+        """Fail in-flight sequences on a crash."""
+        for i in self.lanes.active():
+            seq = self.lanes.retire(i)
+            self._finish_error(seq.request, reason)
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Stop admitting, finish queued + in-flight work, stop the loop.
@@ -584,9 +614,10 @@ class _EngineBase:
                 kv["prefix_hits"] / max(1, kv["prefix_lookups"]), 4
             ),
             "uptime_s": round(time.monotonic() - self._started_at, 3),
+            "lanes": self.lanes.stats(),
         }
 
-    # -- shared engine internals --------------------------------------------
+    # -- the engine thread's work ---------------------------------------------
 
     def _padded_table(self, blocks: List[int]) -> List[int]:
         return blocks + [0] * (self.cfg.blocks_per_seq - len(blocks))
@@ -776,16 +807,11 @@ class _EngineBase:
             self._tokens_generated += 1
         return self._sequence_finished(seq, tok)
 
-    def _decode_and_sample(
-        self,
-        lanes: List[Optional[ActiveSeq]],
-        step: int,
-        on_finished: Callable[[int, ActiveSeq], None],
-    ) -> int:
+    def _decode_and_sample(self, lanes: List[Optional[ActiveSeq]], step: int) -> int:
         """One decode step over the lane table, then one sampled token for
-        every live lane, in lane order; a sequence that finished is handed
-        to ``on_finished`` at once (its response must not wait for the
-        other lanes' sampling).  Returns how many finished.
+        every live lane, in lane order; a sequence that finished is retired
+        at once (its response must not wait for the other lanes'
+        sampling).  Returns how many finished.
 
         ``serve.sample`` runs from before the first lane's sample to the
         last lane's token stamp: first ``sample_token`` call to last."""
@@ -799,7 +825,7 @@ class _EngineBase:
             done = self._advance_lane(seq, logits[i])
             t1 = seq.request.token_at[-1]
             if done:
-                on_finished(i, seq)
+                self._retire_lane(i, seq)
                 finished += 1
         with self._stats_lock:
             seconds = self._step_seconds
@@ -811,54 +837,6 @@ class _EngineBase:
             "serve.sample", "serve", t0, t1, {"step": step, "lanes": live}
         )
         return finished
-
-    def _run(self) -> None:  # pragma: no cover - subclasses implement
-        raise NotImplementedError
-
-
-class ServeEngine(_EngineBase):
-    """Continuous batching: join between any two steps, retire instantly."""
-
-    def __init__(self, kernels: DecodeKernels) -> None:
-        super().__init__(kernels, thread_name="dtpu-serve-engine")
-        self.lanes = LaneTable(self.cfg.max_batch)
-        #: trial/model label surfaced in the master's replica listing
-        self.model_label = type(kernels.model_cfg).__name__
-
-    def _abort_active(self, reason: str) -> None:
-        for i in self.lanes.active():
-            seq = self.lanes.retire(i)
-            self._finish_error(seq.request, reason)
-
-    @classmethod
-    def from_checkpoint(
-        cls,
-        path: str,
-        serve_cfg: Optional[ServeConfig] = None,
-        trial_class: Optional[type] = None,
-    ) -> "ServeEngine":
-        """Load a trial checkpoint (``train.load_trial_from_checkpoint``)
-        and serve its model.  The trial's ``build_model()`` must return a
-        module exposing ``cfg`` (a TransformerConfig) — the LMTrial
-        contract."""
-        from determined_tpu import train
-
-        trial, trainer = train.load_trial_from_checkpoint(path, trial_class=trial_class)
-        model_cfg = getattr(trainer.model, "cfg", None)
-        if model_cfg is None:
-            raise ValueError(
-                "checkpointed trial does not build a decoder-only transformer "
-                "(model has no .cfg); only TransformerLM-style trials serve"
-            )
-        params = trainer.state.params
-        if "params" not in params:
-            raise ValueError(
-                "checkpoint params are in a pipeline-stage layout; serving "
-                "loads single-host (pipe=1) checkpoints only"
-            )
-        engine = cls(DecodeKernels(model_cfg, params, serve_cfg or ServeConfig()))
-        engine.model_label = type(trial).__name__  # e.g. "LMTrial"
-        return engine
 
     def _admit_one(self, step: int) -> bool:
         """Try to move one queued request into a lane.  False when nothing
@@ -900,7 +878,7 @@ class ServeEngine(_EngineBase):
         active = sum(1 for seq in snapshot if seq is not None)
         retired = 0
         if active:
-            retired = self._decode_and_sample(snapshot, step, self._retire_lane)
+            retired = self._decode_and_sample(snapshot, step)
         elif admitted:
             with self._stats_lock:
                 self._steps = step  # admitted, and all finished at prefill
@@ -933,81 +911,4 @@ class ServeEngine(_EngineBase):
                 seq = self.lanes.retire(i)
                 self.allocator.free(seq.blocks)
                 self._finish_error(seq.request, "engine stopped")
-        self._finished.set()
-
-    def stats(self) -> Dict[str, Any]:
-        out = super().stats()
-        out["lanes"] = self.lanes.stats()
-        return out
-
-
-class StaticBatchEngine(_EngineBase):
-    """The naive baseline: form a batch, decode it to FULL completion.
-
-    No mid-flight joins, no early retirement — a lane whose sequence
-    finished early idles (position -1) until the whole batch is done.
-    Exists only as the like-for-like A/B denominator in
-    ``scripts/bench_serve.py``; same kernels, same admission, same
-    sampling.
-    """
-
-    def __init__(self, kernels: DecodeKernels) -> None:
-        super().__init__(kernels, thread_name="dtpu-serve-static")
-        self._current: List[ActiveSeq] = []  # crash-abort bookkeeping
-
-    def _abort_active(self, reason: str) -> None:
-        for seq in self._current:
-            if not seq.request.done.is_set():
-                self._finish_error(seq.request, reason)
-        self._current = []
-
-    def _gather_batch(self) -> List[ActiveSeq]:
-        batch: List[ActiveSeq] = []
-        while len(batch) < self.cfg.max_batch:
-            req = self.queue.get()
-            if req is None:
-                break
-            try:
-                seq = self._start_sequence(req, self._steps + 1)
-            except CacheOOM:
-                self.queue.requeue_head(req)
-                break
-            except Exception as e:  # noqa: BLE001
-                self._finish_error(req, f"prefill failed: {e}")
-                continue
-            if seq is not None:
-                batch.append(seq)
-        return batch
-
-    def _run(self) -> None:
-        while not self._stop.is_set():
-            batch = self._current = self._gather_batch()
-            if not batch:
-                if self.queue.draining and self.queue.empty():
-                    break
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            lanes: List[Optional[ActiveSeq]] = list(batch)
-            lanes += [None] * (self.cfg.max_batch - len(lanes))
-            live = [seq is not None for seq in lanes]
-            def respond(i: int, seq: ActiveSeq) -> None:
-                # the RESPONSE completes now, but the lane stays occupied
-                # until the whole batch drains — that gap is exactly what
-                # continuous batching removes
-                live[i] = False
-                self._retire_seq(seq)
-
-            while any(live) and not self._stop.is_set():
-                self._decode_and_sample(
-                    [seq if live[i] else None for i, seq in enumerate(lanes)],
-                    self._steps + 1,
-                    respond,
-                )
-            if self._stop.is_set():
-                for i, seq in enumerate(lanes):
-                    if seq is not None and live[i]:
-                        self.allocator.free(seq.blocks)
-                        self._finish_error(seq.request, "engine stopped")
-            self._current = []
         self._finished.set()

@@ -16,7 +16,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
-from determined_tpu.serve.engine import _EngineBase
+from determined_tpu.serve.engine import ServeEngine
 from determined_tpu.serve.scheduler import AdmissionRejected
 from determined_tpu.utils import faults
 
@@ -32,7 +32,7 @@ class ServeHTTPServer:
     port (pass port 0 to let the OS choose — tests and multi-replica
     hosts)."""
 
-    def __init__(self, engine: _EngineBase, host: str = "127.0.0.1", port: int = 0) -> None:
+    def __init__(self, engine: ServeEngine, host: str = "127.0.0.1", port: int = 0) -> None:
         self.engine = engine
         self.host = host
         self._requested_port = port

@@ -1229,8 +1229,7 @@ class Trainer:
                 if tracer.enabled:
                     # traced twin of the loop below: two extra clock reads
                     # + two lock-free ring pushes per step attribute the
-                    # step's wall-clock to input wait vs. step dispatch
-                    # (DTPU_BENCH_TRACE measures this at <2% step time);
+                    # step's wall-clock to input wait vs. step dispatch;
                     # the untraced branch stays byte-identical to before
                     while self.steps_completed < next_stop:
                         faults.fire("train.step", step=self.steps_completed)

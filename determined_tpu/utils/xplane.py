@@ -1,10 +1,9 @@
 """xplane (profiler trace) analysis: per-op device-time tables.
 
-One parser serves three consumers: ``scripts/profile_step.py`` (roofline
-accounting), ``scripts/weak_scaling.py`` (collective-vs-compute
-attribution of the virtual-mesh scaling curve), and the tensorboard
-viewer task (``exec/tensorboard.py`` renders op tables per trial — the
-reference wires torch.profiler traces into TensorBoard's plugin,
+One parser serves three consumers: ``dtpu experiment profile --xplane``
+(``cli/main.py``), the trial profiler (``core/_profiler.py``) and the
+tensorboard viewer task (``exec/tensorboard.py`` renders op tables per
+trial — the reference wires torch.profiler traces into TensorBoard's plugin,
 ``_pytorch_context.py:426-462``; here the platform parses its own traces).
 
 Parsing rides the ``xprof`` package's hlo_stats tool (baked into this

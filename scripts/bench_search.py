@@ -8,7 +8,7 @@ wall-clock speedup.  Each arm runs in its own subprocess so neither inherits
 the other's warm jit caches.
 
 The trial is an MLP over a map-style dataset whose per-item latency models
-disk/decode cost (the ``bench_input.py`` convention): on real TPU hardware
+disk/decode cost: on real TPU hardware
 the step executes on the device, so a packed host overlaps its trials'
 input/dispatch stalls the same way this CPU proxy overlaps the fetch
 latency.  The trial routes its learning rate through
@@ -19,7 +19,7 @@ arm compiles once for all four trials (3 hits via LIFO slot affinity); the
 packed arm's four gangs compile once each, concurrently.  The line reports
 both arms' cache counters so the reuse is visible.
 
-Prints ONE JSON line (same schema family as ``bench.py``):
+Prints ONE JSON line:
 
     JAX_PLATFORMS=cpu python scripts/bench_search.py
     python scripts/bench_search.py --trials 4 --steps 32 --item-ms 0.5

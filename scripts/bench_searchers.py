@@ -6,7 +6,7 @@ Hyperband, and PBT at EQUAL total budget, averaged over several seeds —
 the number that matters for method choice is "how good is the best config
 after N training units", not wall-clock (simulation costs milliseconds).
 
-Prints ONE JSON line (same schema family as ``bench.py``):
+Prints ONE JSON line:
 
     python scripts/bench_searchers.py
     python scripts/bench_searchers.py --trials 16 --max-time 64 --seeds 8

@@ -14,9 +14,8 @@ belief (BASELINE.md):
   collective that was already happening, so this is serialization +
   verify cost only, no extra round trips.
 
-Run directly or through the bench harness::
+Run directly::
 
-    DTPU_BENCH_SENTINEL=1 python bench.py
     python scripts/bench_sentinel.py [--rounds 400] [--records 50000]
 
 One-line JSON on stdout, same contract as the other bench scripts.

@@ -7,7 +7,7 @@
 # All targets are passed in ONE invocation on purpose: the whole-program
 # concurrency pass (lock-order-cycle / blocking-under-lock /
 # signal-handler-unsafe) builds a single cross-module lock-acquisition
-# graph spanning the package, scripts, examples, and bench — a script that
+# graph spanning the package, scripts and examples — a script that
 # takes package locks in the wrong order closes a cycle only a joint
 # graph can see.  The serving tier (determined_tpu/serve: allocator
 # free-list, admission queue, lane table, replica heartbeat thread) lints
@@ -39,4 +39,4 @@ exec python -m determined_tpu.cli lint --strict --native \
   --exclude 'traces' --exclude 'traces/*' \
   --exclude '*.egg-info' --exclude 'build' \
   --exclude 'dtpu-ctx-*' \
-  "$@" determined_tpu examples bench.py scripts
+  "$@" determined_tpu examples scripts

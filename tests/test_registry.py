@@ -438,7 +438,7 @@ def test_rolling_deploy_replacement_gate_and_label_matching(tmp_path):
 
 
 class _PoissonLoad:
-    """Open-loop Poisson load (the bench_serve.py arrival model) over the
+    """Open-loop Poisson load (exponential gaps) over the
     master's live routing table.  Every arrival MUST eventually succeed:
     a 503 (draining) or connection error (replica restarting) re-resolves
     the fleet and retries — those are the roll's expected transients — but
