@@ -33,6 +33,7 @@ def load_trial_from_checkpoint(
     restored at the checkpoint's step; call ``trainer.fit`` to continue
     training or use the restored ``trainer.state.params`` directly.
     """
+    serialization.prefetch_backend()  # imports beside the trial's build and set-up
     tstate = serialization.load_trainer_state(path)
     if trial_class is None:
         ref = tstate.get("trial_class")

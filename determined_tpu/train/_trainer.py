@@ -1099,11 +1099,15 @@ class Trainer:
     ) -> Dict[str, Any]:
         """Train until ``max_length``; returns a summary dict."""
         tracer = get_tracer()
-        with tracer.span("trainer.setup", cat="setup"):
-            self._setup()
         if checkpoint_policy is None:
             cfg = self.context.exp_config
             checkpoint_policy = cfg.checkpoint_policy if cfg is not None else "best"
+        if checkpoint_policy != "none" or latest_checkpoint:
+            # this run will touch a checkpoint: the backend's import runs
+            # beside set-up and compile, not in front of the first save
+            serialization.prefetch_backend()
+        with tracer.span("trainer.setup", cat="setup"):
+            self._setup()
 
         max_steps = self._to_batches(Length.parse(max_length))
         val_sched = _BoundarySchedule(self._to_batches(validation_period), max_steps)

@@ -10,20 +10,94 @@ both into the SAME checkpoint directory managed by CheckpointContext.
 Layout inside one checkpoint dir:
     state/         orbax pytree (params, opt_state, rng, step)
     trainer_state.json   loop counters, loader state, callbacks state
+
+The backend is imported by the first call that needs it, never by
+``import determined_tpu.*``: ``orbax.checkpoint`` pulls in tensorstore and
+a cloud logging client (``google.cloud.logging`` -> ``google.api_core``'s
+dependency-version checks), seconds to tens of seconds that a process
+which never checkpoints must not pay (``tests/test_import_graph.py``).  A
+run that knows it will checkpoint calls ``prefetch_backend()`` so the
+import overlaps its set-up instead of standing in front of the first save.
 """
 
 from __future__ import annotations
 
 import json
+import logging
 import os
-from typing import Any, Dict, Optional
+import threading
+import time
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
+
+from determined_tpu.observability import get_tracer
+
+logger = logging.getLogger("determined_tpu.train")
 
 ARRAY_SUBDIR = "state"
 TRAINER_STATE_FILE = "trainer_state.json"
+
+# guards the once-a-process import below and what it records
+_backend_lock = threading.Lock()
+# (orbax.checkpoint, seconds its import took, whether the prefetch thread ran it)
+_loaded: Optional[Tuple[Any, float, bool]] = None
+_prefetch_started = False
+_import_reported = False
+
+
+def _backend(*, prefetch: bool = False) -> Any:
+    """``orbax.checkpoint``, imported on first call, once a process.
+
+    A save or restore that arrives while the prefetch thread is still
+    importing waits on the lock: same module, never two imports.  The
+    process's first save or restore leaves one ``ckpt.backend_import``
+    span (``cat="setup"``) over the time it stood here, with ``seconds``
+    (the import itself, wherever it ran), ``prefetched`` (the background
+    thread ran it) and ``waited_s`` (what this caller paid for it: 0 when
+    the prefetch had finished, ``seconds`` when it had to import itself).
+    """
+    global _loaded, _import_reported
+    t0 = time.monotonic()
+    with _backend_lock:
+        if _loaded is None:
+            t_import = time.monotonic()
+            import orbax.checkpoint as ocp
+
+            _loaded = (ocp, time.monotonic() - t_import, prefetch)
+        report = not prefetch and not _import_reported
+        if report:
+            _import_reported = True
+    ocp, seconds, prefetched = _loaded
+    if report:
+        t1 = time.monotonic()
+        get_tracer().record_span(
+            "ckpt.backend_import", "setup", t0, t1,
+            {"seconds": seconds, "prefetched": prefetched, "waited_s": t1 - t0},
+        )
+    return ocp
+
+
+def prefetch_backend() -> None:
+    """Start the backend's import on a daemon thread; called where a run
+    first learns it will read or write a checkpoint.  Idempotent: a second
+    call, or one after the backend is loaded, does nothing."""
+    global _prefetch_started
+    with _backend_lock:
+        if _loaded is not None or _prefetch_started:
+            return
+        _prefetch_started = True
+
+    def work() -> None:
+        try:
+            _backend(prefetch=True)
+        except Exception:
+            # the first save or restore imports again and raises where the
+            # run can handle it
+            logger.warning("checkpoint backend prefetch failed", exc_info=True)
+
+    threading.Thread(target=work, name="dtpu-ckpt-import", daemon=True).start()
 
 
 def _is_key_dtype(dtype: Any) -> bool:
@@ -77,7 +151,7 @@ def save_arrays(ckpt_dir: str, tree: Any) -> None:
     """Write a pytree of (possibly sharded) jax arrays; collective across
     processes — every process must call with the same tree structure."""
     path = os.path.join(os.path.abspath(ckpt_dir), ARRAY_SUBDIR)
-    with ocp.StandardCheckpointer() as ckptr:
+    with _backend().StandardCheckpointer() as ckptr:
         ckptr.save(path, _unkey(tree))
         ckptr.wait_until_finished()
 
@@ -87,7 +161,7 @@ def restore_arrays(ckpt_dir: str, abstract_tree: Any) -> Any:
     jax.ShapeDtypeStruct with .sharding set, e.g. from eval_shape +
     shardings)."""
     path = os.path.join(os.path.abspath(ckpt_dir), ARRAY_SUBDIR)
-    with ocp.StandardCheckpointer() as ckptr:
+    with _backend().StandardCheckpointer() as ckptr:
         restored = _rekey(ckptr.restore(path, _unkey_abstract(abstract_tree)), abstract_tree)
     # Belt-and-braces: guarantee placement matches the requested shardings
     # (a replicated scalar must span the mesh, not sit on one device, or the
