@@ -229,9 +229,11 @@ class SortedRows(NamedTuple):
     layout: Any            # gm.TileLayout
 
 
-def _sorted_rows(picks: jax.Array, first: Any, count: int) -> SortedRows:
+def _sorted_rows(picks: jax.Array, first: Any, count: int, *, serving: bool = False) -> SortedRows:
     """``picks [T, k]`` (experts, distinct a token) -> the held ones sorted by
-    expert into a tile-aligned buffer sized for the worst case."""
+    expert into a tile-aligned buffer sized for the worst case.  ``serving``
+    (no backward pass): an expert without rows owns no tile, so its matrices
+    are not read, and a tile is at least the 16 rows a bf16 sublane tile packs."""
     from determined_tpu.ops import grouped_matmul as gm
 
     tokens, k = picks.shape
@@ -244,8 +246,8 @@ def _sorted_rows(picks: jax.Array, first: Any, count: int) -> SortedRows:
     load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
     # a token picks a held expert at most min(k, count) times
     max_rows = tokens * min(k, count)
-    tile = min(gm.DEFAULT_TILE, max(8, _round_up_pow2(max_rows // count)))
-    layout = gm.tile_layout(load, max_rows, tile)
+    tile = min(gm.DEFAULT_TILE, max(16 if serving else 8, _round_up_pow2(max_rows // count)))
+    layout = gm.tile_layout(load, max_rows, tile, empty_groups_own_tile=not serving)
     sorted_start = jnp.cumsum(load) - load                             # [count]
     group = jnp.minimum(key, count - 1)
     pick_row = jnp.where(
@@ -383,6 +385,16 @@ class RoutedExperts(nn.Module):
     dtype: Any = jnp.bfloat16
     partition: bool = True
     expert_axis_name: Any = None
+    # "sigmoid_grouped": :func:`route_sigmoid_grouped` over ``router`` and the
+    # selection bias ``router_bias`` in place of the softmax (no auxiliary
+    # loss: the bias is what balances such a router); ``shared_experts`` of
+    # width ``d_ff`` each (``shared_w_*``) take every token beside the picks
+    router_kind: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    shared_experts: int = 0
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -405,23 +417,39 @@ class RoutedExperts(nn.Module):
 
         def param(name, shape, logical):
             init = _maybe_partition(self.partition, nn.initializers.lecun_normal(), logical)
-            return self.param(name, init, shape, jnp.float32)
+            return self.param(name, init, shape, self.param_dtype)
 
         router = param("router", (d, e), ("embed", None))
         w_gate = param("w_gate", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_up = param("w_up", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_down = param("w_down", (count, self.d_ff, d), ("expert", "mlp", "embed"))
 
+        p = {"router": router}
+        if self.router_kind == "sigmoid_grouped":
+            p["router_bias"] = self.param("router_bias", nn.initializers.normal(0.01), (e,), jnp.float32)
+        if self.shared_experts:
+            wide = self.shared_experts * self.d_ff
+            p["shared_w_gate"] = param("shared_w_gate", (d, wide), ("embed", "mlp"))
+            p["shared_w_up"] = param("shared_w_up", (d, wide), ("embed", "mlp"))
+            p["shared_w_down"] = param("shared_w_down", (wide, d), ("mlp", "embed"))
+
         xf = x.reshape(tokens, d)
         with jax.named_scope("moe.route"):
-            # routing in float32: a bf16 softmax ties and misroutes tokens
-            probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)   # [T, E]
-            top_p, picks = jax.lax.top_k(probs, k)                              # [T, k]
-            weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            share = jnp.sum(
-                picks.reshape(-1, 1) == jnp.arange(e)[None, :], axis=0, dtype=jnp.float32
-            ) / (tokens * k)
-            aux = e * jnp.sum(share * jnp.mean(probs, axis=0))
+            if self.router_kind != "softmax":
+                weights, picks = _route(
+                    p, xf, kind=self.router_kind, top_k=k, n_group=self.n_group,
+                    topk_group=self.topk_group, scaling=self.routed_scaling,
+                )
+                aux = jnp.zeros((), jnp.float32)
+            else:
+                # routing in float32: a bf16 softmax ties and misroutes tokens
+                probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)   # [T, E]
+                top_p, picks = jax.lax.top_k(probs, k)                              # [T, k]
+                weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+                share = jnp.sum(
+                    picks.reshape(-1, 1) == jnp.arange(e)[None, :], axis=0, dtype=jnp.float32
+                ) / (tokens * k)
+                aux = e * jnp.sum(share * jnp.mean(probs, axis=0))
         self.sow("intermediates", "picks", picks)
 
         with jax.named_scope("moe.dispatch"):
@@ -436,4 +464,101 @@ class RoutedExperts(nn.Module):
         if self.expert_axis_name is not None:
             with jax.named_scope("moe.combine"):
                 y = jax.lax.psum(y, self.expert_axis_name)
+        if self.shared_experts:
+            y = y + _shared_experts(p, xf.astype(self.dtype)).astype(y.dtype)
         return y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)
+
+
+def route_sigmoid_grouped(
+    logits: jax.Array, bias: jax.Array, *, top_k: int, n_group: int, topk_group: int, scaling: float
+) -> Tuple[jax.Array, jax.Array]:
+    """The router DeepSeek-V3 publishes, on float32 ``logits [T, E]``: scores
+    ``sigmoid(logits)``; selection by ``scores + bias`` (the bias selects and
+    never weighs); a group of ``E / n_group`` consecutive experts scores the
+    sum of its two largest selection values and the ``topk_group`` best groups
+    stay; the ``top_k`` largest selection values inside them are the picks;
+    ``weights = scores[picks] / (their sum + 1e-20) * scaling``.  Returns
+    (weights [T, k] float32, picks [T, k])."""
+    tokens, e = logits.shape
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    select = scores + bias.astype(jnp.float32)[None, :]
+    group_score = jnp.sum(jax.lax.top_k(select.reshape(tokens, n_group, e // n_group), 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)                                    # [T, topk_group]
+    group_kept = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)  # [T, n_group]
+    inside = jnp.where(jnp.repeat(group_kept, e // n_group, axis=1), select, -jnp.inf)
+    _, picks = jax.lax.top_k(inside, top_k)
+    top = jnp.take_along_axis(scores, picks, axis=1)
+    return top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scaling, picks
+
+
+def _route(
+    p: Any, xf: jax.Array, *, kind: str, top_k: int, n_group: int, topk_group: int, scaling: float
+) -> Tuple[jax.Array, jax.Array]:
+    """(weights [T, k] float32, picks [T, k]) of ``xf [T, d]`` under a router
+    of ``kind``.  In float32 at the highest matmul precision: a router that
+    rounds its scores ties and misroutes tokens."""
+    logits = jnp.matmul(
+        xf.astype(jnp.float32), p["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )
+    if kind == "sigmoid_grouped":
+        return route_sigmoid_grouped(
+            logits, p["router_bias"], top_k=top_k, n_group=n_group, topk_group=topk_group, scaling=scaling
+        )
+    top_p, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return top_p / jnp.sum(top_p, axis=-1, keepdims=True), picks
+
+
+def _shared_experts(p: Any, xf: jax.Array) -> jax.Array:
+    """The shared experts of ``xf [T, d]``: one SwiGLU as wide as all of them."""
+    dt = xf.dtype
+    hidden = nn.silu(xf @ p["shared_w_gate"].astype(dt)) * (xf @ p["shared_w_up"].astype(dt))
+    return hidden @ p["shared_w_down"].astype(dt)
+
+
+# Each Mosaic call of the serving forward sits in a jitted function of its own
+# (``ops/expert_rows.py``'s two already do): a bare ``pallas_call`` reaches the
+# optimized program without its ``op_name``, and a reader of a device trace
+# could not tell which scope its time belongs to (PERF.md, PR 33).
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _gmm(lhs, rhs, group_start, tile_group, live_tiles, rows, tile):
+    from determined_tpu.ops import grouped_matmul as gm
+
+    return gm.gmm(lhs, rhs, gm.TileLayout(group_start, tile_group, live_tiles, rows, tile))
+
+
+def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """``RoutedExperts``' forward over its unboxed leaves ``p``, for the
+    serving programs (``models/transformer.py _serve_layer``; ``cfg`` is the
+    ``TransformerConfig``): ``x [b, s, d]`` -> (``y [b, s, d]``, (picks that
+    landed on a held expert, held experts with at least one row)).  Tokens
+    that ``live [b, s]`` does not mark (idle lanes, a prompt's padding) take no
+    expert's rows.  No backward pass follows, so an expert without rows owns
+    no tile of the buffer and its matrices are not read (``_sorted_rows``)."""
+    from determined_tpu.ops import expert_rows
+
+    b, s, d = x.shape
+    first, count = cfg.moe_experts_held or (0, cfg.moe_experts)
+    xf = x.reshape(b * s, d)
+    dt = xf.dtype
+    with jax.named_scope("serve.moe.route"):
+        weights, picks = _route(
+            p, xf, kind=cfg.moe_router, top_k=cfg.moe_top_k, n_group=cfg.moe_n_group,
+            topk_group=cfg.moe_topk_group, scaling=cfg.moe_routed_scaling,
+        )
+        if live is not None:
+            picks = jnp.where(live.reshape(-1, 1), picks, cfg.moe_experts)  # no expert: never held
+        rows = _sorted_rows(picks, first, count, serving=True)
+    with jax.named_scope("serve.moe.experts"):
+        layout = rows.layout
+        row_token = rows.row_pick // weights.shape[1]
+        xr = expert_rows.rows_of_tokens(xf, row_token, rows.tile_rows, layout)
+        scale = _row_weights(weights, rows.row_pick, rows.row_live)
+        gate = _gmm(xr, p["w_gate"].astype(dt), *layout)
+        up = _gmm(xr, p["w_up"].astype(dt), *layout)
+        out = _gmm(_hidden(gate, up, scale)[1], p["w_down"].astype(dt), *layout)
+        y = expert_rows.tokens_of_rows(out, row_token, rows.tile_rows, layout, xf.shape[0])
+    if cfg.moe_shared_experts:
+        with jax.named_scope("serve.moe.shared"):
+            y = y + _shared_experts(p, xf).astype(y.dtype)
+    counted = (jnp.sum(rows.load), jnp.sum(rows.load > 0))
+    return y.astype(x.dtype).reshape(b, s, d), counted

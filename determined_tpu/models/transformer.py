@@ -37,7 +37,7 @@ from determined_tpu.ops.attention import (
     dot_product_attention,
     reference_attention,
 )
-from determined_tpu.ops.paged_attention import paged_decode_attention
+from determined_tpu.ops.paged_attention import paged_decode_attention, paged_latent_attention
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
 from determined_tpu.parallel.sharding import with_sharding_constraint
@@ -88,6 +88,34 @@ class TransformerConfig:
     moe_top_k: int = 0
     moe_intermediate_size: Optional[int] = None
     moe_experts_held: Optional[Tuple[int, int]] = None
+    # the first dense_prefix blocks keep their dense MLP whatever moe_every
+    # says; the period of moe_every starts after them
+    dense_prefix: int = 0
+    # the router of the dropless layer: "softmax" (top-k of the softmax,
+    # renormalised) or "sigmoid_grouped" (sigmoid scores, a selection bias,
+    # the moe_topk_group best of moe_n_group groups, top-k inside them,
+    # weights normalised and times moe_routed_scaling), and how many shared
+    # experts of width moe_intermediate_size every token also passes through
+    moe_router: str = "softmax"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scaling: float = 1.0
+    moe_shared_experts: int = 0
+    # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
+    # it.  Queries come through a q_lora_rank bottleneck as n_heads heads of
+    # [qk_nope_head_dim | qk_rope_head_dim]; keys and values are expanded from
+    # ONE latent row a token, [kv_lora_rank | qk_rope_head_dim] (what serving
+    # caches), to n_heads heads of [qk_nope_head_dim | v_head_dim], with the
+    # row's rotary part shared by all heads.  softmax_scale: None -> the
+    # query-key width ** -0.5
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    softmax_scale: Optional[float] = None
+    # dtype of the parameters TransformerLM.init makes (and serving reads)
+    param_dtype: Any = jnp.float32
     # Quantized matmul arithmetic (train/_quant.py): none|int8|fp8 routes
     # every dense/attention projection matmul (and the logits-path
     # lm_head) through per-channel dynamically-scaled reduced-precision
@@ -151,6 +179,30 @@ class TransformerConfig:
                     )
         elif self.moe_experts_held is not None or self.moe_intermediate_size is not None:
             raise ValueError("moe_experts_held and moe_intermediate_size belong to moe_top_k > 0")
+        if self.moe_router not in ("softmax", "sigmoid_grouped"):
+            raise ValueError(f"moe_router is softmax or sigmoid_grouped (got {self.moe_router!r})")
+        if (self.moe_router != "softmax" or self.moe_shared_experts) and not self.moe_top_k:
+            raise ValueError("moe_router and moe_shared_experts belong to moe_top_k > 0")
+        if self.moe_router == "sigmoid_grouped":
+            g, keep = self.moe_n_group, self.moe_topk_group
+            if g < 1 or self.moe_experts % g or not 1 <= keep <= g or self.moe_experts // g < 2 or (
+                self.moe_top_k > keep * (self.moe_experts // g)
+            ):
+                raise ValueError(
+                    f"sigmoid_grouped: moe_n_group={g} must divide moe_experts={self.moe_experts} into "
+                    f"groups of two or more, and moe_topk_group={keep} of them must hold moe_top_k={self.moe_top_k}"
+                )
+        if not 0 <= self.dense_prefix <= self.n_layers:
+            raise ValueError(f"dense_prefix={self.dense_prefix} must lie in [0, n_layers]")
+        if self.kv_lora_rank is not None:
+            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if any(v is None or int(v) < 1 for v in sizes) or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, kv_lora_rank, qk_nope_head_dim, an even "
+                    f"qk_rope_head_dim and v_head_dim (got {sizes})"
+                )
+            if self.quantized_matmul != "none" or self.seq_axis_name is not None:
+                raise ValueError("latent attention runs without quantized_matmul and outside a `seq` axis")
 
     @property
     def kv_heads(self) -> int:
@@ -163,6 +215,27 @@ class TransformerConfig:
     def layer_type(self, i: int) -> str:
         return FULL if self.layer_types is None else self.layer_types[i]
 
+    def use_moe(self, i: int) -> bool:
+        """Whether block ``i`` holds experts: after the dense prefix, every
+        ``moe_every``-th block."""
+        j = i - self.dense_prefix
+        return self.moe_experts > 0 and j >= 0 and (j % self.moe_every) == self.moe_every - 1
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def rope_dim(self) -> int:
+        """The width rotary embeddings turn: a head, or a latent row's rotary part."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
+    def attn_scale(self) -> float:
+        if self.softmax_scale is not None:
+            return float(self.softmax_scale)
+        return float((self.qk_nope_head_dim + self.qk_rope_head_dim) if self.latent else self.head_dim) ** -0.5
+
     def window(self, layer_type: str) -> Optional[int]:
         return self.sliding_window if layer_type == SLIDING else None
 
@@ -174,14 +247,9 @@ class TransformerConfig:
             return Rope(theta)
         return Rope(
             theta,
-            tuple(yarn_inv_freq(self.head_dim, theta, **{k: params[k] for k in _YARN_KEYS})),
+            tuple(yarn_inv_freq(self.rope_dim, theta, **{k: params[k] for k in _YARN_KEYS})),
             float(params["attention_factor"]),
         )
-
-    @property
-    def uses_layer_kinds(self) -> bool:
-        """Whether any layer departs from full attention under one rotary base."""
-        return (self.layer_types is not None and SLIDING in self.layer_types) or bool(self.rope_parameters)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,6 +315,7 @@ def _maybe_partition(partition: bool, init, names):
 class RMSNorm(nn.Module):
     eps: float = 1e-6
     partition: bool = True
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -254,6 +323,7 @@ class RMSNorm(nn.Module):
             "scale",
             _maybe_partition(self.partition, nn.initializers.ones, ("embed",)),
             (x.shape[-1],),
+            self.param_dtype,
         )
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
         return (x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)) * scale.astype(x.dtype)
@@ -277,7 +347,7 @@ class Attention(nn.Module):
             axis=-1,
             use_bias=False,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             dot_general=qdg,
             kernel_init=_maybe_partition(
                 cfg.partition_params, nn.initializers.lecun_normal(), logical
@@ -338,7 +408,7 @@ class Attention(nn.Module):
             axis=(-2, -1),
             use_bias=False,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             dot_general=qdg,
             kernel_init=_maybe_partition(
                 cfg.partition_params,
@@ -348,6 +418,42 @@ class Attention(nn.Module):
             name="wo",
         )(out)
         return out
+
+
+def _latent_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
+    """Latent attention's leaves: name -> (shape, logical axes, initialiser)."""
+    d, h = cfg.d_model, cfg.n_heads
+    qk, rope = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.qk_rope_head_dim
+    kernel, ones = nn.initializers.lecun_normal(), nn.initializers.ones
+    return {
+        "wq_a": ((d, cfg.q_lora_rank), ("embed", None), kernel),
+        "q_norm": ((cfg.q_lora_rank,), (None,), ones),
+        "wq_b": ((cfg.q_lora_rank, h, qk), (None, "heads", "head_dim"), kernel),
+        "wkv_a": ((d, cfg.kv_lora_rank + rope), ("embed", None), kernel),
+        "kv_norm": ((cfg.kv_lora_rank,), (None,), ones),
+        "wkv_b": ((cfg.kv_lora_rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim), (None, "heads", "head_dim"), kernel),
+        "wo": ((h, cfg.v_head_dim, d), ("heads", "head_dim", "embed"), nn.initializers.lecun_normal(in_axis=(0, 1))),
+    }
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention over the whole sequence (training, and
+    what ``init`` builds for serving): the projections of ``_latent_project``
+    and the expanded, causal form of ``_latent_attend_local``; the serving
+    forward below reads the same leaves."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        p = {
+            name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
+            for name, (shape, logical, init) in _latent_param_shapes(cfg).items()
+        }
+        q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, x, jnp.arange(x.shape[1]), cfg.rope(FULL))
+        out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0)
+        return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype))
 
 
 class MLP(nn.Module):
@@ -364,7 +470,7 @@ class MLP(nn.Module):
             feats,
             use_bias=False,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             dot_general=qdg,
             kernel_init=_maybe_partition(
                 cfg.partition_params, nn.initializers.lecun_normal(), logical
@@ -387,42 +493,51 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        x = x + Attention(self.cfg, self.mesh, self.layer_type, name="attn")(
-            RMSNorm(partition=self.cfg.partition_params, name="ln1")(x)
+        cfg = self.cfg
+        norm = lambda name: RMSNorm(  # noqa: E731
+            partition=cfg.partition_params, param_dtype=cfg.param_dtype, name=name
         )
-        if self.use_moe and self.cfg.moe_top_k:
+        if cfg.latent:
+            x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
+        else:
+            x = x + Attention(cfg, self.mesh, self.layer_type, name="attn")(norm("ln1")(x))
+        if self.use_moe and cfg.moe_top_k:
             from determined_tpu.models.moe import RoutedExperts
 
             y, aux = RoutedExperts(
-                num_experts=self.cfg.moe_experts,
-                top_k=self.cfg.moe_top_k,
-                d_ff=self.cfg.moe_intermediate_size or self.cfg.ff_dim,
-                held=self.cfg.moe_experts_held,
-                dtype=self.cfg.dtype,
-                partition=self.cfg.partition_params,
-                expert_axis_name=self.cfg.expert_axis_name,
+                num_experts=cfg.moe_experts,
+                top_k=cfg.moe_top_k,
+                d_ff=cfg.moe_intermediate_size or cfg.ff_dim,
+                held=cfg.moe_experts_held,
+                dtype=cfg.dtype,
+                partition=cfg.partition_params,
+                expert_axis_name=cfg.expert_axis_name,
+                router_kind=cfg.moe_router,
+                n_group=cfg.moe_n_group,
+                topk_group=cfg.moe_topk_group,
+                routed_scaling=cfg.moe_routed_scaling,
+                shared_experts=cfg.moe_shared_experts,
+                param_dtype=cfg.param_dtype,
                 name="moe",
-            )(RMSNorm(partition=self.cfg.partition_params, name="ln2")(x))
+            )(norm("ln2")(x))
             x = x + y
         elif self.use_moe:
             from determined_tpu.models.moe import MoE
 
             y, aux = MoE(
-                num_experts=self.cfg.moe_experts,
-                d_ff=self.cfg.ff_dim,
-                capacity_factor=self.cfg.moe_capacity_factor,
-                dtype=self.cfg.dtype,
-                partition=self.cfg.partition_params,
-                expert_axis_name=self.cfg.expert_axis_name,
+                num_experts=cfg.moe_experts,
+                d_ff=cfg.ff_dim,
+                capacity_factor=cfg.moe_capacity_factor,
+                dtype=cfg.dtype,
+                partition=cfg.partition_params,
+                expert_axis_name=cfg.expert_axis_name,
                 name="moe",
-            )(RMSNorm(partition=self.cfg.partition_params, name="ln2")(x))
+            )(norm("ln2")(x))
             x = x + y
         else:
-            x = x + MLP(self.cfg, self.mesh, name="mlp")(
-                RMSNorm(partition=self.cfg.partition_params, name="ln2")(x)
-            )
+            x = x + MLP(cfg, self.mesh, name="mlp")(norm("ln2")(x))
             aux = jnp.zeros((), jnp.float32)
-        if self.cfg.partition_params:
+        if cfg.partition_params:
             x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
         return x, aux
 
@@ -443,7 +558,7 @@ class TransformerLM(nn.Module):
             cfg.vocab_size,
             cfg.d_model,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             embedding_init=_maybe_partition(
                 cfg.partition_params,
                 nn.initializers.normal(stddev=0.02),
@@ -459,19 +574,16 @@ class TransformerLM(nn.Module):
             block_cls = nn.remat(Block, prevent_cse=False)
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):
-            use_moe = (
-                cfg.moe_experts > 0 and (i % cfg.moe_every) == cfg.moe_every - 1
-            )
-            x, aux = block_cls(cfg, self.mesh, use_moe, cfg.layer_type(i), name=f"block_{i}")(x)
+            x, aux = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), name=f"block_{i}")(x)
             aux_total = aux_total + aux
-        x = RMSNorm(partition=cfg.partition_params, name="ln_f")(x)
+        x = RMSNorm(partition=cfg.partition_params, param_dtype=cfg.param_dtype, name="ln_f")(x)
         from determined_tpu.train._quant import make_dot_general
 
         lm_head = nn.Dense(
             cfg.vocab_size,
             use_bias=False,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             dot_general=make_dot_general(cfg.quantized_matmul),
             kernel_init=_maybe_partition(
                 cfg.partition_params, nn.initializers.lecun_normal(), ("embed", "vocab")
@@ -684,15 +796,41 @@ def pipeline_forward(
 # the scatter shape static without masking arithmetic inside the kernel.
 
 
+def latent_row_width(cfg: TransformerConfig) -> int:
+    """Columns of the latent pool's row: ``kv_lora_rank + qk_rope_head_dim``
+    values a token, padded with zeros to whole 128-lane tiles (576 -> 640 at
+    the published widths) so that the decode kernel's copies and products are
+    lane-aligned; an unpadded last dimension would be padded by the device's
+    own tiled layout all the same."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
 def kv_cache_shape(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Tuple[int, ...]:
-    return (cfg.n_layers, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+    """One pool array's shape: K (and V) rows of a GQA model, or the latent rows."""
+    width = latent_row_width(cfg) if cfg.latent else cfg.kv_heads * cfg.head_dim
+    return (cfg.n_layers, num_blocks, block_size, width)
+
+
+def kv_bytes_per_token(cfg: TransformerConfig) -> int:
+    """Bytes of cache a token owns over all layers, as attention reads them
+    (a latent row's padding is not counted)."""
+    values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
+    return cfg.n_layers * values * jnp.dtype(cfg.dtype).itemsize
 
 
 def init_kv_cache(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Dict[str, jax.Array]:
-    """Zeroed paged K/V pool in the model's compute dtype (keys are stored
-    post-rope, i.e. exactly what attention consumes)."""
+    """Zeroed paged pool in the model's compute dtype: ``k`` and ``v`` (keys
+    are stored post-rope, i.e. exactly what attention consumes), or, under
+    latent attention, ONE array ``kv`` whose row is ``[c_kv after its norm |
+    k_r after rope | zeros]``."""
     shape = kv_cache_shape(cfg, num_blocks, block_size)
+    if cfg.latent:
+        return {"kv": jnp.zeros(shape, cfg.dtype)}
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _block_size(cache: Dict[str, jax.Array]) -> int:
+    return next(iter(cache.values())).shape[2]
 
 
 def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -716,13 +854,17 @@ def _mlp_apply(p: Dict[str, Any], x: jax.Array, dtype: Any) -> jax.Array:
 
 
 def _check_decodable(cfg: TransformerConfig) -> None:
-    if cfg.moe_experts > 0:
-        raise ValueError("KV-cache serving does not support MoE configs yet")
-    if cfg.uses_layer_kinds:
+    if cfg.moe_experts > 0 and not cfg.moe_top_k:
         raise ValueError(
-            "KV-cache serving runs full attention under one rotary base: it has no "
-            "sliding-window layers (layer_types, sliding_window) and no per-layer-type "
-            "rotary parameters (rope_parameters, YaRN) yet"
+            "KV-cache serving runs dropless experts (moe_top_k > 0); the top-2 capacity "
+            "layer drops tokens by the batch they arrive in and is not served"
+        )
+    if cfg.layer_types is not None and SLIDING in cfg.layer_types:
+        raise ValueError(
+            "KV-cache serving runs full attention in every layer, under the rotary "
+            "parameters of that one layer type: it has no sliding-window layers "
+            "(layer_types, sliding_window), and so none whose rope_parameters differ "
+            "from the full layers' (YaRN on some layers only), yet"
         )
     if cfg.seq_axis_name is not None or cfg.expert_axis_name is not None:
         raise ValueError("KV-cache serving runs outside pipeline stages")
@@ -796,30 +938,159 @@ def _attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Arr
     return attend
 
 
-def _serve_layer(cfg, i, blk, x, positions, write, attend, cache):
+# Latent attention's backends: ``attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i)``
+# with q_nope [b, n_heads, s, qk_nope], q_rope [b, n_heads, s, qk_rope] (after
+# rope), this call's own latent rows c_kv [b, s, kv_lora] (after their norm) and
+# k_r [b, s, qk_rope] (after rope), ``wkv_b`` [kv_lora, n_heads, qk_nope + v]
+# and the pool that already holds the rows; returns [b, s, n_heads, v_head_dim].
+# The first expands keys and values a head from the rows, as the equations are
+# published; the other two stay in the latent space (``q_lat_h = q_nope_h
+# W^K_h``, ``o_h = (sum p c_kv) W^V_h``: the same mathematics, and one row a
+# token serves every head's scores and values).
+
+
+def _latent_project(cfg, p, h, positions, rope):
+    """A latent layer's projections of the normed input ``h`` [b, s, d]."""
+    dt, r = cfg.dtype, cfg.kv_lora_rank
+    c_q = _rms_apply(h @ p["wq_a"].astype(dt), p["q_norm"])
+    q = jnp.einsum("bsr,rhk->bhsk", c_q, p["wq_b"].astype(dt))
+    kv = h @ p["wkv_a"].astype(dt)
+    c_kv = _rms_apply(kv[..., :r], p["kv_norm"])
+    k_r = _rope(kv[:, None, :, r:], positions, rope)[:, 0]
+    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], _rope(q[..., cfg.qk_nope_head_dim:], positions, rope)
+    return q_nope, q_rope, c_kv, k_r
+
+
+def _latent_attend_local(cfg: TransformerConfig):
+    """Causal, over this call's own rows, keys and values expanded a head:
+    the wide prefill (prompts start at position 0) and the training forward.
+    On a TPU at a length worth tiling the flash kernel runs it, q, k and v
+    padded with zeros to one width; no ``[heads, s, s]`` array is built."""
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
+        dt, nope = cfg.dtype, cfg.qk_nope_head_dim
+        kv = jnp.einsum("bsc,chk->bhsk", c_kv, wkv_b.astype(dt))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r[:, None], kv.shape[:3] + k_r.shape[-1:])], axis=-1)
+        v = kv[..., nope:]
+        if _on_tpu() and q.shape[2] >= 256:
+            from determined_tpu.ops.flash_attention import flash_attention
+
+            width = -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
+            pad = lambda t: jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))  # noqa: E731
+            out = flash_attention(pad(q), pad(k), pad(v), causal=True, scale=cfg.attn_scale)[..., : v.shape[-1]]
+        else:
+            out = reference_attention(q, k, v, causal=True, scale=cfg.attn_scale)
+        return out.transpose(0, 2, 1, 3)
+
+    return attend
+
+
+def _on_tpu() -> bool:
+    from determined_tpu.ops import paged_attention
+
+    return paged_attention._on_tpu()  # one switch for the serving forward's kernels (tests steer it)
+
+
+def _latent_split(cfg, wkv_b, q_nope):
+    """(queries in the latent space [b, h, s, kv_lora], W^V [kv_lora, h, v])."""
+    w = wkv_b.astype(cfg.dtype)
+    return jnp.einsum("bhsn,chn->bhsc", q_nope, w[..., : cfg.qk_nope_head_dim]), w[..., cfg.qk_nope_head_dim:]
+
+
+def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array):
+    """One query a lane against the lane's live latent rows, read where they
+    lie in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle."""
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)[:, :, 0]
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, cache["kv"].shape[-1] - q.shape[-1])))
+        with jax.named_scope("serve.mla.attend"):  # the kernel alone: what its roofline share times
+            out = paged_latent_attention(
+                q, cache["kv"], i, block_tables, positions, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+            )
+        return jnp.einsum("bhc,chv->bhv", out.astype(cfg.dtype), w_v)[:, None]
+
+    return attend
+
+
+def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
+    """Queries against every row of every table column, gathered from the
+    pool, under ``mask`` (as ``_attend_table``'s) and a float32 softmax: the
+    suffix prefill's read, and the oracle the paged path is tested against."""
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
+        b, t = block_tables.shape
+        r = cfg.kv_lora_rank
+        rows = cache["kv"][i, block_tables].reshape(b, t * cache["kv"].shape[2], -1)
+        lat, rot = rows[..., :r], rows[..., r: r + cfg.qk_rope_head_dim]
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        logits = jnp.einsum("bhsc,bkc->bhsk", q_lat, lat, preferred_element_type=jnp.float32)
+        logits = logits + jnp.einsum("bhsr,bkr->bhsk", q_rope, rot, preferred_element_type=jnp.float32)
+        seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+        probs = jax.nn.softmax(jnp.where(seen, logits * cfg.attn_scale, NEG_INF), axis=-1)
+        out = jnp.einsum("bhsk,bkc->bhsc", probs.astype(lat.dtype), lat)
+        return jnp.einsum("bhsc,chv->bshv", out, w_v)
+
+    return attend
+
+
+#: what a decode step of a model with expert layers counts beside its logits,
+#: each summed over the expert layers: picks that landed on a held expert, and
+#: held experts that got at least one row (whose matrices the step had to read)
+SERVE_COUNTERS = ("serve.moe.held_picks", "serve.moe.experts_hit")
+
+
+def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
-    points below: norm, q/k/v, rope at ``positions`` ([s], or [b, s]), this
-    call's K,V rows into the pool at ``write`` = (physical block, slot), each
-    [b, s] (or [b] where s is 1), then ``attend`` against the updated pool, so
-    that a token sees its own key, then ``wo``, the MLP and both residuals."""
+    points below: norm, the attention's projections (q/k/v, or latent
+    attention's), rope at ``positions`` ([s], or [b, s]), this call's rows into
+    the pool at ``write`` = (physical block, slot), each [b, s] (or [b] where s
+    is 1), then ``attend`` against the updated pool, so that a token sees its
+    own key, then the output projection, the MLP or the experts held here
+    (which count the tokens ``live`` [b, s] marks) and both residuals.
+    Returns (x, cache, what an expert layer counted or None)."""
     dt = cfg.dtype
     rope = cfg.rope(cfg.layer_type(i))
-    q, k, v = _attn_proj(blk["attn"], _rms_apply(x, blk["ln1"]["scale"]), dt)
-    q, k = _rope(q, positions, rope), _rope(k, positions, rope)
     phys, slots = write
-    cache = {
-        "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
-        "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
-    }
-    att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
-    x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
-    return x + _mlp_apply(blk["mlp"], _rms_apply(x, blk["ln2"]["scale"]), dt), cache
+    h = _rms_apply(x, blk["ln1"]["scale"])
+    if cfg.latent:
+        with jax.named_scope("serve.mla"):
+            p = blk["attn"]
+            q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, h, positions, rope)
+            row = jnp.concatenate([c_kv, k_r], axis=-1)
+            row = jnp.pad(row, ((0, 0), (0, 0), (0, cache["kv"].shape[-1] - row.shape[-1])))
+            cache = {"kv": cache["kv"].at[i, phys, slots].set(row.reshape(*phys.shape, -1))}
+            att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, i)
+            x = x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(dt))
+    else:
+        q, k, v = _attn_proj(blk["attn"], h, dt)
+        q, k = _rope(q, positions, rope), _rope(k, positions, rope)
+        cache = {
+            "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
+            "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
+        }
+        att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
+        x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
+    h = _rms_apply(x, blk["ln2"]["scale"])
+    if not cfg.use_moe(i):
+        return x + _mlp_apply(blk["mlp"], h, dt), cache, None
+    from determined_tpu.models.moe import serve_routed_experts
+
+    y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
+    return x + y, cache, counted
 
 
-def _serve_layers(cfg, params, x, positions, write, attend, cache):
+def _serve_layers(cfg, params, x, positions, write, attend, cache, live=None):
+    """Every layer; the last value is SERVE_COUNTERS' sums over the expert
+    layers, [2] float32, or None for a model without them."""
+    counted = []
     for i in range(cfg.n_layers):
-        x, cache = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache)
-    return x, cache
+        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache, live)
+        if c is not None:
+            counted.append(jnp.stack(c).astype(jnp.float32))
+    return x, cache, sum(counted) if counted else None
 
 
 def transformer_prefill(
@@ -837,7 +1108,7 @@ def transformer_prefill(
     them), which is what the parity tests in tests/test_transformer.py pin.
     """
     _check_decodable(cfg)
-    block_size = cache["k"].shape[2]
+    block_size = _block_size(cache)
     b, s = tokens.shape
     x = _embed_rows(params, tokens, cfg.dtype)
     positions = jnp.arange(s)
@@ -850,13 +1121,17 @@ def transformer_prefill(
         0,
     )
     slots = jnp.broadcast_to((positions % block_size)[None, :], (b, s))
-    x, cache = _serve_layers(cfg, params, x, positions, (phys, slots), _attend_local, cache)
+    attend = _latent_attend_local(cfg) if cfg.latent else _attend_local
+    # the padded tail takes no expert's rows
+    live = positions[None, :] < prompt_lens[:, None] if cfg.moe_experts else None
+    x, cache, _ = _serve_layers(cfg, params, x, positions, (phys, slots), attend, cache, live)
     return _head(params, x, cfg.dtype), cache
 
 
 def transformer_decode(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, positions: jax.Array,
     block_tables: jax.Array, cache: Dict[str, jax.Array], *, chunk_blocks: int = 0,
+    counters: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step over the paged cache for every lane at once.
 
@@ -878,9 +1153,14 @@ def transformer_decode(
     the full-table gather ``[b, T*block_size, kv_heads, head_dim]`` every
     step, the oracle of the parity tests.  Both paths share every
     projection and the cache-write scatter, and agree to f32 tolerance.
+
+    ``counters`` (the engine's decode program, where the model has expert
+    layers): the logits come back ``[B + 1, vocab]``, and the last row's first
+    entries are ``SERVE_COUNTERS`` of this step, so that they reach the host
+    in the logits' own copy.  Idle lanes take no expert's rows.
     """
     _check_decodable(cfg)
-    block_size = cache["k"].shape[2]
+    block_size = _block_size(cache)
     t = block_tables.shape[1]
     if chunk_blocks and t % chunk_blocks:
         raise ValueError(f"chunk_blocks={chunk_blocks} must divide the table width {t}")
@@ -891,13 +1171,18 @@ def transformer_decode(
         active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
     )
     if chunk_blocks:
-        attend = _attend_paged(cfg, block_tables, positions)
+        attend = (_latent_attend_paged if cfg.latent else _attend_paged)(cfg, block_tables, positions)
     else:
         # every cache position up to and including the current token
         mask = (jnp.arange(t * block_size)[None, :] <= pos[:, None]) & active[:, None]  # [B, kv_len]
-        attend = _attend_table(cfg, block_tables, mask[:, None, :])
-    x, cache = _serve_layers(cfg, params, x, pos[:, None], (phys, pos % block_size), attend, cache)
-    return _head(params, x, cfg.dtype, row=0), cache
+        attend = (_latent_attend_table if cfg.latent else _attend_table)(cfg, block_tables, mask[:, None, :])
+    live = active[:, None] if cfg.moe_experts else None
+    x, cache, counted = _serve_layers(cfg, params, x, pos[:, None], (phys, pos % block_size), attend, cache, live)
+    logits = _head(params, x, cfg.dtype, row=0)
+    if counters and counted is not None:
+        row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
+        logits = jnp.concatenate([logits, row], axis=0)
+    return logits, cache
 
 
 def transformer_prefill_suffix(
@@ -928,7 +1213,7 @@ def transformer_prefill_suffix(
     ones.
     """
     _check_decodable(cfg)
-    block_size = cache["k"].shape[2]
+    block_size = _block_size(cache)
     b, s = tokens.shape
     if s % block_size:
         raise ValueError(
@@ -945,9 +1230,9 @@ def transformer_prefill_suffix(
         valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, bs]
         phys = jnp.where(valid, jax.lax.dynamic_slice(block_tables, (0, c), (b, 1)), 0)
         slots = jnp.broadcast_to(jnp.arange(block_size)[None, :], (b, block_size))
-        attend = _attend_table(cfg, block_tables, k_pos[None, :] <= p[:, None])
+        attend = (_latent_attend_table if cfg.latent else _attend_table)(cfg, block_tables, k_pos[None, :] <= p[:, None])
         x = _embed_rows(params, toks, cfg.dtype)
-        x, cache = _serve_layers(cfg, params, x, p, (phys, slots), attend, cache)
+        x, cache, _ = _serve_layers(cfg, params, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
         logits = _head(params, x, cfg.dtype)
         sel = prompt_lens - 1 - c * block_size  # [b]
         contains = (sel >= 0) & (sel < block_size)
@@ -958,6 +1243,10 @@ def transformer_prefill_suffix(
     init = (cache, jnp.zeros((b, cfg.vocab_size), jnp.float32))
     cache, last_logits = jax.lax.fori_loop(c_lo, c_hi, body, init)
     return last_logits, cache
+
+#: latent attention's hparams: passed to the config as they are (absent: GQA)
+_LATENT_HPARAMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "softmax_scale")
+
 
 class LMTrial(JaxTrial):
     """Language-model trial over synthetic (or user-supplied) token data.
@@ -1096,6 +1385,13 @@ class LMTrial(JaxTrial):
             layer_types=None if layer_types is None else tuple(layer_types),
             sliding_window=g("sliding_window", None),
             rope_parameters=g("rope_parameters", None),
+            dense_prefix=int(g("dense_prefix", 0)),
+            moe_router=str(g("moe_router", "softmax")),
+            moe_n_group=int(g("moe_n_group", 1)),
+            moe_topk_group=int(g("moe_topk_group", 1)),
+            moe_routed_scaling=float(g("moe_routed_scaling", 1.0)),
+            moe_shared_experts=int(g("moe_shared_experts", 0)),
+            **{k: g(k, None) for k in _LATENT_HPARAMS},
             quantized_matmul=self._quant_mode(),
         )
 
@@ -1147,10 +1443,16 @@ class LMTrial(JaxTrial):
         cfg = self._cfg()
         d, width = cfg.d_model, cfg.n_heads * cfg.head_dim
         attn = d * cfg.head_dim * (2 * cfg.n_heads + 2 * cfg.kv_heads)
+        if cfg.latent:
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            attn = d * (cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_head_dim) + cfg.n_heads * (
+                cfg.q_lora_rank * qk + cfg.kv_lora_rank * (cfg.qk_nope_head_dim + cfg.v_head_dim) + cfg.v_head_dim * d
+            )
+            width = cfg.n_heads * (qk + cfg.v_head_dim) // 2
         n_params, seen = cfg.vocab_size * d, 0
         for i in range(cfg.n_layers):
             n_params += attn
-            if cfg.moe_experts > 0 and (i % cfg.moe_every) == cfg.moe_every - 1:
+            if cfg.use_moe(i):
                 held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
                 active = cfg.moe_top_k * held / cfg.moe_experts if cfg.moe_top_k else 2
                 n_params += d * cfg.moe_experts + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)

@@ -67,14 +67,27 @@ def buffer_rows(max_rows: int, groups: int, tile: int) -> int:
     return (-(-max_rows // tile) + groups) * tile
 
 
-def tile_layout(group_sizes: jax.Array, max_rows: int, tile: int) -> TileLayout:
-    """``group_sizes [G]`` (summing to at most ``max_rows``) -> the layout."""
+def tile_layout(
+    group_sizes: jax.Array, max_rows: int, tile: int, *, empty_groups_own_tile: bool = True
+) -> TileLayout:
+    """``group_sizes [G]`` (summing to at most ``max_rows``) -> the layout.
+
+    ``empty_groups_own_tile=False`` (a forward pass alone: serving) gives a
+    group without rows no tile, so that its matrices are never read; one tile
+    stays live whatever the sizes, and a dead tile names the last live tile's
+    group.  ``tgmm`` needs the default: every group's block is written."""
     groups = group_sizes.shape[0]
     rows = buffer_rows(max_rows, groups, tile)
-    tiles = jnp.maximum(-(-group_sizes // tile), 1)          # a group owns >= 1 tile
+    if empty_groups_own_tile:
+        tiles = jnp.maximum(-(-group_sizes // tile), 1)          # a group owns >= 1 tile
+    else:
+        tiles = -(-group_sizes // tile)
+        tiles = jnp.where((jnp.arange(groups) == 0) & (jnp.sum(tiles) == 0), 1, tiles)
     ends = jnp.cumsum(tiles)
     live = ends[-1]
     tile_group = jnp.searchsorted(ends, jnp.arange(rows // tile), side="right")
+    if not empty_groups_own_tile:
+        tile_group = jnp.where(jnp.arange(rows // tile) < live, tile_group, tile_group[live - 1])
     return TileLayout(
         group_start=((ends - tiles) * tile).astype(jnp.int32),
         tile_group=jnp.minimum(tile_group, groups - 1).astype(jnp.int32),

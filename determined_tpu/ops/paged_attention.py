@@ -359,3 +359,221 @@ def _paged_attention_pallas(
         q_bd, k_pool, v_pool,
     )
     return out[:, :n_heads, :]
+
+
+# ---------------------------------------------------------------------------
+# Latent rows: ONE row a token serves every head's scores and values
+# ---------------------------------------------------------------------------
+#
+# Multi-head latent attention in its absorbed form: a lane's query is
+# ``n_heads`` vectors in the latent space, ``[q_lat | q_rope | zeros]``, and
+# the pool ``[n_layers, num_blocks, block_size, width]`` holds one row a
+# token, ``[c_kv | k_r | zeros]`` (``models/transformer.py init_kv_cache``).
+# Scores are the query against the whole row; values are the row's first
+# ``value_dim`` columns, so a tile is copied once and feeds both products:
+# ``q @ tile^T`` and ``p @ tile[:, :value_dim]``.  All heads share the tile,
+# so they are the MXU's rows as they are (no block-diagonal layout), and a
+# token costs ``2 x heads x (width + value_dim)`` operations for ``width``
+# values read: at 128 heads the chip's compute and bandwidth weigh the same.
+# The probabilities enter the second product in the pool's dtype (a flash
+# kernel's usual rounding); three-term splitting, as the GQA kernel does it,
+# would make this kernel compute bound at twice the time.
+
+#: tokens a tile of the latent kernel aims for (one buffer pair, not two)
+LATENT_TILE_TOKENS = 512
+
+
+def latent_kernel_takes(width: int, value_dim: int, block_size: int, dtype) -> bool:
+    """Whether the latent shapes tile for the Pallas kernel."""
+    return (
+        jnp.dtype(dtype).itemsize in (2, 4)
+        and width % 128 == 0
+        and value_dim % 128 == 0
+        and block_size % _sublane_packing(dtype) == 0
+    )
+
+
+def paged_latent_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    layer,
+    block_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    scale: float,
+    value_dim: int,
+    tile_blocks: Optional[int] = None,
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Latent attention of one decode step of one layer over the paged pool.
+
+    ``q`` [b, n_heads, width] in the pool's dtype; ``pool`` ``[n_layers,
+    num_blocks, block_size, width]``; ``layer``, ``block_tables``,
+    ``positions``, ``impl`` and ``tile_blocks`` as
+    :func:`paged_decode_attention`'s.  Returns ``[b, n_heads, value_dim]``
+    float32: ``softmax(q . row * scale) @ row[:value_dim]`` over the lane's
+    rows ``0..position``; zeros for an empty lane.
+    """
+    block_size, width = pool.shape[2], pool.shape[3]
+    if tile_blocks is None:
+        tokens = min(LATENT_TILE_TOKENS, TILE_BUFFER_BYTES // (2 * width * pool.dtype.itemsize))
+        tile_blocks = max(1, min(block_tables.shape[1], tokens // block_size))
+    tiles = latent_kernel_takes(width, value_dim, block_size, pool.dtype)
+    if impl is None:
+        impl = "kernel" if _on_tpu() and tiles else "jnp"
+    if impl != "jnp" and not tiles:
+        raise ValueError(
+            f"the latent paged-attention kernel needs width % 128 == 0, value_dim % 128 == 0 and "
+            f"whole sublane tiles a block (got width={width}, value_dim={value_dim}, "
+            f"block_size={block_size}, {pool.dtype})"
+        )
+    return _paged_latent(
+        q.astype(pool.dtype), pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
+        scale=scale, value_dim=value_dim, tile_blocks=tile_blocks, impl=impl,
+    )
+
+
+# one trace and one lowering for every layer, as ``_paged_attention``; and a
+# Pallas call inside a jitted function of its own keeps its name in the
+# optimized program, which is how a trace's reader finds it (``jit.scopes``)
+@functools.partial(jax.jit, static_argnames=("scale", "value_dim", "tile_blocks", "impl"))
+def _paged_latent(q, pool, layer, block_tables, positions, *, scale, value_dim, tile_blocks, impl):
+    lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
+    if impl == "jnp":
+        return _paged_latent_jnp(q, pool, layer, block_tables, lengths, scale, value_dim, tile_blocks)
+    return _paged_latent_pallas(
+        q, pool, layer, block_tables, lengths, scale, value_dim, tile_blocks,
+        interpret=impl == "kernel_interpret",
+    )
+
+
+def _paged_latent_jnp(q, pool, layer, block_tables, lengths, scale, value_dim, tile_blocks):
+    b, n_heads, width = q.shape
+    block_size = pool.shape[2]
+    t = block_tables.shape[1]
+    tile_tokens = tile_blocks * block_size
+    n_tiles = (jnp.max(lengths) + tile_tokens - 1) // tile_tokens
+
+    def body(i, carry):
+        m, l, acc = carry
+        cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        rows = pool[layer, jnp.take(block_tables, cols, axis=1)].reshape(b, tile_tokens, width)
+        s = jnp.einsum("bhw,btw->bht", q, rows, preferred_element_type=jnp.float32) * scale
+        live = (i * tile_tokens + jnp.arange(tile_tokens))[None, :] < lengths[:, None]
+        s = jnp.where(live[:, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(live[:, None, :], jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.einsum(
+            "bht,btc->bhc", p.astype(rows.dtype), rows[..., :value_dim], preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc_new
+
+    init = (
+        jnp.full((b, n_heads, 1), NEG_INF, jnp.float32),
+        jnp.zeros((b, n_heads, 1), jnp.float32),
+        jnp.zeros((b, n_heads, value_dim), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
+    return acc / jnp.maximum(l, 1e-30)
+
+
+def _paged_latent_kernel(
+    layer_ref, lengths_ref, tables_ref,           # scalar prefetch (SMEM)
+    q_ref, pool_hbm,                              # inputs
+    o_ref,                                        # output
+    buf, sems, acc_ref,                           # scratch
+    *, scale: float, tile_blocks: int, block_size: int, table_width: int, value_dim: int,
+):
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    length = lengths_ref[b]
+    tile_tokens = tile_blocks * block_size
+    n_tiles = (length + tile_tokens - 1) // tile_tokens
+    rows = q_ref.shape[0]
+
+    def copies(tile, slot):
+        """The tile's block copies into buffer ``slot``, whole (see the GQA kernel)."""
+        out = []
+        for j in range(tile_blocks):
+            col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
+            blk = tables_ref[b * table_width + col]
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[layer, blk], buf.at[slot, pl.ds(j * block_size, block_size)], sems.at[slot]))
+        return out
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[...]                                    # [rows, width]
+
+    def body(i, carry):
+        m, l = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < n_tiles)
+        def _next():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        tile = buf[slot]                              # [tile_tokens, width]
+        s = jax.lax.dot_general(
+            q, tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                     # [rows, tile_tokens]
+        k_idx = i * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_idx < length, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # masked: exp(NEG_INF - m) = 0
+        alpha = jnp.exp(m - m_new)
+        pv = jax.lax.dot_general(
+            p.astype(tile.dtype), tile[:, :value_dim], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                             # [rows, value_dim]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        return m_new, l * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+    init = (jnp.full((rows, 1), NEG_INF, jnp.float32), jnp.zeros((rows, 1), jnp.float32))
+    _, l = jax.lax.fori_loop(0, n_tiles, body, init)
+    o_ref[...] = acc_ref[...] / jnp.maximum(l, 1e-30)
+
+
+def _paged_latent_pallas(q, pool, layer, block_tables, lengths, scale, value_dim, tile_blocks, *, interpret: bool):
+    b, n_heads, width = q.shape
+    block_size = pool.shape[2]
+    t = block_tables.shape[1]
+    packing = _sublane_packing(q.dtype)
+    rows = -(-n_heads // packing) * packing           # padding rows are zero queries, dropped below
+    q = jnp.pad(q, ((0, 0), (0, rows - n_heads), (0, 0)))
+    tile_tokens = tile_blocks * block_size
+    kernel = functools.partial(
+        _paged_latent_kernel, scale=scale, tile_blocks=tile_blocks, block_size=block_size,
+        table_width=t, value_dim=value_dim,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, rows, width), lambda bi, *_: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, rows, value_dim), lambda bi, *_: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tile_tokens, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, value_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_latent_attention",
+    )(layer.reshape(1), lengths, block_tables.reshape(-1).astype(jnp.int32), q, pool)
+    return out[:, :n_heads, :]

@@ -150,8 +150,10 @@ class DecodeKernels:
         import jax
 
         from determined_tpu.models.transformer import (
+            SERVE_COUNTERS,
             _check_decodable,
             init_kv_cache,
+            kv_bytes_per_token,
             transformer_decode,
             transformer_prefill,
             transformer_prefill_suffix,
@@ -187,13 +189,21 @@ class DecodeKernels:
             "serve.setup.params_to_device", "serve", t_params, t_pool,
             {"bytes": param_bytes},
         )
+        # bytes_per_token: what attention reads of the pool for one cached
+        # token over all layers (K and V rows, or one latent row a layer)
         tracer.record_span(
-            "serve.setup.kv_pool", "serve", t_pool, t_pooled, {"bytes": pool_bytes}
+            "serve.setup.kv_pool", "serve", t_pool, t_pooled,
+            {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg)},
         )
         #: (call, jitted call returned, logits ready, logits on the host) of
         #: the newest ``decode``: the engine, which knows the step, turns
         #: them into the ``serve.decode.*`` spans
         self.last_decode_stamps: Optional[Tuple[float, float, float, float]] = None
+        #: a model with expert layers: the decode program returns one more
+        #: row of logits, whose first entries are these counts of the step
+        #: (``transformer_decode``); the newest step's are kept by name
+        self._counters: Tuple[str, ...] = SERVE_COUNTERS if model_cfg.moe_experts else ()
+        self.last_decode_counters: Dict[str, float] = {}
         #: suffix-prefill token width: the prompt padded up to whole blocks
         #: so the chunked walk slices full blocks only (one trace)
         self._suffix_pad = (
@@ -219,6 +229,7 @@ class DecodeKernels:
                 transformer_decode,
                 model_cfg,
                 chunk_blocks=serve_cfg.decode_chunk_blocks,
+                counters=bool(self._counters),
             ),
             allowed=1,
         )
@@ -291,6 +302,9 @@ class DecodeKernels:
         t2 = mono()
         out = np.asarray(logits)
         self.last_decode_stamps = (t0, t1, t2, mono())
+        if self._counters:
+            counted, out = out[-1], out[:-1]
+            self.last_decode_counters = {n: float(counted[j]) for j, n in enumerate(self._counters)}
         return out
 
 
@@ -343,6 +357,9 @@ class ServeEngine:
         self._step_seconds = {
             "decode_wait": 0.0, "d2h": 0.0, "sample": 0.0, "admission": 0.0,
         }
+        #: what the decode steps of a model with expert layers counted,
+        #: cumulative, by the counter's name (``/stats`` ``step_counters``)
+        self._step_counters: Dict[str, float] = {}
         #: the engine's own step counter: decode steps (and admit-only
         #: iterations) that did work
         self._steps = 0
@@ -578,6 +595,7 @@ class ServeEngine:
                 **{k: round(v, 6) for k, v in self._step_seconds.items()},
                 "steps": self._steps,
             }
+            step_counters = dict(self._step_counters)
         if recent is not None:
             latency = {
                 name: _summary_ms([r[i] for r in recent if r[i] is not None])
@@ -595,6 +613,10 @@ class ServeEngine:
             # waiting for the decode program, copying its logits to the
             # host, sampling, admitting (prefill and first sample)
             "step_seconds": step_seconds,
+            # cumulative counts of the decode steps, where the model has
+            # expert layers: picks that landed on the experts held here and
+            # held experts that got a row, each summed over layers and steps
+            "step_counters": step_counters,
             "queue_depth": self.queue.depth(),
             # static queue bound: the router's saturation signal — at
             # queue_depth >= queue_capacity the next submit would 429
@@ -774,6 +796,11 @@ class ServeEngine:
         stamps = getattr(self.kernels, "last_decode_stamps", None)
         if stamps is not None and stamps[0] < t0:
             stamps = None
+        counted = getattr(self.kernels, "last_decode_counters", None) or {}
+        if counted:
+            with self._stats_lock:
+                for name, value in counted.items():
+                    self._step_counters[name] = self._step_counters.get(name, 0.0) + value
         tracer = self._tracer
         if tracer.enabled:
             # what the step's attention had to read: with the two a trace
@@ -786,6 +813,7 @@ class ServeEngine:
                     "active": n_active,
                     "live_kv_tokens": int((positions + 1).sum()),
                     "max_context": int(positions.max()) + 1,
+                    **counted,
                 },
             )
             if stamps is not None:

@@ -478,11 +478,10 @@ def test_what_cannot_honour_a_window_refuses_by_name():
     # ring attention knows no window
     with pytest.raises(ValueError, match="ring attention.*sliding_attention"):
         TransformerLM(_tiny(attention_impl="ring")).init(jax.random.key(0), tokens)
-    # nor does the serving forward, nor YaRN, nor experts
+    # nor does the serving forward; YaRN on full layers alone it serves (since PR 34)
     with pytest.raises(ValueError, match="sliding-window layers.*YaRN"):
         _check_decodable(_tiny())
-    with pytest.raises(ValueError, match="rope_parameters"):
-        _check_decodable(_tiny(layer_types=None, sliding_window=None))
+    _check_decodable(_tiny(layer_types=None, sliding_window=None))
     _check_decodable(_tiny(layer_types=(FULL,) * 3, sliding_window=None, rope_parameters=None))
 
 

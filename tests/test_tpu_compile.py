@@ -440,3 +440,64 @@ def test_decode_step_holds_no_copy_of_a_layers_pool(tpu_devices, monkeypatch, fo
     text = compiled.as_text()
     assert _kernels(text) == (cfg.n_layers if form == "kernel" else 0)
     assert _pool_sized_results(text, cshape) == []
+
+
+# -- latent attention and served experts: the DeepSeek-V3 cell's shapes --------
+
+
+def test_the_latent_decode_kernel_compiles_at_the_dsv3_cells_shape(tpu_devices):
+    """64 lanes x 128 heads against ONE 640-wide row a token (576 values
+    and 64 zeros), blocks of 16, a table of 448 columns (7,168 positions), a
+    pool of 24,576 blocks x 5 layers: tiles of 512 tokens, two buffers."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, pool, tables, positions):
+        return paged_mod.paged_latent_attention(q, pool, 3, tables, positions, scale=0.135, value_dim=512)
+
+    text = _compile(
+        fn, aval((64, 128, 640), jnp.bfloat16), aval((5, 24576, 16, 640), jnp.bfloat16),
+        aval((64, 448), jnp.int32), aval((64,), jnp.int32),
+    )
+    assert _kernels(text) == 1 and "paged_latent_attention" in text
+    assert paged_mod.latent_kernel_takes(640, 512, 16, jnp.bfloat16) and not paged_mod.latent_kernel_takes(576, 512, 16, jnp.bfloat16)
+
+
+def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, monkeypatch):
+    """The decode program of the cell at its widths, lanes and pool, bfloat16
+    leaves, depth cut to the dense layer and one expert layer: the latent
+    kernel a layer, and in the expert layer the two row movements and three
+    grouped products over [7168, 2048] blocks (58.7 MB of VMEM for a block's
+    two buffers).  Each Mosaic call keeps its name in the optimized program,
+    so the scopes a trace's reader asks for list it."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, transformer_decode
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        softmax_scale=0.135234, dense_prefix=1, moe_experts=256, moe_every=1, moe_top_k=8, moe_intermediate_size=2048,
+        moe_experts_held=(0, 16), moe_router="sigmoid_grouped", moe_n_group=8, moe_topk_group=4,
+        moe_routed_scaling=2.5, moe_shared_experts=1, param_dtype=jnp.bfloat16,
+        rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 40, "beta_fast": 32,
+                                            "original_max_position_embeddings": 4096, "beta_slow": 1, "attention_factor": 1.0}},
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cache = {"kv": aval(kv_cache_shape(cfg, 24576, 16), cfg.dtype)}
+    fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+    compiled = fn.lower(params, aval((64,)), aval((64,)), aval((64, 448)), cache).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 2 + 5
+    assert mem.temp_size_in_bytes < 256 * 1024**2 and mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2   # the pool is donated
+    scopes = program_scopes(text)
+    assert {"serve.mla", "serve.mla.attend", "serve.moe.route", "serve.moe.experts", "serve.moe.shared"} <= set(scopes)
+    assert sum("paged_latent_attention" in n for n in scopes["serve.mla.attend"]) == 2
+    named = [n for n in scopes["serve.moe.experts"] if re.match(r"(moe_gmm|moe_rows_of_tokens|moe_tokens_of_rows)", n)]
+    assert len(named) == 5, scopes["serve.moe.experts"]
