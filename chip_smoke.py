@@ -20,7 +20,7 @@ Phases with one chip, in order, each a failure of the whole run:
            Trainer.fit): finite, flat-or-falling loss, Mosaic kernels in
            the compiled step, a manifest-verified checkpoint on disk
   serve    ``dtpu serve <that checkpoint>``: concurrent POST /v1/generate
-           of mixed lengths, two sharing a prefix (suffix prefill), every
+           of mixed lengths, two sharing a prefix (a warm prefill), every
            reply tokens-and-no-error, then SIGTERM and the drain exit 75
   compare  after the server is gone, ``scripts/serve_reference_check.py``
            runs the plain training forward over prompt + reply and accepts
@@ -490,7 +490,7 @@ def phase_serve(ckpt: str, vocab: int) -> Tuple[str, Dict[str, Any]]:
         if stats.get("errored") or stats.get("failed"):
             raise SmokeFailure(f"serve: the engine counted errors: {stats}")
         if not stats.get("prefix_hits"):
-            raise SmokeFailure(f"serve: no prefix hit, so the suffix prefill never ran: {stats}")
+            raise SmokeFailure(f"serve: no prefix hit, so no prefill started from a cached prefix: {stats}")
 
         os.kill(proc.pid, signal.SIGTERM)
         try:
@@ -504,7 +504,7 @@ def phase_serve(ckpt: str, vocab: int) -> Tuple[str, Dict[str, Any]]:
         stop(proc)
     text = read(log_path)
     facts = facts_of(text, "serve")
-    for name in ("prefill", "prefill_suffix", "decode"):
+    for name in ("prefill", "decode"):
         if f"jit.compile.serve.{name}" not in facts["compiles"]:
             raise SmokeFailure(f"serve: {name} never ran (no first-call line)")
     replies_path = os.path.join(work, "replies.json")

@@ -37,7 +37,11 @@ from determined_tpu.ops.attention import (
     dot_product_attention,
     reference_attention,
 )
-from determined_tpu.ops.paged_attention import paged_decode_attention, paged_latent_attention
+from determined_tpu.ops.paged_attention import (
+    paged_chunk_attention,
+    paged_decode_attention,
+    paged_latent_attention,
+)
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
 from determined_tpu.parallel.sharding import with_sharding_constraint
@@ -920,11 +924,27 @@ def _attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: ja
     return attend
 
 
+def _attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
+    """The prefill walk's read: the queries of chunk ``chunk`` (positions
+    ``chunk * s ..``) against the keys up to the chunk's end, read from the
+    pool a tile at a time (``ops/paged_attention.py paged_chunk_attention``)."""
+
+    def attend(q, k, v, cache, i):
+        b, h, s, d = q.shape
+        att = paged_chunk_attention(
+            q.reshape(b, cfg.kv_heads, h // cfg.kv_heads, s, d), cache["k"], cache["v"], i, block_tables, chunk,
+            scale=cfg.head_dim ** -0.5,
+        )
+        return att.astype(cfg.dtype).reshape(b, h, s, d)
+
+    return attend
+
+
 def _attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
     """Queries against every token of every table column, gathered from the
     pool, under ``mask`` (True = may see: ``[s, T * block_size]`` where the
     lanes are alike, else ``[b, s, T * block_size]``) and a float32 softmax:
-    the suffix prefill's read, and the oracle the paged path is tested against."""
+    decode without the paged path, the oracle that path is tested against."""
 
     def attend(q, k, v, cache, i):
         keys = _gather_table(cfg, cache["k"], i, block_tables)
@@ -1015,10 +1035,28 @@ def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positi
     return attend
 
 
+def _latent_attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
+    """The prefill walk's read (``_attend_chunk``) in the latent space: every
+    head's queries ``[q_lat | q_rope | zeros]`` against the pool's rows, whose
+    first ``kv_lora_rank`` columns are the values."""
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, cache["kv"].shape[-1] - q.shape[-1]),))
+        with jax.named_scope("serve.mla.attend"):
+            out = paged_chunk_attention(
+                q[:, None], cache["kv"], None, i, block_tables, chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+            )
+        return jnp.einsum("bhsc,chv->bshv", out[:, 0].astype(cfg.dtype), w_v)
+
+    return attend
+
+
 def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
     """Queries against every row of every table column, gathered from the
-    pool, under ``mask`` (as ``_attend_table``'s) and a float32 softmax: the
-    suffix prefill's read, and the oracle the paged path is tested against."""
+    pool, under ``mask`` (as ``_attend_table``'s) and a float32 softmax:
+    decode without the paged path, the oracle that path is tested against."""
 
     def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
         b, t = block_tables.shape
@@ -1097,7 +1135,10 @@ def transformer_prefill(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, prompt_lens: jax.Array,
     block_tables: jax.Array, cache: Dict[str, jax.Array],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Full-prompt forward that also populates the paged cache.
+    """Full-prompt forward that also populates the paged cache: every
+    position's logits in one pass over the padded width.  The engine runs
+    :func:`transformer_prefill_chunked`, whose work follows the prompt; this
+    form stays as the oracle the walk and the decode step are tested against.
 
     ``tokens`` [B, S] is the prompt padded to a fixed S (one trace);
     ``prompt_lens`` [B] the real lengths; ``block_tables`` [B, T] each
@@ -1185,64 +1226,101 @@ def transformer_decode(
     return logits, cache
 
 
-def transformer_prefill_suffix(
+#: tokens an iteration of the prefill walk aims for.  An iteration sweeps every
+#: weight once, so its time is the sweep's until the chunk's own arithmetic
+#: passes it: at 256 tokens the sweep still bounds it at InternLM2's and at
+#: DeepSeek-V3's widths (PERF.md section 5), and a prompt pays for at most 255
+#: tokens it did not ask for.
+PREFILL_CHUNK_TOKENS = 256
+
+
+def prefill_chunk_tokens(block_size: int, prompt_tokens: int) -> int:
+    """Tokens a chunk of :func:`transformer_prefill_chunked`, from the shapes:
+    ``PREFILL_CHUNK_TOKENS`` in whole blocks and whole 128-wide tiles, or the
+    longest prompt in whole blocks where that is shorter.  A caller pads its
+    prompts to a multiple of it."""
+    unit = math.lcm(block_size, 128)
+    chunk = -(-PREFILL_CHUNK_TOKENS // unit) * unit
+    return min(chunk, -(-prompt_tokens // block_size) * block_size)
+
+
+def transformer_prefill_chunked(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, start_lens: jax.Array,
     prompt_lens: jax.Array, block_tables: jax.Array, cache: Dict[str, jax.Array],
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill only the un-cached suffix of each prompt (prefix caching).
+    """Prefill each prompt from ``start_lens`` on, a chunk of tokens at a time:
+    the serving engine's one prefill program, for a cold prompt (``start`` 0)
+    and for the un-cached suffix of one whose prefix is cached alike.
 
-    ``tokens`` [B, S] is the FULL prompt padded to a multiple of the block
-    size; ``start_lens`` [B] how many leading tokens already sit in cache
-    blocks mapped into ``block_tables`` (block-aligned by construction —
-    only full blocks are shared); ``prompt_lens`` [B] the real lengths.
-    Returns (last_logits [B, vocab] f32 — the logits at ``prompt_len - 1``
-    each lane samples its first token from — and the updated cache).
+    ``tokens`` [B, S] is the FULL prompt padded to a multiple of the chunk
+    (``prefill_chunk_tokens(block_size, S)``); ``start_lens`` [B] how many
+    leading tokens already sit in cache blocks mapped into ``block_tables``
+    (block-aligned by construction: only full blocks are shared);
+    ``prompt_lens`` [B] the real lengths.  Returns (last_logits [B, vocab] f32,
+    the logits at ``prompt_len - 1`` each lane samples its first token from,
+    and the updated cache).
 
-    The walk is one block of tokens per iteration of a dynamic-trip-count
-    ``fori_loop`` (``start//block_size .. ceil(len/block_size)``), so the
-    compute and the single compiled trace scale with the SUFFIX, not the
-    padded prompt width: a 70%-shared system prompt pays for its unique
-    tail only.  Queries attend against keys READ FROM THE CACHE (prefix
-    blocks written by whoever prefilled them first, suffix blocks written
-    by this call just before attending), masked ``k_pos <= q_pos``, which
-    makes a warm start and a cold ``start=0`` run of the same prompt
-    bitwise identical — the parity the prefix-cache admission tests pin.
-    Positions outside ``[start, len)`` write to scratch block 0 and their
-    logits are never selected; since keys come from the cache rather than
-    the local projection, garbage padding columns cannot leak into valid
-    ones.
+    The walk is one chunk of C tokens an iteration of a dynamic-trip-count
+    ``fori_loop``, chunks at the absolute positions ``[c * C, (c + 1) * C)``
+    for ``c`` in ``start // C .. ceil(len / C)``: the compute and the single
+    compiled trace follow the tokens ASKED, not the padded width, and a
+    70%-shared system prompt pays for its unique tail only.  An iteration
+    reads every weight once for C tokens.  Queries attend against keys READ
+    FROM THE CACHE up to the chunk's end (prefix blocks written by whoever
+    prefilled them first, the chunk's own written just before attending),
+    masked ``k_pos <= q_pos``, which makes a warm start and a cold ``start=0``
+    run of the same prompt bitwise identical, wherever ``start`` falls in its
+    chunk: the parity the prefix-cache admission tests pin.  Positions outside
+    ``[start, len)`` write to scratch block 0 and take no expert's rows;
+    since keys come from the cache rather than the local projection, garbage
+    padding columns cannot leak into valid ones.  The head runs once, on the
+    row of ``prompt_len - 1`` alone.
     """
     _check_decodable(cfg)
     block_size = _block_size(cache)
     b, s = tokens.shape
-    if s % block_size:
+    chunk = prefill_chunk_tokens(block_size, s)
+    if s % chunk:
         raise ValueError(
-            f"suffix prefill needs tokens padded to the block size (got S={s}, block_size={block_size})"
+            f"chunked prefill needs tokens padded to whole chunks (got S={s}, "
+            f"chunk={chunk}, block_size={block_size})"
         )
-    c_lo = jnp.min(start_lens) // block_size
-    c_hi = (jnp.max(prompt_lens) + block_size - 1) // block_size
-    k_pos = jnp.arange(block_tables.shape[1] * block_size)
+    blocks, t = chunk // block_size, block_tables.shape[1]
+    c_lo = jnp.min(start_lens) // chunk
+    c_hi = (jnp.max(prompt_lens) + chunk - 1) // chunk
+    offsets = jnp.arange(chunk)
 
     def body(c, carry):
-        cache, last_logits = carry
-        toks = jax.lax.dynamic_slice(tokens, (0, c * block_size), (b, block_size))
-        p = c * block_size + jnp.arange(block_size)  # absolute positions [bs]
-        valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, bs]
-        phys = jnp.where(valid, jax.lax.dynamic_slice(block_tables, (0, c), (b, 1)), 0)
-        slots = jnp.broadcast_to(jnp.arange(block_size)[None, :], (b, block_size))
-        attend = (_latent_attend_table if cfg.latent else _attend_table)(cfg, block_tables, k_pos[None, :] <= p[:, None])
-        x = _embed_rows(params, toks, cfg.dtype)
-        x, cache, _ = _serve_layers(cfg, params, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
-        logits = _head(params, x, cfg.dtype)
-        sel = prompt_lens - 1 - c * block_size  # [b]
-        contains = (sel >= 0) & (sel < block_size)
-        idx = jnp.clip(sel, 0, block_size - 1)
-        row = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0, :]
-        return cache, jnp.where(contains[:, None], row, last_logits)
+        cache, last = carry
+        toks = jax.lax.dynamic_slice(tokens, (0, c * chunk), (b, chunk))
+        p = c * chunk + offsets  # absolute positions [chunk]
+        valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, chunk]
+        # a padded prompt may be wider than the table: those columns hold no valid row
+        cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
+        phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
+        slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
+        attend = (_latent_attend_chunk if cfg.latent else _attend_chunk)(cfg, block_tables, c)
+        # a leaf stored wider than the compute dtype is read as it lies and
+        # converted on its way into each product, every iteration.  The
+        # conversions depend on nothing the loop changes, and XLA would move
+        # them before it: a second copy of the model in the compute dtype,
+        # made and held for the whole call (3.3 GiB of scratch at InternLM2's
+        # float32 leaves, and 11 ms before the first chunk).  Adding a zero
+        # that only the loop's counter decides keeps them where they are.
+        zero = (c < 0).astype(jnp.float32)
+        layers = {name: sub for name, sub in params.items() if name.startswith("block_")}
+        layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
+        # the rows first, then their conversion: the table is not swept an iteration
+        x = jnp.take(params["embed"]["embedding"], toks, axis=0).astype(cfg.dtype)
+        x, cache, _ = _serve_layers(cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
+        sel = prompt_lens - 1 - c * chunk  # [b]
+        row = jnp.take_along_axis(x, jnp.clip(sel, 0, chunk - 1)[:, None, None], axis=1)
+        return cache, jnp.where(((sel >= 0) & (sel < chunk))[:, None, None], row, last)
 
-    init = (cache, jnp.zeros((b, cfg.vocab_size), jnp.float32))
-    cache, last_logits = jax.lax.fori_loop(c_lo, c_hi, body, init)
-    return last_logits, cache
+    init = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
+    cache, last = jax.lax.fori_loop(c_lo, c_hi, body, init)
+    return _head(params, last, cfg.dtype, row=0), cache
+
 
 #: latent attention's hparams: passed to the config as they are (absent: GQA)
 _LATENT_HPARAMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "softmax_scale")

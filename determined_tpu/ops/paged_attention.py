@@ -577,3 +577,72 @@ def _paged_latent_pallas(q, pool, layer, block_tables, lengths, scale, value_dim
         name="paged_latent_attention",
     )(layer.reshape(1), lengths, block_tables.reshape(-1).astype(jnp.int32), q, pool)
     return out[:, :n_heads, :]
+
+
+# ---------------------------------------------------------------------------
+# A chunk of queries a lane: the prefill walk's read
+# ---------------------------------------------------------------------------
+
+
+def paged_chunk_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: Optional[jax.Array],
+    layer,
+    block_tables: jax.Array,
+    chunk,
+    *,
+    scale: float,
+    value_dim: Optional[int] = None,
+) -> jax.Array:
+    """Causal attention of one CHUNK of queries a lane over the paged pool:
+    the decode forms' mathematics with a block of queries, in ``jax.numpy``.
+
+    ``q`` [b, kv_heads, n_rep, s, width]: the queries at the absolute
+    positions ``chunk * s .. chunk * s + s - 1`` (``chunk`` may be traced;
+    ``s`` a multiple of the block size), grouped by the KV head they read.
+    GQA: ``k_pool`` / ``v_pool`` as :func:`paged_decode_attention`'s.  Latent
+    rows: ``kv_heads`` 1, ``n_rep`` every head, ``v_pool`` None, and the
+    values are the first ``value_dim`` columns of ``k_pool``'s rows.
+    Returns ``[b, kv_heads, n_rep, s, value width]`` float32.
+
+    Keys are folded a tile of ``s`` tokens at a time into a float32 online
+    softmax, tiles ``0 .. chunk``: the work follows the keys up to the
+    chunk's end, and the largest array is one tile's ``[heads, s, s]`` scores,
+    whatever the table's width.  A query sees ``k_pos <= q_pos``; every query
+    sees key 0, so no row of the running maximum is left at ``NEG_INF``.
+    Columns past the table's end re-read its last column, which only a query
+    past the table's end could see.
+    """
+    b, g, r, s, width = q.shape
+    block_size = k_pool.shape[2]
+    tile_blocks = s // block_size
+    t = block_tables.shape[1]
+    values = value_dim if v_pool is None else v_pool.shape[3] // g
+    q_pos = chunk * s + jnp.arange(s)
+
+    def body(i, carry):
+        m, l, acc = carry
+        cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        tbl = jnp.take(block_tables, cols, axis=1)  # [b, tile_blocks]
+        keys = k_pool[layer, tbl].reshape(b, s, g, width)
+        vals = keys[..., :values] if v_pool is None else v_pool[layer, tbl].reshape(b, s, g, values)
+        sc = jnp.einsum("bgrqw,btgw->bgrqt", q, keys, preferred_element_type=jnp.float32) * scale
+        seen = (i * s + jnp.arange(s))[None, :] <= q_pos[:, None]  # [s, s]; all of it before the last tile
+        sc = jnp.where(seen, sc, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)  # masked: exp(NEG_INF - m) = 0
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.einsum(
+            "bgrqt,btgv->bgrqv", p.astype(vals.dtype), vals, preferred_element_type=jnp.float32
+        )
+        return m_new, l_new, acc_new
+
+    init = (
+        jnp.full((b, g, r, s, 1), NEG_INF, jnp.float32),
+        jnp.zeros((b, g, r, s, 1), jnp.float32),
+        jnp.zeros((b, g, r, s, values), jnp.float32),
+    )
+    _, l, acc = jax.lax.fori_loop(0, chunk + 1, body, init)
+    return acc / l

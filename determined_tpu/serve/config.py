@@ -26,7 +26,8 @@ class ServeConfig:
     # ---- continuous batching ----------------------------------------------
     #: decode lanes: max sequences in flight per step (static batch shape)
     max_batch: int = 8
-    #: prompts are padded to this length for the single prefill trace
+    #: the longest prompt admitted (a prefill costs its prompt's chunks, not
+    #: this: ``prefill_chunk``)
     max_prompt_len: int = 128
     #: cap on tokens generated per request (requests may ask for fewer)
     max_new_tokens: int = 64
@@ -105,6 +106,20 @@ class ServeConfig:
     def blocks_per_seq(self) -> int:
         """Block-table width: logical blocks a worst-case sequence spans."""
         return self.blocks_for(self.max_seq_len)
+
+    @property
+    def prefill_chunk(self) -> int:
+        """Tokens an iteration of the prefill walk takes: a prefill computes
+        whole chunks, from the one its first un-cached token lies in to the
+        one that ends its prompt."""
+        from determined_tpu.models.transformer import prefill_chunk_tokens
+
+        return prefill_chunk_tokens(self.block_size, self.max_prompt_len)
+
+    def prefill_chunks(self, prompt_tokens: int, cached_tokens: int = 0) -> int:
+        """Chunks the prefill of a prompt walks, past ``cached_tokens`` of it."""
+        chunk = self.prefill_chunk
+        return -(-prompt_tokens // chunk) - cached_tokens // chunk
 
     @property
     def usable_blocks(self) -> int:

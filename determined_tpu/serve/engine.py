@@ -140,10 +140,11 @@ def _first_above(cum: np.ndarray, u: float) -> int:
 class DecodeKernels:
     """Compiled prefill/decode for one (model cfg, params) pair.
 
-    ``prefill`` runs one request at a time ([1, max_prompt_len] — padded,
-    single trace); ``decode`` steps all ``max_batch`` lanes at once.  The
-    cache argument is donated: each step writes into the buffers of the
-    previous one instead of copying the pool.
+    ``prefill`` / ``prefill_suffix`` run one request at a time through ONE
+    program, the chunked walk (single trace; its device time follows the
+    chunks the prompt asks for); ``decode`` steps all ``max_batch`` lanes at
+    once.  The cache argument is donated: each step writes into the buffers
+    of the previous one instead of copying the pool.
     """
 
     def __init__(self, model_cfg: Any, params: Any, serve_cfg: ServeConfig) -> None:
@@ -155,8 +156,7 @@ class DecodeKernels:
             init_kv_cache,
             kv_bytes_per_token,
             transformer_decode,
-            transformer_prefill,
-            transformer_prefill_suffix,
+            transformer_prefill_chunked,
         )
         from determined_tpu.utils.compilation_cache import (
             setup_compilation_cache,
@@ -166,7 +166,7 @@ class DecodeKernels:
         _check_decodable(model_cfg)
         tracer = get_tracer()
         t_setup = mono()
-        # a relaunched replica loads its three kernels from disk; keeps the
+        # a relaunched replica loads its two kernels from disk; keeps the
         # directory ``train.init`` applied when the engine came from a
         # checkpoint (``from_checkpoint``)
         setup_compilation_cache()
@@ -204,23 +204,16 @@ class DecodeKernels:
         #: (``transformer_decode``); the newest step's are kept by name
         self._counters: Tuple[str, ...] = SERVE_COUNTERS if model_cfg.moe_experts else ()
         self.last_decode_counters: Dict[str, float] = {}
-        #: suffix-prefill token width: the prompt padded up to whole blocks
-        #: so the chunked walk slices full blocks only (one trace)
-        self._suffix_pad = (
-            serve_cfg.blocks_for(serve_cfg.max_prompt_len) * serve_cfg.block_size
-        )
+        #: the prefill's token width: the longest prompt in whole chunks
+        #: (one trace; the walk's trip count follows each prompt)
+        self._prompt_pad = serve_cfg.prefill_chunks(serve_cfg.max_prompt_len) * serve_cfg.prefill_chunk
         sentinel = get_retrace_sentinel()
+        # cold requests run it with start=0, warm requests from the chunk
+        # of their first un-cached block; either way it is the SAME trace
+        # (dynamic trip count inside the program)
         prefill = sentinel.wrap(
             "serve.prefill_step",
-            functools.partial(transformer_prefill, model_cfg),
-            allowed=1,
-        )
-        # the prefix-cache admission path: cold requests run it with
-        # start=0, warm requests from their first un-cached block; either
-        # way it is the SAME trace (dynamic trip count inside the kernel)
-        prefill_suffix = sentinel.wrap(
-            "serve.prefill_suffix_step",
-            functools.partial(transformer_prefill_suffix, model_cfg),
+            functools.partial(transformer_prefill_chunked, model_cfg),
             allowed=1,
         )
         decode = sentinel.wrap(
@@ -234,12 +227,8 @@ class DecodeKernels:
             allowed=1,
         )
         self._prefill = timed_first_call(
-            jax.jit(_named(prefill, "serve_prefill"), donate_argnums=(4,)),
+            jax.jit(_named(prefill, "serve_prefill"), donate_argnums=(5,)),
             "jit.compile.serve.prefill",
-        )
-        self._prefill_suffix = timed_first_call(
-            jax.jit(_named(prefill_suffix, "serve_prefill_suffix"), donate_argnums=(5,)),
-            "jit.compile.serve.prefill_suffix",
         )
         self._decode = timed_first_call(
             jax.jit(_named(decode, "serve_decode"), donate_argnums=(4,)),
@@ -249,21 +238,27 @@ class DecodeKernels:
             "serve.setup", "serve", t_setup, mono(),
             {"param_bytes": param_bytes, "kv_pool_bytes": pool_bytes},
         )
+        # The first call of each program compiles it or loads it from the
+        # cache.  It is made here, by the thread that builds the kernels, and
+        # not by the engine's thread at the first request: taking the prefill
+        # program out of the cache costs a worker thread 7.3 s where it costs
+        # the main thread 0.6 (InternLM2-1.8B on a v5e; PERF.md, PR 35), and
+        # a replica that says it is ready has its programs on the device.
+        # Both calls write the scratch block alone: a one-token prompt under
+        # a table of block 0, and a step with every lane idle.
+        self._prefill_from([0], [0] * serve_cfg.blocks_per_seq, 0)
+        lanes = serve_cfg.max_batch
+        _, self.cache = self._decode(
+            self.params, np.zeros(lanes, np.int32), np.full(lanes, -1, np.int32),
+            np.zeros((lanes, serve_cfg.blocks_per_seq), np.int32), self.cache,
+        )
 
     # -- kernel entry points (device round trips happen HERE) ---------------
 
     def prefill(self, prompt: List[int], block_table: List[int]) -> np.ndarray:
-        """Run the padded prefill for one sequence, writing its K/V into
+        """Prefill one sequence from its first token, writing its K/V into
         the paged cache; returns the f32 logits at the last prompt token."""
-        cfg = self.serve_cfg
-        tokens = np.zeros((1, cfg.max_prompt_len), np.int32)
-        tokens[0, : len(prompt)] = prompt
-        table = np.asarray(block_table, np.int32)[None, :]
-        lens = np.asarray([len(prompt)], np.int32)
-        logits, self.cache = self._prefill(
-            self.params, tokens, lens, table, self.cache
-        )
-        return np.asarray(logits[0, len(prompt) - 1])
+        return self._prefill_from(prompt, block_table, 0)
 
     def prefill_suffix(
         self, prompt: List[int], block_table: List[int], start: int
@@ -271,12 +266,16 @@ class DecodeKernels:
         """Prefill only ``prompt[start:]`` (the un-cached suffix; ``start``
         is block-aligned — the cached prefix already sits in the mapped
         blocks).  Returns the f32 logits at the last prompt token."""
-        tokens = np.zeros((1, self._suffix_pad), np.int32)
+        return self._prefill_from(prompt, block_table, start)
+
+    def _prefill_from(self, prompt: List[int], block_table: List[int], start: int) -> np.ndarray:
+        # under both entry points, which a caller may wrap one by one
+        tokens = np.zeros((1, self._prompt_pad), np.int32)
         tokens[0, : len(prompt)] = prompt
         table = np.asarray(block_table, np.int32)[None, :]
         starts = np.asarray([start], np.int32)
         lens = np.asarray([len(prompt)], np.int32)
-        logits, self.cache = self._prefill_suffix(
+        logits, self.cache = self._prefill(
             self.params, tokens, starts, lens, table, self.cache
         )
         return np.asarray(logits[0])
@@ -357,6 +356,10 @@ class ServeEngine:
         self._step_seconds = {
             "decode_wait": 0.0, "d2h": 0.0, "sample": 0.0, "admission": 0.0,
         }
+        #: prompt tokens the prefills were asked for (past what the prefix
+        #: cache held) and tokens they computed (whole chunks), cumulative
+        self._prefill_tokens_asked = 0
+        self._prefill_tokens_computed = 0
         #: what the decode steps of a model with expert layers counted,
         #: cumulative, by the counter's name (``/stats`` ``step_counters``)
         self._step_counters: Dict[str, float] = {}
@@ -578,6 +581,10 @@ class ServeEngine:
                 "completed": self._completed,
                 "rejected": self._rejected,
                 "tokens_generated": self._tokens_generated,
+                # computed over asked is what the walk's whole chunks cost
+                # beyond the prompts (1.0: every prompt ended on a chunk's edge)
+                "prefill_tokens_asked": self._prefill_tokens_asked,
+                "prefill_tokens_computed": self._prefill_tokens_computed,
                 "errored": self._errored,
                 "http_5xx": self._http_5xx,
                 "latency_ms_avg": round(
@@ -693,20 +700,16 @@ class ServeEngine:
         )
         blocks = shared + private
         table = self._padded_table(blocks)
+        # the walk's trip count: whole chunks, from the one the first
+        # un-cached token lies in (0 cached when nothing matched)
+        chunks = self.cfg.prefill_chunks(len(req.prompt), cached_tokens)
+        computed = chunks * self.cfg.prefill_chunk
         try:
             with tracer.span(
                 "serve.prefill", cat="serve", request=req.id, step=step,
-                cached_tokens=cached_tokens,
+                cached_tokens=cached_tokens, chunks=chunks, computed_tokens=computed,
             ):
-                if cached_tokens:
-                    logits = self.kernels.prefill_suffix(
-                        req.prompt, table, cached_tokens
-                    )
-                else:
-                    # nothing matched: the wide single-pass prefill beats
-                    # the suffix kernel's block-sequential walk (its step
-                    # loop serializes what one pass runs in parallel)
-                    logits = self.kernels.prefill(req.prompt, table)
+                logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens)
         except BaseException:
             self.allocator.free(blocks)
             raise
@@ -725,6 +728,8 @@ class ServeEngine:
         with self._stats_lock:
             self._tokens_generated += 1
             self._step_seconds["admission"] += t_first - t_admit
+            self._prefill_tokens_asked += len(req.prompt) - cached_tokens
+            self._prefill_tokens_computed += computed
         if tracer.enabled:
             tracer.record_span(
                 "serve.first_sample", "serve", t_sample, t_first, {"request": req.id}

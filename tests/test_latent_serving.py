@@ -27,7 +27,7 @@ from determined_tpu.models.transformer import (
     kv_cache_shape,
     transformer_decode,
     transformer_prefill,
-    transformer_prefill_suffix,
+    transformer_prefill_chunked,
 )
 from determined_tpu.ops import grouped_matmul as gm, paged_attention as paged
 
@@ -173,15 +173,59 @@ def test_the_latent_kernel_and_the_jnp_walk_match_a_dense_softmax_over_ragged_la
 
 
 def test_suffix_prefill_from_a_shared_prefix_matches_the_reference_and_a_cold_start(model):
+    """Two lanes in one walk, each from its own start (the chunk is the
+    40-token width here)."""
     cfg, params, tokens, want = model
     tables = jnp.asarray([[1, 2, 3, 4, 5, 0, 0, 0], [6, 7, 8, 9, 10, 0, 0, 0]], jnp.int32)
     lens = jnp.asarray([37, 40], jnp.int32)
-    cold, cache = transformer_prefill_suffix(cfg, params, jnp.asarray(tokens), jnp.zeros(2, jnp.int32), lens, tables, init_kv_cache(cfg, 16, 8))
+    cold, cache = transformer_prefill_chunked(cfg, params, jnp.asarray(tokens), jnp.zeros(2, jnp.int32), lens, tables, init_kv_cache(cfg, 16, 8))
     np.testing.assert_allclose(np.asarray(cold[0]), want[0, 36], atol=3e-4)
     np.testing.assert_allclose(np.asarray(cold[1]), want[1, 39], atol=3e-4)
     # the first 16 and 24 tokens already sit in the pool: only the rest is computed, to the same logits
-    warm, _ = transformer_prefill_suffix(cfg, params, jnp.asarray(tokens), jnp.asarray([16, 24], jnp.int32), lens, tables, cache)
+    warm, _ = transformer_prefill_chunked(cfg, params, jnp.asarray(tokens), jnp.asarray([16, 24], jnp.int32), lens, tables, cache)
     np.testing.assert_array_equal(np.asarray(warm), np.asarray(cold))
+
+
+@pytest.fixture(scope="module")
+def long_model():
+    """The tiny latent + expert model over three chunks of 256, ONE jitted
+    walk under the retrace sentinel, and the full forward's logits of one row."""
+    from determined_tpu.lint._runtime import get_retrace_sentinel
+
+    cfg = tiny(max_seq_len=768)
+    params = build(cfg)
+    tokens = np.asarray(jax.random.randint(jax.random.key(4), (1, 768), 1, cfg.vocab_size), np.int32)
+    full = np.asarray(TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens)))[0]
+    tables = jnp.arange(1, 97, dtype=jnp.int32)[None, :]
+    sentinel = get_retrace_sentinel()
+    walk = jax.jit(sentinel.wrap(
+        "test.latent_prefill_walk", lambda t, s, n, c: transformer_prefill_chunked(cfg, params, t, s, n, tables, c), allowed=1,
+    ))
+
+    def run(n, start=0, cache=None):
+        padded = tokens.copy()
+        padded[0, n:] = 0
+        cache = init_kv_cache(cfg, 97, 8) if cache is None else cache
+        return walk(jnp.asarray(padded), jnp.asarray([start], jnp.int32), jnp.asarray([n], jnp.int32), cache)
+
+    return run, full, sentinel
+
+
+@pytest.mark.parametrize("n", [255, 256, 257, 2 * 256 + 17, 768])
+def test_the_prefill_walk_matches_the_full_forward_across_chunk_edges(long_model, n):
+    """Latent rows and expert layers at C - 1, C, C + 1, 2C + 17 and the padded
+    width: a chunk's rows outside the prompt take no expert's rows and write
+    the scratch block; a warm start inside a chunk (block-aligned, not
+    chunk-aligned: part of its chunk's rows are masked, so the held picks lie
+    elsewhere in the experts' buffer) is bitwise the cold run; one trace."""
+    run, full, sentinel = long_model
+    cold, cache = run(n)
+    np.testing.assert_allclose(np.asarray(cold[0]), full[n - 1], atol=3e-4)
+    start = (n // 2) // 8 * 8 + 8
+    assert start % 256
+    warm, _ = run(n, start, cache)
+    np.testing.assert_array_equal(np.asarray(warm), np.asarray(cold))
+    assert {r.label: r.traces for r in sentinel.records()}["test.latent_prefill_walk"] == 1
 
 
 def test_what_serving_still_refuses_it_refuses_by_name():

@@ -1,7 +1,8 @@
 """The serving forward is stated once (``models/transformer.py``, "KV-cache
-decode path"): one layer function under the three entry points, one ``_rope``
-for shared and per-lane positions, one masked softmax over gathered table
-rows.  The behaviour's guard is the parity tests of ``test_transformer.py``;
+decode path"): one layer function under the three entry points (the chunked
+prefill walk and the decode step the engine runs, the wide prefill the tests
+keep as their oracle), one ``_rope`` for shared and per-lane positions, one
+masked softmax over gathered table rows.  The behaviour's guard is the parity tests of ``test_transformer.py``;
 these pin the structure, so that a fourth hand-written loop, a second rope or
 a second copy of the table read cannot come back unseen."""
 
@@ -44,16 +45,16 @@ def _trace(which, cfg, params):
             )
         )(cache)
     return jax.make_jaxpr(
-        lambda c: tx.transformer_prefill_suffix(
+        lambda c: tx.transformer_prefill_chunked(
             cfg, params, tokens, jnp.asarray([4, 0], jnp.int32), lens, tables, c
         )
     )(cache)
 
 
-@pytest.mark.parametrize("which", ["prefill", "decode", "prefill_suffix"])
+@pytest.mark.parametrize("which", ["prefill", "decode", "prefill_chunked"])
 def test_every_entry_point_runs_the_one_layer_function(tiny, monkeypatch, which):
     """A trace of each serving program calls ``_serve_layer`` once a layer
-    (the suffix walk's body is traced once), and the q/k/v projection nowhere
+    (the chunked walk's body is traced once), and the q/k/v projection nowhere
     else."""
     cfg, params = tiny
     calls = {"layer": [], "proj": 0}

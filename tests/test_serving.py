@@ -655,6 +655,7 @@ def test_engine_keeps_every_name_the_benchmark_reads(kernels):
         assert callable(getattr(eng.allocator, name)), name
     assert set(eng.stats()) >= {
         "submitted", "completed", "rejected", "tokens_generated", "errored", "http_5xx",
+        "prefill_tokens_asked", "prefill_tokens_computed",
         "latency_ms_avg", "latency", "step_seconds", "queue_depth", "queue_capacity",
         "draining", "failed", "kv_cache", "kv_utilization", "prefix_hits",
         "prefix_tokens_saved", "prefix_hit_rate", "uptime_s", "lanes",
@@ -700,11 +701,74 @@ def test_retrace_sentinel_one_decode_trace(lm_setup):
         eng.stop()
     by_label = {r.label: r for r in sentinel.records()}
     assert by_label["serve.decode_step"].traces == 1
-    # cold admissions run the wide padded prefill, warm admissions the
-    # chunked suffix kernel — one trace each across every length mix
+    # cold and warm admissions run ONE program, the chunked walk, from
+    # start 0 or from the first un-cached block: one trace across every
+    # mix of lengths and starts, and no second prefill program
     assert by_label["serve.prefill_step"].traces == 1
-    assert by_label["serve.prefill_suffix_step"].traces == 1
+    assert set(by_label) == {"serve.decode_step", "serve.prefill_step"}
     assert sentinel.violations() == {}
+    sentinel.reset()
+
+
+#: three chunks of 256 to the longest prompt: lengths on both sides of a
+#: chunk's edges, and the longest prompt admitted
+CHUNKED_CFG = ServeConfig(
+    block_size=16, num_blocks=96, max_batch=2, max_prompt_len=600, max_new_tokens=8, queue_depth=8,
+)
+
+
+def test_a_prefill_costs_its_prompts_chunks_and_says_so(lm_setup, tracer):
+    """``serve.prefill`` carries the walk's trip count and what it computed,
+    ``/stats`` the two cumulative counts whose ratio is the padding share;
+    every length and a warm start run the one trace; the first token is the
+    full forward's."""
+    import dataclasses
+
+    from determined_tpu.lint._runtime import get_retrace_sentinel
+
+    cfg, _model, variables = lm_setup
+    cfg = dataclasses.replace(cfg, max_seq_len=CHUNKED_CFG.max_seq_len)
+    chunk = CHUNKED_CFG.prefill_chunk
+    assert chunk == 256 and ServeConfig(block_size=4, max_prompt_len=16, num_blocks=64).prefill_chunk == 16
+    sentinel = get_retrace_sentinel()
+    sentinel.reset()
+    kernels = DecodeKernels(cfg, variables, CHUNKED_CFG)
+    assert kernels._prompt_pad == 768
+    eng = ServeEngine(kernels)
+    rng = np.random.default_rng(3)
+    lengths = [chunk - 1, chunk, chunk + 1, 2 * chunk + 17, CHUNKED_CFG.max_prompt_len]
+    prompts = [[int(t) for t in rng.integers(1, 64, size=n)] for n in lengths]
+    # a warm admission whose first un-cached token lies inside chunk 1
+    prompts.append(prompts[3][:400] + [int(t) for t in rng.integers(1, 64, size=150)])
+    reqs = []
+    try:
+        for prompt in prompts:
+            reqs.append(eng.submit(prompt, max_new_tokens=1, temperature=0.0))
+            while not reqs[-1].done.is_set():
+                assert eng.step_once()
+    finally:
+        eng.stop()
+    model = TransformerLM(cfg)
+    for prompt, req in zip(prompts, reqs):
+        assert req.error is None
+        full = model.apply(variables, jnp.asarray(prompt, jnp.int32)[None, :])
+        assert req.output == [int(np.argmax(np.asarray(full[0, -1])))]
+    spans = {e["args"]["request"]: e["args"] for e in _spans(tracer, "serve.prefill")}
+    asked = computed = 0
+    for prompt, req in zip(prompts, reqs):
+        args = spans[req.id]
+        cached = 400 // 16 * 16 if prompt is prompts[-1] else 0
+        assert args["cached_tokens"] == cached
+        assert args["chunks"] == -(-len(prompt) // chunk) - cached // chunk
+        assert args["computed_tokens"] == args["chunks"] * chunk
+        asked += len(prompt) - cached
+        computed += args["computed_tokens"]
+    assert [spans[r.id]["chunks"] for r in reqs] == [1, 1, 2, 3, 3, 2]
+    st = eng.stats()
+    assert (st["prefill_tokens_asked"], st["prefill_tokens_computed"]) == (asked, computed)
+    assert computed == 12 * chunk and asked == sum(lengths) + 150
+    by_label = {r.label: r for r in sentinel.records()}
+    assert by_label["serve.prefill_step"].traces == 1 and sentinel.violations() == {}
     sentinel.reset()
 
 
@@ -762,6 +826,33 @@ def test_serve_spans_reach_tracer(lm_setup, tracer):
     assert {e["cat"] for e in _spans(tracer) if e["name"].startswith("serve.")} == {"serve"}
     sizes = _spans(tracer, "serve.setup")[0]["args"]
     assert sizes["param_bytes"] > 0 and sizes["kv_pool_bytes"] > 0
+
+
+def test_building_the_kernels_makes_each_programs_first_call(lm_setup, tracer):
+    """Both programs compile (or load) where ``DecodeKernels`` is built, on
+    the builder's thread, before any request: their first-call spans are
+    there, each traced once, and the two calls wrote the scratch block alone."""
+    from determined_tpu.lint._runtime import get_retrace_sentinel
+
+    cfg, _model, variables = lm_setup
+    sentinel = get_retrace_sentinel()
+    sentinel.reset()
+    kernels = DecodeKernels(cfg, variables, SERVE_CFG)
+    first = [e["name"] for e in _spans(tracer) if e["name"].startswith("jit.compile.")]
+    assert sorted(first) == ["jit.compile.serve.decode", "jit.compile.serve.prefill"]
+    assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
+    for pool in kernels.cache.values():
+        assert not np.asarray(pool[:, 1:]).any()
+    assert kernels.last_decode_stamps is None and kernels.last_decode_counters == {}
+    # a request then compiles nothing more
+    eng = ServeEngine(kernels)
+    req = eng.submit([1, 2, 3], max_new_tokens=2)
+    while not req.done.is_set():
+        assert eng.step_once()
+    eng.stop()
+    assert len([e for e in _spans(tracer) if e["name"].startswith("jit.compile.")]) == 2
+    assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
+    sentinel.reset()
 
 
 def test_request_spans_share_an_id_and_add_up(kernels, tracer):

@@ -70,7 +70,14 @@ def _real_kernels_no_cache(monkeypatch):
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    # as a fresh process has it: ``setup_compilation_cache`` turns it off for
+    # the process (any Trainer or DecodeKernels an earlier test file built),
+    # and a compile made HERE for a described chip then names a Mosaic call
+    # ``custom-call.N`` whatever its ``name=``
+    tracebacks = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
     yield
+    jax.config.update("jax_include_full_tracebacks_in_locations", tracebacks)
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
@@ -301,19 +308,20 @@ def test_fused_adamw_step_compiles_over_a_four_chip_mesh(tpu_devices, monkeypatc
 # -- the serving programs ----------------------------------------------------
 
 
-@pytest.mark.parametrize("which", ["prefill", "prefill_suffix", "decode"])
+@pytest.mark.parametrize("which", ["prefill", "prefill_wide", "decode"])
 def test_serve_programs_compile_on_one_chip(tpu_devices, which):
-    """``dtpu serve``'s three jitted programs with the default ServeConfig
-    at d2048 / 16 heads / vocab 32768 — paged scatters and gathers, the
-    donated cache — depth cut to 2 layers (depth repeats, it does not
-    change what the compiler must accept)."""
+    """``dtpu serve``'s two jitted programs (the chunked prefill walk, the
+    decode step) and the wide prefill the tests keep as their oracle, with
+    the default ServeConfig at d2048 / 16 heads / vocab 32768 — paged
+    scatters and gathers, the donated cache — depth cut to 2 layers (depth
+    repeats, it does not change what the compiler must accept)."""
     from determined_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
         kv_cache_shape,
         transformer_decode,
         transformer_prefill,
-        transformer_prefill_suffix,
+        transformer_prefill_chunked,
     )
     from determined_tpu.serve.config import ServeConfig
 
@@ -334,14 +342,14 @@ def test_serve_programs_compile_on_one_chip(tpu_devices, which):
     cshape = kv_cache_shape(cfg, sc.num_blocks, sc.block_size)
     cache = {"k": aval(cshape, cfg.dtype), "v": aval(cshape, cfg.dtype)}
     table = aval((1, sc.blocks_per_seq))
-    if which == "prefill":
+    if which == "prefill_wide":
         fn = jax.jit(functools.partial(transformer_prefill, cfg), donate_argnums=(4,))
         args = (params, aval((1, sc.max_prompt_len)), aval((1,)), table, cache)
-    elif which == "prefill_suffix":
+    elif which == "prefill":
         fn = jax.jit(
-            functools.partial(transformer_prefill_suffix, cfg), donate_argnums=(5,)
+            functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,)
         )
-        pad = sc.blocks_for(sc.max_prompt_len) * sc.block_size
+        pad = sc.prefill_chunks(sc.max_prompt_len) * sc.prefill_chunk
         args = (params, aval((1, pad)), aval((1,)), aval((1,)), table, cache)
     else:
         fn = jax.jit(
@@ -357,6 +365,82 @@ def test_serve_programs_compile_on_one_chip(tpu_devices, which):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 1024**3
     if which == "decode":  # the paged-attention kernel, once a layer
         assert _kernels(compiled.as_text()) == cfg.n_layers
+
+
+def _arrays_with_dims(text: str, dims) -> list:
+    """Array shapes of an optimized HLO module (results and operands alike,
+    inside fusions too) that have every one of ``dims`` among their dimensions."""
+    found = set()
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        shape = [int(d) for d in m.group(1).split(",")]
+        if all(shape.count(d) >= list(dims).count(d) for d in dims):
+            found.add(m.group(0))
+    return sorted(found)
+
+
+def _walk_compiled(one, cfg, *, num_blocks, max_prompt_len, table_width):
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_prefill_chunked
+
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shape = kv_cache_shape(cfg, num_blocks, 16)
+    cache = {"kv": aval(shape, cfg.dtype)} if cfg.latent else {"k": aval(shape, cfg.dtype), "v": aval(shape, cfg.dtype)}
+    assert prefill_chunk_tokens(16, max_prompt_len) == 256 and max_prompt_len % 256 == 0
+    fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,))
+    return fn.lower(params, aval((1, max_prompt_len)), aval((1,)), aval((1,)), aval((1, table_width)), cache).compile()
+
+
+def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(tpu_devices, monkeypatch):
+    """The walk at DeepSeek-V3's published widths, the cell's pool, table and
+    ``max_prompt_len`` 4,096, depth cut to the dense layer and one expert
+    layer: its scratch is a fraction of the wide pass's (1.32 GiB at this
+    depth, 1.35 at the cell's five layers: PERF.md section 4), and no array
+    anywhere in it has heads x chunk x ``max_seq_len`` (a chunk's scores
+    against the whole table: 0.94 GB a layer); a tile's [128, 256, 256] is
+    the largest the attention builds."""
+    from determined_tpu.models.transformer import TransformerConfig
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    cfg = TransformerConfig(
+        vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        softmax_scale=0.135234, dense_prefix=1, moe_experts=256, moe_every=1, moe_top_k=8, moe_intermediate_size=2048,
+        moe_experts_held=(0, 16), moe_router="sigmoid_grouped", moe_n_group=8, moe_topk_group=4,
+        moe_routed_scaling=2.5, moe_shared_experts=1, param_dtype=jnp.bfloat16,
+        rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 40, "beta_fast": 32,
+                                            "original_max_position_embeddings": 4096, "beta_slow": 1, "attention_factor": 1.0}},
+    )
+    compiled = _walk_compiled(SingleDeviceSharding(tpu_devices[0]), cfg, num_blocks=24576, max_prompt_len=4096, table_width=448)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5 * 1024**3
+    assert mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2  # the pool is donated
+    assert _arrays_with_dims(text, (128, 256, 7168)) == [] and _arrays_with_dims(text, (128, 256, 4096)) == []
+    assert _arrays_with_dims(text, (128, 256, 256)) != []
+    assert _kernels(text) == 5  # the expert layer's two row movements and three grouped products
+
+
+def test_the_prefill_walk_at_internlm2s_widths_keeps_no_second_copy_of_the_model(tpu_devices):
+    """InternLM2-1.8B's widths and float32 leaves, the decode cell's pool,
+    table and ``max_prompt_len`` 1,280, 6 of 24 layers: the leaves'
+    conversions stay inside the loop (moved before it they are a bfloat16
+    copy of every layer, 126 MB a layer: 1.29 GiB of scratch here, 3.3 at
+    full depth, against 0.56 and 1.20), and no array has heads x chunk x
+    ``max_seq_len``."""
+    from determined_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=92544, d_model=2048, n_layers=6, n_heads=16, n_kv_heads=8, d_ff=8192, max_seq_len=2048, rope_theta=1e6,
+    )
+    compiled = _walk_compiled(SingleDeviceSharding(tpu_devices[0]), cfg, num_blocks=3500, max_prompt_len=1280, table_width=128)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.8 * 1024**3
+    assert _arrays_with_dims(text, (16, 256, 2048)) == [] and _arrays_with_dims(text, (2, 256, 2048)) == []
+    entry = text[text.index("ENTRY "):]
+    assert not re.search(r"= bf16\[(2048,8192|8192,2048)\]\S* (convert|fusion)\(", entry)
 
 
 def _pool_sized_results(text: str, pool_shape) -> list:

@@ -399,12 +399,81 @@ def test_chunked_decode_rejects_nondivisor_chunk():
         )
 
 
+# -- the chunked prefill walk: lengths that straddle a chunk's edges -----------
+
+WALK_BLOCK, WALK_PAD = 16, 768  # three chunks of 256
+WALK_LENGTHS = [255, 256, 257, 2 * 256 + 17, WALK_PAD]
+
+
+@pytest.fixture(scope="module")
+def walk_setup():
+    """A tiny GQA model and ONE jitted walk, under the retrace sentinel, for
+    every length below; the full forward's logits of one 768-token row."""
+    import dataclasses
+
+    from determined_tpu.lint._runtime import get_retrace_sentinel
+    from determined_tpu.models import transformer as tx
+
+    cfg, model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=5)
+    cfg = dataclasses.replace(cfg, max_seq_len=WALK_PAD)
+    params = variables["params"]
+    assert tx.prefill_chunk_tokens(WALK_BLOCK, WALK_PAD) == tx.PREFILL_CHUNK_TOKENS == 256
+    tokens = np.asarray(jax.random.randint(jax.random.key(2), (1, WALK_PAD), 1, cfg.vocab_size), np.int32)
+    full = np.asarray(TransformerLM(cfg).apply(variables, jnp.asarray(tokens)))[0]
+    table = jnp.arange(1, 1 + WALK_PAD // WALK_BLOCK, dtype=jnp.int32)[None, :]
+    sentinel = get_retrace_sentinel()
+    walk = jax.jit(sentinel.wrap(
+        "test.prefill_walk", lambda t, s, n, c: tx.transformer_prefill_chunked(cfg, params, t, s, n, table, c), allowed=1,
+    ))
+
+    def run(n, start=0, cache=None):
+        padded = tokens.copy()
+        padded[0, n:] = 0
+        if cache is None:
+            cache = init_kv_cache(cfg, num_blocks=1 + WALK_PAD // WALK_BLOCK, block_size=WALK_BLOCK)
+        return walk(jnp.asarray(padded), jnp.asarray([start], jnp.int32), jnp.asarray([n], jnp.int32), cache)
+
+    return run, full, sentinel
+
+
+@pytest.mark.parametrize("n", WALK_LENGTHS)
+def test_the_prefill_walk_matches_the_full_forward_across_chunk_edges(walk_setup, n):
+    """C - 1, C, C + 1, 2C + 17 and the padded width: the walk's one logits
+    row is the full forward's at ``n - 1``, and a warm start that is
+    block-aligned but not chunk-aligned is bitwise the cold run."""
+    run, full, sentinel = walk_setup
+    cold, cache = run(n)
+    assert cold.shape == (1, full.shape[1]) and cold.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(cold[0]), full[n - 1], atol=2e-5, rtol=2e-4)
+    start = (n // 2) // WALK_BLOCK * WALK_BLOCK + WALK_BLOCK  # 128, 144, 144, 272, 400: inside a chunk
+    assert start % WALK_BLOCK == 0 and start % 256
+    warm, _ = run(n, start, cache)
+    np.testing.assert_array_equal(np.asarray(warm), np.asarray(cold))
+    # one trace, whatever the length and the start
+    assert {r.label: r.traces for r in sentinel.records()}["test.prefill_walk"] == 1
+
+
+def test_the_prefill_walk_refuses_a_width_that_is_no_whole_chunk():
+    from determined_tpu.models.transformer import prefill_chunk_tokens, transformer_prefill_chunked
+
+    # whole blocks and whole 128-wide tiles; the longest prompt where that is shorter
+    assert prefill_chunk_tokens(16, 4096) == 256 and prefill_chunk_tokens(16, 100) == 112
+    assert prefill_chunk_tokens(4, 16) == 16 and prefill_chunk_tokens(48, 4096) == 384
+    cfg, _model, variables = _tiny_lm(jnp.float32, n_kv_heads=2)
+    cache = init_kv_cache(cfg, num_blocks=40, block_size=16)
+    with pytest.raises(ValueError, match="whole chunks"):
+        transformer_prefill_chunked(
+            cfg, variables["params"], np.zeros((1, 272), np.int32), jnp.asarray([0]), jnp.asarray([5]),
+            np.arange(1, 18, dtype=np.int32)[None, :], cache,
+        )
+
+
 def test_prefill_suffix_matches_wide_prefill():
-    """Cold suffix prefill (start=0) reproduces the wide padded prefill at
-    f32 tolerance, and a warm start over already-written prefix blocks is
-    BITWISE equal to the cold suffix run — both paths attend over the same
-    stored cache bits, so prefix-cached admission cannot drift."""
-    from determined_tpu.models.transformer import transformer_prefill_suffix
+    """The walk from start=0 reproduces the wide padded prefill (the oracle)
+    at f32 tolerance, and a warm start over already-written prefix blocks is
+    BITWISE equal to the cold run — both attend over the same stored cache
+    bits, so prefix-cached admission cannot drift."""
+    from determined_tpu.models.transformer import transformer_prefill_chunked
 
     cfg, _model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=11)
     params = variables["params"]
@@ -422,7 +491,7 @@ def test_prefill_suffix_matches_wide_prefill():
     padded12 = np.zeros((1, 12), np.int32)  # whole blocks only
     padded12[0, : len(prompt)] = prompt
     cache = init_kv_cache(cfg, num_blocks=16, block_size=block_size)
-    cold_logits, cold_cache = transformer_prefill_suffix(
+    cold_logits, cold_cache = transformer_prefill_chunked(
         cfg, params, padded12, jnp.asarray([0]), jnp.asarray([len(prompt)]),
         table, cache,
     )
@@ -433,7 +502,7 @@ def test_prefill_suffix_matches_wide_prefill():
 
     # warm admission: the first 2 blocks already hold the prefix bits;
     # re-run only the suffix (start=8) against the cold run's cache
-    warm_logits, _warm_cache = transformer_prefill_suffix(
+    warm_logits, _warm_cache = transformer_prefill_chunked(
         cfg, params, padded12, jnp.asarray([8]), jnp.asarray([len(prompt)]),
         table, cold_cache,
     )
