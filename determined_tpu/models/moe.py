@@ -135,13 +135,10 @@ class MoE(nn.Module):
             jnp.float32,
         )
         # routing decisions in f32: bf16 softmax ties misroute tokens
-        gates = jax.nn.softmax(
-            jnp.einsum("ngd,de->nge", xg.astype(jnp.float32), router)
-        )
-        dispatch, combine, aux = jax.vmap(
-            lambda gate: _top2_dispatch(gate, capacity)
-        )(gates)
-        aux = aux.mean()
+        with jax.named_scope("moe.route"):
+            gates = jax.nn.softmax(jnp.einsum("ngd,de->nge", xg.astype(jnp.float32), router))
+            dispatch, combine, aux = jax.vmap(lambda gate: _top2_dispatch(gate, capacity))(gates)
+            aux = aux.mean()
 
         # under manual SPMD the params hold only this device's experts
         e_param = e
@@ -177,18 +174,21 @@ class MoE(nn.Module):
         # dispatch: [n,g,e,c] x [n,g,d] -> [n,e,c,d]; under an
         # "expert"-sharded mesh axis XLA turns these einsums into the
         # all-to-alls
-        expert_in = jnp.einsum(
-            "ngec,ngd->necd", dispatch.astype(cd), xg.astype(cd)
-        )
-        h = jnp.einsum("necd,edf->necf", expert_in, w_in.astype(cd))
-        gate = jnp.einsum("necd,edf->necf", expert_in, w_gate.astype(cd))
-        h = nn.silu(gate) * h
-        expert_out = jnp.einsum("necf,efd->necd", h, w_out.astype(cd))
-        y = jnp.einsum("ngec,necd->ngd", combine.astype(cd), expert_out)
-        if self.expert_axis_name is not None:
-            y = jax.lax.psum(y, self.expert_axis_name)
-        y = y.reshape(n_groups * grp, d)[:g]
-        return y.reshape(b, s, d), aux.astype(jnp.float32)
+        with jax.named_scope("moe.dispatch"):
+            expert_in = jnp.einsum(
+                "ngec,ngd->necd", dispatch.astype(cd), xg.astype(cd)
+            )
+        with jax.named_scope("moe.experts"):
+            h = jnp.einsum("necd,edf->necf", expert_in, w_in.astype(cd))
+            gate = jnp.einsum("necd,edf->necf", expert_in, w_gate.astype(cd))
+            h = nn.silu(gate) * h
+            expert_out = jnp.einsum("necf,efd->necd", h, w_out.astype(cd))
+        with jax.named_scope("moe.combine"):
+            y = jnp.einsum("ngec,necd->ngd", combine.astype(cd), expert_out)
+            if self.expert_axis_name is not None:
+                y = jax.lax.psum(y, self.expert_axis_name)
+            y = y.reshape(n_groups * grp, d)[:g]
+            return y.reshape(b, s, d), aux.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -465,7 +465,8 @@ class RoutedExperts(nn.Module):
             with jax.named_scope("moe.combine"):
                 y = jax.lax.psum(y, self.expert_axis_name)
         if self.shared_experts:
-            y = y + _shared_experts(p, xf.astype(self.dtype)).astype(y.dtype)
+            with jax.named_scope("moe.shared"):
+                y = y + _shared_experts(p, xf.astype(self.dtype)).astype(y.dtype)
         return y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)
 
 

@@ -310,6 +310,12 @@ def _rope(x: jax.Array, positions: jax.Array, rope: Rope) -> jax.Array:
     return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm's numerics, stated once: float32 mean of squares, the rest in x's dtype."""
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
+
+
 def _maybe_partition(partition: bool, init, names):
     """with_partitioning when annotations apply; plain init under manual
     SPMD (pipeline stages inside shard_map)."""
@@ -329,8 +335,8 @@ class RMSNorm(nn.Module):
             (x.shape[-1],),
             self.param_dtype,
         )
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-        return (x * jax.lax.rsqrt(var + self.eps).astype(x.dtype)) * scale.astype(x.dtype)
+        with jax.named_scope("block.norm"):
+            return _rms(x, scale, self.eps)
 
 
 class Attention(nn.Module):
@@ -358,20 +364,21 @@ class Attention(nn.Module):
             ),
             name=name,
         )
-        q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "wq")(x)
-        k = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wk")(x)
-        v = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wv")(x)
-        # [b, s, h, d] -> [b, h, s, d]
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        with jax.named_scope("attn.qkv"):
+            q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "wq")(x)
+            k = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wk")(x)
+            v = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wv")(x)
+            # [b, s, h, d] -> [b, h, s, d]
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
-        positions = jnp.arange(s)
-        if cfg.seq_axis_name is not None:
-            # manual SPMD inside a pipeline stage: s is the LOCAL shard
-            # length; rope positions are global (contiguous assignment)
-            positions = positions + jax.lax.axis_index(cfg.seq_axis_name) * s
-        rope = cfg.rope(self.layer_type)
-        q = _rope(q, positions, rope)
-        k = _rope(k, positions, rope)
+            positions = jnp.arange(s)
+            if cfg.seq_axis_name is not None:
+                # manual SPMD inside a pipeline stage: s is the LOCAL shard
+                # length; rope positions are global (contiguous assignment)
+                positions = positions + jax.lax.axis_index(cfg.seq_axis_name) * s
+            rope = cfg.rope(self.layer_type)
+            q = _rope(q, positions, rope)
+            k = _rope(k, positions, rope)
         window = cfg.window(self.layer_type)
 
         impl = cfg.attention_impl
@@ -388,40 +395,40 @@ class Attention(nn.Module):
                 "ring attention (a `seq` mesh axis, attention: ring) knows no "
                 f"sliding window: layer type {self.layer_type!r} cannot run under it"
             )
-        if cfg.seq_axis_name is not None:
-            # already inside shard_map over the seq axis: run the ring on
-            # local shards (zigzag-balanced for causal)
-            from determined_tpu.ops.ring_attention import ring_attention_local
+        # named for the device trace: the two kinds cost differently (a ring has no window)
+        with jax.named_scope("attn.window" if window is not None else "attn.full"):
+            if cfg.seq_axis_name is not None:
+                # already inside shard_map over the seq axis: run the ring on
+                # local shards (zigzag-balanced for causal)
+                from determined_tpu.ops.ring_attention import ring_attention_local
 
-            out = ring_attention_local(
-                q, k, v, axis_name=cfg.seq_axis_name, causal=True
-            )
-        elif use_ring:
-            if self.mesh is None:
-                raise ValueError("ring attention requires the mesh")
-            out = ring_attention(q, k, v, self.mesh, causal=True)
-        else:
-            # named for the device trace: the two kinds cost differently
-            with jax.named_scope("attn.window" if window is not None else "attn.full"):
+                out = ring_attention_local(
+                    q, k, v, axis_name=cfg.seq_axis_name, causal=True
+                )
+            elif use_ring:
+                if self.mesh is None:
+                    raise ValueError("ring attention requires the mesh")
+                out = ring_attention(q, k, v, self.mesh, causal=True)
+            else:
                 out = dot_product_attention(
                     q, k, v, causal=True, impl=impl, mesh=self.mesh, window=window
                 )
-        out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
-        out = nn.DenseGeneral(
-            cfg.d_model,
-            axis=(-2, -1),
-            use_bias=False,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            dot_general=qdg,
-            kernel_init=_maybe_partition(
-                cfg.partition_params,
-                nn.initializers.lecun_normal(),
-                ("heads", "head_dim", "embed"),
-            ),
-            name="wo",
-        )(out)
-        return out
+        with jax.named_scope("attn.out"):
+            out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
+            return nn.DenseGeneral(
+                cfg.d_model,
+                axis=(-2, -1),
+                use_bias=False,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                dot_general=qdg,
+                kernel_init=_maybe_partition(
+                    cfg.partition_params,
+                    nn.initializers.lecun_normal(),
+                    ("heads", "head_dim", "embed"),
+                ),
+                name="wo",
+            )(out)
 
 
 def _latent_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
@@ -455,9 +462,12 @@ class LatentAttention(nn.Module):
             name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
             for name, (shape, logical, init) in _latent_param_shapes(cfg).items()
         }
-        q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, x, jnp.arange(x.shape[1]), cfg.rope(FULL))
-        out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0)
-        return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype))
+        with jax.named_scope("attn.qkv"):
+            q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, x, jnp.arange(x.shape[1]), cfg.rope(FULL))
+        with jax.named_scope("attn.full"):
+            out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0)
+        with jax.named_scope("attn.out"):
+            return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype))
 
 
 class MLP(nn.Module):
@@ -481,12 +491,13 @@ class MLP(nn.Module):
             ),
             name=name,
         )
-        gate = dense(cfg.ff_dim, ("embed", "mlp"), "w_gate")(x)
-        up = dense(cfg.ff_dim, ("embed", "mlp"), "w_up")(x)
-        h = nn.silu(gate) * up
-        if cfg.partition_params:
-            h = with_sharding_constraint(h, ("batch", "length", "mlp"), mesh=self.mesh)
-        return dense(cfg.d_model, ("mlp", "embed"), "w_down")(h)
+        with jax.named_scope("mlp.dense"):
+            gate = dense(cfg.ff_dim, ("embed", "mlp"), "w_gate")(x)
+            up = dense(cfg.ff_dim, ("embed", "mlp"), "w_up")(x)
+            h = nn.silu(gate) * up
+            if cfg.partition_params:
+                h = with_sharding_constraint(h, ("batch", "length", "mlp"), mesh=self.mesh)
+            return dense(cfg.d_model, ("mlp", "embed"), "w_down")(h)
 
 
 class Block(nn.Module):
@@ -570,9 +581,10 @@ class TransformerLM(nn.Module):
             ),
             name="embed",
         )
-        x = embed(tokens)
-        if cfg.partition_params:
-            x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
+        with jax.named_scope("lm.embed"):
+            x = embed(tokens)
+            if cfg.partition_params:
+                x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(Block, prevent_cse=False)
@@ -600,7 +612,8 @@ class TransformerLM(nn.Module):
             # never hit HBM.  Init always takes the logits path, so the
             # param tree includes lm_head either way.
             return (x, aux_total) if return_aux else x
-        out = lm_head(x).astype(jnp.float32)
+        with jax.named_scope("loss.ce"):  # the head's product: the loss's, fused or not
+            out = lm_head(x).astype(jnp.float32)
         return (out, aux_total) if return_aux else out
 
 
@@ -724,8 +737,9 @@ def pipeline_forward(
     emb = nn.Embed(
         cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, param_dtype=jnp.float32
     )
-    x = emb.apply({"params": outer["embed"]}, tokens)
-    x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=mesh, rules=rules)
+    with jax.named_scope("lm.embed"):
+        x = emb.apply({"params": outer["embed"]}, tokens)
+        x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=mesh, rules=rules)
 
     stage_cfg = dataclasses.replace(
         cfg,
@@ -771,7 +785,8 @@ def pipeline_forward(
         cfg.vocab_size, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
         dot_general=make_dot_general(cfg.quantized_matmul),
     )
-    logits = head.apply({"params": outer["lm_head"]}, x).astype(jnp.float32)
+    with jax.named_scope("loss.ce"):
+        logits = head.apply({"params": outer["lm_head"]}, x).astype(jnp.float32)
     return (logits, aux) if return_aux else logits
 
 
@@ -839,12 +854,13 @@ def _block_size(cache: Dict[str, jax.Array]) -> int:
 
 def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     """RMSNorm with the exact numerics of the ``RMSNorm`` module."""
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
+    with jax.named_scope("serve.norm"):
+        return _rms(x, scale, eps)
 
 
 def _attn_proj(p: Dict[str, Any], x: jax.Array, dtype: Any) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q/k/v projections as ``Attention`` computes them, to [b, heads, s, d]."""
+    # under the caller's scope (``serve.attn.qkv``, with the rope that follows)
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"]["kernel"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"]["kernel"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bhsk", x, p["wv"]["kernel"].astype(dtype))
@@ -890,14 +906,16 @@ def _gather_table(cfg: TransformerConfig, pool: jax.Array, layer: int, block_tab
 
 def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Array:
     """Embedding rows of ``tokens`` in the compute dtype."""
-    return jnp.take(params["embed"]["embedding"].astype(dtype), tokens, axis=0)
+    with jax.named_scope("serve.embed"):
+        return jnp.take(params["embed"]["embedding"].astype(dtype), tokens, axis=0)
 
 
 def _head(params: Dict[str, Any], x: jax.Array, dtype: Any, row: Optional[int] = None) -> jax.Array:
     """Final norm and ``lm_head``: float32 logits at every position of ``x``, or at ``row`` alone."""
     x = _rms_apply(x, params["ln_f"]["scale"])
-    x = x if row is None else x[:, row, :]
-    return (x @ params["lm_head"]["kernel"].astype(dtype)).astype(jnp.float32)
+    with jax.named_scope("serve.head"):
+        x = x if row is None else x[:, row, :]
+        return (x @ params["lm_head"]["kernel"].astype(dtype)).astype(jnp.float32)
 
 
 # The attention backends of the serving layer: ``attend(q, k, v, cache, i)`` with
@@ -1097,23 +1115,29 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None):
         with jax.named_scope("serve.mla"):
             p = blk["attn"]
             q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, h, positions, rope)
-            row = jnp.concatenate([c_kv, k_r], axis=-1)
-            row = jnp.pad(row, ((0, 0), (0, 0), (0, cache["kv"].shape[-1] - row.shape[-1])))
-            cache = {"kv": cache["kv"].at[i, phys, slots].set(row.reshape(*phys.shape, -1))}
+            with jax.named_scope("serve.kv.write"):
+                row = jnp.concatenate([c_kv, k_r], axis=-1)
+                row = jnp.pad(row, ((0, 0), (0, 0), (0, cache["kv"].shape[-1] - row.shape[-1])))
+                cache = {"kv": cache["kv"].at[i, phys, slots].set(row.reshape(*phys.shape, -1))}
             att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, i)
             x = x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(dt))
     else:
-        q, k, v = _attn_proj(blk["attn"], h, dt)
-        q, k = _rope(q, positions, rope), _rope(k, positions, rope)
-        cache = {
-            "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
-            "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
-        }
-        att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
-        x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
+        with jax.named_scope("serve.attn.qkv"):
+            q, k, v = _attn_proj(blk["attn"], h, dt)
+            q, k = _rope(q, positions, rope), _rope(k, positions, rope)
+        with jax.named_scope("serve.kv.write"):
+            cache = {
+                "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
+                "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
+            }
+        with jax.named_scope("serve.attn.attend"):  # whichever backend the entry point picked
+            att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
+        with jax.named_scope("serve.attn.out"):
+            x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
     h = _rms_apply(x, blk["ln2"]["scale"])
     if not cfg.use_moe(i):
-        return x + _mlp_apply(blk["mlp"], h, dt), cache, None
+        with jax.named_scope("serve.mlp"):
+            return x + _mlp_apply(blk["mlp"], h, dt), cache, None
     from determined_tpu.models.moe import serve_routed_experts
 
     y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
@@ -1153,15 +1177,16 @@ def transformer_prefill(
     b, s = tokens.shape
     x = _embed_rows(params, tokens, cfg.dtype)
     positions = jnp.arange(s)
-    # physical destination of every (lane, position): padded tail -> scratch
-    phys = jnp.where(
-        positions[None, :] < prompt_lens[:, None],
-        jnp.take_along_axis(
-            block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)), axis=1
-        ),
-        0,
-    )
-    slots = jnp.broadcast_to((positions % block_size)[None, :], (b, s))
+    with jax.named_scope("serve.kv.write"):
+        # physical destination of every (lane, position): padded tail -> scratch
+        phys = jnp.where(
+            positions[None, :] < prompt_lens[:, None],
+            jnp.take_along_axis(
+                block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)), axis=1
+            ),
+            0,
+        )
+        slots = jnp.broadcast_to((positions % block_size)[None, :], (b, s))
     attend = _latent_attend_local(cfg) if cfg.latent else _attend_local
     # the padded tail takes no expert's rows
     live = positions[None, :] < prompt_lens[:, None] if cfg.moe_experts else None
@@ -1208,21 +1233,27 @@ def transformer_decode(
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
     x = _embed_rows(params, tokens[:, None], cfg.dtype)
-    phys = jnp.where(
-        active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
-    )
+    with jax.named_scope("serve.kv.write"):  # where each lane's row goes: idle lanes -> scratch
+        phys = jnp.where(
+            active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
+        )
     if chunk_blocks:
         attend = (_latent_attend_paged if cfg.latent else _attend_paged)(cfg, block_tables, positions)
     else:
         # every cache position up to and including the current token
-        mask = (jnp.arange(t * block_size)[None, :] <= pos[:, None]) & active[:, None]  # [B, kv_len]
+        with jax.named_scope("serve.attn.attend"):
+            mask = (jnp.arange(t * block_size)[None, :] <= pos[:, None]) & active[:, None]  # [B, kv_len]
         attend = (_latent_attend_table if cfg.latent else _attend_table)(cfg, block_tables, mask[:, None, :])
     live = active[:, None] if cfg.moe_experts else None
-    x, cache, counted = _serve_layers(cfg, params, x, pos[:, None], (phys, pos % block_size), attend, cache, live)
+    pos_col = pos[:, None]
+    with jax.named_scope("serve.kv.write"):
+        slots = pos % block_size
+    x, cache, counted = _serve_layers(cfg, params, x, pos_col, (phys, slots), attend, cache, live)
     logits = _head(params, x, cfg.dtype, row=0)
     if counters and counted is not None:
-        row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
-        logits = jnp.concatenate([logits, row], axis=0)
+        with jax.named_scope("serve.head"):  # the counters ride in the logits' own copy
+            row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
+            logits = jnp.concatenate([logits, row], axis=0)
     return logits, cache
 
 
@@ -1286,19 +1317,22 @@ def transformer_prefill_chunked(
             f"chunk={chunk}, block_size={block_size})"
         )
     blocks, t = chunk // block_size, block_tables.shape[1]
-    c_lo = jnp.min(start_lens) // chunk
-    c_hi = (jnp.max(prompt_lens) + chunk - 1) // chunk
-    offsets = jnp.arange(chunk)
+    with jax.named_scope("serve.walk"):  # the trip count; the loop below is under it too, around its layers' scopes
+        c_lo = jnp.min(start_lens) // chunk
+        c_hi = (jnp.max(prompt_lens) + chunk - 1) // chunk
+        offsets = jnp.arange(chunk)
 
     def body(c, carry):
         cache, last = carry
-        toks = jax.lax.dynamic_slice(tokens, (0, c * chunk), (b, chunk))
+        with jax.named_scope("serve.embed"):
+            toks = jax.lax.dynamic_slice(tokens, (0, c * chunk), (b, chunk))
         p = c * chunk + offsets  # absolute positions [chunk]
-        valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, chunk]
-        # a padded prompt may be wider than the table: those columns hold no valid row
-        cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
-        phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
-        slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
+        with jax.named_scope("serve.kv.write"):
+            valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, chunk]
+            # a padded prompt may be wider than the table: those columns hold no valid row
+            cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
+            phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
+            slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
         attend = (_latent_attend_chunk if cfg.latent else _attend_chunk)(cfg, block_tables, c)
         # a leaf stored wider than the compute dtype is read as it lies and
         # converted on its way into each product, every iteration.  The
@@ -1310,15 +1344,18 @@ def transformer_prefill_chunked(
         zero = (c < 0).astype(jnp.float32)
         layers = {name: sub for name, sub in params.items() if name.startswith("block_")}
         layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
-        # the rows first, then their conversion: the table is not swept an iteration
-        x = jnp.take(params["embed"]["embedding"], toks, axis=0).astype(cfg.dtype)
+        with jax.named_scope("serve.embed"):
+            # the rows first, then their conversion: the table is not swept an iteration
+            x = jnp.take(params["embed"]["embedding"], toks, axis=0).astype(cfg.dtype)
         x, cache, _ = _serve_layers(cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
-        sel = prompt_lens - 1 - c * chunk  # [b]
-        row = jnp.take_along_axis(x, jnp.clip(sel, 0, chunk - 1)[:, None, None], axis=1)
-        return cache, jnp.where(((sel >= 0) & (sel < chunk))[:, None, None], row, last)
+        with jax.named_scope("serve.head"):  # the one row the head will read
+            sel = prompt_lens - 1 - c * chunk  # [b]
+            row = jnp.take_along_axis(x, jnp.clip(sel, 0, chunk - 1)[:, None, None], axis=1)
+            return cache, jnp.where(((sel >= 0) & (sel < chunk))[:, None, None], row, last)
 
-    init = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
-    cache, last = jax.lax.fori_loop(c_lo, c_hi, body, init)
+    with jax.named_scope("serve.walk"):
+        init = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
+        cache, last = jax.lax.fori_loop(c_lo, c_hi, body, init)
     return _head(params, last, cfg.dtype, row=0), cache
 
 
@@ -1568,8 +1605,14 @@ class LMTrial(JaxTrial):
                 clip_norm=float(g("grad_clip", 1.0)),
                 mu_dtype=mu_dtype,
             )
+        clip = optax.clip_by_global_norm(float(g("grad_clip", 1.0)))
+
+        def clip_update(updates, state, params=None):
+            with jax.named_scope("optim.clip"):  # as fused_adamw names its own norm and scale
+                return clip.update(updates, state, params)
+
         return optax.chain(
-            optax.clip_by_global_norm(float(g("grad_clip", 1.0))),
+            optax.GradientTransformation(clip.init, clip_update),
             optax.adamw(
                 schedule,
                 weight_decay=float(g("weight_decay", 0.01)),
@@ -1643,7 +1686,8 @@ class LMTrial(JaxTrial):
         self, model: TransformerLM, params: Any, batch: Dict[str, jax.Array], rng: jax.Array
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        with jax.named_scope("lm.embed"):
+            inputs, targets = tokens[:, :-1], tokens[:, 1:]
         g = self.context.get_hparam
         fused = g("fused_ce", "auto")
         if fused == "auto":
@@ -1672,11 +1716,13 @@ class LMTrial(JaxTrial):
             )
         else:
             (logits, moe_aux), moe_load = self._apply(model, params, inputs, return_aux=True)
-            loss = optax.softmax_cross_entropy_with_integer_labels(logits, targets).mean()
-        metrics = {"perplexity": jnp.exp(loss), **moe_load}
-        if model.cfg.moe_experts > 0:
-            metrics["moe_aux_loss"] = moe_aux
-            loss = loss + model.cfg.moe_aux_weight * moe_aux
+            with jax.named_scope("loss.ce"):
+                loss = optax.softmax_cross_entropy_with_integer_labels(logits, targets).mean()
+        with jax.named_scope("loss.ce"):
+            metrics = {"perplexity": jnp.exp(loss), **moe_load}
+            if model.cfg.moe_experts > 0:
+                metrics["moe_aux_loss"] = moe_aux
+                loss = loss + model.cfg.moe_aux_weight * moe_aux
         return loss, metrics
 
     def _pipeline_loss(
@@ -1720,11 +1766,13 @@ class LMTrial(JaxTrial):
                 rules=self.context.rules, return_aux=True,
                 schedule=sched, virtual_stages=vstages,
             )
-            loss = optax.softmax_cross_entropy_with_integer_labels(logits, targets).mean()
-        metrics = {"perplexity": jnp.exp(loss)}
-        if model.cfg.moe_experts > 0:
-            metrics["moe_aux_loss"] = moe_aux
-            loss = loss + model.cfg.moe_aux_weight * moe_aux
+            with jax.named_scope("loss.ce"):
+                loss = optax.softmax_cross_entropy_with_integer_labels(logits, targets).mean()
+        with jax.named_scope("loss.ce"):
+            metrics = {"perplexity": jnp.exp(loss)}
+            if model.cfg.moe_experts > 0:
+                metrics["moe_aux_loss"] = moe_aux
+                loss = loss + model.cfg.moe_aux_weight * moe_aux
         return loss, metrics
 
     def evaluate_batch(

@@ -110,6 +110,7 @@ def _tile_ce16_bwd(res, g):
 _tile_ce_bf16_residual.defvjp(_tile_ce16_fwd, _tile_ce16_bwd)
 
 
+@jax.named_scope("loss.ce")
 def fused_cross_entropy(
     hidden: jax.Array,            # [batch, seq, d] (or [tokens, d])
     kernel: jax.Array,            # [d, vocab]
@@ -137,6 +138,8 @@ def fused_cross_entropy(
     ``chunk_size=None`` picks by the PER-SHARD f32 residual size
     (``batch_shards`` = product of batch-sharding mesh axes: under dp the
     tile is sharded, so the global token count overstates it).
+
+    Everything here, forward and backward, runs under the scope ``loss.ce``.
     """
     d = hidden.shape[-1]
     x = hidden.reshape(-1, d)

@@ -218,8 +218,9 @@ class FusedAdamW:
         t = (count + 1).astype(jnp.float32)
         lr = self.learning_rate(count) if callable(self.learning_rate) else self.learning_rate
         if self.clip_norm is not None:
-            gn = optax.global_norm(grads)
-            cs = jnp.minimum(1.0, self.clip_norm / jnp.maximum(gn, 1e-16))
+            with jax.named_scope("optim.clip"):
+                gn = optax.global_norm(grads)
+                cs = jnp.minimum(1.0, self.clip_norm / jnp.maximum(gn, 1e-16))
         else:
             cs = jnp.ones(())
         bc1 = 1.0 - self.b1 ** t

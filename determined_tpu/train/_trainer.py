@@ -420,16 +420,19 @@ class Trainer:
                     # sync the ACCUMULATED grads once — inside the scan the
                     # markers would issue agg collectives per optimizer step
                     grads = overlap.apply_grad_sync(grads)
-            if hasattr(tx, "apply_step"):
-                # fused full-step optimizer (ops/fused_adamw.py): produces
-                # new params directly — materializing an updates tree would
-                # cost two extra HBM passes on a bandwidth-bound step
-                new_params, new_opt = tx.apply_step(
-                    grads, state.opt_state, state.params, shardings=update_shardings
-                )
-            else:
-                updates, new_opt = tx.update(grads, state.opt_state, state.params)
-                new_params = optax.apply_updates(state.params, updates)
+            # named for the device trace (``jit.scopes``): the optimizer's
+            # share of a step, whichever optimizer the trial built
+            with jax.named_scope("optim.update"):
+                if hasattr(tx, "apply_step"):
+                    # fused full-step optimizer (ops/fused_adamw.py): produces
+                    # new params directly — materializing an updates tree would
+                    # cost two extra HBM passes on a bandwidth-bound step
+                    new_params, new_opt = tx.apply_step(
+                        grads, state.opt_state, state.params, shardings=update_shardings
+                    )
+                else:
+                    updates, new_opt = tx.update(grads, state.opt_state, state.params)
+                    new_params = optax.apply_updates(state.params, updates)
             if overlap is not None:
                 # the closing all-gather: sharded update back to the
                 # params' own layout; opt state pinned so donated buffers
@@ -443,18 +446,20 @@ class Trainer:
             # gets its current learning rate reported with every batch
             schedule = getattr(trial, "lr_schedule", None)
             if schedule is not None:
-                metrics["lr"] = schedule(state.step).astype(jnp.float32)
-            acc = {
-                k: state.metric_acc[k] + metrics[k].astype(jnp.float32)
-                for k in state.metric_acc
-            }
-            return state.replace(
-                step=state.step + 1,
-                params=new_params,
-                opt_state=new_opt,
-                metric_acc=acc,
-                metric_count=state.metric_count + 1.0,
-            )
+                with jax.named_scope("optim.update"):
+                    metrics["lr"] = schedule(state.step).astype(jnp.float32)
+            with jax.named_scope("train.metrics"):  # the step's bookkeeping: scalars
+                acc = {
+                    k: state.metric_acc[k] + metrics[k].astype(jnp.float32)
+                    for k in state.metric_acc
+                }
+                return state.replace(
+                    step=state.step + 1,
+                    params=new_params,
+                    opt_state=new_opt,
+                    metric_acc=acc,
+                    metric_count=state.metric_count + 1.0,
+                )
 
         from determined_tpu.train._reducer import MEAN, get_reducer
 
