@@ -905,9 +905,10 @@ def _gather_table(cfg: TransformerConfig, pool: jax.Array, layer: int, block_tab
 
 
 def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Array:
-    """Embedding rows of ``tokens`` in the compute dtype."""
+    """Embedding rows of ``tokens`` in the compute dtype: the rows first, then
+    their conversion, so that the table is read where ``tokens`` point and not swept."""
     with jax.named_scope("serve.embed"):
-        return jnp.take(params["embed"]["embedding"].astype(dtype), tokens, axis=0)
+        return jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(dtype)
 
 
 def _head(params: Dict[str, Any], x: jax.Array, dtype: Any, row: Optional[int] = None) -> jax.Array:
@@ -1344,9 +1345,7 @@ def transformer_prefill_chunked(
         zero = (c < 0).astype(jnp.float32)
         layers = {name: sub for name, sub in params.items() if name.startswith("block_")}
         layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
-        with jax.named_scope("serve.embed"):
-            # the rows first, then their conversion: the table is not swept an iteration
-            x = jnp.take(params["embed"]["embedding"], toks, axis=0).astype(cfg.dtype)
+        x = _embed_rows(params, toks, cfg.dtype)
         x, cache, _ = _serve_layers(cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
         with jax.named_scope("serve.head"):  # the one row the head will read
             sel = prompt_lens - 1 - c * chunk  # [b]

@@ -2,7 +2,8 @@
 decode path"): one layer function under the three entry points (the chunked
 prefill walk and the decode step the engine runs, the wide prefill the tests
 keep as their oracle), one ``_rope`` for shared and per-lane positions, one
-masked softmax over gathered table rows.  The behaviour's guard is the parity tests of ``test_transformer.py``;
+masked softmax over gathered table rows, one embedding lookup that takes its
+rows before it converts them.  The behaviour's guard is the parity tests of ``test_transformer.py``;
 these pin the structure, so that a fourth hand-written loop, a second rope or
 a second copy of the table read cannot come back unseen."""
 
@@ -19,39 +20,48 @@ BLOCK = 4
 TABLE_W = 4  # blocks a lane: 16 tokens
 
 
+def _params(cfg):
+    from flax.core import meta as flax_meta
+
+    variables = TransformerLM(cfg).init(jax.random.key(3), jnp.zeros((1, 8), jnp.int32))
+    return flax_meta.unbox(variables)["params"]
+
+
 @pytest.fixture(scope="module")
 def tiny():
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2, max_seq_len=32,
         dtype=jnp.float32,
     )
-    variables = TransformerLM(cfg).init(jax.random.key(3), jnp.zeros((1, 8), jnp.int32))
-    from flax.core import meta as flax_meta
+    return cfg, _params(cfg)
 
-    return cfg, flax_meta.unbox(variables)["params"]
+
+ENTRY_POINTS = ["prefill", "decode", "prefill_chunked"]
 
 
 def _trace(which, cfg, params):
+    """The jaxpr of one serving program, the parameters and the cache its arguments
+    (an operation on a closed-over array would run at trace time and leave no equation)."""
     cache = tx.init_kv_cache(cfg, num_blocks=16, block_size=BLOCK)
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     tokens = jnp.zeros((2, 8), jnp.int32)
     lens = jnp.asarray([7, 5], jnp.int32)
     if which == "prefill":
-        return jax.make_jaxpr(lambda c: tx.transformer_prefill(cfg, params, tokens, lens, tables, c))(cache)
+        return jax.make_jaxpr(lambda p, c: tx.transformer_prefill(cfg, p, tokens, lens, tables, c))(params, cache)
     if which == "decode":
         return jax.make_jaxpr(
-            lambda c: tx.transformer_decode(
-                cfg, params, tokens[:, 0], jnp.asarray([3, -1], jnp.int32), tables, c, chunk_blocks=1
+            lambda p, c: tx.transformer_decode(
+                cfg, p, tokens[:, 0], jnp.asarray([3, -1], jnp.int32), tables, c, chunk_blocks=1
             )
-        )(cache)
+        )(params, cache)
     return jax.make_jaxpr(
-        lambda c: tx.transformer_prefill_chunked(
-            cfg, params, tokens, jnp.asarray([4, 0], jnp.int32), lens, tables, c
+        lambda p, c: tx.transformer_prefill_chunked(
+            cfg, p, tokens, jnp.asarray([4, 0], jnp.int32), lens, tables, c
         )
-    )(cache)
+    )(params, cache)
 
 
-@pytest.mark.parametrize("which", ["prefill", "decode", "prefill_chunked"])
+@pytest.mark.parametrize("which", ENTRY_POINTS)
 def test_every_entry_point_runs_the_one_layer_function(tiny, monkeypatch, which):
     """A trace of each serving program calls ``_serve_layer`` once a layer
     (the chunked walk's body is traced once), and the q/k/v projection nowhere
@@ -76,6 +86,69 @@ def test_every_entry_point_runs_the_one_layer_function(tiny, monkeypatch, which)
     _trace(which, cfg, params)
     assert calls["layer"] == list(range(cfg.n_layers))
     assert calls["proj"] == cfg.n_layers
+
+
+@pytest.mark.parametrize("which", ENTRY_POINTS)
+def test_every_entry_point_takes_its_embedding_through_embed_rows(tiny, monkeypatch, which):
+    """One statement of the lookup: a trace of each serving program calls
+    ``_embed_rows`` once (the chunked walk's body is traced once)."""
+    cfg, params = tiny
+    calls = []
+    embed = tx._embed_rows
+
+    def counted(params_, tokens, dtype):
+        calls.append(tuple(tokens.shape))
+        return embed(params_, tokens, dtype)
+
+    monkeypatch.setattr(tx, "_embed_rows", counted)
+    _trace(which, cfg, params)
+    assert len(calls) == 1, calls
+
+
+@pytest.mark.parametrize(
+    "table_dtype,dtype",
+    [(jnp.float32, jnp.bfloat16), (jnp.float32, jnp.float32), (jnp.bfloat16, jnp.bfloat16)],
+    ids=["f32-bf16", "f32-f32", "bf16-bf16"],
+)
+@pytest.mark.parametrize("shape", [(5,), (5, 1), (3, 7)], ids=["b", "b1", "bs"])
+def test_embed_rows_equals_the_rows_of_the_converted_table(table_dtype, dtype, shape):
+    """A conversion is elementwise: the rows taken and then converted are, bit
+    for bit, the rows of the table converted whole (the statement ``_embed_rows``
+    had, which swept ``vocab`` rows a step to read ``len(tokens)`` of them)."""
+    table = (jax.random.normal(jax.random.key(0), (48, 32), jnp.float32) * 3.0).astype(table_dtype)
+    tokens = jax.random.randint(jax.random.key(1), shape, 0, 48)
+    tokens = tokens.reshape(-1).at[:2].set(jnp.asarray([0, 47])).reshape(shape)  # both ends of the table
+    got = tx._embed_rows({"embed": {"embedding": table}}, tokens, dtype)
+    want = jnp.take(table.astype(dtype), tokens, axis=0)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (*shape, 32)
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32)))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("which", ENTRY_POINTS)
+def test_no_entry_point_converts_the_whole_embedding_table(which):
+    """float32 leaves under bfloat16 compute: no ``convert_element_type`` of
+    any serving program has an operand of the table's shape.  48 rows of 32, a
+    shape no other leaf has, so that what the test finds is the table."""
+    cfg = TransformerConfig(
+        vocab_size=48, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, max_seq_len=32, dtype=jnp.bfloat16,
+    )
+    params = _params(cfg)
+    table = (cfg.vocab_size, cfg.d_model)
+    leaves = jax.tree.leaves(params)
+    assert all(w.dtype == jnp.float32 for w in leaves)
+    assert [tuple(w.shape) for w in leaves].count(table) == 1
+    converts = [e for e in _eqns(_trace(which, cfg, params).jaxpr) if e.primitive.name == "convert_element_type"]
+    assert any(e.outvars[0].aval.dtype == jnp.bfloat16 for e in converts)  # the walk reaches the conversions
+    swept = [e for e in converts if tuple(e.invars[0].aval.shape) == table]
+    assert not swept, swept
 
 
 YARN = Rope(
