@@ -387,13 +387,16 @@ class RoutedExperts(nn.Module):
     expert_axis_name: Any = None
     # "sigmoid_grouped": :func:`route_sigmoid_grouped` over ``router`` and the
     # selection bias ``router_bias`` in place of the softmax (no auxiliary
-    # loss: the bias is what balances such a router); ``shared_experts`` of
+    # loss: the bias is what balances such a router); "sigmoid": the plain
+    # form, :func:`route_sigmoid`, with no bias; ``shared_experts`` of
     # width ``d_ff`` each (``shared_w_*``) take every token beside the picks
     router_kind: str = "softmax"
     n_group: int = 1
     topk_group: int = 1
     routed_scaling: float = 1.0
     shared_experts: int = 0
+    # "mean": the shared experts' sum over their number (Cohere's "average")
+    shared_combine: str = "sum"
     param_dtype: Any = jnp.float32
 
     @nn.compact
@@ -466,7 +469,7 @@ class RoutedExperts(nn.Module):
                 y = jax.lax.psum(y, self.expert_axis_name)
         if self.shared_experts:
             with jax.named_scope("moe.shared"):
-                y = y + _shared_experts(p, xf.astype(self.dtype)).astype(y.dtype)
+                y = y + _shared_experts(p, xf.astype(self.dtype), self.shared_experts, self.shared_combine).astype(y.dtype)
         return y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)
 
 
@@ -492,6 +495,14 @@ def route_sigmoid_grouped(
     return top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scaling, picks
 
 
+def route_sigmoid(logits: jax.Array, *, top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """The plain sigmoid router, on float32 ``logits [T, E]``: the ``top_k``
+    largest ``sigmoid(logits)`` are the picks, their scores over their sum the
+    weights.  Returns (weights [T, k] float32, picks [T, k])."""
+    top, picks = jax.lax.top_k(jax.nn.sigmoid(logits.astype(jnp.float32)), top_k)
+    return top / jnp.sum(top, axis=-1, keepdims=True), picks
+
+
 def _route(
     p: Any, xf: jax.Array, *, kind: str, top_k: int, n_group: int, topk_group: int, scaling: float
 ) -> Tuple[jax.Array, jax.Array]:
@@ -505,15 +516,19 @@ def _route(
         return route_sigmoid_grouped(
             logits, p["router_bias"], top_k=top_k, n_group=n_group, topk_group=topk_group, scaling=scaling
         )
+    if kind == "sigmoid":
+        return route_sigmoid(logits, top_k=top_k)
     top_p, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return top_p / jnp.sum(top_p, axis=-1, keepdims=True), picks
 
 
-def _shared_experts(p: Any, xf: jax.Array) -> jax.Array:
-    """The shared experts of ``xf [T, d]``: one SwiGLU as wide as all of them."""
+def _shared_experts(p: Any, xf: jax.Array, count: int = 1, combine: str = "sum") -> jax.Array:
+    """The shared experts of ``xf [T, d]``: one SwiGLU as wide as all
+    ``count`` of them, which is their sum; their mean under ``combine`` "mean"."""
     dt = xf.dtype
     hidden = nn.silu(xf @ p["shared_w_gate"].astype(dt)) * (xf @ p["shared_w_up"].astype(dt))
-    return hidden @ p["shared_w_down"].astype(dt)
+    out = hidden @ p["shared_w_down"].astype(dt)
+    return out / count if combine == "mean" else out
 
 
 # Each Mosaic call of the serving forward sits in a jitted function of its own
@@ -560,6 +575,6 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
         y = expert_rows.tokens_of_rows(out, row_token, rows.tile_rows, layout, xf.shape[0])
     if cfg.moe_shared_experts:
         with jax.named_scope("serve.moe.shared"):
-            y = y + _shared_experts(p, xf).astype(y.dtype)
+            y = y + _shared_experts(p, xf, cfg.moe_shared_experts, cfg.moe_shared_combine).astype(y.dtype)
     counted = (jnp.sum(rows.load), jnp.sum(rows.load > 0))
     return y.astype(x.dtype).reshape(b, s, d), counted

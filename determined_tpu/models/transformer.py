@@ -76,7 +76,8 @@ class TransformerConfig:
     # rotary parameters per layer type (the published `rope_parameters`
     # group): {"full_attention": {"rope_type": "yarn", "rope_theta": ...,
     # "factor": ..., ...}, "sliding_attention": {"rope_type": "default",
-    # "rope_theta": ...}}.  A type it does not name rotates by rope_theta.
+    # "rope_theta": ...}}.  A type it does not name rotates by rope_theta; one
+    # whose rope_type is "none" does not rotate at all (q and k as projected).
     rope_parameters: Any = None
     # MoE (models/moe.py): every moe_every-th block swaps its dense MLP
     # for experts; 0 = dense everywhere.  moe_top_k == 0 is the top-2
@@ -105,6 +106,22 @@ class TransformerConfig:
     moe_topk_group: int = 1
     moe_routed_scaling: float = 1.0
     moe_shared_experts: int = 0
+    # "sigmoid" is the plain form of the grouped router: sigmoid scores, the
+    # top-k largest, weights normalised to one; no groups, no bias.  How the
+    # shared experts' outputs combine: "sum", or "mean" (their sum over their
+    # number)
+    moe_shared_combine: str = "sum"
+    # The block's norms: "rms" (no mean taken) or "layernorm" (mean and variance
+    # over the features in float32, a weight and no bias), with norm_eps.
+    # parallel_block: ONE norm a block, attention and the MLP or experts both
+    # read it, ``x + Attn(LN(x)) + FFN(LN(x))``.  tie_embeddings: no lm_head,
+    # the logits are the final norm times the embedding's transpose, times
+    # logit_scale.
+    norm: str = "rms"
+    norm_eps: float = 1e-6
+    parallel_block: bool = False
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
     # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
     # it.  Queries come through a q_lora_rank bottleneck as n_heads heads of
     # [qk_nope_head_dim | qk_rope_head_dim]; keys and values are expanded from
@@ -166,7 +183,7 @@ class TransformerConfig:
                 tuple(sorted((t, tuple(sorted(p.items()))) for t, p in self.rope_parameters.items())),
             )
         for layer_type, params in self.rope_parameters or ():
-            if layer_type not in LAYER_TYPES or dict(params).get("rope_type", "default") not in ("default", "yarn"):
+            if layer_type not in LAYER_TYPES or dict(params).get("rope_type", "default") not in ("default", "yarn", "none"):
                 raise ValueError(f"rope_parameters: unknown layer type or rope_type in {layer_type}: {dict(params)}")
         if self.moe_top_k:
             if not 1 <= self.moe_top_k <= self.moe_experts:
@@ -183,10 +200,17 @@ class TransformerConfig:
                     )
         elif self.moe_experts_held is not None or self.moe_intermediate_size is not None:
             raise ValueError("moe_experts_held and moe_intermediate_size belong to moe_top_k > 0")
-        if self.moe_router not in ("softmax", "sigmoid_grouped"):
-            raise ValueError(f"moe_router is softmax or sigmoid_grouped (got {self.moe_router!r})")
+        if self.moe_router not in ("softmax", "sigmoid", "sigmoid_grouped"):
+            raise ValueError(f"moe_router is softmax, sigmoid or sigmoid_grouped (got {self.moe_router!r})")
         if (self.moe_router != "softmax" or self.moe_shared_experts) and not self.moe_top_k:
             raise ValueError("moe_router and moe_shared_experts belong to moe_top_k > 0")
+        if self.moe_shared_combine not in ("sum", "mean") or self.norm not in ("rms", "layernorm"):
+            raise ValueError(
+                f"moe_shared_combine is sum or mean and norm is rms or layernorm "
+                f"(got {self.moe_shared_combine!r}, {self.norm!r})"
+            )
+        if self.latent and (self.parallel_block or SLIDING in (self.layer_types or ())):
+            raise ValueError("latent attention runs in a sequential block of full layers")
         if self.moe_router == "sigmoid_grouped":
             g, keep = self.moe_n_group, self.moe_topk_group
             if g < 1 or self.moe_experts % g or not 1 <= keep <= g or self.moe_experts // g < 2 or (
@@ -243,10 +267,23 @@ class TransformerConfig:
     def window(self, layer_type: str) -> Optional[int]:
         return self.sliding_window if layer_type == SLIDING else None
 
-    def rope(self, layer_type: str) -> "Rope":
-        """How a layer of this type rotates q and k."""
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """The sliding-window layers, in order: serving keeps their keys and
+        values in a store of its own (``init_kv_cache``)."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) == SLIDING)
+
+    def cache_index(self, i: int) -> int:
+        """Layer ``i``'s place among the layers of its own type: its index in
+        the paged pool (full layers) or in the window store (sliding layers)."""
+        return sum(1 for j in range(i) if self.layer_type(j) == self.layer_type(i))
+
+    def rope(self, layer_type: str) -> Optional["Rope"]:
+        """How a layer of this type rotates q and k; None: it does not."""
         params = dict(dict(self.rope_parameters or ()).get(layer_type, ()))
         theta = float(params.get("rope_theta", self.rope_theta))
+        if params.get("rope_type", "default") == "none":
+            return None
         if params.get("rope_type", "default") == "default":
             return Rope(theta)
         return Rope(
@@ -288,11 +325,14 @@ def yarn_inv_freq(
     return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
-def _rope(x: jax.Array, positions: jax.Array, rope: Rope) -> jax.Array:
+def _rope(x: jax.Array, positions: jax.Array, rope: Optional[Rope]) -> jax.Array:
     """Rotary embeddings on [b, h, s, d], as a layer type's ``rope`` states
-    them: its base, or its frequencies and the factor on cos and sin.
+    them: its base, or its frequencies and the factor on cos and sin; None
+    leaves ``x`` as it is (a layer type without positions).
     ``positions`` is [s] where every row of the batch sits at the same ones,
     or [b, s] where each row has its own (the decode step's lanes)."""
+    if rope is None:
+        return x
     d = x.shape[-1]
     if rope.inv_freq is not None:
         freqs = jnp.asarray(rope.inv_freq, jnp.float32)
@@ -316,6 +356,14 @@ def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * scale.astype(x.dtype)
 
 
+def _layer_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm without a bias: mean and variance over the features in float32, the result in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return (centred * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
+
+
 def _maybe_partition(partition: bool, init, names):
     """with_partitioning when annotations apply; plain init under manual
     SPMD (pipeline stages inside shard_map)."""
@@ -323,9 +371,13 @@ def _maybe_partition(partition: bool, init, names):
 
 
 class RMSNorm(nn.Module):
+    """A block's norm: RMSNorm, or under ``kind`` "layernorm" a LayerNorm
+    without a bias (one leaf, ``scale``, either way)."""
+
     eps: float = 1e-6
     partition: bool = True
     param_dtype: Any = jnp.float32
+    kind: str = "rms"
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -336,7 +388,7 @@ class RMSNorm(nn.Module):
             self.param_dtype,
         )
         with jax.named_scope("block.norm"):
-            return _rms(x, scale, self.eps)
+            return (_rms if self.kind == "rms" else _layer_norm)(x, scale, self.eps)
 
 
 class Attention(nn.Module):
@@ -510,48 +562,58 @@ class Block(nn.Module):
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
         norm = lambda name: RMSNorm(  # noqa: E731
-            partition=cfg.partition_params, param_dtype=cfg.param_dtype, name=name
+            eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name=name
         )
+
+        def ffn(h: jax.Array) -> Tuple[jax.Array, jax.Array]:
+            """The block's MLP or experts on the normed input, and the auxiliary loss."""
+            if self.use_moe and cfg.moe_top_k:
+                from determined_tpu.models.moe import RoutedExperts
+
+                return RoutedExperts(
+                    num_experts=cfg.moe_experts,
+                    top_k=cfg.moe_top_k,
+                    d_ff=cfg.moe_intermediate_size or cfg.ff_dim,
+                    held=cfg.moe_experts_held,
+                    dtype=cfg.dtype,
+                    partition=cfg.partition_params,
+                    expert_axis_name=cfg.expert_axis_name,
+                    router_kind=cfg.moe_router,
+                    n_group=cfg.moe_n_group,
+                    topk_group=cfg.moe_topk_group,
+                    routed_scaling=cfg.moe_routed_scaling,
+                    shared_experts=cfg.moe_shared_experts,
+                    shared_combine=cfg.moe_shared_combine,
+                    param_dtype=cfg.param_dtype,
+                    name="moe",
+                )(h)
+            if self.use_moe:
+                from determined_tpu.models.moe import MoE
+
+                return MoE(
+                    num_experts=cfg.moe_experts,
+                    d_ff=cfg.ff_dim,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    dtype=cfg.dtype,
+                    partition=cfg.partition_params,
+                    expert_axis_name=cfg.expert_axis_name,
+                    name="moe",
+                )(h)
+            return MLP(cfg, self.mesh, name="mlp")(h), jnp.zeros((), jnp.float32)
+
+        h = norm("ln1")(x)
         if cfg.latent:
-            x = x + LatentAttention(cfg, name="attn")(norm("ln1")(x))
+            att = LatentAttention(cfg, name="attn")(h)
         else:
-            x = x + Attention(cfg, self.mesh, self.layer_type, name="attn")(norm("ln1")(x))
-        if self.use_moe and cfg.moe_top_k:
-            from determined_tpu.models.moe import RoutedExperts
-
-            y, aux = RoutedExperts(
-                num_experts=cfg.moe_experts,
-                top_k=cfg.moe_top_k,
-                d_ff=cfg.moe_intermediate_size or cfg.ff_dim,
-                held=cfg.moe_experts_held,
-                dtype=cfg.dtype,
-                partition=cfg.partition_params,
-                expert_axis_name=cfg.expert_axis_name,
-                router_kind=cfg.moe_router,
-                n_group=cfg.moe_n_group,
-                topk_group=cfg.moe_topk_group,
-                routed_scaling=cfg.moe_routed_scaling,
-                shared_experts=cfg.moe_shared_experts,
-                param_dtype=cfg.param_dtype,
-                name="moe",
-            )(norm("ln2")(x))
-            x = x + y
-        elif self.use_moe:
-            from determined_tpu.models.moe import MoE
-
-            y, aux = MoE(
-                num_experts=cfg.moe_experts,
-                d_ff=cfg.ff_dim,
-                capacity_factor=cfg.moe_capacity_factor,
-                dtype=cfg.dtype,
-                partition=cfg.partition_params,
-                expert_axis_name=cfg.expert_axis_name,
-                name="moe",
-            )(norm("ln2")(x))
-            x = x + y
+            att = Attention(cfg, self.mesh, self.layer_type, name="attn")(h)
+        if cfg.parallel_block:
+            # one norm: the MLP or the experts read what attention read
+            y, aux = ffn(h)
+            x = x + att + y
         else:
-            x = x + MLP(cfg, self.mesh, name="mlp")(norm("ln2")(x))
-            aux = jnp.zeros((), jnp.float32)
+            x = x + att
+            y, aux = ffn(norm("ln2")(x))
+            x = x + y
         if cfg.partition_params:
             x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
         return x, aux
@@ -592,7 +654,17 @@ class TransformerLM(nn.Module):
         for i in range(cfg.n_layers):
             x, aux = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), name=f"block_{i}")(x)
             aux_total = aux_total + aux
-        x = RMSNorm(partition=cfg.partition_params, param_dtype=cfg.param_dtype, name="ln_f")(x)
+        x = RMSNorm(
+            eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name="ln_f"
+        )(x)
+        if cfg.tie_embeddings:
+            # no lm_head leaf: the embedding's transpose is the head (a fused-CE
+            # caller contracts the hidden state with it, times logit_scale)
+            if return_hidden:
+                return (x, aux_total) if return_aux else x
+            with jax.named_scope("loss.ce"):
+                out = (embed.attend(x) * cfg.logit_scale).astype(jnp.float32)
+            return (out, aux_total) if return_aux else out
         from determined_tpu.train._quant import make_dot_general
 
         lm_head = nn.Dense(
@@ -825,27 +897,62 @@ def latent_row_width(cfg: TransformerConfig) -> int:
 
 
 def kv_cache_shape(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Tuple[int, ...]:
-    """One pool array's shape: K (and V) rows of a GQA model, or the latent rows."""
+    """One pool array's shape: K (and V) rows of a GQA model's full-attention
+    layers (every layer, where none slides), or the latent rows."""
     width = latent_row_width(cfg) if cfg.latent else cfg.kv_heads * cfg.head_dim
-    return (cfg.n_layers, num_blocks, block_size, width)
+    return (cfg.n_layers - len(cfg.window_layers), num_blocks, block_size, width)
+
+
+def window_ring_blocks(cfg: TransformerConfig, block_size: int, chunk_tokens: int) -> int:
+    """Blocks of ONE lane's ring in the window store: the window in whole
+    blocks and one prefill chunk (a chunk's keys are written before its queries
+    attend, and must not land on a key the chunk's first query still sees)."""
+    return -(-cfg.sliding_window // block_size) + chunk_tokens // block_size
+
+
+def window_store_shape(cfg: TransformerConfig, lanes: int, block_size: int, chunk_tokens: int) -> Tuple[int, ...]:
+    """The window layers' store: ``[n_window, lanes * ring_blocks, block_size,
+    kv_heads * head_dim]``.  Lane ``l`` owns the blocks ``[l * ring_blocks, (l
+    + 1) * ring_blocks)`` as a ring: the token at position ``p`` lies in slot
+    ``p % ring_tokens`` of it, whatever the context, so a lane never holds more
+    than ``ring_tokens = ring_blocks * block_size`` tokens a layer.  Laid out
+    as a pool of blocks so that the paged kernels read it as they read the pool."""
+    return (len(cfg.window_layers), lanes * window_ring_blocks(cfg, block_size, chunk_tokens), block_size, cfg.kv_heads * cfg.head_dim)
 
 
 def kv_bytes_per_token(cfg: TransformerConfig) -> int:
     """Bytes of cache a token owns over all layers, as attention reads them
-    (a latent row's padding is not counted)."""
+    (a latent row's padding is not counted; in a window layer a token owns
+    them only while it is inside the window)."""
     values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
     return cfg.n_layers * values * jnp.dtype(cfg.dtype).itemsize
 
 
-def init_kv_cache(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Dict[str, jax.Array]:
-    """Zeroed paged pool in the model's compute dtype: ``k`` and ``v`` (keys
-    are stored post-rope, i.e. exactly what attention consumes), or, under
-    latent attention, ONE array ``kv`` whose row is ``[c_kv after its norm |
-    k_r after rope | zeros]``."""
+def init_kv_cache(
+    cfg: TransformerConfig, num_blocks: int, block_size: int, lanes: Optional[int] = None,
+    chunk_tokens: Optional[int] = None,
+) -> Dict[str, jax.Array]:
+    """Zeroed cache in the model's compute dtype.  The paged pool: ``k`` and
+    ``v`` (keys are stored post-rope, i.e. exactly what attention consumes), or,
+    under latent attention, ONE array ``kv`` whose row is ``[c_kv after its norm
+    | k_r after rope | zeros]``.  A model with sliding-window layers holds a
+    cache of two kinds: the pool keeps its full layers alone, addressed by block
+    table, and ``wk`` / ``wv`` are the window layers' store
+    (:func:`window_store_shape`: a ring a lane, for ``lanes`` decode lanes and
+    prefill chunks of ``chunk_tokens``), addressed by lane and position."""
     shape = kv_cache_shape(cfg, num_blocks, block_size)
     if cfg.latent:
         return {"kv": jnp.zeros(shape, cfg.dtype)}
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    cache = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    if cfg.window_layers:
+        if lanes is None or chunk_tokens is None or chunk_tokens % block_size:
+            raise ValueError(
+                "a model with sliding-window layers needs its lanes and its prefill chunk (whole blocks) "
+                f"to size the window store (got lanes={lanes}, chunk_tokens={chunk_tokens})"
+            )
+        ring = window_store_shape(cfg, lanes, block_size, chunk_tokens)
+        cache.update(wk=jnp.zeros(ring, cfg.dtype), wv=jnp.zeros(ring, cfg.dtype))
+    return cache
 
 
 def _block_size(cache: Dict[str, jax.Array]) -> int:
@@ -856,6 +963,14 @@ def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     """RMSNorm with the exact numerics of the ``RMSNorm`` module."""
     with jax.named_scope("serve.norm"):
         return _rms(x, scale, eps)
+
+
+def _norm_apply(cfg: TransformerConfig, x: jax.Array, scale: jax.Array) -> jax.Array:
+    """A block's (or the final) norm as the configuration states it."""
+    if cfg.norm == "rms":
+        return _rms_apply(x, scale, cfg.norm_eps)
+    with jax.named_scope("serve.norm"):
+        return _layer_norm(x, scale, cfg.norm_eps)
 
 
 def _attn_proj(p: Dict[str, Any], x: jax.Array, dtype: Any) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -878,13 +993,6 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         raise ValueError(
             "KV-cache serving runs dropless experts (moe_top_k > 0); the top-2 capacity "
             "layer drops tokens by the batch they arrive in and is not served"
-        )
-    if cfg.layer_types is not None and SLIDING in cfg.layer_types:
-        raise ValueError(
-            "KV-cache serving runs full attention in every layer, under the rotary "
-            "parameters of that one layer type: it has no sliding-window layers "
-            "(layer_types, sliding_window), and so none whose rope_parameters differ "
-            "from the full layers' (YaRN on some layers only), yet"
         )
     if cfg.seq_axis_name is not None or cfg.expert_axis_name is not None:
         raise ValueError("KV-cache serving runs outside pipeline stages")
@@ -911,12 +1019,19 @@ def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Ar
         return jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(dtype)
 
 
-def _head(params: Dict[str, Any], x: jax.Array, dtype: Any, row: Optional[int] = None) -> jax.Array:
-    """Final norm and ``lm_head``: float32 logits at every position of ``x``, or at ``row`` alone."""
-    x = _rms_apply(x, params["ln_f"]["scale"])
+def _head(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array, row: Optional[int] = None) -> jax.Array:
+    """Final norm and ``lm_head`` (tied: the embedding's transpose, times
+    ``logit_scale``): float32 logits at every position of ``x``, or at ``row`` alone."""
+    x = _norm_apply(cfg, x, params["ln_f"]["scale"])
     with jax.named_scope("serve.head"):
         x = x if row is None else x[:, row, :]
-        return (x @ params["lm_head"]["kernel"].astype(dtype)).astype(jnp.float32)
+        if not cfg.tie_embeddings:
+            return (x @ params["lm_head"]["kernel"].astype(cfg.dtype)).astype(jnp.float32)
+        # float32 out of the product itself: the table is the head, and its logits are what a caller samples from
+        logits = jnp.einsum(
+            "...d,vd->...v", x, params["embed"]["embedding"].astype(cfg.dtype), preferred_element_type=jnp.float32
+        )
+        return logits * cfg.logit_scale
 
 
 # The attention backends of the serving layer: ``attend(q, k, v, cache, i)`` with
@@ -930,49 +1045,81 @@ def _attend_local(q, k, v, cache, i):
     return reference_attention(q, k, v, causal=True)
 
 
-def _attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array):
+def _attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array, window: Optional[int] = None):
     """One query a lane against the lane's live blocks, read where they lie
-    in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle."""
+    in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle.
+    ``window``: the layer slides, ``block_tables`` are the lanes' rings of the
+    window store, and a lane reads its newest ``window`` tokens there."""
+    pools = ("k", "v") if window is None else ("wk", "wv")
 
     def attend(q, k, v, cache, i):
         att = paged_decode_attention(
-            q[:, :, 0, :], cache["k"], cache["v"], i, block_tables, positions, scale=cfg.head_dim ** -0.5
+            q[:, :, 0, :], cache[pools[0]], cache[pools[1]], i, block_tables, positions, scale=cfg.head_dim ** -0.5,
+            window=window,
         )
         return att.astype(cfg.dtype)[:, :, None, :]
 
     return attend
 
 
-def _attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
+def _attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array, window: Optional[int] = None):
     """The prefill walk's read: the queries of chunk ``chunk`` (positions
     ``chunk * s ..``) against the keys up to the chunk's end, read from the
-    pool a tile at a time (``ops/paged_attention.py paged_chunk_attention``)."""
+    pool a tile at a time (``ops/paged_attention.py paged_chunk_attention``);
+    under ``window`` from the lanes' rings, and no key older than the window."""
+    pools = ("k", "v") if window is None else ("wk", "wv")
 
     def attend(q, k, v, cache, i):
         b, h, s, d = q.shape
         att = paged_chunk_attention(
-            q.reshape(b, cfg.kv_heads, h // cfg.kv_heads, s, d), cache["k"], cache["v"], i, block_tables, chunk,
-            scale=cfg.head_dim ** -0.5,
+            q.reshape(b, cfg.kv_heads, h // cfg.kv_heads, s, d), cache[pools[0]], cache[pools[1]], i, block_tables, chunk,
+            scale=cfg.head_dim ** -0.5, window=window,
         )
         return att.astype(cfg.dtype).reshape(b, h, s, d)
 
     return attend
 
 
+def _masked_attention(cfg: TransformerConfig, q: jax.Array, keys: jax.Array, vals: jax.Array, mask: jax.Array) -> jax.Array:
+    """Queries against gathered keys under ``mask`` (True = may see: ``[s, keys]``
+    where the lanes are alike, else ``[b, s, keys]``) and a float32 softmax."""
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32)
+    logits = logits * cfg.head_dim ** -0.5
+    seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, logits, NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
+
+
 def _attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
     """Queries against every token of every table column, gathered from the
-    pool, under ``mask`` (True = may see: ``[s, T * block_size]`` where the
-    lanes are alike, else ``[b, s, T * block_size]``) and a float32 softmax:
-    decode without the paged path, the oracle that path is tested against."""
+    pool, under ``mask``: decode without the paged path, the oracle that path
+    is tested against."""
 
     def attend(q, k, v, cache, i):
         keys = _gather_table(cfg, cache["k"], i, block_tables)
         vals = _gather_table(cfg, cache["v"], i, block_tables)
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32)
-        logits = logits * cfg.head_dim ** -0.5
-        seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
-        probs = jax.nn.softmax(jnp.where(seen, logits, NEG_INF), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
+        return _masked_attention(cfg, q, keys, vals, mask)
+
+    return attend
+
+
+def _attend_ring_table(cfg: TransformerConfig, positions: jax.Array):
+    """A window layer's decode without the paged path: every lane's whole
+    ring, gathered, each slot masked by the position it must hold.  Slot ``s``
+    of a lane at position ``pos`` holds ``p = pos - (pos - s) % ring`` if it
+    holds anything of this request; the query sees it if ``p >= 0`` and ``p >
+    pos - window``.  What an earlier request left in the lane is never seen."""
+
+    def attend(q, k, v, cache, i):
+        b = q.shape[0]
+        ring = cache["wk"].shape[1] // b * cache["wk"].shape[2]
+        rows = lambda pool: _repeat_kv(  # noqa: E731
+            pool[i].reshape(b, ring, cfg.kv_heads, -1).transpose(0, 2, 1, 3), cfg.n_heads // cfg.kv_heads
+        )
+        pos = positions[:, None]
+        held = pos - (pos - jnp.arange(ring)[None, :]) % ring  # [b, ring]
+        mask = (held >= 0) & (held > pos - cfg.sliding_window) & (pos >= 0)
+        return _masked_attention(cfg, q, rows(cache["wk"]), rows(cache["wv"]), mask[:, None, :])
 
     return attend
 
@@ -1097,21 +1244,36 @@ def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: 
 #: each summed over the expert layers: picks that landed on a held expert, and
 #: held experts that got at least one row (whose matrices the step had to read)
 SERVE_COUNTERS = ("serve.moe.held_picks", "serve.moe.experts_hit")
+#: what a decode step of a model with window layers counts first: the cached
+#: tokens its attention reads, summed over the lanes, in the full layers (the
+#: context a layer) and in the window layers (the context or the window a layer)
+SERVE_KV_COUNTERS = ("serve.kv.full_tokens", "serve.kv.window_tokens")
 
 
-def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None):
+def serve_counters(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The names of what ``transformer_decode(counters=True)`` counts, in the row's order."""
+    return (SERVE_KV_COUNTERS if cfg.window_layers else ()) + (SERVE_COUNTERS if cfg.moe_experts else ())
+
+
+def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sliding=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
     points below: norm, the attention's projections (q/k/v, or latent
     attention's), rope at ``positions`` ([s], or [b, s]), this call's rows into
     the pool at ``write`` = (physical block, slot), each [b, s] (or [b] where s
     is 1), then ``attend`` against the updated pool, so that a token sees its
     own key, then the output projection, the MLP or the experts held here
-    (which count the tokens ``live`` [b, s] marks) and both residuals.
+    (which count the tokens ``live`` [b, s] marks) and both residuals; under
+    ``parallel_block`` the MLP or experts read the one norm attention read.
+    A sliding-window layer writes and attends through ``sliding`` = (write,
+    attend) instead, into the window store: its blocks are a lane's ring, and
+    a block id past the store's end drops the row (idle lanes, padding).
     Returns (x, cache, what an expert layer counted or None)."""
     dt = cfg.dtype
     rope = cfg.rope(cfg.layer_type(i))
-    phys, slots = write
-    h = _rms_apply(x, blk["ln1"]["scale"])
+    slides = cfg.layer_type(i) == SLIDING
+    phys, slots = sliding[0] if slides else write
+    j = cfg.cache_index(i)
+    h = _norm_apply(cfg, x, blk["ln1"]["scale"])
     if cfg.latent:
         with jax.named_scope("serve.mla"):
             p = blk["attn"]
@@ -1127,15 +1289,29 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None):
             q, k, v = _attn_proj(blk["attn"], h, dt)
             q, k = _rope(q, positions, rope), _rope(k, positions, rope)
         with jax.named_scope("serve.kv.write"):
-            cache = {
-                "k": cache["k"].at[i, phys, slots].set(_pool_rows(k, phys.shape)),
-                "v": cache["v"].at[i, phys, slots].set(_pool_rows(v, phys.shape)),
-            }
+            if slides:
+                cache = {
+                    **cache,
+                    "wk": cache["wk"].at[j, phys, slots].set(_pool_rows(k, phys.shape), mode="drop"),
+                    "wv": cache["wv"].at[j, phys, slots].set(_pool_rows(v, phys.shape), mode="drop"),
+                }
+            else:
+                cache = {
+                    **cache,
+                    "k": cache["k"].at[j, phys, slots].set(_pool_rows(k, phys.shape)),
+                    "v": cache["v"].at[j, phys, slots].set(_pool_rows(v, phys.shape)),
+                }
         with jax.named_scope("serve.attn.attend"):  # whichever backend the entry point picked
-            att = attend(q, k, v, cache, i).transpose(0, 2, 1, 3)  # [b, s, h, hd]
+            if sliding is None:
+                att = attend(q, k, v, cache, j)
+            else:  # a cache of two kinds: the device trace tells them apart
+                with jax.named_scope("serve.attn.window" if slides else "serve.attn.full"):
+                    att = (sliding[1] if slides else attend)(q, k, v, cache, j)
+            att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
         with jax.named_scope("serve.attn.out"):
             x = x + jnp.einsum("bshk,hkD->bsD", att, blk["attn"]["wo"]["kernel"].astype(dt))
-    h = _rms_apply(x, blk["ln2"]["scale"])
+    if not cfg.parallel_block:  # else the one norm: what attention read
+        h = _norm_apply(cfg, x, blk["ln2"]["scale"])
     if not cfg.use_moe(i):
         with jax.named_scope("serve.mlp"):
             return x + _mlp_apply(blk["mlp"], h, dt), cache, None
@@ -1145,12 +1321,12 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None):
     return x + y, cache, counted
 
 
-def _serve_layers(cfg, params, x, positions, write, attend, cache, live=None):
+def _serve_layers(cfg, params, x, positions, write, attend, cache, live=None, sliding=None):
     """Every layer; the last value is SERVE_COUNTERS' sums over the expert
     layers, [2] float32, or None for a model without them."""
     counted = []
     for i in range(cfg.n_layers):
-        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache, live)
+        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache, live, sliding)
         if c is not None:
             counted.append(jnp.stack(c).astype(jnp.float32))
     return x, cache, sum(counted) if counted else None
@@ -1172,8 +1348,12 @@ def transformer_prefill(
     sample at ``prompt_len - 1``.  Causality makes positions < prompt_len
     match the full-sequence forward exactly (padding sits strictly after
     them), which is what the parity tests in tests/test_transformer.py pin.
+    A model with sliding-window layers prefills through the walk alone (the
+    window store is sized by the walk's chunk), its oracle the full forward.
     """
     _check_decodable(cfg)
+    if cfg.window_layers:
+        raise ValueError("the wide prefill runs full layers only: sliding-window layers prefill through transformer_prefill_chunked")
     block_size = _block_size(cache)
     b, s = tokens.shape
     x = _embed_rows(params, tokens, cfg.dtype)
@@ -1192,7 +1372,7 @@ def transformer_prefill(
     # the padded tail takes no expert's rows
     live = positions[None, :] < prompt_lens[:, None] if cfg.moe_experts else None
     x, cache, _ = _serve_layers(cfg, params, x, positions, (phys, slots), attend, cache, live)
-    return _head(params, x, cfg.dtype), cache
+    return _head(cfg, params, x), cache
 
 
 def transformer_decode(
@@ -1222,9 +1402,15 @@ def transformer_decode(
     projection and the cache-write scatter, and agree to f32 tolerance.
 
     ``counters`` (the engine's decode program, where the model has expert
-    layers): the logits come back ``[B + 1, vocab]``, and the last row's first
-    entries are ``SERVE_COUNTERS`` of this step, so that they reach the host
-    in the logits' own copy.  Idle lanes take no expert's rows.
+    or window layers): the logits come back ``[B + 1, vocab]``, and the last
+    row's first entries are ``serve_counters(cfg)`` of this step, so that they
+    reach the host in the logits' own copy.  Idle lanes take no expert's rows.
+
+    Sliding-window layers read and write the window store (``init_kv_cache``):
+    row ``b`` of the batch IS lane ``b``, whose ring holds the lane's newest
+    tokens by position; a window layer reads ``min(position + 1, window)`` of
+    them and nothing older, by the paged path over the ring (the gathered ring
+    under ``chunk_blocks`` 0).
     """
     _check_decodable(cfg)
     block_size = _block_size(cache)
@@ -1249,8 +1435,28 @@ def transformer_decode(
     pos_col = pos[:, None]
     with jax.named_scope("serve.kv.write"):
         slots = pos % block_size
-    x, cache, counted = _serve_layers(cfg, params, x, pos_col, (phys, slots), attend, cache, live)
-    logits = _head(params, x, cfg.dtype, row=0)
+    sliding = None
+    if cfg.window_layers:
+        lanes, store = tokens.shape[0], cache["wk"].shape[1]
+        ring_blocks = store // lanes
+        with jax.named_scope("serve.kv.write"):  # lane b's ring; an idle lane's row is dropped
+            first = jnp.arange(lanes, dtype=jnp.int32) * ring_blocks
+            wphys = jnp.where(active, first + (pos // block_size) % ring_blocks, store)
+        if chunk_blocks:
+            rings = first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :]
+            sliding = ((wphys, slots), _attend_paged(cfg, rings, positions, cfg.sliding_window))
+        else:
+            sliding = ((wphys, slots), _attend_ring_table(cfg, positions))
+    x, cache, counted = _serve_layers(cfg, params, x, pos_col, (phys, slots), attend, cache, live, sliding)
+    logits = _head(cfg, params, x, row=0)
+    if counters and cfg.window_layers:
+        with jax.named_scope("serve.head"):  # the cached tokens this step's attention reads, by kind of layer
+            lens = jnp.where(active, pos + 1, 0).astype(jnp.float32)
+            n_window = len(cfg.window_layers)
+            read = jnp.stack([
+                jnp.sum(lens) * (cfg.n_layers - n_window), jnp.sum(jnp.minimum(lens, cfg.sliding_window)) * n_window,
+            ])
+            counted = read if counted is None else jnp.concatenate([read, counted])
     if counters and counted is not None:
         with jax.named_scope("serve.head"):  # the counters ride in the logits' own copy
             row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
@@ -1279,6 +1485,7 @@ def prefill_chunk_tokens(block_size: int, prompt_tokens: int) -> int:
 def transformer_prefill_chunked(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, start_lens: jax.Array,
     prompt_lens: jax.Array, block_tables: jax.Array, cache: Dict[str, jax.Array],
+    lanes: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Prefill each prompt from ``start_lens`` on, a chunk of tokens at a time:
     the serving engine's one prefill program, for a cold prompt (``start`` 0)
@@ -1307,6 +1514,14 @@ def transformer_prefill_chunked(
     since keys come from the cache rather than the local projection, garbage
     padding columns cannot leak into valid ones.  The head runs once, on the
     row of ``prompt_len - 1`` alone.
+
+    Sliding-window layers keep their rows in the window store, in the ring of
+    the decode lane each prompt will run in: ``lanes`` [B] (absent: row ``b`` is
+    lane ``b``).  A chunk's rows go to the slots of their positions, and its
+    queries read the ring back to ``window - 1`` positions before each of them:
+    the ring is one chunk longer than the window, so no row a query of the
+    chunk still sees is overwritten.  Such a prompt starts at 0 (a shared
+    block holds no window state), and its walk computes every chunk.
     """
     _check_decodable(cfg)
     block_size = _block_size(cache)
@@ -1318,6 +1533,16 @@ def transformer_prefill_chunked(
             f"chunk={chunk}, block_size={block_size})"
         )
     blocks, t = chunk // block_size, block_tables.shape[1]
+    if cfg.window_layers:
+        ring_blocks, store = window_ring_blocks(cfg, block_size, chunk), cache["wk"].shape[1]
+        if store % ring_blocks:
+            raise ValueError(
+                f"the window store ({store} blocks) is not whole rings of {ring_blocks} blocks: it was sized "
+                f"for another prefill chunk than {chunk} tokens"
+            )
+        with jax.named_scope("serve.kv.write"):
+            first = (jnp.arange(b, dtype=jnp.int32) if lanes is None else lanes.astype(jnp.int32)) * ring_blocks
+            rings = first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :]
     with jax.named_scope("serve.walk"):  # the trip count; the loop below is under it too, around its layers' scopes
         c_lo = jnp.min(start_lens) // chunk
         c_hi = (jnp.max(prompt_lens) + chunk - 1) // chunk
@@ -1335,6 +1560,12 @@ def transformer_prefill_chunked(
             phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
             slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
         attend = (_latent_attend_chunk if cfg.latent else _attend_chunk)(cfg, block_tables, c)
+        sliding = None
+        if cfg.window_layers:
+            with jax.named_scope("serve.kv.write"):  # rows outside [start, len) are dropped
+                wcols = (c * blocks + offsets // block_size) % ring_blocks
+                wphys = jnp.where(valid, first[:, None] + wcols[None, :], store)
+            sliding = ((wphys, slots), _attend_chunk(cfg, rings, c, cfg.sliding_window))
         # a leaf stored wider than the compute dtype is read as it lies and
         # converted on its way into each product, every iteration.  The
         # conversions depend on nothing the loop changes, and XLA would move
@@ -1346,7 +1577,9 @@ def transformer_prefill_chunked(
         layers = {name: sub for name, sub in params.items() if name.startswith("block_")}
         layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
         x = _embed_rows(params, toks, cfg.dtype)
-        x, cache, _ = _serve_layers(cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None)
+        x, cache, _ = _serve_layers(
+            cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None, sliding
+        )
         with jax.named_scope("serve.head"):  # the one row the head will read
             sel = prompt_lens - 1 - c * chunk  # [b]
             row = jnp.take_along_axis(x, jnp.clip(sel, 0, chunk - 1)[:, None, None], axis=1)
@@ -1355,7 +1588,7 @@ def transformer_prefill_chunked(
     with jax.named_scope("serve.walk"):
         init = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
         cache, last = jax.lax.fori_loop(c_lo, c_hi, body, init)
-    return _head(params, last, cfg.dtype, row=0), cache
+    return _head(cfg, params, last, row=0), cache
 
 
 #: latent attention's hparams: passed to the config as they are (absent: GQA)
@@ -1373,7 +1606,8 @@ class LMTrial(JaxTrial):
     rope_theta and rope_parameters (per layer type; YaRN); moe_experts with
     moe_every, and either moe_capacity_factor (top-2, capacity) or moe_top_k
     (dropless) with moe_intermediate_size and moe_experts_held = [first,
-    count]; moe_aux_weight.
+    count]; moe_aux_weight; norm (rms / layernorm) with norm_eps,
+    parallel_block, tie_embeddings with logit_scale, moe_shared_combine.
 
     When the context mesh has a ``pipe`` axis of size P > 1, the trial
     restructures its params into stacked pipeline stages and trains through
@@ -1452,6 +1686,8 @@ class LMTrial(JaxTrial):
         g = self.context.get_hparam
         pipe = self._pipe_stages()
         layer_types = g("layer_types", None)
+        if pipe > 1 and bool(g("tie_embeddings", False)):
+            raise ValueError("tie_embeddings: the embedding and the head sit on different pipeline stages")
         if pipe > 1 and (int(g("moe_experts", 0)) > 0 or layer_types):
             # MoE and layer types compose with pipe when every chunk sees the
             # same layer pattern: their periods must divide layers-per-chunk
@@ -1505,6 +1741,12 @@ class LMTrial(JaxTrial):
             moe_topk_group=int(g("moe_topk_group", 1)),
             moe_routed_scaling=float(g("moe_routed_scaling", 1.0)),
             moe_shared_experts=int(g("moe_shared_experts", 0)),
+            moe_shared_combine=str(g("moe_shared_combine", "sum")),
+            norm=str(g("norm", "rms")),
+            norm_eps=float(g("norm_eps", 1e-6)),
+            parallel_block=bool(g("parallel_block", False)),
+            tie_embeddings=bool(g("tie_embeddings", False)),
+            logit_scale=float(g("logit_scale", 1.0)),
             **{k: g(k, None) for k in _LATENT_HPARAMS},
             quantized_matmul=self._quant_mode(),
         )
@@ -1701,7 +1943,10 @@ class LMTrial(JaxTrial):
             (hidden, moe_aux), moe_load = self._apply(
                 model, params, inputs, return_hidden=True, return_aux=True
             )
-            kernel = flax_meta.unbox(params["params"]["lm_head"]["kernel"])
+            if model.cfg.tie_embeddings:
+                kernel = flax_meta.unbox(params["params"]["embed"]["embedding"]).T * model.cfg.logit_scale
+            else:
+                kernel = flax_meta.unbox(params["params"]["lm_head"]["kernel"])
             chunk = g("ce_chunk", None)
             shards = self.context.batch_axis_size if self.context.mesh is not None else 1
             loss = fused_cross_entropy(

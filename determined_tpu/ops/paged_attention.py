@@ -28,6 +28,14 @@ and accumulator in float32, K and V in the pool's dtype) over tiles of
 
 The tile width is chosen here from the shapes (:func:`_tile_blocks`), not
 by the caller.
+
+A sliding-window layer (``window``) keeps its tokens in a RING a lane: the
+table's ``T`` columns are the ring's blocks, and logical block ``c`` of the
+lane's context lies in column ``c % T`` (``models/transformer.py
+window_store_shape``).  Both forms then walk the tiles that hold the lane's
+newest ``window`` tokens, ``[length - window, length)``, and nothing older:
+what a window layer reads a step is ``min(length, window)`` tokens a lane,
+whatever the context.
 """
 
 from __future__ import annotations
@@ -91,6 +99,7 @@ def paged_decode_attention(
     scale: float,
     tile_blocks: Optional[int] = None,
     impl: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention of one decode step of one layer over the paged pool.
 
@@ -105,6 +114,10 @@ def paged_decode_attention(
     Pallas TPU interpreter: tests), ``"jnp"`` or None: the kernel on a TPU
     when :func:`kernel_takes` the shapes, else the ``jax.numpy`` form.
     ``tile_blocks`` overrides the tile width (tests).
+
+    ``window``: the layer slides and each row of ``block_tables`` is a lane's
+    ring (see the module's text): the query attends to the positions
+    ``position - window < j <= position``.
     """
     block_size = k_pool.shape[2]
     head_dim = q.shape[-1]
@@ -120,6 +133,13 @@ def paged_decode_attention(
             f"the paged-attention kernel needs head_dim % 128 == 0 and whole "
             f"sublane tiles a block (got head_dim={head_dim}, "
             f"block_size={block_size}, {k_pool.dtype})"
+        )
+    if window is not None:
+        if window < 1 or block_tables.shape[1] * block_size < window:
+            raise ValueError(f"a window of {window} tokens needs a ring of at least as many (got {block_tables.shape[1]} blocks of {block_size})")
+        return _paged_window_attention(
+            q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
+            scale=scale, tile_blocks=tile_blocks, impl=impl, window=window,
         )
     return _paged_attention(
         q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
@@ -147,13 +167,30 @@ def _paged_attention(
     )
 
 
+# the window layers' call: a jitted function of its own, so that its kernel
+# keeps its name and its scope in the optimized program (PERF.md, PR 33)
+@functools.partial(jax.jit, static_argnames=("scale", "tile_blocks", "impl", "window"))
+def _paged_window_attention(
+    q, k_pool, v_pool, layer, block_tables, positions, *, scale, tile_blocks, impl, window
+):
+    lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
+    if impl == "jnp":
+        return _paged_attention_jnp(
+            q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks, window
+        )
+    return _paged_attention_pallas(
+        q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks,
+        interpret=impl == "kernel_interpret", window=window,
+    )
+
+
 # ---------------------------------------------------------------------------
 # jax.numpy form
 # ---------------------------------------------------------------------------
 
 
 def _paged_attention_jnp(
-    q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks
+    q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks, window=None
 ):
     b, n_heads, head_dim = q.shape
     block_size = k_pool.shape[2]
@@ -163,11 +200,19 @@ def _paged_attention_jnp(
     tile_tokens = tile_blocks * block_size
     qg = q.reshape(b, kv_heads, n_rep, head_dim)
     n_tiles = (jnp.max(lengths) + tile_tokens - 1) // tile_tokens
+    first_tile = 0
+    if window is not None:
+        starts = jnp.maximum(lengths - window, 0)  # a lane's oldest position still seen
+        # the batch walks from the tile of its oldest seen position (empty lanes aside)
+        first_tile = jnp.min(jnp.where(lengths > 0, starts, jnp.max(lengths))) // tile_tokens
 
     def body(i, carry):
         m, l, acc = carry
-        # columns past the table's end re-read its last column, masked below
-        cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        if window is None:
+            # columns past the table's end re-read its last column, masked below
+            cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        else:
+            cols = (i * tile_blocks + jnp.arange(tile_blocks)) % t  # the ring
         tbl = jnp.take(block_tables, cols, axis=1)  # [b, tile_blocks]
         # layer and block ids in ONE gather: the pool is never sliced
         keys = k_pool[layer, tbl].reshape(b, tile_tokens, kv_heads, head_dim)
@@ -178,6 +223,8 @@ def _paged_attention_jnp(
         )
         k_idx = i * tile_tokens + jnp.arange(tile_tokens)
         live = k_idx[None, :] < lengths[:, None]  # [b, tile_tokens]
+        if window is not None:
+            live = live & (k_idx[None, :] >= starts[:, None])
         s = jnp.where(live[:, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # the where is for an empty lane alone (m still NEG_INF, so exp
@@ -195,7 +242,7 @@ def _paged_attention_jnp(
         jnp.zeros((b, kv_heads, n_rep, 1), jnp.float32),
         jnp.zeros((b, kv_heads, n_rep, head_dim), jnp.float32),
     )
-    _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
+    _, l, acc = jax.lax.fori_loop(first_tile, n_tiles, body, init)
     return (acc / jnp.maximum(l, 1e-30)).reshape(b, n_heads, head_dim)
 
 
@@ -226,7 +273,7 @@ def _paged_attention_kernel(
     o_ref,                                        # output
     k_buf, v_buf, sems,                           # scratch
     *, scale: float, tile_blocks: int, block_size: int, table_width: int,
-    kv_heads: int, head_dim: int, n_rep: int,
+    kv_heads: int, head_dim: int, n_rep: int, window: Optional[int] = None,
 ):
     b = pl.program_id(0)
     layer = layer_ref[0]
@@ -234,15 +281,28 @@ def _paged_attention_kernel(
     tile_tokens = tile_blocks * block_size
     n_tiles = (length + tile_tokens - 1) // tile_tokens
     rows = q_ref.shape[0]
+    if window is None:
+        tile_of = lambda i: i  # noqa: E731 (trip i of the walk is tile i)
+    else:
+        # the table is a ring: the walk starts at the tile of the oldest
+        # position the query still sees, which always holds one it does see
+        start = jnp.maximum(length - window, 0)
+        first = start // tile_tokens
+        n_tiles = n_tiles - first
+        tile_of = lambda i: first + i  # noqa: E731
 
     def copies(tile, slot):
         """The tile's 2 * tile_blocks block copies into buffer ``slot``.
         Every tile is copied whole: columns past the lane's last block hold
         block ids all the same (the allocator's scratch block 0, or the
-        table's last column), and their scores are masked."""
+        table's last column; a ring's older blocks), and their scores are
+        masked."""
         out = []
         for j in range(tile_blocks):
-            col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
+            if window is None:
+                col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
+            else:
+                col = (tile * tile_blocks + j) % table_width
             blk = tables_ref[b * table_width + col]
             dst = pl.ds(j * block_size, block_size)
             out.append(pltpu.make_async_copy(
@@ -253,7 +313,7 @@ def _paged_attention_kernel(
 
     @pl.when(n_tiles > 0)
     def _first():
-        for c in copies(0, 0):
+        for c in copies(tile_of(0), 0):
             c.start()
 
     q = q_ref[...]                                    # [rows, kv_heads*head_dim]
@@ -264,18 +324,21 @@ def _paged_attention_kernel(
 
         @pl.when(i + 1 < n_tiles)
         def _next():
-            for c in copies(i + 1, 1 - slot):
+            for c in copies(tile_of(i + 1), 1 - slot):
                 c.start()
 
-        for c in copies(i, slot):
+        for c in copies(tile_of(i), slot):
             c.wait()
         k = k_buf[slot]                               # [tile_tokens, kv_heads*head_dim]
         v = v_buf[slot]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                     # [rows, tile_tokens]
-        k_idx = i * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_idx < length, s, NEG_INF)
+        k_idx = tile_of(i) * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if window is None:
+            s = jnp.where(k_idx < length, s, NEG_INF)
+        else:
+            s = jnp.where((k_idx < length) & (k_idx >= start), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                        # masked: exp(NEG_INF - m) = 0
         alpha = jnp.exp(m - m_new)
@@ -306,7 +369,7 @@ def _paged_attention_kernel(
 
 def _paged_attention_pallas(
     q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks,
-    *, interpret: bool,
+    *, interpret: bool, window: Optional[int] = None,
 ):
     b, n_heads, head_dim = q.shape
     _, _, block_size, kvd = k_pool.shape
@@ -329,7 +392,7 @@ def _paged_attention_pallas(
     kernel = functools.partial(
         _paged_attention_kernel,
         scale=scale, tile_blocks=tile_blocks, block_size=block_size,
-        table_width=t, kv_heads=kv_heads, head_dim=head_dim, n_rep=n_rep,
+        table_width=t, kv_heads=kv_heads, head_dim=head_dim, n_rep=n_rep, window=window,
     )
     out = pl.pallas_call(
         kernel,
@@ -351,7 +414,7 @@ def _paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b, rows, head_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="paged_decode_attention",
+        name="paged_decode_attention" if window is None else "paged_window_attention",
     )(
         layer.reshape(1),
         lengths,
@@ -594,6 +657,7 @@ def paged_chunk_attention(
     *,
     scale: float,
     value_dim: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Causal attention of one CHUNK of queries a lane over the paged pool:
     the decode forms' mathematics with a block of queries, in ``jax.numpy``.
@@ -613,6 +677,11 @@ def paged_chunk_attention(
     sees key 0, so no row of the running maximum is left at ``NEG_INF``.
     Columns past the table's end re-read its last column, which only a query
     past the table's end could see.
+
+    ``window``: the layer slides and each row of ``block_tables`` is a lane's
+    ring (see the module's text), at least ``window + s`` tokens long.  A query
+    sees ``q_pos - window < k_pos <= q_pos``; the tiles walked are those from
+    the chunk's first query's oldest key on, at most ``window / s + 2``.
     """
     b, g, r, s, width = q.shape
     block_size = k_pool.shape[2]
@@ -623,15 +692,23 @@ def paged_chunk_attention(
 
     def body(i, carry):
         m, l, acc = carry
-        cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        if window is None:
+            cols = jnp.minimum(i * tile_blocks + jnp.arange(tile_blocks), t - 1)
+        else:
+            cols = (i * tile_blocks + jnp.arange(tile_blocks)) % t  # the ring
         tbl = jnp.take(block_tables, cols, axis=1)  # [b, tile_blocks]
         keys = k_pool[layer, tbl].reshape(b, s, g, width)
         vals = keys[..., :values] if v_pool is None else v_pool[layer, tbl].reshape(b, s, g, values)
         sc = jnp.einsum("bgrqw,btgw->bgrqt", q, keys, preferred_element_type=jnp.float32) * scale
-        seen = (i * s + jnp.arange(s))[None, :] <= q_pos[:, None]  # [s, s]; all of it before the last tile
+        k_pos = i * s + jnp.arange(s)
+        seen = k_pos[None, :] <= q_pos[:, None]  # [s, s]; all of it before the last tile
+        if window is not None:
+            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
         sc = jnp.where(seen, sc, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         p = jnp.exp(sc - m_new)  # masked: exp(NEG_INF - m) = 0
+        if window is not None:
+            p = jnp.where(seen, p, 0.0)  # a late query sees nothing of the walk's first tile: its m is still NEG_INF
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + jnp.einsum(
@@ -644,5 +721,6 @@ def paged_chunk_attention(
         jnp.zeros((b, g, r, s, 1), jnp.float32),
         jnp.zeros((b, g, r, s, values), jnp.float32),
     )
-    _, l, acc = jax.lax.fori_loop(0, chunk + 1, body, init)
+    first_tile = 0 if window is None else jnp.maximum(chunk * s - window + 1, 0) // s
+    _, l, acc = jax.lax.fori_loop(first_tile, chunk + 1, body, init)
     return acc / l

@@ -151,12 +151,13 @@ class DecodeKernels:
         import jax
 
         from determined_tpu.models.transformer import (
-            SERVE_COUNTERS,
             _check_decodable,
             init_kv_cache,
             kv_bytes_per_token,
+            serve_counters,
             transformer_decode,
             transformer_prefill_chunked,
+            window_ring_blocks,
         )
         from determined_tpu.utils.compilation_cache import (
             setup_compilation_cache,
@@ -164,6 +165,15 @@ class DecodeKernels:
         )
 
         _check_decodable(model_cfg)
+        #: a model with sliding-window layers: their keys and values live in
+        #: a ring a decode lane, not in the blocks the allocator hands out
+        self.windowed = bool(model_cfg.window_layers)
+        if self.windowed and serve_cfg.prefix_cache:
+            raise ValueError(
+                "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no "
+                "window state: the sliding-window layers keep a request's newest tokens in its own lane's ring, "
+                "which a prefill from the first un-cached token would leave without the prefix. Set prefix_cache: false"
+            )
         tracer = get_tracer()
         t_setup = mono()
         # a relaunched replica loads its two kernels from disk; keeps the
@@ -180,7 +190,9 @@ class DecodeKernels:
         self.params = jax.block_until_ready(jax.device_put(params))
         t_pool = mono()
         self.cache = jax.block_until_ready(
-            init_kv_cache(model_cfg, serve_cfg.num_blocks, serve_cfg.block_size)
+            init_kv_cache(
+                model_cfg, serve_cfg.num_blocks, serve_cfg.block_size, serve_cfg.max_batch, serve_cfg.prefill_chunk
+            )
         )
         t_pooled = mono()
         param_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(self.params))
@@ -189,20 +201,37 @@ class DecodeKernels:
             "serve.setup.params_to_device", "serve", t_params, t_pool,
             {"bytes": param_bytes},
         )
+        #: ``/stats`` ``window_store`` (empty where no layer slides): the bytes
+        #: the window layers' store takes whatever the contexts, and a lane's ring
+        self.window_store: Dict[str, int] = {}
+        kinds: Dict[str, int] = {}
+        if self.windowed:
+            self.window_store = {
+                "window_store_bytes": int(self.cache["wk"].nbytes + self.cache["wv"].nbytes),
+                "ring_tokens": window_ring_blocks(model_cfg, serve_cfg.block_size, serve_cfg.prefill_chunk) * serve_cfg.block_size,
+            }
+            # what a token costs in each kind of cache
+            layer = kv_bytes_per_token(model_cfg) // model_cfg.n_layers
+            n_window = len(model_cfg.window_layers)
+            kinds = {
+                "bytes_per_token_full": layer * (model_cfg.n_layers - n_window),
+                "bytes_per_token_window": layer * n_window,
+                **self.window_store,
+            }
         # bytes_per_token: what attention reads of the pool for one cached
         # token over all layers (K and V rows, or one latent row a layer)
         tracer.record_span(
             "serve.setup.kv_pool", "serve", t_pool, t_pooled,
-            {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg)},
+            {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg), **kinds},
         )
         #: (call, jitted call returned, logits ready, logits on the host) of
         #: the newest ``decode``: the engine, which knows the step, turns
         #: them into the ``serve.decode.*`` spans
         self.last_decode_stamps: Optional[Tuple[float, float, float, float]] = None
-        #: a model with expert layers: the decode program returns one more
-        #: row of logits, whose first entries are these counts of the step
-        #: (``transformer_decode``); the newest step's are kept by name
-        self._counters: Tuple[str, ...] = SERVE_COUNTERS if model_cfg.moe_experts else ()
+        #: a model with expert or window layers: the decode program returns
+        #: one more row of logits, whose first entries are these counts of the
+        #: step (``transformer_decode``); the newest step's are kept by name
+        self._counters: Tuple[str, ...] = serve_counters(model_cfg)
         self.last_decode_counters: Dict[str, float] = {}
         #: the prefill's token width: the longest prompt in whole chunks
         #: (one trace; the walk's trip count follows each prompt)
@@ -255,29 +284,35 @@ class DecodeKernels:
 
     # -- kernel entry points (device round trips happen HERE) ---------------
 
-    def prefill(self, prompt: List[int], block_table: List[int]) -> np.ndarray:
+    def prefill(self, prompt: List[int], block_table: List[int], lane: int = 0) -> np.ndarray:
         """Prefill one sequence from its first token, writing its K/V into
-        the paged cache; returns the f32 logits at the last prompt token."""
-        return self._prefill_from(prompt, block_table, 0)
+        the paged cache; returns the f32 logits at the last prompt token.
+        ``lane``: the decode lane (the row of ``decode``'s batch) the sequence
+        will run in, whose ring a model's sliding-window layers write; a
+        model without them takes no notice of it."""
+        return self._prefill_from(prompt, block_table, 0, lane)
 
     def prefill_suffix(
-        self, prompt: List[int], block_table: List[int], start: int
+        self, prompt: List[int], block_table: List[int], start: int, lane: int = 0
     ) -> np.ndarray:
         """Prefill only ``prompt[start:]`` (the un-cached suffix; ``start``
         is block-aligned — the cached prefix already sits in the mapped
         blocks).  Returns the f32 logits at the last prompt token."""
-        return self._prefill_from(prompt, block_table, start)
+        return self._prefill_from(prompt, block_table, start, lane)
 
-    def _prefill_from(self, prompt: List[int], block_table: List[int], start: int) -> np.ndarray:
+    def _prefill_from(self, prompt: List[int], block_table: List[int], start: int, lane: int = 0) -> np.ndarray:
         # under both entry points, which a caller may wrap one by one
         tokens = np.zeros((1, self._prompt_pad), np.int32)
         tokens[0, : len(prompt)] = prompt
         table = np.asarray(block_table, np.int32)[None, :]
         starts = np.asarray([start], np.int32)
         lens = np.asarray([len(prompt)], np.int32)
-        logits, self.cache = self._prefill(
-            self.params, tokens, starts, lens, table, self.cache
-        )
+        args = (self.params, tokens, starts, lens, table, self.cache)
+        if self.windowed:
+            if start:
+                raise ValueError(f"a prompt of a model with sliding-window layers is prefilled from 0, not from {start}")
+            args += (np.asarray([lane], np.int32),)
+        logits, self.cache = self._prefill(*args)
         return np.asarray(logits[0])
 
     def decode(
@@ -634,6 +669,10 @@ class ServeEngine:
             # the TTL behind a 500 /healthz
             "failed": self.failed,
             "kv_cache": kv,
+            # a model with sliding-window layers: the bytes their store takes
+            # whatever the contexts, and the tokens a lane's ring holds a layer
+            # (``kv_cache`` counts the full layers' blocks alone)
+            "window_store": dict(getattr(self.kernels, "window_store", None) or {}),
             # live-block fraction, shared (ref>1) blocks counted ONCE so
             # prefix sharing never inflates the router's load signal
             "kv_utilization": round(kv["used"] / max(1, kv["capacity"]), 4),
@@ -700,6 +739,8 @@ class ServeEngine:
         )
         blocks = shared + private
         table = self._padded_table(blocks)
+        # the lane is known before the prefill: a window layer's rows go to its ring
+        lane = self.lanes.free_lane()
         # the walk's trip count: whole chunks, from the one the first
         # un-cached token lies in (0 cached when nothing matched)
         chunks = self.cfg.prefill_chunks(len(req.prompt), cached_tokens)
@@ -709,7 +750,7 @@ class ServeEngine:
                 "serve.prefill", cat="serve", request=req.id, step=step,
                 cached_tokens=cached_tokens, chunks=chunks, computed_tokens=computed,
             ):
-                logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens)
+                logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens, lane)
         except BaseException:
             self.allocator.free(blocks)
             raise
@@ -748,6 +789,7 @@ class ServeEngine:
             pos=len(req.prompt),
             next_token=tok,
             rng=rng,
+            lane=lane,
         )
         if self._sequence_finished(seq, tok):
             self._retire_seq(seq)
@@ -888,7 +930,7 @@ class ServeEngine:
             self._finish_error(req, f"prefill failed: {e}")
             return True
         if seq is not None:
-            self.lanes.join(seq)
+            self.lanes.join(seq, seq.lane)
         return True
 
     def _retire_lane(self, lane: int, seq: ActiveSeq) -> None:

@@ -176,6 +176,7 @@ class ActiveSeq:
     block_table: List[int]          # padded to blocks_per_seq with scratch 0
     pos: int                        # position of the NEXT token to feed
     next_token: int                 # token to feed at `pos`
+    lane: int                       # the decode lane it was prefilled for
     rng: Any = None                 # np.random.Generator for sampling
 
     @property
@@ -196,16 +197,21 @@ class LaneTable:
         self.joined = 0
         self.retired = 0
 
-    def join(self, seq: ActiveSeq) -> int:
-        """Place ``seq`` into the lowest free lane; raises if none free
-        (the engine checks ``has_free_lane`` first)."""
+    def free_lane(self) -> Optional[int]:
+        """The lowest free lane: the one the next ``join`` takes (only the
+        engine thread joins, so it can be told to a prefill beforehand)."""
         with self._lock:
-            for i, lane in enumerate(self._lanes):
-                if lane is None:
-                    self._lanes[i] = seq
-                    self.joined += 1
-                    return i
-        raise RuntimeError("no free decode lane")
+            return next((i for i, lane in enumerate(self._lanes) if lane is None), None)
+
+    def join(self, seq: ActiveSeq, lane: int) -> int:
+        """Place ``seq`` into ``lane``; raises if it is taken (the engine
+        asks ``free_lane`` before it prefills)."""
+        with self._lock:
+            if self._lanes[lane] is not None:
+                raise RuntimeError(f"decode lane {lane} is not free")
+            self._lanes[lane] = seq
+            self.joined += 1
+            return lane
 
     def retire(self, lane: int) -> ActiveSeq:
         with self._lock:

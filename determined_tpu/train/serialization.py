@@ -41,6 +41,12 @@ TRAINER_STATE_FILE = "trainer_state.json"
 
 # guards the once-a-process import below and what it records
 _backend_lock = threading.Lock()
+# one save at a time enters the backend's ``save``: orbax numbers a save with a
+# process-wide counter (``OperationIdGenerator``) and every future that call
+# creates reads the CURRENT number, so two threads (concurrent trials of a
+# packed local experiment) that start a save together wait on each other's
+# signals until the 300 s time-out.  The writes themselves still overlap.
+_save_lock = threading.Lock()
 # (orbax.checkpoint, seconds its import took, whether the prefetch thread ran it)
 _loaded: Optional[Tuple[Any, float, bool]] = None
 _prefetch_started = False
@@ -152,7 +158,8 @@ def save_arrays(ckpt_dir: str, tree: Any) -> None:
     processes — every process must call with the same tree structure."""
     path = os.path.join(os.path.abspath(ckpt_dir), ARRAY_SUBDIR)
     with _backend().StandardCheckpointer() as ckptr:
-        ckptr.save(path, _unkey(tree))
+        with _save_lock:
+            ckptr.save(path, _unkey(tree))
         ckptr.wait_until_finished()
 
 

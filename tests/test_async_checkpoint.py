@@ -251,3 +251,41 @@ def test_async_written_checkpoint_corruption_falls_back(tmp_path):
     trainer2._restore_checkpoint(sid_b)
     assert trainer2.steps_completed == 4  # fell back to the step-4 parent
     assert trainer2.latest_checkpoint == sid_a
+
+
+def test_two_threads_never_enter_the_backends_save_together(tmp_path, monkeypatch):
+    """orbax numbers a save from a process-wide counter that every future of
+    the call reads as "the current one": two concurrent trials that start a
+    save together wait on each other's signals until the time-out.  The
+    ``save`` calls are serialized; the waits for the writes are not."""
+    import time
+
+    inside, most, waiting = [0], [0], []
+
+    class Checkpointer:
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def save(self, path, tree):
+            inside[0] += 1
+            most[0] = max(most[0], inside[0])
+            time.sleep(0.05)
+            inside[0] -= 1
+
+        def wait_until_finished(self):
+            waiting.append(threading.get_ident())
+            time.sleep(0.05)
+
+    class Backend:
+        StandardCheckpointer = Checkpointer
+
+    monkeypatch.setattr(serialization, "_backend", lambda: Backend)
+    threads = [threading.Thread(target=serialization.save_arrays, args=(str(tmp_path / str(i)), {"w": np.ones(3)})) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert most[0] == 1 and len(set(waiting)) == 4

@@ -478,9 +478,12 @@ def test_what_cannot_honour_a_window_refuses_by_name():
     # ring attention knows no window
     with pytest.raises(ValueError, match="ring attention.*sliding_attention"):
         TransformerLM(_tiny(attention_impl="ring")).init(jax.random.key(0), tokens)
-    # nor does the serving forward; YaRN on full layers alone it serves (since PR 34)
-    with pytest.raises(ValueError, match="sliding-window layers.*YaRN"):
-        _check_decodable(_tiny())
+    # the serving forward does (since PR 38: a ring a lane, tests/test_window_serving.py), its wide prefill aside
+    _check_decodable(_tiny())
+    with pytest.raises(ValueError, match="the wide prefill runs full layers only"):
+        from determined_tpu.models.transformer import transformer_prefill
+
+        transformer_prefill(_tiny(), {}, tokens, jnp.ones(1, jnp.int32), jnp.zeros((1, 8), jnp.int32), {"k": jnp.zeros((1, 2, 4, 8))})
     _check_decodable(_tiny(layer_types=None, sliding_window=None))
     _check_decodable(_tiny(layer_types=(FULL,) * 3, sliding_window=None, rope_parameters=None))
 

@@ -253,24 +253,27 @@ def test_prefix_interleaved_share_release_no_fragmentation():
 # ---------------------------------------------------------------------------
 
 
-def _dummy_seq(rid=0):
+def _dummy_seq(lane=0):
     return ActiveSeq(
         request=GenRequest(prompt=[1], max_new_tokens=1),
         blocks=[1],
         block_table=[1, 0],
         pos=1,
         next_token=0,
+        lane=lane,
     )
 
 
 def test_lane_table_join_retire():
     lanes = LaneTable(2)
-    i0 = lanes.join(_dummy_seq())
-    i1 = lanes.join(_dummy_seq())
+    assert lanes.free_lane() == 0
+    i0 = lanes.join(_dummy_seq(0), 0)
+    assert lanes.free_lane() == 1
+    i1 = lanes.join(_dummy_seq(1), 1)
     assert {i0, i1} == {0, 1}
-    assert not lanes.has_free_lane()
+    assert not lanes.has_free_lane() and lanes.free_lane() is None
     with pytest.raises(RuntimeError):
-        lanes.join(_dummy_seq())
+        lanes.join(_dummy_seq(1), 1)
     lanes.retire(i0)
     assert lanes.has_free_lane()
     with pytest.raises(RuntimeError):

@@ -585,3 +585,79 @@ def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, mo
     assert sum("paged_latent_attention" in n for n in scopes["serve.mla.attend"]) == 2
     named = [n for n in scopes["serve.moe.experts"] if re.match(r"(moe_gmm|moe_rows_of_tokens|moe_tokens_of_rows)", n)]
     assert len(named) == 5, scopes["serve.moe.experts"]
+
+
+# -- sliding-window layers served from a ring a lane: the Command A+ cell's shapes --
+
+
+def test_the_window_decode_kernel_compiles_at_the_command_cells_shape(tpu_devices):
+    """32 lanes x 128 query heads over 8 KV heads of 128, blocks of 16, a ring
+    of 272 blocks (4,352 tokens) a lane in a store of three window layers:
+    tiles of 256 tokens through the ring, the window's 4,096 newest alone."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, k_pool, v_pool, tables, positions):
+        return paged_mod.paged_decode_attention(q, k_pool, v_pool, 2, tables, positions, scale=128 ** -0.5, window=4096)
+
+    store = aval((3, 32 * 272, 16, 1024), jnp.bfloat16)
+    text = _compile(fn, aval((32, 128, 128), jnp.bfloat16), store, store, aval((32, 272), jnp.int32), aval((32,), jnp.int32))
+    assert _kernels(text) == 1 and "paged_window_attention" in text
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_devices, monkeypatch, which):
+    """The cell's decode step and prefill walk at its widths, lanes, pool and
+    window store, bfloat16 leaves, depth cut to one window layer and the full
+    layer: weights, both kinds of cache and the program's scratch fit the chip;
+    the window layer's kernel keeps its name under its own scope, the full
+    layer's under the other; nothing the size of a lane's context is gathered."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_decode,
+        transformer_prefill_chunked, window_store_shape,
+    )
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=2, n_heads=128, n_kv_heads=8, head_dim=128, max_seq_len=20480,
+        layer_types=("sliding_attention", "full_attention"), sliding_window=4096,
+        rope_parameters={"full_attention": {"rope_type": "none"}, "sliding_attention": {"rope_type": "default", "rope_theta": 50000.0}},
+        moe_experts=128, moe_every=1, moe_top_k=8, moe_intermediate_size=4096, moe_experts_held=(0, 16), moe_router="sigmoid",
+        moe_shared_experts=4, moe_shared_combine="mean", norm="layernorm", norm_eps=1e-5, parallel_block=True,
+        tie_embeddings=True, param_dtype=jnp.bfloat16,
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    assert prefill_chunk_tokens(16, 14336) == 256
+    pool, ring = kv_cache_shape(cfg, 24576, 16), window_store_shape(cfg, 32, 16, 256)
+    assert pool == (1, 24576, 16, 1024) and ring == (1, 32 * 272, 16, 1024)
+    cache = {"k": aval(pool, cfg.dtype), "v": aval(pool, cfg.dtype), "wk": aval(ring, cfg.dtype), "wv": aval(ring, cfg.dtype)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((32,)), aval((32,)), aval((32, 1280)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,))
+        args = (params, aval((1, 14336)), aval((1,)), aval((1,)), aval((1, 1280)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache_bytes = 2 * 2 * (24576 + 32 * 272) * 16 * 1024
+    assert mem.alias_size_in_bytes >= cache_bytes                                    # both kinds are donated
+    assert mem.temp_size_in_bytes < (64 if which == "decode" else 1024) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.attn.window", "serve.attn.full", "serve.attn.attend", "serve.kv.write", "serve.moe.route", "serve.moe.experts",
+            "serve.moe.shared"} <= set(scopes)
+    if which == "decode":
+        assert _kernels(text) == 2 + 2 * 5                                          # an attention kernel and five of the experts a layer
+        assert sum("paged_window_attention" in n for n in scopes["serve.attn.window"]) == 1
+        assert sum("paged_decode_attention" in n for n in scopes["serve.attn.full"]) == 1
+        assert not any("paged_" in n for n in set(scopes["serve.attn.window"]) & set(scopes["serve.attn.full"]))
+        assert _arrays_with_dims(text, (32, 20480)) == [] and _arrays_with_dims(text, (32, 4352, 1024)) == []
+    else:
+        assert _kernels(text) == 2 * 5
+        assert _arrays_with_dims(text, (128, 256, 20480)) == [] and _arrays_with_dims(text, (256, 4352)) == []
