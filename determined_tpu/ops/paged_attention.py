@@ -16,11 +16,31 @@ and accumulator in float32, K and V in the pool's dtype) over tiles of
   ``ceil(length / tile_tokens)`` tiles (an empty lane none, a short lane
   does not wait for the longest), copying each tile's blocks HBM -> VMEM
   with the next tile's copies in flight.  The ``n_rep`` query heads of a KV
-  head are served from ONE copy of its K/V: the wrapper lays the query out
-  block-diagonally (``[heads, kv_heads * head_dim]``, a head's vector in its
-  KV head's columns, zeros elsewhere), so a tile costs two wide MXU calls
-  (``q_bd @ K^T``, ``p @ V``) and the result is the diagonal blocks of the
-  accumulator.  Taken on a TPU when the shapes tile: ``head_dim`` a multiple
+  head are served from ONE copy of its K/V, in one of two layouts of the
+  tile's two products, chosen from ``n_rep`` (:func:`attn_products`), never
+  by the caller:
+
+  - *a KV head at a time*, where a KV head's rows of the float32 scores
+    fill whole sublane tiles (``n_rep`` a multiple of 8: Command A+'s 16).
+    For each KV head's 128-aligned column slice of the tile, ``q[g] [n_rep,
+    head_dim] @ K_g^T``; one softmax update over the stacked ``[heads,
+    tile]`` scores; ``p_g [terms * n_rep, tile] @ V_g`` into an accumulator
+    ``[heads, head_dim]``.  K and V pass through the MXU once each and
+    nothing is multiplied by zero.
+  - *block-diagonal* below that (InternLM2's 2, Mistral's 4): the wrapper
+    lays the query out as ``[heads, kv_heads * head_dim]``, a head's vector
+    in its KV head's columns and zeros elsewhere, so a tile costs two wide
+    MXU calls (``q_bd @ K^T``, ``p @ V``) and the result is the diagonal
+    blocks of the accumulator.  With 16 or 32 rows in all the zeros cost
+    nothing (K and V must pass through the array once whatever the rows
+    are); at 128 rows they cost 8 x the products and a ``[128, 1024]``
+    float32 accumulator.
+
+  On a v5e over 8 KV heads of 128 in bfloat16 (32 lanes, contexts ~7.5 k)
+  a 256-token tile takes 1.44 / 1.46 / 1.60 / 2.25 us block-diagonal at 2 /
+  4 / 8 / 16 query heads a KV head and 1.44 / 1.44 us a KV head at a time
+  at 8 / 16, where the tile's copy is 1.28 us (my chip runs, PR 42: PERF.md
+  section 5).  Taken on a TPU when the shapes tile: ``head_dim`` a multiple
   of 128 and ``block_size`` a multiple of the pool dtype's sublane packing.
 * :func:`_paged_attention_jnp` — the same mathematics in plain
   ``jax.numpy`` for every other shape and backend, batched over lanes (it
@@ -56,7 +76,9 @@ from determined_tpu.ops.flash_attention import NEG_INF
 # last tile of a lane is copied whole, so the width also bounds the waste.
 # On a v5e at InternLM2's shapes (16 x 128 heads, 8 KV, blocks of 16, ~27 k
 # live tokens over 32 lanes) 24 layers took 6.9 / 5.2 / 5.1 / 5.9 / 7.6 ms at
-# 64 / 128 / 256 / 512 / 1024 tokens a tile (my chip run, PR 25).
+# 64 / 128 / 256 / 512 / 1024 tokens a tile (my chip run, PR 25).  Both
+# layouts of the products keep it: a KV head at a time a tile's time is its
+# copies' (1.44 us against 1.28 us of read at Command A+'s shapes, PR 42).
 TILE_TOKENS = 256
 # VMEM the kernel's four tile buffers (K and V, two slots each) may take
 TILE_BUFFER_BYTES = 4 * 1024 * 1024
@@ -86,6 +108,14 @@ def kernel_takes(head_dim: int, block_size: int, dtype) -> bool:
         and head_dim % 128 == 0
         and block_size % _sublane_packing(dtype) == 0
     )
+
+
+def attn_products(n_rep: int) -> str:
+    """Which layout the kernel's two products take at ``n_rep`` query heads a
+    KV head (see the module's text): ``"per_kv_head"`` where a KV head's rows
+    of the float32 scores fill whole sublane tiles, else ``"block_diagonal"``.
+    The name is what ``/stats`` and the ``serve.setup.kv_pool`` span say."""
+    return "per_kv_head" if n_rep % _sublane_packing(jnp.float32) == 0 else "block_diagonal"
 
 
 def paged_decode_attention(
@@ -273,14 +303,14 @@ def _paged_attention_kernel(
     o_ref,                                        # output
     k_buf, v_buf, sems,                           # scratch
     *, scale: float, tile_blocks: int, block_size: int, table_width: int,
-    kv_heads: int, head_dim: int, n_rep: int, window: Optional[int] = None,
+    kv_heads: int, head_dim: int, n_rep: int, per_kv_head: bool, window: Optional[int] = None,
 ):
     b = pl.program_id(0)
     layer = layer_ref[0]
     length = lengths_ref[b]
     tile_tokens = tile_blocks * block_size
     n_tiles = (length + tile_tokens - 1) // tile_tokens
-    rows = q_ref.shape[0]
+    rows = o_ref.shape[0]
     if window is None:
         tile_of = lambda i: i  # noqa: E731 (trip i of the walk is tile i)
     else:
@@ -316,7 +346,19 @@ def _paged_attention_kernel(
         for c in copies(tile_of(0), 0):
             c.start()
 
-    q = q_ref[...]                                    # [rows, kv_heads*head_dim]
+    if not per_kv_head:
+        q = q_ref[...]                                # [rows, kv_heads*head_dim]
+
+    def of_head(buf, slot, g):
+        """KV head ``g``'s columns of the tile in ``slot``: [tile_tokens, head_dim]."""
+        return buf[slot, :, g * head_dim:(g + 1) * head_dim]
+
+    def sum_terms(pv, n):
+        """``pv`` [terms * n, ...] -> the sum of its terms [n, ...]."""
+        total = pv[:n]
+        for t in range(1, pv.shape[0] // n):
+            total = total + pv[t * n:(t + 1) * n]
+        return total
 
     def body(i, carry):
         m, l, acc = carry
@@ -329,11 +371,19 @@ def _paged_attention_kernel(
 
         for c in copies(tile_of(i), slot):
             c.wait()
-        k = k_buf[slot]                               # [tile_tokens, kv_heads*head_dim]
-        v = v_buf[slot]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                     # [rows, tile_tokens]
+        if per_kv_head:
+            s = jnp.concatenate([
+                jax.lax.dot_general(
+                    q_ref[g], of_head(k_buf, slot, g), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for g in range(kv_heads)
+            ], axis=0) * scale                        # [rows, tile_tokens], a KV head's n_rep rows together
+        else:
+            k = k_buf[slot]                           # [tile_tokens, kv_heads*head_dim]
+            v = v_buf[slot]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale                                 # [rows, tile_tokens]
         k_idx = tile_of(i) * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if window is None:
             s = jnp.where(k_idx < length, s, NEG_INF)
@@ -343,22 +393,31 @@ def _paged_attention_kernel(
         p = jnp.exp(s - m_new)                        # masked: exp(NEG_INF - m) = 0
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            _split_terms(p, v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                             # [terms*rows, kv_heads*head_dim]
-        total = pv[:rows]
-        for t in range(1, pv.shape[0] // rows):
-            total = total + pv[t * rows:(t + 1) * rows]
+        if per_kv_head:
+            total = jnp.concatenate([
+                sum_terms(jax.lax.dot_general(
+                    _split_terms(p[g * n_rep:(g + 1) * n_rep], v_buf.dtype), of_head(v_buf, slot, g),
+                    (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+                ), n_rep) for g in range(kv_heads)    # [terms*n_rep, head_dim] a KV head
+            ], axis=0)                                # [rows, head_dim]
+        else:
+            pv = jax.lax.dot_general(
+                _split_terms(p, v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                         # [terms*rows, kv_heads*head_dim]
+            total = sum_terms(pv, rows)
         return m_new, l_new, acc * alpha + total
 
     init = (
         jnp.full((rows, 1), NEG_INF, jnp.float32),
         jnp.zeros((rows, 1), jnp.float32),
-        jnp.zeros((rows, kv_heads * head_dim), jnp.float32),
+        jnp.zeros((rows, head_dim if per_kv_head else kv_heads * head_dim), jnp.float32),
     )
     _, l, acc = jax.lax.fori_loop(0, n_tiles, body, init)
     acc = acc / jnp.maximum(l, 1e-30)
+    if per_kv_head:
+        o_ref[...] = acc
+        return
     # row h holds head h's result in the columns of its KV head
     row_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, head_dim), 0) // n_rep
     out = jnp.zeros((rows, head_dim), jnp.float32)
@@ -376,23 +435,31 @@ def _paged_attention_pallas(
     kv_heads = kvd // head_dim
     n_rep = n_heads // kv_heads
     t = block_tables.shape[1]
-    # whole sublane tiles of rows for the MXU's left operand; padding rows
-    # are zero queries, dropped below
-    packing = _sublane_packing(q.dtype)
-    rows = -(-n_heads // packing) * packing
-    # block-diagonal query: head h's vector in the columns of KV head h // n_rep
-    kv_of_head = jnp.arange(n_heads) // n_rep
-    q_bd = jnp.where(
-        (kv_of_head[:, None] == jnp.arange(kv_heads)[None, :])[None, :, :, None],
-        q[:, :, None, :],
-        jnp.zeros((), q.dtype),
-    ).reshape(b, n_heads, kvd)
-    q_bd = jnp.pad(q_bd, ((0, 0), (0, rows - n_heads), (0, 0)))
+    per_kv_head = attn_products(n_rep) == "per_kv_head"
+    if per_kv_head:
+        rows = n_heads
+        q_in = q.reshape(b, kv_heads, n_rep, head_dim)
+        q_spec = pl.BlockSpec((None, kv_heads, n_rep, head_dim), lambda bi, *_: (bi, 0, 0, 0))
+    else:
+        # whole sublane tiles of rows for the MXU's left operand; padding rows
+        # are zero queries, dropped below
+        packing = _sublane_packing(q.dtype)
+        rows = -(-n_heads // packing) * packing
+        # block-diagonal query: head h's vector in the columns of KV head h // n_rep
+        kv_of_head = jnp.arange(n_heads) // n_rep
+        q_bd = jnp.where(
+            (kv_of_head[:, None] == jnp.arange(kv_heads)[None, :])[None, :, :, None],
+            q[:, :, None, :],
+            jnp.zeros((), q.dtype),
+        ).reshape(b, n_heads, kvd)
+        q_in = jnp.pad(q_bd, ((0, 0), (0, rows - n_heads), (0, 0)))
+        q_spec = pl.BlockSpec((None, rows, kvd), lambda bi, *_: (bi, 0, 0))
     tile_tokens = tile_blocks * block_size
     kernel = functools.partial(
         _paged_attention_kernel,
         scale=scale, tile_blocks=tile_blocks, block_size=block_size,
         table_width=t, kv_heads=kv_heads, head_dim=head_dim, n_rep=n_rep, window=window,
+        per_kv_head=per_kv_head,
     )
     out = pl.pallas_call(
         kernel,
@@ -400,7 +467,7 @@ def _paged_attention_pallas(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
-                pl.BlockSpec((None, rows, kvd), lambda bi, *_: (bi, 0, 0)),
+                q_spec,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -419,7 +486,7 @@ def _paged_attention_pallas(
         layer.reshape(1),
         lengths,
         block_tables.reshape(-1).astype(jnp.int32),
-        q_bd, k_pool, v_pool,
+        q_in, k_pool, v_pool,
     )
     return out[:, :n_heads, :]
 

@@ -159,6 +159,7 @@ class DecodeKernels:
             transformer_prefill_chunked,
             window_ring_blocks,
         )
+        from determined_tpu.ops.paged_attention import attn_products
         from determined_tpu.utils.compilation_cache import (
             setup_compilation_cache,
             timed_first_call,
@@ -204,7 +205,7 @@ class DecodeKernels:
         #: ``/stats`` ``window_store`` (empty where no layer slides): the bytes
         #: the window layers' store takes whatever the contexts, and a lane's ring
         self.window_store: Dict[str, int] = {}
-        kinds: Dict[str, int] = {}
+        kinds: Dict[str, Any] = {}
         if self.windowed:
             self.window_store = {
                 "window_store_bytes": int(self.cache["wk"].nbytes + self.cache["wv"].nbytes),
@@ -218,6 +219,12 @@ class DecodeKernels:
                 "bytes_per_token_window": layer * n_window,
                 **self.window_store,
             }
+        #: ``/stats`` ``attn_products``: what a tile of the GQA decode kernel
+        #: multiplies at this model's heads (``ops/paged_attention.py``); None
+        #: for latent layers, whose heads all share a row
+        self.attn_products: Optional[str] = None
+        if not model_cfg.latent:
+            kinds["attn_products"] = self.attn_products = attn_products(model_cfg.n_heads // model_cfg.kv_heads)
         # bytes_per_token: what attention reads of the pool for one cached
         # token over all layers (K and V rows, or one latent row a layer)
         tracer.record_span(
@@ -646,6 +653,7 @@ class ServeEngine:
             with self._stats_lock:
                 self._latency_summary = (completed, latency)
         kv = self.allocator.stats()
+        products = getattr(self.kernels, "attn_products", None)
         return {
             **counters,
             # what a caller feels, over the newest LATENCY_WINDOW finished
@@ -673,6 +681,9 @@ class ServeEngine:
             # whatever the contexts, and the tokens a lane's ring holds a layer
             # (``kv_cache`` counts the full layers' blocks alone)
             "window_store": dict(getattr(self.kernels, "window_store", None) or {}),
+            # what a tile of the GQA decode kernel multiplies ("per_kv_head" |
+            # "block_diagonal"); absent for latent layers
+            **({"attn_products": products} if products else {}),
             # live-block fraction, shared (ref>1) blocks counted ONCE so
             # prefix sharing never inflates the router's load signal
             "kv_utilization": round(kv["used"] / max(1, kv["capacity"]), 4),

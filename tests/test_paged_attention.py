@@ -65,7 +65,7 @@ def _pool_case(dtype, n_rep, head_dim, block_size, positions, seed=0):
 
 
 @pytest.mark.parametrize("tile_blocks", [1, 4, None], ids=["tile1", "tile4", "tile_auto"])
-@pytest.mark.parametrize("n_rep", [1, 2, 4], ids=lambda r: f"n_rep{r}")
+@pytest.mark.parametrize("n_rep", [1, 2, 4, 8, 16], ids=lambda r: f"n_rep{r}")
 @pytest.mark.parametrize(
     "impl,dtype",
     [("jnp", jnp.float32), ("jnp", jnp.bfloat16),
@@ -77,7 +77,9 @@ def test_paged_attention_matches_numpy_over_ragged_lanes(impl, dtype, n_rep, til
     trips, the last one part live; 4 does not divide the table's 6 columns)
     and the width the shapes choose (one trip).  K, V and q are exact in the
     pool's dtype on both sides, so what is left is float32 reassociation:
-    a bf16 pool must NOT cost bf16 precision in the probabilities."""
+    a bf16 pool must NOT cost bf16 precision in the probabilities.  ``n_rep``
+    1 to 4 run the kernel's block-diagonal products, 8 and 16 a KV head at a
+    time."""
     q, k_pool, v_pool, tables, positions = _pool_case(
         dtype, n_rep, 128, 16, list(RAGGED.values())
     )
@@ -126,6 +128,34 @@ def test_kernel_is_the_choice_on_a_tpu_when_the_shapes_tile(monkeypatch):
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     pa.paged_decode_attention(*args, scale=1.0)
     assert taken == [False]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_products_layout_comes_from_the_query_heads_a_kv_head(dtype, monkeypatch):
+    """A KV head at a time where its query heads fill whole sublane tiles of
+    the float32 scores, the block-diagonal query below that: InternLM2 (2) and
+    Mistral (4) stay, Command A+ (16) turns, whatever the query's dtype; and
+    the layout the function names is the one the wrapper hands the kernel."""
+    assert [pa.attn_products(n_rep) for n_rep in (1, 2, 4, 8, 16)] == 3 * ["block_diagonal"] + 2 * ["per_kv_head"]
+    seen = {}  # n_rep -> (the kernel's layout, the dimensions of the query it was handed)
+    call = pa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        run = call(kernel, **kw)
+
+        def called(*args):
+            seen[kernel.keywords["n_rep"]] = (kernel.keywords["per_kv_head"], args[3].ndim)
+            return run(*args)
+
+        return called
+
+    monkeypatch.setattr(pa.pl, "pallas_call", spy)
+    for n_rep in (1, 2, 4, 16):
+        q, k_pool, v_pool, tables, positions = _pool_case(dtype, n_rep, 128, 16, [5, 40])
+        pa._paged_attention_pallas(q, k_pool, v_pool, jnp.asarray(0), jnp.asarray(tables), jnp.asarray(positions) + 1,
+                                   128 ** -0.5, 2, interpret=True)
+    # [b, kv_heads, n_rep, head_dim] a KV head at a time, [b, rows, kv_heads * head_dim] block-diagonal
+    assert seen == {1: (False, 3), 2: (False, 3), 4: (False, 3), 16: (True, 4)}
 
 
 def test_tile_width_comes_from_the_shapes():

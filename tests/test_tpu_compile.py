@@ -603,6 +603,32 @@ def test_the_window_decode_kernel_compiles_at_the_command_cells_shape(tpu_device
     store = aval((3, 32 * 272, 16, 1024), jnp.bfloat16)
     text = _compile(fn, aval((32, 128, 128), jnp.bfloat16), store, store, aval((32, 272), jnp.int32), aval((32,), jnp.int32))
     assert _kernels(text) == 1 and "paged_window_attention" in text
+    # 16 query heads a KV head: the kernel multiplies a KV head's own queries, and no block-diagonal query is built
+    assert paged_mod.attn_products(16) == "per_kv_head" and _arrays_with_dims(text, (32, 128, 1024)) == []
+
+
+@pytest.mark.parametrize(
+    "heads, pool_shape, products",
+    [(16, (24, 3500, 16, 1024), "block_diagonal"), (64, (1, 24576, 16, 1024), "per_kv_head")],
+    ids=["internlm2-cell", "8-a-kv-head"],
+)
+def test_the_decode_kernel_compiles_in_both_layouts_of_its_products(tpu_devices, heads, pool_shape, products):
+    """The InternLM2 cells' shape: 32 lanes x 16 query heads over 8 KV heads of
+    128 against a bfloat16 pool of 3,500 blocks x 24 layers, where 2 query heads
+    a KV head keep the block-diagonal query (16 rows against the tile's 1,024
+    columns); and 8 query heads a KV head, half a packed tile of the bfloat16
+    query each, where the rule turns (1.60 -> 1.44 us a tile: PERF.md, PR 42)."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, k_pool, v_pool, tables, positions):
+        return paged_mod.paged_decode_attention(q, k_pool, v_pool, 0, tables, positions, scale=128 ** -0.5)
+
+    pool = aval(pool_shape, jnp.bfloat16)
+    text = _compile(fn, aval((32, heads, 128), jnp.bfloat16), pool, pool, aval((32, 128), jnp.int32), aval((32,), jnp.int32))
+    assert _kernels(text) == 1 and "paged_decode_attention" in text
+    assert paged_mod.attn_products(heads // 8) == products
+    assert bool(_arrays_with_dims(text, (32, heads, 1024))) == (products == "block_diagonal")
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])

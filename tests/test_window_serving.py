@@ -331,10 +331,16 @@ def _plain_window_attention(q, k_pool, v_pool, layer, tables, positions, window,
     return np.stack(out)
 
 
-@pytest.mark.parametrize("dtype, tile_blocks", [(jnp.float32, 2), (jnp.float32, 3), (jnp.bfloat16, 2)])
+@pytest.mark.parametrize(
+    "dtype, tile_blocks, n_rep",
+    [(jnp.float32, 2, 4), (jnp.float32, 3, 4), (jnp.bfloat16, 2, 4), (jnp.bfloat16, 3, 16)],
+    ids=["f32-tile2", "f32-tile3", "bf16-tile2", "bf16-tile3-n_rep16"],
+)
 @pytest.mark.parametrize("impl", ["jnp", "kernel_interpret"])
-def test_the_window_kernel_and_its_jnp_form_read_the_window_and_nothing_older(dtype, tile_blocks, impl):
-    q, k_pool, v_pool, tables, positions, window, _ = _ring_case(dtype)
+def test_the_window_kernel_and_its_jnp_form_read_the_window_and_nothing_older(dtype, tile_blocks, n_rep, impl):
+    """At 4 query heads a KV head the kernel's block-diagonal products, at 16 a KV head at a time."""
+    q, k_pool, v_pool, tables, positions, window, _ = _ring_case(dtype, n_rep=n_rep)
+    assert paged.attn_products(n_rep) == ("per_kv_head" if n_rep == 16 else "block_diagonal")
     positions = positions.at[1].set(-1) if tile_blocks == 3 else positions           # an idle lane: zeros
     want = _plain_window_attention(q, k_pool, v_pool, 1, tables, positions, window, 0.09)
     got = paged.paged_decode_attention(q, k_pool, v_pool, 1, tables, positions, scale=0.09, window=window,
@@ -500,6 +506,35 @@ def test_two_lanes_of_unequal_length_through_the_engine_match_the_full_forward(e
     assert set(stats["step_counters"]) == set(SERVE_KV_COUNTERS + SERVE_COUNTERS)
     assert stats["step_counters"]["serve.kv.full_tokens"] > stats["step_counters"]["serve.kv.window_tokens"] / 3 > 0
     assert stats["kv_cache"]["used"] == 0                            # the allocator counts the full layer's blocks, all freed
+
+
+@pytest.mark.parametrize(
+    "heads, want",
+    [(dict(n_heads=16, n_kv_heads=1), "per_kv_head"), (dict(n_heads=4, n_kv_heads=2), "block_diagonal"),
+     (dict(n_heads=2, q_lora_rank=8, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8), None)],
+    ids=["16-a-kv-head", "2-a-kv-head", "latent"],
+)
+def test_the_engine_says_what_a_tile_of_its_decode_kernel_multiplies(heads, want):
+    """``serve.setup.kv_pool`` and ``/stats`` name the layout of the GQA kernel's
+    products, from the function the kernel's wrapper asks; a latent model has none."""
+    from determined_tpu.observability import get_tracer
+    from determined_tpu.serve.config import ServeConfig
+    from determined_tpu.serve.engine import DecodeKernels, ServeEngine
+
+    cfg = TransformerConfig(vocab_size=32, d_model=32, n_layers=1, d_ff=32, max_seq_len=32, dtype=jnp.bfloat16,
+                            attention_impl="reference", partition_params=False, **heads)
+    params = meta.unbox(TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.configure(enabled=True)
+    try:
+        kernels = DecodeKernels(cfg, params, ServeConfig(block_size=BLOCK, num_blocks=8, max_batch=1, max_prompt_len=8,
+                                                         max_new_tokens=4, queue_depth=2))
+        (pool,) = [e for e in tracer.chrome_events() if e.get("ph") == "X" and e["name"] == "serve.setup.kv_pool"]
+    finally:
+        tracer.reset()
+    stats = ServeEngine(kernels).stats()
+    assert kernels.attn_products == want and pool["args"].get("attn_products") == want and stats.get("attn_products") == want
 
 
 def test_the_engine_picks_the_lane_before_it_prefills(engine_parts, model):
