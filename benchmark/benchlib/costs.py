@@ -8,8 +8,8 @@ roofline share is (least time the chip could take) / (time taken), and a PR
 that claims a gain may not touch either half.  Recomputed operations (remat)
 are never counted.  The parameter counts and attention's shape come from the
 configuration's adapter (``arch``: ``benchmark/archs/``); the per-token
-arithmetic follows ``bench.py`` of the repo (6 x matmul parameters +
-attention), made exact there for grouped-query attention, a gated MLP and an
+arithmetic is that of ``LMTrial.flops_per_token`` (6 x matmul parameters +
+attention), made exact here for grouped-query attention, a gated MLP and an
 untied head.
 """
 

@@ -9,6 +9,7 @@ TPU or fails).
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 import time
@@ -148,6 +149,14 @@ def run_cell(
             }
         _say("end_to_end_of_traced_run", values=out["values"])
     line["device"] = device
+    # each number the verdict rests on beside its limit (the runner's check
+    # names both), last in the line; one that is not a number as a word: the
+    # line stays JSON
+    check = out["check"]
+    compared = {**{q: (check[q], limit) for q, limit in check["tolerance"].items()}, "compiles_in_window": (compiled, 0)}
+    line["compared"] = {
+        name: [value if math.isfinite(value) else str(value), limit] for name, (value, limit) in compared.items()
+    }
     return line
 
 
@@ -169,4 +178,7 @@ def main(argv: Optional[List[str]] = None, t_start: Optional[float] = None, **ce
         print(f"benchmark: {e}", file=sys.stderr)
         return 3
     print(json.dumps(line), flush=True)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})", file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
     return 0

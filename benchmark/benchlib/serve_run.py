@@ -4,9 +4,11 @@ The system under test is the engine exactly as ``dtpu serve`` builds it —
 ``ServeEngine(DecodeKernels(model_cfg, params, serve_cfg))``, its own thread
 running its own loop — fed through ``engine.submit`` by this file's load
 generator.  The benchmark's readings come from the requests' own stamps
-(``first_token_at``, ``finished_at``) and from thin wrappers this file puts
-round the engine's three device calls (and, in a traced run only, round the
-host sampler): spans inside the program are a later PR's.
+(``first_token_at``, ``finished_at``), from thin wrappers this file puts
+round the engine's three device calls (step boundaries for the slice rates)
+and from the program's own spans (``serve.step``, ``serve.sample``,
+``serve.decode`` and its children).  Nothing here knows where the program
+samples: on the host from ``decode``'s logits, or inside the decode program.
 """
 
 from __future__ import annotations
@@ -31,21 +33,17 @@ class StepRecorder:
 
     Every run keeps one tuple a call, with the engine's own count of the
     tokens it has emitted so far (the earlier line's slice rates are cut at
-    these step boundaries).  A traced run also wraps the module's
-    ``sample_token`` and writes spans into the repo's tracer.
+    these step boundaries).  What ``decode`` returns (logits for a host
+    sampler, or token ids) passes through untouched.
     """
 
-    def __init__(self, engine: Any, spans: bool) -> None:
+    def __init__(self, engine: Any) -> None:
         self.engine = engine
-        self.spans = spans
         #: (t0, t1, active lanes, live kv tokens, engine's tokens_generated at t0)
         self.decodes: List[Tuple[float, float, int, int, int]] = []
         #: (t0, t1)
         self.prefills: List[Tuple[float, float]] = []
-        #: per decode step: (first sample start, last sample end)
-        self.samples: List[List[float]] = []
         self._restore: List[Callable[[], None]] = []
-        self._step_open = False
 
     def install(self) -> None:
         k = self.engine.kernels
@@ -61,12 +59,10 @@ class StepRecorder:
             self.decodes.append(
                 (t0, mono(), int(live.sum()), int(positions[live].sum() + live.sum()), emitted)
             )
-            self._step_open = True
             return out
 
         def timed(fn: Callable[..., np.ndarray]) -> Callable[..., np.ndarray]:
             def call(*args: Any) -> np.ndarray:
-                self._step_open = False
                 t0 = mono()
                 out = fn(*args)
                 self.prefills.append((t0, mono()))
@@ -76,26 +72,6 @@ class StepRecorder:
 
         k.decode, k.prefill, k.prefill_suffix = timed_decode, timed(prefill), timed(suffix)
         self._restore.append(lambda: (setattr(k, "decode", decode), setattr(k, "prefill", prefill), setattr(k, "prefill_suffix", suffix)))
-        if self.spans:
-            from determined_tpu.serve import engine as engine_mod
-
-            sample = engine_mod.sample_token
-
-            def timed_sample(logits: np.ndarray, temperature: float, rng: Any) -> int:
-                t0 = mono()
-                tok = sample(logits, temperature, rng)
-                t1 = mono()
-                # a prefill's first-token sample belongs to admission, not
-                # to the step: _step_open is false from a prefill's start
-                if self._step_open:
-                    if len(self.samples) < len(self.decodes):
-                        self.samples.append([t0, t1])
-                    else:
-                        self.samples[-1][1] = t1
-                return tok
-
-            engine_mod.sample_token = timed_sample
-            self._restore.append(lambda: setattr(engine_mod, "sample_token", sample))
 
     def remove(self) -> None:
         for undo in self._restore:
@@ -190,7 +166,7 @@ def run(
     tracer = get_tracer()
     tracer.configure(enabled=traced)
     epoch = tracer_epoch(tracer) if traced else 0.0
-    recorder = StepRecorder(engine, spans=traced)
+    recorder = StepRecorder(engine)
     recorder.install()
     engine.start()
     kv_samples: List[float] = []
@@ -273,11 +249,7 @@ def run(
             counters["serve." + name] = values[name]
     spans: List[Tuple[str, float, float]] = []
     if traced:
-        for (t0, t1, *_), smp in itertools.zip_longest(recorder.decodes, recorder.samples):
-            spans.append(("bench.serve.decode_call", t0, t1 - t0))
-            if smp is not None:
-                spans.append(("bench.serve.sample", smp[0], smp[1] - smp[0]))
-                spans.append(("bench.serve.decode_step", t0, smp[1] - t0))
+        spans += [("bench.serve.decode_call", t0, t1 - t0) for t0, t1, *_ in recorder.decodes]
         spans += [("bench.serve.prefill_call", t0, t1 - t0) for t0, t1 in recorder.prefills]
         spans += result.get("spans", [])
     obs = Observations(
@@ -293,6 +265,7 @@ def run(
         "attempted": result["attempted"],
         "failed": result["failed"],
         "correct": bool(correct and result["failed"] == 0),
+        "check": detail,
         "memory_peak_bytes": peak,
         "observations": obs,
     }

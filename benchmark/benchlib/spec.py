@@ -13,11 +13,22 @@ import dataclasses
 import json
 import os
 import re
-from typing import Any, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+#: what a metric's file may say under ``"cells"``: the cells that report it, by
+#: the ``kind`` of their traffic.  ``{"of": <one of these>, "scope": <name>}``
+#: narrows it to the cells whose program has that scope.  Absent, the metric's
+#: list is its builder's to say (a cost function counts one architecture's work)
+CELLS = {
+    "every": ("train", "serve-open", "serve-closed"),
+    "training": ("train",),
+    "serving": ("serve-open", "serve-closed"),
+    "open-loop": ("serve-open",),
+}
 
 #: the checkout: ``benchmark/benchlib/spec.py`` -> two levels up
 CHECKOUT = os.path.dirname(
@@ -104,6 +115,32 @@ class Spec:
             end_to_end=[m for m in self.doc["end_to_end"] if self._applies(m, name)],
             per_layer=per_layer,
         )
+
+    def belongs(self, metric: str, cell: Cell, has_scope: Callable[[Cell, str], bool]) -> Optional[bool]:
+        """Does the metric's file (``"cells"``: see ``CELLS``) put this cell
+        on the metric's list?  None where the file states no rule.
+        ``has_scope(cell, scope)`` says whether the cell's program has a
+        scope: the tests ask a CPU lowering of the architecture's tiny form."""
+        path = os.path.join(self.data_dir, "metrics", metric + ".json")
+        rule = _load(path).get("cells")
+        if rule is None:
+            return None
+        of, scope = (rule.get("of"), rule.get("scope")) if isinstance(rule, dict) else (rule, None)
+        if not isinstance(of, str) or of not in CELLS or (isinstance(rule, dict) and (set(rule) != {"of", "scope"} or not isinstance(scope, str))):
+            raise SpecError(f'{path}: "cells" is one of {", ".join(CELLS)}, or {{"of": one of them, "scope": a scope\'s name}}')
+        return cell.traffic["kind"] in CELLS[of] and (scope is None or bool(has_scope(cell, scope)))
+
+    def list_faults(self, has_scope: Callable[[Cell, str], bool]) -> List[str]:
+        """Where a per-layer metric's ``workloads`` differs from what its
+        file's ``"cells"`` says, for whatever cells the document has."""
+        bad = []
+        cells = [self.cell(w["name"]) for w in self.doc["workloads"]]
+        for m in self.doc["per_layer"]:
+            for cell in cells:
+                want = self.belongs(m["name"], cell, has_scope)
+                if want is not None and want != self._applies(m, cell.name):
+                    bad.append(f"{m['name']}: {cell.name} is {'not ' if want else ''}on its list, against its file's \"cells\"")
+        return bad
 
     def peak(self, device_kind: str) -> Dict[str, Any]:
         """Peaks of one chip; a kind the table does not hold is an error."""

@@ -16,21 +16,18 @@ for p in (BENCH, REPO):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY_CONFIG = {
-    "source": "none: a throw-away configuration of a test",
-    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "num_hidden_layers": 2, "head_dim": 16,
-    "rms_norm_eps": 1e-5, "deviations": {"rms_norm_eps": {"as_run": 1e-6, "why": "the program's"}}, "rope_theta": 10000.0, "tie_word_embeddings": False,
-    "vocab_size": 256,
-    "dtypes": {"params": "float32", "optimizer_state": "float32", "compute": "float32",
-               "serve_params": "float32", "kv_cache": "float32"},
-    "train_batch": {"global_batch_sequences": 2, "why": "test"},
-    "tolerance": {
-        "serve_logits": {"sequence_tokens": 32, "rel_rms": 1e-3, "max_abs": 1e-3, "why": "float32 both sides"},
-        "train_step": {"sequence_tokens": 32, "loss_rel": 1e-4, "logits_rel_rms": 1e-3, "grad_rel": 1e-2,
-                       "moment2_rel": 1e-2, "update_rel": 1e-2, "why": "float32 both sides"},
-    },
-}
+
+
+def tiny_form(arch: str) -> dict:
+    """An architecture at a tiny size (``tiny/<arch>.json``: ``config``, and
+    for one that is served its ``serve_engine``): what the tests run on the
+    CPU and what decides, by a CPU lowering, which scopes its programs have.
+    A PR that brings an architecture brings its file."""
+    with open(os.path.join(HERE, "tiny", arch + ".json")) as f:
+        return json.load(f)
+
+
+TINY_CONFIG = tiny_form("dense_decoder")["config"]
 ENGINE = {"block_size": 4, "num_blocks": 128, "max_batch": 4, "decode_chunk_blocks": 1,
           "prefix_cache": True, "queue_depth": 32}
 TINY_TRAFFIC = {
@@ -101,17 +98,24 @@ def renamed_adapter() -> str:
     return text
 
 
+def copy_of_the_benchmark(tmp: str) -> str:
+    """BENCHMARK.json and the data files as they are, under another root."""
+    os.makedirs(os.path.join(tmp, "benchmark"))
+    for d in ("configs", "traffic", "metrics", "readers", "archs", "reference", "costs"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(tmp, "benchmark", d))
+    shutil.copy(os.path.join(BENCH, "peaks.json"), os.path.join(tmp, "benchmark"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp)
+    return tmp
+
+
 def throwaway_root(tmp: str) -> str:
     """Copy BENCHMARK.json and the data files, then add to them."""
-    os.makedirs(os.path.join(tmp, "benchmark"))
-    for d in ("configs", "traffic", "metrics", "readers", "archs", "reference"):
-        shutil.copytree(os.path.join(BENCH, d), os.path.join(tmp, "benchmark", d))
+    copy_of_the_benchmark(tmp)
     shutil.copytree(os.path.join(HERE, "files"), os.path.join(tmp, "benchmark"), dirs_exist_ok=True)
     shutil.copy(os.path.join(BENCH, "reference", "dense_decoder.py"),
                 os.path.join(tmp, "benchmark", "reference", "dense_renamed.py"))
     with open(os.path.join(tmp, "benchmark", "archs", "dense_renamed.py"), "w") as f:
         f.write(renamed_adapter())
-    shutil.copy(os.path.join(BENCH, "peaks.json"), os.path.join(tmp, "benchmark"))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         doc = json.load(f)
 
@@ -162,7 +166,7 @@ def throwaway_root(tmp: str) -> str:
             continue
         if m["name"].startswith("train_") and "collective" not in m["name"]:
             m["workloads"] += train
-        elif m["name"] in ("tpot_p50_ms", "serve_decode_step_ms", "serve_sample_ms"):
+        elif m["name"] in ("tpot_p50_ms", "serve_step_ms", "serve_step_sample_ms"):
             m["workloads"] += serve
         elif m["name"] in ("serve_tokens_per_s", "serve_lane_occupancy", "serve_kv_pool_live"):
             m["workloads"] += closed

@@ -22,10 +22,14 @@ def doc():
         return json.load(f)
 
 
-def test_benchmark_json_keeps_the_contracts_rules(doc):
-    assert S.check_document(doc) == []
-    assert os.path.getsize(os.path.join(B.REPO, "BENCHMARK.json")) < 64 * 1024
-    assert doc["command"] == ["python3", "benchmark/run.py"]
+def benchmark_json_keeps_the_contracts_rules(spec):
+    assert S.check_document(spec.doc) == []
+    assert os.path.getsize(os.path.join(spec.root, "BENCHMARK.json")) < 64 * 1024
+    assert spec.doc["command"] == ["python3", "benchmark/run.py"]
+
+
+def test_benchmark_json_keeps_the_contracts_rules():
+    benchmark_json_keeps_the_contracts_rules(S.Spec())
 
 
 def test_the_checker_catches_what_the_contract_forbids(doc):
@@ -37,9 +41,8 @@ def test_the_checker_catches_what_the_contract_forbids(doc):
     assert "unit" in faults and "valid name" in faults and "bound" in faults
 
 
-def test_every_cell_resolves_to_its_files(doc):
-    spec = S.Spec()
-    for w in doc["workloads"]:
+def every_cell_resolves_to_its_files(spec):
+    for w in spec.doc["workloads"]:
         cell = spec.cell(w["name"])
         assert cell.traffic["kind"] in ("train", "serve-open", "serve-closed")
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
@@ -59,6 +62,15 @@ def test_every_cell_resolves_to_its_files(doc):
     assert spec.peak("TPU v5 lite")["bf16_flops_per_s"] == 197e12
 
 
+#: what this file asserts of the DOCUMENT: each takes a ``Spec``, so that
+#: test_bench_rules.py can hold a document with one more cell to all of them
+DOCUMENT_CHECKS = [benchmark_json_keeps_the_contracts_rules, every_cell_resolves_to_its_files]
+
+
+def test_every_cell_resolves_to_its_files():
+    every_cell_resolves_to_its_files(S.Spec())
+
+
 def test_flops_per_token_match_the_issues_arithmetic():
     spec = S.Spec()
     cell = spec.cell("train-mistral7b-l2-seq4k")
@@ -74,15 +86,15 @@ def tiny_root(tmp_path_factory):
     return B.throwaway_root(str(tmp_path_factory.mktemp("bench_root")))
 
 
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 @pytest.mark.parametrize("workload,traced,expect", [
     ("tiny.train", False, {"train_tokens_per_s", "setup_s"}),
     ("tiny.closed", False, {"serve_tokens_per_s", "tpot_p50_ms", "setup_s"}),
     ("tiny.open", False, {"tpot_p50_ms", "setup_s"}),
-    ("tiny.open", True, {"ttft_p90_ms", "ttft_p50_ms", "serve_prefill_share", "serve_decode_step_ms"}),
-    ("tiny.closed", True, {"serve_decode_step_ms", "serve_sample_ms", "serve_lane_occupancy", "tiny_decode_calls_ms", "tiny_decode_steps"}),
+    ("tiny.open", True, {"ttft_p90_ms", "ttft_p50_ms", "serve_prefill_share", "serve_step_ms"}),
+    ("tiny.closed", True, {"serve_step_ms", "serve_step_sample_ms", "serve_lane_occupancy", "tiny_decode_calls_ms", "tiny_decode_steps"}),
     ("tiny.train", True, {"train_data_wait_share", "train_mfu"}),
     # an architecture with experts, brought as an adapter, a reference and a configuration
     ("tiny-moe.train", False, {"train_tokens_per_s", "setup_s"}),
@@ -96,6 +108,9 @@ LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 def test_a_cell_added_by_files_alone_runs_and_prints_the_contracts_line(tiny_root, capsys, workload, traced, expect):
     line = harness.run_cell(workload, seed=2**31 + 5, seconds=1.5, traced=traced, root=tiny_root, require_tpu=False)
     assert set(line) == LINE_KEYS  # no device trace on a CPU, so no breakdown
+    # each number the verdict rests on beside its limit, under a key that comes last
+    assert list(line)[-1] == "compared" and "compiles_in_window" in line["compared"]
+    assert all(len(pair) == 2 and pair[0] <= pair[1] for pair in line["compared"].values())
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) >= expect
     # device metrics have nothing to read on a CPU and are left out

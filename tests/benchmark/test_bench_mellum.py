@@ -21,25 +21,7 @@ CELL = "train-mellum2-l4-ep4-seq8k"
 
 # 2 sliding layers to 1 full, a window shorter than the sequence, 8 experts
 # of which 4 are held from expert 2, top-3, head_dim != hidden / heads
-TINY_MELLUM = {
-    "source": "none: a throw-away configuration of a test", "arch": "mellum_moe",
-    "attention_bias": False, "head_dim": 24, "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 128,
-    "layer_types": ["sliding_attention", "sliding_attention", "full_attention"],
-    "mlp_layer_types": ["sparse"] * 3, "moe_intermediate_size": 32, "norm_topk_prob": True,
-    "num_attention_heads": 4, "num_experts": 4, "num_experts_published": 8, "first_expert_held": 2,
-    "num_experts_per_tok": 3, "num_hidden_layers": 3, "num_key_value_heads": 2, "rms_norm_eps": 1e-6,
-    "rope_parameters": {
-        "full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
-                           "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
-                           "attention_factor": 1.2772588722239782},
-        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
-    },
-    "sliding_window": 12, "tie_word_embeddings": False, "vocab_size": 256,
-    "assumed": {"moe_aux_weight": {"value": 0.001, "why": "test"}},
-    "dtypes": B.TINY_CONFIG["dtypes"], "train_batch": {"global_batch_sequences": 2, "why": "test"},
-    "tolerance": {"train_step": {"sequence_tokens": 32, "loss_rel": 1e-4, "logits_rel_rms": 1e-3, "grad_rel": 1e-2,
-                                 "moment2_rel": 1e-2, "update_rel": 1e-2, "why": "float32 both sides"}},
-}
+TINY_MELLUM = B.tiny_form("mellum_moe")["config"]
 #: an adapter of the test's own, whose reference is told something else than the configuration states
 TOLD_OTHERWISE = '''
 from benchlib import model
@@ -103,10 +85,11 @@ def cell():
 # ---------------------------------------------------------------------------
 
 
-def test_the_configuration_keeps_every_published_width_and_states_its_cut(cell):
+def the_configuration_keeps_every_published_width_and_states_its_cut(spec):
+    cell = spec.cell(CELL)
     with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
         published = next(e for e in map(json.loads, f) if e["name"] == "Mellum2-12B-A2.5B-Instruct")
-    doc = S.Spec().doc
+    doc = spec.doc
     entry = next(c for c in doc["configs"] if c["name"] == "mellum2-12b-a2.5b-l4-ep4")
     assert entry["source"] == published["source_url"] == cell.config["source"]
     assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"] == list(cell.config["reduced"])
@@ -125,6 +108,15 @@ def test_the_configuration_keeps_every_published_width_and_states_its_cut(cell):
     with open(os.path.join(B.BENCH, "traffic", "train-seq4k.json")) as f:
         seq4k = json.load(f)
     assert {k: v for k, v in cell.traffic.items() if k not in ("seq_len", "why")} == {k: v for k, v in seq4k.items() if k not in ("seq_len", "why")}
+
+
+#: what this file asserts of the DOCUMENT: each takes a ``Spec``, so that
+#: test_bench_rules.py can hold a document with one more cell to all of them
+DOCUMENT_CHECKS = [the_configuration_keeps_every_published_width_and_states_its_cut]
+
+
+def test_the_configuration_keeps_every_published_width_and_states_its_cut():
+    the_configuration_keeps_every_published_width_and_states_its_cut(S.Spec())
 
 
 def test_parameter_counts_match_the_issues_arithmetic(cell):

@@ -1,6 +1,7 @@
 """The step by scope (PR 36): the reader ``named_device_share`` on a small
 synthetic trace and hand-made observations, and the thirteen metric files it
-came with: each resolves to its reader and lists exactly its cells."""
+came with: each resolves to its reader in every cell that lists it (which
+cells those are is each metric file's to say: ``"cells"``, ``test_bench_rules.py``)."""
 
 import dataclasses
 
@@ -11,28 +12,25 @@ from benchlib import model, readers, spec as S
 from benchlib.observe import Observations
 
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-TRAIN = ["train-mistral7b-l2-seq4k", "train-mellum2-l4-ep4-seq8k"]
-INTERNLM2 = ["serve-internlm2-decode", "serve-internlm2-chat"]
-SERVING = INTERNLM2 + ["serve-dsv3-l5-ep16-reason"]
 DECODE = {"span": "serve.decode", "program": "jit.compile.serve.decode"}
 
-#: metric -> (reader, the arguments that matter, the cells that list it)
+#: metric -> (reader, the arguments that matter)
 METRICS = {
-    "train_attn_device_share": ("scope_device_share", {"scopes": ["attn.qkv", "attn.window", "attn.full", "attn.out"]}, TRAIN),
-    "train_mlp_device_share": ("scope_device_share", {"scopes": ["mlp.dense"]}, TRAIN[:1]),
-    "train_norm_device_share": ("scope_device_share", {"scopes": ["block.norm"]}, TRAIN),
-    "train_loss_device_share": ("scope_device_share", {"scopes": ["lm.embed", "loss.ce"]}, TRAIN),
-    "train_optim_device_share": ("scope_device_share", {"scopes": ["optim.clip", "optim.update"]}, TRAIN),
-    "moe_experts_device_share": ("scope_device_share", {"scopes": ["moe.experts", "moe.shared"]}, TRAIN[1:]),
+    "train_attn_device_share": ("scope_device_share", {"scopes": ["attn.qkv", "attn.window", "attn.full", "attn.out"]}),
+    "train_mlp_device_share": ("scope_device_share", {"scopes": ["mlp.dense"]}),
+    "train_norm_device_share": ("scope_device_share", {"scopes": ["block.norm"]}),
+    "train_loss_device_share": ("scope_device_share", {"scopes": ["lm.embed", "loss.ce"]}),
+    "train_optim_device_share": ("scope_device_share", {"scopes": ["optim.clip", "optim.update"]}),
+    "moe_experts_device_share": ("scope_device_share", {"scopes": ["moe.experts", "moe.shared"]}),
     "serve_attn_device_share": (
-        "decode_step_ops", {**DECODE, "scopes": ["serve.attn.qkv", "serve.kv.write", "serve.attn.attend", "serve.attn.out"]}, INTERNLM2,
+        "decode_step_ops", {**DECODE, "scopes": ["serve.attn.qkv", "serve.kv.write", "serve.attn.attend", "serve.attn.out"]},
     ),
-    "serve_mlp_device_share": ("decode_step_ops", {**DECODE, "scopes": ["serve.mlp"]}, INTERNLM2),
-    "serve_vocab_device_share": ("decode_step_ops", {**DECODE, "scopes": ["serve.embed", "serve.head"]}, SERVING),
-    "train_named_device_share": ("named_device_share", {"also": "^%tpu_custom_call"}, TRAIN),
-    "serve_decode_named_device_share": ("named_device_share", {**DECODE, "also": "^%tpu_custom_call"}, SERVING),
-    "train_mixed_fusion_device_share": ("named_device_share", {"count": "mixed"}, TRAIN),
-    "serve_decode_mixed_fusion_device_share": ("named_device_share", {**DECODE, "count": "mixed"}, SERVING),
+    "serve_mlp_device_share": ("decode_step_ops", {**DECODE, "scopes": ["serve.mlp"]}),
+    "serve_vocab_device_share": ("decode_step_ops", {**DECODE, "scopes": ["serve.embed", "serve.head"]}),
+    "train_named_device_share": ("named_device_share", {"also": "^%tpu_custom_call"}),
+    "serve_decode_named_device_share": ("named_device_share", {**DECODE, "also": "^%tpu_custom_call"}),
+    "train_mixed_fusion_device_share": ("named_device_share", {"count": "mixed"}),
+    "serve_decode_mixed_fusion_device_share": ("named_device_share", {**DECODE, "count": "mixed"}),
 }
 
 
@@ -42,20 +40,19 @@ def spec():
 
 
 @pytest.mark.parametrize("name", list(METRICS))
-def test_a_metric_file_resolves_to_its_reader_and_lists_exactly_its_cells(spec, name):
-    reader, args, cells = METRICS[name]
+def test_a_metric_file_resolves_to_its_reader_in_every_cell_that_lists_it(spec, name):
+    reader, args = METRICS[name]
     entry = next(m for m in spec.doc["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == cells and entry["source"] == "device_trace" and entry["unit"] == "%"
-    moves = "train_tokens_per_s" if cells[0].startswith("train") else "tpot_p50_ms"
-    assert entry["moves"] == moves
-    for cell_name in cells:
+    assert entry["workloads"] and entry["source"] == "device_trace" and entry["unit"] == "%"
+    # a reader of the decode step names the decode program; the others read the training step
+    assert entry["moves"] == ("tpot_p50_ms" if "program" in args else "train_tokens_per_s")
+    for cell_name in entry["workloads"]:
         cell = spec.cell(cell_name)
+        assert cell.traffic["kind"].startswith("serve-") == ("program" in args)
         metric = next(m for m in cell.per_layer if m["name"] == name)
         assert metric["reader"]["reader"] == reader and metric["reader"]["args"] == args
         assert callable(readers.find(metric, cell.data_dir))
         readers.check(metric, cell.data_dir)
-    listed = [c.name for c in map(spec.cell, (w["name"] for w in spec.doc["workloads"])) if any(m["name"] == name for m in c.per_layer)]
-    assert listed == cells
 
 
 # ---------------------------------------------------------------------------
@@ -165,11 +162,11 @@ def test_the_named_share_of_a_whole_trace_reads_every_programs_instant(spec):
     assert _read(cell, "train_mlp_device_share", _obs(cell, [_instant("jit.compile.train", {"mlp.dense": ["fusion.7"]})])) == pytest.approx(100.0 * 11 / 32)
 
 
-@pytest.mark.parametrize("name", [n for n, (reader, _, _) in METRICS.items()])
+@pytest.mark.parametrize("name", list(METRICS))
 def test_nothing_is_read_where_there_is_no_trace(spec, name):
     """Which is also what keeps the CPU contract's "no metric with `device` in
     its name" true: a run that traced nothing reports none of the thirteen."""
-    cell = spec.cell(METRICS[name][2][0])
+    cell = spec.cell(next(m for m in spec.doc["per_layer"] if m["name"] == name)["workloads"][0])
     events = [_instant("jit.compile.train", SCOPES, MIXED)] + EVENTS
     assert _read(cell, name, dataclasses.replace(_obs(cell, events), profiler=None)) is None
     assert _read(cell, name, _obs(cell, [])) is None

@@ -116,10 +116,10 @@ def test_reference_learning_rate_is_optaxs_warmup_cosine(count):
 
 def test_idle_gaps_are_laid_against_host_spans():
     t = tr.TraceData(devices={"d": [("a", 0.0, 100.0), ("b", 100_100.0, 100.0), ("c", 100_210.0, 100.0)]}, host=[])
-    spans = [("bench.serve.sample", 10_000.0, 60_000.0), ("bench.serve.decode_step", 0.0, 80_000.0)]
+    spans = [("serve.sample", 10_000.0, 60_000.0), ("serve.step", 0.0, 80_000.0)]
     gaps = dict(tr.idle_gaps_by_host_span(t, spans))
-    assert gaps["bench.serve.sample"] * 1e9 == pytest.approx(60_000.0)
-    assert gaps["bench.serve.decode_step"] * 1e9 == pytest.approx(19_900.0)  # the rest of the step
+    assert gaps["serve.sample"] * 1e9 == pytest.approx(60_000.0)
+    assert gaps["serve.step"] * 1e9 == pytest.approx(19_900.0)  # the rest of the step
     assert gaps["host_outside_any_span"] * 1e9 == pytest.approx(20_100.0)
     assert gaps["between_ops_20_us"] * 1e9 == pytest.approx(10.0)
 
