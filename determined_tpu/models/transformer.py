@@ -10,7 +10,9 @@ MeshConfig alone, with:
 - activation sharding constraints (batch over dp/fsdp, seq over sp);
 - rotary position embeddings (one base, or parameters per layer type with
   YaRN), GQA with a stated ``head_dim``, RMSNorm, SwiGLU;
-- a type per layer: full causal attention or a sliding window;
+- a type per layer: full causal attention, a sliding window, or power
+  retention (``ops/retention.py``: gated attention of degree 2, whose serving
+  cache is a fixed-size state a lane and not keys and values a token);
 - experts: the top-2 capacity layer or dropless top-k over the experts a
   device holds (models/moe.py);
 - attention dispatch: ring attention when the mesh has a "seq" axis,
@@ -42,6 +44,12 @@ from determined_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_latent_attention,
 )
+from determined_tpu.ops.retention import (
+    retention_chunk,
+    retention_decode,
+    retention_quadratic,
+    state_shapes,
+)
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
 from determined_tpu.parallel.sharding import with_sharding_constraint
@@ -49,8 +57,8 @@ from determined_tpu.train._trial import JaxTrial
 
 
 #: the kinds of layer, under the names published configurations give them
-FULL, SLIDING = "full_attention", "sliding_attention"
-LAYER_TYPES = (FULL, SLIDING)
+FULL, SLIDING, RETENTION = "full_attention", "sliding_attention", "power_retention"
+LAYER_TYPES = (FULL, SLIDING, RETENTION)
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
@@ -71,8 +79,15 @@ class TransformerConfig:
     head_dim: Optional[int] = None
     # one of LAYER_TYPES per block; None -> full causal attention everywhere.
     # A sliding layer's query i sees keys i - sliding_window < j <= i.
+    # A power_retention layer keeps GQA's projections and adds a gate a KV
+    # head (``wg``, no bias): its decay is sigmoid(gate + retention_gate_bias),
+    # the bias a constant and no leaf (0: a fresh gate forgets half a step).
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: Optional[int] = None
+    retention_gate_bias: float = 0.0
+    # RMSNorm over each head of q and k before rotary, one learned weight of
+    # head_dim each shared by the heads (Qwen3's); power_retention layers run it
+    qk_norm: bool = False
     # rotary parameters per layer type (the published `rope_parameters`
     # group): {"full_attention": {"rope_type": "yarn", "rope_theta": ...,
     # "factor": ..., ...}, "sliding_attention": {"rope_type": "default",
@@ -176,6 +191,16 @@ class TransformerConfig:
                 )
             if SLIDING in self.layer_types and not (self.sliding_window or 0) >= 1:
                 raise ValueError("a sliding_attention layer needs sliding_window >= 1")
+        if self.retention_layers and (
+            self.kv_lora_rank is not None or self.parallel_block or self.head_dim % 2 or self.n_heads % self.kv_heads
+            or self.seq_axis_name is not None
+        ):
+            raise ValueError(
+                "a power_retention layer runs in a sequential block, without latent attention or a `seq` axis, "
+                "on an even head_dim and whole groups of query heads a KV head"
+            )
+        if self.qk_norm and len(self.retention_layers) != self.n_layers:
+            raise ValueError("qk_norm runs in power_retention layers only: every layer must be one")
         if isinstance(self.rope_parameters, Mapping):
             # hashable, so that the config can stay a static argument
             setattr_(
@@ -273,9 +298,21 @@ class TransformerConfig:
         values in a store of its own (``init_kv_cache``)."""
         return tuple(i for i in range(self.n_layers) if self.layer_type(i) == SLIDING)
 
+    @property
+    def retention_layers(self) -> Tuple[int, ...]:
+        """The power-retention layers, in order: serving keeps a state a decode
+        lane for each (``init_kv_cache``), and no token's keys or values."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) == RETENTION)
+
+    @property
+    def paged_layers(self) -> int:
+        """How many layers keep a token's rows in the paged pool."""
+        return self.n_layers - len(self.window_layers) - len(self.retention_layers)
+
     def cache_index(self, i: int) -> int:
         """Layer ``i``'s place among the layers of its own type: its index in
-        the paged pool (full layers) or in the window store (sliding layers)."""
+        the paged pool (full layers), in the window store (sliding layers) or
+        in the state pool (retention layers)."""
         return sum(1 for j in range(i) if self.layer_type(j) == self.layer_type(i))
 
     def rope(self, layer_type: str) -> Optional["Rope"]:
@@ -483,6 +520,50 @@ class Attention(nn.Module):
             )(out)
 
 
+def _gate_log(cfg: TransformerConfig, gate: jax.Array) -> jax.Array:
+    """The logarithm of a retention layer's decay, float32, from the gate's
+    projection ``[..., kv_heads]``."""
+    return jax.nn.log_sigmoid(gate.astype(jnp.float32) + cfg.retention_gate_bias)
+
+
+class Retention(nn.Module):
+    """A power-retention layer over the whole sequence, in its quadratic form
+    (``ops/retention.py retention_quadratic``): GQA's projections, RMSNorm a
+    head on q and k (``qk_norm``), rotary, a gate a KV head.  What ``init``
+    builds for serving, the wide oracle of the serving forward below (which
+    reads the same leaves and runs the recurrent form), and training."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        hd = cfg.head_dim
+        from determined_tpu.train._quant import make_dot_general
+
+        qdg = make_dot_general(cfg.quantized_matmul)
+        dense = lambda feats, logical, name, axis=-1: nn.DenseGeneral(  # noqa: E731
+            feats, axis=axis, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, dot_general=qdg,
+            kernel_init=_maybe_partition(cfg.partition_params, nn.initializers.lecun_normal(), logical), name=name,
+        )
+        with jax.named_scope("attn.qkv"):
+            q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "wq")(x)
+            k = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wk")(x)
+            v = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wv")(x)
+            log_g = _gate_log(cfg, dense(cfg.kv_heads, ("embed", "kv"), "wg")(x)).transpose(0, 2, 1)  # [b, kv, s]
+            if cfg.qk_norm:
+                ones = _maybe_partition(cfg.partition_params, nn.initializers.ones, ("head_dim",))
+                q = _rms(q, self.param("q_norm", ones, (hd,), cfg.param_dtype), cfg.norm_eps)
+                k = _rms(k, self.param("k_norm", ones, (hd,), cfg.param_dtype), cfg.norm_eps)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            positions, rope = jnp.arange(x.shape[1]), cfg.rope(RETENTION)
+            q, k = _rope(q, positions, rope), _rope(k, positions, rope)
+        with jax.named_scope("attn.retention"):
+            out = retention_quadratic(q, k, v, log_g)
+        with jax.named_scope("attn.out"):
+            return dense(cfg.d_model, ("heads", "head_dim", "embed"), "wo", (-2, -1))(out.transpose(0, 2, 1, 3))
+
+
 def _latent_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
     """Latent attention's leaves: name -> (shape, logical axes, initialiser)."""
     d, h = cfg.d_model, cfg.n_heads
@@ -604,6 +685,8 @@ class Block(nn.Module):
         h = norm("ln1")(x)
         if cfg.latent:
             att = LatentAttention(cfg, name="attn")(h)
+        elif self.layer_type == RETENTION:
+            att = Retention(cfg, name="attn")(h)
         else:
             att = Attention(cfg, self.mesh, self.layer_type, name="attn")(h)
         if cfg.parallel_block:
@@ -900,7 +983,7 @@ def kv_cache_shape(cfg: TransformerConfig, num_blocks: int, block_size: int) -> 
     """One pool array's shape: K (and V) rows of a GQA model's full-attention
     layers (every layer, where none slides), or the latent rows."""
     width = latent_row_width(cfg) if cfg.latent else cfg.kv_heads * cfg.head_dim
-    return (cfg.n_layers - len(cfg.window_layers), num_blocks, block_size, width)
+    return (cfg.paged_layers, num_blocks, block_size, width)
 
 
 def window_ring_blocks(cfg: TransformerConfig, block_size: int, chunk_tokens: int) -> int:
@@ -921,11 +1004,31 @@ def window_store_shape(cfg: TransformerConfig, lanes: int, block_size: int, chun
 
 
 def kv_bytes_per_token(cfg: TransformerConfig) -> int:
-    """Bytes of cache a token owns over all layers, as attention reads them
-    (a latent row's padding is not counted; in a window layer a token owns
-    them only while it is inside the window)."""
+    """Bytes of cache a token owns over all layers that cache tokens, as
+    attention reads them (a latent row's padding is not counted; in a window
+    layer a token owns them only while it is inside the window; a retention
+    layer caches no token: ``state_bytes_per_slot``)."""
     values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
-    return cfg.n_layers * values * jnp.dtype(cfg.dtype).itemsize
+    return (cfg.n_layers - len(cfg.retention_layers)) * values * jnp.dtype(cfg.dtype).itemsize
+
+
+#: the dtype of a retention layer's state and normaliser: sums over a whole context
+STATE_DTYPE = jnp.float32
+
+
+def state_pool_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The retention layers' state pool and its normaliser (``ops/retention.py
+    state_shapes``): a slot a decode lane a layer."""
+    return state_shapes(len(cfg.retention_layers), lanes, cfg.kv_heads, cfg.head_dim)
+
+
+def state_bytes_per_slot(cfg: TransformerConfig) -> int:
+    """Bytes of ONE retention layer's state and normaliser in one lane: what a
+    request holds of such a layer whatever its length (0 without such layers)."""
+    if not cfg.retention_layers:
+        return 0
+    state, norm = state_pool_shapes(cfg, 1)
+    return (math.prod(state[1:]) + math.prod(norm[1:])) * jnp.dtype(STATE_DTYPE).itemsize
 
 
 def init_kv_cache(
@@ -939,11 +1042,19 @@ def init_kv_cache(
     cache of two kinds: the pool keeps its full layers alone, addressed by block
     table, and ``wk`` / ``wv`` are the window layers' store
     (:func:`window_store_shape`: a ring a lane, for ``lanes`` decode lanes and
-    prefill chunks of ``chunk_tokens``), addressed by lane and position."""
+    prefill chunks of ``chunk_tokens``), addressed by lane and position.
+    Power-retention layers make a third kind: ``rs`` / ``rz``, a float32 state
+    and its normaliser a lane a layer (:func:`state_pool_shapes`), addressed
+    by lane alone.  A model none of whose layers reads the pool gets none."""
     shape = kv_cache_shape(cfg, num_blocks, block_size)
     if cfg.latent:
         return {"kv": jnp.zeros(shape, cfg.dtype)}
-    cache = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    cache = {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)} if cfg.paged_layers else {}
+    if cfg.retention_layers:
+        if lanes is None:
+            raise ValueError("a model with power-retention layers needs its lanes to size the state pool")
+        state, norm = state_pool_shapes(cfg, lanes)
+        cache.update(rs=jnp.zeros(state, STATE_DTYPE), rz=jnp.zeros(norm, STATE_DTYPE))
     if cfg.window_layers:
         if lanes is None or chunk_tokens is None or chunk_tokens % block_size:
             raise ValueError(
@@ -955,8 +1066,10 @@ def init_kv_cache(
     return cache
 
 
-def _block_size(cache: Dict[str, jax.Array]) -> int:
-    return next(iter(cache.values())).shape[2]
+def _block_size(cache: Dict[str, jax.Array]) -> Optional[int]:
+    """Tokens a block of the paged pool; None where the cache has no pool."""
+    pool = cache.get("kv", cache.get("k"))
+    return None if pool is None else pool.shape[2]
 
 
 def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -980,6 +1093,17 @@ def _attn_proj(p: Dict[str, Any], x: jax.Array, dtype: Any) -> Tuple[jax.Array, 
     k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"]["kernel"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bhsk", x, p["wv"]["kernel"].astype(dtype))
     return q, k, v
+
+
+def _retention_proj(cfg: TransformerConfig, p: Dict[str, Any], h: jax.Array, positions: jax.Array):
+    """A retention layer's q, k, v ``[b, heads, s, d]`` as ``Retention`` makes
+    them (a norm a head, rotary) and the gate's logarithm ``[b, kv_heads, s]``."""
+    q, k, v = _attn_proj(p, h, cfg.dtype)
+    log_g = _gate_log(cfg, h @ p["wg"]["kernel"].astype(cfg.dtype)).transpose(0, 2, 1)
+    if cfg.qk_norm:
+        q, k = _rms(q, p["q_norm"], cfg.norm_eps), _rms(k, p["k_norm"], cfg.norm_eps)
+    rope = cfg.rope(RETENTION)
+    return _rope(q, positions, rope), _rope(k, positions, rope), v, log_g
 
 
 def _mlp_apply(p: Dict[str, Any], x: jax.Array, dtype: Any) -> jax.Array:
@@ -1124,6 +1248,42 @@ def _attend_ring_table(cfg: TransformerConfig, positions: jax.Array):
     return attend
 
 
+# A retention layer's backends: ``retain(q, k, v, log_g, cache, j)`` with q [b,
+# n_heads, s, head_dim], k, v [b, kv_heads, s, head_dim], the gate's logarithm
+# [b, kv_heads, s] and ``j`` the layer's place in the state pool; returns ([b,
+# n_heads, s, head_dim], the cache with the lanes' slots updated).  It is write
+# and attend in one: the state is both.
+
+
+def _retain_chunk(cfg: TransformerConfig, lanes: jax.Array, valid: jax.Array, fresh):
+    """``s`` tokens a row after what the slots of ``lanes`` [b] hold (nothing,
+    under ``fresh``: a sequence starts from a zeroed slot), and into them:
+    the prefill walk's chunk, and the wide prefill as one chunk."""
+
+    def retain(q, k, v, log_g, cache, j):
+        state, norm = cache["rs"][j, lanes], cache["rz"][j, lanes]
+        state, norm = jnp.where(fresh, 0.0, state), jnp.where(fresh, 0.0, norm)
+        out, state, norm = retention_chunk(q, k, v, log_g, state, norm, valid)
+        return out.astype(cfg.dtype), {**cache, "rs": cache["rs"].at[j, lanes].set(state), "rz": cache["rz"].at[j, lanes].set(norm)}
+
+    return retain
+
+
+def _retain_decode(cfg: TransformerConfig, live: jax.Array, impl: Optional[str] = None):
+    """One token a lane, row ``b`` of the batch IS lane ``b``: the slot is
+    decayed, takes the token and answers it (``ops/retention.py
+    retention_decode``: the Pallas kernel on a TPU, in place); a lane that is
+    not ``live`` [b] leaves its slot alone."""
+
+    def retain(q, k, v, log_g, cache, j):
+        out, state, norm = retention_decode(
+            q[:, :, 0], k[:, :, 0], v[:, :, 0], log_g[:, :, 0], cache["rs"], cache["rz"], j, live, impl=impl
+        )
+        return out.astype(cfg.dtype)[:, :, None, :], {**cache, "rs": state, "rz": norm}
+
+    return retain
+
+
 # Latent attention's backends: ``attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i)``
 # with q_nope [b, n_heads, s, qk_nope], q_rope [b, n_heads, s, qk_rope] (after
 # rope), this call's own latent rows c_kv [b, s, kv_lora] (after their norm) and
@@ -1248,14 +1408,21 @@ SERVE_COUNTERS = ("serve.moe.held_picks", "serve.moe.experts_hit")
 #: tokens its attention reads, summed over the lanes, in the full layers (the
 #: context a layer) and in the window layers (the context or the window a layer)
 SERVE_KV_COUNTERS = ("serve.kv.full_tokens", "serve.kv.window_tokens")
+#: what a decode step of a model with retention layers counts first: the lanes
+#: whose state it updated, and the bytes of state those hold over the retention
+#: layers (lanes x layers x ``state_bytes_per_slot``): what the step had to read
+SERVE_STATE_COUNTERS = ("serve.state.live_lanes", "serve.state.bytes")
 
 
 def serve_counters(cfg: TransformerConfig) -> Tuple[str, ...]:
     """The names of what ``transformer_decode(counters=True)`` counts, in the row's order."""
-    return (SERVE_KV_COUNTERS if cfg.window_layers else ()) + (SERVE_COUNTERS if cfg.moe_experts else ())
+    return (
+        (SERVE_STATE_COUNTERS if cfg.retention_layers else ()) + (SERVE_KV_COUNTERS if cfg.window_layers else ())
+        + (SERVE_COUNTERS if cfg.moe_experts else ())
+    )
 
 
-def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sliding=None):
+def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sliding=None, retain=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
     points below: norm, the attention's projections (q/k/v, or latent
     attention's), rope at ``positions`` ([s], or [b, s]), this call's rows into
@@ -1267,11 +1434,15 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sli
     A sliding-window layer writes and attends through ``sliding`` = (write,
     attend) instead, into the window store: its blocks are a lane's ring, and
     a block id past the store's end drops the row (idle lanes, padding).
+    A power-retention layer writes no row and attends to none: ``retain``
+    updates its lanes' slots of the state pool and answers from them (``write``
+    may be None for a model of such layers alone).
     Returns (x, cache, what an expert layer counted or None)."""
     dt = cfg.dtype
     rope = cfg.rope(cfg.layer_type(i))
-    slides = cfg.layer_type(i) == SLIDING
-    phys, slots = sliding[0] if slides else write
+    slides, retains = cfg.layer_type(i) == SLIDING, cfg.layer_type(i) == RETENTION
+    if not retains:
+        phys, slots = sliding[0] if slides else write
     j = cfg.cache_index(i)
     h = _norm_apply(cfg, x, blk["ln1"]["scale"])
     if cfg.latent:
@@ -1284,6 +1455,13 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sli
                 cache = {"kv": cache["kv"].at[i, phys, slots].set(row.reshape(*phys.shape, -1))}
             att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, i)
             x = x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(dt))
+    elif retains:
+        with jax.named_scope("serve.retention.qkvg"):
+            q, k, v, log_g = _retention_proj(cfg, blk["attn"], h, positions)
+        with jax.named_scope("serve.retention.state"):  # decay, update, query, normalise
+            att, cache = retain(q, k, v, log_g, cache, j)
+        with jax.named_scope("serve.retention.out"):
+            x = x + jnp.einsum("bshk,hkD->bsD", att.transpose(0, 2, 1, 3), blk["attn"]["wo"]["kernel"].astype(dt))
     else:
         with jax.named_scope("serve.attn.qkv"):
             q, k, v = _attn_proj(blk["attn"], h, dt)
@@ -1321,12 +1499,12 @@ def _serve_layer(cfg, i, blk, x, positions, write, attend, cache, live=None, sli
     return x + y, cache, counted
 
 
-def _serve_layers(cfg, params, x, positions, write, attend, cache, live=None, sliding=None):
+def _serve_layers(cfg, params, x, positions, write, attend, cache, live=None, sliding=None, retain=None):
     """Every layer; the last value is SERVE_COUNTERS' sums over the expert
     layers, [2] float32, or None for a model without them."""
     counted = []
     for i in range(cfg.n_layers):
-        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache, live, sliding)
+        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, positions, write, attend, cache, live, sliding, retain)
         if c is not None:
             counted.append(jnp.stack(c).astype(jnp.float32))
     return x, cache, sum(counted) if counted else None
@@ -1358,27 +1536,32 @@ def transformer_prefill(
     b, s = tokens.shape
     x = _embed_rows(params, tokens, cfg.dtype)
     positions = jnp.arange(s)
-    with jax.named_scope("serve.kv.write"):
-        # physical destination of every (lane, position): padded tail -> scratch
-        phys = jnp.where(
-            positions[None, :] < prompt_lens[:, None],
-            jnp.take_along_axis(
-                block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)), axis=1
-            ),
-            0,
-        )
-        slots = jnp.broadcast_to((positions % block_size)[None, :], (b, s))
+    valid = positions[None, :] < prompt_lens[:, None]
+    write = None
+    if block_size is not None:
+        with jax.named_scope("serve.kv.write"):
+            # physical destination of every (lane, position): padded tail -> scratch
+            phys = jnp.where(
+                valid,
+                jnp.take_along_axis(
+                    block_tables, jnp.broadcast_to(positions[None, :] // block_size, (b, s)), axis=1
+                ),
+                0,
+            )
+            write = (phys, jnp.broadcast_to((positions % block_size)[None, :], (b, s)))
     attend = _latent_attend_local(cfg) if cfg.latent else _attend_local
+    # a retention layer takes the prompt as one chunk into lane b's zeroed slot
+    retain = _retain_chunk(cfg, jnp.arange(b), valid, True) if cfg.retention_layers else None
     # the padded tail takes no expert's rows
-    live = positions[None, :] < prompt_lens[:, None] if cfg.moe_experts else None
-    x, cache, _ = _serve_layers(cfg, params, x, positions, (phys, slots), attend, cache, live)
+    live = valid if cfg.moe_experts else None
+    x, cache, _ = _serve_layers(cfg, params, x, positions, write, attend, cache, live, retain=retain)
     return _head(cfg, params, x), cache
 
 
 def transformer_decode(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, positions: jax.Array,
     block_tables: jax.Array, cache: Dict[str, jax.Array], *, chunk_blocks: int = 0,
-    counters: bool = False,
+    counters: bool = False, retention_impl: Optional[str] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step over the paged cache for every lane at once.
 
@@ -1411,6 +1594,12 @@ def transformer_decode(
     tokens by position; a window layer reads ``min(position + 1, window)`` of
     them and nothing older, by the paged path over the ring (the gathered ring
     under ``chunk_blocks`` 0).
+
+    Power-retention layers read and write the state pool alone: row ``b`` of
+    the batch IS lane ``b`` and updates slot ``b``; an idle lane leaves its
+    slot as it is.  A model of such layers alone has no pool, and
+    ``block_tables`` is read by nothing.  ``retention_impl`` picks the form
+    of ``ops/retention.py retention_decode`` (tests).
     """
     _check_decodable(cfg)
     block_size = _block_size(cache)
@@ -1420,6 +1609,10 @@ def transformer_decode(
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
     x = _embed_rows(params, tokens[:, None], cfg.dtype)
+    retain = _retain_decode(cfg, active, retention_impl) if cfg.retention_layers else None
+    if block_size is None:  # no layer reads a pool: nothing to write, no table to attend through
+        x, cache, counted = _serve_layers(cfg, params, x, pos[:, None], None, None, cache, retain=retain)
+        return _decode_result(cfg, params, x, active, pos, counted, counters), cache
     with jax.named_scope("serve.kv.write"):  # where each lane's row goes: idle lanes -> scratch
         phys = jnp.where(
             active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
@@ -1447,7 +1640,13 @@ def transformer_decode(
             sliding = ((wphys, slots), _attend_paged(cfg, rings, positions, cfg.sliding_window))
         else:
             sliding = ((wphys, slots), _attend_ring_table(cfg, positions))
-    x, cache, counted = _serve_layers(cfg, params, x, pos_col, (phys, slots), attend, cache, live, sliding)
+    x, cache, counted = _serve_layers(cfg, params, x, pos_col, (phys, slots), attend, cache, live, sliding, retain)
+    return _decode_result(cfg, params, x, active, pos, counted, counters), cache
+
+
+def _decode_result(cfg, params, x, active, pos, counted, counters: bool) -> jax.Array:
+    """A decode step's logits and, under ``counters``, the row of
+    ``serve_counters(cfg)`` after them (``counted``: what the expert layers counted)."""
     logits = _head(cfg, params, x, row=0)
     if counters and cfg.window_layers:
         with jax.named_scope("serve.head"):  # the cached tokens this step's attention reads, by kind of layer
@@ -1457,11 +1656,16 @@ def transformer_decode(
                 jnp.sum(lens) * (cfg.n_layers - n_window), jnp.sum(jnp.minimum(lens, cfg.sliding_window)) * n_window,
             ])
             counted = read if counted is None else jnp.concatenate([read, counted])
+    if counters and cfg.retention_layers:
+        with jax.named_scope("serve.head"):  # the lanes whose state this step updated, and the bytes they hold
+            lanes = jnp.sum(active.astype(jnp.float32))
+            held = jnp.stack([lanes, lanes * (len(cfg.retention_layers) * state_bytes_per_slot(cfg))])
+            counted = held if counted is None else jnp.concatenate([held, counted])
     if counters and counted is not None:
         with jax.named_scope("serve.head"):  # the counters ride in the logits' own copy
             row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
             logits = jnp.concatenate([logits, row], axis=0)
-    return logits, cache
+    return logits
 
 
 #: tokens an iteration of the prefill walk aims for.  An iteration sweeps every
@@ -1485,7 +1689,7 @@ def prefill_chunk_tokens(block_size: int, prompt_tokens: int) -> int:
 def transformer_prefill_chunked(
     cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array, start_lens: jax.Array,
     prompt_lens: jax.Array, block_tables: jax.Array, cache: Dict[str, jax.Array],
-    lanes: Optional[jax.Array] = None,
+    lanes: Optional[jax.Array] = None, *, chunk_tokens: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Prefill each prompt from ``start_lens`` on, a chunk of tokens at a time:
     the serving engine's one prefill program, for a cold prompt (``start`` 0)
@@ -1522,16 +1726,30 @@ def transformer_prefill_chunked(
     the ring is one chunk longer than the window, so no row a query of the
     chunk still sees is overwritten.  Such a prompt starts at 0 (a shared
     block holds no window state), and its walk computes every chunk.
+
+    Power-retention layers carry a state through the walk, in the slot of the
+    decode lane each prompt will run in (``lanes`` as above): the first chunk
+    starts from a zeroed slot, every chunk is answered from the slot and folded
+    into it, and the walk leaves it holding the prompt's state.  Such a prompt
+    starts at 0 too (no block holds a state).  A model of such layers alone has
+    no pool to take the block size from: ``chunk_tokens`` states the chunk.
     """
     _check_decodable(cfg)
     block_size = _block_size(cache)
+    paged = block_size is not None
     b, s = tokens.shape
-    chunk = prefill_chunk_tokens(block_size, s)
+    if not paged:
+        if chunk_tokens is None:
+            raise ValueError("a cache without a pool states no block size: pass chunk_tokens")
+        chunk, block_size = chunk_tokens, 1
+    else:
+        chunk = prefill_chunk_tokens(block_size, s)
     if s % chunk:
         raise ValueError(
             f"chunked prefill needs tokens padded to whole chunks (got S={s}, "
             f"chunk={chunk}, block_size={block_size})"
         )
+    slot_lanes = jnp.arange(b, dtype=jnp.int32) if lanes is None else lanes.astype(jnp.int32)
     blocks, t = chunk // block_size, block_tables.shape[1]
     if cfg.window_layers:
         ring_blocks, store = window_ring_blocks(cfg, block_size, chunk), cache["wk"].shape[1]
@@ -1555,11 +1773,16 @@ def transformer_prefill_chunked(
         p = c * chunk + offsets  # absolute positions [chunk]
         with jax.named_scope("serve.kv.write"):
             valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, chunk]
-            # a padded prompt may be wider than the table: those columns hold no valid row
-            cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
-            phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
-            slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
-        attend = (_latent_attend_chunk if cfg.latent else _attend_chunk)(cfg, block_tables, c)
+        write = attend = None
+        if paged:
+            with jax.named_scope("serve.kv.write"):
+                # a padded prompt may be wider than the table: those columns hold no valid row
+                cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
+                phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
+                slots = jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk))
+            write = (phys, slots)
+            attend = (_latent_attend_chunk if cfg.latent else _attend_chunk)(cfg, block_tables, c)
+        retain = _retain_chunk(cfg, slot_lanes, valid, c == c_lo) if cfg.retention_layers else None
         sliding = None
         if cfg.window_layers:
             with jax.named_scope("serve.kv.write"):  # rows outside [start, len) are dropped
@@ -1578,7 +1801,7 @@ def transformer_prefill_chunked(
         layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
         x = _embed_rows(params, toks, cfg.dtype)
         x, cache, _ = _serve_layers(
-            cfg, layers, x, p, (phys, slots), attend, cache, valid if cfg.moe_experts else None, sliding
+            cfg, layers, x, p, write, attend, cache, valid if cfg.moe_experts else None, sliding, retain
         )
         with jax.named_scope("serve.head"):  # the one row the head will read
             sel = prompt_lens - 1 - c * chunk  # [b]

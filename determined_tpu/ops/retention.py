@@ -1,0 +1,424 @@
+"""Power retention of degree 2: attention whose cache is a fixed-size state.
+
+For queries and keys of one head, ``A_ij = exp(G_i - G_j) (q_i . k_j)^2`` for
+``j <= i`` (``G`` the running sum of the gate's logarithm) and ``o_i = sum_j
+A_ij v_j / sum_j A_ij``.  Because ``(x . y)^2 = phi(x) . phi(y)`` for a fixed
+feature map ``phi``, the sums over ``j`` fold into a state a KV head that does
+not grow with the context::
+
+    S_t = g_t S_{t-1} + phi(k_t) v_t^T        z_t = g_t z_{t-1} + phi(k_t)
+    o_t = S_t^T phi(q_t) / (z_t . phi(q_t))
+
+**The feature map.**  ``phi(x)[r, c] = w_r x_c x_{(c + r) mod d}`` for ``r`` in
+``0 .. d/2`` with ``w_0 = w_{d/2} = 1`` and ``w_r = sqrt 2`` between: row ``r``
+is the row ``x`` times itself rolled by ``r``, which the chip makes with one
+lane rotation.  Row 0 holds the squares; rows ``1 .. d/2 - 1`` hold every
+unordered pair at circular distance ``r`` once (weight ``sqrt 2``, squared 2:
+the pair's two places in ``(x . y)^2``); row ``d/2`` holds its pairs twice, at
+weight 1 each.  ``d/2 + 1`` rows of ``d``: 65 x 128 = 8,320 features at a head
+of 128, where the symmetric form has 8,256 and ``x (x) x`` 16,384.
+
+**The state pool** (``models/transformer.py init_kv_cache``): ``rs [layers,
+lanes, kv_heads, rows * d, d]`` float32, feature row ``r`` and value ``v`` at
+row ``r * d + v``, the feature's column ``c`` along the lanes; ``rz [layers,
+lanes, kv_heads, rows, d]`` the normaliser.  A decode lane owns slot ``lane``.
+
+* :func:`retention_decode`: one token a lane.  The Pallas kernel's grid is
+  (lane, KV head): a program reads the head's ``[rows * d, d]`` state once,
+  decays it, adds the token's outer product a feature row at a time, answers
+  the KV head's query heads from the NEW rows (``phi(q) [heads, d] @ S_r^T``
+  on the MXU) and writes them back into the pool's own buffer
+  (``input_output_aliases``).  ``phi`` is built in the kernel from the
+  128-wide rows and never lies in HBM.  An idle lane's slot passes through.
+* :func:`retention_chunk`: a chunk of tokens (the prefill walk, and the wide
+  prefill as one chunk): the chunk is answered from the state at its start,
+  decayed, plus the quadratic form inside the chunk (``jax.numpy``), and
+  folded into the state once.  What it reads of the state and writes to it
+  is a second Pallas kernel, grid (row, KV head): ``phi`` of the chunk's
+  queries and keys is built a feature row at a time in VMEM, each row of the
+  state is read once, answers the queries as it comes, takes the keys and is
+  written back.  Its ``jax.numpy`` form holds ``phi`` of the queries whole.
+* :func:`retention_quadratic`: the masked quadratic form over a whole
+  sequence: the training forward, and the oracle of both forms above.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+#: how the decode kernel multiplies phi(q) with the float32 state on the MXU
+QUERY_PRECISION = _HIGHEST
+#: VMEM the decode kernel may take: a head's state at 128 is 4.26 MB, held
+#: twice coming in and twice going out
+_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+
+
+def _on_tpu() -> bool:
+    from determined_tpu.ops import paged_attention
+
+    return paged_attention._on_tpu()  # one switch for the serving forward's kernels (tests steer it)
+
+
+def phi_rows(head_dim: int) -> int:
+    """Feature rows of ``phi`` at a head of ``head_dim``: rolls 0 .. d/2."""
+    return head_dim // 2 + 1
+
+
+def state_shapes(layers: int, lanes: int, kv_heads: int, head_dim: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The shapes of the state pool and of its normaliser."""
+    rows = phi_rows(head_dim)
+    return (layers, lanes, kv_heads, rows * head_dim, head_dim), (layers, lanes, kv_heads, rows, head_dim)
+
+
+def _weight(r: int, head_dim: int) -> float:
+    return 1.0 if r in (0, head_dim // 2) else math.sqrt(2.0)
+
+
+def phi(x: jax.Array) -> jax.Array:
+    """``[..., d] -> [..., d/2 + 1, d]`` with ``phi(x) . phi(y) = (x . y)^2``."""
+    d = x.shape[-1]
+    return jnp.stack([_weight(r, d) * x * jnp.roll(x, -r, axis=-1) for r in range(phi_rows(d))], axis=-2)
+
+
+def kernel_takes(head_dim: int, state_dtype) -> bool:
+    """Whether the decode kernel runs these shapes: a head is one lane tile."""
+    return head_dim == 128 and jnp.dtype(state_dtype).itemsize in (2, 4)
+
+
+# ---------------------------------------------------------------------------
+# the quadratic form
+# ---------------------------------------------------------------------------
+
+
+def retention_quadratic(q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Array) -> jax.Array:
+    """``q`` [b, heads, s, d], ``k`` / ``v`` [b, kv_heads, s, d], ``log_g`` [b,
+    kv_heads, s] float32 -> [b, heads, s, d] in q's dtype.  Weights in float32."""
+    b, h, s, d = q.shape
+    g = k.shape[1]
+    cum = jnp.cumsum(log_g.astype(jnp.float32), axis=-1)
+    scores = jnp.einsum(
+        "bgnsd,bgtd->bgnst", q.reshape(b, g, h // g, s, d), k, preferred_element_type=jnp.float32, precision=_HIGHEST
+    )
+    decay = cum[:, :, None, :, None] - cum[:, :, None, None, :]
+    seen = jnp.tril(jnp.ones((s, s), bool))
+    weights = jnp.where(seen, jnp.exp(jnp.where(seen, decay, 0.0)) * jnp.square(scores), 0.0)
+    num = jnp.einsum("bgnst,bgtd->bgnsd", weights, v.astype(jnp.float32), precision=_HIGHEST)
+    return (num / jnp.sum(weights, axis=-1, keepdims=True)).reshape(b, h, s, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# a chunk of tokens against a state, and into it
+# ---------------------------------------------------------------------------
+
+
+def chunk_kernel_takes(query_rows: int, tokens: int, head_dim: int, state_dtype) -> bool:
+    """Whether the chunk kernel runs these shapes: a head is one lane tile, the
+    chunk's rows are whole sublane tiles, and a KV head's queries fit VMEM
+    beside its state (the walk's 256 tokens x 5 query heads do)."""
+    return kernel_takes(head_dim, state_dtype) and tokens % 8 == 0 and tokens <= 512 and query_rows <= 4096
+
+
+def retention_chunk(
+    q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Array, state: jax.Array, norm: jax.Array, valid: jax.Array,
+    *, impl: Optional[str] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``s`` tokens a row of the batch, after the ones ``state`` / ``norm``
+    ([b, kv_heads, rows * d, d] / [b, kv_heads, rows, d]) already hold.  ``valid``
+    [b, s] marks the tokens that exist: the others neither decay the state nor
+    enter it, and what they are answered is not read.  Returns (o [b, heads, s,
+    d] float32, the state and the normaliser after the chunk).
+
+    The quadratic form inside the chunk is ``jax.numpy``.  What the chunk
+    reads of the state and folds into it is ``impl``: ``"kernel"`` (the Pallas
+    kernel: one pass over a KV head's state, ``phi`` of the chunk's queries and
+    keys built a feature row at a time in VMEM), ``"kernel_interpret"``
+    (tests), ``"jnp"`` (``phi`` whole, in HBM), or None: the kernel on a TPU
+    when :func:`chunk_kernel_takes` the shapes."""
+    b, h, s, d = q.shape
+    g = k.shape[1]
+    n = h // g
+    if impl is None:
+        impl = "kernel" if _on_tpu() and chunk_kernel_takes(n * s, s, d, state.dtype) else "jnp"
+    if impl != "jnp" and not chunk_kernel_takes(n * s, s, d, state.dtype):
+        raise ValueError(f"the retention chunk kernel does not take {n * s} query rows of {s} tokens at head_dim {d}, {state.dtype}")
+    f32 = jnp.float32
+    qf, kf, vf = q.astype(f32).reshape(b, g, n, s, d), k.astype(f32), v.astype(f32)
+    vf = jnp.where(valid[:, None, :, None], vf, 0.0)
+    cum = jnp.cumsum(jnp.where(valid[:, None, :], log_g.astype(f32), 0.0), axis=-1)  # [b, g, s]
+    # inside the chunk: the quadratic form
+    scores = jnp.einsum("bgnsd,bgtd->bgnst", qf, kf, precision=_HIGHEST)
+    seen = jnp.tril(jnp.ones((s, s), bool))[None, None, None] & valid[:, None, None, None, :]
+    decay = cum[:, :, None, :, None] - cum[:, :, None, None, :]
+    weights = jnp.where(seen, jnp.exp(jnp.where(seen, decay, 0.0)) * jnp.square(scores), 0.0)
+    num = jnp.einsum("bgnst,bgtd->bgnsd", weights, vf, precision=_HIGHEST)
+    den = jnp.sum(weights, axis=-1)
+    # what came before it, and the chunk into it: each key decayed from its place to the chunk's end
+    total = cum[..., -1]
+    left = jnp.where(valid[:, None, :], jnp.exp(total[..., None] - cum), 0.0)  # [b, g, s]
+    against = _chunk_state_jnp if impl == "jnp" else functools.partial(_chunk_state_pallas, interpret=impl == "kernel_interpret")
+    num0, den0, s1, z1 = against(qf, kf, vf, left, jnp.exp(total), state, norm)
+    since = jnp.exp(cum)[:, :, None, :]  # the state at the chunk's start, decayed up to each query
+    num, den = num + since[..., None] * num0, den + since * den0
+    out = num / jnp.where(den == 0.0, 1.0, den)[..., None]
+    return out.reshape(b, h, s, d), s1, z1
+
+
+def _chunk_state_jnp(qf, kf, vf, left, kept, state, norm):
+    """``phi(q) . S`` and ``phi(q) . z`` for the chunk's queries ([b, g, n, s, d]
+    / [b, g, n, s]) against the state as it comes, and the state and the
+    normaliser after the chunk's keys ``kf`` (weighed by ``left`` [b, g, s]) and
+    values ``vf`` entered them, what they held decayed by ``kept`` [b, g]."""
+    b, g, n, s, d = qf.shape
+    rows = phi_rows(d)
+    s0, z0 = state.astype(jnp.float32).reshape(b, g, rows, d, d), norm.astype(jnp.float32)
+    pq = phi(qf)  # [b, g, n, s, rows, d]
+    num0 = jnp.einsum("bgnsrc,bgrvc->bgnsv", pq, s0, precision=_HIGHEST)
+    den0 = jnp.einsum("bgnsrc,bgrc->bgns", pq, z0, precision=_HIGHEST)
+    pk = phi(kf) * left[..., None, None]  # [b, g, s, rows, d]
+    s1 = kept[..., None, None, None] * s0 + jnp.einsum("bgsrc,bgsv->bgrvc", pk, vf, precision=_HIGHEST)
+    z1 = kept[..., None, None] * z0 + jnp.sum(pk, axis=2)
+    return num0, den0, s1.reshape(state.shape).astype(state.dtype), z1.astype(norm.dtype)
+
+
+def _two_terms(x):
+    """float32 -> (hi, lo) bfloat16 with hi + lo = x to 16 bits."""
+    hi = x.astype(jnp.bfloat16)
+    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _nt(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _query_state(pq, s_old):
+    """``pq [m, c] . s_old [v, c]^T`` in float32 from three bfloat16 products
+    (hi x hi, hi x lo, lo x hi).  Not one float32 product at
+    ``Precision.HIGHEST``: with 256 to 1,280 rows on the left Mosaic's reads
+    0.6-3.5 % off the ``jax.numpy`` form on a v5e where this reads 2e-6 and is
+    faster; the decode kernel's 8 rows are sound (PERF.md section 7)."""
+    (a, a_lo), (b, b_lo) = _two_terms(pq), _two_terms(s_old)
+    return _nt(a, b) + (_nt(a, b_lo) + _nt(a_lo, b))
+
+
+def _retention_chunk_kernel(q_ref, k_ref, left_ref, vt_ref, kept_ref, s_ref, z_ref, num_ref, den_ref, s_out, z_out, *, d, block):
+    """One (row of the batch, KV head): ``q_ref`` [m, d] the KV head's queries
+    (query head by token), ``k_ref`` [s, d] the keys, ``left_ref`` [s, 1] each
+    key's decay to the chunk's end (0: no such token), ``vt_ref`` [d, s] the
+    values as columns, ``kept_ref`` [1, 1]; ``s_ref`` [rows * d, d] and
+    ``z_ref`` [rows, d] the head's state.  The queries read the state AS IT
+    COMES, ``block`` rows at a time; the keys and values enter it after."""
+    f32 = jnp.float32
+    key, vt, kept = k_ref[...], vt_ref[...], kept_ref[...]
+    weighed = key * left_ref[...]
+    num_ref[...] = jnp.zeros(num_ref.shape, f32)
+    den_ref[...] = jnp.zeros(den_ref.shape, f32)
+    for r in range(phi_rows(d)):
+        w = _weight(r, d)
+        rows = pl.ds(r * d, d)
+        s_old = s_ref[rows, :].astype(f32)  # [d (value), d (feature column)]
+        z_old = z_ref[r:r + 1, :].astype(f32)
+        for lo in range(0, q_ref.shape[0], block):
+            q = q_ref[lo:lo + block, :]
+            pq = q * (q if r == 0 else pltpu.roll(q, d - r, 1)) * w  # phi's row r of the queries: column c holds q[:, (c + r) % d]
+            num_ref[lo:lo + block, :] += _query_state(pq, s_old)
+            den_ref[lo:lo + block, :] += jnp.sum(pq * z_old, axis=1, keepdims=True)
+        pk = weighed * (key if r == 0 else pltpu.roll(key, d - r, 1)) * w
+        # [d, s] x [s, d] at HIGHEST agrees with the jax.numpy form to 7.6e-6 on the chip (the queries' product does not: _query_state)
+        entered = jax.lax.dot_general(vt, pk, (((1,), (0,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
+        s_out[rows, :] = (kept * s_old + entered).astype(s_out.dtype)
+        z_out[r:r + 1, :] = (kept * z_old + jnp.sum(pk, axis=0, keepdims=True)).astype(z_out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _chunk_state_pallas(qf, kf, vf, left, kept, state, norm, *, interpret: bool):
+    """:func:`_chunk_state_jnp` as one pass over each KV head's state."""
+    b, g, n, s, d = qf.shape
+    rows, m = phi_rows(d), n * s
+    at = lambda bi, gi: (bi, gi, 0, 0)  # noqa: E731
+    num0, den0, s1, z1 = pl.pallas_call(
+        functools.partial(_retention_chunk_kernel, d=d, block=s),  # a query head's rows at a time
+        grid=(b, g),
+        in_specs=[
+            pl.BlockSpec((None, None, m, d), at),
+            pl.BlockSpec((None, None, s, d), at),
+            pl.BlockSpec((None, None, s, 1), at),
+            pl.BlockSpec((None, None, d, s), at),
+            pl.BlockSpec((None, None, 1, 1), at),
+            pl.BlockSpec((None, None, rows * d, d), at),
+            pl.BlockSpec((None, None, rows, d), at),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, m, d), at),
+            pl.BlockSpec((None, None, m, 1), at),
+            pl.BlockSpec((None, None, rows * d, d), at),
+            pl.BlockSpec((None, None, rows, d), at),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, g, m, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, g, m, 1), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+            jax.ShapeDtypeStruct(norm.shape, norm.dtype),
+        ],
+        input_output_aliases={5: 2, 6: 3},  # the lanes' slots as the walk gathered them: updated where they lie
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="retention_chunk",
+    )(
+        qf.reshape(b, g, m, d), kf, left[..., None], vf.transpose(0, 1, 3, 2), kept[..., None, None], state, norm,
+    )
+    return num0.reshape(b, g, n, s, d), den0.reshape(b, g, n, s), s1, z1
+
+
+# ---------------------------------------------------------------------------
+# one token a lane
+# ---------------------------------------------------------------------------
+
+
+def retention_decode(
+    q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Array, state: jax.Array, norm: jax.Array, layer,
+    live: jax.Array, *, impl: Optional[str] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decode step of one layer over the state pool, in place.
+
+    ``q`` [lanes, heads, d], ``k`` / ``v`` [lanes, kv_heads, d] (q and k after
+    their norm and rotary), ``log_g`` [lanes, kv_heads] float32, ``state`` /
+    ``norm`` the whole pools (:func:`state_shapes`), ``layer`` the layer to
+    update, ``live`` [lanes] bool: an idle lane's slot is left as it is and
+    its output is zeros.  Returns (o [lanes, heads, d] float32, state, norm).
+
+    ``impl``: ``"kernel"``, ``"kernel_interpret"`` (tests), ``"jnp"`` or None:
+    the kernel on a TPU when :func:`kernel_takes` the shapes.
+    """
+    d = q.shape[-1]
+    if impl is None:
+        impl = "kernel" if _on_tpu() and kernel_takes(d, state.dtype) else "jnp"
+    if impl != "jnp" and not kernel_takes(d, state.dtype):
+        raise ValueError(f"the retention kernel needs head_dim 128 and a 2- or 4-byte state (got {d}, {state.dtype})")
+    return _retention_decode(q, k, v, log_g, state, norm, jnp.asarray(layer, jnp.int32), live, impl=impl)
+
+
+# the layer is an ARGUMENT of one jitted function: a model's layers share one
+# lowering of the kernel (as ``ops/paged_attention.py _paged_attention``)
+@functools.partial(jax.jit, static_argnames=("impl",))
+def _retention_decode(q, k, v, log_g, state, norm, layer, live, *, impl):
+    if impl == "jnp":
+        return _retention_decode_jnp(q, k, v, log_g, state, norm, layer, live)
+    return _retention_decode_pallas(q, k, v, log_g, state, norm, layer, live, interpret=impl == "kernel_interpret")
+
+
+def _retention_decode_jnp(q, k, v, log_g, state, norm, layer, live):
+    b, h, d = q.shape
+    g, rows = k.shape[1], phi_rows(d)
+    f32 = jnp.float32
+    s0 = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    z0 = jax.lax.dynamic_index_in_dim(norm, layer, 0, keepdims=False)
+    pk = phi(k.astype(f32))  # [b, g, rows, d]
+    pq = phi(q.astype(f32).reshape(b, g, h // g, d))  # [b, g, n, rows, d]
+    decay = jnp.exp(log_g.astype(f32))
+    s1 = decay[..., None, None, None] * s0.astype(f32).reshape(b, g, rows, d, d)
+    s1 = s1 + v.astype(f32)[:, :, None, :, None] * pk[:, :, :, None, :]
+    z1 = decay[..., None, None] * z0.astype(f32) + pk
+    num = jnp.einsum("bgnrc,bgrvc->bgnv", pq, s1, precision=_HIGHEST)
+    den = jnp.einsum("bgnrc,bgrc->bgn", pq, z1, precision=_HIGHEST)
+    out = jnp.where(live[:, None, None], (num / den[..., None]).reshape(b, h, d), 0.0)
+    keep = live[:, None, None, None]
+    s1 = jnp.where(keep, s1.reshape(s0.shape).astype(state.dtype), s0)
+    z1 = jnp.where(keep, z1.astype(norm.dtype), z0)
+    return (
+        out,
+        jax.lax.dynamic_update_index_in_dim(state, s1, layer, 0),
+        jax.lax.dynamic_update_index_in_dim(norm, z1, layer, 0),
+    )
+
+
+def _retention_kernel(layer_ref, live_ref, qk_ref, v_ref, decay_ref, s_ref, z_ref, o_ref, s_out, z_out, *, n_rep, d):
+    """One (lane, KV head): ``qk_ref`` [rows8, d] float32 holds the KV head's
+    ``n_rep`` queries and then its key; ``v_ref`` [d, 1] the value as a column;
+    ``s_ref`` [rows * d, d] and ``z_ref`` [rows, d] the head's state."""
+    alive = live_ref[pl.program_id(0)] > 0
+    f32 = jnp.float32
+
+    @pl.when(alive)
+    def _update():
+        qk = qk_ref[...]
+        value = v_ref[...]
+        decay = decay_ref[...]  # [1, 1]
+        num = jnp.zeros(qk.shape, f32)
+        den = jnp.zeros((qk.shape[0], 1), f32)
+        for r in range(phi_rows(d)):
+            rolled = qk if r == 0 else pltpu.roll(qk, d - r, 1)  # column c holds qk[:, (c + r) % d]
+            ph = qk * rolled * _weight(r, d)  # phi's row r of the queries and of the key
+            pk = ph[n_rep:n_rep + 1, :]
+            rows = pl.ds(r * d, d)
+            s_new = decay * s_ref[rows, :].astype(f32) + value * pk  # [d (value), d (feature column)]
+            s_out[rows, :] = s_new.astype(s_out.dtype)
+            z_new = decay * z_ref[r:r + 1, :].astype(f32) + pk
+            z_out[r:r + 1, :] = z_new.astype(z_out.dtype)
+            num = num + jax.lax.dot_general(
+                ph, s_new, (((1,), (1,)), ((), ())), precision=QUERY_PRECISION, preferred_element_type=f32
+            )
+            den = den + jnp.sum(ph * z_new, axis=1, keepdims=True)
+        o_ref[...] = num / jnp.where(den == 0.0, 1.0, den)  # rows past n_rep are not read
+
+    @pl.when(jnp.logical_not(alive))
+    def _pass():
+        s_out[...] = s_ref[...]
+        z_out[...] = z_ref[...]
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+
+def _retention_decode_pallas(q, k, v, log_g, state, norm, layer, live, *, interpret: bool):
+    b, h, d = q.shape
+    g, rows = k.shape[1], phi_rows(d)
+    n_rep = h // g
+    rows8 = -(-(n_rep + 1) // 8) * 8  # whole sublane tiles: the queries, the key, zeros
+    f32 = jnp.float32
+    qk = jnp.concatenate([q.astype(f32).reshape(b, g, n_rep, d), k.astype(f32)[:, :, None, :]], axis=2)
+    qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rows8 - n_rep - 1), (0, 0)))
+    at_head = lambda bi, gi, *_: (bi, gi, 0, 0)  # noqa: E731
+    at_slot = lambda bi, gi, lay, _: (lay[0], bi, gi, 0, 0)  # noqa: E731
+    out, state, norm = pl.pallas_call(
+        functools.partial(_retention_kernel, n_rep=n_rep, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, g),
+            in_specs=[
+                pl.BlockSpec((None, None, rows8, d), at_head),
+                pl.BlockSpec((None, None, d, 1), at_head),
+                pl.BlockSpec((None, None, 1, 1), at_head),
+                pl.BlockSpec((None, None, None, rows * d, d), at_slot),
+                pl.BlockSpec((None, None, None, rows, d), at_slot),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, rows8, d), at_head),
+                pl.BlockSpec((None, None, None, rows * d, d), at_slot),
+                pl.BlockSpec((None, None, None, rows, d), at_slot),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, g, rows8, d), f32),
+            jax.ShapeDtypeStruct(state.shape, state.dtype),
+            jax.ShapeDtypeStruct(norm.shape, norm.dtype),
+        ],
+        # the pools are updated where they lie (inputs count the two scalar-prefetch arguments)
+        input_output_aliases={5: 1, 6: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="retention_decode",
+    )(
+        layer.reshape(1), live.astype(jnp.int32),
+        qk, v.astype(f32)[..., None], jnp.exp(log_g.astype(f32))[..., None, None], state, norm,
+    )
+    return out[:, :, :n_rep, :].reshape(b, h, d), state, norm

@@ -155,6 +155,7 @@ class DecodeKernels:
             init_kv_cache,
             kv_bytes_per_token,
             serve_counters,
+            state_bytes_per_slot,
             transformer_decode,
             transformer_prefill_chunked,
             window_ring_blocks,
@@ -174,6 +175,19 @@ class DecodeKernels:
                 "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no "
                 "window state: the sliding-window layers keep a request's newest tokens in its own lane's ring, "
                 "which a prefill from the first un-cached token would leave without the prefix. Set prefix_cache: false"
+            )
+        #: a model with power-retention layers: what a request holds of them is
+        #: one state slot, its lane's, whatever its length
+        self.stateful = bool(model_cfg.retention_layers)
+        #: whether any layer reads the paged pool: where none does, the
+        #: allocator's block ids address nothing and no array is made for them
+        self.paged = model_cfg.paged_layers > 0
+        if self.stateful and serve_cfg.prefix_cache:
+            raise ValueError(
+                "prefix_cache shares a prompt's full blocks between requests, and a block holds no state: a "
+                "power-retention layer keeps a request's whole context in its own lane's state slot, and a prefill "
+                "from the first un-cached token would need the state as it stood at that block's edge (a snapshot "
+                "nobody keeps). Set prefix_cache: false"
             )
         tracer = get_tracer()
         t_setup = mono()
@@ -219,11 +233,26 @@ class DecodeKernels:
                 "bytes_per_token_window": layer * n_window,
                 **self.window_store,
             }
+        #: ``/stats`` ``state`` (empty without retention layers): the slots
+        #: (one a lane) and the bytes one holds over the retention layers
+        self.state_pool: Dict[str, int] = {}
+        if self.stateful:
+            self.state_pool = {
+                "slots": serve_cfg.max_batch,
+                "bytes_per_slot": len(model_cfg.retention_layers) * state_bytes_per_slot(model_cfg),
+            }
+            kinds = {**kinds, **self.state_pool, "state_pool_bytes": int(self.cache["rs"].nbytes + self.cache["rz"].nbytes)}
+            if not self.paged:
+                logger.info(
+                    "no layer of this model reads the paged pool: the allocator's %d block ids address nothing and no "
+                    "array was made for them; a request holds one of %d state slots (%d bytes) whatever its length",
+                    serve_cfg.num_blocks, serve_cfg.max_batch, self.state_pool["bytes_per_slot"],
+                )
         #: ``/stats`` ``attn_products``: what a tile of the GQA decode kernel
         #: multiplies at this model's heads (``ops/paged_attention.py``); None
-        #: for latent layers, whose heads all share a row
+        #: for latent layers, whose heads all share a row, and where no layer reads K and V
         self.attn_products: Optional[str] = None
-        if not model_cfg.latent:
+        if not model_cfg.latent and len(model_cfg.retention_layers) < model_cfg.n_layers:
             kinds["attn_products"] = self.attn_products = attn_products(model_cfg.n_heads // model_cfg.kv_heads)
         # bytes_per_token: what attention reads of the pool for one cached
         # token over all layers (K and V rows, or one latent row a layer)
@@ -247,9 +276,11 @@ class DecodeKernels:
         # cold requests run it with start=0, warm requests from the chunk
         # of their first un-cached block; either way it is the SAME trace
         # (dynamic trip count inside the program)
+        # a cache without a pool states no block size: the walk is told its chunk
+        chunk = {} if self.paged else {"chunk_tokens": serve_cfg.prefill_chunk}
         prefill = sentinel.wrap(
             "serve.prefill_step",
-            functools.partial(transformer_prefill_chunked, model_cfg),
+            functools.partial(transformer_prefill_chunked, model_cfg, **chunk),
             allowed=1,
         )
         decode = sentinel.wrap(
@@ -315,9 +346,11 @@ class DecodeKernels:
         starts = np.asarray([start], np.int32)
         lens = np.asarray([len(prompt)], np.int32)
         args = (self.params, tokens, starts, lens, table, self.cache)
-        if self.windowed:
+        if self.windowed or self.stateful:
             if start:
-                raise ValueError(f"a prompt of a model with sliding-window layers is prefilled from 0, not from {start}")
+                raise ValueError(
+                    f"a prompt of a model with sliding-window or power-retention layers is prefilled from 0, not from {start}"
+                )
             args += (np.asarray([lane], np.int32),)
         logits, self.cache = self._prefill(*args)
         return np.asarray(logits[0])
@@ -356,6 +389,10 @@ class ServeEngine:
         self.kernels = kernels
         self.cfg = kernels.serve_cfg
         self.lanes = LaneTable(self.cfg.max_batch)
+        #: False for a model none of whose layers reads the paged pool: a
+        #: request then holds a state slot (its lane) and no block, and
+        #: admission never asks the allocator (a stand-in for the kernels is paged)
+        self.kernels_paged = bool(getattr(kernels, "paged", True))
         #: trial/model label surfaced in the master's replica listing
         self.model_label = type(kernels.model_cfg).__name__
         self.allocator = BlockAllocator(
@@ -471,7 +508,7 @@ class ServeEngine:
         )
         if new < 1:  # 0 is a client error, not "use the default"
             raise AdmissionRejected(400, "max_new_tokens must be >= 1")
-        if self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
+        if self.kernels_paged and self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
             # permanent: this request can NEVER fit this replica's cache
             raise AdmissionRejected(
                 413, "request exceeds kv cache capacity (kv_cache_oom)"
@@ -681,6 +718,11 @@ class ServeEngine:
             # whatever the contexts, and the tokens a lane's ring holds a layer
             # (``kv_cache`` counts the full layers' blocks alone)
             "window_store": dict(getattr(self.kernels, "window_store", None) or {}),
+            # a model with power-retention layers: its state slots (one a
+            # lane), how many hold a sequence, and the bytes one holds over those
+            # layers; ``block_ids_address_nothing``: no layer reads the pool
+            # ``kv_cache`` counts, and admission is by free lane alone
+            **self._state_stats(),
             # what a tile of the GQA decode kernel multiplies ("per_kv_head" |
             # "block_diagonal"); absent for latent layers
             **({"attn_products": products} if products else {}),
@@ -694,6 +736,15 @@ class ServeEngine:
             ),
             "uptime_s": round(time.monotonic() - self._started_at, 3),
             "lanes": self.lanes.stats(),
+        }
+
+    def _state_stats(self) -> Dict[str, Any]:
+        pool = getattr(self.kernels, "state_pool", None)
+        if not pool:
+            return {}
+        return {
+            "state": {"slots": pool["slots"], "live": self.lanes.stats()["active"], "bytes_per_slot": pool["bytes_per_slot"]},
+            "block_ids_address_nothing": not self.kernels_paged,
         }
 
     # -- the engine thread's work ---------------------------------------------
@@ -723,7 +774,8 @@ class ServeEngine:
         """
         tracer = self._tracer
         t_admit = mono()  # just off the queue
-        total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens)
+        # a model without a paged layer holds no block: the free lane is the admission
+        total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens) if self.kernels_paged else 0
         shared: List[int] = []
         cached_tokens = 0
         chain: List[Any] = []
@@ -738,7 +790,7 @@ class ServeEngine:
             with tracer.span(
                 "serve.kv_alloc", cat="serve", request=req.id, step=step, blocks=needed
             ):
-                private = self.allocator.alloc(needed)
+                private = self.allocator.alloc(needed) if needed else []
         except CacheOOM:
             if shared:
                 self.allocator.free(shared)

@@ -687,3 +687,78 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
     else:
         assert _kernels(text) == 2 * 5
         assert _arrays_with_dims(text, (128, 256, 20480)) == [] and _arrays_with_dims(text, (256, 4352)) == []
+
+
+# -- power-retention layers served from a state a lane: the Brumby cell's shapes --
+
+
+def test_the_retention_decode_kernel_compiles_at_the_brumby_cells_shape(tpu_devices):
+    """32 lanes x 40 query heads over 8 KV heads of 128 against a float32 state
+    pool of five layers (8,320 x 128 a head): one kernel, the pools updated
+    where they lie (aliased, no scratch the size of a layer's state)."""
+    retention_mod = importlib.import_module("determined_tpu.ops.retention")
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state, norm = retention_mod.state_shapes(5, 32, 8, 128)
+    assert state == (5, 32, 8, 8320, 128) and norm == (5, 32, 8, 65, 128)
+
+    def fn(q, k, v, log_g, rs, rz, live):
+        return retention_mod.retention_decode(q, k, v, log_g, rs, rz, 3, live)
+
+    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(
+        aval((32, 40, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16),
+        aval((32, 8), jnp.float32), aval(state, jnp.float32), aval(norm, jnp.float32), aval((32,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 1 and "retention_decode" in text
+    pool_bytes = 4 * (math.prod(state) + math.prod(norm))
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 16 * 1024**2
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, which):
+    """The cell's decode step and prefill walk at its widths, lanes and state
+    pool, bfloat16 leaves, depth cut to two layers: weights, the pool and the
+    program's scratch fit the chip; the pool is donated and no second copy of
+    it is held; the kernel keeps its name under its own scope; no array is
+    made for the allocator's block ids."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, state_pool_shapes, transformer_decode, transformer_prefill_chunked,
+    )
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=151936, d_model=5120, n_layers=2, n_heads=40, n_kv_heads=8, head_dim=128, d_ff=17408, max_seq_len=28672,
+        layer_types=("power_retention",) * 2, qk_norm=True, retention_gate_bias=6.0, rope_theta=1e6, param_dtype=jnp.bfloat16,
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state, norm = state_pool_shapes(cfg, 32)
+    cache = {"rs": aval(state, jnp.float32), "rz": aval(norm, jnp.float32)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((32,)), aval((32,)), aval((32, 1792)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg, chunk_tokens=256), donate_argnums=(5,))
+        args = (params, aval((1, 22528)), aval((1,)), aval((1,)), aval((1, 1792)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pool_bytes = 4 * (math.prod(state) + math.prod(norm))
+    assert pool_bytes == 2 * 32 * 34_344_960
+    assert mem.alias_size_in_bytes >= pool_bytes                                     # the pool is donated
+    # the decode step holds nothing the size of a layer's pool; a chunk of the walk holds its scores and products, and phi of nothing (the kernel builds it in VMEM)
+    assert mem.temp_size_in_bytes < (64 if which == "decode" else 512) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.retention.qkvg", "serve.retention.state", "serve.retention.out", "serve.mlp", "serve.embed", "serve.head"} <= set(scopes)
+    assert "serve.attn.qkv" not in scopes
+    if which == "decode":
+        assert "serve.kv.write" not in scopes
+        assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_decode")}) == 2
+        assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
+    else:                                                                            # the chunk's pass over the state, a layer
+        assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
