@@ -4,14 +4,45 @@ This is the analog of the reference's artificial agent slots
 (``agent/internal/detect/detect.go:40-57``) + thread-rank simulator
 (``harness/tests/parallel.py``): all sharding/mesh tests run on CPU with 8
 virtual devices, no TPU required.
+
+What tier-1 may cost (PERF.md "What tier-1 costs").  The driver runs
+``-n 6 --dist loadfile`` under a clock that cuts the run, and what the cut
+does not reach is the end of the alphabet, so:
+
+- a FILE is the unit of balance: run alone while five others run, no file
+  outside ``tests/benchmark/`` takes more than 150 s.  One that does is made
+  cheaper (the least shapes that cross the edges its tests name, one jitted
+  program where a bare call compiles an operation at a time, module fixtures
+  for what is read and not written) or cut in two along a seam it already
+  has, its shared helpers in ``tests/model_cases.py``;
+- a TEST has ``TEST_TIME_LIMIT_S`` (``_time_limit`` below): one that waits
+  longer fails by its name with every thread's stack, and the run goes on;
+- ``slow`` is the last resort: only for a test still over 30 s whose
+  assertions a cheaper test that stays makes too, and CHANGES.md names both.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
+import tempfile
+import threading
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+# The CPU here answers for numerics and control flow, not for what its code
+# generator makes of a program (the TPU's compiler is asked by
+# tests/test_tpu_compile*.py, whose assertions on memory and kernels hold
+# under these flags as without them): LLVM at level 0
+# without its expensive passes compiles the suite's thousands of small programs
+# in two thirds of the CPU seconds (68 -> 47 minutes of a run; PERF.md "What
+# tier-1 costs"), and the processes the tests start inherit it.
+_CPU_FLAGS = {
+    "xla_force_host_platform_device_count": "8",
+    "xla_backend_optimization_level": "0",
+    "xla_llvm_disable_expensive_passes": "true",
+}
 prev = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in prev:
-    os.environ["XLA_FLAGS"] = (prev + " --xla_force_host_platform_device_count=8").strip()
+os.environ["XLA_FLAGS"] = " ".join([prev] + [f"--{k}={v}" for k, v in _CPU_FLAGS.items() if k not in prev]).strip()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -29,7 +60,10 @@ def pytest_configure(config):
         "faults: fault-injection tests (crash/corrupt/drop-peer; tier-1, tight timeouts)",
     )
     config.addinivalue_line(
-        "markers", "slow: excluded from the tier-1 `-m 'not slow'` run"
+        "markers",
+        "slow: excluded from the tier-1 `-m 'not slow'` run; the last resort "
+        "(still over 30 s, and a cheaper test that stays asserts the same), "
+        "and each use has its line in CHANGES.md",
     )
     config.addinivalue_line(
         "markers",
@@ -96,6 +130,49 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.get_closest_marker("devcluster") is not None:
             item.add_marker(skip)
+
+
+#: seconds a test may take, its function-scoped fixtures included: three times
+#: the slowest test of the suite under the driver's command when this was set
+#: (PERF.md "What tier-1 costs").  A constant: no marker raises it, no option sets it.
+TEST_TIME_LIMIT_S = 180.0
+
+
+@contextlib.contextmanager
+def time_limit(nodeid: str):
+    """Fail what runs inside by name after ``TEST_TIME_LIMIT_S``: SIGALRM's
+    handler raises in the main thread with ``nodeid`` and every thread's stack
+    (a wait on a lock, a join, a sleep and a child's exit are all interrupted;
+    a call that holds the interpreter, such as a compile, is failed when it
+    returns).  Nothing off the main thread, where no handler can be set, nor
+    on a platform without SIGALRM."""
+    if not hasattr(signal, "SIGALRM") or threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        with tempfile.TemporaryFile("w+") as dump:
+            faulthandler.dump_traceback(file=dump, all_threads=True)
+            dump.seek(0)
+            stacks = dump.read()
+        pytest.fail(f"{nodeid} took more than {TEST_TIME_LIMIT_S:g} s (tests/conftest.py TEST_TIME_LIMIT_S)\n{stacks}", pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """A test that hangs costs the run that test, not every test behind it in
+    a worker's queue (the driver's clock cut 23 of them at PR 44).  The
+    ``timeout=`` arguments inside the fault tests guard other things and stay."""
+    with time_limit(request.node.nodeid):
+        yield
 
 
 @pytest.fixture(autouse=True)

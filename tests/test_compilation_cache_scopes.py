@@ -302,7 +302,8 @@ def _serve_texts(cfg):
         TransformerLM, init_kv_cache, prefill_chunk_tokens, transformer_decode, transformer_prefill_chunked,
     )
 
-    params = meta.unbox(TransformerLM(cfg).init(jax.random.key(1), jnp.zeros((1, 8), jnp.int32)))["params"]
+    # shapes alone: the programs are lowered and compiled, never run
+    params = meta.unbox(jax.eval_shape(TransformerLM(cfg).init, jax.random.key(1), jnp.zeros((1, 8), jnp.int32)))["params"]
     cache = init_kv_cache(cfg, 16, 8)
     lanes, width = 4, 8
     tables = jnp.asarray(np.arange(1, 1 + lanes * width).reshape(lanes, width) % 16, jnp.int32)

@@ -6,8 +6,7 @@ over experts, no import from the program).  CPU, tiny sizes, seeded weights;
 Pallas kernels in interpret mode."""
 
 import dataclasses
-import importlib.util
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,11 +29,9 @@ from determined_tpu.models.transformer import (
     transformer_prefill_chunked,
 )
 from determined_tpu.ops import grouped_matmul as gm, paged_attention as paged
+from tests.model_cases import reference_module
 
-_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "reference", "deepseek_mla_moe.py")
-_spec = importlib.util.spec_from_file_location("reference_deepseek_mla_moe", _REF)
-reference = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(reference)
+reference = reference_module("deepseek_mla_moe")
 
 ROPE_SCALING = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 16, "beta_fast": 32, "beta_slow": 1,
                 "mscale": 1.0, "mscale_all_dim": 1.0}
@@ -63,7 +60,7 @@ def tiny(**kw) -> TransformerConfig:
 def build(cfg, seed=1, bias_scale=20.0):
     """The program's own initialiser; the selection bias made large enough
     (0.2 against sigmoid scores near 0.5) that it changes picks."""
-    params = meta.unbox(TransformerLM(cfg).init(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = meta.unbox(jax.jit(TransformerLM(cfg).init)(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)))["params"]
     for name, block in params.items():
         if "moe" in block:
             block["moe"]["router_bias"] = block["moe"]["router_bias"] * bias_scale
@@ -86,10 +83,8 @@ def model():
     cfg = tiny()
     params = build(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.key(0), (2, 40), 1, cfg.vocab_size))
-    want = np.stack([
-        np.asarray(reference.forward(reference_weights(params, cfg), jnp.asarray(row), first_expert=4, **NUMERICS))
-        for row in tokens
-    ])
+    forward = jax.jit(functools.partial(reference.forward, first_expert=4, **NUMERICS))
+    want = np.stack([np.asarray(forward(reference_weights(params, cfg), jnp.asarray(row))) for row in tokens])
     return cfg, params, tokens, want
 
 
@@ -106,7 +101,7 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
     assert shapes == {"wq_a": (64, 24), "q_norm": (24,), "wq_b": (24, 4, 24), "wkv_a": (64, 40), "kv_norm": (32,),
                       "wkv_b": (32, 4, 32), "wo": (4, 16, 64)}
     assert params["block_1"]["moe"]["router_bias"].shape == (16,) and params["block_1"]["moe"]["shared_w_gate"].shape == (64, 32)
-    got = TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens))
+    got = jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens))
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-4)
     # bfloat16 leaves are made as such, and the forward still runs on them
     half = dataclasses.replace(cfg, param_dtype=jnp.bfloat16)
@@ -125,24 +120,26 @@ def test_prefill_then_decode_through_the_latent_pool_match_the_reference(model, 
     assert kv_bytes_per_token(cfg) == 3 * 40 * 4
     tables = jnp.asarray([list(range(1, 9)), list(range(9, 17))], jnp.int32)
     lens = jnp.asarray([20, 24], jnp.int32)
-    logits, cache = transformer_prefill(cfg, params, jnp.asarray(tokens[:, :32]), lens, tables, cache)
+    # one program a shape: called bare, the step is compiled an operation at a time
+    decode = jax.jit(functools.partial(transformer_decode, cfg), static_argnames=("chunk_blocks", "counters"))
+    logits, cache = jax.jit(functools.partial(transformer_prefill, cfg))(params, jnp.asarray(tokens[:, :32]), lens, tables, cache)
     np.testing.assert_allclose(np.asarray(logits[0, :20]), want[0, :20], atol=2e-4)
     np.testing.assert_allclose(np.asarray(logits[1, :24]), want[1, :24], atol=2e-4)
     chunk = 0 if form == "table" else 1
     for step in range(8):
         pos = jnp.asarray([20 + step, 24 + step], jnp.int32)
         tok = jnp.asarray([tokens[0, 20 + step], tokens[1, 24 + step]], jnp.int32)
-        out, cache = transformer_decode(cfg, params, tok, pos, tables, cache, chunk_blocks=chunk, counters=True)
+        out, cache = decode(params, tok, pos, tables, cache, chunk_blocks=chunk, counters=True)
         assert out.shape == (3, cfg.vocab_size)
         np.testing.assert_allclose(np.asarray(out[0]), want[0, 20 + step], atol=3e-4)
         np.testing.assert_allclose(np.asarray(out[1]), want[1, 24 + step], atol=3e-4)
         held, hit = (float(v) for v in out[2, :2])
         assert len(SERVE_COUNTERS) == 2 and 0 <= hit <= min(held, 8) and held <= 2 * 2 * TOP_K and not np.any(np.asarray(out[2, 2:]))
     # an idle lane takes no expert's rows, and its logits are nobody's
-    out, _ = transformer_decode(cfg, params, tok, jnp.asarray([28, -1], jnp.int32), tables, cache, chunk_blocks=1, counters=True)
-    alone, _ = transformer_decode(cfg, params, tok[:1], jnp.asarray([28], jnp.int32), tables[:1], cache, chunk_blocks=1, counters=True)
+    out, _ = decode(params, tok, jnp.asarray([28, -1], jnp.int32), tables, cache, chunk_blocks=1, counters=True)
+    alone, _ = decode(params, tok[:1], jnp.asarray([28], jnp.int32), tables[:1], cache, chunk_blocks=1, counters=True)
     assert float(out[2, 0]) == float(alone[1, 0])
-    assert transformer_decode(cfg, params, tok, pos, tables, cache, chunk_blocks=1)[0].shape == (2, cfg.vocab_size)
+    assert decode(params, tok, pos, tables, cache, chunk_blocks=1)[0].shape == (2, cfg.vocab_size)
 
 
 @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-6), (jnp.bfloat16, 4e-3)], ids=["f32", "bf16"])
@@ -178,11 +175,12 @@ def test_suffix_prefill_from_a_shared_prefix_matches_the_reference_and_a_cold_st
     cfg, params, tokens, want = model
     tables = jnp.asarray([[1, 2, 3, 4, 5, 0, 0, 0], [6, 7, 8, 9, 10, 0, 0, 0]], jnp.int32)
     lens = jnp.asarray([37, 40], jnp.int32)
-    cold, cache = transformer_prefill_chunked(cfg, params, jnp.asarray(tokens), jnp.zeros(2, jnp.int32), lens, tables, init_kv_cache(cfg, 16, 8))
+    walk = jax.jit(functools.partial(transformer_prefill_chunked, cfg))
+    cold, cache = walk(params, jnp.asarray(tokens), jnp.zeros(2, jnp.int32), lens, tables, init_kv_cache(cfg, 16, 8))
     np.testing.assert_allclose(np.asarray(cold[0]), want[0, 36], atol=3e-4)
     np.testing.assert_allclose(np.asarray(cold[1]), want[1, 39], atol=3e-4)
     # the first 16 and 24 tokens already sit in the pool: only the rest is computed, to the same logits
-    warm, _ = transformer_prefill_chunked(cfg, params, jnp.asarray(tokens), jnp.asarray([16, 24], jnp.int32), lens, tables, cache)
+    warm, _ = walk(params, jnp.asarray(tokens), jnp.asarray([16, 24], jnp.int32), lens, tables, cache)
     np.testing.assert_array_equal(np.asarray(warm), np.asarray(cold))
 
 
@@ -195,7 +193,7 @@ def long_model():
     cfg = tiny(max_seq_len=768)
     params = build(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.key(4), (1, 768), 1, cfg.vocab_size), np.int32)
-    full = np.asarray(TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens)))[0]
+    full = np.asarray(jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens)))[0]
     tables = jnp.arange(1, 97, dtype=jnp.int32)[None, :]
     sentinel = get_retrace_sentinel()
     walk = jax.jit(sentinel.wrap(

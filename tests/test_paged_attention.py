@@ -24,6 +24,7 @@ from determined_tpu.models.transformer import (
     transformer_prefill,
 )
 from determined_tpu.ops import paged_attention as pa
+from tests.model_cases import causal_forward
 
 # lanes of the ragged batch, by what each one pins (block_size 16, table 6):
 # empty; position 0; last slot of a block (the walk ends exactly on a block
@@ -179,7 +180,7 @@ def _lm(n_heads, n_kv_heads, head_dim, dtype, seed=0):
     )
     model = TransformerLM(cfg)
     variables = flax_meta.unbox(
-        model.init(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))
+        jax.jit(model.init)(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))
     )
     return cfg, model, variables
 
@@ -197,6 +198,9 @@ def _decode_steps(cfg, params, prompts, block_size, chunk_blocks, steps=2):
     per-step logits ``[steps, lanes, vocab]`` and the tokens fed."""
     t = 3
     lanes = len(prompts) + 1
+    # one program each: called bare, the forward is compiled an operation at a time, anew at every case's shapes
+    prefill = jax.jit(functools.partial(transformer_prefill, cfg))
+    decode = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=chunk_blocks))
     cache = init_kv_cache(cfg, num_blocks=1 + lanes * t, block_size=block_size)
     tables = np.zeros((lanes, t), np.int32)
     toks = np.zeros(lanes, np.int32)
@@ -205,9 +209,7 @@ def _decode_steps(cfg, params, prompts, block_size, chunk_blocks, steps=2):
         tables[lane] = 1 + lane * t + np.arange(t) - t
         padded = np.zeros((1, t * block_size), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits, cache = transformer_prefill(
-            cfg, params, padded, jnp.asarray([len(prompt)]), tables[lane][None], cache
-        )
+        logits, cache = prefill(params, padded, jnp.asarray([len(prompt)]), tables[lane][None], cache)
         toks[lane] = int(np.argmax(np.asarray(logits[0, len(prompt) - 1])))
         poss[lane] = len(prompt)
     out, fed = [], []
@@ -215,10 +217,7 @@ def _decode_steps(cfg, params, prompts, block_size, chunk_blocks, steps=2):
         # a lane at the table's full width has no slot left: it retires
         poss = np.where(poss >= t * block_size, -1, poss)
         fed.append((toks.copy(), poss.copy()))
-        logits, cache = transformer_decode(
-            cfg, params, jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(tables),
-            cache, chunk_blocks=chunk_blocks,
-        )
+        logits, cache = decode(params, jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(tables), cache)
         out.append(np.asarray(logits))
         toks = np.where(poss >= 0, np.argmax(out[-1], axis=-1), 0).astype(np.int32)
         poss = np.where(poss >= 0, poss + 1, -1).astype(np.int32)
@@ -259,11 +258,13 @@ def test_paged_decode_matches_full_gather_and_full_forward(
         live = poss >= 0
         np.testing.assert_allclose(paged[step][live], full[step][live], atol=3e-5, rtol=3e-4)
     # each lane's last live step against the full-sequence forward of its
-    # prompt and the tokens the decode fed it
+    # prompt and the tokens the decode fed it (on one padded width: the
+    # forward is causal, what follows a position cannot move it)
+    forward = causal_forward(model, 3 * block_size + len(fed))
     for lane, prompt in enumerate(prompts, start=1):
         live_steps = [i for i, (_, poss) in enumerate(fed) if poss[lane] >= 0]
         seq = list(prompt) + [int(fed[i][0][lane]) for i in live_steps]
-        want = model.apply(variables, jnp.asarray(seq, jnp.int32)[None, :])[0, -1]
+        want = forward(variables, seq)[-1]
         np.testing.assert_allclose(
             paged[live_steps[-1]][lane], np.asarray(want), atol=3e-5, rtol=3e-4
         )

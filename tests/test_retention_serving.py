@@ -7,8 +7,6 @@ sizes, seeded weights; the Pallas kernel in interpret mode."""
 
 import dataclasses
 import functools
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +14,6 @@ import numpy as np
 import pytest
 from flax.core import meta
 
-from determined_tpu.models import transformer as tx
 from determined_tpu.models.transformer import (
     RETENTION,
     SERVE_STATE_COUNTERS,
@@ -34,11 +31,9 @@ from determined_tpu.models.transformer import (
 from determined_tpu.ops import retention
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import DecodeKernels, ServeEngine
+from tests.model_cases import reference_module, retention_chunk_step as _chunk_step, retention_heads as _heads
 
-_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "reference", "power_retention.py")
-_spec = importlib.util.spec_from_file_location("reference_power_retention", _REF)
-reference = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(reference)
+reference = reference_module("power_retention")
 
 LAYERS, GATE_BIAS = 2, 3.0
 
@@ -54,7 +49,7 @@ def tiny(**kw) -> TransformerConfig:
 
 
 def build(cfg, seed=1):
-    params = meta.unbox(TransformerLM(cfg).init(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)))["params"]
+    params = meta.unbox(jax.jit(TransformerLM(cfg).init)(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)))["params"]
     # norms away from one, so that a norm the program skipped or ran twice shows
     leaves = [params["ln_f"]] + [params[f"block_{i}"][n] for i in range(cfg.n_layers) for n in ("ln1", "ln2")]
     for i, leaf in enumerate(leaves):
@@ -108,12 +103,6 @@ def test_phi_of_x_dot_phi_of_y_is_x_dot_y_squared(d):
     np.testing.assert_allclose(np.asarray(got), np.asarray(jnp.sum(x * y, -1) ** 2), rtol=2e-5, atol=1e-5)
 
 
-def _heads(seed, b=2, h=4, g=2, s=24, d=16, bias=2.0):
-    ks = jax.random.split(jax.random.key(seed), 4)
-    q, k, v = (jax.random.normal(ks[i], (b, n, s, d), jnp.float32) for i, n in enumerate((h, g, g)))
-    return q, k, v, jax.nn.log_sigmoid(bias + jax.random.normal(ks[3], (b, g, s)))
-
-
 @pytest.mark.parametrize("chunk", [1, 5, 8, 24])
 def test_chunks_that_carry_a_state_give_the_quadratic_form(chunk):
     q, k, v, log_g = _heads(3)
@@ -125,7 +114,7 @@ def test_chunks_that_carry_a_state_give_the_quadratic_form(chunk):
         hi = min(lo + chunk, s)
         pad = lambda t: jnp.pad(t[:, :, lo:hi], ((0, 0), (0, 0), (0, chunk - (hi - lo))) + ((0, 0),) * (t.ndim - 3))  # noqa: E731
         valid = jnp.broadcast_to(jnp.arange(chunk) < hi - lo, (b, chunk))
-        out, state, norm = retention.retention_chunk(pad(q), pad(k), pad(v), pad(log_g), state, norm, valid)
+        out, state, norm = _chunk_step("jnp")(pad(q), pad(k), pad(v), pad(log_g), state, norm, valid)
         outs.append(out[:, :, : hi - lo])
     np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=2)), np.asarray(want), atol=2e-5)
 
@@ -140,7 +129,7 @@ def test_a_token_at_a_time_gives_the_quadratic_form_and_the_chunks_state():
         out, state, norm = retention.retention_decode(q[:, :, t], k[:, :, t], v[:, :, t], log_g[:, :, t], state, norm, 1, jnp.ones(b, bool))
         outs.append(out)
     np.testing.assert_allclose(np.asarray(jnp.stack(outs, axis=2)), np.asarray(want), atol=2e-4, rtol=2e-3)
-    _, whole, whole_norm = retention.retention_chunk(q, k, v, log_g, jnp.zeros(shapes[0][1:]), jnp.zeros(shapes[1][1:]), jnp.ones((b, s), bool))
+    _, whole, whole_norm = _chunk_step("jnp")(q, k, v, log_g, jnp.zeros(shapes[0][1:]), jnp.zeros(shapes[1][1:]), jnp.ones((b, s), bool))
     np.testing.assert_allclose(np.asarray(state[1]), np.asarray(whole), atol=2e-5)
     np.testing.assert_allclose(np.asarray(norm[1]), np.asarray(whole_norm), atol=2e-5)
     assert not np.asarray(state[0]).any() and not np.asarray(norm[0]).any()          # the other layer's slots: untouched
@@ -172,48 +161,6 @@ def test_the_kernel_in_interpret_mode_is_its_jnp_form(n_rep, state_dtype):
         retention.retention_decode(q[..., :64], k[..., :64], v[..., :64], log_g, state, norm, 0, live, impl="kernel")
 
 
-@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("n_rep", [1, 5])
-def test_the_chunk_kernel_in_interpret_mode_is_its_jnp_form(n_rep, state_dtype):
-    """Two rows of 16 tokens (the second holds 11) against a state that holds
-    something: what the chunk is answered, and the state and the normaliser after it."""
-    b, g, s, d = 2, 2, 16, 128
-    ks = jax.random.split(jax.random.key(10 + n_rep), 6)
-    unit = lambda x: (x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True))).astype(jnp.bfloat16)  # noqa: E731  (as after the norm a head)
-    q, k = unit(jax.random.normal(ks[0], (b, g * n_rep, s, d))), unit(jax.random.normal(ks[1], (b, g, s, d)))
-    v = jax.random.normal(ks[2], (b, g, s, d), jnp.bfloat16)
-    log_g = jax.nn.log_sigmoid(4.0 + jax.random.normal(ks[3], (b, g, s)))
-    shapes = retention.state_shapes(1, b, g, d)
-    state = jax.random.normal(ks[4], shapes[0][1:]).astype(state_dtype)
-    norm = (1.0 + jnp.abs(jax.random.normal(ks[5], shapes[1][1:]))).astype(state_dtype)
-    valid = jnp.arange(s)[None, :] < jnp.asarray([s, 11])[:, None]
-    want = retention.retention_chunk(q, k, v, log_g, state, norm, valid, impl="jnp")
-    got = retention.retention_chunk(q, k, v, log_g, state, norm, valid, impl="kernel_interpret")
-    tol = 2e-3 if state_dtype == jnp.float32 else 0.15
-    for a, c in zip(got, want):
-        assert a.dtype == c.dtype and a.shape == c.shape
-    keep = np.asarray(valid)[:, None, :, None]                                         # what a token that does not exist is answered is not read
-    np.testing.assert_allclose(np.where(keep, np.asarray(got[0]), 0.0), np.where(keep, np.asarray(want[0]), 0.0), atol=tol, rtol=1e-4)
-    for a, c in zip(got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(c, np.float32), atol=tol, rtol=1e-2 if state_dtype != jnp.float32 else 1e-5)
-    with pytest.raises(ValueError, match="chunk kernel does not take"):
-        retention.retention_chunk(q[:, :, :12], k[:, :, :12], v[:, :, :12], log_g[:, :, :12], state, norm, valid[:, :12], impl="kernel")
-
-
-@pytest.mark.parametrize("chunk", [8, 24])
-def test_chunks_that_carry_a_state_through_the_kernel_give_the_quadratic_form(chunk):
-    q, k, v, log_g = _heads(6, b=1, h=2, g=1, s=24, d=128)
-    q, k = (t / jnp.sqrt(jnp.mean(t * t, axis=-1, keepdims=True)) for t in (q, k))
-    want = retention.retention_quadratic(q, k, v, log_g)
-    shapes = retention.state_shapes(1, 1, 1, 128)
-    state, norm, outs = jnp.zeros(shapes[0][1:]), jnp.zeros(shapes[1][1:]), []
-    for lo in range(0, 24, chunk):
-        part = lambda t: t[:, :, lo:lo + chunk]  # noqa: E731
-        out, state, norm = retention.retention_chunk(part(q), part(k), part(v), part(log_g), state, norm, jnp.ones((1, chunk), bool), impl="kernel_interpret")
-        outs.append(out)
-    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=2)), np.asarray(want), atol=2e-4, rtol=2e-3)
-
-
 # ---------------------------------------------------------------------------
 # the block as published, and the program against the reference
 # ---------------------------------------------------------------------------
@@ -226,7 +173,7 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
         "wq": (48, 4, 16), "wk": (48, 2, 16), "wv": (48, 2, 16), "wg": (48, 2), "q_norm": (16,), "k_norm": (16,), "wo": (4, 16, 48),
     }
     assert cfg.retention_layers == (0, 1) and cfg.paged_layers == 0 and [cfg.cache_index(i) for i in range(2)] == [0, 1]
-    got = TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens))
+    got = jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens))
     np.testing.assert_allclose(np.asarray(got), want, atol=5e-5)
     half = dataclasses.replace(cfg, param_dtype=jnp.bfloat16)
     leaves = jax.tree_util.tree_leaves(jax.eval_shape(lambda: TransformerLM(half).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
@@ -331,7 +278,7 @@ def test_a_model_that_mixes_retention_with_full_layers_serves_from_a_cache_of_bo
     cfg = tiny(layer_types=(RETENTION, "full_attention"), qk_norm=False)
     params = build(cfg)
     tokens = np.asarray(jax.random.randint(jax.random.key(7), (1, 308), 1, cfg.vocab_size))
-    want = np.asarray(TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens)))[0]
+    want = np.asarray(jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens)))[0]
     block, n = 4, 300
     table = jnp.arange(1, 512 // block + 1, dtype=jnp.int32)[None, :]              # block 0 is the scratch block
     cache = init_kv_cache(cfg, 512 // block + 1, block, lanes=2)

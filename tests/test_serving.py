@@ -33,6 +33,7 @@ from determined_tpu.serve import (
     ServeWorker,
 )
 from determined_tpu.serve.scheduler import ActiveSeq, GenRequest
+from tests.model_cases import causal_forward
 
 pytestmark = [pytest.mark.lock_order, pytest.mark.no_thread_leaks]
 
@@ -305,7 +306,7 @@ def lm_setup():
 
     model = TransformerLM(cfg)
     variables = flax_meta.unbox(
-        model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+        jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
     )
     return cfg, model, variables
 
@@ -346,9 +347,9 @@ def test_generate_greedy_matches_full_forward(engine, lm_setup):
     req = engine.generate(prompt, max_new_tokens=6)
     assert req.error is None and len(req.output) == 6
     seq = list(prompt)
+    forward = causal_forward(model, 16)
     for tok in req.output:
-        logits = model.apply(variables, jnp.asarray(seq, jnp.int32)[None, :])
-        assert tok == int(np.argmax(np.asarray(logits[0, -1])))
+        assert tok == int(np.argmax(np.asarray(forward(variables, seq)[-1])))
         seq.append(tok)
 
 
@@ -751,11 +752,10 @@ def test_a_prefill_costs_its_prompts_chunks_and_says_so(lm_setup, tracer):
                 assert eng.step_once()
     finally:
         eng.stop()
-    model = TransformerLM(cfg)
+    forward = causal_forward(TransformerLM(cfg), CHUNKED_CFG.max_prompt_len)
     for prompt, req in zip(prompts, reqs):
         assert req.error is None
-        full = model.apply(variables, jnp.asarray(prompt, jnp.int32)[None, :])
-        assert req.output == [int(np.argmax(np.asarray(full[0, -1])))]
+        assert req.output == [int(np.argmax(np.asarray(forward(variables, prompt)[-1])))]
     spans = {e["args"]["request"]: e["args"] for e in _spans(tracer, "serve.prefill")}
     asked = computed = 0
     for prompt, req in zip(prompts, reqs):

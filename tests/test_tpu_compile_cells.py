@@ -1,0 +1,342 @@
+"""What the TPU's own compiler says of the serving cells' programs — no chip
+(the why and the how: tests/test_tpu_compile.py, which keeps the training
+kernels, ``dtpu serve``'s default programs and the decode step's pool).  Each
+case is one compile at a cell's published widths, its lanes, pool and table,
+depth alone cut: DeepSeek-V3's walk, decode program and latent kernel,
+InternLM2's walk and decode kernel, Command A+'s programs and window kernel,
+Brumby's programs and retention kernel.  Cut from that file because the
+heaviest compile of the suite is here (the Brumby walk's two chunk kernels,
+65 unrolled feature rows each), and ``--dist loadfile`` balances by the file."""
+
+import functools
+import importlib
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tests.model_cases import (  # noqa: F401  (fixture reuse)
+    compile_text as _compile,
+    mosaic_calls as _kernels,
+    paged_mod,
+    real_kernels_no_cache,
+    rows_mod,
+    tpu_devices,
+)
+
+
+def _arrays_with_dims(text: str, dims) -> list:
+    """Array shapes of an optimized HLO module (results and operands alike,
+    inside fusions too) that have every one of ``dims`` among their dimensions."""
+    found = set()
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        shape = [int(d) for d in m.group(1).split(",")]
+        if all(shape.count(d) >= list(dims).count(d) for d in dims):
+            found.add(m.group(0))
+    return sorted(found)
+
+
+def _walk_compiled(one, cfg, *, num_blocks, max_prompt_len, table_width):
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_prefill_chunked
+
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shape = kv_cache_shape(cfg, num_blocks, 16)
+    cache = {"kv": aval(shape, cfg.dtype)} if cfg.latent else {"k": aval(shape, cfg.dtype), "v": aval(shape, cfg.dtype)}
+    assert prefill_chunk_tokens(16, max_prompt_len) == 256 and max_prompt_len % 256 == 0
+    fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,))
+    return fn.lower(params, aval((1, max_prompt_len)), aval((1,)), aval((1,)), aval((1, table_width)), cache).compile()
+
+
+def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(tpu_devices, monkeypatch):
+    """The walk at DeepSeek-V3's published widths, the cell's pool, table and
+    ``max_prompt_len`` 4,096, depth cut to the dense layer and one expert
+    layer: its scratch is a fraction of the wide pass's (1.32 GiB at this
+    depth, 1.35 at the cell's five layers: PERF.md section 4), and no array
+    anywhere in it has heads x chunk x ``max_seq_len`` (a chunk's scores
+    against the whole table: 0.94 GB a layer); a tile's [128, 256, 256] is
+    the largest the attention builds."""
+    from determined_tpu.models.transformer import TransformerConfig
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    cfg = TransformerConfig(
+        vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        softmax_scale=0.135234, dense_prefix=1, moe_experts=256, moe_every=1, moe_top_k=8, moe_intermediate_size=2048,
+        moe_experts_held=(0, 16), moe_router="sigmoid_grouped", moe_n_group=8, moe_topk_group=4,
+        moe_routed_scaling=2.5, moe_shared_experts=1, param_dtype=jnp.bfloat16,
+        rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 40, "beta_fast": 32,
+                                            "original_max_position_embeddings": 4096, "beta_slow": 1, "attention_factor": 1.0}},
+    )
+    compiled = _walk_compiled(SingleDeviceSharding(tpu_devices[0]), cfg, num_blocks=24576, max_prompt_len=4096, table_width=448)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5 * 1024**3
+    assert mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2  # the pool is donated
+    assert _arrays_with_dims(text, (128, 256, 7168)) == [] and _arrays_with_dims(text, (128, 256, 4096)) == []
+    assert _arrays_with_dims(text, (128, 256, 256)) != []
+    assert _kernels(text) == 5  # the expert layer's two row movements and three grouped products
+
+
+def test_the_prefill_walk_at_internlm2s_widths_keeps_no_second_copy_of_the_model(tpu_devices):
+    """InternLM2-1.8B's widths and float32 leaves, the decode cell's pool,
+    table and ``max_prompt_len`` 1,280, 6 of 24 layers: the leaves'
+    conversions stay inside the loop (moved before it they are a bfloat16
+    copy of every layer, 126 MB a layer: 1.29 GiB of scratch here, 3.3 at
+    full depth, against 0.56 and 1.20), and no array has heads x chunk x
+    ``max_seq_len``."""
+    from determined_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=92544, d_model=2048, n_layers=6, n_heads=16, n_kv_heads=8, d_ff=8192, max_seq_len=2048, rope_theta=1e6,
+    )
+    compiled = _walk_compiled(SingleDeviceSharding(tpu_devices[0]), cfg, num_blocks=3500, max_prompt_len=1280, table_width=128)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.8 * 1024**3
+    assert _arrays_with_dims(text, (16, 256, 2048)) == [] and _arrays_with_dims(text, (2, 256, 2048)) == []
+    entry = text[text.index("ENTRY "):]
+    assert not re.search(r"= bf16\[(2048,8192|8192,2048)\]\S* (convert|fusion)\(", entry)
+
+
+# -- latent attention and served experts: the DeepSeek-V3 cell's shapes --------
+
+
+def test_the_latent_decode_kernel_compiles_at_the_dsv3_cells_shape(tpu_devices):
+    """64 lanes x 128 heads against ONE 640-wide row a token (576 values
+    and 64 zeros), blocks of 16, a table of 448 columns (7,168 positions), a
+    pool of 24,576 blocks x 5 layers: tiles of 512 tokens, two buffers."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, pool, tables, positions):
+        return paged_mod.paged_latent_attention(q, pool, 3, tables, positions, scale=0.135, value_dim=512)
+
+    text = _compile(
+        fn, aval((64, 128, 640), jnp.bfloat16), aval((5, 24576, 16, 640), jnp.bfloat16),
+        aval((64, 448), jnp.int32), aval((64,), jnp.int32),
+    )
+    assert _kernels(text) == 1 and "paged_latent_attention" in text
+    assert paged_mod.latent_kernel_takes(640, 512, 16, jnp.bfloat16) and not paged_mod.latent_kernel_takes(576, 512, 16, jnp.bfloat16)
+
+
+def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, monkeypatch):
+    """The decode program of the cell at its widths, lanes and pool, bfloat16
+    leaves, depth cut to the dense layer and one expert layer: the latent
+    kernel a layer, and in the expert layer the two row movements and three
+    grouped products over [7168, 2048] blocks (58.7 MB of VMEM for a block's
+    two buffers).  Each Mosaic call keeps its name in the optimized program,
+    so the scopes a trace's reader asks for list it."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, transformer_decode
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        softmax_scale=0.135234, dense_prefix=1, moe_experts=256, moe_every=1, moe_top_k=8, moe_intermediate_size=2048,
+        moe_experts_held=(0, 16), moe_router="sigmoid_grouped", moe_n_group=8, moe_topk_group=4,
+        moe_routed_scaling=2.5, moe_shared_experts=1, param_dtype=jnp.bfloat16,
+        rope_parameters={"full_attention": {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 40, "beta_fast": 32,
+                                            "original_max_position_embeddings": 4096, "beta_slow": 1, "attention_factor": 1.0}},
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cache = {"kv": aval(kv_cache_shape(cfg, 24576, 16), cfg.dtype)}
+    fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+    compiled = fn.lower(params, aval((64,)), aval((64,)), aval((64, 448)), cache).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 2 + 5
+    assert mem.temp_size_in_bytes < 256 * 1024**2 and mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2   # the pool is donated
+    scopes = program_scopes(text)
+    assert {"serve.mla", "serve.mla.attend", "serve.moe.route", "serve.moe.experts", "serve.moe.shared"} <= set(scopes)
+    assert sum("paged_latent_attention" in n for n in scopes["serve.mla.attend"]) == 2
+    named = [n for n in scopes["serve.moe.experts"] if re.match(r"(moe_gmm|moe_rows_of_tokens|moe_tokens_of_rows)", n)]
+    assert len(named) == 5, scopes["serve.moe.experts"]
+
+
+# -- sliding-window layers served from a ring a lane: the Command A+ cell's shapes --
+
+
+def test_the_window_decode_kernel_compiles_at_the_command_cells_shape(tpu_devices):
+    """32 lanes x 128 query heads over 8 KV heads of 128, blocks of 16, a ring
+    of 272 blocks (4,352 tokens) a lane in a store of three window layers:
+    tiles of 256 tokens through the ring, the window's 4,096 newest alone."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, k_pool, v_pool, tables, positions):
+        return paged_mod.paged_decode_attention(q, k_pool, v_pool, 2, tables, positions, scale=128 ** -0.5, window=4096)
+
+    store = aval((3, 32 * 272, 16, 1024), jnp.bfloat16)
+    text = _compile(fn, aval((32, 128, 128), jnp.bfloat16), store, store, aval((32, 272), jnp.int32), aval((32,), jnp.int32))
+    assert _kernels(text) == 1 and "paged_window_attention" in text
+    # 16 query heads a KV head: the kernel multiplies a KV head's own queries, and no block-diagonal query is built
+    assert paged_mod.attn_products(16) == "per_kv_head" and _arrays_with_dims(text, (32, 128, 1024)) == []
+
+
+@pytest.mark.parametrize(
+    "heads, pool_shape, products",
+    [(16, (24, 3500, 16, 1024), "block_diagonal"), (64, (1, 24576, 16, 1024), "per_kv_head")],
+    ids=["internlm2-cell", "8-a-kv-head"],
+)
+def test_the_decode_kernel_compiles_in_both_layouts_of_its_products(tpu_devices, heads, pool_shape, products):
+    """The InternLM2 cells' shape: 32 lanes x 16 query heads over 8 KV heads of
+    128 against a bfloat16 pool of 3,500 blocks x 24 layers, where 2 query heads
+    a KV head keep the block-diagonal query (16 rows against the tile's 1,024
+    columns); and 8 query heads a KV head, half a packed tile of the bfloat16
+    query each, where the rule turns (1.60 -> 1.44 us a tile: PERF.md, PR 42)."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+
+    def fn(q, k_pool, v_pool, tables, positions):
+        return paged_mod.paged_decode_attention(q, k_pool, v_pool, 0, tables, positions, scale=128 ** -0.5)
+
+    pool = aval(pool_shape, jnp.bfloat16)
+    text = _compile(fn, aval((32, heads, 128), jnp.bfloat16), pool, pool, aval((32, 128), jnp.int32), aval((32,), jnp.int32))
+    assert _kernels(text) == 1 and "paged_decode_attention" in text
+    assert paged_mod.attn_products(heads // 8) == products
+    assert bool(_arrays_with_dims(text, (32, heads, 1024))) == (products == "block_diagonal")
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_devices, monkeypatch, which):
+    """The cell's decode step and prefill walk at its widths, lanes, pool and
+    window store, bfloat16 leaves, depth cut to one window layer and the full
+    layer: weights, both kinds of cache and the program's scratch fit the chip;
+    the window layer's kernel keeps its name under its own scope, the full
+    layer's under the other; nothing the size of a lane's context is gathered."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_decode,
+        transformer_prefill_chunked, window_store_shape,
+    )
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=2, n_heads=128, n_kv_heads=8, head_dim=128, max_seq_len=20480,
+        layer_types=("sliding_attention", "full_attention"), sliding_window=4096,
+        rope_parameters={"full_attention": {"rope_type": "none"}, "sliding_attention": {"rope_type": "default", "rope_theta": 50000.0}},
+        moe_experts=128, moe_every=1, moe_top_k=8, moe_intermediate_size=4096, moe_experts_held=(0, 16), moe_router="sigmoid",
+        moe_shared_experts=4, moe_shared_combine="mean", norm="layernorm", norm_eps=1e-5, parallel_block=True,
+        tie_embeddings=True, param_dtype=jnp.bfloat16,
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    assert prefill_chunk_tokens(16, 14336) == 256
+    pool, ring = kv_cache_shape(cfg, 24576, 16), window_store_shape(cfg, 32, 16, 256)
+    assert pool == (1, 24576, 16, 1024) and ring == (1, 32 * 272, 16, 1024)
+    cache = {"k": aval(pool, cfg.dtype), "v": aval(pool, cfg.dtype), "wk": aval(ring, cfg.dtype), "wv": aval(ring, cfg.dtype)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((32,)), aval((32,)), aval((32, 1280)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,))
+        args = (params, aval((1, 14336)), aval((1,)), aval((1,)), aval((1, 1280)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache_bytes = 2 * 2 * (24576 + 32 * 272) * 16 * 1024
+    assert mem.alias_size_in_bytes >= cache_bytes                                    # both kinds are donated
+    assert mem.temp_size_in_bytes < (64 if which == "decode" else 1024) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.attn.window", "serve.attn.full", "serve.attn.attend", "serve.kv.write", "serve.moe.route", "serve.moe.experts",
+            "serve.moe.shared"} <= set(scopes)
+    if which == "decode":
+        assert _kernels(text) == 2 + 2 * 5                                          # an attention kernel and five of the experts a layer
+        assert sum("paged_window_attention" in n for n in scopes["serve.attn.window"]) == 1
+        assert sum("paged_decode_attention" in n for n in scopes["serve.attn.full"]) == 1
+        assert not any("paged_" in n for n in set(scopes["serve.attn.window"]) & set(scopes["serve.attn.full"]))
+        assert _arrays_with_dims(text, (32, 20480)) == [] and _arrays_with_dims(text, (32, 4352, 1024)) == []
+    else:
+        assert _kernels(text) == 2 * 5
+        assert _arrays_with_dims(text, (128, 256, 20480)) == [] and _arrays_with_dims(text, (256, 4352)) == []
+
+
+# -- power-retention layers served from a state a lane: the Brumby cell's shapes --
+
+
+def test_the_retention_decode_kernel_compiles_at_the_brumby_cells_shape(tpu_devices):
+    """32 lanes x 40 query heads over 8 KV heads of 128 against a float32 state
+    pool of five layers (8,320 x 128 a head): one kernel, the pools updated
+    where they lie (aliased, no scratch the size of a layer's state)."""
+    retention_mod = importlib.import_module("determined_tpu.ops.retention")
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state, norm = retention_mod.state_shapes(5, 32, 8, 128)
+    assert state == (5, 32, 8, 8320, 128) and norm == (5, 32, 8, 65, 128)
+
+    def fn(q, k, v, log_g, rs, rz, live):
+        return retention_mod.retention_decode(q, k, v, log_g, rs, rz, 3, live)
+
+    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(
+        aval((32, 40, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16),
+        aval((32, 8), jnp.float32), aval(state, jnp.float32), aval(norm, jnp.float32), aval((32,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 1 and "retention_decode" in text
+    pool_bytes = 4 * (math.prod(state) + math.prod(norm))
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 16 * 1024**2
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, which):
+    """The cell's decode step and prefill walk at its widths, lanes and state
+    pool, bfloat16 leaves, depth cut to two layers: weights, the pool and the
+    program's scratch fit the chip; the pool is donated and no second copy of
+    it is held; the kernel keeps its name under its own scope; no array is
+    made for the allocator's block ids."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, state_pool_shapes, transformer_decode, transformer_prefill_chunked,
+    )
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=151936, d_model=5120, n_layers=2, n_heads=40, n_kv_heads=8, head_dim=128, d_ff=17408, max_seq_len=28672,
+        layer_types=("power_retention",) * 2, qk_norm=True, retention_gate_bias=6.0, rope_theta=1e6, param_dtype=jnp.bfloat16,
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state, norm = state_pool_shapes(cfg, 32)
+    cache = {"rs": aval(state, jnp.float32), "rz": aval(norm, jnp.float32)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((32,)), aval((32,)), aval((32, 1792)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg, chunk_tokens=256), donate_argnums=(5,))
+        args = (params, aval((1, 22528)), aval((1,)), aval((1,)), aval((1, 1792)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    pool_bytes = 4 * (math.prod(state) + math.prod(norm))
+    assert pool_bytes == 2 * 32 * 34_344_960
+    assert mem.alias_size_in_bytes >= pool_bytes                                     # the pool is donated
+    # the decode step holds nothing the size of a layer's pool; a chunk of the walk holds its scores and products, and phi of nothing (the kernel builds it in VMEM)
+    assert mem.temp_size_in_bytes < (64 if which == "decode" else 512) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.retention.qkvg", "serve.retention.state", "serve.retention.out", "serve.mlp", "serve.embed", "serve.head"} <= set(scopes)
+    assert "serve.attn.qkv" not in scopes
+    if which == "decode":
+        assert "serve.kv.write" not in scopes
+        assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_decode")}) == 2
+        assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
+    else:                                                                            # the chunk's pass over the state, a layer
+        assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2

@@ -180,6 +180,8 @@ def test_hf_gpt2_trial_learns(tmp_path):
 # (pins the paged cache layout before anything serves from it)
 # ---------------------------------------------------------------------------
 
+import functools  # noqa: E402
+
 import jax.numpy as jnp  # noqa: E402
 
 from determined_tpu.models.transformer import (  # noqa: E402
@@ -188,6 +190,7 @@ from determined_tpu.models.transformer import (  # noqa: E402
     transformer_prefill,
 )
 from determined_tpu.serve.engine import sample_token  # noqa: E402
+from tests.model_cases import causal_forward  # noqa: E402
 
 # bf16 keeps ~8 mantissa bits; logits here are O(1), so 1/32 absolute slack
 # covers the re-associated attention reductions without masking layout bugs
@@ -205,9 +208,20 @@ def _tiny_lm(dtype, n_kv_heads=None, seed=0):
     from flax.core import meta as flax_meta
 
     variables = flax_meta.unbox(
-        model.init(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))
+        jax.jit(model.init)(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))
     )
     return cfg, model, variables
+
+
+def _programs(cfg, model):
+    """The wide prefill, the decode step and the full forward (on one padded
+    width) as ONE program a shape each: called bare they are compiled an
+    operation at a time."""
+    return (
+        jax.jit(functools.partial(transformer_prefill, cfg)),
+        jax.jit(functools.partial(transformer_decode, cfg), static_argnames=("chunk_blocks",)),
+        causal_forward(model, 32),
+    )
 
 
 # f32 decode parity costs ~16-24s per case on the 2-core verify box; the
@@ -231,16 +245,15 @@ def test_decode_matches_full_forward_logits(dtype, n_kv_heads):
     table = np.arange(1, 1 + (32 // block_size), dtype=np.int32)[None, :]
     padded = np.zeros((1, max_prompt), np.int32)
     padded[0, : len(prompt)] = prompt
-    logits_pf, cache = transformer_prefill(
-        cfg, params, padded, jnp.asarray([len(prompt)]), table, cache
-    )
+    prefill, decode, full_forward = _programs(cfg, model)
+    logits_pf, cache = prefill(params, padded, jnp.asarray([len(prompt)]), table, cache)
     tol = _DECODE_TOL[dtype]
 
     # every prompt position's logits match the full forward (causality:
     # the padding after them cannot contribute)
-    full = model.apply(variables, jnp.asarray(prompt, jnp.int32)[None, :])
+    full = full_forward(variables, prompt)
     np.testing.assert_allclose(
-        np.asarray(logits_pf[0, : len(prompt)]), np.asarray(full[0]), **tol
+        np.asarray(logits_pf[0, : len(prompt)]), np.asarray(full), **tol
     )
 
     seq = list(prompt)
@@ -248,13 +261,12 @@ def test_decode_matches_full_forward_logits(dtype, n_kv_heads):
     for _ in range(6):
         seq.append(tok)
         pos = len(seq) - 1
-        logits_dec, cache = transformer_decode(
-            cfg, params, jnp.asarray([tok], jnp.int32),
-            jnp.asarray([pos], jnp.int32), table, cache,
+        logits_dec, cache = decode(
+            params, jnp.asarray([tok], jnp.int32), jnp.asarray([pos], jnp.int32), table, cache,
         )
-        full = model.apply(variables, jnp.asarray(seq, jnp.int32)[None, :])
+        full = full_forward(variables, seq)
         np.testing.assert_allclose(
-            np.asarray(logits_dec[0]), np.asarray(full[0, -1]), **tol
+            np.asarray(logits_dec[0]), np.asarray(full[-1]), **tol
         )
         tok = int(np.argmax(np.asarray(logits_dec[0])))
 
@@ -271,9 +283,8 @@ def test_decode_sampling_matches_full_forward(temperature):
     table = np.arange(1, 9, dtype=np.int32)[None, :]
     padded = np.zeros((1, 8), np.int32)
     padded[0, : len(prompt)] = prompt
-    logits_pf, cache = transformer_prefill(
-        cfg, params, padded, jnp.asarray([len(prompt)]), table, cache
-    )
+    prefill, decode, full_forward = _programs(cfg, model)
+    logits_pf, cache = prefill(params, padded, jnp.asarray([len(prompt)]), table, cache)
 
     rng_dec = np.random.default_rng(7)
     rng_full = np.random.default_rng(7)
@@ -285,9 +296,8 @@ def test_decode_sampling_matches_full_forward(temperature):
     seq = list(prompt)
     for _ in range(5):
         seq.append(tok)
-        logits_dec, cache = transformer_decode(
-            cfg, params, jnp.asarray([tok], jnp.int32),
-            jnp.asarray([len(seq) - 1], jnp.int32), table, cache,
+        logits_dec, cache = decode(
+            params, jnp.asarray([tok], jnp.int32), jnp.asarray([len(seq) - 1], jnp.int32), table, cache,
         )
         tok = sample_token(np.asarray(logits_dec[0]), temperature, rng_dec)
         dec_tokens.append(tok)
@@ -296,8 +306,8 @@ def test_decode_sampling_matches_full_forward(temperature):
     full_tokens = []
     seq = list(prompt)
     for _ in range(6):
-        logits = model.apply(variables, jnp.asarray(seq, jnp.int32)[None, :])
-        tok = sample_token(np.asarray(logits[0, -1]), temperature, rng_full)
+        logits = full_forward(variables, seq)
+        tok = sample_token(np.asarray(logits[-1]), temperature, rng_full)
         full_tokens.append(tok)
         seq.append(tok)
     assert dec_tokens == full_tokens
@@ -307,8 +317,9 @@ def test_decode_inactive_lanes_do_not_disturb_active(devices8):
     """A batch mixing active and empty (-1) lanes produces the same logits
     for the active lane as a batch of one — the scratch-block writes of
     idle lanes must never leak into real sequences."""
-    cfg, _model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=5)
+    cfg, model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=5)
     params = variables["params"]
+    prefill, decode, _ = _programs(cfg, model)
     block_size = 4
     prompt = [9, 8, 7, 6, 5, 4]
 
@@ -318,18 +329,13 @@ def test_decode_inactive_lanes_do_not_disturb_active(devices8):
         tables[0] = np.arange(1, 9)
         padded = np.zeros((1, 8), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits_pf, cache = transformer_prefill(
-            cfg, params, padded, jnp.asarray([len(prompt)]), tables[:1], cache
-        )
+        logits_pf, cache = prefill(params, padded, jnp.asarray([len(prompt)]), tables[:1], cache)
         tok = int(np.argmax(np.asarray(logits_pf[0, len(prompt) - 1])))
         toks = np.zeros(batch_lanes, np.int32)
         poss = np.full(batch_lanes, -1, np.int32)
         toks[0] = tok
         poss[0] = len(prompt)
-        logits_dec, cache = transformer_decode(
-            cfg, params, jnp.asarray(toks), jnp.asarray(poss),
-            jnp.asarray(tables), cache,
-        )
+        logits_dec, cache = decode(params, jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(tables), cache)
         return np.asarray(logits_dec[0])
 
     solo = run(1)
@@ -350,8 +356,9 @@ def test_chunked_decode_matches_full_gather(chunk_blocks):
     equals the full-table gather step for step at f32 tolerance; the
     scratch block is the only cache cell allowed to differ (inactive-lane
     padding writes land there by design)."""
-    cfg, _model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=9)
+    cfg, model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=9)
     params = variables["params"]
+    prefill, decode, _ = _programs(cfg, model)
     block_size = 4
     prompt = [11, 4, 93, 7, 55, 21, 8]
     table = np.arange(1, 9, dtype=np.int32)[None, :]  # 8 blocks = 32 tokens
@@ -360,16 +367,13 @@ def test_chunked_decode_matches_full_gather(chunk_blocks):
         cache = init_kv_cache(cfg, num_blocks=16, block_size=block_size)
         padded = np.zeros((1, 8), np.int32)
         padded[0, : len(prompt)] = prompt
-        logits_pf, cache = transformer_prefill(
-            cfg, params, padded, jnp.asarray([len(prompt)]), table, cache
-        )
+        logits_pf, cache = prefill(params, padded, jnp.asarray([len(prompt)]), table, cache)
         tok = int(np.argmax(np.asarray(logits_pf[0, len(prompt) - 1])))
         outs = []
         for step in range(6):
             pos = len(prompt) + step
-            logits, cache = transformer_decode(
-                cfg, params, jnp.asarray([tok], jnp.int32),
-                jnp.asarray([pos], jnp.int32), table, cache,
+            logits, cache = decode(
+                params, jnp.asarray([tok], jnp.int32), jnp.asarray([pos], jnp.int32), table, cache,
                 chunk_blocks=chunk,
             )
             outs.append(np.asarray(logits[0]))
@@ -419,7 +423,7 @@ def walk_setup():
     params = variables["params"]
     assert tx.prefill_chunk_tokens(WALK_BLOCK, WALK_PAD) == tx.PREFILL_CHUNK_TOKENS == 256
     tokens = np.asarray(jax.random.randint(jax.random.key(2), (1, WALK_PAD), 1, cfg.vocab_size), np.int32)
-    full = np.asarray(TransformerLM(cfg).apply(variables, jnp.asarray(tokens)))[0]
+    full = np.asarray(jax.jit(TransformerLM(cfg).apply)(variables, jnp.asarray(tokens)))[0]
     table = jnp.arange(1, 1 + WALK_PAD // WALK_BLOCK, dtype=jnp.int32)[None, :]
     sentinel = get_retrace_sentinel()
     walk = jax.jit(sentinel.wrap(
