@@ -12,6 +12,14 @@ compiles exactly once per kernel — enforced by wrapping the pre-jit
 callables in the PR-4 RetraceSentinel (``lint/_runtime.py``), the same
 compile-count guard the Trainer runs under.
 
+A decode step's tokens are drawn on the device: ``kernels.decode`` hands
+back the logits where they lie, ONE jitted call (``sample_lanes``) draws
+every lane's token from them with the uniform the host drew from the
+lane's seeded generator, and what crosses to the host a step is the ids and
+the step's counters.  A request's first token, one row from its prefill, is
+sampled on the host (``sample_token``, which is also the oracle the tests
+hold the device's sampler to; ``docs/serving.md`` has the contract of both).
+
 The engine times itself.  One set of ``time.monotonic()`` stamps — a
 handful a step, one a token — is taken always and feeds two sinks: the
 cumulative ``step_seconds`` and the window of recent requests' latencies
@@ -137,14 +145,85 @@ def _first_above(cum: np.ndarray, u: float) -> int:
     return int(np.searchsorted(cum, min(u, np.nextafter(cum[-1], -np.inf)), side="right"))
 
 
+def sample_lanes(logits: Any, temperature: Any, uniform: Any, *, counters: int = 0) -> Tuple[Any, Any]:
+    """``sample_token`` for every lane of a decode step at once, as one
+    program over the logits where the device holds them.
+
+    ``logits`` f32 ``[lanes (+ 1), vocab]`` as ``DecodeKernels.decode``
+    returns them (a device's array or a host's), ``temperature`` and
+    ``uniform`` f32 ``[lanes]``: a lane's ``rng.random()``, drawn on the host.
+    Returns the int32 token of each lane and the first ``counters`` entries
+    of the row after the lanes (the step's ``serve_counters``).
+
+    A lane at temperature <= 0 gets ``argmax``, first index on ties.  Any
+    other gets the inverse-CDF draw ``sample_token`` makes, with float32
+    sums: the maximum comes off before the scaling, the exponentials are
+    summed by blocks of ``_SAMPLE_BLOCK`` (a short last block padded with
+    ``-inf``), the draw is searched first over the blocks' cumulative sum (a
+    scan of logarithmic depth) and then over the cumulative sum inside the
+    block it lands in.  Both searches take the first entry whose cumulative
+    sum exceeds the draw AND which adds something, and the last entry that
+    adds anything where rounding put the draw past the end: an entry of
+    probability zero is never returned.  A lane whose normaliser is not
+    finite or not positive gets ``argmax(nan_to_num(logits))``; so does a
+    greedy lane, which differs from ``np.argmax`` only in never choosing a
+    NaN."""
+    import jax.numpy as jnp
+
+    lanes = temperature.shape[0]
+    x = logits[:lanes]
+    vocab = x.shape[1]
+    blocks = -(-vocab // _SAMPLE_BLOCK)
+    greedy = temperature <= 0.0
+    scale = 1.0 / jnp.where(greedy, 1.0, temperature)
+
+    def first_above(cum: Any, mass: Any, u: Any) -> Any:
+        hit = (cum > u[:, None]) & (mass > 0.0)
+        last = jnp.max(jnp.where(mass > 0.0, jnp.arange(mass.shape[1]), 0), axis=-1)
+        return jnp.where(hit.any(axis=-1), jnp.argmax(hit, axis=-1), last)
+
+    padded = jnp.pad(x, ((0, 0), (0, blocks * _SAMPLE_BLOCK - vocab)), constant_values=-jnp.inf)
+    top = jnp.max(x, axis=-1, keepdims=True)
+    z = jnp.exp((padded - top) * scale[:, None]).reshape(lanes, blocks, _SAMPLE_BLOCK)
+    sums = z.sum(axis=-1)
+    cum = jnp.cumsum(sums, axis=-1)
+    total = cum[:, -1]
+    u = uniform * total
+    block = first_above(cum, sums, u)
+    before = jnp.take_along_axis(cum, jnp.maximum(block - 1, 0)[:, None], axis=1)[:, 0]
+    mass = jnp.take_along_axis(z, block[:, None, None], axis=1)[:, 0]
+    inside = first_above(jnp.cumsum(mass, axis=-1), mass, u - jnp.where(block > 0, before, 0.0))
+    sound = jnp.isfinite(total) & (total > 0.0) & ~greedy
+    fallback = jnp.argmax(jnp.nan_to_num(x, nan=-jnp.inf), axis=-1)
+    ids = jnp.where(sound, block * _SAMPLE_BLOCK + inside, fallback)
+    return ids.astype(jnp.int32), logits[lanes:, :counters].reshape(-1)
+
+
+@functools.lru_cache(maxsize=None)
+def lane_sampler(counters: int = 0) -> Any:
+    """The jitted :func:`sample_lanes` that returns ``counters`` counts, as
+    the engine's step calls it: ``(logits, draws)`` with ``draws`` f32
+    ``[2, lanes]``, the lanes' temperatures over their uniforms (ONE array
+    to the device a step: each costs a third of a millisecond on a v5e's
+    host).  One a process, so that the engine's thread finds the program the
+    kernels' builder loaded (``jit_serve_sample`` in a device trace)."""
+    import jax
+
+    def serve_sample(logits: Any, draws: Any) -> Tuple[Any, Any]:
+        return sample_lanes(logits, draws[0], draws[1], counters=counters)
+
+    return jax.jit(serve_sample)
+
+
 class DecodeKernels:
     """Compiled prefill/decode for one (model cfg, params) pair.
 
     ``prefill`` / ``prefill_suffix`` run one request at a time through ONE
     program, the chunked walk (single trace; its device time follows the
     chunks the prompt asks for); ``decode`` steps all ``max_batch`` lanes at
-    once.  The cache argument is donated: each step writes into the buffers
-    of the previous one instead of copying the pool.
+    once and leaves their logits on the device.  The cache argument is
+    donated: each step writes into the buffers of the previous one instead
+    of copying the pool.
     """
 
     def __init__(self, model_cfg: Any, params: Any, serve_cfg: ServeConfig) -> None:
@@ -260,15 +339,15 @@ class DecodeKernels:
             "serve.setup.kv_pool", "serve", t_pool, t_pooled,
             {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg), **kinds},
         )
-        #: (call, jitted call returned, logits ready, logits on the host) of
-        #: the newest ``decode``: the engine, which knows the step, turns
-        #: them into the ``serve.decode.*`` spans
-        self.last_decode_stamps: Optional[Tuple[float, float, float, float]] = None
-        #: a model with expert or window layers: the decode program returns
-        #: one more row of logits, whose first entries are these counts of the
-        #: step (``transformer_decode``); the newest step's are kept by name
-        self._counters: Tuple[str, ...] = serve_counters(model_cfg)
-        self.last_decode_counters: Dict[str, float] = {}
+        #: (call, jitted call returned, logits ready on the device) of the
+        #: newest ``decode``: the engine, which knows the step, turns them
+        #: into ``serve.decode.dispatch`` and ``serve.decode.wait``
+        self.last_decode_stamps: Optional[Tuple[float, float, float]] = None
+        #: a model with expert, window or retention layers: the decode program
+        #: returns one more row of logits, whose first entries are these
+        #: counts of the step (``transformer_decode``), in this order; the
+        #: engine's sampler hands them back beside the tokens
+        self.counters: Tuple[str, ...] = serve_counters(model_cfg)
         #: the prefill's token width: the longest prompt in whole chunks
         #: (one trace; the walk's trip count follows each prompt)
         self._prompt_pad = serve_cfg.prefill_chunks(serve_cfg.max_prompt_len) * serve_cfg.prefill_chunk
@@ -289,7 +368,7 @@ class DecodeKernels:
                 transformer_decode,
                 model_cfg,
                 chunk_blocks=serve_cfg.decode_chunk_blocks,
-                counters=bool(self._counters),
+                counters=bool(self.counters),
             ),
             allowed=1,
         )
@@ -311,14 +390,18 @@ class DecodeKernels:
         # program out of the cache costs a worker thread 7.3 s where it costs
         # the main thread 0.6 (InternLM2-1.8B on a v5e; PERF.md, PR 35), and
         # a replica that says it is ready has its programs on the device.
-        # Both calls write the scratch block alone: a one-token prompt under
-        # a table of block 0, and a step with every lane idle.
+        # The first two write the scratch block alone: a one-token prompt
+        # under a table of block 0, and a step with every lane idle, whose
+        # logits the engine's sampler (``lane_sampler``) then takes as it
+        # will take a step's.
         self._prefill_from([0], [0] * serve_cfg.blocks_per_seq, 0)
         lanes = serve_cfg.max_batch
-        _, self.cache = self._decode(
+        logits, self.cache = self._decode(
             self.params, np.zeros(lanes, np.int32), np.full(lanes, -1, np.int32),
             np.zeros((lanes, serve_cfg.blocks_per_seq), np.int32), self.cache,
         )
+        sample = timed_first_call(lane_sampler(len(self.counters)), "jit.compile.serve.sample")
+        jax.block_until_ready(sample(logits, np.zeros((2, lanes), np.float32)))
 
     # -- kernel entry points (device round trips happen HERE) ---------------
 
@@ -355,31 +438,43 @@ class DecodeKernels:
         logits, self.cache = self._prefill(*args)
         return np.asarray(logits[0])
 
-    def decode(
-        self, tokens: np.ndarray, positions: np.ndarray, tables: np.ndarray
-    ) -> np.ndarray:
-        """One decode step over every lane; returns f32 logits [B, vocab].
+    def decode(self, tokens: np.ndarray, positions: np.ndarray, tables: np.ndarray) -> Any:
+        """One decode step over every lane; returns the f32 logits
+        ``[B, vocab]`` as the device holds them, ready, and with one more row
+        after the lanes' where the model counts its steps (``counters``).
 
-        The call is stamped where its three parts end — the jitted call
-        returns (enqueued), the logits are ready on the device, they are
-        on the host — and the stamps left in ``last_decode_stamps``.  The
-        copy is queued behind the program at once, as a bare ``np.asarray``
-        has it: waiting for the logits first and only then asking for them
-        cost a host round trip, ~0.5 ms a step on the chip."""
+        Nothing of them is copied to the host here: the engine draws the
+        step's tokens from them on the device (``sample_lanes``), and a
+        caller that wants a row asks for it (``np.asarray(logits[lane])``).
+        The call is stamped where its two parts end — the jitted call returns
+        (enqueued), the logits are ready on the device — and the stamps left
+        in ``last_decode_stamps``: whatever times this call from outside
+        holds the device's whole step."""
         t0 = mono()
         logits, self.cache = self._decode(
             self.params, tokens, positions, tables, self.cache
         )
-        logits.copy_to_host_async()
         t1 = mono()
         logits.block_until_ready()
-        t2 = mono()
-        out = np.asarray(logits)
-        self.last_decode_stamps = (t0, t1, t2, mono())
-        if self._counters:
-            counted, out = out[-1], out[:-1]
-            self.last_decode_counters = {n: float(counted[j]) for j, n in enumerate(self._counters)}
-        return out
+        self.last_decode_stamps = (t0, t1, mono())
+        return logits
+
+
+class LaneRow:
+    """What a decode step hands ``ServeEngine._advance_lane`` for one lane:
+    the token the device drew for it, and the lane's float32 logits for
+    whoever asks for them as an array (one row's copy to the host).  The
+    engine reads the token and never asks."""
+
+    __slots__ = ("token", "_logits", "_lane")
+
+    def __init__(self, token: int, logits: Any, lane: int) -> None:
+        self.token = token
+        self._logits = logits
+        self._lane = lane
+
+    def __array__(self, dtype: Any = None, copy: Any = None) -> np.ndarray:
+        return np.asarray(self._logits[self._lane], dtype=dtype)
 
 
 class ServeEngine:
@@ -416,6 +511,12 @@ class ServeEngine:
         self._completed = 0
         self._rejected = 0
         self._tokens_generated = 0
+        #: of those, the tokens a decode step's one call drew on the device
+        #: (the rest: each request's first, sampled on the host at admission)
+        self._tokens_sampled_on_device = 0
+        #: the counts the kernels' decode program returns in the row after
+        #: the lanes' logits, by name and in its order (a stand-in has none)
+        self._counters: Tuple[str, ...] = tuple(getattr(kernels, "counters", ()))
         #: requests that finished with an error (prefill crash, engine
         #: stop/crash, drain abandonment) — the error-rate numerator the
         #: master's canary bake compares against its pre-roll baseline
@@ -660,6 +761,7 @@ class ServeEngine:
                 "completed": self._completed,
                 "rejected": self._rejected,
                 "tokens_generated": self._tokens_generated,
+                "tokens_sampled_on_device": self._tokens_sampled_on_device,
                 # computed over asked is what the walk's whole chunks cost
                 # beyond the prompts (1.0: every prompt ended on a chunk's edge)
                 "prefill_tokens_asked": self._prefill_tokens_asked,
@@ -697,8 +799,10 @@ class ServeEngine:
             # requests (tpot: those with two tokens or more)
             "latency": {name: dict(v) for name, v in latency.items()},
             # where the engine thread's time went, cumulative since start:
-            # waiting for the decode program, copying its logits to the
-            # host, sampling, admitting (prefill and first sample)
+            # waiting for the decode program, sampling (the draws, the
+            # device's call, its ids taken per lane), of which copying the
+            # ids and the counters to the host, admitting (prefill and
+            # first sample)
             "step_seconds": step_seconds,
             # cumulative counts of the decode steps, where the model has
             # expert layers: picks that landed on the experts held here and
@@ -877,103 +981,121 @@ class ServeEngine:
             self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s))
         self._record_request(req)
 
-    def _decode_batch(
-        self, lanes: List[Optional[ActiveSeq]], step: int
-    ) -> Tuple[np.ndarray, float, float]:
+    def _decode_batch(self, lanes: List[Optional[ActiveSeq]]) -> Tuple[Any, np.ndarray, float, float]:
         """One jitted decode step over the full (static) lane table.
-        Returns the logits and the seconds the call waited for the device
-        and copied the logits to the host (0.0 where the kernels left no
-        stamps)."""
+        Returns the logits as the kernels handed them back (on the device,
+        ready), the lanes' positions (-1: idle) and the call's two ends."""
         b = self.cfg.max_batch
         t = self.cfg.blocks_per_seq
         tokens = np.zeros(b, np.int32)
         positions = np.full(b, -1, np.int32)
         tables = np.zeros((b, t), np.int32)
-        n_active = 0
         for i, seq in enumerate(lanes):
             if seq is None:
                 continue
             tokens[i] = seq.next_token
             positions[i] = seq.pos
             tables[i] = seq.block_table
-            n_active += 1
         t0 = mono()
         logits = self.kernels.decode(tokens, positions, tables)
-        t1 = mono()
-        # the kernels stamp the parts of their own call (whatever wraps
-        # ``kernels.decode`` from outside); a stand-in that leaves no stamps,
-        # or stale ones, leaves the step with its whole ``serve.decode`` only
-        stamps = getattr(self.kernels, "last_decode_stamps", None)
-        if stamps is not None and stamps[0] < t0:
-            stamps = None
-        counted = getattr(self.kernels, "last_decode_counters", None) or {}
-        if counted:
-            with self._stats_lock:
-                for name, value in counted.items():
-                    self._step_counters[name] = self._step_counters.get(name, 0.0) + value
-        tracer = self._tracer
-        if tracer.enabled:
-            # what the step's attention had to read: with the two a trace
-            # says whether device time follows what is live (the kernel
-            # walks each lane's own blocks) or the longest lane
-            tracer.record_span(
-                "serve.decode", "serve", t0, t1,
-                {
-                    "step": step,
-                    "active": n_active,
-                    "live_kv_tokens": int((positions + 1).sum()),
-                    "max_context": int(positions.max()) + 1,
-                    **counted,
-                },
-            )
-            if stamps is not None:
-                at = {"step": step}
-                for name, lo, hi in zip(("dispatch", "wait", "d2h"), stamps, stamps[1:]):
-                    tracer.record_span("serve.decode." + name, "serve", lo, hi, at)
-        if stamps is None:
-            return logits, 0.0, 0.0
-        return logits, stamps[2] - stamps[1], stamps[3] - stamps[2]
+        return logits, positions, t0, mono()
 
-    def _advance_lane(self, seq: ActiveSeq, logits_row: np.ndarray) -> bool:
-        """Sample the next token for one lane; True when the seq finished."""
-        tok = sample_token(logits_row, seq.request.temperature, seq.rng)
+    def _advance_lane(self, seq: ActiveSeq, row: LaneRow) -> bool:
+        """Give one lane the token the step's call drew for it; True when
+        the seq finished."""
+        tok = row.token
         seq.request.output.append(tok)
         seq.request.token_at.append(mono())
         seq.pos += 1
         seq.next_token = tok
         with self._stats_lock:
             self._tokens_generated += 1
+            self._tokens_sampled_on_device += 1
         return self._sequence_finished(seq, tok)
 
     def _decode_and_sample(self, lanes: List[Optional[ActiveSeq]], step: int) -> int:
-        """One decode step over the lane table, then one sampled token for
-        every live lane, in lane order; a sequence that finished is retired
-        at once (its response must not wait for the other lanes'
-        sampling).  Returns how many finished.
+        """One decode step over the lane table, then one call that draws
+        every lane's token on the device (``sample_lanes``) from the logits
+        where they lie; what comes to the host is the ids and the step's
+        counters.  Each live lane then takes its token, in lane order; a
+        sequence that finished is retired at once (its response must not
+        wait for the other lanes).  Returns how many finished.
 
-        ``serve.sample`` runs from before the first lane's sample to the
-        last lane's token stamp: first ``sample_token`` call to last."""
-        logits, wait_s, d2h_s = self._decode_batch(lanes, step)
+        A sampled lane's uniform is ONE ``rng.random()`` of its request's
+        generator, drawn here in lane order; a greedy lane draws nothing.
+        ``serve.sample`` runs from before the draws to the last lane's token
+        stamp, and ``serve.decode.d2h`` (inside it) from the ids being ready
+        on the device to their being on the host.  ``serve.decode`` has
+        ended before: a reader that takes the device's step from it counts
+        no operation of the sampler."""
+        logits, positions, t_call, t_back = self._decode_batch(lanes)
+        t0 = mono()
+        draws = np.zeros((2, len(lanes)), np.float32)  # temperatures over uniforms; an idle lane: argmax, ignored
+        for i, seq in enumerate(lanes):
+            if seq is not None and seq.request.temperature > 0.0:
+                draws[0, i] = seq.request.temperature
+                draws[1, i] = seq.rng.random()
+        ids, counted = lane_sampler(len(self._counters))(logits, draws)
+        # queued behind the program at once: waiting for the ids first and
+        # only then asking for them costs a host round trip
+        ids.copy_to_host_async()
+        if self._counters:
+            counted.copy_to_host_async()
+        ids.block_until_ready()
+        t_ready = mono()
+        tokens = np.asarray(ids).tolist()
+        counts = dict(zip(self._counters, np.asarray(counted).tolist())) if self._counters else {}
+        t1 = t_host = mono()
+        # the kernels stamp the parts of their own call (whatever wraps
+        # ``kernels.decode`` from outside); a stand-in that leaves no stamps,
+        # or stale ones, leaves the step with its whole ``serve.decode`` only
+        stamps = getattr(self.kernels, "last_decode_stamps", None)
+        if stamps is not None and stamps[0] < t_call:
+            stamps = None
         finished = live = 0
-        t0 = t1 = mono()
+        before = self._tokens_sampled_on_device
         for i, seq in enumerate(lanes):
             if seq is None:
                 continue
             live += 1
-            done = self._advance_lane(seq, logits[i])
+            done = self._advance_lane(seq, LaneRow(tokens[i], logits, i))
             t1 = seq.request.token_at[-1]
             if done:
                 self._retire_lane(i, seq)
                 finished += 1
         with self._stats_lock:
             seconds = self._step_seconds
-            seconds["decode_wait"] += wait_s
-            seconds["d2h"] += d2h_s
+            if stamps is not None:
+                seconds["decode_wait"] += stamps[2] - stamps[1]
+            seconds["d2h"] += t_host - t_ready
             seconds["sample"] += t1 - t0
+            for name, value in counts.items():
+                self._step_counters[name] = self._step_counters.get(name, 0.0) + value
             self._steps = step
-        self._tracer.record_span(
-            "serve.sample", "serve", t0, t1, {"step": step, "lanes": live}
-        )
+            on_device = self._tokens_sampled_on_device - before
+        tracer = self._tracer
+        if tracer.enabled:
+            at = {"step": step}
+            # what the step's attention had to read: with the two a trace
+            # says whether device time follows what is live (the kernel
+            # walks each lane's own blocks) or the longest lane
+            tracer.record_span(
+                "serve.decode", "serve", t_call, t_back,
+                {
+                    **at,
+                    "active": live,
+                    "live_kv_tokens": int((positions + 1).sum()),
+                    "max_context": int(positions.max()) + 1,
+                    **counts,
+                },
+            )
+            if stamps is not None:
+                tracer.record_span("serve.decode.dispatch", "serve", stamps[0], stamps[1], at)
+                tracer.record_span("serve.decode.wait", "serve", stamps[1], stamps[2], at)
+            tracer.record_span("serve.decode.d2h", "serve", t_ready, t_host, at)
+            tracer.record_span(
+                "serve.sample", "serve", t0, t1, {**at, "lanes": live, "device_lanes": on_device}
+            )
         return finished
 
     def _admit_one(self, step: int) -> bool:

@@ -658,7 +658,7 @@ def test_engine_keeps_every_name_the_benchmark_reads(kernels):
     for name in ("alloc", "blocks_for", "stats", "free"):
         assert callable(getattr(eng.allocator, name)), name
     assert set(eng.stats()) >= {
-        "submitted", "completed", "rejected", "tokens_generated", "errored", "http_5xx",
+        "submitted", "completed", "rejected", "tokens_generated", "tokens_sampled_on_device", "errored", "http_5xx",
         "prefill_tokens_asked", "prefill_tokens_computed",
         "latency_ms_avg", "latency", "step_seconds", "queue_depth", "queue_capacity",
         "draining", "failed", "kv_cache", "kv_utilization", "prefix_hits",
@@ -832,9 +832,10 @@ def test_serve_spans_reach_tracer(lm_setup, tracer):
 
 
 def test_building_the_kernels_makes_each_programs_first_call(lm_setup, tracer):
-    """Both programs compile (or load) where ``DecodeKernels`` is built, on
-    the builder's thread, before any request: their first-call spans are
-    there, each traced once, and the two calls wrote the scratch block alone."""
+    """The three programs (the walk, the decode step, the step's sampler)
+    compile (or load) where ``DecodeKernels`` is built, on the builder's
+    thread, before any request: their first-call spans are there, the model's
+    two each traced once, and the calls wrote the scratch block alone."""
     from determined_tpu.lint._runtime import get_retrace_sentinel
 
     cfg, _model, variables = lm_setup
@@ -842,18 +843,18 @@ def test_building_the_kernels_makes_each_programs_first_call(lm_setup, tracer):
     sentinel.reset()
     kernels = DecodeKernels(cfg, variables, SERVE_CFG)
     first = [e["name"] for e in _spans(tracer) if e["name"].startswith("jit.compile.")]
-    assert sorted(first) == ["jit.compile.serve.decode", "jit.compile.serve.prefill"]
+    assert sorted(first) == ["jit.compile.serve.decode", "jit.compile.serve.prefill", "jit.compile.serve.sample"]
     assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
     for pool in kernels.cache.values():
         assert not np.asarray(pool[:, 1:]).any()
-    assert kernels.last_decode_stamps is None and kernels.last_decode_counters == {}
+    assert kernels.last_decode_stamps is None and kernels.counters == ()
     # a request then compiles nothing more
     eng = ServeEngine(kernels)
     req = eng.submit([1, 2, 3], max_new_tokens=2)
     while not req.done.is_set():
         assert eng.step_once()
     eng.stop()
-    assert len([e for e in _spans(tracer) if e["name"].startswith("jit.compile.")]) == 2
+    assert len([e for e in _spans(tracer) if e["name"].startswith("jit.compile.")]) == 3
     assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
     sentinel.reset()
 
@@ -925,11 +926,95 @@ def test_step_spans_nest_and_carry_their_step(kernels, tracer):
             seen.add(e["name"])
     assert len(seen) == 8
     decodes = {e["args"]["step"]: e for e in _spans(tracer, "serve.decode")}
-    for part in ("dispatch", "wait", "d2h"):
+    samples = {e["args"]["step"]: e for e in _spans(tracer, "serve.sample")}
+    assert sorted(decodes) == sorted(samples) and len(decodes) >= 3
+    for part in ("dispatch", "wait"):
         for e in _spans(tracer, "serve.decode." + part):
-            assert _inside(e, decodes[e["args"]["step"]])
-    for e in _spans(tracer, "serve.sample"):
-        assert e["args"]["lanes"] == steps[e["args"]["step"]]["args"]["active"]
+            assert _inside(e, decodes[e["args"]["step"]]) and e["dur"] > 0
+    # the copy that is left (ids, counters) lies in the sampling; the decode
+    # call has ended before the sampler is launched
+    copies = _spans(tracer, "serve.decode.d2h")
+    assert len(copies) == len(decodes)
+    for e in copies:
+        assert _inside(e, samples[e["args"]["step"]]) and e["dur"] > 0
+    for step, e in samples.items():
+        assert e["ts"] >= decodes[step]["ts"] + decodes[step]["dur"] - 0.2
+        assert e["args"]["lanes"] == e["args"]["device_lanes"] == steps[step]["args"]["active"]
+
+
+def test_a_steps_tokens_are_the_host_samplers_on_the_same_logits_and_uniforms(kernels):
+    """The step's one call on the device against the host's ``sample_token``
+    (the oracle): the logits each call returned (kept here row by row) and a
+    twin of each request's seeded generator give the request's own tokens.
+    ``decode`` hands back the device's array and copies nothing of it."""
+    from determined_tpu.serve.engine import sample_token
+
+    eng = ServeEngine(kernels)
+    decode, prefill = kernels.decode, kernels.prefill_suffix
+    steps, firsts = [], []
+    kernels.decode = lambda *a: steps.append(decode(*a)) or steps[-1]
+    kernels.prefill_suffix = lambda *a: firsts.append(prefill(*a)) or firsts[-1]
+    try:
+        temperatures = [0.8, 0.0, 1.3]
+        reqs = [
+            eng.submit([2 + i, 3, 5], max_new_tokens=9 - 2 * i, temperature=t, seed=40 + i)
+            for i, t in enumerate(temperatures)
+        ]
+        while eng.step_once():
+            pass
+    finally:
+        del kernels.decode, kernels.prefill_suffix
+    assert all(isinstance(x, jax.Array) and x.shape == (4, 64) for x in steps)
+    assert len(kernels.last_decode_stamps) == 3  # call, enqueued, ready: no copy to time
+    for lane, (req, t) in enumerate(zip(reqs, temperatures)):
+        twin = np.random.default_rng(req.seed)
+        want = [sample_token(firsts[lane], t, twin)]
+        want += [sample_token(np.asarray(x[lane]), t, twin) for x in steps[: req.max_new_tokens - 1]]
+        assert req.error is None and req.output == want
+    stats = eng.stats()
+    assert stats["tokens_generated"] == 9 + 7 + 5
+    assert stats["tokens_sampled_on_device"] == stats["tokens_generated"] - 3  # a first token is admission's
+
+
+def test_a_sampler_of_the_callers_own_is_handed_each_live_lanes_float32_row(kernels, tracer):
+    """``_advance_lane(seq, row)`` stays the seam a lane's token goes through
+    (``tests/benchmark/test_bench_other_sampler.py`` lays a sampler of its
+    own there): what it is handed turns into the lane's own row of the
+    step's logits for whoever asks, and the device's token is not counted
+    where it was not taken."""
+    import types
+
+    eng = ServeEngine(kernels)
+    decode = kernels.decode
+    steps, rows = [], []
+    kernels.decode = lambda *a: steps.append(decode(*a)) or steps[-1]
+
+    def advance(self, seq, row):
+        got = np.asarray(row)
+        rows.append((len(steps) - 1, seq.lane, got))
+        tok = int(np.argmin(got))  # no sampler of the engine's yields this
+        seq.request.output.append(tok)
+        seq.request.token_at.append(time.monotonic())
+        seq.pos += 1
+        seq.next_token = tok
+        return self._sequence_finished(seq, tok)
+
+    eng._advance_lane = types.MethodType(advance, eng)
+    try:
+        reqs = [eng.submit([7 + i, 8], max_new_tokens=4, temperature=0.9, seed=i) for i in range(2)]
+        while eng.step_once():
+            pass
+    finally:
+        del kernels.decode
+    assert len(rows) == 2 * 3 and {lane for _, lane, _ in rows} == {0, 1}
+    for step, lane, got in rows:
+        assert got.dtype == np.float32 and got.shape == (64,)
+        np.testing.assert_array_equal(got, np.asarray(steps[step])[lane])
+    for req in reqs:
+        assert req.error is None and len(req.output) == 4
+    assert [r[2].argmin() for r in rows if r[1] == 0] == reqs[0].output[1:]
+    assert {e["args"]["device_lanes"] for e in _spans(tracer, "serve.sample")} == {0}
+    assert eng.stats()["tokens_sampled_on_device"] == 0
 
 
 def test_decode_span_says_what_the_step_had_to_read(kernels, tracer):

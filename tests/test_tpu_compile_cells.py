@@ -340,3 +340,64 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
         assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
     else:                                                                            # the chunk's pass over the state, a layer
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
+
+
+# -- the decode step's sampler: one call over all lanes, at the serving cells' logits ------
+
+
+_ARRAY = re.compile(r"\b(pred|s8|s16|s32|u8|u16|u32|bf16|f16|f32|f64)\[([\d,]*)\]\{([^}]*)\}")
+_WIDTH = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4, "f64": 8}
+
+
+def _hbm_bytes(text: str) -> int:
+    """Bytes the program's top-level instructions move to and from HBM, by
+    the optimized module's own layouts: an array whose layout names a memory
+    space (``S(1)``: the chip's vector memory, where XLA keeps what fits) is
+    not in HBM.  Each instruction is charged its HBM operands whole and its
+    HBM results; an asynchronous copy or slice, what lands at its end.
+    (``cost_analysis()["bytes accessed"]`` charges every pass alike, the ones
+    that stay in vector memory too.)"""
+    def arrays(part):
+        return [(_WIDTH[t] * math.prod(int(d) for d in dims.split(",") if d), "S(" not in layout) for t, dims, layout in _ARRAY.findall(part)]
+
+    made, moved = {}, 0
+    for line in text[text.index("ENTRY "):].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][a-z\-]+)\((.*?)\)", line)
+        if not m:
+            continue
+        name, kind, op, operands = m.groups()
+        made[name] = arrays(kind)
+        if op in ("parameter", "constant", "tuple", "get-tuple-element", "bitcast", "iota", "copy-done", "slice-done"):
+            continue
+        if op.endswith("-start"):
+            moved += max(size for size, in_hbm in made[name] if not in_hbm)
+            continue
+        moved += sum(size for size, in_hbm in made[name] if in_hbm)
+        moved += sum(size for o in re.findall(r"%([\w.\-]+)", operands) for size, in_hbm in made.get(o, ()) if in_hbm)
+    return moved
+
+
+@pytest.mark.parametrize(
+    "lanes, vocab, counters",
+    [(32, 92544, 0), (32, 151936, 2), (64, 16160, 2)],
+    ids=["internlm2-32x92544", "brumby-32x151936", "dsv3-64x16160"],
+)
+def test_the_steps_sampler_compiles_at_the_cells_logits_and_sweeps_them_a_few_times(tpu_devices, lanes, vocab, counters):
+    """``sample_lanes`` as the engine calls it, at the decode cells' lanes and
+    vocabularies (DeepSeek-V3's share is no multiple of 128; the models that
+    count their steps hand one more row): no sort, nothing in float64, and
+    what it moves to and from HBM is under four times the logits (they are
+    read twice to thrice: the maximum, the exponentials, the copy the rest
+    works on; nothing of their size is written back).  By XLA's own count,
+    which charges the passes over that copy too, under 16 times."""
+    from determined_tpu.serve.engine import lane_sampler
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
+    compiled = lane_sampler(counters).lower(aval((lanes + bool(counters), vocab)), aval((2, lanes))).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and "f64[" not in text
+    logits = 4 * lanes * vocab
+    assert logits < _hbm_bytes(text) <= 4 * logits
+    assert compiled.cost_analysis()["bytes accessed"] <= 16 * logits
+    assert compiled.memory_analysis().temp_size_in_bytes == 0 and _kernels(text) == 0
