@@ -544,7 +544,7 @@ def _gmm(lhs, rhs, group_start, tile_group, live_tiles, rows, tile):
 
 def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """``RoutedExperts``' forward over its unboxed leaves ``p``, for the
-    serving programs (``models/transformer.py _serve_layer``; ``cfg`` is the
+    serving programs (``models/serving.py _serve_layer``; ``cfg`` is the
     ``TransformerConfig``): ``x [b, s, d]`` -> (``y [b, s, d]``, (picks that
     landed on a held expert, held experts with at least one row)).  Tokens
     that ``live [b, s]`` does not mark (idle lanes, a prompt's padding) take no

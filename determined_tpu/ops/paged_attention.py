@@ -498,7 +498,7 @@ def _paged_attention_pallas(
 # Multi-head latent attention in its absorbed form: a lane's query is
 # ``n_heads`` vectors in the latent space, ``[q_lat | q_rope | zeros]``, and
 # the pool ``[n_layers, num_blocks, block_size, width]`` holds one row a
-# token, ``[c_kv | k_r | zeros]`` (``models/transformer.py init_kv_cache``).
+# token, ``[c_kv | k_r | zeros]`` (``models/serving.py init_kv_cache``).
 # Scores are the query against the whole row; values are the row's first
 # ``value_dim`` columns, so a tile is copied once and feeds both products:
 # ``q @ tile^T`` and ``p @ tile[:, :value_dim]``.  All heads share the tile,

@@ -18,7 +18,7 @@ the pair's two places in ``(x . y)^2``); row ``d/2`` holds its pairs twice, at
 weight 1 each.  ``d/2 + 1`` rows of ``d``: 65 x 128 = 8,320 features at a head
 of 128, where the symmetric form has 8,256 and ``x (x) x`` 16,384.
 
-**The state pool** (``models/transformer.py init_kv_cache``): ``rs [layers,
+**The state pool** (``models/serving.py init_kv_cache``): ``rs [layers,
 lanes, kv_heads, rows * d, d]`` float32, feature row ``r`` and value ``v`` at
 row ``r * d + v``, the feature's column ``c`` along the lanes; ``rz [layers,
 lanes, kv_heads, rows, d]`` the normaliser.  A decode lane owns slot ``lane``.

@@ -3,14 +3,14 @@
 ``determined_tpu/inference.py`` is the OFFLINE path (checkpointed batch
 processing of a finite dataset); this package is the ONLINE one — a
 ``ServeWorker`` loads a trial checkpoint, compiles prefill/decode step
-functions for the decoder-only transformer (``models/transformer.py``
-KV-cache decode path), and serves ``POST /v1/generate`` with:
+functions for the decoder-only transformer (``models/serving.py``, the
+serving forward), and serves ``POST /v1/generate`` with:
 
 - **continuous batching** (``engine.ServeEngine``): requests join the
   running decode batch between any two steps and retire the moment they
   finish — Orca-style iteration-level scheduling;
 - a **paged KV cache** (``kv_cache.BlockAllocator`` over the block pool
-  in ``models/transformer.py``): fixed-size blocks, free-list allocation,
+  in ``models/serving.py``): fixed-size blocks, free-list allocation,
   per-sequence block tables baked into a single decode trace;
 - **bounded admission** (``scheduler.AdmissionQueue``): a full queue
   answers 429, a draining worker 503 — overload degrades into fast

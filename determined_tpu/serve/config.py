@@ -112,7 +112,7 @@ class ServeConfig:
         """Tokens an iteration of the prefill walk takes: a prefill computes
         whole chunks, from the one its first un-cached token lies in to the
         one that ends its prompt."""
-        from determined_tpu.models.transformer import prefill_chunk_tokens
+        from determined_tpu.models.serving import prefill_chunk_tokens
 
         return prefill_chunk_tokens(self.block_size, self.max_prompt_len)
 

@@ -229,14 +229,16 @@ class DecodeKernels:
     def __init__(self, model_cfg: Any, params: Any, serve_cfg: ServeConfig) -> None:
         import jax
 
-        from determined_tpu.models.transformer import (
+        from determined_tpu.models.serving import (
             _check_decodable,
             init_kv_cache,
-            kv_bytes_per_token,
             serve_counters,
-            state_bytes_per_slot,
             transformer_decode,
             transformer_prefill_chunked,
+        )
+        from determined_tpu.models.transformer import (
+            kv_bytes_per_token,
+            state_bytes_per_slot,
             window_ring_blocks,
         )
         from determined_tpu.ops.paged_attention import attn_products

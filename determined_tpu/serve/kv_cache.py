@@ -1,6 +1,6 @@
 """Paged KV-cache block allocator: the host-side half of PagedAttention.
 
-The device arrays (``models/transformer.py init_kv_cache``) are a flat pool
+The device arrays (``models/serving.py init_kv_cache``) are a flat pool
 of fixed-size blocks (``[n_layers, num_blocks, block_size, kv_heads *
 head_dim]``; this module deals in block ids only); this module owns WHICH blocks belong to WHOM.  A
 ref-counted free-list allocator hands out physical block ids all-or-nothing

@@ -298,9 +298,13 @@ def _step_text(tmp_path, hparams):
 def _serve_texts(cfg):
     from flax.core import meta
 
-    from determined_tpu.models.transformer import (
-        TransformerLM, init_kv_cache, prefill_chunk_tokens, transformer_decode, transformer_prefill_chunked,
+    from determined_tpu.models.serving import (
+        init_kv_cache,
+        prefill_chunk_tokens,
+        transformer_decode,
+        transformer_prefill_chunked,
     )
+    from determined_tpu.models.transformer import TransformerLM
 
     # shapes alone: the programs are lowered and compiled, never run
     params = meta.unbox(jax.eval_shape(TransformerLM(cfg).init, jax.random.key(1), jnp.zeros((1, 8), jnp.int32)))["params"]

@@ -15,18 +15,20 @@ import pytest
 from flax.core import meta
 
 from determined_tpu.models import moe
-from determined_tpu.models.transformer import (
-    FULL,
+from determined_tpu.models.serving import (
     SERVE_COUNTERS,
-    TransformerConfig,
-    TransformerLM,
     _check_decodable,
     init_kv_cache,
-    kv_bytes_per_token,
-    kv_cache_shape,
     transformer_decode,
     transformer_prefill,
     transformer_prefill_chunked,
+)
+from determined_tpu.models.transformer import (
+    FULL,
+    TransformerConfig,
+    TransformerLM,
+    kv_bytes_per_token,
+    kv_cache_shape,
 )
 from determined_tpu.ops import grouped_matmul as gm, paged_attention as paged
 from tests.model_cases import reference_module

@@ -13,14 +13,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from determined_tpu.models.transformer import (
-    FULL,
-    SLIDING,
-    TransformerConfig,
-    TransformerLM,
-    _check_decodable,
-    yarn_inv_freq,
-)
+from determined_tpu.models.serving import _check_decodable
+from determined_tpu.models.transformer import FULL, SLIDING, TransformerConfig, TransformerLM, yarn_inv_freq
 from determined_tpu.ops import grouped_matmul as gm
 from determined_tpu.ops.attention import dot_product_attention, reference_attention
 from determined_tpu.ops.flash_attention import flash_attention
@@ -174,7 +168,7 @@ def test_what_cannot_honour_a_window_refuses_by_name():
     # the serving forward does (since PR 38: a ring a lane, tests/test_window_serving.py), its wide prefill aside
     _check_decodable(_tiny())
     with pytest.raises(ValueError, match="the wide prefill runs full layers only"):
-        from determined_tpu.models.transformer import transformer_prefill
+        from determined_tpu.models.serving import transformer_prefill
 
         transformer_prefill(_tiny(), {}, tokens, jnp.ones(1, jnp.int32), jnp.zeros((1, 8), jnp.int32), {"k": jnp.zeros((1, 2, 4, 8))})
     _check_decodable(_tiny(layer_types=None, sliding_window=None))

@@ -15,14 +15,9 @@ import numpy as np
 import pytest
 from flax.core import meta as flax_meta
 
-import determined_tpu.models.transformer as tfm
-from determined_tpu.models.transformer import (
-    TransformerConfig,
-    TransformerLM,
-    init_kv_cache,
-    transformer_decode,
-    transformer_prefill,
-)
+import determined_tpu.models.serving as tfm
+from determined_tpu.models.serving import init_kv_cache, transformer_decode, transformer_prefill
+from determined_tpu.models.transformer import TransformerConfig, TransformerLM
 from determined_tpu.ops import paged_attention as pa
 from tests.model_cases import causal_forward
 

@@ -14,19 +14,21 @@ import numpy as np
 import pytest
 from flax.core import meta
 
-from determined_tpu.models.transformer import (
-    RETENTION,
+from determined_tpu.models.serving import (
     SERVE_STATE_COUNTERS,
-    TransformerConfig,
-    TransformerLM,
     init_kv_cache,
-    kv_bytes_per_token,
     serve_counters,
-    state_bytes_per_slot,
-    state_pool_shapes,
     transformer_decode,
     transformer_prefill,
     transformer_prefill_chunked,
+)
+from determined_tpu.models.transformer import (
+    RETENTION,
+    TransformerConfig,
+    TransformerLM,
+    kv_bytes_per_token,
+    state_bytes_per_slot,
+    state_pool_shapes,
 )
 from determined_tpu.ops import retention
 from determined_tpu.serve.config import ServeConfig

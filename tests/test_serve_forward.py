@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from determined_tpu.models import transformer as tx
-from determined_tpu.models.transformer import Rope, TransformerConfig, TransformerLM
+from determined_tpu.models import serving as tx
+from determined_tpu.models.transformer import Rope, TransformerConfig, TransformerLM, yarn_inv_freq
 from determined_tpu.ops.attention import reference_attention
 
 BLOCK = 4
@@ -153,7 +153,7 @@ def test_no_entry_point_converts_the_whole_embedding_table(which):
 
 YARN = Rope(
     500000.0,
-    tuple(tx.yarn_inv_freq(16, 500000.0, factor=8.0, original_max_position_embeddings=64, beta_fast=32.0, beta_slow=1.0)),
+    tuple(yarn_inv_freq(16, 500000.0, factor=8.0, original_max_position_embeddings=64, beta_fast=32.0, beta_slow=1.0)),
     1.2,
 )
 

@@ -269,14 +269,12 @@ def test_serve_programs_compile_on_one_chip(tpu_devices, which):
     the default ServeConfig at d2048 / 16 heads / vocab 32768 — paged
     scatters and gathers, the donated cache — depth cut to 2 layers (depth
     repeats, it does not change what the compiler must accept)."""
-    from determined_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerLM,
-        kv_cache_shape,
+    from determined_tpu.models.serving import (
         transformer_decode,
         transformer_prefill,
         transformer_prefill_chunked,
     )
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
     from determined_tpu.serve.config import ServeConfig
 
     one = SingleDeviceSharding(tpu_devices[0])
@@ -363,12 +361,8 @@ def test_decode_step_holds_no_copy_of_a_layers_pool(tpu_devices, monkeypatch, fo
     48 such slices a step.)  Both forms, since either may serve a shape."""
     from flax.core import meta as flax_meta
 
-    from determined_tpu.models.transformer import (
-        TransformerConfig,
-        TransformerLM,
-        kv_cache_shape,
-        transformer_decode,
-    )
+    from determined_tpu.models.serving import transformer_decode
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
     from determined_tpu.serve.config import ServeConfig
 
     monkeypatch.setattr(paged_mod, "_on_tpu", lambda: form == "kernel")

@@ -42,7 +42,8 @@ def _arrays_with_dims(text: str, dims) -> list:
 def _walk_compiled(one, cfg, *, num_blocks, max_prompt_len, table_width):
     from flax.core import meta as flax_meta
 
-    from determined_tpu.models.transformer import TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_prefill_chunked
+    from determined_tpu.models.serving import prefill_chunk_tokens, transformer_prefill_chunked
+    from determined_tpu.models.transformer import TransformerLM, kv_cache_shape
 
     boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
@@ -134,7 +135,8 @@ def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, mo
     so the scopes a trace's reader asks for list it."""
     from flax.core import meta as flax_meta
 
-    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, transformer_decode
+    from determined_tpu.models.serving import transformer_decode
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
     from determined_tpu.utils.compilation_cache import program_scopes
 
     monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
@@ -218,9 +220,16 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
     layer's under the other; nothing the size of a lane's context is gathered."""
     from flax.core import meta as flax_meta
 
+    from determined_tpu.models.serving import (
+        prefill_chunk_tokens,
+        transformer_decode,
+        transformer_prefill_chunked,
+    )
     from determined_tpu.models.transformer import (
-        TransformerConfig, TransformerLM, kv_cache_shape, prefill_chunk_tokens, transformer_decode,
-        transformer_prefill_chunked, window_store_shape,
+        TransformerConfig,
+        TransformerLM,
+        kv_cache_shape,
+        window_store_shape,
     )
     from determined_tpu.utils.compilation_cache import program_scopes
 
@@ -302,9 +311,8 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
     made for the allocator's block ids."""
     from flax.core import meta as flax_meta
 
-    from determined_tpu.models.transformer import (
-        TransformerConfig, TransformerLM, state_pool_shapes, transformer_decode, transformer_prefill_chunked,
-    )
+    from determined_tpu.models.serving import transformer_decode, transformer_prefill_chunked
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, state_pool_shapes
     from determined_tpu.utils.compilation_cache import program_scopes
 
     one = SingleDeviceSharding(tpu_devices[0])

@@ -184,11 +184,7 @@ import functools  # noqa: E402
 
 import jax.numpy as jnp  # noqa: E402
 
-from determined_tpu.models.transformer import (  # noqa: E402
-    init_kv_cache,
-    transformer_decode,
-    transformer_prefill,
-)
+from determined_tpu.models.serving import init_kv_cache, transformer_decode, transformer_prefill
 from determined_tpu.serve.engine import sample_token  # noqa: E402
 from tests.model_cases import causal_forward  # noqa: E402
 
@@ -416,7 +412,7 @@ def walk_setup():
     import dataclasses
 
     from determined_tpu.lint._runtime import get_retrace_sentinel
-    from determined_tpu.models import transformer as tx
+    from determined_tpu.models import serving as tx
 
     cfg, model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=5)
     cfg = dataclasses.replace(cfg, max_seq_len=WALK_PAD)
@@ -458,7 +454,7 @@ def test_the_prefill_walk_matches_the_full_forward_across_chunk_edges(walk_setup
 
 
 def test_the_prefill_walk_refuses_a_width_that_is_no_whole_chunk():
-    from determined_tpu.models.transformer import prefill_chunk_tokens, transformer_prefill_chunked
+    from determined_tpu.models.serving import prefill_chunk_tokens, transformer_prefill_chunked
 
     # whole blocks and whole 128-wide tiles; the longest prompt where that is shorter
     assert prefill_chunk_tokens(16, 4096) == 256 and prefill_chunk_tokens(16, 100) == 112
@@ -477,7 +473,7 @@ def test_prefill_suffix_matches_wide_prefill():
     at f32 tolerance, and a warm start over already-written prefix blocks is
     BITWISE equal to the cold run — both attend over the same stored cache
     bits, so prefix-cached admission cannot drift."""
-    from determined_tpu.models.transformer import transformer_prefill_chunked
+    from determined_tpu.models.serving import transformer_prefill_chunked
 
     cfg, _model, variables = _tiny_lm(jnp.float32, n_kv_heads=2, seed=11)
     params = variables["params"]

@@ -18,21 +18,23 @@ import pytest
 from flax.core import meta
 
 from determined_tpu.models import moe
-from determined_tpu.models.transformer import (
-    FULL,
+from determined_tpu.models.serving import (
     SERVE_COUNTERS,
     SERVE_KV_COUNTERS,
-    SLIDING,
-    TransformerConfig,
-    TransformerLM,
     _check_decodable,
     init_kv_cache,
-    kv_cache_shape,
     prefill_chunk_tokens,
     serve_counters,
     transformer_decode,
     transformer_prefill,
     transformer_prefill_chunked,
+)
+from determined_tpu.models.transformer import (
+    FULL,
+    SLIDING,
+    TransformerConfig,
+    TransformerLM,
+    kv_cache_shape,
     window_ring_blocks,
     window_store_shape,
 )
