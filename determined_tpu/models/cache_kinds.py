@@ -1,0 +1,629 @@
+"""What a layer keeps of the past when it is served, said once: the table of
+cache kinds under the serving forward (``models/serving.py``).
+
+A kind is what a layer keeps and how it is addressed, ONE record
+(:class:`CacheKind`) in :data:`CACHE_KINDS`: ``paged_kv`` (K and V rows a token
+in the paged pool), ``paged_latent`` (one latent row a token in the pool),
+``state_slot`` (a float32 state a decode lane) and ``window_ring`` (K and V rows
+of the newest tokens in a ring a decode lane); ``docs/serving.md`` "What a
+request holds" has them side by side.  The forward's layer function calls every
+kind's mixer the same way, and the engine (``serve/engine.py``) derives
+admission, its refusals and ``/stats`` from the records: neither names a leaf
+or asks which kind a model has, and nothing outside this file adds to the table.
+
+A mixer is ``mix(p, x, h, cache, j) -> (x, cache)``: ``p`` the layer's attention
+leaves, ``x`` [b, s, d] the residual stream, ``h`` its norm, ``j`` the layer's row
+in the kind's arrays.  It projects, writes this call's rows (or folds them into
+the state), attends so that a token sees itself, and adds the output projection
+to the stream.  A kind builds one from the rows of a call (:class:`Rows`) in
+each form an entry point may pick: ``step`` (a decode step by the paged path),
+``walk`` (a chunk of the prefill walk) and the tests' reference forms ``table``
+(a decode step over gathered rows) and ``wide`` (the whole prompt in one pass).
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import functools
+import math
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from determined_tpu.models import transformer
+from determined_tpu.models.transformer import (
+    FULL, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
+    kv_bytes_per_token, kv_cache_shape, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
+)
+from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
+from determined_tpu.ops.paged_attention import (
+    attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
+)
+from determined_tpu.ops.retention import retention_chunk, retention_decode
+
+#: what a request holds of a kind.  BLOCKS: rows a token in blocks the
+#: allocator hands out, shareable by prefix, prefilled from any block edge.
+#: LANE: a fixed store of its decode lane (a ring, a slot): not shareable, and
+#: prefilled from 0 into a lane known before the prefill
+BLOCKS, LANE = "blocks", "lane"
+
+
+@dataclasses.dataclass(frozen=True)
+class Rows:
+    """The rows of one call of the serving forward: what its entry point knows
+    of them, made once and read by every kind's mixer."""
+
+    positions: jax.Array      # where rotary embeddings turn them: [s] alike in every lane, or [b, s]
+    block_tables: jax.Array   # [b, T] each lane's blocks of the paged pool
+    live: jax.Array           # which are a request's: a decode step's active lanes [b], a prefill's tokens [b, s]
+    where: Optional[Tuple[jax.Array, jax.Array]]  # (block, slot) each lies at in the pool; None: no layer reads one
+    block_size: Optional[int]
+    lanes: Optional[jax.Array] = None  # [b] the decode lane of each row; None: row ``b`` is lane ``b``
+    # a decode step's: the position of the token each lane consumes [b], -1 idle; the same, an idle lane at 0
+    lane_positions: Optional[jax.Array] = None
+    pos: Optional[jax.Array] = None
+    # the walk's: this chunk's index, the index of the walk's first, a token's place in its chunk [chunk]
+    chunk: Any = 0
+    first_chunk: Any = 0
+    offsets: Optional[jax.Array] = None
+
+
+# -- K and V rows: the paged pool's and the window ring's ---------------------
+
+
+def _attn_proj(p: Dict[str, Any], x: jax.Array, dtype: Any) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """q/k/v projections as ``Attention`` computes them, to [b, heads, s, d]."""
+    # under the caller's scope (``serve.attn.qkv``, with the rope that follows)
+    q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"]["kernel"].astype(dtype))
+    k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"]["kernel"].astype(dtype))
+    v = jnp.einsum("bsd,dhk->bhsk", x, p["wv"]["kernel"].astype(dtype))
+    return q, k, v
+
+
+def _pool_rows(x: jax.Array, lead: Tuple[int, ...]) -> jax.Array:
+    """Projected k or v ``[b, kv_heads, s, head_dim]`` as the pool stores a
+    token, ``[*lead, kv_heads * head_dim]``: ``lead`` is (b, s), or (b,) where s is 1."""
+    return x.transpose(0, 2, 1, 3).reshape(*lead, x.shape[1] * x.shape[3])
+
+
+def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, attend, drop: bool = False):
+    """The mixer of a kind that keeps K and V rows: q/k/v and rope, this call's
+    rows into the kind's two leaves at ``where`` = (block, slot), each [b, s] (or
+    [b] where s is 1; under ``drop`` a block id past the store's end drops the
+    row: idle lanes, padding), then ``attend(q, k, v, cache, j)`` against the
+    store that now holds them, with q [b, n_heads, s, head_dim] and this call's
+    own k, v [b, kv_heads, s, head_dim], to [b, n_heads, s, head_dim]."""
+    (phys, slots), (keys, vals) = where, kind.leaves
+    rope, dt, mode = cfg.rope(kind.layer_type), cfg.dtype, "drop" if drop else None
+    # a cache of two kinds: the device trace tells them apart
+    scope = "serve.attn.window" if kind.layer_type == SLIDING else "serve.attn.full"
+    split = functools.partial(jax.named_scope, scope) if cfg.window_layers else contextlib.nullcontext
+
+    def mix(p, x, h, cache, j):
+        with jax.named_scope("serve.attn.qkv"):
+            q, k, v = _attn_proj(p, h, dt)
+            q, k = _rope(q, rows.positions, rope), _rope(k, rows.positions, rope)
+        with jax.named_scope("serve.kv.write"):
+            cache = {
+                **cache,
+                keys: cache[keys].at[j, phys, slots].set(_pool_rows(k, phys.shape), mode=mode),
+                vals: cache[vals].at[j, phys, slots].set(_pool_rows(v, phys.shape), mode=mode),
+            }
+        with jax.named_scope("serve.attn.attend"):  # whichever form the entry point picked
+            with split():
+                att = attend(q, k, v, cache, j)
+            att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
+        with jax.named_scope("serve.attn.out"):
+            return x + jnp.einsum("bshk,hkD->bsD", att, p["wo"]["kernel"].astype(dt)), cache
+
+    return mix
+
+
+def _attend_local(q, k, v, cache, j):
+    """Causal, over this call's own keys: the wide prefill's prompts start at position 0."""
+    return reference_attention(q, k, v, causal=True)
+
+
+def _attend_paged(cfg: TransformerConfig, kind: "CacheKind", block_tables: jax.Array, positions: jax.Array, window: Optional[int] = None):
+    """One query a lane against the lane's live blocks, read where they lie
+    in the kind's store (``ops/paged_attention.py``); ``positions`` [b], -1 = idle.
+    ``window``: the layer slides, ``block_tables`` are the lanes' rings, and a
+    lane reads its newest ``window`` tokens there."""
+    keys, vals = kind.leaves
+
+    def attend(q, k, v, cache, j):
+        att = paged_decode_attention(
+            q[:, :, 0, :], cache[keys], cache[vals], j, block_tables, positions, scale=cfg.head_dim ** -0.5,
+            window=window,
+        )
+        return att.astype(cfg.dtype)[:, :, None, :]
+
+    return attend
+
+
+def _attend_chunk(cfg: TransformerConfig, kind: "CacheKind", block_tables: jax.Array, chunk: jax.Array, window: Optional[int] = None):
+    """The prefill walk's read: the queries of chunk ``chunk`` (positions
+    ``chunk * s ..``) against the keys up to the chunk's end, read from the
+    store a tile at a time (``ops/paged_attention.py paged_chunk_attention``);
+    under ``window`` from the lanes' rings, and no key older than the window."""
+    keys, vals = kind.leaves
+
+    def attend(q, k, v, cache, j):
+        b, h, s, d = q.shape
+        att = paged_chunk_attention(
+            q.reshape(b, cfg.kv_heads, h // cfg.kv_heads, s, d), cache[keys], cache[vals], j, block_tables, chunk,
+            scale=cfg.head_dim ** -0.5, window=window,
+        )
+        return att.astype(cfg.dtype).reshape(b, h, s, d)
+
+    return attend
+
+
+def _masked_attention(cfg: TransformerConfig, q: jax.Array, keys: jax.Array, vals: jax.Array, mask: jax.Array) -> jax.Array:
+    """Queries against gathered keys under ``mask`` (True = may see: ``[s, keys]``
+    where the lanes are alike, else ``[b, s, keys]``) and a float32 softmax."""
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, keys, preferred_element_type=jnp.float32)
+    logits = logits * cfg.head_dim ** -0.5
+    seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, logits, NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vals.dtype), vals)
+
+
+def _table_mask(rows: Rows) -> jax.Array:
+    """A decode step's mask over the gathered table, [b, 1, T * block_size]:
+    every cache position up to and including the lane's current token."""
+    with jax.named_scope("serve.attn.attend"):
+        kv_len = rows.block_tables.shape[1] * rows.block_size
+        return ((jnp.arange(kv_len)[None, :] <= rows.positions) & rows.live[:, None])[:, None, :]
+
+
+def _attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
+    """Queries against every token of every table column, gathered from the
+    pool with the KV heads repeated, under ``mask``: decode without the paged
+    path, the oracle that path is tested against."""
+    keys, vals = PAGED_KV.leaves
+
+    def gathered(pool: jax.Array, j: int) -> jax.Array:  # [b, n_heads, T * block_size, head_dim]
+        b, t = block_tables.shape
+        found = pool[j, block_tables].reshape(b, t * pool.shape[2], cfg.kv_heads, -1)
+        return _repeat_kv(found.transpose(0, 2, 1, 3), cfg.n_heads // cfg.kv_heads)
+
+    def attend(q, k, v, cache, j):
+        return _masked_attention(cfg, q, gathered(cache[keys], j), gathered(cache[vals], j), mask)
+
+    return attend
+
+
+def _attend_ring_table(cfg: TransformerConfig, positions: jax.Array):
+    """A window layer's decode without the paged path: every lane's whole
+    ring, gathered, each slot masked by the position it must hold.  Slot ``s``
+    of a lane at position ``pos`` holds ``p = pos - (pos - s) % ring`` if it
+    holds anything of this request; the query sees it if ``p >= 0`` and ``p >
+    pos - window``.  What an earlier request left in the lane is never seen."""
+    keys, vals = WINDOW_RING.leaves
+
+    def attend(q, k, v, cache, j):
+        b = q.shape[0]
+        ring = cache[keys].shape[1] // b * cache[keys].shape[2]
+        found = lambda pool: _repeat_kv(  # noqa: E731
+            pool[j].reshape(b, ring, cfg.kv_heads, -1).transpose(0, 2, 1, 3), cfg.n_heads // cfg.kv_heads
+        )
+        pos = positions[:, None]
+        held = pos - (pos - jnp.arange(ring)[None, :]) % ring  # [b, ring]
+        mask = (held >= 0) & (held > pos - cfg.sliding_window) & (pos >= 0)
+        return _masked_attention(cfg, q, found(cache[keys]), found(cache[vals]), mask[:, None, :])
+
+    return attend
+
+
+def _paged_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    return (kv_cache_shape(cfg, sizes.num_blocks, sizes.block_size),) * (1 if cfg.latent else 2)
+
+
+def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0) -> Dict[str, Any]:
+    """``attn_products``: what a tile of the GQA decode kernel multiplies at this
+    model's heads (``ops/paged_attention.py``); absent for latent layers, whose
+    heads all share a row, and where no layer reads K and V."""
+    if cfg.latent or len(cfg.retention_layers) == cfg.n_layers:
+        return {}
+    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads)}
+
+
+def _ring_step(cfg: TransformerConfig, rows: Rows, cache: Dict[str, jax.Array], table: bool = False):
+    """Row ``b`` of a decode step IS lane ``b``, whose ring holds the lane's
+    newest tokens by position: the step reads ``min(position + 1, window)`` of
+    them and nothing older, by the paged path over the ring (``table``: gathered)."""
+    lanes, store = rows.pos.shape[0], cache[WINDOW_RING.leaves[0]].shape[1]
+    ring_blocks = store // lanes
+    with jax.named_scope("serve.kv.write"):  # lane b's ring; an idle lane's row is dropped
+        first = jnp.arange(lanes, dtype=jnp.int32) * ring_blocks
+        phys = jnp.where(rows.live, first + (rows.pos // rows.block_size) % ring_blocks, store)
+    if table:
+        attend = _attend_ring_table(cfg, rows.lane_positions)
+    else:
+        rings = first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :]
+        attend = _attend_paged(cfg, WINDOW_RING, rings, rows.lane_positions, cfg.sliding_window)
+    return _kv_mixer(cfg, WINDOW_RING, rows, (phys, rows.where[1]), attend, drop=True)
+
+
+def _ring_walk(cfg: TransformerConfig, cache: Dict[str, jax.Array], lanes: jax.Array, chunk_tokens: int):
+    """The walk keeps a prompt's rows in the ring of the decode lane it will run
+    in.  A chunk's rows go to the slots of their positions, and its queries read
+    the ring back to ``window - 1`` positions before each of them: the ring is
+    one chunk longer than the window, so no row a query of the chunk still sees
+    is overwritten."""
+    _, store, block_size, _ = cache[WINDOW_RING.leaves[0]].shape
+    ring_blocks = window_ring_blocks(cfg, block_size, chunk_tokens)
+    if store % ring_blocks:
+        raise ValueError(
+            f"the window store ({store} blocks) is not whole rings of {ring_blocks} blocks: it was sized "
+            f"for another prefill chunk than {chunk_tokens} tokens"
+        )
+    with jax.named_scope("serve.kv.write"):
+        first = lanes * ring_blocks
+        rings = first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :]
+
+    def at_chunk(rows: Rows):
+        with jax.named_scope("serve.kv.write"):  # rows outside [start, len) are dropped
+            cols = (rows.chunk * (chunk_tokens // block_size) + rows.offsets // block_size) % ring_blocks
+            phys = jnp.where(rows.live, first[:, None] + cols[None, :], store)
+        attend = _attend_chunk(cfg, WINDOW_RING, rings, rows.chunk, cfg.sliding_window)
+        return _kv_mixer(cfg, WINDOW_RING, rows, (phys, rows.where[1]), attend, drop=True)
+
+    return at_chunk
+
+
+def _ring_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    lanes, chunk_tokens = sizes.max_batch, sizes.prefill_chunk
+    if lanes is None or chunk_tokens is None or chunk_tokens % sizes.block_size:
+        raise ValueError(
+            "a model with sliding-window layers needs its lanes and its prefill chunk (whole blocks) "
+            f"to size the window store (got lanes={lanes}, chunk_tokens={chunk_tokens})"
+        )
+    return (window_store_shape(cfg, lanes, sizes.block_size, chunk_tokens),) * 2
+
+
+def _ring_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
+    """The cached tokens this step's attention reads, by kind of layer."""
+    lens = jnp.where(active, pos + 1, 0).astype(jnp.float32)
+    n_window = len(cfg.window_layers)
+    return jnp.stack([
+        jnp.sum(lens) * (cfg.n_layers - n_window), jnp.sum(jnp.minimum(lens, cfg.sliding_window)) * n_window,
+    ])
+
+
+def _ring_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
+    """``window_store`` (empty where no layer slides): the bytes the store takes
+    whatever the contexts, and the tokens a lane's ring holds a layer."""
+    if not cfg.window_layers:
+        return {"window_store": {}}
+    ring_tokens = window_ring_blocks(cfg, sizes.block_size, sizes.prefill_chunk) * sizes.block_size
+    return {"window_store": {"window_store_bytes": _nbytes(WINDOW_RING, cfg, sizes), "ring_tokens": ring_tokens}, **_kv_report(cfg)}
+
+
+def _ring_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    # what a token costs in each kind of cache, then what /stats says of the store
+    layer, n_window = kv_bytes_per_token(cfg) // cfg.n_layers, len(cfg.window_layers)
+    said = _ring_report(cfg, sizes)
+    return {"bytes_per_token_full": layer * (cfg.n_layers - n_window), "bytes_per_token_window": layer * n_window,
+            **said.pop("window_store"), **said}
+
+
+# -- a latent row a token -------------------------------------------------------
+#
+# Its forms ``attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j)`` with q_nope
+# [b, n_heads, s, qk_nope], q_rope [b, n_heads, s, qk_rope] (after rope), this
+# call's own latent rows c_kv [b, s, kv_lora] (after their norm) and k_r [b, s,
+# qk_rope] (after rope), ``wkv_b`` [kv_lora, n_heads, qk_nope + v] and the pool
+# that already holds the rows; they return [b, s, n_heads, v_head_dim].
+# ``_latent_attend_local`` (``models/transformer.py``) expands keys and values a
+# head from the rows, as the equations are published; the others stay in the
+# latent space (``q_lat_h = q_nope_h W^K_h``, ``o_h = (sum p c_kv) W^V_h``: the
+# same mathematics, and one row a token serves every head's scores and values).
+
+
+def _latent_mixer(cfg: TransformerConfig, rows: Rows, attend):
+    """Latent attention's projections, this call's rows ``[c_kv after its norm |
+    k_r after rope | zeros]`` into the pool at ``rows.where``, ``attend``
+    against the pool that now holds them, and the output projection."""
+    (phys, slots), (leaf,) = rows.where, PAGED_LATENT.leaves
+    rope = cfg.rope(FULL)
+
+    def mix(p, x, h, cache, j):
+        with jax.named_scope("serve.mla"):
+            q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, h, rows.positions, rope)
+            with jax.named_scope("serve.kv.write"):
+                row = jnp.concatenate([c_kv, k_r], axis=-1)
+                row = jnp.pad(row, ((0, 0), (0, 0), (0, cache[leaf].shape[-1] - row.shape[-1])))
+                cache = {**cache, leaf: cache[leaf].at[j, phys, slots].set(row.reshape(*phys.shape, -1))}
+            att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, j)
+            return x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(cfg.dtype)), cache
+
+    return mix
+
+
+def _latent_split(cfg, wkv_b, q_nope):
+    """(queries in the latent space [b, h, s, kv_lora], W^V [kv_lora, h, v])."""
+    w = wkv_b.astype(cfg.dtype)
+    return jnp.einsum("bhsn,chn->bhsc", q_nope, w[..., : cfg.qk_nope_head_dim]), w[..., cfg.qk_nope_head_dim:]
+
+
+def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array):
+    """One query a lane against the lane's live latent rows, read where they
+    lie in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle."""
+    (leaf,) = PAGED_LATENT.leaves
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)[:, :, 0]
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, cache[leaf].shape[-1] - q.shape[-1])))
+        with jax.named_scope("serve.mla.attend"):  # the kernel alone: what its roofline share times
+            out = paged_latent_attention(
+                q, cache[leaf], j, block_tables, positions, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+            )
+        return jnp.einsum("bhc,chv->bhv", out.astype(cfg.dtype), w_v)[:, None]
+
+    return attend
+
+
+def _latent_attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
+    """The prefill walk's read (``_attend_chunk``) in the latent space: every
+    head's queries ``[q_lat | q_rope | zeros]`` against the pool's rows, whose
+    first ``kv_lora_rank`` columns are the values."""
+    (leaf,) = PAGED_LATENT.leaves
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, cache[leaf].shape[-1] - q.shape[-1]),))
+        with jax.named_scope("serve.mla.attend"):
+            out = paged_chunk_attention(
+                q[:, None], cache[leaf], None, j, block_tables, chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+            )
+        return jnp.einsum("bhsc,chv->bshv", out[:, 0].astype(cfg.dtype), w_v)
+
+    return attend
+
+
+def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
+    """Queries against every row of every table column, gathered from the
+    pool, under ``mask`` (as ``_attend_table``'s) and a float32 softmax:
+    decode without the paged path, the oracle that path is tested against."""
+    (leaf,) = PAGED_LATENT.leaves
+
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+        b, t = block_tables.shape
+        r = cfg.kv_lora_rank
+        found = cache[leaf][j, block_tables].reshape(b, t * cache[leaf].shape[2], -1)
+        lat, rot = found[..., :r], found[..., r: r + cfg.qk_rope_head_dim]
+        q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
+        logits = jnp.einsum("bhsc,bkc->bhsk", q_lat, lat, preferred_element_type=jnp.float32)
+        logits = logits + jnp.einsum("bhsr,bkr->bhsk", q_rope, rot, preferred_element_type=jnp.float32)
+        seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+        probs = jax.nn.softmax(jnp.where(seen, logits * cfg.attn_scale, NEG_INF), axis=-1)
+        out = jnp.einsum("bhsk,bkc->bhsc", probs.astype(lat.dtype), lat)
+        return jnp.einsum("bhsc,chv->bshv", out, w_v)
+
+    return attend
+
+
+# -- a state a lane ---------------------------------------------------------------
+#
+# Its forms ``retain(q, k, v, log_g, cache, j)`` with q [b, n_heads, s,
+# head_dim], k, v [b, kv_heads, s, head_dim] and the gate's logarithm [b,
+# kv_heads, s]; they return ([b, n_heads, s, head_dim], the cache with the lanes'
+# slots updated): write and attend in one, the state is both.
+
+
+def _retention_proj(cfg: TransformerConfig, p: Dict[str, Any], h: jax.Array, positions: jax.Array):
+    """A retention layer's q, k, v ``[b, heads, s, d]`` as ``Retention`` makes
+    them (a norm a head, rotary) and the gate's logarithm ``[b, kv_heads, s]``."""
+    q, k, v = _attn_proj(p, h, cfg.dtype)
+    log_g = _gate_log(cfg, h @ p["wg"]["kernel"].astype(cfg.dtype)).transpose(0, 2, 1)
+    if cfg.qk_norm:
+        q, k = _rms(q, p["q_norm"], cfg.norm_eps), _rms(k, p["k_norm"], cfg.norm_eps)
+    rope = cfg.rope(RETENTION)
+    return _rope(q, positions, rope), _rope(k, positions, rope), v, log_g
+
+
+def _state_mixer(cfg: TransformerConfig, rows: Rows, retain):
+    """A power-retention layer writes no row and attends to none: ``retain``
+    updates its lanes' slots of the state pool and answers from them."""
+
+    def mix(p, x, h, cache, j):
+        with jax.named_scope("serve.retention.qkvg"):
+            q, k, v, log_g = _retention_proj(cfg, p, h, rows.positions)
+        with jax.named_scope("serve.retention.state"):  # decay, update, query, normalise
+            att, cache = retain(q, k, v, log_g, cache, j)
+        with jax.named_scope("serve.retention.out"):
+            return x + jnp.einsum("bshk,hkD->bsD", att.transpose(0, 2, 1, 3), p["wo"]["kernel"].astype(cfg.dtype)), cache
+
+    return mix
+
+
+def _state_chunk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """``s`` tokens a row after what the slots of the rows' lanes hold (nothing,
+    in the walk's first chunk: a sequence starts from a zeroed slot), and into
+    them: the prefill walk's chunk, and the wide prefill as one chunk."""
+    state_leaf, norm_leaf = STATE_SLOT.leaves
+    lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
+    fresh = rows.chunk == rows.first_chunk
+
+    def retain(q, k, v, log_g, cache, j):
+        state, norm = cache[state_leaf][j, lanes], cache[norm_leaf][j, lanes]
+        state, norm = jnp.where(fresh, 0.0, state), jnp.where(fresh, 0.0, norm)
+        out, state, norm = retention_chunk(q, k, v, log_g, state, norm, rows.live)
+        return out.astype(cfg.dtype), {
+            **cache, state_leaf: cache[state_leaf].at[j, lanes].set(state), norm_leaf: cache[norm_leaf].at[j, lanes].set(norm),
+        }
+
+    return _state_mixer(cfg, rows, retain)
+
+
+def _state_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """One token a lane, row ``b`` of the batch IS lane ``b``: the slot is
+    decayed, takes the token and answers it (``ops/retention.py
+    retention_decode``: the Pallas kernel on a TPU, in place); a lane that is
+    not live leaves its slot alone."""
+    state_leaf, norm_leaf = STATE_SLOT.leaves
+
+    def retain(q, k, v, log_g, cache, j):
+        out, state, norm = retention_decode(
+            q[:, :, 0], k[:, :, 0], v[:, :, 0], log_g[:, :, 0], cache[state_leaf], cache[norm_leaf], j, rows.live
+        )
+        return out.astype(cfg.dtype)[:, :, None, :], {**cache, state_leaf: state, norm_leaf: norm}
+
+    return _state_mixer(cfg, rows, retain)
+
+
+def _state_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    if sizes.max_batch is None:
+        raise ValueError("a model with power-retention layers needs its lanes to size the state pool")
+    return state_pool_shapes(cfg, sizes.max_batch)
+
+
+def _state_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
+    """The lanes whose state this step updated, and the bytes they hold."""
+    lanes = jnp.sum(active.astype(jnp.float32))
+    return jnp.stack([lanes, lanes * (len(cfg.retention_layers) * state_bytes_per_slot(cfg))])
+
+
+def _state_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
+    """``state``: the slots (one a lane), how many hold a sequence, and the bytes
+    one holds over the retention layers; ``block_ids_address_nothing``: no layer
+    reads the pool ``kv_cache`` counts, and admission is by free lane alone."""
+    if not cfg.retention_layers:
+        return {}
+    per_slot = len(cfg.retention_layers) * state_bytes_per_slot(cfg)
+    return {
+        "state": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": per_slot},
+        "block_ids_address_nothing": not any(kind.holds == BLOCKS for kind in cache_kinds(cfg)),
+    }
+
+
+def _state_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    slots = _state_report(cfg, sizes)["state"]
+    return {"slots": slots["slots"], "bytes_per_slot": slots["bytes_per_slot"], "state_pool_bytes": _nbytes(STATE_SLOT, cfg, sizes)}
+
+
+# -- the table ----------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """One kind of cache: everything the forward and the engine know of it."""
+
+    name: str
+    #: its layers in a config: those of ``layer_type`` in a model whose attention is ``latent`` (or is not)
+    layer_type: str
+    latent: bool
+    #: the arrays it owns in the cache, and ``shapes(cfg, sizes)`` -> a shape each (``sizes``: a ``ServeConfig``'s
+    #: ``num_blocks``, ``block_size``, ``max_batch``, ``prefill_chunk``), of ``dtype(cfg)``
+    leaves: Tuple[str, ...]
+    shapes: Callable
+    #: what a request holds of it, BLOCKS or LANE; for LANE, why ``prefix_cache`` cannot be served
+    holds: str
+    #: its mixers: ``step``, ``table`` and ``wide`` are ``(cfg, rows, cache) -> mix`` (``wide`` None: the kind has
+    #: no wide prefill); ``walk(cfg, cache, lanes, chunk_tokens)``, called before the loop, returns ``rows -> mix``
+    step: Callable
+    walk: Callable
+    table: Callable
+    wide: Optional[Callable]
+    no_prefix_cache: Optional[str] = None
+    dtype: Callable = lambda cfg: cfg.dtype
+    #: what a decode step counts for it, by name, and ``count(cfg, active [b], pos [b])`` -> float32, one each
+    counters: Tuple[str, ...] = ()
+    count: Optional[Callable] = None
+    #: ``report(cfg, sizes, live lanes)``: what it adds to ``/stats``, asked of EVERY kind of the table (one without
+    #: layers in the model says so itself: nothing, or an empty entry); ``setup(cfg, sizes)``: what a kind of the
+    #: model adds to the ``serve.setup.kv_pool`` span
+    report: Callable = lambda cfg, sizes, live: {}
+    setup: Callable = lambda cfg, sizes: {}
+
+    def layers(self, cfg: TransformerConfig) -> Tuple[int, ...]:
+        """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its arrays."""
+        if cfg.latent != self.latent:
+            return ()
+        return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) == self.layer_type)
+
+
+def _nbytes(kind: CacheKind, cfg: TransformerConfig, sizes: Any) -> int:
+    return sum(math.prod(shape) for shape in kind.shapes(cfg, sizes)) * jnp.dtype(kind.dtype(cfg)).itemsize
+
+
+def _every_chunk(build: Callable):
+    """The ``walk`` of a kind that prepares nothing before the loop: ``build(cfg, rows)`` a chunk."""
+    return lambda cfg, cache, lanes, chunk_tokens: functools.partial(build, cfg)
+
+
+PAGED_KV = CacheKind(
+    name="paged_kv", layer_type=FULL, latent=False, leaves=("k", "v"), shapes=_paged_shapes, holds=BLOCKS,
+    step=lambda cfg, rows, cache: _kv_mixer(
+        cfg, PAGED_KV, rows, rows.where, _attend_paged(cfg, PAGED_KV, rows.block_tables, rows.lane_positions)),
+    walk=_every_chunk(lambda cfg, rows: _kv_mixer(
+        cfg, PAGED_KV, rows, rows.where, _attend_chunk(cfg, PAGED_KV, rows.block_tables, rows.chunk))),
+    table=lambda cfg, rows, cache: _kv_mixer(
+        cfg, PAGED_KV, rows, rows.where, _attend_table(cfg, rows.block_tables, _table_mask(rows))),
+    wide=lambda cfg, rows, cache: _kv_mixer(cfg, PAGED_KV, rows, rows.where, _attend_local),
+    report=_kv_report, setup=_kv_report,
+)
+
+PAGED_LATENT = CacheKind(
+    name="paged_latent", layer_type=FULL, latent=True, leaves=("kv",), shapes=_paged_shapes, holds=BLOCKS,
+    step=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_paged(cfg, rows.block_tables, rows.lane_positions)),
+    walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk))),
+    table=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows))),
+    wide=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_local(cfg)),
+)
+
+STATE_SLOT = CacheKind(
+    name="state_slot", layer_type=RETENTION, latent=False, leaves=("rs", "rz"), shapes=_state_shapes, holds=LANE,
+    dtype=lambda cfg: transformer.STATE_DTYPE,  # read where it is stated: the benchmark's check sets another there
+    no_prefix_cache=(
+        "prefix_cache shares a prompt's full blocks between requests, and a block holds no state: a "
+        "power-retention layer keeps a request's whole context in its own lane's state slot, and a prefill "
+        "from the first un-cached token would need the state as it stood at that block's edge (a snapshot "
+        "nobody keeps). Set prefix_cache: false"
+    ),
+    step=_state_step, walk=_every_chunk(_state_chunk), table=_state_step, wide=_state_chunk,
+    # the lanes whose state the step updated, and the bytes of state those hold over the retention layers
+    counters=("serve.state.live_lanes", "serve.state.bytes"), count=_state_count,
+    report=_state_report, setup=_state_setup,
+)
+
+WINDOW_RING = CacheKind(
+    name="window_ring", layer_type=SLIDING, latent=False, leaves=("wk", "wv"), shapes=_ring_shapes, holds=LANE,
+    no_prefix_cache=(
+        "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no "
+        "window state: the sliding-window layers keep a request's newest tokens in its own lane's ring, "
+        "which a prefill from the first un-cached token would leave without the prefix. Set prefix_cache: false"
+    ),
+    step=_ring_step, walk=_ring_walk, table=functools.partial(_ring_step, table=True), wide=None,
+    # the cached tokens the step's attention reads, summed over the lanes, in the full and in the window layers
+    counters=("serve.kv.full_tokens", "serve.kv.window_tokens"), count=_ring_count,
+    report=_ring_report, setup=_ring_setup,
+)
+
+#: every kind, in the order a decode step's counters and a walk's chunk take them
+CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, STATE_SLOT, WINDOW_RING)
+
+
+def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:
+    """The kinds ``cfg``'s layers are of, in the table's order."""
+    return tuple(kind for kind in CACHE_KINDS if kind.layers(cfg))
+
+
+def layer_kind(cfg: TransformerConfig, i: int) -> Tuple[CacheKind, int]:
+    """Layer ``i``'s kind, and its place among the layers of that kind: its row in the kind's arrays."""
+    (kind,) = (kind for kind in CACHE_KINDS if i in kind.layers(cfg))
+    return kind, kind.layers(cfg).index(i)
+
+
+def pool_block_size(cfg: TransformerConfig, cache: Dict[str, jax.Array]) -> Optional[int]:
+    """Tokens a block of the paged pool, read off the cache; None where no kind of the model holds blocks."""
+    for kind in cache_kinds(cfg):
+        if kind.holds == BLOCKS:
+            return cache[kind.leaves[0]].shape[2]
+    return None

@@ -280,25 +280,19 @@ class TransformerConfig:
     @property
     def window_layers(self) -> Tuple[int, ...]:
         """The sliding-window layers, in order: serving keeps their keys and
-        values in a store of its own (``init_kv_cache``)."""
+        values in a store of its own (``models/cache_kinds.py``)."""
         return tuple(i for i in range(self.n_layers) if self.layer_type(i) == SLIDING)
 
     @property
     def retention_layers(self) -> Tuple[int, ...]:
         """The power-retention layers, in order: serving keeps a state a decode
-        lane for each (``init_kv_cache``), and no token's keys or values."""
+        lane for each (``models/cache_kinds.py``), and no token's keys or values."""
         return tuple(i for i in range(self.n_layers) if self.layer_type(i) == RETENTION)
 
     @property
     def paged_layers(self) -> int:
         """How many layers keep a token's rows in the paged pool."""
         return self.n_layers - len(self.window_layers) - len(self.retention_layers)
-
-    def cache_index(self, i: int) -> int:
-        """Layer ``i``'s place among the layers of its own type: its index in
-        the paged pool (full layers), in the window store (sliding layers) or
-        in the state pool (retention layers)."""
-        return sum(1 for j in range(i) if self.layer_type(j) == self.layer_type(i))
 
     def rope(self, layer_type: str) -> Optional["Rope"]:
         """How a layer of this type rotates q and k; None: it does not."""
@@ -930,11 +924,8 @@ def pipeline_forward(
     return (logits, aux) if return_aux else logits
 
 
-# ---------------------------------------------------------------------------
-# The serving cache's sizes (the forward that reads it: models/serving.py)
-# ---------------------------------------------------------------------------
-# Functions of the config alone, beside it: the engine, the benchmark and the
-# cost models size a deployment with them without loading the serving forward.
+# The serving cache's sizes, functions of the config alone (the forward that
+# reads the cache is ``models/serving.py``, over ``models/cache_kinds.py``).
 
 
 def latent_row_width(cfg: TransformerConfig) -> int:
@@ -998,8 +989,7 @@ def state_bytes_per_slot(cfg: TransformerConfig) -> int:
     return (math.prod(state[1:]) + math.prod(norm[1:])) * jnp.dtype(STATE_DTYPE).itemsize
 
 
-# What ``LatentAttention`` above and the serving forward both run: one statement
-# of latent attention's projections and of its expanded, causal form.
+# What ``LatentAttention`` above and the serving forward both run.
 
 
 def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

@@ -229,6 +229,7 @@ class DecodeKernels:
     def __init__(self, model_cfg: Any, params: Any, serve_cfg: ServeConfig) -> None:
         import jax
 
+        from determined_tpu.models.cache_kinds import BLOCKS, LANE, cache_kinds
         from determined_tpu.models.serving import (
             _check_decodable,
             init_kv_cache,
@@ -236,40 +237,22 @@ class DecodeKernels:
             transformer_decode,
             transformer_prefill_chunked,
         )
-        from determined_tpu.models.transformer import (
-            kv_bytes_per_token,
-            state_bytes_per_slot,
-            window_ring_blocks,
-        )
-        from determined_tpu.ops.paged_attention import attn_products
+        from determined_tpu.models.transformer import kv_bytes_per_token
         from determined_tpu.utils.compilation_cache import (
             setup_compilation_cache,
             timed_first_call,
         )
 
         _check_decodable(model_cfg)
-        #: a model with sliding-window layers: their keys and values live in
-        #: a ring a decode lane, not in the blocks the allocator hands out
-        self.windowed = bool(model_cfg.window_layers)
-        if self.windowed and serve_cfg.prefix_cache:
-            raise ValueError(
-                "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no "
-                "window state: the sliding-window layers keep a request's newest tokens in its own lane's ring, "
-                "which a prefill from the first un-cached token would leave without the prefix. Set prefix_cache: false"
-            )
-        #: a model with power-retention layers: what a request holds of them is
-        #: one state slot, its lane's, whatever its length
-        self.stateful = bool(model_cfg.retention_layers)
-        #: whether any layer reads the paged pool: where none does, the
-        #: allocator's block ids address nothing and no array is made for them
-        self.paged = model_cfg.paged_layers > 0
-        if self.stateful and serve_cfg.prefix_cache:
-            raise ValueError(
-                "prefix_cache shares a prompt's full blocks between requests, and a block holds no state: a "
-                "power-retention layer keeps a request's whole context in its own lane's state slot, and a prefill "
-                "from the first un-cached token would need the state as it stood at that block's edge (a snapshot "
-                "nobody keeps). Set prefix_cache: false"
-            )
+        #: the kinds of cache this model's layers keep (``models/cache_kinds.py``):
+        #: what the engine derives admission, its refusals and ``/stats`` from
+        self.kinds = cache_kinds(model_cfg)
+        #: some kind is held by the decode lane: no block holds what it keeps, so
+        #: no prefix is shared and a prefill takes its lane and starts at 0
+        lane_held = [kind for kind in self.kinds if kind.holds == LANE]
+        self._lane_held = bool(lane_held)
+        if lane_held and serve_cfg.prefix_cache:
+            raise ValueError(lane_held[0].no_prefix_cache)
         tracer = get_tracer()
         t_setup = mono()
         # a relaunched replica loads its two kernels from disk; keeps the
@@ -297,56 +280,25 @@ class DecodeKernels:
             "serve.setup.params_to_device", "serve", t_params, t_pool,
             {"bytes": param_bytes},
         )
-        #: ``/stats`` ``window_store`` (empty where no layer slides): the bytes
-        #: the window layers' store takes whatever the contexts, and a lane's ring
-        self.window_store: Dict[str, int] = {}
-        kinds: Dict[str, Any] = {}
-        if self.windowed:
-            self.window_store = {
-                "window_store_bytes": int(self.cache["wk"].nbytes + self.cache["wv"].nbytes),
-                "ring_tokens": window_ring_blocks(model_cfg, serve_cfg.block_size, serve_cfg.prefill_chunk) * serve_cfg.block_size,
-            }
-            # what a token costs in each kind of cache
-            layer = kv_bytes_per_token(model_cfg) // model_cfg.n_layers
-            n_window = len(model_cfg.window_layers)
-            kinds = {
-                "bytes_per_token_full": layer * (model_cfg.n_layers - n_window),
-                "bytes_per_token_window": layer * n_window,
-                **self.window_store,
-            }
-        #: ``/stats`` ``state`` (empty without retention layers): the slots
-        #: (one a lane) and the bytes one holds over the retention layers
-        self.state_pool: Dict[str, int] = {}
-        if self.stateful:
-            self.state_pool = {
-                "slots": serve_cfg.max_batch,
-                "bytes_per_slot": len(model_cfg.retention_layers) * state_bytes_per_slot(model_cfg),
-            }
-            kinds = {**kinds, **self.state_pool, "state_pool_bytes": int(self.cache["rs"].nbytes + self.cache["rz"].nbytes)}
-            if not self.paged:
-                logger.info(
-                    "no layer of this model reads the paged pool: the allocator's %d block ids address nothing and no "
-                    "array was made for them; a request holds one of %d state slots (%d bytes) whatever its length",
-                    serve_cfg.num_blocks, serve_cfg.max_batch, self.state_pool["bytes_per_slot"],
-                )
-        #: ``/stats`` ``attn_products``: what a tile of the GQA decode kernel
-        #: multiplies at this model's heads (``ops/paged_attention.py``); None
-        #: for latent layers, whose heads all share a row, and where no layer reads K and V
-        self.attn_products: Optional[str] = None
-        if not model_cfg.latent and len(model_cfg.retention_layers) < model_cfg.n_layers:
-            kinds["attn_products"] = self.attn_products = attn_products(model_cfg.n_heads // model_cfg.kv_heads)
-        # bytes_per_token: what attention reads of the pool for one cached
-        # token over all layers (K and V rows, or one latent row a layer)
-        tracer.record_span(
-            "serve.setup.kv_pool", "serve", t_pool, t_pooled,
-            {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg), **kinds},
-        )
+        # bytes_per_token: what attention reads of the cache for one cached
+        # token over all layers that cache tokens (K and V rows, or one latent
+        # row a layer); then what each kind says of its own store
+        setup: Dict[str, Any] = {"bytes": pool_bytes, "bytes_per_token": kv_bytes_per_token(model_cfg)}
+        for kind in self.kinds:
+            setup.update(kind.setup(model_cfg, serve_cfg))
+        tracer.record_span("serve.setup.kv_pool", "serve", t_pool, t_pooled, setup)
+        if not any(kind.holds == BLOCKS for kind in self.kinds):
+            logger.info(
+                "no layer of this model reads the paged pool: the allocator's %d block ids address nothing and no "
+                "array was made for them; a request holds the store of its decode lane (one of %d) whatever its length",
+                serve_cfg.num_blocks, serve_cfg.max_batch,
+            )
         #: (call, jitted call returned, logits ready on the device) of the
         #: newest ``decode``: the engine, which knows the step, turns them
         #: into ``serve.decode.dispatch`` and ``serve.decode.wait``
         self.last_decode_stamps: Optional[Tuple[float, float, float]] = None
-        #: a model with expert, window or retention layers: the decode program
-        #: returns one more row of logits, whose first entries are these
+        #: a model with expert layers or a cache kind that counts: the decode
+        #: program returns one more row of logits, whose first entries are these
         #: counts of the step (``transformer_decode``), in this order; the
         #: engine's sampler hands them back beside the tokens
         self.counters: Tuple[str, ...] = serve_counters(model_cfg)
@@ -357,11 +309,10 @@ class DecodeKernels:
         # cold requests run it with start=0, warm requests from the chunk
         # of their first un-cached block; either way it is the SAME trace
         # (dynamic trip count inside the program)
-        # a cache without a pool states no block size: the walk is told its chunk
-        chunk = {} if self.paged else {"chunk_tokens": serve_cfg.prefill_chunk}
+        # the walk is told its chunk: a cache without a pool states no block size
         prefill = sentinel.wrap(
             "serve.prefill_step",
-            functools.partial(transformer_prefill_chunked, model_cfg, **chunk),
+            functools.partial(transformer_prefill_chunked, model_cfg, chunk_tokens=serve_cfg.prefill_chunk),
             allowed=1,
         )
         decode = sentinel.wrap(
@@ -411,8 +362,8 @@ class DecodeKernels:
         """Prefill one sequence from its first token, writing its K/V into
         the paged cache; returns the f32 logits at the last prompt token.
         ``lane``: the decode lane (the row of ``decode``'s batch) the sequence
-        will run in, whose ring a model's sliding-window layers write; a
-        model without them takes no notice of it."""
+        will run in, whose store a kind of cache that a request holds by its
+        lane writes; a model without one takes no notice of it."""
         return self._prefill_from(prompt, block_table, 0, lane)
 
     def prefill_suffix(
@@ -431,7 +382,7 @@ class DecodeKernels:
         starts = np.asarray([start], np.int32)
         lens = np.asarray([len(prompt)], np.int32)
         args = (self.params, tokens, starts, lens, table, self.cache)
-        if self.windowed or self.stateful:
+        if self._lane_held:
             if start:
                 raise ValueError(
                     f"a prompt of a model with sliding-window or power-retention layers is prefilled from 0, not from {start}"
@@ -486,10 +437,12 @@ class ServeEngine:
         self.kernels = kernels
         self.cfg = kernels.serve_cfg
         self.lanes = LaneTable(self.cfg.max_batch)
-        #: False for a model none of whose layers reads the paged pool: a
-        #: request then holds a state slot (its lane) and no block, and
-        #: admission never asks the allocator (a stand-in for the kernels is paged)
-        self.kernels_paged = bool(getattr(kernels, "paged", True))
+        from determined_tpu.models.cache_kinds import BLOCKS
+
+        #: whether a request holds blocks of some kind of the kernels' cache
+        #: (``models/cache_kinds.py``).  Where none does, a request holds the
+        #: store of its lane and no block, and admission never asks the allocator
+        self._holds_blocks = any(kind.holds == BLOCKS for kind in kernels.kinds)
         #: trial/model label surfaced in the master's replica listing
         self.model_label = type(kernels.model_cfg).__name__
         self.allocator = BlockAllocator(
@@ -611,7 +564,7 @@ class ServeEngine:
         )
         if new < 1:  # 0 is a client error, not "use the default"
             raise AdmissionRejected(400, "max_new_tokens must be >= 1")
-        if self.kernels_paged and self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
+        if self._holds_blocks and self.allocator.blocks_for(len(prompt) + new) > self.allocator.capacity:
             # permanent: this request can NEVER fit this replica's cache
             raise AdmissionRejected(
                 413, "request exceeds kv cache capacity (kv_cache_oom)"
@@ -793,8 +746,10 @@ class ServeEngine:
             }
             with self._stats_lock:
                 self._latency_summary = (completed, latency)
+        from determined_tpu.models.cache_kinds import CACHE_KINDS
+
         kv = self.allocator.stats()
-        products = getattr(self.kernels, "attn_products", None)
+        live = self.lanes.stats()["active"]
         return {
             **counters,
             # what a caller feels, over the newest LATENCY_WINDOW finished
@@ -820,18 +775,11 @@ class ServeEngine:
             # the TTL behind a 500 /healthz
             "failed": self.failed,
             "kv_cache": kv,
-            # a model with sliding-window layers: the bytes their store takes
-            # whatever the contexts, and the tokens a lane's ring holds a layer
-            # (``kv_cache`` counts the full layers' blocks alone)
-            "window_store": dict(getattr(self.kernels, "window_store", None) or {}),
-            # a model with power-retention layers: its state slots (one a
-            # lane), how many hold a sequence, and the bytes one holds over those
-            # layers; ``block_ids_address_nothing``: no layer reads the pool
-            # ``kv_cache`` counts, and admission is by free lane alone
-            **self._state_stats(),
-            # what a tile of the GQA decode kernel multiplies ("per_kv_head" |
-            # "block_diagonal"); absent for latent layers
-            **({"attn_products": products} if products else {}),
+            # what each kind of cache says of its own store (``window_store``,
+            # ``state`` with ``block_ids_address_nothing``, ``attn_products``:
+            # ``models/cache_kinds.py``; one the model has no layer of says so
+            # itself); ``kv_cache`` counts the allocator's blocks alone
+            **{k: v for kind in CACHE_KINDS for k, v in kind.report(self.kernels.model_cfg, self.cfg, live).items()},
             # live-block fraction, shared (ref>1) blocks counted ONCE so
             # prefix sharing never inflates the router's load signal
             "kv_utilization": round(kv["used"] / max(1, kv["capacity"]), 4),
@@ -842,15 +790,6 @@ class ServeEngine:
             ),
             "uptime_s": round(time.monotonic() - self._started_at, 3),
             "lanes": self.lanes.stats(),
-        }
-
-    def _state_stats(self) -> Dict[str, Any]:
-        pool = getattr(self.kernels, "state_pool", None)
-        if not pool:
-            return {}
-        return {
-            "state": {"slots": pool["slots"], "live": self.lanes.stats()["active"], "bytes_per_slot": pool["bytes_per_slot"]},
-            "block_ids_address_nothing": not self.kernels_paged,
         }
 
     # -- the engine thread's work ---------------------------------------------
@@ -880,8 +819,8 @@ class ServeEngine:
         """
         tracer = self._tracer
         t_admit = mono()  # just off the queue
-        # a model without a paged layer holds no block: the free lane is the admission
-        total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens) if self.kernels_paged else 0
+        # a request that holds no block of any kind: the free lane is the admission
+        total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens) if self._holds_blocks else 0
         shared: List[int] = []
         cached_tokens = 0
         chain: List[Any] = []
@@ -908,7 +847,7 @@ class ServeEngine:
         )
         blocks = shared + private
         table = self._padded_table(blocks)
-        # the lane is known before the prefill: a window layer's rows go to its ring
+        # the lane is known before the prefill: a kind held by the lane writes its store
         lane = self.lanes.free_lane()
         # the walk's trip count: whole chunks, from the one the first
         # un-cached token lies in (0 cached when nothing matched)

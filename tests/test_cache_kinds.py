@@ -1,0 +1,105 @@
+"""The table of cache kinds (``models/cache_kinds.py``) is where the forward,
+the kernels and the engine take what they know of a model's cache: over the
+benchmark's four serving architectures at their tiny sizes, the cache is the
+union of the kinds' leaves, what the engine does at admission follows from what
+a request holds of each kind, and the decode step's counters are the kinds'."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_kinds, layer_kind
+from determined_tpu.models.serving import SERVE_COUNTERS, init_kv_cache, serve_counters
+from determined_tpu.serve.config import ServeConfig
+from determined_tpu.serve.engine import DecodeKernels, ServeEngine
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+if BENCH not in sys.path:  # an architecture's adapter imports the harness beside it
+    sys.path.insert(0, BENCH)
+
+#: the architecture's tiny form -> the kinds its layers are of
+ARCHS = {
+    "dense_decoder": ("paged_kv",),
+    "deepseek_mla_moe": ("paged_latent",),
+    "cohere2_moe": ("paged_kv", "window_ring"),
+    "power_retention": ("state_slot",),
+}
+
+
+def _tiny(arch_name):
+    with open(os.path.join(REPO, "tests", "benchmark", "tiny", arch_name + ".json")) as f:
+        form = json.load(f)
+    from benchlib import model as bench_model
+
+    arch = bench_model.load_file(os.path.join(BENCH, "archs", arch_name + ".py"), arch_name)
+    serve_cfg = ServeConfig(**form["serve_engine"])
+    cfg = arch.model_config(form["config"], serve_cfg.max_seq_len)
+    return cfg, arch.init_params(cfg, 0), serve_cfg, form
+
+
+@pytest.mark.parametrize("arch_name", list(ARCHS))
+def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
+    cfg, params, serve_cfg, form = _tiny(arch_name)
+    kinds = cache_kinds(cfg)
+    assert tuple(kind.name for kind in kinds) == ARCHS[arch_name] and set(kinds) <= set(CACHE_KINDS)
+    # every layer is of one kind, at the next row of that kind's arrays
+    rows = {kind.name: 0 for kind in kinds}
+    for i in range(cfg.n_layers):
+        kind, j = layer_kind(cfg, i)
+        assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j] == i
+        rows[kind.name] += 1
+
+    # the cache: the union of the kinds' leaves, each of the kind's shape and dtype, a row a layer of the kind
+    sizes = serve_cfg  # a kind reads num_blocks, block_size, max_batch and prefill_chunk of it
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, sizes.num_blocks, sizes.block_size, sizes.max_batch, sizes.prefill_chunk))
+    want = {leaf: (shape, jnp.dtype(kind.dtype(cfg))) for kind in kinds for leaf, shape in zip(kind.leaves, kind.shapes(cfg, sizes))}
+    assert {leaf: (a.shape, a.dtype) for leaf, a in cache.items()} == want
+    assert all(want[leaf][0][0] == len(kind.layers(cfg)) for kind in kinds for leaf in kind.leaves)
+
+    # the counters: the kinds' in the table's order, then the experts'
+    assert serve_counters(cfg) == sum((kind.counters for kind in kinds), ()) + (SERVE_COUNTERS if cfg.moe_experts else ())
+
+    # the engine: prefix_cache is refused iff some kind is held by the lane, with that kind's sentence ...
+    held = {BLOCKS: [k for k in kinds if k.holds == BLOCKS], LANE: [k for k in kinds if k.holds == LANE]}
+    assert all((kind.no_prefix_cache is not None) == (kind.holds == LANE) for kind in CACHE_KINDS)
+    assert form["serve_engine"]["prefix_cache"] is not bool(held[LANE])          # the tiny form states what it may
+    if held[LANE]:
+        with pytest.raises(ValueError) as refused:
+            DecodeKernels(cfg, params, ServeConfig(**{**form["serve_engine"], "prefix_cache": True}))
+        assert str(refused.value) == held[LANE][0].no_prefix_cache and "Set prefix_cache: false" in str(refused.value)
+    kernels = DecodeKernels(cfg, params, serve_cfg)
+    assert kernels.kinds == kinds and kernels.counters == serve_counters(cfg) and set(kernels.cache) == set(want)
+    engine = ServeEngine(kernels)
+    # ... the prefill takes its lane and refuses a start past 0 iff some kind is held by the lane ...
+    prompt = list(range(1, 2 * serve_cfg.block_size + 1))
+    if held[LANE]:
+        with pytest.raises(ValueError, match="is prefilled from 0, not from %d" % serve_cfg.block_size):
+            kernels.prefill_suffix(prompt, [0] * serve_cfg.blocks_per_seq, serve_cfg.block_size, 1)
+    else:
+        blocks = engine.allocator.alloc(2)
+        table = blocks + [0] * (serve_cfg.blocks_per_seq - 2)
+        kernels.prefill(prompt, table)
+        warm = kernels.prefill_suffix(prompt, table, serve_cfg.block_size, 1)
+        assert np.isfinite(warm).all() and warm.shape == (cfg.vocab_size,)
+        engine.allocator.free(blocks)
+    # ... and the allocator is asked iff some kind is held in blocks
+    req = engine.submit(prompt, max_new_tokens=3)
+    while not req.done.is_set():
+        assert engine.step_once()
+    stats = engine.stats()
+    assert req.error is None and len(req.output) == 3
+    asked = engine.allocator.blocks_for(len(prompt) + 3) if held[BLOCKS] else 0
+    assert stats["kv_cache"]["peak"] == asked and stats["kv_cache"]["used"] == 0
+    assert stats.get("block_ids_address_nothing", False) == (not held[BLOCKS])
+    assert set(stats["step_counters"]) == set(serve_counters(cfg))
+    # /stats carries what each kind of the table reports, the model's or not
+    for kind in CACHE_KINDS:
+        said = kind.report(cfg, sizes, 0)
+        assert {key: stats[key] for key in said} == said
+        assert kind in kinds or said in ({}, {"window_store": {}})

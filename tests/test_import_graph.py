@@ -86,3 +86,62 @@ def test_process_start_loads_no_checkpoint_backend(case):
         f"{case} loaded the checkpoint backend or a cloud SDK: {report['heavy']}"
     )
     assert "ckpt.backend_import" not in report["spans"]
+
+
+# -- the serving forward's arrows --------------------------------------------
+#
+# ``serve/`` -> ``models/serving`` -> ``models/cache_kinds`` -> ``models/transformer``,
+# ``ops/``, and never back: read off each module's own import statements,
+# wherever in the file they stand (an import inside a function hides a cycle,
+# it does not remove it).
+
+
+def _imports(module: str) -> set:
+    import ast
+
+    with open(os.path.join(REPO, *module.split(".")) + ".py") as f:
+        tree = ast.parse(f.read())
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            found.add(node.module)
+            found.update(f"{node.module}.{alias.name}" for alias in node.names)
+    return found
+
+
+def _defined(module: str) -> set:
+    import ast
+
+    with open(os.path.join(REPO, *module.split(".")) + ".py") as f:
+        tree = ast.parse(f.read())
+    names = set()
+    for node in tree.body:
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+        elif isinstance(node, ast.Assign):
+            names.update(target.id for target in node.targets if isinstance(target, ast.Name))
+    return names
+
+
+@pytest.mark.parametrize("module", ["determined_tpu.models.serving", "determined_tpu.models.cache_kinds"])
+def test_the_serving_forward_imports_neither_the_trainer_nor_the_engine(module):
+    imported = _imports(module)
+    back = sorted(m for m in imported if m.startswith(("determined_tpu.train", "determined_tpu.serve")))
+    assert back == [], f"{module} imports {back}: the arrow points from serve/ to the forward, and the forward trains nothing"
+    assert "determined_tpu.models.transformer" in imported
+
+
+def test_the_model_module_holds_no_serving_entry_point():
+    module = "determined_tpu.models.transformer"
+    forward = {"transformer_decode", "transformer_prefill", "transformer_prefill_chunked", "_serve_layer", "init_kv_cache"}
+    assert _defined(module) & forward == set()
+    serving = sorted(m for m in _imports(module) if m.startswith(("determined_tpu.models.serving", "determined_tpu.models.cache_kinds")))
+    assert serving == [], f"models/transformer.py imports {serving}: the serving forward imports it, never the reverse"
+    # and the names files under the benchmark's paths import from it never left
+    kept = {
+        "TransformerConfig", "TransformerLM", "LMTrial", "kv_cache_shape", "window_store_shape", "kv_bytes_per_token",
+        "STATE_DTYPE", "state_bytes_per_slot", "state_pool_shapes",
+    }
+    assert kept <= _defined(module)

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from flax.core import meta as flax_meta
 
-import determined_tpu.models.serving as tfm
+from determined_tpu.models import cache_kinds
 from determined_tpu.models.serving import init_kv_cache, transformer_decode, transformer_prefill
 from determined_tpu.models.transformer import TransformerConfig, TransformerLM
 from determined_tpu.ops import paged_attention as pa
@@ -235,7 +235,7 @@ def test_paged_decode_matches_full_gather_and_full_forward(
     batch, and the last step against the full-sequence forward of each
     lane's own tokens."""
     monkeypatch.setattr(
-        tfm, "paged_decode_attention",
+        cache_kinds, "paged_decode_attention",
         functools.partial(pa.paged_decode_attention, impl=impl),
     )
     cfg, model, variables = _lm(n_heads, n_kv_heads, head_dim, jnp.float32, seed=4)

@@ -14,8 +14,9 @@ import numpy as np
 import pytest
 from flax.core import meta
 
+from determined_tpu.models import cache_kinds
+from determined_tpu.models.cache_kinds import LANE, STATE_SLOT, layer_kind
 from determined_tpu.models.serving import (
-    SERVE_STATE_COUNTERS,
     init_kv_cache,
     serve_counters,
     transformer_decode,
@@ -174,7 +175,8 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
     assert {n: tuple(v.shape) if hasattr(v, "shape") else tuple(v["kernel"].shape) for n, v in params["block_0"]["attn"].items()} == {
         "wq": (48, 4, 16), "wk": (48, 2, 16), "wv": (48, 2, 16), "wg": (48, 2), "q_norm": (16,), "k_norm": (16,), "wo": (4, 16, 48),
     }
-    assert cfg.retention_layers == (0, 1) and cfg.paged_layers == 0 and [cfg.cache_index(i) for i in range(2)] == [0, 1]
+    assert cfg.retention_layers == STATE_SLOT.layers(cfg) == (0, 1) and cfg.paged_layers == 0
+    assert [layer_kind(cfg, i) for i in range(2)] == [(STATE_SLOT, 0), (STATE_SLOT, 1)]
     got = jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens))
     np.testing.assert_allclose(np.asarray(got), want, atol=5e-5)
     half = dataclasses.replace(cfg, param_dtype=jnp.bfloat16)
@@ -213,7 +215,7 @@ def test_the_cache_is_a_state_pool_and_no_token_owns_a_byte_of_it(model):
     assert state_pool_shapes(cfg, 3) == (cache["rs"].shape, cache["rz"].shape)
     assert kv_bytes_per_token(cfg) == 0
     assert state_bytes_per_slot(cfg) == 2 * (9 * 16 * 16 + 9 * 16) * 4
-    assert serve_counters(cfg) == SERVE_STATE_COUNTERS
+    assert serve_counters(cfg) == STATE_SLOT.counters
     mixed = dataclasses.replace(cfg, layer_types=(RETENTION, "full_attention"), qk_norm=False)
     assert set(init_kv_cache(mixed, 8, 4, lanes=2)) == {"k", "v", "rs", "rz"} and kv_bytes_per_token(mixed) == 2 * 2 * 16 * 4
     # the published widths: 8,320 features of a head of 128, 34.3 MB a lane a layer
@@ -310,7 +312,7 @@ def test_a_sequence_that_starts_in_a_used_lane_starts_from_a_zeroed_slot(model):
         assert np.array_equal(np.asarray(again[name]), np.asarray(fresh[name]))
 
 
-def test_the_decode_step_through_the_kernel_is_the_step_through_jnp():
+def test_the_decode_step_through_the_kernel_is_the_step_through_jnp(monkeypatch):
     """At a head of 128 (the kernel's shape), two layers, three lanes of which one idles."""
     cfg = tiny(d_model=64, n_heads=2, n_kv_heads=1, head_dim=128, d_ff=32, vocab_size=64)
     params = build(cfg)
@@ -319,7 +321,8 @@ def test_the_decode_step_through_the_kernel_is_the_step_through_jnp():
     toks, pos = jnp.asarray([tokens[1, 5], 0, tokens[0, 8]], jnp.int32), jnp.asarray([5, -1, 8], jnp.int32)
     outs = {}
     for impl in ("jnp", "kernel_interpret"):
-        step = jax.jit(functools.partial(transformer_decode, cfg, retention_impl=impl))
+        monkeypatch.setattr(cache_kinds, "retention_decode", functools.partial(retention.retention_decode, impl=impl))
+        step = jax.jit(functools.partial(transformer_decode, cfg))
         outs[impl] = step(params, toks, pos, jnp.zeros((3, 1), jnp.int32), cache)
     np.testing.assert_allclose(np.asarray(outs["jnp"][0])[[0, 2]], np.asarray(outs["kernel_interpret"][0])[[0, 2]], atol=2e-4)
     for name in ("rs", "rz"):
@@ -348,7 +351,8 @@ def engine(model):
 def test_generate_is_the_references_argmax_and_a_reused_lane_starts_afresh(model, engine):
     cfg, params, tokens, _ = model
     kernels = engine.kernels
-    assert kernels.stateful and not kernels.paged and not kernels.windowed and set(kernels.cache) == {"rs", "rz"}
+    # one kind, held by the lane: no layer reads a pool, and a request holds no block
+    assert kernels.kinds == (STATE_SLOT,) and STATE_SLOT.holds == LANE and set(kernels.cache) == set(STATE_SLOT.leaves) == {"rs", "rz"}
 
     def greedy(prompt, new):
         seq = list(prompt)

@@ -1,4 +1,4 @@
-"""The serving forward is stated once (``models/transformer.py``, "KV-cache
+"""The serving forward is stated once (``models/serving.py``, "KV-cache
 decode path"): one layer function under the three entry points (the chunked
 prefill walk and the decode step the engine runs, the wide prefill the tests
 keep as their oracle), one ``_rope`` for shared and per-lane positions, one
@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from determined_tpu.models import serving as tx
-from determined_tpu.models.transformer import Rope, TransformerConfig, TransformerLM, yarn_inv_freq
+from determined_tpu.models import cache_kinds, serving as tx
+from determined_tpu.models.transformer import Rope, TransformerConfig, TransformerLM, _rope, kv_cache_shape, yarn_inv_freq
 from determined_tpu.ops.attention import reference_attention
 
 BLOCK = 4
@@ -68,7 +68,7 @@ def test_every_entry_point_runs_the_one_layer_function(tiny, monkeypatch, which)
     else."""
     cfg, params = tiny
     calls = {"layer": [], "proj": 0}
-    layer, proj = tx._serve_layer, tx._attn_proj
+    layer, proj = tx._serve_layer, cache_kinds._attn_proj
 
     def counted_layer(cfg_, i, *a, **kw):
         calls["layer"].append(i)
@@ -82,7 +82,7 @@ def test_every_entry_point_runs_the_one_layer_function(tiny, monkeypatch, which)
         return proj(*a, **kw)
 
     monkeypatch.setattr(tx, "_serve_layer", counted_layer)
-    monkeypatch.setattr(tx, "_attn_proj", counted_proj)
+    monkeypatch.setattr(cache_kinds, "_attn_proj", counted_proj)
     _trace(which, cfg, params)
     assert calls["layer"] == list(range(cfg.n_layers))
     assert calls["proj"] == cfg.n_layers
@@ -166,29 +166,29 @@ def test_rope_per_lane_positions_equal_shared_positions_row_by_row(rope):
     b, h, d = 5, 4, 16
     x = jax.random.normal(jax.random.key(0), (b, h, 1, d), jnp.float32)
     pos = jnp.asarray([0, 3, 17, 4096, 31], jnp.int32)
-    per_lane = tx._rope(x, pos[:, None], rope)
+    per_lane = _rope(x, pos[:, None], rope)
     assert per_lane.shape == x.shape
     for lane in range(b):
-        shared = tx._rope(x[lane : lane + 1], pos[lane : lane + 1], rope)
+        shared = _rope(x[lane : lane + 1], pos[lane : lane + 1], rope)
         np.testing.assert_array_equal(np.asarray(per_lane[lane]), np.asarray(shared[0]))
     # and [b, s] positions that happen to be alike equal the [s] form
     xs = jax.random.normal(jax.random.key(1), (2, h, 6, d), jnp.float32)
     p = jnp.arange(6) + 9
     np.testing.assert_array_equal(
-        np.asarray(tx._rope(xs, jnp.broadcast_to(p, (2, 6)), rope)), np.asarray(tx._rope(xs, p, rope))
+        np.asarray(_rope(xs, jnp.broadcast_to(p, (2, 6)), rope)), np.asarray(_rope(xs, p, rope))
     )
 
 
 def _filled_pool(cfg, seed):
-    shape = tx.kv_cache_shape(cfg, 16, BLOCK)
+    shape = kv_cache_shape(cfg, 16, BLOCK)
     k, v = jax.random.split(jax.random.key(seed))
     return {"k": jax.random.normal(k, shape, cfg.dtype), "v": jax.random.normal(v, shape, cfg.dtype)}
 
 
 @pytest.mark.parametrize("mask_of", ["decode", "suffix"])
 def test_table_backend_equals_reference_attention_on_the_gathered_rows(tiny, mask_of):
-    """``_attend_table`` under decode's mask (one query a lane, keys up to
-    its own position) and under the suffix walk's mask (a block of queries, keys up to each) is
+    """The gathered table's read (``cache_kinds._attend_table``, under ``paged_kv``'s
+    reference mixer) under decode's mask (one query a lane, keys up to its own position) and under the suffix walk's mask (a block of queries, keys up to each) is
     ``reference_attention`` over the rows the table names."""
     cfg, _ = tiny
     cache = _filled_pool(cfg, 5)
@@ -206,7 +206,7 @@ def test_table_backend_equals_reference_attention_on_the_gathered_rows(tiny, mas
         q = jax.random.normal(jax.random.key(6), (2, cfg.n_heads, BLOCK, cfg.head_dim), cfg.dtype)
         mask = k_pos[None, :] <= p[:, None]  # [s, kv_len]
         lens = [3 * BLOCK, 3 * BLOCK]
-    got = tx._attend_table(cfg, tables, mask)(q, None, None, cache, layer)
+    got = cache_kinds._attend_table(cfg, tables, mask)(q, None, None, cache, layer)
     assert got.shape == q.shape
     for lane in range(2):
         n = lens[lane]

@@ -578,6 +578,7 @@ class _CrashingKernels:
         self._kernels = kernels
         self.serve_cfg = kernels.serve_cfg
         self.model_cfg = kernels.model_cfg
+        self.kinds = kernels.kinds  # what a request holds of the cache: the engine derives admission from it
         self.prefill = kernels.prefill
         self.prefill_suffix = kernels.prefill_suffix
 
@@ -1468,6 +1469,7 @@ class _FastHeartbeatKernels:
             kernels.serve_cfg, heartbeat_interval_s=interval_s
         )
         self.model_cfg = kernels.model_cfg
+        self.kinds = kernels.kinds
         self.prefill = kernels.prefill
         self.prefill_suffix = kernels.prefill_suffix
         self.decode = kernels.decode

@@ -193,7 +193,12 @@ def test_the_engine_says_what_a_tile_of_its_decode_kernel_multiplies(heads, want
     finally:
         tracer.reset()
     stats = ServeEngine(kernels).stats()
-    assert kernels.attn_products == want and pool["args"].get("attn_products") == want and stats.get("attn_products") == want
+    from determined_tpu.models.cache_kinds import PAGED_KV, PAGED_LATENT
+
+    # the kind says it once, to the set-up span and to /stats
+    assert kernels.kinds == ((PAGED_KV,) if want else (PAGED_LATENT,))
+    assert PAGED_KV.report(cfg, None, 0) == ({"attn_products": want} if want else {})
+    assert pool["args"].get("attn_products") == want and stats.get("attn_products") == want
 
 
 # ---------------------------------------------------------------------------

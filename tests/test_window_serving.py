@@ -18,9 +18,9 @@ import pytest
 from flax.core import meta
 
 from determined_tpu.models import moe
+from determined_tpu.models.cache_kinds import BLOCKS, LANE, PAGED_KV, WINDOW_RING, layer_kind
 from determined_tpu.models.serving import (
     SERVE_COUNTERS,
-    SERVE_KV_COUNTERS,
     _check_decodable,
     init_kv_cache,
     prefill_chunk_tokens,
@@ -109,7 +109,8 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
     assert set(params["block_0"]["moe"]) == {"router", "w_gate", "w_up", "w_down", "shared_w_gate", "shared_w_up", "shared_w_down"}
     assert params["block_0"]["moe"]["shared_w_gate"].shape == (64, SHARED * 32)
     assert cfg.rope(FULL) is None and cfg.rope(SLIDING).theta == 50000.0
-    assert cfg.window_layers == (0, 1, 2) and [cfg.cache_index(i) for i in range(4)] == [0, 1, 2, 0]
+    assert cfg.window_layers == WINDOW_RING.layers(cfg) == (0, 1, 2) and PAGED_KV.layers(cfg) == (3,)
+    assert [layer_kind(cfg, i) for i in range(4)] == [(WINDOW_RING, 0), (WINDOW_RING, 1), (WINDOW_RING, 2), (PAGED_KV, 0)]
     got = _forward(cfg)(params, jnp.asarray(tokens[:, :64]))
     np.testing.assert_allclose(np.asarray(got), want[:, :64], atol=3e-5)
     # the hidden state times the table, times logit_scale, is what a fused loss contracts
@@ -171,9 +172,9 @@ def test_the_cache_holds_a_pool_for_full_layers_and_a_ring_a_lane_for_window_lay
     # a model without window layers: today's pool and nothing else
     plain = tiny(layer_types=None, sliding_window=None, rope_parameters=None)
     assert set(init_kv_cache(plain, 24, BLOCK)) == {"k", "v"} and kv_cache_shape(plain, 24, BLOCK)[0] == 4
-    assert serve_counters(cfg) == SERVE_KV_COUNTERS + SERVE_COUNTERS and serve_counters(plain) == SERVE_COUNTERS
+    assert serve_counters(cfg) == WINDOW_RING.counters + SERVE_COUNTERS and serve_counters(plain) == SERVE_COUNTERS
     assert serve_counters(tiny(moe_experts=0, moe_top_k=0, moe_intermediate_size=None, moe_experts_held=None, moe_router="softmax",
-                               moe_shared_experts=0)) == SERVE_KV_COUNTERS
+                               moe_shared_experts=0)) == WINDOW_RING.counters
 
 
 @functools.lru_cache(maxsize=None)
@@ -349,7 +350,9 @@ def test_two_lanes_of_unequal_length_through_the_engine_match_the_full_forward(e
 
     cfg, params, serve_cfg, kernels = engine_parts
     tokens = model[2]
-    assert kernels.windowed and kernels.window_store["ring_tokens"] == 8 + serve_cfg.prefill_chunk == 264
+    # a cache of two kinds: a request holds blocks of the one and its lane's ring of the other
+    assert kernels.kinds == (PAGED_KV, WINDOW_RING) and [kind.holds for kind in kernels.kinds] == [BLOCKS, LANE]
+    assert 8 + serve_cfg.prefill_chunk == 264                         # a ring's tokens: ``window_store`` below
     assert kernels.cache["wk"].shape == (3, 3 * 66, BLOCK, 32) and kernels.cache["k"].shape == (1, 200, BLOCK, 32)
     eng = ServeEngine(kernels)                                       # not started: the test drives step_once()
     short = eng.submit(tokens[0, :5].tolist(), max_new_tokens=6)      # inside the window when it ends
@@ -366,7 +369,7 @@ def test_two_lanes_of_unequal_length_through_the_engine_match_the_full_forward(e
     assert third.output == _greedy(cfg, params, tokens[0, 40:70].tolist(), 12)
     stats = eng.stats()
     assert stats["window_store"] == {"window_store_bytes": 2 * 3 * 3 * 264 * 32 * 4, "ring_tokens": 264}
-    assert set(stats["step_counters"]) == set(SERVE_KV_COUNTERS + SERVE_COUNTERS)
+    assert set(stats["step_counters"]) == set(WINDOW_RING.counters + SERVE_COUNTERS)
     assert stats["step_counters"]["serve.kv.full_tokens"] > stats["step_counters"]["serve.kv.window_tokens"] / 3 > 0
     assert stats["kv_cache"]["used"] == 0                            # the allocator counts the full layer's blocks, all freed
 
