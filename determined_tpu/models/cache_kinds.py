@@ -4,15 +4,19 @@ cache kinds under the serving forward (``models/serving.py``).
 A kind is what a layer keeps and how it is addressed, ONE record
 (:class:`CacheKind`) in :data:`CACHE_KINDS`: ``paged_kv`` (K and V rows a token
 in the paged pool), ``paged_latent`` (one latent row a token in the pool),
-``state_slot`` (a float32 state a decode lane) and ``window_ring`` (K and V rows
-of the newest tokens in a ring a decode lane); ``docs/serving.md`` "What a
-request holds" has them side by side.  The forward's layer function calls every
+``state_slot`` (a float32 state a decode lane), ``window_ring`` (K and V rows
+of the newest tokens in a ring a decode lane) and ``ssm_slot`` (a Mamba-2
+mixer's float32 state and its convolution's tail a decode lane);
+``docs/serving.md`` "What a request holds" has them side by side.  A layer is
+of every kind whose ``layer_types`` name its type: an ``attention_mamba2`` layer
+is of ``paged_kv`` AND ``ssm_slot``, and its mixers run one after the other on
+the one norm, each adding its output to the stream.  The forward's layer function calls every
 kind's mixer the same way, and the engine (``serve/engine.py``) derives
 admission, its refusals and ``/stats`` from the records: neither names a leaf
 or asks which kind a model has, and nothing outside this file adds to the table.
 
-A mixer is ``mix(p, x, h, cache, j) -> (x, cache)``: ``p`` the layer's attention
-leaves, ``x`` [b, s, d] the residual stream, ``h`` its norm, ``j`` the layer's row
+A mixer is ``mix(p, x, h, cache, j) -> (x, cache)``: ``p`` the leaves of the
+layer's subtree the kind names (``params``), ``x`` [b, s, d] the residual stream, ``h`` its norm, ``j`` the layer's row
 in the kind's arrays.  It projects, writes this call's rows (or folds them into
 the state), attends so that a token sees itself, and adds the output projection
 to the stream.  A kind builds one from the rows of a call (:class:`Rows`) in
@@ -34,14 +38,16 @@ import jax.numpy as jnp
 
 from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
-    FULL, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
-    kv_bytes_per_token, kv_cache_shape, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
+    FULL, HYBRID, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
+    _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, kv_bytes_per_token, kv_cache_shape, ssm_bytes_per_slot,
+    ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
 )
 from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
 from determined_tpu.ops.paged_attention import (
     attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
 )
 from determined_tpu.ops.retention import retention_chunk, retention_decode
+from determined_tpu.ops.ssm import ssm_chunk, ssm_decode
 
 #: what a request holds of a kind.  BLOCKS: rows a token in blocks the
 #: allocator hands out, shareable by prefix, prefilled from any block edge.
@@ -96,15 +102,15 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
     store that now holds them, with q [b, n_heads, s, head_dim] and this call's
     own k, v [b, kv_heads, s, head_dim], to [b, n_heads, s, head_dim]."""
     (phys, slots), (keys, vals) = where, kind.leaves
-    rope, dt, mode = cfg.rope(kind.layer_type), cfg.dtype, "drop" if drop else None
+    rope, dt, mode = cfg.rope(kind.layer_types[0]), cfg.dtype, "drop" if drop else None
     # a cache of two kinds: the device trace tells them apart
-    scope = "serve.attn.window" if kind.layer_type == SLIDING else "serve.attn.full"
+    scope = "serve.attn.window" if kind.layer_types[0] == SLIDING else "serve.attn.full"
     split = functools.partial(jax.named_scope, scope) if cfg.window_layers else contextlib.nullcontext
 
     def mix(p, x, h, cache, j):
         with jax.named_scope("serve.attn.qkv"):
-            q, k, v = _attn_proj(p, h, dt)
-            q, k = _rope(q, rows.positions, rope), _rope(k, rows.positions, rope)
+            q, k, v = _attn_proj(p, _times(h, cfg.attention_in_multiplier), dt)
+            q, k = _rope(q, rows.positions, rope), _rope(_times(k, cfg.key_multiplier), rows.positions, rope)
         with jax.named_scope("serve.kv.write"):
             cache = {
                 **cache,
@@ -116,7 +122,8 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
                 att = attend(q, k, v, cache, j)
             att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
         with jax.named_scope("serve.attn.out"):
-            return x + jnp.einsum("bshk,hkD->bsD", att, p["wo"]["kernel"].astype(dt)), cache
+            out = jnp.einsum("bshk,hkD->bsD", att, p["wo"]["kernel"].astype(dt))
+            return x + _times(out, cfg.attention_out_multiplier), cache
 
     return mix
 
@@ -508,6 +515,103 @@ def _state_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
     return {"slots": slots["slots"], "bytes_per_slot": slots["bytes_per_slot"], "state_pool_bytes": _nbytes(STATE_SLOT, cfg, sizes)}
 
 
+# -- a Mamba-2 mixer's state and its convolution's tail a lane -------------------
+#
+# Its forms are two functions of a call's rows: ``conv(p, xbc, cache, j)`` takes
+# what the in-projection made for the convolution [b, s, channels], puts the
+# lanes' tails before it, keeps the new tails and returns (the convolution's
+# output, the cache); ``scan(x, B, C, dt, A, D, cache, j)`` runs the tokens
+# through the lanes' states and returns (y [b, s, heads, P] float32, the cache).
+
+
+def _ssm_mixer(cfg: TransformerConfig, conv, scan):
+    """A Mamba-2 mixer reads the norm the layer's attention heads read, writes
+    no row a token and adds its own output to the stream."""
+
+    def mix(p, x, h, cache, j):
+        with jax.named_scope("serve.ssm.in"):  # projection, multipliers, convolution, the step
+            z, xbc, dt = _ssm_project(cfg, p, h)
+            xbc, cache = conv(p, xbc, cache, j)
+            parts = _ssm_split(cfg, xbc, dt, p)
+        with jax.named_scope("serve.ssm.state"):  # decay, update, read-out
+            y, cache = scan(*parts, cache, j)
+        with jax.named_scope("serve.ssm.out"):  # gated norm, the out-projection
+            return x + _ssm_out(cfg, p, y, z), cache
+
+    return mix
+
+
+def _ssm_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """``s`` tokens a row after what the slots and tails of the rows' lanes hold
+    (nothing, in the walk's first chunk: a sequence starts from a zeroed slot
+    and a zeroed tail), and into them: the prefill walk's chunk, and the wide
+    prefill as one chunk.  A token that does not exist (``rows.live``: the
+    padded end of a prompt) advances neither."""
+    state_leaf, tail_leaf = SSM_SLOT.leaves
+    lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
+    fresh = rows.chunk == rows.first_chunk
+
+    def conv(p, xbc, cache, j):
+        tail = jnp.where(fresh, 0, cache[tail_leaf][j, lanes])
+        window = jnp.concatenate([tail, xbc], axis=1)
+        # the new tail: the rows before the first token that does not exist
+        last = jnp.sum(rows.live, axis=1)[:, None] + jnp.arange(cfg.ssm_conv - 1)[None, :]
+        tail = jnp.take_along_axis(window, last[..., None], axis=1)
+        return _ssm_conv(cfg, p, window), {**cache, tail_leaf: cache[tail_leaf].at[j, lanes].set(tail)}
+
+    def scan(x, b, c, dt, a, skip, cache, j):
+        state = jnp.where(fresh, 0.0, cache[state_leaf][j, lanes])
+        y, state = ssm_chunk(x, b, c, dt, a, skip, state, rows.live)
+        return y, {**cache, state_leaf: cache[state_leaf].at[j, lanes].set(state)}
+
+    return _ssm_mixer(cfg, conv, scan)
+
+
+def _ssm_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """One token a lane, row ``b`` of the batch IS lane ``b``: the tail moves on
+    by a row, the slot is decayed, takes the token and answers it
+    (``ops/ssm.py ssm_decode``: the Pallas kernel on a TPU, in place); a lane
+    that is not live leaves both alone."""
+    state_leaf, tail_leaf = SSM_SLOT.leaves
+
+    def conv(p, xbc, cache, j):
+        tail = cache[tail_leaf][j]
+        window = jnp.concatenate([tail, xbc], axis=1)
+        tail = jnp.where(rows.live[:, None, None], window[:, 1:], tail)
+        return _ssm_conv(cfg, p, window), {**cache, tail_leaf: cache[tail_leaf].at[j].set(tail)}
+
+    def scan(x, b, c, dt, a, skip, cache, j):
+        y, state = ssm_decode(x[:, 0], b[:, 0], c[:, 0], dt[:, 0], a, skip, cache[state_leaf], j, rows.live)
+        return y[:, None], {**cache, state_leaf: state}
+
+    return _ssm_mixer(cfg, conv, scan)
+
+
+def _ssm_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    if sizes.max_batch is None:
+        raise ValueError("a model with Mamba-2 layers needs its lanes to size the state pool")
+    return ssm_pool_shapes(cfg, sizes.max_batch)
+
+
+def _ssm_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
+    """The lanes whose state this step updated, and the bytes of state they hold."""
+    lanes = jnp.sum(active.astype(jnp.float32))
+    return jnp.stack([lanes, lanes * (len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg))])
+
+
+def _ssm_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
+    """``ssm``: the slots (one a lane), how many hold a sequence, and the bytes
+    of state one holds over the Mamba-2 layers."""
+    if not cfg.ssm_layers:
+        return {}
+    return {"ssm": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg)}}
+
+
+def _ssm_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    slots = _ssm_report(cfg, sizes)["ssm"]
+    return {"ssm_slots": slots["slots"], "ssm_bytes_per_slot": slots["bytes_per_slot"], "ssm_pool_bytes": _nbytes(SSM_SLOT, cfg, sizes)}
+
+
 # -- the table ----------------------------------------------------------------------
 
 
@@ -516,13 +620,16 @@ class CacheKind:
     """One kind of cache: everything the forward and the engine know of it."""
 
     name: str
-    #: its layers in a config: those of ``layer_type`` in a model whose attention is ``latent`` (or is not)
-    layer_type: str
+    #: its layers in a config: those of one of ``layer_types`` in a model whose attention is ``latent`` (or is
+    #: not).  A type that several kinds name makes a layer of several kinds.  The first is the type whose
+    #: rotary parameters and scope the kind's mixer takes
+    layer_types: Tuple[str, ...]
     latent: bool
-    #: the arrays it owns in the cache, and ``shapes(cfg, sizes)`` -> a shape each (``sizes``: a ``ServeConfig``'s
-    #: ``num_blocks``, ``block_size``, ``max_batch``, ``prefill_chunk``), of ``dtype(cfg)``
+    #: the arrays it owns in the cache, ``shapes(cfg, sizes)`` -> a shape each (``sizes``: a ``ServeConfig``'s
+    #: ``num_blocks``, ``block_size``, ``max_batch``, ``prefill_chunk``) and ``dtypes(cfg)`` -> a dtype each
     leaves: Tuple[str, ...]
     shapes: Callable
+    dtypes: Callable
     #: what a request holds of it, BLOCKS or LANE; for LANE, why ``prefix_cache`` cannot be served
     holds: str
     #: its mixers: ``step``, ``table`` and ``wide`` are ``(cfg, rows, cache) -> mix`` (``wide`` None: the kind has
@@ -532,7 +639,8 @@ class CacheKind:
     table: Callable
     wide: Optional[Callable]
     no_prefix_cache: Optional[str] = None
-    dtype: Callable = lambda cfg: cfg.dtype
+    #: the subtree of a block whose leaves its mixer reads
+    params: str = "attn"
     #: what a decode step counts for it, by name, and ``count(cfg, active [b], pos [b])`` -> float32, one each
     counters: Tuple[str, ...] = ()
     count: Optional[Callable] = None
@@ -546,11 +654,16 @@ class CacheKind:
         """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its arrays."""
         if cfg.latent != self.latent:
             return ()
-        return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) == self.layer_type)
+        return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) in self.layer_types)
 
 
 def _nbytes(kind: CacheKind, cfg: TransformerConfig, sizes: Any) -> int:
-    return sum(math.prod(shape) for shape in kind.shapes(cfg, sizes)) * jnp.dtype(kind.dtype(cfg)).itemsize
+    return sum(math.prod(shape) * jnp.dtype(dt).itemsize for shape, dt in zip(kind.shapes(cfg, sizes), kind.dtypes(cfg)))
+
+
+def _compute_dtype(leaves: int):
+    """The ``dtypes`` of a kind whose ``leaves`` arrays are all in the compute dtype."""
+    return lambda cfg: (cfg.dtype,) * leaves
 
 
 def _every_chunk(build: Callable):
@@ -559,7 +672,8 @@ def _every_chunk(build: Callable):
 
 
 PAGED_KV = CacheKind(
-    name="paged_kv", layer_type=FULL, latent=False, leaves=("k", "v"), shapes=_paged_shapes, holds=BLOCKS,
+    name="paged_kv", layer_types=(FULL, HYBRID), latent=False, holds=BLOCKS,
+    leaves=("k", "v"), shapes=_paged_shapes, dtypes=_compute_dtype(2),
     step=lambda cfg, rows, cache: _kv_mixer(
         cfg, PAGED_KV, rows, rows.where, _attend_paged(cfg, PAGED_KV, rows.block_tables, rows.lane_positions)),
     walk=_every_chunk(lambda cfg, rows: _kv_mixer(
@@ -571,7 +685,8 @@ PAGED_KV = CacheKind(
 )
 
 PAGED_LATENT = CacheKind(
-    name="paged_latent", layer_type=FULL, latent=True, leaves=("kv",), shapes=_paged_shapes, holds=BLOCKS,
+    name="paged_latent", layer_types=(FULL,), latent=True, holds=BLOCKS,
+    leaves=("kv",), shapes=_paged_shapes, dtypes=_compute_dtype(1),
     step=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_paged(cfg, rows.block_tables, rows.lane_positions)),
     walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk))),
     table=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows))),
@@ -579,8 +694,8 @@ PAGED_LATENT = CacheKind(
 )
 
 STATE_SLOT = CacheKind(
-    name="state_slot", layer_type=RETENTION, latent=False, leaves=("rs", "rz"), shapes=_state_shapes, holds=LANE,
-    dtype=lambda cfg: transformer.STATE_DTYPE,  # read where it is stated: the benchmark's check sets another there
+    name="state_slot", layer_types=(RETENTION,), latent=False, leaves=("rs", "rz"), shapes=_state_shapes, holds=LANE,
+    dtypes=lambda cfg: (transformer.STATE_DTYPE,) * 2,  # read where it is stated: the benchmark's check sets another there
     no_prefix_cache=(
         "prefix_cache shares a prompt's full blocks between requests, and a block holds no state: a "
         "power-retention layer keeps a request's whole context in its own lane's state slot, and a prefill "
@@ -594,7 +709,8 @@ STATE_SLOT = CacheKind(
 )
 
 WINDOW_RING = CacheKind(
-    name="window_ring", layer_type=SLIDING, latent=False, leaves=("wk", "wv"), shapes=_ring_shapes, holds=LANE,
+    name="window_ring", layer_types=(SLIDING,), latent=False, holds=LANE,
+    leaves=("wk", "wv"), shapes=_ring_shapes, dtypes=_compute_dtype(2),
     no_prefix_cache=(
         "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no "
         "window state: the sliding-window layers keep a request's newest tokens in its own lane's ring, "
@@ -606,8 +722,25 @@ WINDOW_RING = CacheKind(
     report=_ring_report, setup=_ring_setup,
 )
 
-#: every kind, in the order a decode step's counters and a walk's chunk take them
-CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, STATE_SLOT, WINDOW_RING)
+SSM_SLOT = CacheKind(
+    name="ssm_slot", layer_types=(HYBRID,), latent=False, leaves=("ssm", "conv"), shapes=_ssm_shapes, holds=LANE,
+    params="ssm",
+    # the state where it is stated (the benchmark's check sets another there); the tail as the convolution reads it
+    dtypes=lambda cfg: (transformer.STATE_DTYPE, cfg.dtype),
+    no_prefix_cache=(
+        "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no state: a "
+        "Mamba-2 mixer keeps a request's whole context in its own lane's state slot and convolution tail, and a "
+        "prefill from the first un-cached token would need both as they stood at that block's edge (a snapshot "
+        "nobody keeps). Set prefix_cache: false"
+    ),
+    step=_ssm_step, walk=_every_chunk(_ssm_walk), table=_ssm_step, wide=_ssm_walk,
+    # the lanes whose state the step updated, and the bytes of state those hold over the Mamba-2 layers
+    counters=("serve.ssm.live_lanes", "serve.ssm.bytes"), count=_ssm_count,
+    report=_ssm_report, setup=_ssm_setup,
+)
+
+#: every kind, in the order a layer's mixers run, a decode step's counters and a walk's chunk take them
+CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, STATE_SLOT, WINDOW_RING, SSM_SLOT)
 
 
 def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:
@@ -615,10 +748,10 @@ def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:
     return tuple(kind for kind in CACHE_KINDS if kind.layers(cfg))
 
 
-def layer_kind(cfg: TransformerConfig, i: int) -> Tuple[CacheKind, int]:
-    """Layer ``i``'s kind, and its place among the layers of that kind: its row in the kind's arrays."""
-    (kind,) = (kind for kind in CACHE_KINDS if i in kind.layers(cfg))
-    return kind, kind.layers(cfg).index(i)
+def layer_kinds(cfg: TransformerConfig, i: int) -> Tuple[Tuple[CacheKind, int], ...]:
+    """Layer ``i``'s kinds in the table's order, each with the layer's place among the layers of that kind: its
+    row in the kind's arrays."""
+    return tuple((kind, kind.layers(cfg).index(i)) for kind in CACHE_KINDS if i in kind.layers(cfg))
 
 
 def pool_block_size(cfg: TransformerConfig, cache: Dict[str, jax.Array]) -> Optional[int]:

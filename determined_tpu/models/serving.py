@@ -35,8 +35,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from determined_tpu.models.cache_kinds import Rows, cache_kinds, layer_kind, pool_block_size
-from determined_tpu.models.transformer import TransformerConfig, _layer_norm, _rms_apply
+from determined_tpu.models.cache_kinds import Rows, cache_kinds, layer_kinds, pool_block_size
+from determined_tpu.models.transformer import TransformerConfig, _layer_norm, _rms_apply, _times
 
 
 def init_kv_cache(
@@ -49,8 +49,8 @@ def init_kv_cache(
     lanes and prefill chunks of ``chunk_tokens`` size the stores a lane holds."""
     sizes = types.SimpleNamespace(num_blocks=num_blocks, block_size=block_size, max_batch=lanes, prefill_chunk=chunk_tokens)
     return {
-        leaf: jnp.zeros(shape, kind.dtype(cfg))
-        for kind in cache_kinds(cfg) for leaf, shape in zip(kind.leaves, kind.shapes(cfg, sizes))
+        leaf: jnp.zeros(shape, dtype)
+        for kind in cache_kinds(cfg) for leaf, shape, dtype in zip(kind.leaves, kind.shapes(cfg, sizes), kind.dtypes(cfg))
     }
 
 
@@ -62,10 +62,11 @@ def _norm_apply(cfg: TransformerConfig, x: jax.Array, scale: jax.Array) -> jax.A
         return _layer_norm(x, scale, cfg.norm_eps)
 
 
-def _mlp_apply(p: Dict[str, Any], x: jax.Array, dtype: Any) -> jax.Array:
-    gate = x @ p["w_gate"]["kernel"].astype(dtype)
+def _mlp_apply(p: Dict[str, Any], x: jax.Array, dtype: Any, multipliers: Tuple[float, float] = (1.0, 1.0)) -> jax.Array:
+    """SwiGLU; ``multipliers``: muP's scalars on the gate and on the output."""
+    gate = _times(x @ p["w_gate"]["kernel"].astype(dtype), multipliers[0])
     up = x @ p["w_up"]["kernel"].astype(dtype)
-    return (nn.silu(gate) * up) @ p["w_down"]["kernel"].astype(dtype)
+    return _times((nn.silu(gate) * up) @ p["w_down"]["kernel"].astype(dtype), multipliers[1])
 
 
 def _check_decodable(cfg: TransformerConfig) -> None:
@@ -86,13 +87,13 @@ def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Ar
 
 
 def _head(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array, row: Optional[int] = None) -> jax.Array:
-    """Final norm and ``lm_head`` (tied: the embedding's transpose, times
-    ``logit_scale``): float32 logits at every position of ``x``, or at ``row`` alone."""
+    """Final norm and ``lm_head`` (tied: the embedding's transpose), times
+    ``logit_scale``: float32 logits at every position of ``x``, or at ``row`` alone."""
     x = _norm_apply(cfg, x, params["ln_f"]["scale"])
     with jax.named_scope("serve.head"):
         x = x if row is None else x[:, row, :]
         if not cfg.tie_embeddings:
-            return (x @ params["lm_head"]["kernel"].astype(cfg.dtype)).astype(jnp.float32)
+            return _times((x @ params["lm_head"]["kernel"].astype(cfg.dtype)).astype(jnp.float32), cfg.logit_scale)
         # float32 out of the product itself: the table is the head, and its logits are what a caller samples from
         logits = jnp.einsum(
             "...d,vd->...v", x, params["embed"]["embedding"].astype(cfg.dtype), preferred_element_type=jnp.float32
@@ -115,22 +116,24 @@ def serve_counters(cfg: TransformerConfig) -> Tuple[str, ...]:
 
 def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
-    points below: norm; the mixer of the layer's cache kind (``mixers``: one a
-    kind of the model, built by the entry point from the rows of its call),
-    which projects, writes this call's rows, attends so that a token sees its
-    own key, and adds the output projection to the stream; then the MLP or the
+    points below: norm; the mixer of each of the layer's cache kinds, in the
+    table's order (``mixers``: one a kind of the model, built by the entry point
+    from the rows of its call), which reads the norm, projects, writes this
+    call's rows, attends so that a token sees its own key, and adds its output
+    projection to the stream (attention heads and Mamba-2 heads side by side are
+    a layer of two kinds); then the MLP or the
     experts held here (which count the tokens ``live`` [b, s] marks) and its
     residual; under ``parallel_block`` the MLP or experts read the one norm
     attention read.
     Returns (x, cache, what an expert layer counted or None)."""
-    kind, j = layer_kind(cfg, i)
     h = _norm_apply(cfg, x, blk["ln1"]["scale"])
-    x, cache = mixers[kind.name](blk["attn"], x, h, cache, j)
+    for kind, j in layer_kinds(cfg, i):
+        x, cache = mixers[kind.name](blk[kind.params], x, h, cache, j)
     if not cfg.parallel_block:  # else the one norm: what attention read
         h = _norm_apply(cfg, x, blk["ln2"]["scale"])
     if not cfg.use_moe(i):
         with jax.named_scope("serve.mlp"):
-            return x + _mlp_apply(blk["mlp"], h, cfg.dtype), cache, None
+            return x + _mlp_apply(blk["mlp"], h, cfg.dtype, cfg.mlp_multipliers), cache, None
     from determined_tpu.models.moe import serve_routed_experts
 
     y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
@@ -174,7 +177,7 @@ def transformer_prefill(
         raise ValueError("the wide prefill runs full layers only: sliding-window layers prefill through transformer_prefill_chunked")
     block_size = pool_block_size(cfg, cache)
     b, s = tokens.shape
-    x = _embed_rows(params, tokens, cfg.dtype)
+    x = _times(_embed_rows(params, tokens, cfg.dtype), cfg.embedding_multiplier)
     positions = jnp.arange(s)
     valid = positions[None, :] < prompt_lens[:, None]
     where = None
@@ -242,7 +245,7 @@ def transformer_decode(
         raise ValueError(f"chunk_blocks={chunk_blocks} must divide the table width {t}")
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
-    x = _embed_rows(params, tokens[:, None], cfg.dtype)
+    x = _times(_embed_rows(params, tokens[:, None], cfg.dtype), cfg.embedding_multiplier)
     with jax.named_scope("serve.kv.write"):  # where each lane's row goes: idle lanes -> scratch
         phys = None if block_size is None else jnp.where(
             active, jnp.take_along_axis(block_tables, (pos // block_size)[:, None], axis=1)[:, 0], 0
@@ -377,7 +380,7 @@ def transformer_prefill_chunked(
         zero = (c < 0).astype(jnp.float32)
         layers = {name: sub for name, sub in params.items() if name.startswith("block_")}
         layers = jax.tree.map(lambda w: w if w.dtype == cfg.dtype else w + zero.astype(w.dtype), layers)
-        x = _embed_rows(params, toks, cfg.dtype)
+        x = _times(_embed_rows(params, toks, cfg.dtype), cfg.embedding_multiplier)
         x, cache, _ = _serve_layers(cfg, layers, x, mixers, cache, valid if cfg.moe_experts else None)
         with jax.named_scope("serve.head"):  # the one row the head will read
             sel = prompt_lens - 1 - c * chunk  # [b]

@@ -10,9 +10,11 @@ MeshConfig alone, with:
 - activation sharding constraints (batch over dp/fsdp, seq over sp);
 - rotary position embeddings (one base, or parameters per layer type with
   YaRN), GQA with a stated ``head_dim``, RMSNorm, SwiGLU;
-- a type per layer: full causal attention, a sliding window, or power
+- a type per layer: full causal attention, a sliding window, power
   retention (``ops/retention.py``: gated attention of degree 2, whose serving
-  cache is a fixed-size state a lane and not keys and values a token);
+  cache is a fixed-size state a lane and not keys and values a token), or
+  attention heads beside Mamba-2 heads under one norm (``ops/ssm.py``:
+  Falcon-H1's block, whose serving cache is K and V a token AND a state a lane);
 - experts: the top-2 capacity layer or dropless top-k over the experts a
   device holds (models/moe.py);
 - attention dispatch: ring attention when the mesh has a "seq" axis,
@@ -35,6 +37,7 @@ from flax import linen as nn
 from determined_tpu.data import DataLoader, SyntheticDataset
 from determined_tpu.ops.attention import dot_product_attention, reference_attention
 from determined_tpu.ops.retention import retention_quadratic, state_shapes
+from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
 from determined_tpu.parallel.sharding import with_sharding_constraint
@@ -43,7 +46,9 @@ from determined_tpu.train._trial import JaxTrial
 
 #: the kinds of layer, under the names published configurations give them
 FULL, SLIDING, RETENTION = "full_attention", "sliding_attention", "power_retention"
-LAYER_TYPES = (FULL, SLIDING, RETENTION)
+#: full attention and a Mamba-2 mixer side by side: ``x + a(u) + s(u)`` for ONE norm ``u`` (Falcon-H1's block)
+HYBRID = "attention_mamba2"
+LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID)
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
@@ -135,6 +140,29 @@ class TransformerConfig:
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     softmax_scale: Optional[float] = None
+    # An attention_mamba2 layer's Mamba-2 mixer (``Mamba2`` below): ssm_heads
+    # heads of ssm_head_dim over ssm_groups groups that share B and C of
+    # ssm_state values, a causal depthwise convolution over ssm_conv tokens, and
+    # ssm_chunk tokens a chunk of the whole-sequence form
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # muP's scalars, constants of the forward and no leaves (1: not there):
+    # on the embedding's rows; on k, on attention's input and output; on the
+    # Mamba-2 mixer's input, on the five segments z, x, B, C, dt of its
+    # in-projection and on its output; on the MLP's gate and output.  The
+    # head's is ``logit_scale``
+    embedding_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
     # dtype of the parameters TransformerLM.init makes (and serving reads)
     param_dtype: Any = jnp.float32
     # Quantized matmul arithmetic (train/_quant.py): none|int8|fp8 routes
@@ -183,6 +211,18 @@ class TransformerConfig:
             raise ValueError(
                 "a power_retention layer runs in a sequential block, without latent attention or a `seq` axis, "
                 "on an even head_dim and whole groups of query heads a KV head"
+            )
+        setattr_("ssm_multipliers", tuple(float(m) for m in self.ssm_multipliers))
+        setattr_("mlp_multipliers", tuple(float(m) for m in self.mlp_multipliers))
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ValueError("ssm_multipliers has one scalar for each of z, x, B, C, dt and mlp_multipliers two")
+        if self.ssm_layers and (
+            min(self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_groups) < 1 or self.ssm_conv < 2
+            or self.ssm_heads % self.ssm_groups or self.latent or self.parallel_block or self.seq_axis_name is not None
+        ):
+            raise ValueError(
+                "an attention_mamba2 layer needs ssm_heads (whole groups of them), ssm_head_dim, ssm_state and "
+                "ssm_conv >= 2, in a sequential block, without latent attention or a `seq` axis"
             )
         if self.qk_norm and len(self.retention_layers) != self.n_layers:
             raise ValueError("qk_norm runs in power_retention layers only: every layer must be one")
@@ -288,6 +328,22 @@ class TransformerConfig:
         """The power-retention layers, in order: serving keeps a state a decode
         lane for each (``models/cache_kinds.py``), and no token's keys or values."""
         return tuple(i for i in range(self.n_layers) if self.layer_type(i) == RETENTION)
+
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        """The layers with a Mamba-2 mixer, in order: serving keeps a state and
+        a convolution tail a decode lane for each (``models/cache_kinds.py``),
+        beside what their attention heads keep."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) == HYBRID)
+
+    @property
+    def ssm_width(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_channels(self) -> int:
+        """What the convolution runs over: x, B and C."""
+        return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def paged_layers(self) -> int:
@@ -436,6 +492,7 @@ class Attention(nn.Module):
             q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "wq")(x)
             k = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wk")(x)
             v = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wv")(x)
+            k = _times(k, cfg.key_multiplier)
             # [b, s, h, d] -> [b, h, s, d]
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
@@ -582,6 +639,105 @@ class LatentAttention(nn.Module):
             return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype))
 
 
+def _times(x: jax.Array, scalar: float) -> jax.Array:
+    """``x`` times one of muP's scalars; at 1 the program has no such product."""
+    return x if scalar == 1.0 else x * scalar
+
+
+def _uniform(low: float, high: float, of=lambda v: v):
+    """An initialiser: ``of`` of a uniform draw, taken in float32."""
+    return lambda key, shape, dtype=jnp.float32: of(jax.random.uniform(key, shape, jnp.float32, low, high)).astype(dtype)
+
+
+def _ssm_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
+    """The Mamba-2 mixer's leaves: name -> (shape, logical axes, initialiser).
+    ``A_log`` and ``dt_bias`` as Mamba-2 draws them (``A`` in (1, 16), a step
+    log-uniform in (0.001, 0.1) through the softplus's inverse: a fresh head
+    remembers between ~1 and ~1,000 tokens), the convolution as a depthwise
+    ``Conv1d`` is drawn (uniform within ``ssm_conv ** -0.5``), ``D`` ones."""
+    d, width, ch, h = cfg.d_model, cfg.ssm_width, cfg.ssm_channels, cfg.ssm_heads
+    kernel, ones, bound = nn.initializers.lecun_normal(), nn.initializers.ones, cfg.ssm_conv ** -0.5
+    return {
+        "w_in": ((d, 2 * width + 2 * cfg.ssm_groups * cfg.ssm_state + h), ("embed", "mlp"), kernel),
+        "conv_w": ((cfg.ssm_conv, ch), (None, "mlp"), _uniform(-bound, bound)),
+        "conv_b": ((ch,), ("mlp",), _uniform(-bound, bound)),
+        "dt_bias": ((h,), (None,), _uniform(math.log(1e-3), math.log(0.1), lambda u: jnp.exp(u) + jnp.log(-jnp.expm1(-jnp.exp(u))))),
+        "A_log": ((h,), (None,), _uniform(1.0, 16.0, jnp.log)),
+        "D": ((h,), (None,), ones),
+        "norm": ((width,), ("mlp",), ones),
+        "w_out": ((width, d), ("mlp", "embed"), kernel),
+    }
+
+
+def _ssm_project(cfg: TransformerConfig, p: Dict[str, Any], u: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The in-projection of the normed input ``u`` [..., d] with its
+    multipliers: the gate ``z`` [..., width], what the convolution takes (x, B,
+    C side by side) [..., channels], and the step before its bias [..., heads]."""
+    width, state, h = cfg.ssm_width, cfg.ssm_groups * cfg.ssm_state, cfg.ssm_heads
+    out = _times(u, cfg.ssm_in_multiplier) @ p["w_in"].astype(cfg.dtype)
+    if set(cfg.ssm_multipliers) != {1.0}:
+        spans = np.repeat(np.asarray(cfg.ssm_multipliers, np.float32), [width, width, state, state, h])
+        out = out * jnp.asarray(spans, cfg.dtype)
+    return out[..., :width], out[..., width: width + cfg.ssm_channels], out[..., width + cfg.ssm_channels:]
+
+
+def _ssm_conv(cfg: TransformerConfig, p: Dict[str, Any], rows: jax.Array) -> jax.Array:
+    """The causal depthwise convolution and its SiLU: ``rows`` [b, s + ssm_conv
+    - 1, channels] is what came before (zeros before a sequence's start) and then
+    the ``s`` tokens; token ``t`` is ``silu(sum_i w_i rows[t + i] + bias)``."""
+    s = rows.shape[1] - cfg.ssm_conv + 1
+    w = p["conv_w"].astype(cfg.dtype)
+    out = sum(w[i] * rows[:, i: i + s] for i in range(cfg.ssm_conv))
+    return nn.silu(out + p["conv_b"].astype(cfg.dtype))
+
+
+def _ssm_split(cfg: TransformerConfig, xbc: jax.Array, dt: jax.Array, p: Dict[str, Any]):
+    """The convolution's output [..., channels] as x [..., heads, P], B and C
+    [..., groups, N]; the step after its bias and softplus, float32 [...,
+    heads]; ``A`` (negative) and ``D``, float32 [heads]."""
+    width, state = cfg.ssm_width, cfg.ssm_groups * cfg.ssm_state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :width].reshape(*lead, cfg.ssm_heads, cfg.ssm_head_dim)
+    b = xbc[..., width: width + state].reshape(*lead, cfg.ssm_groups, cfg.ssm_state)
+    c = xbc[..., width + state:].reshape(*lead, cfg.ssm_groups, cfg.ssm_state)
+    step = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    return x, b, c, step, -jnp.exp(p["A_log"].astype(jnp.float32)), p["D"].astype(jnp.float32)
+
+
+def _ssm_out(cfg: TransformerConfig, p: Dict[str, Any], y: jax.Array, z: jax.Array) -> jax.Array:
+    """``y`` [..., heads, P] float32 gated by ``silu(z)``, then RMSNorm over
+    each group's channels (the gate before the norm), the out-projection and its
+    multiplier."""
+    lead = z.shape[:-1]
+    gated = y.reshape(*lead, cfg.ssm_groups, -1) * nn.silu(z.astype(jnp.float32)).reshape(*lead, cfg.ssm_groups, -1)
+    gated = gated * jax.lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+    normed = gated.reshape(*lead, -1).astype(cfg.dtype) * p["norm"].astype(cfg.dtype)
+    return _times(normed @ p["w_out"].astype(cfg.dtype), cfg.ssm_out_multiplier)
+
+
+class Mamba2(nn.Module):
+    """A Mamba-2 mixer over the whole sequence, in its chunked form
+    (``ops/ssm.py ssm_scan``): what ``init`` builds for serving, and the wide
+    oracle of the serving forward (``models/cache_kinds.py``), which reads the
+    same leaves through the same functions and carries a state and the
+    convolution's tail between its calls."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        p = {
+            name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
+            for name, (shape, logical, init) in _ssm_param_shapes(cfg).items()
+        }
+        with jax.named_scope("attn.ssm"):
+            z, xbc, dt = _ssm_project(cfg, p, u)
+            xbc = _ssm_conv(cfg, p, jnp.pad(xbc, ((0, 0), (cfg.ssm_conv - 1, 0), (0, 0))))
+            x, b, c, step, a, skip = _ssm_split(cfg, xbc, dt, p)
+            return _ssm_out(cfg, p, ssm_scan(x, b, c, step, a, skip, cfg.ssm_chunk), z)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
     mesh: Any = None
@@ -606,10 +762,10 @@ class MLP(nn.Module):
         with jax.named_scope("mlp.dense"):
             gate = dense(cfg.ff_dim, ("embed", "mlp"), "w_gate")(x)
             up = dense(cfg.ff_dim, ("embed", "mlp"), "w_up")(x)
-            h = nn.silu(gate) * up
+            h = nn.silu(_times(gate, cfg.mlp_multipliers[0])) * up
             if cfg.partition_params:
                 h = with_sharding_constraint(h, ("batch", "length", "mlp"), mesh=self.mesh)
-            return dense(cfg.d_model, ("mlp", "embed"), "w_down")(h)
+            return _times(dense(cfg.d_model, ("mlp", "embed"), "w_down")(h), cfg.mlp_multipliers[1])
 
 
 class Block(nn.Module):
@@ -666,6 +822,10 @@ class Block(nn.Module):
             att = LatentAttention(cfg, name="attn")(h)
         elif self.layer_type == RETENTION:
             att = Retention(cfg, name="attn")(h)
+        elif self.layer_type == HYBRID:
+            # attention heads and Mamba-2 heads read the one norm side by side
+            att = Attention(cfg, self.mesh, self.layer_type, name="attn")(_times(h, cfg.attention_in_multiplier))
+            att = _times(att, cfg.attention_out_multiplier) + Mamba2(cfg, name="ssm")(h)
         else:
             att = Attention(cfg, self.mesh, self.layer_type, name="attn")(h)
         if cfg.parallel_block:
@@ -706,7 +866,7 @@ class TransformerLM(nn.Module):
             name="embed",
         )
         with jax.named_scope("lm.embed"):
-            x = embed(tokens)
+            x = _times(embed(tokens), cfg.embedding_multiplier)
             if cfg.partition_params:
                 x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
         block_cls = Block
@@ -747,7 +907,7 @@ class TransformerLM(nn.Module):
             # param tree includes lm_head either way.
             return (x, aux_total) if return_aux else x
         with jax.named_scope("loss.ce"):  # the head's product: the loss's, fused or not
-            out = lm_head(x).astype(jnp.float32)
+            out = _times(lm_head(x).astype(jnp.float32), cfg.logit_scale)
         return (out, aux_total) if return_aux else out
 
 
@@ -970,7 +1130,7 @@ def kv_bytes_per_token(cfg: TransformerConfig) -> int:
     return (cfg.n_layers - len(cfg.retention_layers)) * values * jnp.dtype(cfg.dtype).itemsize
 
 
-#: the dtype of a retention layer's state and normaliser: sums over a whole context
+#: the dtype of a state a lane holds (a retention layer's and its normaliser, a Mamba-2 layer's): sums over a whole context
 STATE_DTYPE = jnp.float32
 
 
@@ -987,6 +1147,23 @@ def state_bytes_per_slot(cfg: TransformerConfig) -> int:
         return 0
     state, norm = state_pool_shapes(cfg, 1)
     return (math.prod(state[1:]) + math.prod(norm[1:])) * jnp.dtype(STATE_DTYPE).itemsize
+
+
+def ssm_pool_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The Mamba-2 layers' state pool (``ops/ssm.py state_shape``: a slot a
+    decode lane a layer and one scratch slot) and the convolution's tails: the
+    last ``ssm_conv - 1`` rows of its input a lane a layer."""
+    layers = len(cfg.ssm_layers)
+    return (
+        ssm_pool_shape(layers, lanes, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+        (layers, lanes, cfg.ssm_conv - 1, cfg.ssm_channels),
+    )
+
+
+def ssm_bytes_per_slot(cfg: TransformerConfig) -> int:
+    """Bytes of ONE Mamba-2 layer's state in one lane: what a decode step reads
+    and writes of it, whatever the context (the tail's 30 KB are not counted)."""
+    return cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * jnp.dtype(STATE_DTYPE).itemsize
 
 
 # What ``LatentAttention`` above and the serving forward both run.

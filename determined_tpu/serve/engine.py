@@ -385,7 +385,7 @@ class DecodeKernels:
         if self._lane_held:
             if start:
                 raise ValueError(
-                    f"a prompt of a model with sliding-window or power-retention layers is prefilled from 0, not from {start}"
+                    f"a prompt of a model that keeps part of its cache by the decode lane (a ring, a state slot) is prefilled from 0, not from {start}"
                 )
             args += (np.asarray([lane], np.int32),)
         logits, self.cache = self._prefill(*args)

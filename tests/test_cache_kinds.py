@@ -1,6 +1,6 @@
 """The table of cache kinds (``models/cache_kinds.py``) is where the forward,
 the kernels and the engine take what they know of a model's cache: over the
-benchmark's four serving architectures at their tiny sizes, the cache is the
+benchmark's five serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_kinds, layer_kind
+from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_kinds, layer_kinds
 from determined_tpu.models.serving import SERVE_COUNTERS, init_kv_cache, serve_counters
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import DecodeKernels, ServeEngine
@@ -29,6 +29,7 @@ ARCHS = {
     "deepseek_mla_moe": ("paged_latent",),
     "cohere2_moe": ("paged_kv", "window_ring"),
     "power_retention": ("state_slot",),
+    "falcon_h1": ("paged_kv", "ssm_slot"),
 }
 
 
@@ -48,17 +49,20 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
     cfg, params, serve_cfg, form = _tiny(arch_name)
     kinds = cache_kinds(cfg)
     assert tuple(kind.name for kind in kinds) == ARCHS[arch_name] and set(kinds) <= set(CACHE_KINDS)
-    # every layer is of one kind, at the next row of that kind's arrays
+    # every layer is of one kind or of several in the table's order, at the next row of each kind's arrays
     rows = {kind.name: 0 for kind in kinds}
     for i in range(cfg.n_layers):
-        kind, j = layer_kind(cfg, i)
-        assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j] == i
-        rows[kind.name] += 1
+        mine = layer_kinds(cfg, i)
+        assert mine and [kind for kind, _ in mine] == [kind for kind in CACHE_KINDS if i in kind.layers(cfg)]
+        for kind, j in mine:
+            assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j] == i
+            rows[kind.name] += 1
+    assert (len(layer_kinds(cfg, 0)) == 2) == (arch_name == "falcon_h1")
 
     # the cache: the union of the kinds' leaves, each of the kind's shape and dtype, a row a layer of the kind
     sizes = serve_cfg  # a kind reads num_blocks, block_size, max_batch and prefill_chunk of it
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, sizes.num_blocks, sizes.block_size, sizes.max_batch, sizes.prefill_chunk))
-    want = {leaf: (shape, jnp.dtype(kind.dtype(cfg))) for kind in kinds for leaf, shape in zip(kind.leaves, kind.shapes(cfg, sizes))}
+    want = {leaf: (shape, jnp.dtype(dtype)) for kind in kinds for leaf, shape, dtype in zip(kind.leaves, kind.shapes(cfg, sizes), kind.dtypes(cfg))}
     assert {leaf: (a.shape, a.dtype) for leaf, a in cache.items()} == want
     assert all(want[leaf][0][0] == len(kind.layers(cfg)) for kind in kinds for leaf in kind.leaves)
 

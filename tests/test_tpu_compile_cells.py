@@ -350,6 +350,85 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
 
 
+def test_the_ssm_decode_kernel_compiles_at_the_falcon_cells_shape(tpu_devices):
+    """64 lanes x 32 Mamba-2 heads of 128 over 2 groups of 256 state values
+    against a float32 state pool of six layers: one kernel, the pool updated
+    where it lies (aliased, no scratch the size of a layer's state)."""
+    ssm_mod = importlib.import_module("determined_tpu.ops.ssm")
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state = ssm_mod.state_shape(6, 64, 32, 128, 256)
+    assert state == (6, 65, 32, 128, 256) and ssm_mod.kernel_takes(32, 2, 128, 256, jnp.float32)
+
+    def fn(x, b, c, dt, a, skip, pool, live):
+        return ssm_mod.ssm_decode(x, b, c, dt, a, skip, pool, 4, live)
+
+    compiled = jax.jit(fn, donate_argnums=(6,)).lower(
+        aval((64, 32, 128), jnp.bfloat16), aval((64, 2, 256), jnp.bfloat16), aval((64, 2, 256), jnp.bfloat16),
+        aval((64, 32), jnp.float32), aval((32,), jnp.float32), aval((32,), jnp.float32), aval(state, jnp.float32),
+        aval((64,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 1 and "ssm_decode" in text
+    assert mem.alias_size_in_bytes >= 4 * math.prod(state) and mem.temp_size_in_bytes < 16 * 1024**2
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_falcon_cells_programs_compile_over_a_layer_of_two_kinds(tpu_devices, which):
+    """The cell's decode step and prefill walk at its widths, lanes, pool and
+    state pool, bfloat16 leaves, all six layers: the weights, both pools and
+    the program's scratch fit the chip's 15.75 GiB; the cache is donated and no
+    second copy of a pool is held; each branch keeps its scopes, the state
+    kernel its name under its own; the paged kernel multiplies 5 queries a KV
+    head in the block-diagonal layout."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.cache_kinds import PAGED_KV, SSM_SLOT, cache_kinds
+    from determined_tpu.models.serving import transformer_decode, transformer_prefill_chunked
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, ssm_pool_shapes
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=261120, d_model=5120, n_layers=6, n_heads=20, n_kv_heads=4, head_dim=128, d_ff=21504, max_seq_len=2560,
+        layer_types=("attention_mamba2",) * 6, rope_theta=1e11, norm_eps=1e-5, param_dtype=jnp.bfloat16,
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2, ssm_conv=4, ssm_chunk=128,
+        embedding_multiplier=5.656854249492381, key_multiplier=0.011048543456039804, attention_out_multiplier=0.0375,
+        ssm_in_multiplier=0.25, ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738),
+        ssm_out_multiplier=0.08838834764831845, mlp_multipliers=(0.1767766952966369, 0.011160714285714284), logit_scale=0.0078125,
+    )
+    assert cache_kinds(cfg) == (PAGED_KV, SSM_SLOT)
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    pool, (state, tail) = kv_cache_shape(cfg, 8193, 16), ssm_pool_shapes(cfg, 64)
+    assert pool == (6, 8193, 16, 512) and state == (6, 65, 32, 128, 256) and tail == (6, 64, 3, 5120)
+    cache = {"k": aval(pool, jnp.bfloat16), "v": aval(pool, jnp.bfloat16), "ssm": aval(state, jnp.float32), "conv": aval(tail, jnp.bfloat16)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((64,)), aval((64,)), aval((64, 160)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg, chunk_tokens=256), donate_argnums=(5,))
+        args = (params, aval((1, 2048)), aval((1,)), aval((1,)), aval((1, 160)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache_bytes = 2 * 2 * math.prod(pool) + 4 * math.prod(state) + 2 * math.prod(tail)
+    assert mem.alias_size_in_bytes >= cache_bytes                                    # the cache is donated: no second copy of a pool
+    assert mem.argument_size_in_bytes >= 2 * 5_254_594_112 + cache_bytes
+    # what the chip must hold at once: the arguments (the weights, both pools), what is not aliased of the output, the scratch
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.75 * 1024**3
+    assert mem.temp_size_in_bytes < (256 if which == "decode" else 768) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.attn.qkv", "serve.kv.write", "serve.attn.attend", "serve.attn.out", "serve.ssm.in", "serve.ssm.state",
+            "serve.ssm.out", "serve.mlp", "serve.embed", "serve.head"} <= set(scopes)
+    if which == "decode":                                                            # a layer: the paged kernel and the state kernel
+        assert _kernels(text) == 12 and len({n for n in scopes["serve.ssm.state"] if n.startswith("ssm_decode")}) == 6
+        assert paged_mod.attn_products(5) == "block_diagonal"
+    print(which, "args", mem.argument_size_in_bytes, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes)
+
+
 # -- the decode step's sampler: one call over all lanes, at the serving cells' logits ------
 
 
