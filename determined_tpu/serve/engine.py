@@ -20,6 +20,15 @@ the step's counters.  A request's first token, one row from its prefill, is
 sampled on the host (``sample_token``, which is also the oracle the tests
 hold the device's sampler to; ``docs/serving.md`` has the contract of both).
 
+Of what the host does for a step, everything that needs no ids of that step
+is done while the device runs it, and what the device already holds is not
+sent again (``docs/serving.md`` "A step, in order").  A lane's block table is
+an int32 row made once at admission; the engine keeps one matrix of them and
+the device's copy of it, sent again only after a lane joined or retired.  A
+step's tokens are the ids the sampler left on the device the step before,
+unless a lane joined since.  The lanes' uniforms are drawn, and sent, between
+the decode call's two stamps (``DecodeKernels.during_wait``).
+
 The engine times itself.  One set of ``time.monotonic()`` stamps — a
 handful a step, one a token — is taken always and feeds two sinks: the
 cumulative ``step_seconds`` and the window of recent requests' latencies
@@ -36,7 +45,7 @@ import functools
 import logging
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -297,6 +306,11 @@ class DecodeKernels:
         #: newest ``decode``: the engine, which knows the step, turns them
         #: into ``serve.decode.dispatch`` and ``serve.decode.wait``
         self.last_decode_stamps: Optional[Tuple[float, float, float]] = None
+        #: what the engine left for the next ``decode`` to run on this thread
+        #: while the device runs the step: called once between the call's two
+        #: stamps, and cleared by the call (an attribute and no parameter:
+        #: whatever wraps ``decode`` from outside passes its three arrays on)
+        self.during_wait: Optional[Callable[[], None]] = None
         #: a model with expert layers or a cache kind that counts: the decode
         #: program returns one more row of logits, whose first entries are these
         #: counts of the step (``transformer_decode``), in this order; the
@@ -346,7 +360,7 @@ class DecodeKernels:
         # The first two write the scratch block alone: a one-token prompt
         # under a table of block 0, and a step with every lane idle, whose
         # logits the engine's sampler (``lane_sampler``) then takes as it
-        # will take a step's.
+        # will take a step's, with draws the device holds.
         self._prefill_from([0], [0] * serve_cfg.blocks_per_seq, 0)
         lanes = serve_cfg.max_batch
         logits, self.cache = self._decode(
@@ -354,11 +368,11 @@ class DecodeKernels:
             np.zeros((lanes, serve_cfg.blocks_per_seq), np.int32), self.cache,
         )
         sample = timed_first_call(lane_sampler(len(self.counters)), "jit.compile.serve.sample")
-        jax.block_until_ready(sample(logits, np.zeros((2, lanes), np.float32)))
+        jax.block_until_ready(sample(logits, jax.device_put(np.zeros((2, lanes), np.float32))))
 
     # -- kernel entry points (device round trips happen HERE) ---------------
 
-    def prefill(self, prompt: List[int], block_table: List[int], lane: int = 0) -> np.ndarray:
+    def prefill(self, prompt: List[int], block_table: Any, lane: int = 0) -> np.ndarray:
         """Prefill one sequence from its first token, writing its K/V into
         the paged cache; returns the f32 logits at the last prompt token.
         ``lane``: the decode lane (the row of ``decode``'s batch) the sequence
@@ -366,16 +380,15 @@ class DecodeKernels:
         lane writes; a model without one takes no notice of it."""
         return self._prefill_from(prompt, block_table, 0, lane)
 
-    def prefill_suffix(
-        self, prompt: List[int], block_table: List[int], start: int, lane: int = 0
-    ) -> np.ndarray:
+    def prefill_suffix(self, prompt: List[int], block_table: Any, start: int, lane: int = 0) -> np.ndarray:
         """Prefill only ``prompt[start:]`` (the un-cached suffix; ``start``
         is block-aligned — the cached prefix already sits in the mapped
         blocks).  Returns the f32 logits at the last prompt token."""
         return self._prefill_from(prompt, block_table, start, lane)
 
-    def _prefill_from(self, prompt: List[int], block_table: List[int], start: int, lane: int = 0) -> np.ndarray:
-        # under both entry points, which a caller may wrap one by one
+    def _prefill_from(self, prompt: List[int], block_table: Any, start: int, lane: int = 0) -> np.ndarray:
+        # under both entry points, which a caller may wrap one by one; the
+        # engine's table is a sequence's own int32 row, taken as it is
         tokens = np.zeros((1, self._prompt_pad), np.int32)
         tokens[0, : len(prompt)] = prompt
         table = np.asarray(block_table, np.int32)[None, :]
@@ -391,10 +404,12 @@ class DecodeKernels:
         logits, self.cache = self._prefill(*args)
         return np.asarray(logits[0])
 
-    def decode(self, tokens: np.ndarray, positions: np.ndarray, tables: np.ndarray) -> Any:
+    def decode(self, tokens: Any, positions: np.ndarray, tables: Any) -> Any:
         """One decode step over every lane; returns the f32 logits
         ``[B, vocab]`` as the device holds them, ready, and with one more row
         after the lanes' where the model counts its steps (``counters``).
+        ``tokens`` and ``tables`` are the host's arrays or the device's (the
+        engine hands over the sampler's ids and its own copy of the table).
 
         Nothing of them is copied to the host here: the engine draws the
         step's tokens from them on the device (``sample_lanes``), and a
@@ -402,22 +417,28 @@ class DecodeKernels:
         The call is stamped where its two parts end — the jitted call returns
         (enqueued), the logits are ready on the device — and the stamps left
         in ``last_decode_stamps``: whatever times this call from outside
-        holds the device's whole step."""
+        holds the device's whole step.  Between the two, while the device
+        runs the step, ``during_wait`` is run, once, where the engine left
+        one."""
         t0 = mono()
         logits, self.cache = self._decode(
             self.params, tokens, positions, tables, self.cache
         )
         t1 = mono()
+        work, self.during_wait = self.during_wait, None
+        if work is not None:
+            work()
         logits.block_until_ready()
         self.last_decode_stamps = (t0, t1, mono())
         return logits
 
 
 class LaneRow:
-    """What a decode step hands ``ServeEngine._advance_lane`` for one lane:
-    the token the device drew for it, and the lane's float32 logits for
-    whoever asks for them as an array (one row's copy to the host).  The
-    engine reads the token and never asks."""
+    """What a decode step hands a sampler of the caller's own
+    (``ServeEngine._advance_lane``) for one lane: the token the device drew
+    for it, and the lane's float32 logits for whoever asks for them as an
+    array (one row's copy to the host).  The engine's own bookkeeping reads
+    the token where it lies and makes none."""
 
     __slots__ = ("token", "_logits", "_lane")
 
@@ -433,10 +454,35 @@ class LaneRow:
 class ServeEngine:
     """Continuous batching: join between any two steps, retire instantly."""
 
+    #: a sampler of the caller's own, ``(seq, row: LaneRow) -> finished``, laid
+    #: over an engine as a bound method: called for each live lane of a step in
+    #: place of the engine's bookkeeping (which gives the lane the device's
+    #: token), it appends the token and its stamp, advances ``pos`` and
+    #: ``next_token`` and counts what it emitted itself
+    _advance_lane: Optional[Callable[[ActiveSeq, LaneRow], bool]] = None
+
     def __init__(self, kernels: DecodeKernels) -> None:
         self.kernels = kernels
         self.cfg = kernels.serve_cfg
         self.lanes = LaneTable(self.cfg.max_batch)
+        #: every lane's block table, for the engine's life: a lane that joins
+        #: writes its row, a lane that retires gets the scratch block 0 again
+        #: (an idle lane's row never names a block the allocator may have
+        #: handed to someone else)
+        self._tables = np.zeros((self.cfg.max_batch, self.cfg.blocks_per_seq), np.int32)
+        #: the device's copy of it; None once a row changed: the next step
+        #: sends the matrix again
+        self._tables_on_device: Any = None
+        #: the lanes' positions at the next step (-1: idle).  A step hands
+        #: the array over and makes the next one while the device runs
+        self._positions = np.full(self.cfg.max_batch, -1, np.int32)
+        #: the ids the newest step's sampler left on the device: the next
+        #: step's tokens as they lie.  None once a lane joined (its first
+        #: token is the host's draw) and where the caller's own sampler chose
+        self._ids_on_device: Any = None
+        #: decode steps, those whose table was sent again, and those whose
+        #: tokens were the device's ids (``/stats`` ``step_inputs``)
+        self._step_inputs = {"decode_steps": 0, "table_sent": 0, "tokens_from_device": 0}
         from determined_tpu.models.cache_kinds import BLOCKS
 
         #: whether a request holds blocks of some kind of the kernels' cache
@@ -739,6 +785,7 @@ class ServeEngine:
                 "steps": self._steps,
             }
             step_counters = dict(self._step_counters)
+            step_inputs = dict(self._step_inputs)
         if recent is not None:
             latency = {
                 name: _summary_ms([r[i] for r in recent if r[i] is not None])
@@ -756,15 +803,20 @@ class ServeEngine:
             # requests (tpot: those with two tokens or more)
             "latency": {name: dict(v) for name, v in latency.items()},
             # where the engine thread's time went, cumulative since start:
-            # waiting for the decode program, sampling (the draws, the
-            # device's call, its ids taken per lane), of which copying the
-            # ids and the counters to the host, admitting (prefill and
-            # first sample)
+            # waiting for the decode program (the lanes' uniforms are drawn
+            # and sent inside that wait), sampling (the device's call, its
+            # ids taken per lane), of which copying the ids and the counters
+            # to the host, admitting (prefill and first sample)
             "step_seconds": step_seconds,
             # cumulative counts of the decode steps, where the model has
             # expert layers: picks that landed on the experts held here and
             # held experts that got a row, each summed over layers and steps
             "step_counters": step_counters,
+            # how often a decode step had to send its inputs: the block table
+            # goes to the device again only after a lane joined or retired,
+            # and the tokens are the ids the sampler left there unless a lane
+            # joined since
+            "step_inputs": step_inputs,
             "queue_depth": self.queue.depth(),
             # static queue bound: the router's saturation signal — at
             # queue_depth >= queue_capacity the next submit would 429
@@ -794,8 +846,11 @@ class ServeEngine:
 
     # -- the engine thread's work ---------------------------------------------
 
-    def _padded_table(self, blocks: List[int]) -> List[int]:
-        return blocks + [0] * (self.cfg.blocks_per_seq - len(blocks))
+    def _padded_table(self, blocks: List[int]) -> np.ndarray:
+        """A sequence's row of the block table, made once: its blocks, then the scratch block 0."""
+        table = np.zeros(self.cfg.blocks_per_seq, np.int32)
+        table[: len(blocks)] = blocks
+        return table
 
     def _start_sequence(self, req: GenRequest, step: int) -> Optional[ActiveSeq]:
         """Allocate + prefill + sample the first token.  Returns the live
@@ -922,60 +977,84 @@ class ServeEngine:
             self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s))
         self._record_request(req)
 
-    def _decode_batch(self, lanes: List[Optional[ActiveSeq]]) -> Tuple[Any, np.ndarray, float, float]:
-        """One jitted decode step over the full (static) lane table.
+    def _decode_batch(
+        self, lanes: List[Optional[ActiveSeq]]
+    ) -> Tuple[Any, np.ndarray, Any, Dict[str, int], Tuple[float, float], Tuple[float, float]]:
+        """One jitted decode step over the full (static) lane table, and
+        inside its wait what the step's sampler needs that no id decides.
         Returns the logits as the kernels handed them back (on the device,
-        ready), the lanes' positions (-1: idle) and the call's two ends."""
-        b = self.cfg.max_batch
-        t = self.cfg.blocks_per_seq
-        tokens = np.zeros(b, np.int32)
-        positions = np.full(b, -1, np.int32)
-        tables = np.zeros((b, t), np.int32)
-        for i, seq in enumerate(lanes):
-            if seq is None:
-                continue
-            tokens[i] = seq.next_token
-            positions[i] = seq.pos
-            tables[i] = seq.block_table
-        t0 = mono()
-        logits = self.kernels.decode(tokens, positions, tables)
-        return logits, positions, t0, mono()
+        ready), the lanes' positions (-1: idle), the lanes' temperatures
+        over their uniforms as the device holds them, which of the step's
+        inputs had to be sent (``step_inputs``), the call's two ends and the
+        prepared work's.
 
-    def _advance_lane(self, seq: ActiveSeq, row: LaneRow) -> bool:
-        """Give one lane the token the step's call drew for it; True when
-        the seq finished."""
-        tok = row.token
-        seq.request.output.append(tok)
-        seq.request.token_at.append(mono())
-        seq.pos += 1
-        seq.next_token = tok
-        with self._stats_lock:
-            self._tokens_generated += 1
-            self._tokens_sampled_on_device += 1
-        return self._sequence_finished(seq, tok)
+        The table goes to the device only where a row changed since it last
+        went; the tokens are the ids the last step's sampler left there,
+        unless a lane joined since (then the host's, as each lane's
+        ``next_token`` has them).  The positions are the host's array.
+
+        The prepared work runs on this thread between the kernels' two
+        stamps (``DecodeKernels.during_wait``), and here, after the call, under
+        a stand-in for the kernels that never runs it: a sampled lane's
+        uniform is ONE ``rng.random()`` of its request's generator, drawn in
+        lane order (a greedy lane draws nothing), the ``[2, lanes]`` draws go
+        to the device, and the positions of the step after are made (a NEW
+        array: whoever wraps the call reads this step's after it returns)."""
+        import jax
+
+        b = self.cfg.max_batch
+        positions = self._positions
+        sent = {"table_sent": int(self._tables_on_device is None), "tokens_from_device": int(self._ids_on_device is not None)}
+        if self._tables_on_device is None:
+            # a copy: the matrix is written again while the device's buffer is still held
+            self._tables_on_device = jax.device_put(self._tables.copy())
+        tokens = self._ids_on_device
+        if tokens is None:
+            tokens = np.zeros(b, np.int32)
+            for i, seq in enumerate(lanes):
+                if seq is not None:
+                    tokens[i] = seq.next_token
+        prepared: List[Any] = []
+
+        def prepare() -> None:
+            t_prepare = mono()
+            draws = np.zeros((2, b), np.float32)  # temperatures over uniforms; an idle lane: argmax, ignored
+            for i, seq in enumerate(lanes):
+                if seq is not None and seq.request.temperature > 0.0:
+                    draws[0, i] = seq.request.temperature
+                    draws[1, i] = seq.rng.random()
+            on_device = jax.device_put(draws)
+            self._positions = np.where(positions >= 0, positions + 1, positions)
+            prepared.extend((on_device, (t_prepare, mono())))
+
+        kernels = self.kernels
+        kernels.during_wait = prepare
+        t0 = mono()
+        logits = kernels.decode(tokens, positions, self._tables_on_device)
+        t1 = mono()
+        if not prepared:
+            kernels.during_wait = None
+            prepare()
+        return logits, positions, prepared[0], sent, (t0, t1), prepared[1]
 
     def _decode_and_sample(self, lanes: List[Optional[ActiveSeq]], step: int) -> int:
         """One decode step over the lane table, then one call that draws
         every lane's token on the device (``sample_lanes``) from the logits
-        where they lie; what comes to the host is the ids and the step's
-        counters.  Each live lane then takes its token, in lane order; a
-        sequence that finished is retired at once (its response must not
-        wait for the other lanes).  Returns how many finished.
+        where they lie, with the draws the device already holds; what comes
+        to the host is the ids and the step's counters.  Each live lane then
+        takes its token, in lane order, the step's counts go into the stats
+        under ONE hold of their lock, and the sequences that finished are
+        retired, in this step (a response's counts are in ``stats()`` before
+        it is complete).  Returns how many finished.
 
-        A sampled lane's uniform is ONE ``rng.random()`` of its request's
-        generator, drawn here in lane order; a greedy lane draws nothing.
-        ``serve.sample`` runs from before the draws to the last lane's token
-        stamp, and ``serve.decode.d2h`` (inside it) from the ids being ready
-        on the device to their being on the host.  ``serve.decode`` has
+        ``serve.sample`` runs from the sampler's launch to the last lane's
+        token stamp, and ``serve.decode.d2h`` (inside it) from the ids being
+        ready on the device to their being on the host.  ``serve.decode`` has
         ended before: a reader that takes the device's step from it counts
-        no operation of the sampler."""
-        logits, positions, t_call, t_back = self._decode_batch(lanes)
+        no operation of the sampler.  ``serve.step.prepare`` is the prepared
+        work, inside ``serve.decode.wait`` where the kernels ran it."""
+        logits, positions, draws, sent, (t_call, t_back), prepare = self._decode_batch(lanes)
         t0 = mono()
-        draws = np.zeros((2, len(lanes)), np.float32)  # temperatures over uniforms; an idle lane: argmax, ignored
-        for i, seq in enumerate(lanes):
-            if seq is not None and seq.request.temperature > 0.0:
-                draws[0, i] = seq.request.temperature
-                draws[1, i] = seq.rng.random()
         ids, counted = lane_sampler(len(self._counters))(logits, draws)
         # queued behind the program at once: waiting for the ids first and
         # only then asking for them costs a host round trip
@@ -993,18 +1072,32 @@ class ServeEngine:
         stamps = getattr(self.kernels, "last_decode_stamps", None)
         if stamps is not None and stamps[0] < t_call:
             stamps = None
-        finished = live = 0
-        before = self._tokens_sampled_on_device
+        own = self._advance_lane
+        self._ids_on_device = ids if own is None else None
+        finished: List[Tuple[int, ActiveSeq]] = []
+        live = 0
         for i, seq in enumerate(lanes):
             if seq is None:
                 continue
             live += 1
-            done = self._advance_lane(seq, LaneRow(tokens[i], logits, i))
-            t1 = seq.request.token_at[-1]
+            tok = tokens[i]
+            if own is None:
+                req = seq.request
+                req.output.append(tok)
+                t1 = mono()
+                req.token_at.append(t1)
+                seq.pos += 1
+                seq.next_token = tok
+                done = self._sequence_finished(seq, tok)
+            else:
+                done = own(seq, LaneRow(tok, logits, i))
+                t1 = seq.request.token_at[-1]
             if done:
-                self._retire_lane(i, seq)
-                finished += 1
+                finished.append((i, seq))
+        on_device = live if own is None else 0
         with self._stats_lock:
+            self._tokens_generated += on_device
+            self._tokens_sampled_on_device += on_device
             seconds = self._step_seconds
             if stamps is not None:
                 seconds["decode_wait"] += stamps[2] - stamps[1]
@@ -1012,8 +1105,12 @@ class ServeEngine:
             seconds["sample"] += t1 - t0
             for name, value in counts.items():
                 self._step_counters[name] = self._step_counters.get(name, 0.0) + value
+            self._step_inputs["decode_steps"] += 1
+            for name, value in sent.items():
+                self._step_inputs[name] += value
             self._steps = step
-            on_device = self._tokens_sampled_on_device - before
+        for i, seq in finished:
+            self._retire_lane(i, seq)
         tracer = self._tracer
         if tracer.enabled:
             at = {"step": step}
@@ -1027,17 +1124,19 @@ class ServeEngine:
                     "active": live,
                     "live_kv_tokens": int((positions + 1).sum()),
                     "max_context": int(positions.max()) + 1,
+                    **sent,
                     **counts,
                 },
             )
             if stamps is not None:
                 tracer.record_span("serve.decode.dispatch", "serve", stamps[0], stamps[1], at)
                 tracer.record_span("serve.decode.wait", "serve", stamps[1], stamps[2], at)
+            tracer.record_span("serve.step.prepare", "serve", *prepare, at)
             tracer.record_span("serve.decode.d2h", "serve", t_ready, t_host, at)
             tracer.record_span(
                 "serve.sample", "serve", t0, t1, {**at, "lanes": live, "device_lanes": on_device}
             )
-        return finished
+        return len(finished)
 
     def _admit_one(self, step: int) -> bool:
         """Try to move one queued request into a lane.  False when nothing
@@ -1057,10 +1156,20 @@ class ServeEngine:
             return True
         if seq is not None:
             self.lanes.join(seq, seq.lane)
+            self._positions[seq.lane] = seq.pos
+            self._ids_on_device = None  # its first token is the host's draw
+            if seq.blocks:
+                self._tables[seq.lane] = seq.block_table
+                self._tables_on_device = None
         return True
 
     def _retire_lane(self, lane: int, seq: ActiveSeq) -> None:
         self.lanes.retire(lane)
+        self._positions[lane] = -1
+        if seq.blocks:
+            # before the blocks are free again: an idle lane names the scratch block alone
+            self._tables[lane] = 0
+            self._tables_on_device = None
         self._retire_seq(seq)
 
     def step_once(self) -> bool:
@@ -1075,7 +1184,7 @@ class ServeEngine:
             if not self._admit_one(step):
                 break
             admitted += 1
-        snapshot = list(self.lanes.snapshot())
+        snapshot = self.lanes.snapshot()
         active = sum(1 for seq in snapshot if seq is not None)
         retired = 0
         if active:

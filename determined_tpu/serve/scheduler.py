@@ -173,7 +173,7 @@ class ActiveSeq:
 
     request: GenRequest
     blocks: List[int]               # physical block ids owned by this seq
-    block_table: List[int]          # padded to blocks_per_seq with scratch 0
+    block_table: Any                # int32 [blocks_per_seq], made once at admission: `blocks`, then scratch 0
     pos: int                        # position of the NEXT token to feed
     next_token: int                 # token to feed at `pos`
     lane: int                       # the decode lane it was prefilled for

@@ -1018,6 +1018,280 @@ def test_a_sampler_of_the_callers_own_is_handed_each_live_lanes_float32_row(kern
     assert eng.stats()["tokens_sampled_on_device"] == 0
 
 
+# ---------------------------------------------------------------------------
+# a step's inputs: the table and the tokens stay on the device, the draws are
+# made inside the decode call's wait (PR 49)
+# ---------------------------------------------------------------------------
+
+
+class _HostRebuiltEngine(ServeEngine):
+    """The path before PR 49, as the reference: every step makes the table
+    and the tokens anew on the host from each lane's own state, hands the
+    kernels three numpy arrays, and draws the uniforms after the call."""
+
+    def _decode_batch(self, lanes):
+        b, t = self.cfg.max_batch, self.cfg.blocks_per_seq
+        tokens, positions = np.zeros(b, np.int32), np.full(b, -1, np.int32)
+        tables = np.zeros((b, t), np.int32)
+        for i, seq in enumerate(lanes):
+            if seq is not None:
+                tokens[i], positions[i], tables[i] = seq.next_token, seq.pos, seq.block_table
+        t0 = time.monotonic()
+        logits = self.kernels.decode(tokens, positions, tables)
+        t1 = time.monotonic()
+        draws = np.zeros((2, b), np.float32)
+        for i, seq in enumerate(lanes):
+            if seq is not None and seq.request.temperature > 0.0:
+                draws[:, i] = seq.request.temperature, seq.rng.random()
+        return logits, positions, draws, {"table_sent": 1, "tokens_from_device": 0}, (t0, t1), (t1, t1)
+
+
+def _mixed_traffic(seed=11, n=12):
+    """(step it arrives at, prompt, keywords): sampled and greedy requests of
+    unequal lengths, half of them behind one of two shared prefixes of two
+    blocks, so that lanes join and retire all through the run."""
+    rng = np.random.default_rng(seed)
+    shared = [[int(t) for t in rng.integers(1, 64, size=8)] for _ in range(2)]
+    plan = []
+    for i in range(n):
+        own = [int(t) for t in rng.integers(1, 64, size=int(rng.integers(1, 8)))]
+        prompt = (shared[i % 2] + own) if i % 2 else own
+        plan.append((
+            int(rng.integers(0, 14)), prompt,
+            dict(max_new_tokens=int(rng.integers(1, 12)), temperature=float(rng.choice([0.0, 0.7, 1.2])), seed=100 + i),
+        ))
+    return sorted(plan, key=lambda entry: entry[0])
+
+
+def _drive(eng, plan):
+    """Submit each request at its step, step the engine by hand to the end;
+    the requests in the plan's order."""
+    reqs, waiting, step = [], list(plan), 0
+    while waiting or eng.lanes.active_count() or not eng.queue.empty():
+        while waiting and waiting[0][0] <= step and eng.queue.depth() < eng.cfg.queue_depth:
+            _, prompt, kw = waiting.pop(0)
+            reqs.append(eng.submit(prompt, **kw))
+        eng.step_once()
+        step += 1
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    return reqs
+
+
+class _SeenDecodes:
+    """Wraps ``kernels.decode`` as the benchmark's recorder does (three
+    positional arrays) and keeps what each call was handed."""
+
+    def __init__(self, kernels):
+        self.kernels, self.calls = kernels, []
+        self._decode = kernels.decode
+
+    def __enter__(self):
+        def decode(tokens, positions, tables):
+            out = self._decode(tokens, positions, tables)
+            self.calls.append((tokens, positions.copy(), tables, np.asarray(tables).copy()))
+            return out
+
+        self.kernels.decode = decode
+        return self
+
+    def __exit__(self, *exc):
+        del self.kernels.decode
+
+
+def test_every_requests_tokens_are_those_of_the_host_rebuilt_step(kernels):
+    """(a) Over a seeded mix of sampled and greedy requests with joins,
+    retirements and the prefix cache on, every request gets the tokens of
+    the path that makes the table and the tokens anew every step and draws
+    after the call; and the table the device holds is, at every step, the
+    live lanes' own rows over the scratch block for every idle lane."""
+    plan = _mixed_traffic()
+    want = [r.output for r in _drive(_HostRebuiltEngine(kernels), plan)]
+    eng = ServeEngine(kernels)
+    with _SeenDecodes(kernels) as seen:
+        got = _drive(eng, plan)
+    assert [r.output for r in got] == want
+    assert any(r.temperature > 0 and len(r.output) > 3 for r in got) and any(r.temperature == 0 for r in got)
+    stats = eng.stats()
+    assert stats["prefix_hits"] > 0 and stats["lanes"]["joined"] == stats["lanes"]["retired"] > SERVE_CFG.max_batch
+    assert 0 < stats["step_inputs"]["tokens_from_device"] < stats["step_inputs"]["decode_steps"] == len(seen.calls)
+    for _tokens, positions, _tables, table in seen.calls:
+        assert table.dtype == np.int32 and not table[positions < 0].any()
+        assert (table[positions >= 0][:, 0] > 0).all()  # a live lane's first block is its own, never the scratch block
+    assert not eng._tables.any() and (eng._positions == -1).all()  # all retired: nothing but the scratch block is named
+
+
+def test_a_retired_lanes_row_names_the_scratch_block_before_its_blocks_are_anyones(lm_setup):
+    """(b) A lane that retires has its row set to block 0 in the step that
+    retired it, and a request admitted next, into ANOTHER lane and onto the
+    blocks just freed, runs as it runs alone."""
+    cfg, _model, variables = lm_setup
+    serve_cfg = ServeConfig(
+        block_size=4, num_blocks=64, max_batch=4, max_prompt_len=16, max_new_tokens=32, queue_depth=4, prefix_cache=False,
+    )
+    kernels = DecodeKernels(cfg, variables, serve_cfg)
+    late = dict(max_new_tokens=9, temperature=0.9, seed=5)
+    alone = ServeEngine(kernels)
+    want = alone.submit([9, 8, 7, 6, 5], **late)
+    while alone.step_once():
+        pass
+    eng = ServeEngine(kernels)
+    first = eng.submit([1, 2, 3], max_new_tokens=2)        # lane 0, gone after one step
+    second = eng.submit([4, 5, 6, 7, 8], max_new_tokens=4)  # lane 1, gone two steps later
+    eng.step_once()
+    assert first.done.is_set() and not eng._tables[0].any() and eng._tables[1].any()
+    held = list(eng.lanes.get(1).blocks)
+    assert eng._tables[1].tolist() == held + [0] * (serve_cfg.blocks_per_seq - len(held))
+    while not second.done.is_set():
+        eng.step_once()
+    assert not eng._tables.any() and eng._tables_on_device is None  # before the next step, and marked to be sent
+    third = eng.submit([9, 8, 7, 6, 5], **late)
+    with _SeenDecodes(kernels) as seen:
+        eng.step_once()
+        seq = eng.lanes.get(0)
+        assert seq.request is third and set(seq.blocks) & set(held)  # the blocks lane 1 just let go, in lane 0
+        while eng.step_once():
+            pass
+    assert all(not table[1:].any() and table[0].any() for *_, table in seen.calls)
+    assert third.error is None and third.output == want.output and len(want.output) == 9
+
+
+class _KernelsThatNeverPrepare:
+    """A stand-in that hands the decode call on and knows nothing of
+    ``during_wait`` (the engine sets it on THIS object, which never runs it)
+    nor of stamps; ``host`` makes it return the host's array."""
+
+    def __init__(self, kernels, host):
+        self._kernels, self._host = kernels, host
+        self.serve_cfg, self.model_cfg, self.kinds = kernels.serve_cfg, kernels.model_cfg, kernels.kinds
+        self.prefill, self.prefill_suffix = kernels.prefill, kernels.prefill_suffix
+
+    def decode(self, tokens, positions, tables):
+        assert self._kernels.during_wait is None  # nothing reached the kernels underneath
+        out = self._kernels.decode(tokens, positions, tables)
+        return np.asarray(out) if self._host else out
+
+
+@pytest.mark.parametrize("host", [False, True], ids=["device_logits", "host_logits"])
+def test_kernels_that_never_run_the_prepared_work_give_the_same_tokens(kernels, tracer, host):
+    """(c) Under a stand-in for the kernels that never runs what the engine
+    left for the call's wait, the engine runs it itself after the call: the
+    same tokens, whether the stand-in returns the device's logits or the
+    host's, and the span says that nothing overlapped."""
+    plan = _mixed_traffic(seed=12, n=8)
+    want = [r.output for r in _drive(ServeEngine(kernels), plan)]
+    tracer.reset()
+    shim = _KernelsThatNeverPrepare(kernels, host)
+    got = _drive(ServeEngine(shim), plan)
+    assert [r.output for r in got] == want and shim.during_wait is None
+    decodes = {e["args"]["step"]: e for e in _spans(tracer, "serve.decode")}
+    prepared = _spans(tracer, "serve.step.prepare")
+    assert len(prepared) == len(decodes) > 5 and not _spans(tracer, "serve.decode.wait")
+    for e in prepared:
+        assert e["ts"] >= decodes[e["args"]["step"]]["ts"] + decodes[e["args"]["step"]]["dur"] - 0.2
+
+
+def test_the_table_is_sent_after_a_join_or_a_retirement_and_the_devices_ids_go_in_otherwise(kernels, tracer):
+    """(d) The two counters, a step at a time: the table goes to the device
+    again on exactly the steps after one in which a lane joined or retired
+    (and on the first), the tokens are the device's ids, the array the
+    sampler returned, on exactly the steps no lane joined before."""
+    eng = ServeEngine(kernels)
+    moved = []  # (lanes joined, lanes joined or retired) before each decode call, since the call before
+    with _SeenDecodes(kernels) as seen:
+        inner = kernels.decode
+        last = [0, 0]
+
+        def decode(tokens, positions, tables):
+            lanes = eng.lanes.stats()
+            moved.append((lanes["joined"] - last[0], lanes["joined"] + lanes["retired"] - sum(last)))
+            last[:] = lanes["joined"], lanes["retired"]
+            return inner(tokens, positions, tables)
+
+        kernels.decode = decode
+        _drive(eng, _mixed_traffic(seed=13, n=10))
+    assert len(moved) == len(seen.calls) > 10
+    sent = from_device = 0
+    for n, ((joined, changed), (tokens, _positions, tables, _table)) in enumerate(zip(moved, seen.calls)):
+        assert isinstance(tables, jax.Array)
+        again = n == 0 or tables is not seen.calls[n - 1][2]
+        assert again == (n == 0 or changed > 0), n
+        assert isinstance(tokens, jax.Array) == (joined == 0), n
+        assert isinstance(tokens, (jax.Array, np.ndarray)) and tokens.dtype == np.int32
+        sent += again
+        from_device += joined == 0
+    assert 0 < sent < len(moved) and 0 < from_device < len(moved)
+    assert eng.stats()["step_inputs"] == {"decode_steps": len(moved), "table_sent": sent, "tokens_from_device": from_device}
+    spans = sorted(_spans(tracer, "serve.decode"), key=lambda e: e["args"]["step"])
+    assert [e["args"]["table_sent"] for e in spans] == [int(n == 0 or changed > 0) for n, (_, changed) in enumerate(moved)]
+    assert [e["args"]["tokens_from_device"] for e in spans] == [int(joined == 0) for joined, _ in moved]
+
+
+def test_the_prepared_work_lies_inside_the_decode_calls_wait(kernels, tracer):
+    """(e) ``serve.sample`` starts after ``serve.decode`` has ended, and the
+    prepared work's span lies inside ``serve.decode.wait`` of its step, the
+    draws of every sampled lane in it: the lanes' generators have moved by
+    the time the call returns."""
+    eng = ServeEngine(kernels)
+    reqs = [eng.submit([3 + i, 4], max_new_tokens=5, temperature=0.8 * (i % 2), seed=i) for i in range(3)]
+    decode, drawn = kernels.decode, []
+
+    def watched(*a):
+        before = [seq.rng.bit_generator.state["state"]["state"] for seq in eng.lanes.snapshot() if seq is not None]
+        out = decode(*a)
+        after = [seq.rng.bit_generator.state["state"]["state"] for seq in eng.lanes.snapshot() if seq is not None]
+        drawn.append([x != y for x, y in zip(before, after)])
+        return out
+
+    kernels.decode = watched
+    try:
+        while eng.step_once():
+            pass
+    finally:
+        del kernels.decode
+    assert all(r.error is None and len(r.output) == 5 for r in reqs)
+    assert drawn and all(step == [False, True, False] for step in drawn)  # inside the call, the sampled lane alone
+    by_step = lambda name: {e["args"]["step"]: e for e in _spans(tracer, name)}  # noqa: E731
+    decodes, waits, samples, prepared = (by_step(n) for n in ("serve.decode", "serve.decode.wait", "serve.sample", "serve.step.prepare"))
+    assert sorted(decodes) == sorted(waits) == sorted(samples) == sorted(prepared) and len(decodes) == 4
+    for step, e in prepared.items():
+        assert _inside(e, waits[step]) and _inside(waits[step], decodes[step]) and e["dur"] > 0
+        assert samples[step]["ts"] >= decodes[step]["ts"] + decodes[step]["dur"] - 0.2
+
+
+def test_no_kind_of_step_compiles_or_lowers_a_program_after_the_kernels_are_built(lm_setup):
+    """Whatever kind of argument a step hands the decode program and the
+    sampler (the host's tokens or the sampler's ids, the device's table and
+    draws), it finds the programs the kernels' builder loaded: once the
+    kernels stand, a run with joins and retirements lowers and compiles
+    nothing at all, and traces neither program again."""
+    from jax._src import monitoring
+
+    from determined_tpu.lint._runtime import get_retrace_sentinel
+
+    cfg, _model, variables = lm_setup
+    sentinel = get_retrace_sentinel()
+    sentinel.reset()
+    kernels = DecodeKernels(cfg, variables, SERVE_CFG)
+    np.asarray(kernels.prefill([1, 2], np.zeros(SERVE_CFG.blocks_per_seq, np.int32)))  # a row of logits to the host, once
+    made = []  # a program lowered, or compiled (jax times its own look-up of a traced program under a third name)
+
+    def listener(event, seconds, **kw):
+        if event.endswith(("jaxpr_to_mlir_module_duration", "backend_compile_duration")):
+            made.append((event, kw))
+
+    monitoring.register_event_duration_secs_listener(listener)
+    try:
+        eng = ServeEngine(kernels)
+        _drive(eng, _mixed_traffic(seed=14, n=8))
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
+    inputs = eng.stats()["step_inputs"]
+    assert 0 < inputs["table_sent"] < inputs["decode_steps"] and 0 < inputs["tokens_from_device"] < inputs["decode_steps"]
+    assert made == []
+    assert {r.label: r.traces for r in sentinel.records()} == {"serve.decode_step": 1, "serve.prefill_step": 1}
+    sentinel.reset()
+
+
 def test_decode_span_says_what_the_step_had_to_read(kernels, tracer):
     """``serve.decode`` carries the live KV tokens (sum of pos + 1 over
     active lanes) and the longest context of its step: with the step's
