@@ -40,13 +40,13 @@ from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
     FULL, HYBRID, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
     _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, kv_bytes_per_token, kv_cache_shape, ssm_bytes_per_slot,
-    ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
+    recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
 )
 from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
 from determined_tpu.ops.paged_attention import (
     attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
 )
-from determined_tpu.ops.retention import retention_chunk, retention_decode
+from determined_tpu.ops.retention import FOLD_EVERY, retention_chunk, retention_decode
 from determined_tpu.ops.ssm import ssm_chunk, ssm_decode
 
 #: what a request holds of a kind.  BLOCKS: rows a token in blocks the
@@ -229,7 +229,7 @@ def _paged_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], 
     return (kv_cache_shape(cfg, sizes.num_blocks, sizes.block_size),) * (1 if cfg.latent else 2)
 
 
-def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0) -> Dict[str, Any]:
+def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``attn_products``: what a tile of the GQA decode kernel multiplies at this
     model's heads (``ops/paged_attention.py``); absent for latent layers, whose
     heads all share a row, and where no layer reads K and V."""
@@ -301,7 +301,7 @@ def _ring_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> ja
     ])
 
 
-def _ring_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
+def _ring_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``window_store`` (empty where no layer slides): the bytes the store takes
     whatever the contexts, and the tokens a lane's ring holds a layer."""
     if not cfg.window_layers:
@@ -453,8 +453,9 @@ def _state_mixer(cfg: TransformerConfig, rows: Rows, retain):
 def _state_chunk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     """``s`` tokens a row after what the slots of the rows' lanes hold (nothing,
     in the walk's first chunk: a sequence starts from a zeroed slot), and into
-    them: the prefill walk's chunk, and the wide prefill as one chunk."""
-    state_leaf, norm_leaf = STATE_SLOT.leaves
+    them: the prefill walk's chunk, and the wide prefill as one chunk.  A chunk
+    is folded whole: it leaves its lanes no recent row pending."""
+    state_leaf, norm_leaf, *_, pending_leaf = STATE_SLOT.leaves
     lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
     fresh = rows.chunk == rows.first_chunk
 
@@ -464,23 +465,26 @@ def _state_chunk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
         out, state, norm = retention_chunk(q, k, v, log_g, state, norm, rows.live)
         return out.astype(cfg.dtype), {
             **cache, state_leaf: cache[state_leaf].at[j, lanes].set(state), norm_leaf: cache[norm_leaf].at[j, lanes].set(norm),
+            pending_leaf: cache[pending_leaf].at[j, lanes].set(0),
         }
 
     return _state_mixer(cfg, rows, retain)
 
 
 def _state_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
-    """One token a lane, row ``b`` of the batch IS lane ``b``: the slot is
-    decayed, takes the token and answers it (``ops/retention.py
-    retention_decode``: the Pallas kernel on a TPU, in place); a lane that is
-    not live leaves its slot alone."""
-    state_leaf, norm_leaf = STATE_SLOT.leaves
+    """One token a lane, row ``b`` of the batch IS lane ``b``: the token joins
+    the lane's recent rows and is answered from them and from the slot as it
+    lies; a lane whose rows are whole folds them into its slot, the one write
+    of ``FOLD_EVERY`` tokens (``ops/retention.py retention_decode``: the Pallas
+    kernel on a TPU, in place); a lane that is not live leaves all alone."""
+    state_leaf, norm_leaf, *recent = STATE_SLOT.leaves
 
     def retain(q, k, v, log_g, cache, j):
-        out, state, norm = retention_decode(
-            q[:, :, 0], k[:, :, 0], v[:, :, 0], log_g[:, :, 0], cache[state_leaf], cache[norm_leaf], j, rows.live
+        out, state, norm, newest = retention_decode(
+            q[:, :, 0], k[:, :, 0], v[:, :, 0], log_g[:, :, 0], cache[state_leaf], cache[norm_leaf],
+            tuple(cache[leaf] for leaf in recent), j, rows.live,
         )
-        return out.astype(cfg.dtype)[:, :, None, :], {**cache, state_leaf: state, norm_leaf: norm}
+        return out.astype(cfg.dtype)[:, :, None, :], {**cache, state_leaf: state, norm_leaf: norm, **dict(zip(recent, newest))}
 
     return _state_mixer(cfg, rows, retain)
 
@@ -488,7 +492,7 @@ def _state_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
 def _state_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
     if sizes.max_batch is None:
         raise ValueError("a model with power-retention layers needs its lanes to size the state pool")
-    return state_pool_shapes(cfg, sizes.max_batch)
+    return state_pool_shapes(cfg, sizes.max_batch) + recent_rows_shapes(cfg, sizes.max_batch)
 
 
 def _state_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
@@ -497,22 +501,32 @@ def _state_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> j
     return jnp.stack([lanes, lanes * (len(cfg.retention_layers) * state_bytes_per_slot(cfg))])
 
 
-def _state_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
-    """``state``: the slots (one a lane), how many hold a sequence, and the bytes
-    one holds over the retention layers; ``block_ids_address_nothing``: no layer
-    reads the pool ``kv_cache`` counts, and admission is by free lane alone."""
+def _state_gauge(cfg: TransformerConfig, active: jax.Array, cache: Dict[str, jax.Array]) -> jax.Array:
+    """The recent rows the live lanes hold after this step, not yet folded into their slots (a layer's: all alike)."""
+    return jnp.sum(jnp.where(active, cache[STATE_SLOT.leaves[-1]][0], 0)).astype(jnp.float32)[None]
+
+
+def _state_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
+    """``state``: the slots (one a lane), how many hold a sequence, the bytes
+    one holds over the retention layers, after how many tokens a lane's recent
+    rows are folded into its slot and how many rows the live lanes held pending
+    after the newest decode step (between 0 and ``live x (fold_every - 1)``);
+    ``block_ids_address_nothing``: no layer reads the pool ``kv_cache``
+    counts, and admission is by free lane alone."""
     if not cfg.retention_layers:
         return {}
     per_slot = len(cfg.retention_layers) * state_bytes_per_slot(cfg)
+    pending = int((gauges or {}).get(STATE_SLOT.gauges[0], 0)) if live else 0  # the newest step's; no lane, no row
     return {
-        "state": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": per_slot},
+        "state": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": per_slot, "fold_every": FOLD_EVERY, "pending_rows": pending},
         "block_ids_address_nothing": not any(kind.holds == BLOCKS for kind in cache_kinds(cfg)),
     }
 
 
 def _state_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
     slots = _state_report(cfg, sizes)["state"]
-    return {"slots": slots["slots"], "bytes_per_slot": slots["bytes_per_slot"], "state_pool_bytes": _nbytes(STATE_SLOT, cfg, sizes)}
+    pool, recent = _nbytes(STATE_SLOT, cfg, sizes, slice(2)), _nbytes(STATE_SLOT, cfg, sizes, slice(2, None))
+    return {"slots": slots["slots"], "bytes_per_slot": slots["bytes_per_slot"], "state_pool_bytes": pool, "recent_rows_bytes": recent}
 
 
 # -- a Mamba-2 mixer's state and its convolution's tail a lane -------------------
@@ -599,7 +613,7 @@ def _ssm_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax
     return jnp.stack([lanes, lanes * (len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg))])
 
 
-def _ssm_report(cfg: TransformerConfig, sizes: Any, live: int = 0) -> Dict[str, Any]:
+def _ssm_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``ssm``: the slots (one a lane), how many hold a sequence, and the bytes
     of state one holds over the Mamba-2 layers."""
     if not cfg.ssm_layers:
@@ -644,10 +658,14 @@ class CacheKind:
     #: what a decode step counts for it, by name, and ``count(cfg, active [b], pos [b])`` -> float32, one each
     counters: Tuple[str, ...] = ()
     count: Optional[Callable] = None
-    #: ``report(cfg, sizes, live lanes)``: what it adds to ``/stats``, asked of EVERY kind of the table (one without
-    #: layers in the model says so itself: nothing, or an empty entry); ``setup(cfg, sizes)``: what a kind of the
-    #: model adds to the ``serve.setup.kv_pool`` span
-    report: Callable = lambda cfg, sizes, live: {}
+    #: what a decode step reads off the cache as it leaves it, by name, and ``gauge(cfg, active [b], cache)`` ->
+    #: float32, one each: the newest step's value for ``report``, never summed and no step counter
+    gauges: Tuple[str, ...] = ()
+    gauge: Optional[Callable] = None
+    #: ``report(cfg, sizes, live lanes, the newest step's gauges by name)``: what it adds to ``/stats``, asked of
+    #: EVERY kind of the table (one without layers in the model says so itself: nothing, or an empty entry);
+    #: ``setup(cfg, sizes)``: what a kind of the model adds to the ``serve.setup.kv_pool`` span
+    report: Callable = lambda cfg, sizes, live, gauges=None: {}
     setup: Callable = lambda cfg, sizes: {}
 
     def layers(self, cfg: TransformerConfig) -> Tuple[int, ...]:
@@ -657,8 +675,9 @@ class CacheKind:
         return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) in self.layer_types)
 
 
-def _nbytes(kind: CacheKind, cfg: TransformerConfig, sizes: Any) -> int:
-    return sum(math.prod(shape) * jnp.dtype(dt).itemsize for shape, dt in zip(kind.shapes(cfg, sizes), kind.dtypes(cfg)))
+def _nbytes(kind: CacheKind, cfg: TransformerConfig, sizes: Any, leaves: slice = slice(None)) -> int:
+    """The bytes of a kind's arrays, or of those of its ``leaves`` alone."""
+    return sum(math.prod(shape) * jnp.dtype(dt).itemsize for shape, dt in zip(kind.shapes(cfg, sizes)[leaves], kind.dtypes(cfg)[leaves]))
 
 
 def _compute_dtype(leaves: int):
@@ -694,8 +713,11 @@ PAGED_LATENT = CacheKind(
 )
 
 STATE_SLOT = CacheKind(
-    name="state_slot", layer_types=(RETENTION,), latent=False, leaves=("rs", "rz"), shapes=_state_shapes, holds=LANE,
-    dtypes=lambda cfg: (transformer.STATE_DTYPE,) * 2,  # read where it is stated: the benchmark's check sets another there
+    name="state_slot", layer_types=(RETENTION,), latent=False, shapes=_state_shapes, holds=LANE,
+    # the state and its normaliser; then the recent rows a lane: keys, values, the gate's running logarithm, rows pending
+    leaves=("rs", "rz", "rk", "rv", "rg", "rn"),
+    # the state read where it is stated: the benchmark's check sets another there; k and v as they reach the layer
+    dtypes=lambda cfg: (transformer.STATE_DTYPE,) * 2 + (cfg.dtype, cfg.dtype, transformer.STATE_DTYPE, jnp.int32),
     no_prefix_cache=(
         "prefix_cache shares a prompt's full blocks between requests, and a block holds no state: a "
         "power-retention layer keeps a request's whole context in its own lane's state slot, and a prefill "
@@ -705,6 +727,7 @@ STATE_SLOT = CacheKind(
     step=_state_step, walk=_every_chunk(_state_chunk), table=_state_step, wide=_state_chunk,
     # the lanes whose state the step updated, and the bytes of state those hold over the retention layers
     counters=("serve.state.live_lanes", "serve.state.bytes"), count=_state_count,
+    gauges=("serve.state.pending_rows",), gauge=_state_gauge,
     report=_state_report, setup=_state_setup,
 )
 

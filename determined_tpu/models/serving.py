@@ -114,6 +114,13 @@ def serve_counters(cfg: TransformerConfig) -> Tuple[str, ...]:
     return kinds + (SERVE_COUNTERS if cfg.moe_experts else ())
 
 
+def serve_gauges(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The names of what ``transformer_decode(counters=True)`` reads off the
+    cache it returns, in the row's order after ``serve_counters``: each cache
+    kind's gauges, in the table's order."""
+    return sum((kind.gauges for kind in cache_kinds(cfg)), ())
+
+
 def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
     points below: norm; the mixer of each of the layer's cache kinds, in the
@@ -228,7 +235,8 @@ def transformer_decode(
     ``counters`` (the engine's decode program, where the model has expert
     layers or a cache kind that counts): the logits come back ``[B + 1,
     vocab]``, and the last row's first entries are ``serve_counters(cfg)`` of
-    this step, so that they reach the host in the logits' own copy.  Idle lanes
+    this step and then ``serve_gauges(cfg)`` of the cache it leaves, so that
+    they reach the host in the logits' own copy.  Idle lanes
     take no expert's rows.
 
     Row ``b`` of the batch IS lane ``b`` for the kinds a request holds by its
@@ -265,6 +273,9 @@ def transformer_decode(
             if kind.count is not None:
                 own = kind.count(cfg, active, pos)
                 counted = own if counted is None else jnp.concatenate([own, counted])
+        gauged = [kind.gauge(cfg, active, cache) for kind in kinds if kind.gauge is not None]
+        if gauged:  # after every count: the newest step's values, which nobody sums
+            counted = jnp.concatenate(([] if counted is None else [counted]) + gauged)
         if counted is not None:  # the counters ride in the logits' own copy
             row = jnp.zeros((1, logits.shape[1]), jnp.float32).at[0, : counted.shape[0]].set(counted)
             logits = jnp.concatenate([logits, row], axis=0)

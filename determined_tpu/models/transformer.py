@@ -36,7 +36,7 @@ from flax import linen as nn
 
 from determined_tpu.data import DataLoader, SyntheticDataset
 from determined_tpu.ops.attention import dot_product_attention, reference_attention
-from determined_tpu.ops.retention import retention_quadratic, state_shapes
+from determined_tpu.ops.retention import recent_shapes, retention_quadratic, state_shapes
 from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
 from determined_tpu.ops.ring_attention import ring_attention
 from determined_tpu.parallel.mesh import MeshAxes
@@ -1138,6 +1138,12 @@ def state_pool_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ..
     """The retention layers' state pool and its normaliser (``ops/retention.py
     state_shapes``): a slot a decode lane a layer."""
     return state_shapes(len(cfg.retention_layers), lanes, cfg.kv_heads, cfg.head_dim)
+
+
+def recent_rows_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ...], ...]:
+    """The retention layers' recent rows a decode lane (``ops/retention.py
+    recent_shapes``): the tokens a decode step has not folded into the state yet."""
+    return recent_shapes(len(cfg.retention_layers), lanes, cfg.kv_heads, cfg.head_dim)
 
 
 def state_bytes_per_slot(cfg: TransformerConfig) -> int:

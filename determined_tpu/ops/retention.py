@@ -21,15 +21,29 @@ of 128, where the symmetric form has 8,256 and ``x (x) x`` 16,384.
 **The state pool** (``models/serving.py init_kv_cache``): ``rs [layers,
 lanes, kv_heads, rows * d, d]`` float32, feature row ``r`` and value ``v`` at
 row ``r * d + v``, the feature's column ``c`` along the lanes; ``rz [layers,
-lanes, kv_heads, rows, d]`` the normaliser.  A decode lane owns slot ``lane``.
+lanes, kv_heads, rows, d]`` the normaliser.  A decode lane owns slot ``lane``,
+and beside it its **recent rows** (:func:`recent_shapes`): the keys and values
+of the tokens a decode step has not folded into the slot yet, at most
+``FOLD_EVERY``, the running sum of the gate's logarithm at each, and their count.
 
-* :func:`retention_decode`: one token a lane.  The Pallas kernel's grid is
-  (lane, KV head): a program reads the head's ``[rows * d, d]`` state once,
-  decays it, adds the token's outer product a feature row at a time, answers
-  the KV head's query heads from the NEW rows (``phi(q) [heads, d] @ S_r^T``
-  on the MXU) and writes them back into the pool's own buffer
-  (``input_output_aliases``).  ``phi`` is built in the kernel from the
-  128-wide rows and never lies in HBM.  An idle lane's slot passes through.
+* :func:`retention_decode`: one token a lane.  The token joins the lane's
+  recent rows and is answered from both halves of the one sum: the state AS IT
+  LIES, decayed to the token in the answer (``e^{G_t} phi(q) . S``), plus the
+  quadratic form over the rows (``sum_j e^{G_t - G_j} (q . k_j)^2 v_j``, the
+  token's own among them): the same sums in another order.  The Pallas
+  kernel's grid is (lane, KV head): a program reads the head's ``[rows * d,
+  d]`` state once (``phi(q) [heads, d] @ S_r^T`` on the MXU, a feature row at a
+  time) and WRITES NOTHING of it, unless the lane's rows have reached
+  ``FOLD_EVERY``: then they all enter the state at once (the chunk kernel's
+  arithmetic at ``s = FOLD_EVERY``) and the slot is written into the pool's own
+  buffer (``input_output_aliases``), one write in ``FOLD_EVERY`` tokens where
+  the per-token form wrote every slot every token.  A lane folds by its OWN
+  count of tokens, so the lanes that are due vary from step to step: the
+  output block's index comes from a scalar-prefetched map (:func:`_held_blocks`)
+  that stays where it was on programs that are not due, and the pipeline
+  writes a block back only when the index moves.  ``phi`` is built in the
+  kernel from the 128-wide rows and never lies in HBM.  An idle lane's slot
+  and rows pass through.
 * :func:`retention_chunk`: a chunk of tokens (the prefill walk, and the wide
   prefill as one chunk): the chunk is answered from the state at its start,
   decayed, plus the quadratic form inside the chunk (``jax.numpy``), and
@@ -59,6 +73,12 @@ QUERY_PRECISION = _HIGHEST
 #: VMEM the decode kernel may take: a head's state at 128 is 4.26 MB, held
 #: twice coming in and twice going out
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+#: tokens a lane's recent rows hold before they are folded into its state: a
+#: decode step reads a slot every token and writes it once in as many.  Chosen
+#: on a v5e at Brumby's shape (32 lanes x 8 KV heads x 5 layers, ms a step):
+#: 8.19 at 16, 8.00 at 32, 8.20 at 64, 8.70 at 128 (the write falls as 1 / C,
+#: the rows a program copies and multiplies grow with C; PERF.md section 5)
+FOLD_EVERY = 32
 
 
 def _on_tpu() -> bool:
@@ -284,17 +304,32 @@ def _chunk_state_pallas(qf, kf, vf, left, kept, state, norm, *, interpret: bool)
 # ---------------------------------------------------------------------------
 
 
+def recent_shapes(layers: int, lanes: int, kv_heads: int, head_dim: int) -> Tuple[Tuple[int, ...], ...]:
+    """The shapes of a lane's recent rows: the keys and the values of the tokens
+    not yet folded into its state, the running sum of the gate's logarithm at
+    each since the last fold (held as the state is), and how many rows are pending (int32)."""
+    rows = (layers, lanes, kv_heads, FOLD_EVERY, head_dim)
+    return rows, rows, rows[:-1], (layers, lanes)
+
+
 def retention_decode(
-    q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Array, state: jax.Array, norm: jax.Array, layer,
-    live: jax.Array, *, impl: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Array, state: jax.Array, norm: jax.Array,
+    recent: Tuple[jax.Array, jax.Array, jax.Array, jax.Array], layer, live: jax.Array, *, impl: Optional[str] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array, Tuple[jax.Array, jax.Array, jax.Array, jax.Array]]:
     """One decode step of one layer over the state pool, in place.
 
     ``q`` [lanes, heads, d], ``k`` / ``v`` [lanes, kv_heads, d] (q and k after
     their norm and rotary), ``log_g`` [lanes, kv_heads] float32, ``state`` /
-    ``norm`` the whole pools (:func:`state_shapes`), ``layer`` the layer to
-    update, ``live`` [lanes] bool: an idle lane's slot is left as it is and
-    its output is zeros.  Returns (o [lanes, heads, d] float32, state, norm).
+    ``norm`` the whole pools (:func:`state_shapes`), ``recent`` the lanes' whole
+    recent rows (:func:`recent_shapes`), ``layer`` the layer to update,
+    ``live`` [lanes] bool: an idle lane's slot and rows are left as they are
+    and its output is zeros.  Returns (o [lanes, heads, d] float32, state,
+    norm, recent).
+
+    The token joins its lane's recent rows and is answered from the state AS
+    IT LIES, decayed to the token, plus the quadratic form over the rows; the
+    slot of a lane whose rows have reached ``FOLD_EVERY`` takes them all and
+    is written, the others are not.
 
     ``impl``: ``"kernel"``, ``"kernel_interpret"`` (tests), ``"jnp"`` or None:
     the kernel on a TPU when :func:`kernel_takes` the shapes.
@@ -304,121 +339,210 @@ def retention_decode(
         impl = "kernel" if _on_tpu() and kernel_takes(d, state.dtype) else "jnp"
     if impl != "jnp" and not kernel_takes(d, state.dtype):
         raise ValueError(f"the retention kernel needs head_dim 128 and a 2- or 4-byte state (got {d}, {state.dtype})")
-    return _retention_decode(q, k, v, log_g, state, norm, jnp.asarray(layer, jnp.int32), live, impl=impl)
+    return _retention_decode(q, k, v, log_g, state, norm, tuple(recent), jnp.asarray(layer, jnp.int32), live, impl=impl)
 
 
 # the layer is an ARGUMENT of one jitted function: a model's layers share one
 # lowering of the kernel (as ``ops/paged_attention.py _paged_attention``)
 @functools.partial(jax.jit, static_argnames=("impl",))
-def _retention_decode(q, k, v, log_g, state, norm, layer, live, *, impl):
-    if impl == "jnp":
-        return _retention_decode_jnp(q, k, v, log_g, state, norm, layer, live)
-    return _retention_decode_pallas(q, k, v, log_g, state, norm, layer, live, interpret=impl == "kernel_interpret")
+def _retention_decode(q, k, v, log_g, state, norm, recent, layer, live, *, impl):
+    recent, rows = _append(k, v, log_g, recent, layer, live)
+    against = _retention_decode_jnp if impl == "jnp" else functools.partial(_retention_decode_pallas, interpret=impl == "kernel_interpret")
+    out, state, norm = against(q, *recent[:2], *rows, state, norm, layer, live)
+    return out, state, norm, recent
 
 
-def _retention_decode_jnp(q, k, v, log_g, state, norm, layer, live):
+def _append(k, v, log_g, recent, layer, live):
+    """The token into its lane's recent rows, where they lie.  Returns the rows
+    and what a step reads beside them: each row's decay up to this token
+    ``left`` [b, g, C] (0: no such row), the state's ``kept`` [b, g], and the
+    lanes whose rows are now whole, ``due`` [b]: those the step folds into
+    their slots, and whose count starts again."""
+    keys, vals, sums, pending = recent
+    b, every = k.shape[0], keys.shape[3]
+    lanes = jnp.arange(b)
+    had = pending[layer]  # [b] rows before this token: 0 .. C - 1
+    at = jnp.where(live, had, every)  # an idle lane's row is dropped
+    before = jnp.take_along_axis(sums[layer], jnp.maximum(had - 1, 0)[:, None, None], axis=2)[..., 0].astype(jnp.float32)
+    total = jnp.where(had[:, None] > 0, before, 0.0) + log_g.astype(jnp.float32)  # [b, g] the gate's logarithm since the last fold
+    total = total.astype(sums.dtype)  # as the rows keep it: a running sum is state, held as the state is
+    # a select over the layer's rows, not a scatter of one: XLA keeps a scatter's operand with the scattered
+    # dimension outermost, and would copy the rows to that order and back around every step
+    here = (jnp.arange(every)[None, :] == at[:, None])[:, None, :, None]  # [b, 1, C, 1]; an idle lane: nowhere
+    keys, vals = (
+        jax.lax.dynamic_update_index_in_dim(rows, jnp.where(here, new[:, :, None, :].astype(rows.dtype), rows[layer]), layer, 0)
+        for rows, new in ((keys, k), (vals, v))
+    )
+    sums = sums.at[layer, lanes, :, at].set(total, mode="drop")
+    total = total.astype(jnp.float32)
+    due = live & (had + 1 == every)
+    pending = pending.at[layer].set(jnp.where(live, jnp.where(due, 0, had + 1), had))
+    held = jnp.arange(every)[None, None, :] <= had[:, None, None]
+    left = jnp.where(held, jnp.exp(jnp.where(held, total[..., None] - sums[layer].astype(jnp.float32), 0.0)), 0.0)
+    return (keys, vals, sums, pending), (left, jnp.exp(total), due)
+
+
+def _retention_decode_jnp(q, keys, vals, left, kept, due, state, norm, layer, live):
     b, h, d = q.shape
-    g, rows = k.shape[1], phi_rows(d)
+    g = keys.shape[2]
     f32 = jnp.float32
     s0 = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
     z0 = jax.lax.dynamic_index_in_dim(norm, layer, 0, keepdims=False)
-    pk = phi(k.astype(f32))  # [b, g, rows, d]
-    pq = phi(q.astype(f32).reshape(b, g, h // g, d))  # [b, g, n, rows, d]
-    decay = jnp.exp(log_g.astype(f32))
-    s1 = decay[..., None, None, None] * s0.astype(f32).reshape(b, g, rows, d, d)
-    s1 = s1 + v.astype(f32)[:, :, None, :, None] * pk[:, :, :, None, :]
-    z1 = decay[..., None, None] * z0.astype(f32) + pk
-    num = jnp.einsum("bgnrc,bgrvc->bgnv", pq, s1, precision=_HIGHEST)
-    den = jnp.einsum("bgnrc,bgrc->bgn", pq, z1, precision=_HIGHEST)
-    out = jnp.where(live[:, None, None], (num / den[..., None]).reshape(b, h, d), 0.0)
-    keep = live[:, None, None, None]
-    s1 = jnp.where(keep, s1.reshape(s0.shape).astype(state.dtype), s0)
-    z1 = jnp.where(keep, z1.astype(norm.dtype), z0)
+    qf, kf, vf = q.astype(f32).reshape(b, g, h // g, d), keys[layer].astype(f32), vals[layer].astype(f32)
+    # the tokens the state does not hold yet: the quadratic form; the others: the state as it lies, decayed; and the
+    # state a fold leaves: every row into it, each decayed from its place to this token (a chunk of C keys, one query)
+    weights = left[:, :, None, :] * jnp.square(jnp.einsum("bgnd,bgjd->bgnj", qf, kf, precision=_HIGHEST))
+    num0, den0, s1, z1 = _chunk_state_jnp(qf[:, :, :, None, :], kf, vf, left, kept, s0, z0)
+    num = jnp.einsum("bgnj,bgjd->bgnd", weights, vf, precision=_HIGHEST) + kept[..., None, None] * num0[:, :, :, 0]
+    den = jnp.sum(weights, axis=-1) + kept[..., None] * den0[:, :, :, 0]
+    out = jnp.where(live[:, None, None], (num / jnp.where(den == 0.0, 1.0, den)[..., None]).reshape(b, h, d), 0.0)
+    fold = due[:, None, None, None]  # the lanes whose rows are whole; the others' slots stay bit for bit
     return (
         out,
-        jax.lax.dynamic_update_index_in_dim(state, s1, layer, 0),
-        jax.lax.dynamic_update_index_in_dim(norm, z1, layer, 0),
+        jax.lax.dynamic_update_index_in_dim(state, jnp.where(fold, s1, s0), layer, 0),
+        jax.lax.dynamic_update_index_in_dim(norm, jnp.where(fold, z1, z0), layer, 0),
     )
 
 
-def _retention_kernel(layer_ref, live_ref, qk_ref, v_ref, decay_ref, s_ref, z_ref, o_ref, s_out, z_out, *, n_rep, d):
-    """One (lane, KV head): ``qk_ref`` [rows8, d] float32 holds the KV head's
-    ``n_rep`` queries and then its key; ``v_ref`` [d, 1] the value as a column;
-    ``s_ref`` [rows * d, d] and ``z_ref`` [rows, d] the head's state."""
-    alive = live_ref[pl.program_id(0)] > 0
+def _retention_kernel(
+    layer_ref, live_ref, due_ref, read_lane, read_head, write_lane, write_head, q_ref, k_ref, v_ref, row_ref, col_ref, kept_ref,
+    s_ref, z_ref, o_ref, s_out, z_out, *, d,
+):
+    """One (lane, KV head): ``q_ref`` [rows8, d] float32 the KV head's queries,
+    ``k_ref`` / ``v_ref`` [C, d] the lane's recent keys and values with this
+    token's, ``row_ref`` [1, C] / ``col_ref`` [C, 1] each row's
+    decay up to this token (0: no such row), ``kept_ref`` [1, 1] the state's;
+    ``s_ref`` [rows * d, d] the head's state and ``z_ref`` [rows, kv_heads, d]
+    the LANE's normalisers (this head's at ``[:, head]``) where the lane is live (else the block the pipeline already holds: nothing is
+    fetched for an idle lane), READ; ``s_out`` / ``z_out`` the block the
+    pipeline last wrote or will write next (:func:`_held_blocks`): this
+    program's own only where the lane is due, and written only then."""
+    lane, head = pl.program_id(0), pl.ds(pl.program_id(1), 1)
     f32 = jnp.float32
+    alive, due = live_ref[lane] > 0, due_ref[lane] > 0
+    features = [(r, _weight(r, d), pl.ds(r * d, d)) for r in range(phi_rows(d))]
+
+    def rolled(x, r):  # column c holds x[:, (c + r) % d]
+        return x if r == 0 else pltpu.roll(x, d - r, 1)
 
     @pl.when(alive)
-    def _update():
-        qk = qk_ref[...]
-        value = v_ref[...]
-        decay = decay_ref[...]  # [1, 1]
-        num = jnp.zeros(qk.shape, f32)
-        den = jnp.zeros((qk.shape[0], 1), f32)
-        for r in range(phi_rows(d)):
-            rolled = qk if r == 0 else pltpu.roll(qk, d - r, 1)  # column c holds qk[:, (c + r) % d]
-            ph = qk * rolled * _weight(r, d)  # phi's row r of the queries and of the key
-            pk = ph[n_rep:n_rep + 1, :]
-            rows = pl.ds(r * d, d)
-            s_new = decay * s_ref[rows, :].astype(f32) + value * pk  # [d (value), d (feature column)]
-            s_out[rows, :] = s_new.astype(s_out.dtype)
-            z_new = decay * z_ref[r:r + 1, :].astype(f32) + pk
-            z_out[r:r + 1, :] = z_new.astype(z_out.dtype)
-            num = num + jax.lax.dot_general(
-                ph, s_new, (((1,), (1,)), ((), ())), precision=QUERY_PRECISION, preferred_element_type=f32
+    def _answer():
+        q, key, kept = q_ref[...], k_ref[...].astype(f32), kept_ref[...]
+        weights = row_ref[...] * jnp.square(jax.lax.dot_general(q, key, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32))
+        num = jax.lax.dot_general(weights, v_ref[...].astype(f32), (((1,), (0,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
+        den = jnp.sum(weights, axis=1, keepdims=True)
+        num0, den0 = jnp.zeros(q.shape, f32), jnp.zeros((q.shape[0], 1), f32)
+        for r, w, rows in features:
+            pq = q * rolled(q, r) * w  # phi's row r of the queries
+            # ONE float32 product: sound at these 8 left rows where ``_query_state``'s shapes are not (PERF.md section 7)
+            num0 = num0 + jax.lax.dot_general(
+                pq, s_ref[rows, :].astype(f32), (((1,), (1,)), ((), ())), precision=QUERY_PRECISION, preferred_element_type=f32
             )
-            den = den + jnp.sum(ph * z_new, axis=1, keepdims=True)
+            den0 = den0 + jnp.sum(pq * z_ref[r, head, :].astype(f32), axis=1, keepdims=True)
+        num, den = num + kept * num0, den + kept * den0
         o_ref[...] = num / jnp.where(den == 0.0, 1.0, den)  # rows past n_rep are not read
 
     @pl.when(jnp.logical_not(alive))
-    def _pass():
-        s_out[...] = s_ref[...]
-        z_out[...] = z_ref[...]
+    def _idle():
         o_ref[...] = jnp.zeros(o_ref.shape, f32)
 
+    @pl.when(due)
+    def _fold():
+        key, vt, kept = k_ref[...].astype(f32), v_ref[...].astype(f32).T, kept_ref[...]  # the values as columns [d, C]
+        weighed = key * col_ref[...]
+        for r, w, rows in features:
+            pk = weighed * rolled(key, r) * w  # [C, d]
+            entered = jax.lax.dot_general(vt, pk, (((1,), (0,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
+            s_out[rows, :] = (kept * s_ref[rows, :].astype(f32) + entered).astype(s_out.dtype)  # [d (value), d (feature column)]
+            z_out[r, head, :] = (kept * z_ref[r, head, :].astype(f32) + jnp.sum(pk, axis=0, keepdims=True)).astype(z_out.dtype)
 
-def _retention_decode_pallas(q, k, v, log_g, state, norm, layer, live, *, interpret: bool):
+    # no lane is due: every program's block is the one the first program read, written once as it was
+    @pl.when(jnp.logical_and(write_lane[0] < 0, jnp.logical_and(lane == 0, pl.program_id(1) == 0)))
+    def _through():
+        s_out[...] = s_ref[...]
+        z_out[...] = z_ref[...]
+
+
+def _held_blocks(mine, heads: int):
+    """The block of a pool that the pipeline holds at each program of the grid
+    (lane, KV head) when only the lanes ``mine`` [b] ask for their own: a lane
+    that does not names the block of the program BEFORE it (the last head of
+    the last lane that did), or the first head of the first lane that will where
+    none came before.  Pallas copies a block when the index MOVES between one
+    program and the next: so nothing is fetched for a lane that is not live,
+    no slot is written that is not due, and each due slot once.  Returns (lane
+    [b] int32, -1 throughout where no lane asks; head [b] int32)."""
+    b = mine.shape[0]
+    idx = jnp.arange(b, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(mine, idx, -1))
+    first = jnp.min(jnp.where(mine, idx, b))
+    lane = jnp.where(last >= 0, last, jnp.where(first < b, first, -1))
+    return lane.astype(jnp.int32), jnp.where(last >= 0, heads - 1, 0).astype(jnp.int32)
+
+
+def _retention_decode_pallas(q, keys, vals, left, kept, due, state, norm, layer, live, *, interpret: bool):
     b, h, d = q.shape
-    g, rows = k.shape[1], phi_rows(d)
+    g, rows, every = keys.shape[2], phi_rows(d), keys.shape[3]
     n_rep = h // g
-    rows8 = -(-(n_rep + 1) // 8) * 8  # whole sublane tiles: the queries, the key, zeros
+    rows8 = -(-n_rep // 8) * 8  # whole sublane tiles: the queries, zeros
     f32 = jnp.float32
-    qk = jnp.concatenate([q.astype(f32).reshape(b, g, n_rep, d), k.astype(f32)[:, :, None, :]], axis=2)
-    qk = jnp.pad(qk, ((0, 0), (0, 0), (0, rows8 - n_rep - 1), (0, 0)))
+    qf = jnp.pad(q.astype(f32).reshape(b, g, n_rep, d), ((0, 0), (0, 0), (0, rows8 - n_rep), (0, 0)))
     at_head = lambda bi, gi, *_: (bi, gi, 0, 0)  # noqa: E731
-    at_slot = lambda bi, gi, lay, _: (lay[0], bi, gi, 0, 0)  # noqa: E731
-    out, state, norm = pl.pallas_call(
-        functools.partial(_retention_kernel, n_rep=n_rep, d=d),
+    at_rows = lambda bi, gi, lay, *_: (lay[0], bi, gi, 0, 0)  # noqa: E731
+
+    def held(bi, gi, mine, lane, head):
+        return jnp.where(mine[bi] > 0, bi, jnp.maximum(lane[bi], 0)), jnp.where(mine[bi] > 0, gi, head[bi])
+
+    def read(bi, gi, lay, live, due, read_lane, read_head, *_):
+        return lay[0], *held(bi, gi, live, read_lane, read_head), 0, 0
+
+    def written(bi, gi, lay, live, due, read_lane, read_head, write_lane, write_head):
+        # no lane is due: the block the first program read (its own, or the first live lane's: head 0 either way)
+        first = jnp.where(live[0] > 0, 0, jnp.maximum(read_lane[0], 0))
+        lane, head = held(bi, gi, due, write_lane, write_head)
+        return lay[0], jnp.where(write_lane[0] < 0, first, lane), head, 0, 0
+
+    # the normaliser a LANE at a time, [rows, kv_heads, d]: the order the device keeps that pool in (65 rows are no
+    # whole sublane tiles, so its heads lie inside its rows), where the transposes are free and XLA copies nothing
+    lane_of = lambda at: lambda *a: (*at(*a)[:2], 0, 0, 0)  # noqa: E731
+
+    by_lane = norm.transpose(0, 1, 3, 2, 4)
+    out, state, by_lane = pl.pallas_call(
+        functools.partial(_retention_kernel, d=d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=7,
             grid=(b, g),
             in_specs=[
                 pl.BlockSpec((None, None, rows8, d), at_head),
-                pl.BlockSpec((None, None, d, 1), at_head),
+                pl.BlockSpec((None, None, None, every, d), at_rows),
+                pl.BlockSpec((None, None, None, every, d), at_rows),
+                pl.BlockSpec((None, None, 1, every), at_head),
+                pl.BlockSpec((None, None, every, 1), at_head),
                 pl.BlockSpec((None, None, 1, 1), at_head),
-                pl.BlockSpec((None, None, None, rows * d, d), at_slot),
-                pl.BlockSpec((None, None, None, rows, d), at_slot),
+                pl.BlockSpec((None, None, None, rows * d, d), read),
+                pl.BlockSpec((None, None, rows, g, d), lane_of(read)),
             ],
             out_specs=[
                 pl.BlockSpec((None, None, rows8, d), at_head),
-                pl.BlockSpec((None, None, None, rows * d, d), at_slot),
-                pl.BlockSpec((None, None, None, rows, d), at_slot),
+                pl.BlockSpec((None, None, None, rows * d, d), written),
+                pl.BlockSpec((None, None, rows, g, d), lane_of(written)),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, g, rows8, d), f32),
             jax.ShapeDtypeStruct(state.shape, state.dtype),
-            jax.ShapeDtypeStruct(norm.shape, norm.dtype),
+            jax.ShapeDtypeStruct(by_lane.shape, norm.dtype),
         ],
-        # the pools are updated where they lie (inputs count the two scalar-prefetch arguments)
-        input_output_aliases={5: 1, 6: 2},
+        # the pools are updated where they lie (inputs count the scalar-prefetch arguments)
+        input_output_aliases={13: 1, 14: 2},
+        # in the grid's order: what ``_held_blocks`` says of a block holds between one program and the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="retention_decode",
     )(
-        layer.reshape(1), live.astype(jnp.int32),
-        qk, v.astype(f32)[..., None], jnp.exp(log_g.astype(f32))[..., None, None], state, norm,
+        layer.reshape(1), live.astype(jnp.int32), due.astype(jnp.int32), *_held_blocks(live, g), *_held_blocks(due, g),
+        qf, keys, vals, left[:, :, None, :], left[..., None], kept[..., None, None], state, by_lane,
     )
-    return out[:, :, :n_rep, :].reshape(b, h, d), state, norm
+    return out[:, :, :n_rep, :].reshape(b, h, d), state, by_lane.transpose(0, 1, 3, 2, 4)

@@ -243,6 +243,7 @@ class DecodeKernels:
             _check_decodable,
             init_kv_cache,
             serve_counters,
+            serve_gauges,
             transformer_decode,
             transformer_prefill_chunked,
         )
@@ -316,6 +317,9 @@ class DecodeKernels:
         #: counts of the step (``transformer_decode``), in this order; the
         #: engine's sampler hands them back beside the tokens
         self.counters: Tuple[str, ...] = serve_counters(model_cfg)
+        #: after them in that row, what the step read off the cache it left (a
+        #: kind's gauges): the newest step's values, for ``/stats`` alone
+        self.gauges: Tuple[str, ...] = serve_gauges(model_cfg)
         #: the prefill's token width: the longest prompt in whole chunks
         #: (one trace; the walk's trip count follows each prompt)
         self._prompt_pad = serve_cfg.prefill_chunks(serve_cfg.max_prompt_len) * serve_cfg.prefill_chunk
@@ -335,7 +339,7 @@ class DecodeKernels:
                 transformer_decode,
                 model_cfg,
                 chunk_blocks=serve_cfg.decode_chunk_blocks,
-                counters=bool(self.counters),
+                counters=bool(self.counters + self.gauges),
             ),
             allowed=1,
         )
@@ -367,7 +371,7 @@ class DecodeKernels:
             self.params, np.zeros(lanes, np.int32), np.full(lanes, -1, np.int32),
             np.zeros((lanes, serve_cfg.blocks_per_seq), np.int32), self.cache,
         )
-        sample = timed_first_call(lane_sampler(len(self.counters)), "jit.compile.serve.sample")
+        sample = timed_first_call(lane_sampler(len(self.counters + self.gauges)), "jit.compile.serve.sample")
         jax.block_until_ready(sample(logits, jax.device_put(np.zeros((2, lanes), np.float32))))
 
     # -- kernel entry points (device round trips happen HERE) ---------------
@@ -518,6 +522,9 @@ class ServeEngine:
         #: the counts the kernels' decode program returns in the row after
         #: the lanes' logits, by name and in its order (a stand-in has none)
         self._counters: Tuple[str, ...] = tuple(getattr(kernels, "counters", ()))
+        #: what follows them: the kinds' gauges, whose newest values ``stats()`` hands the kinds' reports
+        self._gauges: Tuple[str, ...] = tuple(getattr(kernels, "gauges", ()))
+        self._step_gauges: Dict[str, float] = {}
         #: requests that finished with an error (prefill crash, engine
         #: stop/crash, drain abandonment) — the error-rate numerator the
         #: master's canary bake compares against its pre-roll baseline
@@ -785,6 +792,7 @@ class ServeEngine:
                 "steps": self._steps,
             }
             step_counters = dict(self._step_counters)
+            gauges = dict(self._step_gauges)
             step_inputs = dict(self._step_inputs)
         if recent is not None:
             latency = {
@@ -831,7 +839,7 @@ class ServeEngine:
             # ``state`` with ``block_ids_address_nothing``, ``attn_products``:
             # ``models/cache_kinds.py``; one the model has no layer of says so
             # itself); ``kv_cache`` counts the allocator's blocks alone
-            **{k: v for kind in CACHE_KINDS for k, v in kind.report(self.kernels.model_cfg, self.cfg, live).items()},
+            **{k: v for kind in CACHE_KINDS for k, v in kind.report(self.kernels.model_cfg, self.cfg, live, gauges).items()},
             # live-block fraction, shared (ref>1) blocks counted ONCE so
             # prefix sharing never inflates the router's load signal
             "kv_utilization": round(kv["used"] / max(1, kv["capacity"]), 4),
@@ -1055,16 +1063,18 @@ class ServeEngine:
         work, inside ``serve.decode.wait`` where the kernels ran it."""
         logits, positions, draws, sent, (t_call, t_back), prepare = self._decode_batch(lanes)
         t0 = mono()
-        ids, counted = lane_sampler(len(self._counters))(logits, draws)
+        names = self._counters + self._gauges
+        ids, counted = lane_sampler(len(names))(logits, draws)
         # queued behind the program at once: waiting for the ids first and
         # only then asking for them costs a host round trip
         ids.copy_to_host_async()
-        if self._counters:
+        if names:
             counted.copy_to_host_async()
         ids.block_until_ready()
         t_ready = mono()
         tokens = np.asarray(ids).tolist()
-        counts = dict(zip(self._counters, np.asarray(counted).tolist())) if self._counters else {}
+        counts = dict(zip(names, np.asarray(counted).tolist())) if names else {}
+        gauges = {name: counts.pop(name) for name in self._gauges}
         t1 = t_host = mono()
         # the kernels stamp the parts of their own call (whatever wraps
         # ``kernels.decode`` from outside); a stand-in that leaves no stamps,
@@ -1105,6 +1115,7 @@ class ServeEngine:
             seconds["sample"] += t1 - t0
             for name, value in counts.items():
                 self._step_counters[name] = self._step_counters.get(name, 0.0) + value
+            self._step_gauges = gauges
             self._step_inputs["decode_steps"] += 1
             for name, value in sent.items():
                 self._step_inputs[name] += value

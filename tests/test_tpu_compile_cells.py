@@ -281,25 +281,34 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
 
 def test_the_retention_decode_kernel_compiles_at_the_brumby_cells_shape(tpu_devices):
     """32 lanes x 40 query heads over 8 KV heads of 128 against a float32 state
-    pool of five layers (8,320 x 128 a head): one kernel, the pools updated
-    where they lie (aliased, no scratch the size of a layer's state)."""
+    pool of five layers (8,320 x 128 a head) and the lanes' recent rows: ONE
+    kernel (the conditional write is its own: a block index held where it was,
+    no second call for the fold), the pools and the rows updated where they lie
+    (aliased, no scratch the size of a layer's state)."""
     retention_mod = importlib.import_module("determined_tpu.ops.retention")
     one = SingleDeviceSharding(tpu_devices[0])
     aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     state, norm = retention_mod.state_shapes(5, 32, 8, 128)
     assert state == (5, 32, 8, 8320, 128) and norm == (5, 32, 8, 65, 128)
+    rows = retention_mod.recent_shapes(5, 32, 8, 128)
+    every = retention_mod.FOLD_EVERY
+    assert rows == ((5, 32, 8, every, 128),) * 2 + ((5, 32, 8, every), (5, 32))
+    row_dtypes = (jnp.bfloat16, jnp.bfloat16, jnp.float32, jnp.int32)
 
-    def fn(q, k, v, log_g, rs, rz, live):
-        return retention_mod.retention_decode(q, k, v, log_g, rs, rz, 3, live)
+    def fn(q, k, v, log_g, rs, rz, recent, live):
+        return retention_mod.retention_decode(q, k, v, log_g, rs, rz, recent, 3, live, impl="kernel")
 
-    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(
+    compiled = jax.jit(fn, donate_argnums=(4, 5, 6)).lower(
         aval((32, 40, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16), aval((32, 8, 128), jnp.bfloat16),
-        aval((32, 8), jnp.float32), aval(state, jnp.float32), aval(norm, jnp.float32), aval((32,), jnp.bool_),
+        aval((32, 8), jnp.float32), aval(state, jnp.float32), aval(norm, jnp.float32),
+        tuple(aval(shape, dt) for shape, dt in zip(rows, row_dtypes)), aval((32,), jnp.bool_),
     ).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert _kernels(text) == 1 and "retention_decode" in text
     pool_bytes = 4 * (math.prod(state) + math.prod(norm))
-    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 16 * 1024**2
+    row_bytes = sum(math.prod(shape) * jnp.dtype(dt).itemsize for shape, dt in zip(rows, row_dtypes))
+    assert row_bytes == 5 * 32 * (2 * 8 * every * 128 * 2 + 8 * every * 4 + 4) < pool_bytes // 200       # 21 MB beside 5.5 GB
+    assert mem.alias_size_in_bytes >= pool_bytes + row_bytes and mem.temp_size_in_bytes < 16 * 1024**2
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
@@ -312,7 +321,8 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
     from flax.core import meta as flax_meta
 
     from determined_tpu.models.serving import transformer_decode, transformer_prefill_chunked
-    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, state_pool_shapes
+    from determined_tpu.models.cache_kinds import STATE_SLOT
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, recent_rows_shapes, state_pool_shapes
     from determined_tpu.utils.compilation_cache import program_scopes
 
     one = SingleDeviceSharding(tpu_devices[0])
@@ -325,7 +335,9 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
     params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
     aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     state, norm = state_pool_shapes(cfg, 32)
-    cache = {"rs": aval(state, jnp.float32), "rz": aval(norm, jnp.float32)}
+    shapes = (state, norm) + recent_rows_shapes(cfg, 32)
+    cache = {leaf: aval(shape, dt) for leaf, shape, dt in zip(STATE_SLOT.leaves, shapes, STATE_SLOT.dtypes(cfg))}
+    assert tuple(cache) == ("rs", "rz", "rk", "rv", "rg", "rn") and cache["rk"].dtype == jnp.bfloat16 and cache["rs"].dtype == jnp.float32
     if which == "decode":
         fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
         args = (params, aval((32,)), aval((32,)), aval((32, 1792)), cache)
@@ -344,7 +356,9 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
     assert "serve.attn.qkv" not in scopes
     if which == "decode":
         assert "serve.kv.write" not in scopes
+        # a layer's ONE kernel answers, and folds the lanes that are due: no second call, no branch of the program
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_decode")}) == 2
+        assert "conditional(" not in text
         assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
     else:                                                                            # the chunk's pass over the state, a layer
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
