@@ -376,6 +376,12 @@ class RoutedExperts(nn.Module):
     ``intermediates/load [count]`` (picks that landed on each held expert) and
     ``intermediates/live_rows`` (rows of the buffer in tiles a group owns: what
     the kernels touch, the held picks and each group's padding to a tile).
+
+    Every router but ``"mlp"`` renormalises its picks' weights to a constant
+    sum: at ``top_k`` 1 the one weight is that constant, no gradient reaches
+    the router through the experts, and the layer refuses the pair by name.
+    Under ``"mlp"`` the call takes the layer before's router state and returns
+    ``(y, aux, state)``: :func:`route_mlp`.
     """
 
     num_experts: int
@@ -388,9 +394,12 @@ class RoutedExperts(nn.Module):
     # "sigmoid_grouped": :func:`route_sigmoid_grouped` over ``router`` and the
     # selection bias ``router_bias`` in place of the softmax (no auxiliary
     # loss: the bias is what balances such a router); "sigmoid": the plain
-    # form, :func:`route_sigmoid`, with no bias; ``shared_experts`` of
+    # form, :func:`route_sigmoid`, with no bias; "mlp": :func:`route_mlp`
+    # over ``router_hidden`` values a token, top-1; ``shared_experts`` of
     # width ``d_ff`` each (``shared_w_*``) take every token beside the picks
     router_kind: str = "softmax"
+    router_hidden: int = 0
+    norm_eps: float = 1e-6        # of the "mlp" router's RMSNorm over its state
     n_group: int = 1
     topk_group: int = 1
     routed_scaling: float = 1.0
@@ -400,12 +409,22 @@ class RoutedExperts(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """[batch, seq, d] -> ([batch, seq, d], aux_loss)."""
+    def __call__(self, x: jax.Array, state: Any = None) -> Any:
+        """[batch, seq, d] -> ([batch, seq, d], aux_loss); under the "mlp"
+        router, with the layer before's router ``state`` [batch, seq,
+        router_hidden] (None: there is none), -> (y, aux_loss, this layer's)."""
         from determined_tpu.models.transformer import _maybe_partition
 
         b, s, d = x.shape
         tokens, e, k = b * s, self.num_experts, self.top_k
+        mlp = self.router_kind == "mlp"
+        if k == 1 and not mlp:
+            raise ValueError(
+                f"router_kind={self.router_kind!r} with top_k=1 renormalises the one pick's weight to a "
+                "constant, so the router gets no gradient from the experts: a top-1 layer takes router_kind='mlp'"
+            )
+        if mlp and (k != 1 or self.router_hidden < 1 or self.shared_experts):
+            raise ValueError("router_kind='mlp' is top-1 over router_hidden >= 1 values a token, with no shared expert")
         if self.expert_axis_name is not None:
             n = jax.lax.axis_size(self.expert_axis_name)
             if self.held is not None or e % n:
@@ -422,13 +441,19 @@ class RoutedExperts(nn.Module):
             init = _maybe_partition(self.partition, nn.initializers.lecun_normal(), logical)
             return self.param(name, init, shape, self.param_dtype)
 
-        router = param("router", (d, e), ("embed", None))
+        if mlp:
+            p = {
+                name: self.param(name, _maybe_partition(self.partition, init, logical), shape, self.param_dtype)
+                for name, (shape, logical, init) in _mlp_router_shapes(d, self.router_hidden, e).items()
+            }
+        else:
+            router = param("router", (d, e), ("embed", None))
+            p = {"router": router}
         w_gate = param("w_gate", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_up = param("w_up", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_down = param("w_down", (count, self.d_ff, d), ("expert", "mlp", "embed"))
 
-        p = {"router": router}
-        if self.router_kind == "sigmoid_grouped":
+        if mlp or self.router_kind == "sigmoid_grouped":
             p["router_bias"] = self.param("router_bias", nn.initializers.normal(0.01), (e,), jnp.float32)
         if self.shared_experts:
             wide = self.shared_experts * self.d_ff
@@ -438,7 +463,16 @@ class RoutedExperts(nn.Module):
 
         xf = x.reshape(tokens, d)
         with jax.named_scope("moe.route"):
-            if self.router_kind != "softmax":
+            if mlp:
+                probs, state = route_mlp(
+                    p, xf.astype(self.dtype), None if state is None else state.reshape(tokens, -1), self.norm_eps
+                )
+                picks = jnp.argmax(probs + p["router_bias"][None, :], axis=-1)[:, None]   # the bias picks and never weighs
+                weights = jnp.take_along_axis(probs, picks, axis=1)                        # the pick's probability, as it is
+                aux = _switch_aux(probs, picks, e)
+                state = state.reshape(b, s, -1)
+                self.sow("intermediates", "pick_weight", jnp.mean(weights))
+            elif self.router_kind != "softmax":
                 weights, picks = _route(
                     p, xf, kind=self.router_kind, top_k=k, n_group=self.n_group,
                     topk_group=self.topk_group, scaling=self.routed_scaling,
@@ -449,10 +483,7 @@ class RoutedExperts(nn.Module):
                 probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)   # [T, E]
                 top_p, picks = jax.lax.top_k(probs, k)                              # [T, k]
                 weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-                share = jnp.sum(
-                    picks.reshape(-1, 1) == jnp.arange(e)[None, :], axis=0, dtype=jnp.float32
-                ) / (tokens * k)
-                aux = e * jnp.sum(share * jnp.mean(probs, axis=0))
+                aux = _switch_aux(probs, picks, e)
         self.sow("intermediates", "picks", picks)
 
         with jax.named_scope("moe.dispatch"):
@@ -470,7 +501,52 @@ class RoutedExperts(nn.Module):
         if self.shared_experts:
             with jax.named_scope("moe.shared"):
                 y = y + _shared_experts(p, xf.astype(self.dtype), self.shared_experts, self.shared_combine).astype(y.dtype)
-        return y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)
+        y, aux = y.astype(x.dtype).reshape(b, s, d), aux.astype(jnp.float32)
+        return (y, aux, state) if mlp else (y, aux)
+
+
+def _switch_aux(probs: jax.Array, picks: jax.Array, experts: int) -> jax.Array:
+    """Switch's load-balancing term (eq. 4) over all experts and all picks:
+    ``experts * sum_e (share of the picks on e) * (mean probability of e)``."""
+    share = jnp.sum(picks.reshape(-1, 1) == jnp.arange(experts)[None, :], axis=0, dtype=jnp.float32) / picks.size
+    return experts * jnp.sum(share * jnp.mean(probs, axis=0))
+
+
+def _mlp_router_shapes(d: int, hidden: int, experts: int) -> Any:
+    """The "mlp" router's leaves: name -> (shape, logical axes, initialiser)."""
+    kernel, zeros, ones = nn.initializers.lecun_normal(), nn.initializers.zeros, nn.initializers.ones
+    return {
+        "router_down": ((d, hidden), ("embed", None), kernel),
+        "router_down_bias": ((hidden,), (None,), zeros),
+        "router_mix": ((hidden,), (None,), ones),
+        "router_norm": ((hidden,), (None,), ones),
+        "router_w1": ((hidden, hidden), (None, None), kernel),
+        "router_b1": ((hidden,), (None,), zeros),
+        "router_w2": ((hidden, hidden), (None, None), kernel),
+        "router_b2": ((hidden,), (None,), zeros),
+        "router_w3": ((hidden, experts), (None, None), kernel),
+    }
+
+
+def route_mlp(p: Any, xf: jax.Array, before: Any, eps: float) -> Tuple[jax.Array, jax.Array]:
+    """ZAYA1's router on ``xf [T, d]``: a state ``r = xf W_down + b`` of
+    ``router_hidden`` values a token, to which the layer before's state
+    ``before [T, hidden]`` (None: there is none) is added times ``router_mix``;
+    the probabilities are ``softmax(W3 gelu(W2 gelu(W1 rmsnorm(r) + b1) + b2))``
+    over all experts (the norm with ``eps``, the exact gelu).  Returns
+    (probabilities ``[T, E]``, the state after its mix: what the next layer is
+    handed), both float32.  The down-projection runs in ``xf``'s dtype with a
+    float32 sum; everything after it in float32 at the highest matmul
+    precision: a router that rounds its scores ties and misroutes tokens."""
+    f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
+    r = jnp.dot(xf, p["router_down"].astype(xf.dtype), preferred_element_type=jnp.float32) + f32("router_down_bias")
+    if before is not None:
+        r = r + f32("router_mix") * before.astype(jnp.float32)
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    h = r * jax.lax.rsqrt(jnp.mean(jnp.square(r), axis=-1, keepdims=True) + eps) * f32("router_norm")
+    h = jax.nn.gelu(dot(h, f32("router_w1")) + f32("router_b1"), approximate=False)
+    h = jax.nn.gelu(dot(h, f32("router_w2")) + f32("router_b2"), approximate=False)
+    return jax.nn.softmax(dot(h, f32("router_w3")), axis=-1), r
 
 
 def route_sigmoid_grouped(

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from determined_tpu.models.cache_kinds import Rows, cache_kinds, layer_kinds, pool_block_size
-from determined_tpu.models.transformer import TransformerConfig, _layer_norm, _rms_apply, _times
+from determined_tpu.models.transformer import CCA, TransformerConfig, _layer_norm, _rms_apply, _times
 
 
 def init_kv_cache(
@@ -77,6 +77,16 @@ def _check_decodable(cfg: TransformerConfig) -> None:
         )
     if cfg.seq_axis_name is not None or cfg.expert_axis_name is not None:
         raise ValueError("KV-cache serving runs outside pipeline stages")
+    unserved = [
+        what for what, there in (
+            ("a cca layer (its cache is K and V a token AND a lane's tail: the convolutions' newest latent rows and "
+             "the newest normed input, a kind models/cache_kinds.py does not have)", CCA in (cfg.layer_types or ())),
+            ("moe_router mlp (its state from layer to layer)", cfg.moe_router == "mlp"),
+            ("residual_scaling", cfg.residual_scaling),
+        ) if there
+    ]
+    if unserved:
+        raise ValueError("KV-cache serving does not run " + "; ".join(unserved) + ": such a model is trained, not served yet")
 
 
 def _embed_rows(params: Dict[str, Any], tokens: jax.Array, dtype: Any) -> jax.Array:

@@ -14,9 +14,13 @@ MeshConfig alone, with:
   retention (``ops/retention.py``: gated attention of degree 2, whose serving
   cache is a fixed-size state a lane and not keys and values a token), or
   attention heads beside Mamba-2 heads under one norm (``ops/ssm.py``:
-  Falcon-H1's block, whose serving cache is K and V a token AND a state a lane);
+  Falcon-H1's block, whose serving cache is K and V a token AND a state a lane),
+  or attention inside a compressed, convolved latent (``CompressedAttention``:
+  ZAYA1's CCA; trained, not served yet);
 - experts: the top-2 capacity layer or dropless top-k over the experts a
-  device holds (models/moe.py);
+  device holds (models/moe.py); under the "mlp" router a layer hands the next
+  its router's state, a second value beside the residual stream;
+- ``residual_scaling``: a learned scale and bias on both terms of each merge;
 - attention dispatch: ring attention when the mesh has a "seq" axis,
   Pallas flash attention on TPU otherwise, reference for tiny seqs;
 - bf16 compute with f32 params, per-block remat for long-context memory.
@@ -48,7 +52,10 @@ from determined_tpu.train._trial import JaxTrial
 FULL, SLIDING, RETENTION = "full_attention", "sliding_attention", "power_retention"
 #: full attention and a Mamba-2 mixer side by side: ``x + a(u) + s(u)`` for ONE norm ``u`` (Falcon-H1's block)
 HYBRID = "attention_mamba2"
-LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID)
+#: compressed convolutional attention: full causal attention over q and k that two causal
+#: convolutions mixed over time inside their latent (``CompressedAttention``)
+CCA = "cca"
+LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA)
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
@@ -106,7 +113,12 @@ class TransformerConfig:
     # the moe_topk_group best of moe_n_group groups, top-k inside them,
     # weights normalised and times moe_routed_scaling), and how many shared
     # experts of width moe_intermediate_size every token also passes through
+    # "mlp" (ZAYA1's; models/moe.py ``route_mlp``): top-1 by an MLP over
+    # router_hidden_size values a token to which the layer before's are added
+    # (a state every expert block hands the next), the pick's weight its
+    # probability as it is, a selection bias that picks and never weighs
     moe_router: str = "softmax"
+    router_hidden_size: Optional[int] = None
     moe_n_group: int = 1
     moe_topk_group: int = 1
     moe_routed_scaling: float = 1.0
@@ -122,9 +134,13 @@ class TransformerConfig:
     # read it, ``x + Attn(LN(x)) + FFN(LN(x))``.  tie_embeddings: no lm_head,
     # the logits are the final norm times the embedding's transpose, times
     # logit_scale.
+    # residual_scaling: both merges of a sequential block are ``(x + br) * ar
+    # + (f + bf) * af`` with four learned vectors of d_model each (scales one,
+    # biases zero at the start) in place of ``x + f``.
     norm: str = "rms"
     norm_eps: float = 1e-6
     parallel_block: bool = False
+    residual_scaling: bool = False
     tie_embeddings: bool = False
     logit_scale: float = 1.0
     # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
@@ -140,6 +156,13 @@ class TransformerConfig:
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     softmax_scale: Optional[float] = None
+    # A cca layer (``CompressedAttention``): q and k pass a depthwise causal
+    # convolution over cca_time0 tokens and one over cca_time1 tokens that
+    # mixes each head's channels; rotary turns the first partial_rotary_factor
+    # of every head (of every layer type: 1 turns the whole head)
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 1.0
     # An attention_mamba2 layer's Mamba-2 mixer (``Mamba2`` below): ssm_heads
     # heads of ssm_head_dim over ssm_groups groups that share B and C of
     # ssm_state values, a causal depthwise convolution over ssm_conv tokens, and
@@ -224,6 +247,22 @@ class TransformerConfig:
                 "an attention_mamba2 layer needs ssm_heads (whole groups of them), ssm_head_dim, ssm_state and "
                 "ssm_conv >= 2, in a sequential block, without latent attention or a `seq` axis"
             )
+        if CCA in (self.layer_types or ()) and (
+            self.latent or self.parallel_block or self.seq_axis_name is not None or self.n_heads % self.kv_heads
+            or self.kv_heads % 2 or min(self.cca_time0, self.cca_time1) < 1
+        ):
+            raise ValueError(
+                "a cca layer needs whole groups of query heads a KV head, an even count of KV heads (half take the "
+                "token before's value) and cca_time0, cca_time1 >= 1, in a sequential block, without latent "
+                "attention or a `seq` axis"
+            )
+        rotary = self.head_dim * self.partial_rotary_factor
+        if not 0 < self.partial_rotary_factor <= 1 or rotary != int(rotary) or int(rotary) % 2:
+            raise ValueError(f"partial_rotary_factor={self.partial_rotary_factor} must leave an even count of head_dim={self.head_dim} to turn")
+        if self.partial_rotary_factor != 1 and set(self.layer_types or (FULL,)) != {CCA}:
+            raise ValueError("partial_rotary_factor < 1 runs in cca layers only: every layer must be one")
+        if self.residual_scaling and (self.parallel_block or self.expert_axis_name is not None):
+            raise ValueError("residual_scaling runs in a sequential block, outside pipeline stages")
         if self.qk_norm and len(self.retention_layers) != self.n_layers:
             raise ValueError("qk_norm runs in power_retention layers only: every layer must be one")
         if isinstance(self.rope_parameters, Mapping):
@@ -250,8 +289,15 @@ class TransformerConfig:
                     )
         elif self.moe_experts_held is not None or self.moe_intermediate_size is not None:
             raise ValueError("moe_experts_held and moe_intermediate_size belong to moe_top_k > 0")
-        if self.moe_router not in ("softmax", "sigmoid", "sigmoid_grouped"):
-            raise ValueError(f"moe_router is softmax, sigmoid or sigmoid_grouped (got {self.moe_router!r})")
+        if self.moe_router not in ("softmax", "sigmoid", "sigmoid_grouped", "mlp"):
+            raise ValueError(f"moe_router is softmax, sigmoid, sigmoid_grouped or mlp (got {self.moe_router!r})")
+        if (self.moe_router == "mlp") != (self.router_hidden_size is not None) or (
+            self.moe_router == "mlp" and self.expert_axis_name is not None
+        ):
+            raise ValueError(
+                "moe_router mlp routes over router_hidden_size values a token (which belongs to it), outside "
+                "pipeline stages: a stage would have to hand the router's state on"
+            )
         if (self.moe_router != "softmax" or self.moe_shared_experts) and not self.moe_top_k:
             raise ValueError("moe_router and moe_shared_experts belong to moe_top_k > 0")
         if self.moe_shared_combine not in ("sum", "mean") or self.norm not in ("rms", "layernorm"):
@@ -556,6 +602,127 @@ class Attention(nn.Module):
             )(out)
 
 
+def _cca_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
+    """Compressed attention's leaves beside its five projections: name ->
+    (shape, logical axes, initialiser).  The convolutions as a ``Conv1d`` is
+    drawn (uniform within fan-in ** -0.5: a channel's ``cca_time0`` taps, a
+    head's ``cca_time1 x head_dim``), the keys' temperature ``tau`` zero."""
+    hq, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    b0, b1 = cfg.cca_time0 ** -0.5, (cfg.cca_time1 * hd) ** -0.5
+    return {
+        "conv0_w": ((cfg.cca_time0, hq + hk, hd), (None, None, "head_dim"), _uniform(-b0, b0)),
+        "conv0_b": ((hq + hk, hd), (None, "head_dim"), _uniform(-b0, b0)),
+        "conv1_w": ((cfg.cca_time1, hq + hk, hd, hd), (None, None, None, "head_dim"), _uniform(-b1, b1)),
+        "conv1_b": ((hq + hk, hd), (None, "head_dim"), _uniform(-b1, b1)),
+        "tau": ((hk,), (None,), nn.initializers.zeros),
+    }
+
+
+def _causal_taps(x: jax.Array, taps: int) -> Tuple[jax.Array, ...]:
+    """``x`` [b, s, ...] as a causal convolution's taps see it: tap ``i`` of
+    token ``t`` is ``x[t - (taps - 1) + i]``, zeros before the sequence's start."""
+    rows = jnp.pad(x, ((0, 0), (taps - 1, 0)) + ((0, 0),) * (x.ndim - 2))
+    return tuple(rows[:, i: i + x.shape[1]] for i in range(taps))
+
+
+def _cca_mix(cfg: TransformerConfig, p: Dict[str, Any], q: jax.Array, k: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The latent's mixing over time and heads, on the projections ``q`` [b, s,
+    heads, hd] and ``k`` [b, s, kv, hd]: the heads side by side pass a depthwise
+    causal convolution and then one that mixes each head's channels; to the
+    result is added the q-k mean (a query head with its KV head, halved; for a
+    key, the mean of that over its group's query heads)."""
+    b, s, hq, hd = q.shape
+    hk, dt = k.shape[2], q.dtype
+    mean_q = (q + jnp.repeat(k, hq // hk, axis=2)) * 0.5
+    mean_k = jnp.mean(mean_q.reshape(b, s, hk, hq // hk, hd), axis=3)
+    z = jnp.concatenate([q, k], axis=2)
+    w0, w1 = p["conv0_w"].astype(dt), p["conv1_w"].astype(dt)
+    z = sum(w0[i] * rows for i, rows in enumerate(_causal_taps(z, cfg.cca_time0))) + p["conv0_b"].astype(dt)
+    z = sum(jnp.einsum("bshc,hcd->bshd", rows, w1[i]) for i, rows in enumerate(_causal_taps(z, cfg.cca_time1)))
+    z = z + p["conv1_b"].astype(dt)
+    return z[:, :, :hq] + mean_q, z[:, :, hq:] + mean_k
+
+
+def _l2_heads(x: jax.Array, scale: jax.Array) -> jax.Array:
+    """Each head of ``x`` [..., heads, hd] at Euclidean length ``scale`` (a
+    scalar, or one a head): the sum of squares in float32, the result in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    unit = x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True))
+    return (unit * jnp.asarray(scale, jnp.float32)[..., None]).astype(x.dtype)
+
+
+class CompressedAttention(nn.Module):
+    """Compressed convolutional attention (CCA; Zyphra, arXiv:2510.04476) over
+    the whole sequence: q and k are projected into a latent of ``n_heads`` and
+    ``kv_heads`` heads, mixed there over time (``_cca_mix``), brought to length
+    ``sqrt(head_dim)`` a head (a key's times ``exp(tau)``, learned a KV head)
+    and turned by rotary on the first ``partial_rotary_factor`` of each head;
+    the first half of the KV heads take their value from the token, the rest
+    from the token before (the value shift); full causal attention runs inside
+    the latent and ``wo`` leads out of it.  Training only: a served layer's
+    cache would keep, beside K and V a token, the convolutions' tail and the
+    newest normed input a lane (``models/serving.py`` refuses it)."""
+
+    cfg: TransformerConfig
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u: jax.Array) -> jax.Array:
+        from determined_tpu.train._quant import make_dot_general
+
+        cfg = self.cfg
+        hq, hk, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        p = {
+            name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
+            for name, (shape, logical, init) in _cca_param_shapes(cfg).items()
+        }
+        dense = lambda feats, logical, name, axis=-1: nn.DenseGeneral(  # noqa: E731
+            feats, axis=axis, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            dot_general=make_dot_general(cfg.quantized_matmul),
+            kernel_init=_maybe_partition(cfg.partition_params, nn.initializers.lecun_normal(), logical), name=name,
+        )
+        with jax.named_scope("attn.qkv"):
+            q = dense((hq, hd), ("embed", "heads", "head_dim"), "wq")(u)
+            k = dense((hk, hd), ("embed", "kv", "head_dim"), "wk")(u)
+        with jax.named_scope("attn.cca.mix"):
+            q, k = _cca_mix(cfg, p, q, k)
+            before = _causal_taps(u, 2)[0]  # the value shift: token t reads token t - 1's normed input
+        with jax.named_scope("attn.qkv"):
+            v = jnp.concatenate([
+                dense((hk - hk // 2, hd), ("embed", "kv", "head_dim"), "wv1")(u),
+                dense((hk // 2, hd), ("embed", "kv", "head_dim"), "wv2")(before),
+            ], axis=2)
+        with jax.named_scope("attn.cca.norm"):
+            q = _l2_heads(q, math.sqrt(hd))
+            k = _l2_heads(k, math.sqrt(hd) * jnp.exp(p["tau"].astype(jnp.float32)))
+        with jax.named_scope("attn.qkv"):
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [b, h, s, d]
+            positions, rope, turned = jnp.arange(u.shape[1]), cfg.rope(CCA), int(hd * cfg.partial_rotary_factor)
+            q, k = (jnp.concatenate([_rope(t[..., :turned], positions, rope), t[..., turned:]], axis=-1) for t in (q, k))
+        with jax.named_scope("attn.full"):
+            out = dot_product_attention(q, k, v, causal=True, impl=cfg.attention_impl, mesh=self.mesh)
+        with jax.named_scope("attn.out"):
+            return dense(cfg.d_model, ("heads", "head_dim", "embed"), "wo", (-2, -1))(out.transpose(0, 2, 1, 3))
+
+
+class ResidualScale(nn.Module):
+    """``(x + res_bias) * res_scale + (f + out_bias) * out_scale``: a block's
+    merge of the stream ``x`` with a sublayer's output ``f`` under
+    ``residual_scaling``, four learned vectors (scales one, biases zero at the start)."""
+
+    partition: bool = True
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, f: jax.Array) -> jax.Array:
+        def vec(name: str, init: Any) -> jax.Array:
+            return self.param(name, _maybe_partition(self.partition, init, ("embed",)), (x.shape[-1],), self.param_dtype).astype(x.dtype)
+
+        ones, zeros = nn.initializers.ones, nn.initializers.zeros
+        with jax.named_scope("block.rescale"):
+            return (x + vec("res_bias", zeros)) * vec("res_scale", ones) + (f + vec("out_bias", zeros)) * vec("out_scale", ones)
+
+
 def _gate_log(cfg: TransformerConfig, gate: jax.Array) -> jax.Array:
     """The logarithm of a retention layer's decay, float32, from the gate's
     projection ``[..., kv_heads]``."""
@@ -775,18 +942,30 @@ class Block(nn.Module):
     layer_type: str = FULL
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array, state: Any = None) -> Tuple[jax.Array, jax.Array, Any]:
+        """``x`` [b, s, d] -> (``x``, the auxiliary loss, what the block hands
+        the next beside the stream: under the "mlp" router its router's state
+        [b, s, router_hidden_size], from the last expert block's ``state``;
+        None under every other router)."""
         cfg = self.cfg
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name=name
         )
 
-        def ffn(h: jax.Array) -> Tuple[jax.Array, jax.Array]:
-            """The block's MLP or experts on the normed input, and the auxiliary loss."""
+        def merge(name: str, x: jax.Array, f: jax.Array) -> jax.Array:
+            if not cfg.residual_scaling:
+                return x + f
+            return ResidualScale(cfg.partition_params, cfg.param_dtype, name=name)(x, f)
+
+        def ffn(h: jax.Array) -> Tuple[jax.Array, jax.Array, Any]:
+            """The block's MLP or experts on the normed input, the auxiliary
+            loss, and what the block hands on (the "mlp" router's state; else
+            what it was handed)."""
             if self.use_moe and cfg.moe_top_k:
                 from determined_tpu.models.moe import RoutedExperts
 
-                return RoutedExperts(
+                mlp = cfg.moe_router == "mlp"
+                out = RoutedExperts(
                     num_experts=cfg.moe_experts,
                     top_k=cfg.moe_top_k,
                     d_ff=cfg.moe_intermediate_size or cfg.ff_dim,
@@ -801,12 +980,15 @@ class Block(nn.Module):
                     shared_experts=cfg.moe_shared_experts,
                     shared_combine=cfg.moe_shared_combine,
                     param_dtype=cfg.param_dtype,
+                    router_hidden=cfg.router_hidden_size or 0,
+                    norm_eps=cfg.norm_eps,
                     name="moe",
-                )(h)
+                )(h, *((state,) if mlp else ()))
+                return out if mlp else (*out, state)
             if self.use_moe:
                 from determined_tpu.models.moe import MoE
 
-                return MoE(
+                return *MoE(
                     num_experts=cfg.moe_experts,
                     d_ff=cfg.ff_dim,
                     capacity_factor=cfg.moe_capacity_factor,
@@ -814,14 +996,16 @@ class Block(nn.Module):
                     partition=cfg.partition_params,
                     expert_axis_name=cfg.expert_axis_name,
                     name="moe",
-                )(h)
-            return MLP(cfg, self.mesh, name="mlp")(h), jnp.zeros((), jnp.float32)
+                )(h), state
+            return MLP(cfg, self.mesh, name="mlp")(h), jnp.zeros((), jnp.float32), state
 
         h = norm("ln1")(x)
         if cfg.latent:
             att = LatentAttention(cfg, name="attn")(h)
         elif self.layer_type == RETENTION:
             att = Retention(cfg, name="attn")(h)
+        elif self.layer_type == CCA:
+            att = CompressedAttention(cfg, self.mesh, name="attn")(h)
         elif self.layer_type == HYBRID:
             # attention heads and Mamba-2 heads read the one norm side by side
             att = Attention(cfg, self.mesh, self.layer_type, name="attn")(_times(h, cfg.attention_in_multiplier))
@@ -830,15 +1014,15 @@ class Block(nn.Module):
             att = Attention(cfg, self.mesh, self.layer_type, name="attn")(h)
         if cfg.parallel_block:
             # one norm: the MLP or the experts read what attention read
-            y, aux = ffn(h)
+            y, aux, handed = ffn(h)
             x = x + att + y
         else:
-            x = x + att
-            y, aux = ffn(norm("ln2")(x))
-            x = x + y
+            x = merge("rescale1", x, att)
+            y, aux, handed = ffn(norm("ln2")(x))
+            x = merge("rescale2", x, y)
         if cfg.partition_params:
             x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
-        return x, aux
+        return x, aux, handed
 
 
 class TransformerLM(nn.Module):
@@ -873,8 +1057,9 @@ class TransformerLM(nn.Module):
         if cfg.remat:
             block_cls = nn.remat(Block, prevent_cse=False)
         aux_total = jnp.zeros((), jnp.float32)
+        state = None  # what a layer hands the next beside the stream (the "mlp" router's state)
         for i in range(cfg.n_layers):
-            x, aux = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), name=f"block_{i}")(x)
+            x, aux, state = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), name=f"block_{i}")(x, state)
             aux_total = aux_total + aux
         x = RMSNorm(
             eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name="ln_f"
@@ -1047,7 +1232,7 @@ def pipeline_forward(
         blk = Block(stage_cfg, use_moe=use_moe, layer_type=layer_type)
 
         def block_step(p, h):
-            return blk.apply({"params": p}, h)
+            return blk.apply({"params": p}, h)[:2]  # no block of a stage hands a state on (LMTrial._cfg)
 
         if cfg.remat:
             block_step = jax.checkpoint(block_step, prevent_cse=False)
@@ -1240,7 +1425,9 @@ class LMTrial(JaxTrial):
     moe_every, and either moe_capacity_factor (top-2, capacity) or moe_top_k
     (dropless) with moe_intermediate_size and moe_experts_held = [first,
     count]; moe_aux_weight; norm (rms / layernorm) with norm_eps,
-    parallel_block, tie_embeddings with logit_scale, moe_shared_combine.
+    parallel_block, tie_embeddings with logit_scale, moe_shared_combine;
+    layer type cca with cca_time0, cca_time1 and partial_rotary_factor;
+    moe_router mlp with router_hidden_size; residual_scaling.
 
     When the context mesh has a ``pipe`` axis of size P > 1, the trial
     restructures its params into stacked pipeline stages and trains through
@@ -1336,6 +1523,17 @@ class LMTrial(JaxTrial):
                     f"pipe={pipe} needs the period of layer_types to divide "
                     f"layers-per-chunk ({lps}): layer j of every chunk is one stacked leaf"
                 )
+        carried = [
+            what for what, there in (
+                ("a cca layer", CCA in (layer_types or ())), ("moe_router mlp", g("moe_router", "softmax") == "mlp"),
+                ("residual_scaling", bool(g("residual_scaling", False))),
+            ) if there
+        ]
+        if pipe > 1 and carried:
+            raise ValueError(
+                f"pipe={pipe}: {', '.join(carried)} not run inside pipeline stages: the stage function hands on the "
+                "residual stream alone, not the router's state beside it"
+            )
         mesh = self.context.mesh
         if int(g("moe_top_k", 0)) and pipe <= 1 and mesh is not None and mesh.size > 1:
             raise ValueError(
@@ -1370,6 +1568,11 @@ class LMTrial(JaxTrial):
             rope_parameters=g("rope_parameters", None),
             dense_prefix=int(g("dense_prefix", 0)),
             moe_router=str(g("moe_router", "softmax")),
+            router_hidden_size=g("router_hidden_size", None),
+            residual_scaling=bool(g("residual_scaling", False)),
+            cca_time0=int(g("cca_time0", 2)),
+            cca_time1=int(g("cca_time1", 2)),
+            partial_rotary_factor=float(g("partial_rotary_factor", 1.0)),
             moe_n_group=int(g("moe_n_group", 1)),
             moe_topk_group=int(g("moe_topk_group", 1)),
             moe_routed_scaling=float(g("moe_routed_scaling", 1.0)),
@@ -1388,7 +1591,7 @@ class LMTrial(JaxTrial):
     #: (train/_trainer.py): what the dropless expert layers saw
     step_counters = (
         "moe.held_picks", "moe.picks", "moe.live_rows", "moe.expert_load_max", "moe.expert_load_mean",
-        "moe_aux_loss",
+        "moe.pick_weight", "moe_aux_loss",
     )
 
     @staticmethod
@@ -1397,7 +1600,9 @@ class LMTrial(JaxTrial):
         step's expert load from what the layers ``sow`` (no second forward):
         picks that landed on a held expert, all picks, the rows of the buffer
         the kernels touch (held picks and each group's padding to a tile), and
-        the fullest held expert against the mean, over the layers."""
+        the fullest held expert against the mean, over the layers; under the
+        "mlp" router also the mean weight of a token's one pick (its
+        probability: the router's gradient dies where it goes to 1 / experts or 1)."""
         if not model.cfg.moe_top_k:
             return model.apply(params, inputs, **kw), {}
         out, state = model.apply(params, inputs, mutable=["intermediates"], **kw)
@@ -1407,7 +1612,9 @@ class LMTrial(JaxTrial):
             for name in ("load", "live_rows")
         )
         picks = load.shape[0] * inputs.size * model.cfg.moe_top_k
+        weight = [x for path, x in sown if "pick_weight" in jax.tree_util.keystr(path)]
         return out, {
+            **({"moe.pick_weight": jnp.mean(jnp.stack(weight)).astype(jnp.float32)} if weight else {}),
             "moe.held_picks": jnp.sum(load),
             "moe.picks": jnp.asarray(picks, jnp.float32),
             "moe.live_rows": jnp.sum(live_rows),
@@ -1439,12 +1646,18 @@ class LMTrial(JaxTrial):
             )
             width = cfg.n_heads * (qk + cfg.v_head_dim) // 2
         n_params, seen = cfg.vocab_size * d, 0
+        router = d * cfg.moe_experts
+        if cfg.moe_router == "mlp":
+            r = cfg.router_hidden_size
+            router = d * r + 2 * r * r + r * cfg.moe_experts
         for i in range(cfg.n_layers):
             n_params += attn
+            if cfg.layer_type(i) == CCA:  # the two convolutions' products
+                n_params += (cfg.n_heads + cfg.kv_heads) * cfg.head_dim * (cfg.cca_time0 + cfg.cca_time1 * cfg.head_dim)
             if cfg.use_moe(i):
                 held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
                 active = cfg.moe_top_k * held / cfg.moe_experts if cfg.moe_top_k else 2
-                n_params += d * cfg.moe_experts + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)
+                n_params += router + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)
             else:
                 n_params += 3 * d * cfg.ff_dim
             seen += min(cfg.window(cfg.layer_type(i)) or cfg.max_seq_len, cfg.max_seq_len)

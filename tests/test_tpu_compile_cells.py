@@ -502,3 +502,69 @@ def test_the_steps_sampler_compiles_at_the_cells_logits_and_sweeps_them_a_few_ti
     assert logits < _hbm_bytes(text) <= 4 * logits
     assert compiled.cost_analysis()["bytes accessed"] <= 16 * logits
     assert compiled.memory_analysis().temp_size_in_bytes == 0 and _kernels(text) == 0
+
+
+# -- a training cell's whole step: the loss, its gradient, the clip and fused AdamW ------
+
+
+def _train_step_compiled(one, cell_name: str, batch=None):
+    """A training cell's step as ``Trainer``'s ``train_step`` puts it together
+    (``LMTrial.loss`` under the cell's hparams, its gradient, the optimizer's
+    ``apply_step``, the state donated), compiled for one described chip at the
+    configuration's widths and its ``train_batch`` (or ``batch`` sequences):
+    shapes only, nothing is built."""
+    from flax.core import meta as flax_meta
+
+    from tests.benchmark import bench_testlib  # noqa: F401  (puts the harness on sys.path)
+    from benchlib import model, spec, train_run
+    from determined_tpu.models.transformer import LMTrial
+
+    cell = spec.Spec().cell(cell_name)
+    arch = model.adapter(cell)
+    arch.check_as_run(cell.config)
+    hparams = train_run._hparams(cell.config, cell.traffic, arch)
+
+    class Context:
+        mesh = exp_config = None
+        batch_axis_size = 1
+
+        def get_hparam(self, name, default=None):
+            return hparams.get(name, default)
+
+        def get_global_batch_size(self):
+            return hparams["global_batch_size"]
+
+    trial = LMTrial.__new__(LMTrial)
+    trial.context = Context()
+    lm, tx = trial.build_model(), trial.build_optimizer()
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = flax_meta.unbox(jax.eval_shape(lambda: lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
+    opt_state = jax.eval_shape(tx.init, params)
+
+    def step(params, opt_state, tokens):
+        (loss, metrics), grads = jax.value_and_grad(
+            lambda p: trial.loss(lm, p, {"tokens": tokens}, jax.random.key(0)), has_aux=True
+        )(params)
+        with jax.named_scope("optim.update"):
+            params, opt_state = tx.apply_step(grads, opt_state, params)
+        return params, opt_state, loss, metrics
+
+    tokens = jax.ShapeDtypeStruct((batch or hparams["global_batch_size"], hparams["seq_len"] + 1), jnp.int32, sharding=one)
+    return jax.jit(step, donate_argnums=(0, 1)).lower(jax.tree.map(on_chip, params), jax.tree.map(on_chip, opt_state), tokens).compile()
+
+
+def test_the_zaya_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_devices):
+    """ZAYA1-8B's cell: five CCA layers at 8 over 2 heads of 128 and 8,192
+    keys, the MLP router, top-1 into 8 held experts of 2048 x 2048, a tied head
+    of 32,784 rows (16 x 2,049: no multiple of 128) through fused CE, fused
+    AdamW over 601,744,730 parameters, at the configuration's batch: the
+    flash kernels forward and backward a layer, the grouped products, the
+    sweeps; state and scratch inside the chip's 15.75 GiB."""
+    compiled = _train_step_compiled(SingleDeviceSharding(tpu_devices[0]), "train-zaya1-8b-l5-ep2-seq8k")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    state = mem.argument_size_in_bytes
+    assert 12 * 601_744_730 <= state < 12 * 601_744_730 + (1 << 20)         # parameters and both moments (the gradient is scratch)
+    assert state + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes < 15.75 * 2**30
+    # a layer: flash forward + its two backward kernels, 3 + 6 grouped products and the rows' movements; the sweeps on top
+    assert _kernels(text) >= 5 * (3 + 9)
+    print("args", state, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes, "kernels", _kernels(text))
