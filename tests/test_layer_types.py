@@ -5,6 +5,8 @@ counters of the expert layers' load, and layer types under pipeline stages.
 Cut from tests/test_routed_experts.py, which keeps the expert layer itself.
 CPU, small sizes, seeded weights; Pallas kernels in interpret mode."""
 
+import importlib
+import itertools
 import math
 
 import jax
@@ -18,6 +20,8 @@ from determined_tpu.models.transformer import FULL, SLIDING, TransformerConfig, 
 from determined_tpu.ops import grouped_matmul as gm
 from determined_tpu.ops.attention import dot_product_attention, reference_attention
 from determined_tpu.ops.flash_attention import flash_attention
+
+flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")  # ``determined_tpu.ops.flash_attention`` is the function
 
 YARN = {
     "rope_type": "yarn", "rope_theta": 500000, "factor": 16, "original_max_position_embeddings": 8192,
@@ -36,15 +40,41 @@ def _qkv(seq, heads=4, kv=2, d=16):
             jax.random.normal(keys[2], (1, kv, seq, d)))
 
 
-@pytest.mark.parametrize("seq,block_q,block_k,window", [
-    (256, 64, 64, 64),     # the window is a block
-    (256, 64, 32, 100),    # divides neither block
-    (256, 32, 64, 37),
-    (128, 128, 128, 50),   # one block: the single-pass kernel
-    (256, 64, 64, 1),      # a query sees itself alone
-    (256, 64, 64, 255),    # all but one key of the last query
+@pytest.fixture
+def sub_tile(monkeypatch):
+    """Sets the kernels' sub-tile (``SUB_TILE``, read as a call is traced), so
+    that blocks small enough for the interpreter are worked in sub-tiles."""
+    return lambda tile: monkeypatch.setattr(flash_mod, "SUB_TILE", tile)
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,window,tile", [
+    (256, 64, 64, 64, None),     # the window is a block
+    (256, 64, 32, 100, None),    # divides neither block
+    (256, 32, 64, 37, None),
+    (128, 128, 128, 50, None),   # one block: the single-pass kernel
+    (256, 64, 64, 1, None),      # a query sees itself alone
+    (256, 64, 64, 255, None),    # all but one key of the last query
+    # blocks worked in sub-tiles: the window
+    (256, 64, 64, 32, 16),       # a multiple of the sub-tile
+    (256, 64, 64, 40, 16),       # not a multiple of it
+    (256, 64, 64, 5, 16),        # smaller than a sub-tile
+    (256, 64, 64, 64, 16),       # the block: every live block has an edge in it
+    (256, 64, 64, 128, 16),      # two blocks: an interior block between the edges
+    (256, 64, 32, 100, 16),      # block_q != block_k, either way round
+    (256, 32, 64, 37, 16),
+    (128, 128, 128, 50, 32),     # the single-pass kernel, both edges in its one block
+    (128, 32, 128, 20, 16),      # one key block under several query blocks
+    (2048, 1024, 1024, 1024, None),  # the shipped sub-tile in the cells' blocks
+    # a block that the sub-tile does not divide is worked whole on that side
+    (192, 192, 192, 50, None),   # a sequence under 1,024 is one block: the single-pass kernel, 192 % 128
+    (320, 320, 320, 100, None),
+    (384, 192, 128, 100, None),  # the query side whole, the key side in sub-tiles; and the other way round
+    (384, 128, 192, 100, None),
+    (256, 64, 64, 40, 48),       # several blocks, none divided
 ])
-def test_flash_with_a_window_matches_the_reference_forward_and_backward(seq, block_q, block_k, window):
+def test_flash_with_a_window_matches_the_reference_forward_and_backward(seq, block_q, block_k, window, tile, sub_tile):
+    if tile:
+        sub_tile(tile)
     q, k, v = _qkv(seq)
     flash = lambda q, k, v: flash_attention(q, k, v, block_q=block_q, block_k=block_k, window=window)  # noqa: E731
     ref = lambda q, k, v: reference_attention(q, k, v, window=window)  # noqa: E731
@@ -53,6 +83,99 @@ def test_flash_with_a_window_matches_the_reference_forward_and_backward(seq, blo
     want = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_a_row_whose_first_computed_sub_tile_hides_every_key_is_wiped_by_its_first_visible_key(sub_tile):
+    """Window = block = 64, sub-tiles of 16: the last query of a block (127)
+    sees nothing of the block before it, yet its band's one rectangle there
+    (keys 48..63, an edge in it) is computed: the row reads m = NEG_INF and
+    p = 1, and sums those keys' v.  The values there are huge, so anything
+    left of them after alpha = 0 would show."""
+    sub_tile(16)
+    assert flash_mod._pieces(64, 64, 64, True, 64, 16)[-1] == (48, 16, 48, 16, True)
+    assert not any(0 <= 127 - j < 64 for j in range(64))
+    q, k, v = _qkv(128)
+    flash = lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=64, window=64)  # noqa: E731
+    ref = lambda q, k, v: reference_attention(q, k, v, window=64)  # noqa: E731
+    huge = v.at[:, :, 48:64].set(1e4)
+    np.testing.assert_allclose(flash(q, k, huge)[:, :, 127], ref(q, k, huge)[:, :, 127], atol=2e-6)
+    np.testing.assert_allclose(flash(q, k, huge), ref(q, k, huge), rtol=2e-6, atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def _mask(offset, q_n, k_n, window):
+    """The mask itself: pair (i, j) of a rectangle whose first query is ``offset`` after its first key."""
+    d = offset + np.arange(q_n)[:, None] - np.arange(k_n)[None, :]
+    return (d >= 0) & (d < (window or 10**9))
+
+
+@pytest.mark.parametrize("window", [None, 1, 2, 3, 5, 8, 13])
+def test_the_rule_of_what_is_visible_is_the_mask_itself_for_blocks_and_sub_tiles(window):
+    """``_visible`` (none / some / every pair) against the mask, every shape
+    up to 5 x 5 at every offset that matters; then ``_pieces``: of a block,
+    exactly the sub-tiles that hold a visible pair, each once, unmasked
+    only where every pair is visible; and ``_crossed_offsets``: the offsets
+    of the grid's blocks that hold both kinds of pair."""
+    for q_n, k_n, offset in itertools.product(range(1, 6), range(1, 6), range(-8, 24)):
+        seen = _mask(offset, q_n, k_n, window)
+        assert flash_mod._visible(offset, q_n, k_n, True, window) == (seen.any(), seen.all()), (q_n, k_n, offset)
+    assert flash_mod._visible(-3, 4, 4, False, None) == (True, True)
+    blocks = [(8, 8), (8, 4), (4, 8), (4, 4), (6, 6), (6, 8), (9, 6)]
+    for (block_q, block_k), tile, tall in itertools.product(blocks, (2, 3, 4, 5, 8), (False, True)):
+        # a side that the sub-tile does not divide is one sub-tile
+        tq, tk = (tile if block % tile == 0 else block for block in (block_q, block_k))
+        for offset in range(-12, 28):
+            seen = _mask(offset, block_q, block_k, window)
+            covered = np.zeros_like(seen, dtype=int)
+            for a, rows, c, cols, masked in flash_mod._pieces(offset, block_q, block_k, True, window, tile, tall):
+                assert 0 <= a < a + rows <= block_q and 0 <= c < c + cols <= block_k, (block_q, block_k, tile, tall, offset)
+                covered[a:a + rows, c:c + cols] += 1
+                assert masked == (not seen[a:a + rows, c:c + cols].all())
+                assert (cols if tall else rows) == (tk if tall else tq) or seen.all()
+            want = np.zeros_like(covered)
+            if seen.all():
+                want[:] = 1
+            else:
+                for a, c in itertools.product(range(0, block_q, tq), range(0, block_k, tk)):
+                    want[a:a + tq, c:c + tk] = seen[a:a + tq, c:c + tk].any()
+            assert (covered == want).all(), (block_q, block_k, tile, tall, offset)
+    for nq, nk, block_q, block_k in [(4, 4, 8, 8), (4, 8, 8, 4), (8, 4, 4, 8)]:
+        blocks = {qi * block_q - ki * block_k for qi in range(nq) for ki in range(nk)}
+        crossed = {d for d in blocks if _mask(d, block_q, block_k, window).any() and not _mask(d, block_q, block_k, window).all()}
+        assert set(flash_mod._crossed_offsets(nq, nk, block_q, block_k, True, window)) == crossed
+    assert flash_mod._crossed_offsets(4, 4, 8, 8, False, None) == ()
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,window,tile", [
+    (64, 16, 16, None, 4), (64, 16, 16, 16, 4), (64, 16, 8, 10, 4), (64, 8, 16, 23, 2), (64, 16, 16, 5, 16), (32, 32, 32, 7, 8),
+    (60, 20, 20, 7, 8), (60, 20, 12, None, 4),   # a side of the block that the sub-tile does not divide is whole
+])
+def test_block_work_counts_what_the_mask_and_the_sub_tiles_say(seq, block_q, block_k, window, tile):
+    seen = _mask(0, seq, seq, window)
+    computed = 0
+    for a, c in itertools.product(range(0, seq, block_q), range(0, seq, block_k)):
+        block = seen[a:a + block_q, c:c + block_k]
+        tq, tk = (block_q, block_k) if block.all() else (tile if block_q % tile == 0 else block_q, tile if block_k % tile == 0 else block_k)
+        computed += sum(
+            tq * tk for i, j in itertools.product(range(0, block_q, tq), range(0, block_k, tk)) if block[i:i + tq, j:j + tk].any()
+        )
+    assert flash_mod.block_work(seq, block_q, block_k, window, tile) == (computed, seen.sum())
+
+
+@pytest.mark.parametrize("seq,window,tile,blocks", [
+    (8192, 1024, 128, 8.4375), (8192, None, 128, 32.5), (4096, None, 128, 8.25),   # a Mellum2 window layer; its full layer and ZAYA1's; Mistral's
+    (8192, 1024, 256, 9.375), (8192, None, 256, 33.0), (4096, None, 256, 8.5),
+    (8192, 1024, 1024, 15.0), (8192, None, 1024, 36.0), (4096, None, 1024, 10.0),  # whole blocks, as before PR 52
+])
+def test_block_work_at_the_training_cells_shapes(seq, window, tile, blocks):
+    """docs/training.md's table: blocks' worth of 1,024 x 1,024 computed a head a layer."""
+    computed, visible = flash_mod.block_work(seq, 1024, 1024, window, tile)
+    assert computed == blocks * 1024 * 1024 and visible == sum(min(i + 1, window or seq) for i in range(seq))
+    if tile == 128:
+        assert flash_mod.SUB_TILE == 128 and flash_mod.DEFAULT_BLOCK == 1024
 
 
 def test_the_reference_window_is_the_stated_mask():

@@ -1,5 +1,7 @@
 """Attention op tests: flash (interpret) and ring vs reference."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,8 @@ from determined_tpu.ops import (
     ring_attention,
 )
 from determined_tpu.parallel.mesh import MeshConfig, make_mesh
+
+flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")  # ``ops.flash_attention`` is the function
 
 
 def make_qkv(b=2, h=4, s=256, d=64, hkv=None, seed=0, dtype=jnp.float32):
@@ -49,6 +53,31 @@ def test_flash_gradients_match():
     gf = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("seq,block,tile,causal", [
+    (256, 64, 16, True),     # several interior blocks under the diagonal's crossed ones
+    (256, 64, 32, True),
+    (128, 128, 32, True),    # one block: the single-pass kernel in bands
+    (256, 64, 16, False),    # no mask: every block interior, whatever the sub-tile
+    (128, 128, 32, False),
+    (2048, 1024, None, True),  # the shipped sub-tile in the cells' blocks
+    (192, 192, None, True),  # a block that the sub-tile (128) does not divide is worked whole: one block
+    (320, 320, None, True),
+    (640, 320, None, True),  # several such blocks
+    (256, 64, 48, True),
+])
+def test_flash_blocks_worked_in_sub_tiles_match_the_reference_forward_and_backward(monkeypatch, seq, block, tile, causal):
+    if tile:
+        monkeypatch.setattr(flash_mod, "SUB_TILE", tile)
+    q, k, v = make_qkv(b=1, h=2, hkv=1, s=seq, d=16)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=block, block_k=block)  # noqa: E731
+    ref = lambda q, k, v: reference_attention(q, k, v, causal=causal)  # noqa: E731
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-6)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(ref(*a))), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
 
 
 @pytest.mark.parametrize(

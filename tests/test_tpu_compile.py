@@ -35,6 +35,7 @@ from determined_tpu.parallel.mesh import MeshConfig, make_mesh
 from tests.model_cases import (  # noqa: F401  (fixture reuse)
     adamw_mod,
     compile_text as _compile,
+    flash_mod,
     grouped_mod,
     mosaic_calls as _kernels,
     paged_mod,
@@ -60,15 +61,32 @@ def _attn_loss(mesh, q, k, v):
 
 
 @pytest.mark.parametrize(
-    "shape",
-    [(8, 16, 1024, 128), (1, 16, 4096, 128)],
-    ids=["const_yaml_b8_s1024", "long_b1_s4096"],
+    "shape,kv_heads",
+    [((8, 16, 1024, 128), None), ((1, 16, 4096, 128), None), ((4, 32, 4096, 128), 8), ((3, 8, 8192, 128), 2),
+     ((2, 4, 320, 128), None)],
+    ids=["const_yaml_b8_s1024", "long_b1_s4096", "mistral_cell_b4_s4096", "zaya1_cell_b3_s8192", "one_block_of_320"],
 )
-def test_flash_fwd_bwd_compiles_on_one_chip(tpu_devices, shape):
+def test_flash_fwd_bwd_compiles_on_one_chip(tpu_devices, shape, kv_heads):
+    """A sequence of one block (the single-pass forward in bands) and the two
+    dense training cells' shapes (benchmark/configs/mistral-7b-v0.3-l2.json,
+    zaya1-8b-l5-ep2.json): blocks of 1,024 worked in sub-tiles of 128 where
+    the diagonal crosses them.  A block that 128 does not divide (a sequence
+    of 320: one block) is worked whole."""
     one = SingleDeviceSharding(tpu_devices[0])
     grad = jax.grad(functools.partial(_attn_loss, None), argnums=(0, 1, 2))
-    text = _compile(grad, *_qkv(shape, one))
+    text = _compile(grad, *_qkv(shape, one, kv_heads))
     assert _kernels(text) == 3  # fwd, dq, dkv
+    assert len(text) < FLASH_TEXT_LIMIT
+
+
+# The compiled text of one layer's three kernels with what stands round them
+# (builder's compiles, PR 52): 92,000 characters under a window and 61,000
+# without, a crossed block's bands one product each (43,000 and 37,000 with
+# whole blocks, before).  Sub-tile by sub-tile under a ``pl.when`` each, the
+# forward's and the backward's programs came to 489,000 at 128 and compiled
+# in 16 s, not 6: a body unrolled that far lengthens every training cell's
+# set-up, and should fail here and not on the chip.
+FLASH_TEXT_LIMIT = 200_000
 
 
 def test_flash_with_a_window_compiles_at_the_mellum_cells_shape(tpu_devices):
@@ -84,6 +102,7 @@ def test_flash_with_a_window_compiles_at_the_mellum_cells_shape(tpu_devices):
     avals = _qkv((1, 32, 8192, 128), one, kv_heads=4)
     text = _compile(jax.grad(functools.partial(loss, 1024), argnums=(0, 1, 2)), *avals)
     assert _kernels(text) == 3 and all(f"flash_window_{k}" in text for k in ("fwd", "dq", "dkv"))
+    assert len(text) < FLASH_TEXT_LIMIT  # two crossed offsets (the diagonal's, the trailing edge's) a kernel
     plain = _compile(jax.grad(functools.partial(loss, None), argnums=(0, 1, 2)), *avals)
     assert _kernels(plain) == 3 and "flash_window" not in plain
 
@@ -176,6 +195,11 @@ def test_a_kernel_compiles_to_the_same_program_whoever_calls_it(tpu_devices):
     one = SingleDeviceSharding(tpu_devices[0])
 
     def lowered_from(filename: str) -> str:
+        # a process keeps the flash kernels' first trace a configuration
+        # (``_fwd_program``, ``_bwd_program``): each caller here stands for a
+        # process of its own
+        flash_mod._fwd_program.clear_cache()
+        flash_mod._bwd_program.clear_cache()
         ns: dict = {}
         exec(compile("def call(fn, *a):\n    return fn(*a)\n", filename, "exec"), ns)
         loss = functools.partial(_attn_loss, None)
