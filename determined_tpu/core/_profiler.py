@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional
 
 from determined_tpu.core._distributed import DistributedContext
 from determined_tpu.core._metrics import MetricsContext
+from determined_tpu.observability import get_tracer
 
 logger = logging.getLogger("determined_tpu.core.profiler")
 
@@ -101,6 +102,30 @@ def _tpu_memory_stats() -> Dict[str, float]:
     return out
 
 
+#: the annotation an xplane window carries at both ends, and the instant
+#: the process tracer gets with it
+CLOCK_SYNC = "dtpu.clock_sync"
+
+
+def _clock_sync() -> None:
+    """Stamp the host's monotonic clock into the running device trace and
+    into the process tracer: three ``dtpu.clock_sync`` annotations, each with
+    the ``time.monotonic_ns()`` read just before it as ``monotonic_ns``, and
+    an instant of the same name and argument beside each.  The annotation's
+    start on the trace's clock less its ``monotonic_ns`` is the offset
+    between the two clocks (take the median of the six), so whoever has the
+    xplane and ``events.jsonl`` lays one on the other with no side file
+    (``docs/observability.md`` "A device trace on the tracer's clock")."""
+    import jax
+
+    tracer = get_tracer()
+    for _ in range(3):
+        ns = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation(CLOCK_SYNC, monotonic_ns=ns):
+            pass
+        tracer.instant(CLOCK_SYNC, cat="profile", monotonic_ns=ns)
+
+
 class ProfilerContext:
     SAMPLE_INTERVAL = 10.0
 
@@ -138,6 +163,7 @@ class ProfilerContext:
             os.makedirs(trace_dir, exist_ok=True)
             jax.profiler.start_trace(trace_dir)
             self._tracing = True
+            _clock_sync()
 
     @property
     def tracing(self) -> bool:
@@ -150,6 +176,7 @@ class ProfilerContext:
         if self._tracing:
             import jax
 
+            _clock_sync()
             jax.profiler.stop_trace()
             self._tracing = False
             self._report_trace_summary()

@@ -27,9 +27,10 @@ Design constraints, in order:
    ``{"traceEvents": [...]}`` JSON that Perfetto/chrome://tracing load.
 
 Clocks: span timestamps are ``time.monotonic()`` relative to a per-process
-epoch; the matching ``time.time()`` wall epoch is stored in the trace
-metadata so a sampled ``jax.profiler`` xplane window can be lined up with
-the span timeline.
+epoch (``epoch_monotonic``); it and the matching ``time.time()`` wall epoch
+are stored in the trace metadata, and a sampled ``jax.profiler`` xplane
+window carries ``dtpu.clock_sync`` annotations with the monotonic time
+(``core/_profiler.py``), so the window can be laid on the span timeline.
 """
 
 from __future__ import annotations
@@ -439,6 +440,14 @@ class Tracer:
     @property
     def epoch_wall(self) -> float:
         return self._epoch_wall
+
+    @property
+    def epoch_monotonic(self) -> float:
+        """``time.monotonic()`` at the events' ``ts`` 0: an event's monotonic
+        time is this plus ``ts / 1e6``.  With it and a ``dtpu.clock_sync``
+        instant (``core/_profiler.py``) the timeline lies on a device
+        trace's clock."""
+        return self._epoch
 
     def chrome_events(self) -> List[Dict[str, Any]]:
         """Snapshot of all drained events (drains first)."""

@@ -30,11 +30,15 @@ unless a lane joined since.  The lanes' uniforms are drawn, and sent, between
 the decode call's two stamps (``DecodeKernels.during_wait``).
 
 The engine times itself.  One set of ``time.monotonic()`` stamps — a
-handful a step, one a token — is taken always and feeds two sinks: the
-cumulative ``step_seconds`` and the window of recent requests' latencies
-that ``stats()`` (``/stats``, the heartbeat) reports, and, only while the
-process tracer is enabled, the ``serve.*`` spans (``docs/serving.md``
-"Observability" names each with what reads it).  Request spans carry
+dozen a step, one a token — is taken always and feeds two sinks.  The first
+is the phase clock (``scheduler.PhaseClock``): every moment of the engine
+thread's life lies in one phase of a closed set, so the cumulative
+``step_seconds`` that ``stats()`` (``/stats``, the heartbeat) reports add up
+to the thread's time, and a request knows what the thread did between its
+tokens (``GenRequest.tpot_split_s``, beside the window of recent requests'
+latencies).  The second, only while the process tracer is enabled, is the
+``serve.*`` spans, from the same stamps (``docs/serving.md`` "Observability"
+names each phase and span with what reads it).  Request spans carry
 ``request=<id>``, step spans ``step=<n>``.
 """
 
@@ -58,11 +62,27 @@ from determined_tpu.serve.kv_cache import (
     prefix_block_hashes,
 )
 from determined_tpu.serve.scheduler import (
+    ADMISSION_FIRST_SAMPLE,
+    ADMISSION_KV_ALLOC,
+    ADMISSION_PREFILL,
+    ADMISSION_REST,
+    D2H,
+    DECODE_DISPATCH,
+    DECODE_WAIT,
+    IDLE,
+    LANES,
+    PHASES,
+    REST,
+    RETIRE,
+    SAMPLE_LAUNCH,
+    SAMPLE_WAIT,
+    TPOT_PARTS,
     ActiveSeq,
     AdmissionQueue,
     AdmissionRejected,
     GenRequest,
     LaneTable,
+    PhaseClock,
 )
 
 logger = logging.getLogger("determined_tpu.serve")
@@ -533,17 +553,20 @@ class ServeEngine:
         #: catches handler-level failures the engine never sees
         self._http_5xx = 0
         self._latency_ms_total = 0.0
-        #: (ttft_s, tpot_s, queue_wait_s) of the newest finished requests;
-        #: appended at retire, summarized by stats() on the caller's thread
-        self._recent: "collections.deque[Tuple[Optional[float], ...]]" = (
+        #: (ttft_s, tpot_s, queue_wait_s, tpot_split_s) of the newest finished
+        #: requests; appended at retire, summarized by stats() on the caller's thread
+        self._recent: "collections.deque[Tuple[Any, ...]]" = (
             collections.deque(maxlen=LATENCY_WINDOW)
         )
         #: (``_completed`` when summarized, the summary): stats()'s cache
         self._latency_summary: Tuple[int, Dict[str, Any]] = (-1, {})
-        #: cumulative seconds of the parts of a step, and the steps counted
-        self._step_seconds = {
-            "decode_wait": 0.0, "d2h": 0.0, "sample": 0.0, "admission": 0.0,
-        }
+        #: where this thread's time goes, by phase: fed from a step's stamps
+        #: by the engine's thread alone, which starts here, idle
+        self._clock = PhaseClock()
+        #: a reading of the clock and the seconds it covers, as the engine's
+        #: thread last published it (a step's one hold of the stats lock,
+        #: an idle wait's end): what ``stats()`` reports as ``step_seconds``
+        self._step_seconds: Tuple[Tuple[float, ...], float] = self._clock.reading()
         #: prompt tokens the prefills were asked for (past what the prefix
         #: cache held) and tokens they computed (whole chunks), cumulative
         self._prefill_tokens_asked = 0
@@ -556,7 +579,7 @@ class ServeEngine:
         self._steps = 0
         #: true while THIS engine runs the tracer's shipper (it started it)
         self._owns_shipper = False
-        self._started_at = time.monotonic()
+        self._started_at = self._clock.started_at
 
     @classmethod
     def from_checkpoint(
@@ -652,7 +675,7 @@ class ServeEngine:
     def start(self) -> "ServeEngine":
         if not self._thread.is_alive() and not self._finished.is_set():
             if self._tracer.enabled and not self._tracer.shipping:
-                # a step writes half a dozen spans: without the shipper the
+                # a step writes ten spans: without the shipper the
                 # engine thread's ring fills within minutes and every later
                 # event is dropped.  An entry point that runs the shipper
                 # itself (dtpu serve with trace_dir, a trial) keeps it.
@@ -723,10 +746,19 @@ class ServeEngine:
     def _finish_error(self, req: GenRequest, reason: str) -> None:
         """Fail one request AND count it: every error-finish goes through
         here so the `errored` stat the heartbeat ships stays truthful."""
-        req.finish(error=reason)
+        self._finish(req, reason)
         with self._stats_lock:
             self._errored += 1
         self._record_request(req)
+
+    def _finish(self, req: GenRequest, error: Optional[str] = None) -> None:
+        """Finish a request and, where it has a first token, read the phase
+        clock at its last stamp.  Only the engine's thread finishes such a
+        request (another thread fails what is still queued), so only it
+        reads the clock."""
+        req.finish(error)
+        if req.phases_at_first is not None:
+            req.phases_at_finish = self._clock.read(req.finished_at)
 
     def note_http_response(self, status: int) -> None:
         """HTTP layer callback: count 5xx responses (handler failures the
@@ -739,6 +771,7 @@ class ServeEngine:
         """One ``serve.request`` span a finished request, arrival to finish:
         the line an operator's export holds for it."""
         if self._tracer.enabled:
+            split = req.tpot_split_s
             self._tracer.record_span(
                 "serve.request", "serve", req.arrival, req.finished_at,
                 {
@@ -748,6 +781,8 @@ class ServeEngine:
                     "queue_wait_ms": _ms(req.queue_wait_s),
                     "ttft_ms": _ms(req.ttft_s),
                     "tpot_ms": _ms(req.tpot_s),
+                    # its parts, to 0.1 us so that the four add up to it within 1 us
+                    **{f"tpot_{part}_ms": None if split is None else round(1000.0 * split[part], 4) for part in TPOT_PARTS},
                     "itl_max_ms": _ms(req.itl_max_s),
                     "error": req.error,
                 },
@@ -787,10 +822,8 @@ class ServeEngine:
             at, latency = self._latency_summary
             completed = self._completed
             recent = list(self._recent) if at != completed else None
-            step_seconds = {
-                **{k: round(v, 6) for k, v in self._step_seconds.items()},
-                "steps": self._steps,
-            }
+            phases, covered = self._step_seconds
+            steps = self._steps
             step_counters = dict(self._step_counters)
             gauges = dict(self._step_gauges)
             step_inputs = dict(self._step_inputs)
@@ -799,8 +832,12 @@ class ServeEngine:
                 name: _summary_ms([r[i] for r in recent if r[i] is not None])
                 for i, name in enumerate(("ttft_ms", "tpot_ms", "queue_wait_ms"))
             }
+            splits = [r[3] for r in recent if r[3] is not None]
+            latency["tpot_split_ms"] = {part: _summary_ms([s[part] for s in splits]) for part in TPOT_PARTS}
             with self._stats_lock:
                 self._latency_summary = (completed, latency)
+        latency = {name: dict(v) for name, v in latency.items()}
+        latency["tpot_split_ms"] = {part: dict(v) for part, v in latency["tpot_split_ms"].items()}
         from determined_tpu.models.cache_kinds import CACHE_KINDS
 
         kv = self.allocator.stats()
@@ -808,14 +845,25 @@ class ServeEngine:
         return {
             **counters,
             # what a caller feels, over the newest LATENCY_WINDOW finished
-            # requests (tpot: those with two tokens or more)
-            "latency": {name: dict(v) for name, v in latency.items()},
-            # where the engine thread's time went, cumulative since start:
-            # waiting for the decode program (the lanes' uniforms are drawn
-            # and sent inside that wait), sampling (the device's call, its
-            # ids taken per lane), of which copying the ids and the counters
-            # to the host, admitting (prefill and first sample)
-            "step_seconds": step_seconds,
+            # requests (tpot, and its split by what the engine's thread was
+            # doing between a request's tokens: those with two tokens or more)
+            "latency": latency,
+            # where the engine thread's time went, cumulative since the
+            # engine was made: every phase of the clock, which add up to
+            # ``uptime`` (the seconds the reading covers), and the four sums
+            # that were here before it: waiting for the decode program (the
+            # lanes' uniforms are drawn and sent inside that wait), sampling
+            # (the launch to the last lane's stamp), of which copying the ids
+            # and the counters to the host, admitting (prefill and first sample)
+            "step_seconds": {
+                "decode_wait": round(phases[DECODE_WAIT], 6),
+                "d2h": round(phases[D2H], 6),
+                "sample": round(sum(phases[i] for i in TPOT_PARTS["sample"]), 6),
+                "admission": round(sum(phases[i] for i in TPOT_PARTS["prefill_stall"]), 6),
+                "steps": steps,
+                "uptime": round(covered, 6),
+                "phases": {name: round(v, 6) for name, v in zip(PHASES, phases)},
+            },
             # cumulative counts of the decode steps, where the model has
             # expert layers: picks that landed on the experts held here and
             # held experts that got a row, each summed over layers and steps
@@ -878,9 +926,12 @@ class ServeEngine:
         taken here are the request's own (``admitted_at``, the first
         ``token_at``) and the edges of its ``serve.queue_wait`` and
         ``serve.admission`` spans: the wait ends where the admission
-        starts, the admission where the first token is out.
+        starts, the admission where the first token is out.  The phase
+        clock is fed from them once the blocks are held: an attempt that has
+        to wait for blocks is not an admission, and its time stays the loop's.
         """
         tracer = self._tracer
+        clock = self._clock
         t_admit = mono()  # just off the queue
         # a request that holds no block of any kind: the free lane is the admission
         total = self.allocator.blocks_for(len(req.prompt) + req.max_new_tokens) if self._holds_blocks else 0
@@ -894,17 +945,23 @@ class ServeEngine:
             shared = self.allocator.match_prefix(chain)
             cached_tokens = len(shared) * self.cfg.block_size
         needed = total - len(shared)
+        t_alloc = mono()
         try:
-            with tracer.span(
-                "serve.kv_alloc", cat="serve", request=req.id, step=step, blocks=needed
-            ):
-                private = self.allocator.alloc(needed) if needed else []
+            private = self.allocator.alloc(needed) if needed else []
         except CacheOOM:
             if shared:
                 self.allocator.free(shared)
             raise
+        finally:
+            t_alloced = mono()
+            tracer.record_span(
+                "serve.kv_alloc", "serve", t_alloc, t_alloced, {"request": req.id, "step": step, "blocks": needed}
+            )
         # this attempt holds its blocks: the wait in the queue is over
         req.admitted_at = t_admit
+        clock.to(ADMISSION_REST, t_admit)
+        clock.to(ADMISSION_KV_ALLOC, t_alloc)
+        clock.to(ADMISSION_REST, t_alloced)
         tracer.record_span(
             "serve.queue_wait", "serve", req.arrival, t_admit, {"request": req.id}
         )
@@ -916,15 +973,23 @@ class ServeEngine:
         # un-cached token lies in (0 cached when nothing matched)
         chunks = self.cfg.prefill_chunks(len(req.prompt), cached_tokens)
         computed = chunks * self.cfg.prefill_chunk
+        t_prefill = mono()
         try:
-            with tracer.span(
-                "serve.prefill", cat="serve", request=req.id, step=step,
-                cached_tokens=cached_tokens, chunks=chunks, computed_tokens=computed,
-            ):
-                logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens, lane)
+            logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens, lane)
         except BaseException:
             self.allocator.free(blocks)
             raise
+        finally:
+            t_prefilled = mono()
+            tracer.record_span(
+                "serve.prefill", "serve", t_prefill, t_prefilled,
+                {
+                    "request": req.id, "step": step,
+                    "cached_tokens": cached_tokens, "chunks": chunks, "computed_tokens": computed,
+                },
+            )
+        clock.to(ADMISSION_PREFILL, t_prefill)
+        clock.to(ADMISSION_REST, t_prefilled)
         if chain:
             # the suffix just materialized this prompt's remaining full
             # blocks; make them matchable (shared prefix entries are
@@ -934,12 +999,17 @@ class ServeEngine:
         t_sample = mono()
         tok = sample_token(logits, req.temperature, rng)
         t_first = mono()
+        clock.to(ADMISSION_FIRST_SAMPLE, t_sample)
+        clock.to(REST, t_first)
         req.first_token_at = t_first
+        # its own admission is counted up to here, so what the clock gains
+        # between this reading and the one at the finish is the time between
+        # the request's first token and its last
+        req.phases_at_first = clock.read(t_first)
         req.output.append(tok)
         req.token_at.append(t_first)
         with self._stats_lock:
             self._tokens_generated += 1
-            self._step_seconds["admission"] += t_first - t_admit
             self._prefill_tokens_asked += len(req.prompt) - cached_tokens
             self._prefill_tokens_computed += computed
         if tracer.enabled:
@@ -976,13 +1046,13 @@ class ServeEngine:
     def _retire_seq(self, seq: ActiveSeq) -> None:
         self.allocator.free(seq.blocks)
         req = seq.request
-        req.finish()
+        self._finish(req)
         latency = req.latency_s
         with self._stats_lock:
             self._completed += 1
             if latency is not None:
                 self._latency_ms_total += latency * 1000.0
-            self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s))
+            self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s, req.tpot_split_s))
         self._record_request(req)
 
     def _decode_batch(
@@ -1056,11 +1126,15 @@ class ServeEngine:
         it is complete).  Returns how many finished.
 
         ``serve.sample`` runs from the sampler's launch to the last lane's
-        token stamp, and ``serve.decode.d2h`` (inside it) from the ids being
-        ready on the device to their being on the host.  ``serve.decode`` has
-        ended before: a reader that takes the device's step from it counts
+        token stamp, in four parts end to end: ``serve.sample.launch`` (the
+        call and the two copies are queued), ``serve.sample.wait`` (the ids
+        are ready on the device), ``serve.decode.d2h`` (they are on the
+        host) and ``serve.lanes`` (each lane has its token).  ``serve.decode``
+        has ended before: a reader that takes the device's step from it counts
         no operation of the sampler.  ``serve.step.prepare`` is the prepared
-        work, inside ``serve.decode.wait`` where the kernels ran it."""
+        work, inside ``serve.decode.wait`` where the kernels ran it.
+        ``serve.retire`` follows where a sequence finished.  The phase clock
+        is fed from the same stamps, after the lanes' loop."""
         logits, positions, draws, sent, (t_call, t_back), prepare = self._decode_batch(lanes)
         t0 = mono()
         names = self._counters + self._gauges
@@ -1070,6 +1144,7 @@ class ServeEngine:
         ids.copy_to_host_async()
         if names:
             counted.copy_to_host_async()
+        t_launched = mono()
         ids.block_until_ready()
         t_ready = mono()
         tokens = np.asarray(ids).tolist()
@@ -1105,14 +1180,22 @@ class ServeEngine:
             if done:
                 finished.append((i, seq))
         on_device = live if own is None else 0
+        # what lies in ``serve.decode`` outside the kernels' own wait (a
+        # wrapper's work round the call, the launch) is the dispatch
+        clock = self._clock
+        clock.to(DECODE_DISPATCH, t_call)
+        if stamps is not None:
+            clock.to(DECODE_WAIT, stamps[1])
+            clock.to(DECODE_DISPATCH, stamps[2])
+        clock.to(SAMPLE_LAUNCH, t0)
+        clock.to(SAMPLE_WAIT, t_launched)
+        clock.to(D2H, t_ready)
+        clock.to(LANES, t_host)
+        clock.to(REST, t1)
         with self._stats_lock:
             self._tokens_generated += on_device
             self._tokens_sampled_on_device += on_device
-            seconds = self._step_seconds
-            if stamps is not None:
-                seconds["decode_wait"] += stamps[2] - stamps[1]
-            seconds["d2h"] += t_host - t_ready
-            seconds["sample"] += t1 - t0
+            self._step_seconds = clock.reading()
             for name, value in counts.items():
                 self._step_counters[name] = self._step_counters.get(name, 0.0) + value
             self._step_gauges = gauges
@@ -1120,8 +1203,13 @@ class ServeEngine:
             for name, value in sent.items():
                 self._step_inputs[name] += value
             self._steps = step
-        for i, seq in finished:
-            self._retire_lane(i, seq)
+        if finished:
+            t_retire = mono()
+            clock.to(RETIRE, t_retire)
+            for i, seq in finished:
+                self._retire_lane(i, seq)
+            t_retired = mono()
+            clock.to(REST, t_retired)
         tracer = self._tracer
         if tracer.enabled:
             at = {"step": step}
@@ -1143,10 +1231,15 @@ class ServeEngine:
                 tracer.record_span("serve.decode.dispatch", "serve", stamps[0], stamps[1], at)
                 tracer.record_span("serve.decode.wait", "serve", stamps[1], stamps[2], at)
             tracer.record_span("serve.step.prepare", "serve", *prepare, at)
+            tracer.record_span("serve.sample.launch", "serve", t0, t_launched, at)
+            tracer.record_span("serve.sample.wait", "serve", t_launched, t_ready, at)
             tracer.record_span("serve.decode.d2h", "serve", t_ready, t_host, at)
+            tracer.record_span("serve.lanes", "serve", t_host, t1, at)
             tracer.record_span(
                 "serve.sample", "serve", t0, t1, {**at, "lanes": live, "device_lanes": on_device}
             )
+            if finished:
+                tracer.record_span("serve.retire", "serve", t_retire, t_retired, {**at, "retired": len(finished)})
         return len(finished)
 
     def _admit_one(self, step: int) -> bool:
@@ -1162,6 +1255,7 @@ class ServeEngine:
             self.queue.requeue_head(req)
             return False
         except Exception as e:  # noqa: BLE001 - a poisoned request must not kill the loop
+            self._clock.to(REST, mono())  # wherever the attempt broke off
             logger.exception("request %d failed at prefill", req.id)
             self._finish_error(req, f"prefill failed: {e}")
             return True
@@ -1187,8 +1281,17 @@ class ServeEngine:
         """One scheduler iteration: admit whatever fits, run one decode
         step, retire what finished.  Returns True when any work happened.
         The engine thread loops this; tests drive it directly for
-        deterministic join/retire assertions (no wall-clock races)."""
+        deterministic join/retire assertions (no wall-clock races).
+
+        What of an iteration lies under none of its parts (this loop, the
+        lanes' snapshot, the stats lock, the tracer's pushes) is the phase
+        ``rest``; ``serve.step`` carries the iteration's own split by phase
+        (``phase_ms``: the phases that took any time), which adds up to it."""
         t0 = mono()
+        clock = self._clock
+        clock.to(REST, t0)
+        traced = self._tracer.enabled
+        before = clock.totals.copy() if traced else None
         step = self._steps + 1  # this iteration's number, kept if it works
         admitted = 0
         while self.lanes.has_free_lane() and not self._stop.is_set():
@@ -1203,20 +1306,39 @@ class ServeEngine:
         elif admitted:
             with self._stats_lock:
                 self._steps = step  # admitted, and all finished at prefill
+                self._step_seconds = clock.reading()
         else:
             return False
-        if self._tracer.enabled:
-            # the step's own line: what it did, and the queue and the pool
-            # as it left them (the two gauges nobody read rode here)
+        if traced:
+            # the step's own line: what it did, where its time went, and the
+            # queue and the pool as it left them (the two gauges nobody read
+            # rode here)
+            t1 = mono()
+            clock.to(REST, t1)
             self._tracer.record_span(
-                "serve.step", "serve", t0, mono(),
+                "serve.step", "serve", t0, t1,
                 {
                     "step": step, "active": active, "admitted": admitted,
                     "retired": retired, "queued": self.queue.depth(),
                     "kv_used_blocks": self.allocator.used_blocks,
+                    "phase_ms": {
+                        name: round(1000.0 * (now - was), 3)
+                        for name, was, now in zip(PHASES, before, clock.totals) if now != was
+                    },
                 },
             )
         return True
+
+    def _idle_wait(self) -> None:
+        """Nothing to do: wait to be woken, as the phase ``idle``, and publish
+        the clock (no step does while none works)."""
+        clock = self._clock
+        clock.to(IDLE, mono())
+        self._wake.wait(timeout=0.05)
+        self._wake.clear()
+        clock.to(REST, mono())
+        with self._stats_lock:
+            self._step_seconds = clock.reading()
 
     def _run(self) -> None:
         while not self._stop.is_set():
@@ -1225,11 +1347,14 @@ class ServeEngine:
             # idle: no active lanes, nothing admitted
             if self.queue.draining and self.queue.empty():
                 break
-            self._wake.wait(timeout=0.05)
-            self._wake.clear()
+            self._idle_wait()
         if self._stop.is_set():
             for i in self.lanes.active():
                 seq = self.lanes.retire(i)
                 self.allocator.free(seq.blocks)
                 self._finish_error(seq.request, "engine stopped")
+        # the thread's last reading: from here on the engine is idle for good
+        self._clock.to(IDLE, mono())
+        with self._stats_lock:
+            self._step_seconds = self._clock.reading()
         self._finished.set()

@@ -1,5 +1,6 @@
 """Continuous-batching scheduler: requests, the bounded admission queue,
-and the decode-lane table.
+the decode-lane table, and the clock that says which phase of the step loop
+the engine's thread is in (what a request waits through between its tokens).
 
 Iteration-level (continuous) batching as in Orca (Yu et al., OSDI '22):
 the unit of scheduling is one decode STEP, not one request.  New sequences
@@ -22,9 +23,74 @@ import itertools
 import queue
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 _req_ids = itertools.count(1)
+
+#: what the engine's thread can be doing: a closed set, every moment of the
+#: thread's life in exactly one of them (``PhaseClock``; ``docs/serving.md``
+#: "Observability" has a line for each).  The index is the phase's place in a
+#: reading of the clock
+PHASES = (
+    "idle",
+    "admission.kv_alloc", "admission.prefill", "admission.first_sample", "admission.rest",
+    "decode.dispatch", "decode.wait",
+    "sample.launch", "sample.wait", "d2h", "lanes",
+    "retire", "rest",
+)
+(
+    IDLE,
+    ADMISSION_KV_ALLOC, ADMISSION_PREFILL, ADMISSION_FIRST_SAMPLE, ADMISSION_REST,
+    DECODE_DISPATCH, DECODE_WAIT,
+    SAMPLE_LAUNCH, SAMPLE_WAIT, D2H, LANES,
+    RETIRE, REST,
+) = range(len(PHASES))
+
+#: the four parts of a request's time a token, each a sum of phases, together
+#: all of them: waiting for the device's step, the step's sampling (launch to
+#: the last lane's stamp), admissions (other requests': its own ended with its
+#: first token), and what is left of the host's loop
+TPOT_PARTS: Dict[str, Tuple[int, ...]] = {
+    "decode_wait": (DECODE_WAIT,),
+    "sample": (SAMPLE_LAUNCH, SAMPLE_WAIT, D2H, LANES),
+    "prefill_stall": (ADMISSION_KV_ALLOC, ADMISSION_PREFILL, ADMISSION_FIRST_SAMPLE, ADMISSION_REST),
+    "host": (DECODE_DISPATCH, RETIRE, REST, IDLE),
+}
+
+
+class PhaseClock:
+    """Cumulative seconds of the engine thread by phase.
+
+    The thread is always in one phase; ``to(phase, at)`` closes the running
+    one at the stamp ``at`` and opens the next, so the totals add up to the
+    time from ``started_at`` to the newest stamp, whatever the stamps are.
+    The engine takes a step's stamps as it always did and feeds them in
+    afterwards, in order: a stamp is never earlier than the one before it.
+    One writer (the engine's thread) and no lock: another thread reads the
+    reading the engine publishes (``ServeEngine.stats``), not the clock.
+    """
+
+    __slots__ = ("totals", "phase", "at", "started_at")
+
+    def __init__(self) -> None:
+        self.started_at = self.at = time.monotonic()
+        self.totals = [0.0] * len(PHASES)
+        self.phase = IDLE
+
+    def to(self, phase: int, at: float) -> None:
+        self.totals[self.phase] += at - self.at
+        self.phase = phase
+        self.at = at
+
+    def read(self, at: float) -> Tuple[float, ...]:
+        """The totals as of ``at``, the running phase counted up to it."""
+        totals = list(self.totals)
+        totals[self.phase] += at - self.at
+        return tuple(totals)
+
+    def reading(self) -> Tuple[Tuple[float, ...], float]:
+        """The totals as of the newest stamp, and the seconds they cover."""
+        return self.read(self.at), self.at - self.started_at
 
 
 @dataclasses.dataclass
@@ -54,6 +120,10 @@ class GenRequest:
     #: one monotonic stamp per emitted token, appended with ``output``
     token_at: List[float] = dataclasses.field(default_factory=list)
     finished_at: Optional[float] = None
+    #: the engine's phase clock (``PhaseClock.read``) as of ``first_token_at``
+    #: and as of ``finished_at``: what the thread did in between, by phase
+    phases_at_first: Optional[Tuple[float, ...]] = None
+    phases_at_finish: Optional[Tuple[float, ...]] = None
 
     @property
     def queue_wait_s(self) -> Optional[float]:
@@ -73,6 +143,16 @@ class GenRequest:
         if self.finished_at is None or self.first_token_at is None or len(self.output) < 2:
             return None
         return (self.finished_at - self.first_token_at) / (len(self.output) - 1)
+
+    @property
+    def tpot_split_s(self) -> Optional[Dict[str, float]]:
+        """``tpot_s`` by what the engine's thread was doing between the first
+        token and the finish (``TPOT_PARTS``); the parts add up to it."""
+        if self.tpot_s is None or self.phases_at_first is None or self.phases_at_finish is None:
+            return None
+        spent = [b - a for a, b in zip(self.phases_at_first, self.phases_at_finish)]
+        gaps = len(self.output) - 1
+        return {part: sum(spent[i] for i in phases) / gaps for part, phases in TPOT_PARTS.items()}
 
     @property
     def itl_max_s(self) -> Optional[float]:

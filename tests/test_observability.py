@@ -459,3 +459,59 @@ def test_ledger_rebases_resumed_run_epochs():
     # without pid separation the second trial.run would nest under the
     # first and its duration would vanish into double-counted self time
     assert len(ledger["threads"]) == 2
+
+
+def test_a_profiling_window_carries_the_hosts_clock_at_both_ends(tmp_path):
+    """``ProfilerContext.on(trace=True)`` / ``stop_trace()`` write three
+    ``dtpu.clock_sync`` annotations each, with the monotonic time they were
+    taken at, and the tracer gets an instant with the same time beside each:
+    the xplane and ``events.jsonl`` can be laid on one another from what they
+    hold (docs/observability.md "A device trace on the tracer's clock")."""
+    import glob
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from determined_tpu.core._profiler import CLOCK_SYNC, ProfilerContext
+    from determined_tpu.observability import get_tracer
+
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.configure(enabled=True)
+    reported = []  # not the chief: the window's op table is not parsed here
+    profiler = ProfilerContext(
+        types.SimpleNamespace(rank=1), types.SimpleNamespace(report=lambda *a: reported.append(a)), trace_dir=str(tmp_path)
+    )
+    before = time.monotonic_ns()
+    profiler.on(sampling=False, trace=True)
+    with tracer.span("work", cat="test"):
+        jax.jit(lambda x: (x @ x).sum())(jnp.ones((64, 64))).block_until_ready()
+    profiler.stop_trace()
+    after = time.monotonic_ns()
+    assert not profiler.tracing and not reported
+    # the annotations, read back with nothing but jax
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert len(files) == 1
+    marks = []
+    for plane in jax.profiler.ProfileData.from_file(files[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == CLOCK_SYNC:
+                    marks.append((ev.start_ns, dict(ev.stats)["monotonic_ns"]))
+    assert len(marks) == 6 and all(before <= ns <= after for _, ns in marks)
+    # the tracer's instants carry the same readings, on its own clock: ts is
+    # microseconds from `epoch_monotonic`
+    instants = [e for e in tracer.chrome_events() if e["name"] == CLOCK_SYNC]
+    assert sorted(e["args"]["monotonic_ns"] for e in instants) == sorted(ns for _, ns in marks)
+    for e in instants:
+        assert tracer.epoch_monotonic + e["ts"] / 1e6 == pytest.approx(e["args"]["monotonic_ns"] / 1e9, abs=1e-3)
+    # one offset lays the trace on the monotonic clock: the six agree within a millisecond
+    offsets = sorted(start - ns for start, ns in marks)
+    assert offsets[-1] - offsets[0] < 1e6
+    # and with it the tracer's span lies inside the profiling window
+    offset = offsets[len(offsets) // 2]
+    work = next(e for e in tracer.chrome_events() if e["name"] == "work")
+    start_ns = (tracer.epoch_monotonic + work["ts"] / 1e6) * 1e9 + offset
+    assert min(s for s, _ in marks) <= start_ns <= max(s for s, _ in marks)
+    tracer.reset()

@@ -32,7 +32,7 @@ from determined_tpu.serve import (
     ServeEngine,
     ServeWorker,
 )
-from determined_tpu.serve.scheduler import ActiveSeq, GenRequest
+from determined_tpu.serve.scheduler import PHASES, TPOT_PARTS, ActiveSeq, GenRequest
 from tests.model_cases import causal_forward
 
 pytestmark = [pytest.mark.lock_order, pytest.mark.no_thread_leaks]
@@ -1355,16 +1355,29 @@ def test_stats_carries_latency_and_step_seconds(kernels, tracer):
         eng.stop()
     assert OLD_STATS_KEYS <= set(st)
     lat = st["latency"]
-    assert set(lat) == {"ttft_ms", "tpot_ms", "queue_wait_ms"}
+    assert set(lat) == {"ttft_ms", "tpot_ms", "queue_wait_ms", "tpot_split_ms"}
     assert lat["ttft_ms"]["n"] == lat["queue_wait_ms"]["n"] == 5
     assert lat["tpot_ms"]["n"] == 4  # the one-token request has no gap
-    for v in lat.values():
+    split = lat.pop("tpot_split_ms")
+    for v in list(lat.values()) + list(split.values()):
         assert 0 <= v["p50"] <= v["p90"]
     assert lat["queue_wait_ms"]["p50"] <= lat["ttft_ms"]["p50"]
+    # a request's time a token by what the engine's thread was doing, over the same requests
+    assert set(split) == set(TPOT_PARTS) == {"decode_wait", "sample", "prefill_stall", "host"}
+    assert {v["n"] for v in split.values()} == {4} and split["decode_wait"]["p50"] > 0
+    assert split["decode_wait"]["p50"] < lat["tpot_ms"]["p50"] <= sum(v["p90"] for v in split.values()) + 0.004
     ss = st["step_seconds"]
-    assert set(ss) == {"decode_wait", "d2h", "sample", "admission", "steps"}
-    assert ss["steps"] >= 4 and all(ss[k] > 0 for k in ss)
+    assert set(ss) == {"decode_wait", "d2h", "sample", "admission", "steps", "uptime", "phases"}
+    assert ss["steps"] >= 4 and all(ss[k] > 0 for k in ss if k != "phases")
     assert ss["decode_wait"] + ss["d2h"] + ss["sample"] + ss["admission"] < st["uptime_s"]
+    # the four that were there are sums of the clock's phases, which are a
+    # closed set: together they are the seconds the reading covers
+    phases = ss["phases"]
+    assert tuple(phases) == PHASES and all(v > 0 for v in phases.values())
+    assert sum(phases.values()) == pytest.approx(ss["uptime"], abs=2e-5) and ss["uptime"] <= st["uptime_s"]
+    assert ss["decode_wait"] == phases["decode.wait"] and ss["d2h"] == phases["d2h"]
+    assert ss["sample"] == pytest.approx(sum(phases[k] for k in ("sample.launch", "sample.wait", "d2h", "lanes")), abs=5e-6)
+    assert ss["admission"] == pytest.approx(sum(v for k, v in phases.items() if k.startswith("admission.")), abs=5e-6)
     # the disabled tracer recorded nothing, and no shipper was started for it
     assert tracer.stats()["events"] == 0 and not tracer.shipping
 
@@ -1393,7 +1406,12 @@ def test_2000_steps_drop_no_event(kernels, tracer):
     assert tracer.shipping
     try:
         pending = []
-        while eng.stats()["step_seconds"]["steps"] < 2000:
+        while True:
+            # read while the engine's thread runs: every reading it publishes is closed
+            ss = eng.stats()["step_seconds"]
+            assert abs(sum(ss["phases"].values()) - ss["uptime"]) < 1e-3, ss
+            if ss["steps"] >= 2000:
+                break
             pending.append(_submit_retry(eng, [1, 2, 3], max_new_tokens=32))
             pending = [r for r in pending if not r.done.is_set()]
         for r in pending:
@@ -1401,6 +1419,14 @@ def test_2000_steps_drop_no_event(kernels, tracer):
     finally:
         eng.stop()
     assert not tracer.shipping  # the engine started it, the engine stopped it
+    # the clock lost none of the thread's time over those steps, and every
+    # request's four parts are its time a token
+    ss = eng.stats()["step_seconds"]
+    assert ss["steps"] >= 2000 and abs(sum(ss["phases"].values()) - ss["uptime"]) < 1e-3
+    requests = [e["args"] for e in _spans(tracer, "serve.request")]
+    assert len(requests) >= 60 and all(a["tpot_ms"] is not None for a in requests)
+    for a in requests:
+        assert abs(sum(a[f"tpot_{part}_ms"] for part in TPOT_PARTS) - a["tpot_ms"]) < 0.001, a
     st = tracer.stats()
     assert st["dropped"] == 0
     assert len(_spans(tracer, "serve.step")) == eng.stats()["step_seconds"]["steps"] >= 2000
