@@ -7,11 +7,23 @@ and reads them back in the backward pass — ~1 GiB per step at d2048/s1024/b8
 — which is pure bandwidth waste on a bandwidth-bound chip (BASELINE.md: the
 bs16 step *regresses* because of it).  Here the token dimension is scanned
 in chunks: each chunk computes its logits tile in bf16 on the MXU, reduces
-to per-token loss in f32, and the tile dies in VMEM/registers.
-``jax.checkpoint`` on the chunk body makes the backward pass recompute the
-tile instead of storing it, so the only HBM traffic is x, W, and the scan
-carry.  The extra recompute is one lm_head matmul (<5% of model FLOPs); the
-saved traffic is the whole logits tensor, twice.
+to per-token loss in f32, and the tile dies with its chunk.
+
+Nothing is recomputed for the backward pass and no tile is kept for it.  The
+loss is a mean over valid tokens, so ``d loss / d logits = (softmax - onehot)
+* valid / count`` needs nothing the backward pass brings but one scalar (the
+loss's cotangent; ``count`` comes from ``targets`` before the scan).  So the
+FORWARD scan, while a chunk's float32 tile is alive, forms ``dlogits`` and
+the two transposed products: ``dx_c = dlogits @ W.T`` stacked as the scan's
+output, ``dk += x_c.T @ dlogits`` in a float32 carry.  That is three
+vocabulary-wide products a chunk, which is what the mathematics needs
+(``jax.checkpoint`` on the chunk body would run four: the logits a second
+time in the backward scan, 1.1 TFLOP a chunk of 4,096 at d4096/V32k).  What the scan
+keeps for the backward pass is ``dx`` (``[tokens, d]``, x's dtype) and ``dk``
+(``[d, vocab]``, the kernel's dtype), from the forward pass's last act to the
+backward pass's first; the backward rule multiplies both by the cotangent.
+Called without a gradient (validation) the scan is one product a chunk and
+keeps nothing.
 
 The reference has no analog (loss math lives in user pytorch code); this is
 TPU-native design per SURVEY §7 hard-part (e).
@@ -110,6 +122,65 @@ def _tile_ce16_bwd(res, g):
 _tile_ce_bf16_residual.defvjp(_tile_ce16_fwd, _tile_ce16_bwd)
 
 
+# ---------------------------------------------------------------------------
+# chunked scan: a chunk's dlogits, dx and dk are made in the FORWARD scan,
+# while its float32 logits tile is alive, and the backward rule only scales
+# them by the loss's cotangent (the module docstring has the why).
+# ---------------------------------------------------------------------------
+
+
+def _valid_count(tgt: jax.Array) -> jax.Array:
+    return jnp.maximum((tgt >= 0).sum().astype(jnp.float32), 1.0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _scan_ce(x, kernel, tgt, compute_dtype):
+    """Mean loss over ``x [chunks, chunk, d]``, ``tgt [chunks, chunk]``.  Not
+    differentiated (validation), it is one product a chunk and keeps nothing."""
+
+    def step(loss_sum, chunk):
+        xs, ts = chunk
+        return loss_sum + _chunk_loss(xs, kernel, ts, compute_dtype)[0], None
+
+    loss_sum, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32), (x, tgt))
+    return loss_sum / _valid_count(tgt)
+
+
+def _scan_ce_fwd(x, kernel, tgt, compute_dtype):
+    count = _valid_count(tgt)
+    inv_count = 1.0 / count
+    k_c = kernel.astype(compute_dtype)
+
+    def step(carry, chunk):
+        loss_sum, dk = carry
+        xs, ts = chunk
+        s, _, logits, lse = _chunk_loss(xs, kernel, ts, compute_dtype, return_internals=True)
+        # elementwise from the float32 tile (an iota compare, no scatter), so
+        # nothing tile-sized is written but dlogits in the compute dtype: the
+        # rounding the transposed products give their operand in one pass
+        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        dlogits = jnp.exp(logits - lse[:, None]) - (cols == ts[:, None]).astype(jnp.float32)
+        dlogits = jnp.where((ts >= 0)[:, None], dlogits * inv_count, 0.0).astype(compute_dtype)
+        dx = jnp.dot(dlogits, k_c.T, preferred_element_type=jnp.float32).astype(xs.dtype)
+        dk = dk + jnp.dot(xs.astype(compute_dtype).T, dlogits, preferred_element_type=jnp.float32)
+        return (loss_sum + s, dk), dx
+
+    init = (jnp.zeros((), jnp.float32), jnp.zeros(kernel.shape, jnp.float32))
+    (loss_sum, dk), dx = jax.lax.scan(step, init, (x, tgt))
+    return loss_sum / count, (dx, dk.astype(kernel.dtype))
+
+
+def _scan_ce_bwd(compute_dtype, res, g):
+    del compute_dtype
+    dx, dk = res
+    # a custom_vjp's backward rule is traced outside the caller's scope
+    with jax.named_scope("loss.ce"):
+        return (g * dx).astype(dx.dtype), (g * dk).astype(dk.dtype), None
+
+
+_scan_ce.defvjp(_scan_ce_fwd, _scan_ce_bwd)
+
+
 @jax.named_scope("loss.ce")
 def fused_cross_entropy(
     hidden: jax.Array,            # [batch, seq, d] (or [tokens, d])
@@ -131,9 +202,22 @@ def fused_cross_entropy(
       autodiff keeps the f32 logits tile as a backward residual (no
       recompute) — fastest when that residual fits (measured +1.2 MFU pts
       at d2048/V32k/8k tokens on v5e);
-    - chunked scan (``chunk_size=N``): ``jax.checkpoint`` per chunk, so NO
-      logits tensor survives to the backward pass — the long-context /
-      huge-batch mode (caps live memory at chunk x vocab).
+    - chunked scan (``chunk_size=N``): NO logits tile outlives its chunk —
+      the long-context / huge-batch mode (caps live memory at chunk x vocab).
+      Under differentiation the forward scan makes a chunk's ``dlogits``,
+      ``dx`` and ``dk`` while its tile is alive (three vocabulary-wide
+      products a chunk, none in the backward pass, nothing recomputed) and
+      keeps ``dx`` (x's shape and dtype) and ``dk`` (the kernel's shape
+      and dtype) for the backward rule, which scales them by the loss's
+      cotangent ``g``.  Same arithmetic as autodiff of the chunk body:
+      bf16 products with f32 accumulation, ``softmax`` from the f32 tile,
+      ``dlogits`` rounded to the compute dtype as the MXU rounds a float32
+      operand in its one pass, chunks of ``dk`` summed in float32.  The one
+      new rounding: ``g * dx`` (and ``g * dk`` under a kernel that is not
+      float32) is rounded to its dtype a second time when ``g`` is not a
+      power of two (``g`` is 1 where the loss is what is differentiated).
+      A caller that freezes the head (no gradient asked for the kernel)
+      pays two products: ``jit`` prunes the ``dk`` carry.
 
     ``chunk_size=None`` picks by the PER-SHARD f32 residual size
     (``batch_shards`` = product of batch-sharding mesh axes: under dp the
@@ -158,14 +242,14 @@ def fused_cross_entropy(
         bytes_per = 2 if bf16_residual else 4
         tile_bytes = n * vocab * bytes_per // max(batch_shards, 1)
         # measured on v5e (d2048/L8/V32k): 1GB residual (8k tokens) is
-        # fastest; 2GB (16k tokens) loses to the scan's remat
+        # fastest; 2GB (16k tokens) loses to the scan
         chunk_size = 0 if tile_bytes <= (3 << 29) else 4096
 
     if chunk_size <= 0:
-        # single-tile is an explicit opt-in (or auto pick): no remat, the
-        # f32 logits tile survives as a backward residual.  An explicit
-        # chunk_size >= n still runs the remat'd scan with one chunk —
-        # callers who asked for chunking asked for the memory guarantee.
+        # single-tile is an explicit opt-in (or auto pick): the f32 logits
+        # tile survives as a backward residual.  An explicit chunk_size >= n
+        # still runs the scan with one chunk — callers who asked for
+        # chunking asked for the memory guarantee.
         if bf16_residual:
             loss_sum, count = _tile_ce_bf16_residual(x, kernel, tgt)
         else:
@@ -181,20 +265,7 @@ def fused_cross_entropy(
     x = x.reshape(num_chunks, chunk_size, d)
     tgt = tgt.reshape(num_chunks, chunk_size)
 
-    body = jax.checkpoint(
-        partial(_chunk_loss, compute_dtype=compute_dtype), prevent_cse=False
-    )
-
-    def scan_step(carry, chunk):
-        loss_sum, count = carry
-        xs, ts = chunk
-        s, c = body(xs, kernel, ts)
-        return (loss_sum + s, count + c), None
-
-    (loss_sum, count), _ = jax.lax.scan(
-        scan_step, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), (x, tgt)
-    )
-    return loss_sum / jnp.maximum(count, 1.0)
+    return _scan_ce(x, kernel, tgt, compute_dtype)
 
 
 def naive_cross_entropy(

@@ -345,13 +345,15 @@ def _latent_experts():
 def _programs(which, tmp_path):
     if which == "train.dense":
         yield "step", _step_text(tmp_path, _TRAIN_HP)
+    elif which == "train.dense.chunked_ce":  # the scan that makes dx and dk beside a chunk's logits (64 tokens a step)
+        yield "step", _step_text(tmp_path, {**_TRAIN_HP, "ce_chunk": 16})
     elif which == "train.routed_experts":
         yield "step", _step_text(tmp_path, {**_TRAIN_HP, **_EXPERTS_HP})
     else:
         yield from _serve_texts(_gqa() if which == "serve.gqa" else _latent_experts())
 
 
-@pytest.mark.parametrize("which", ["train.dense", "train.routed_experts", "serve.gqa", "serve.latent_experts"])
+@pytest.mark.parametrize("which", ["train.dense", "train.dense.chunked_ce", "train.routed_experts", "serve.gqa", "serve.latent_experts"])
 def test_the_programs_stay_scoped(which, tmp_path):
     """Of the instructions that do work (all but parameters, constants,
     tuples and copies), at least 95 % by count fall under a scope; and each
@@ -365,7 +367,8 @@ def test_the_programs_stay_scoped(which, tmp_path):
                       "serve.attn.out", "serve.mlp", "serve.head"},
         "serve.latent_experts": {"serve.embed", "serve.norm", "serve.kv.write", "serve.mla", "serve.mla.attend", "serve.mlp",
                                  "serve.moe.route", "serve.moe.experts", "serve.moe.shared", "serve.head"},
-    }[which]
+    }
+    want = want[which.removesuffix(".chunked_ce")]
     for program, text in _programs(which, tmp_path):
         found = cc.read_program_scopes(text)
         assert want <= set(found.scopes), (program, sorted(want - set(found.scopes)))

@@ -189,68 +189,195 @@ def test_ring_falls_back_without_seq_axis(devices8):
 # ---- fused cross-entropy ---------------------------------------------------
 
 
-def test_fused_cross_entropy_matches_naive():
+def _ce_inputs(seed, b, s, d, v, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    hidden = jnp.asarray(rng.standard_normal((b, s, d)), dtype)
+    kernel = jnp.asarray(rng.standard_normal((d, v)) * 0.1, jnp.float32)
+    targets = jnp.asarray(rng.integers(0, v, (b, s)), jnp.int32)
+    return hidden, kernel, targets
+
+
+def _dots_by_scan(jaxpr, inside=None, found=None):
+    """``dot_general``s of a jaxpr: {id of the scan equation that holds it
+    (None outside any scan): how many}, through every nested jaxpr."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found[inside] = found.get(inside, 0) + 1
+        here = id(eqn) if eqn.primitive.name == "scan" and inside is None else inside
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dots_by_scan(sub, here, found)
+    return found
+
+
+def _ce_against_naive(hidden, kernel, targets, chunk, *, scale=lambda loss: loss, kernel_of=lambda k: k):
+    """Loss, dx and dk of the chunked mode at float32 compute against the
+    materialized form, through ``scale`` (the cotangent the scan's backward
+    rule is handed) and ``kernel_of`` (what the head makes of its parameter)."""
+    from determined_tpu.ops.cross_entropy import fused_cross_entropy, naive_cross_entropy
+
+    fused = lambda h, k: scale(fused_cross_entropy(h, kernel_of(k), targets, chunk_size=chunk, compute_dtype=jnp.float32))  # noqa: E731
+    naive = lambda h, k: scale(naive_cross_entropy(h, kernel_of(k), targets))  # noqa: E731
+    lf, gf = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(hidden, kernel)
+    ln, gn = jax.jit(jax.value_and_grad(naive, argnums=(0, 1)))(hidden, kernel)
+    np.testing.assert_allclose(np.asarray(lf), np.asarray(ln), rtol=1e-5)
+    for a, e in zip(gf, gn):
+        assert a.shape == e.shape and a.dtype == e.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-5 * float(jnp.abs(e).max()) + 1e-9, rtol=1e-4)
+    return lf, gf
+
+
+def _case_matches_naive(devices8):
     """Value + grads of the blocked CE must match the materialized version."""
-    from determined_tpu.ops.cross_entropy import fused_cross_entropy, naive_cross_entropy
-
-    rng = np.random.default_rng(0)
-    b, s, d, v = 2, 24, 16, 97  # odd sizes force the padding path
-    hidden = jnp.asarray(rng.standard_normal((b, s, d)), jnp.float32)
-    kernel = jnp.asarray(rng.standard_normal((d, v)) * 0.1, jnp.float32)
-    targets = jnp.asarray(rng.integers(0, v, (b, s)), jnp.int32)
-
-    fused = jax.jit(
-        lambda h, k: fused_cross_entropy(
-            h, k, targets, chunk_size=16, compute_dtype=jnp.float32
-        )
-    )
-    naive = jax.jit(lambda h, k: naive_cross_entropy(h, k, targets))
-    np.testing.assert_allclose(
-        np.asarray(fused(hidden, kernel)), np.asarray(naive(hidden, kernel)), rtol=1e-5
-    )
-    gf = jax.jit(jax.grad(lambda h, k: fused_cross_entropy(
-        h, k, targets, chunk_size=16, compute_dtype=jnp.float32), argnums=(0, 1)))
-    gn = jax.jit(jax.grad(lambda h, k: naive_cross_entropy(h, k, targets), argnums=(0, 1)))
-    for a, e in zip(gf(hidden, kernel), gn(hidden, kernel)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-5, rtol=1e-4)
+    _ce_against_naive(*_ce_inputs(0, 2, 24, 16, 97), 16)  # odd sizes force the padding path
 
 
-def test_fused_cross_entropy_ignores_masked_tokens():
-    from determined_tpu.ops.cross_entropy import fused_cross_entropy, naive_cross_entropy
-
-    rng = np.random.default_rng(1)
-    d, v = 8, 33
-    hidden = jnp.asarray(rng.standard_normal((1, 12, d)), jnp.float32)
-    kernel = jnp.asarray(rng.standard_normal((d, v)) * 0.1, jnp.float32)
-    targets = jnp.asarray(rng.integers(0, v, (1, 12)), jnp.int32)
+def _case_ignores_masked_tokens(devices8):
+    hidden, kernel, targets = _ce_inputs(1, 1, 12, 8, 33)
     targets = targets.at[0, 5:].set(-1)  # half the tokens masked
-    out = fused_cross_entropy(hidden, kernel, targets, chunk_size=4,
-                              compute_dtype=jnp.float32)
-    ref = naive_cross_entropy(hidden, kernel, targets)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
+    _, (dx, _) = _ce_against_naive(hidden, kernel, targets, 4)
+    assert not np.asarray(dx)[0, 5:].any()  # a masked token's row has no gradient
 
 
-def test_fused_cross_entropy_batch_sharded(devices8):
-    """Fused CE under a dp-sharded hidden: same value as unsharded."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def _case_padded_last_chunk_and_an_all_masked_chunk(devices8):
+    """21 tokens in chunks of 8: the last chunk is 5 tokens and 3 rows of
+    padding, the middle one is masked whole (its dlogits are zeros, not
+    0 / 0), and the count is the valid tokens' alone."""
+    hidden, kernel, targets = _ce_inputs(3, 1, 21, 8, 40)
+    targets = targets.at[0, 8:16].set(-1)
+    _, (dx, _) = _ce_against_naive(hidden, kernel, targets, 8)
+    assert not np.asarray(dx)[0, 8:16].any() and np.isfinite(np.asarray(dx)).all()
+    # and no valid token at all: a loss of 0 with gradients of 0, as the materialized form gives
+    _, (dx, dk) = _ce_against_naive(hidden, kernel, jnp.full_like(targets, -1), 8)
+    assert not np.asarray(dx).any() and not np.asarray(dk).any()
 
+
+def _case_cotangent_3(devices8):
+    """``3.0 * loss``: the backward rule scales what the forward scan made."""
+    _ce_against_naive(*_ce_inputs(4, 2, 24, 16, 97), 16, scale=lambda loss: 3.0 * loss)
+
+
+def _case_cotangent_a_quarter(devices8):
+    """``loss / 4``, as gradient accumulation over four microbatches hands it."""
+    _ce_against_naive(*_ce_inputs(5, 2, 24, 16, 97), 16, scale=lambda loss: loss / 4)
+
+
+def _case_tied_kernel(devices8):
+    """``kernel = embedding.T * scale`` (a tied head): dk flows back through
+    the transpose and the scale into the embedding's gradient."""
+    hidden, kernel, targets = _ce_inputs(6, 2, 24, 16, 97)
+    _ce_against_naive(hidden, kernel.T, targets, 16, kernel_of=lambda embedding: embedding.T * 0.5)
+
+
+def _case_bf16_against_autodiff_of_the_chunk_body(devices8):
+    """bfloat16 compute against ``jax.grad`` of the un-checkpointed chunk body:
+    the same loss to the bit, and gradients within the ONE rounding the scan
+    adds off a TPU: dlogits to bfloat16 (8 bits of mantissa: half a unit in
+    the last place is 2**-9 of an element) before the two transposed products,
+    which autodiff hands a float32 cotangent (on the chip's MXU that operand
+    is rounded the same way in its one pass: PERF.md section 5, PR 54).  A
+    product sums hundreds of such errors of either sign, so the bound is on
+    the whole gradient: 2**-8 of its norm, and 2**-7 of its largest element
+    an element (test_fused_ce_bf16_residual_grads_close allows 0.1 of an
+    element and 5e-3 besides)."""
+    from determined_tpu.ops.cross_entropy import _chunk_loss, fused_cross_entropy
+
+    hidden, kernel, targets = _ce_inputs(7, 2, 64, 32, 256, jnp.bfloat16)
+    chunk = 32
+
+    def body_autodiff(h, k):
+        x, t = h.reshape(-1, chunk, h.shape[-1]), targets.reshape(-1, chunk)
+        sums = [_chunk_loss(x[i], k, t[i], jnp.bfloat16)[0] for i in range(x.shape[0])]
+        return sum(sums) / t.size
+
+    fused = lambda h, k: fused_cross_entropy(h, k, targets, chunk_size=chunk, compute_dtype=jnp.bfloat16)  # noqa: E731
+    lf, gf = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(hidden, kernel)
+    lr, gr = jax.jit(jax.value_and_grad(body_autodiff, argnums=(0, 1)))(hidden, kernel)
+    np.testing.assert_allclose(np.asarray(lf), np.asarray(lr), rtol=1e-6)
+    for a, e in zip(gf, gr):
+        assert a.dtype == e.dtype
+        a, e = np.asarray(a.astype(jnp.float32)), np.asarray(e.astype(jnp.float32))
+        assert np.linalg.norm(a - e) <= 2.0**-8 * np.linalg.norm(e)
+        assert np.abs(a - e).max() <= 2.0**-7 * np.abs(e).max()
+
+
+def _case_three_products_a_chunk_and_none_in_the_backward_pass(devices8):
+    """What engages the mechanism is a property of the lowered program: the
+    gradient program holds ONE scan with the three vocabulary-wide products
+    (logits, dx, dk) and no product anywhere else (a remat'd scan held four
+    across a forward and a backward scan); evaluation, which asks for no
+    gradient, lowers to one product a chunk."""
     from determined_tpu.ops.cross_entropy import fused_cross_entropy
-    from determined_tpu.parallel.mesh import MeshConfig, make_mesh
 
-    mesh = make_mesh(MeshConfig(data=8), devices8)
-    rng = np.random.default_rng(2)
-    b, s, d, v = 8, 16, 8, 64
-    hidden = jnp.asarray(rng.standard_normal((b, s, d)), jnp.float32)
-    kernel = jnp.asarray(rng.standard_normal((d, v)) * 0.1, jnp.float32)
-    targets = jnp.asarray(rng.integers(0, v, (b, s)), jnp.int32)
-    ref = fused_cross_entropy(hidden, kernel, targets, chunk_size=16,
-                              compute_dtype=jnp.float32)
+    hidden, kernel, targets = _ce_inputs(8, 2, 24, 16, 97)
+    f = lambda h, k: fused_cross_entropy(h, k, targets, chunk_size=16, compute_dtype=jnp.bfloat16)  # noqa: E731
+    products = lambda fn: jax.jit(fn).lower(hidden, kernel).as_text().count("stablehlo.dot_general")  # noqa: E731
+    for grad in (jax.grad(f, argnums=(0, 1)), jax.value_and_grad(lambda h, k: 3.0 * f(h, k), argnums=(0, 1))):
+        dots = _dots_by_scan(jax.make_jaxpr(grad)(hidden, kernel).jaxpr)
+        assert sorted(dots.values()) == [3] and None not in dots, dots
+        assert products(grad) == 3
+    # a frozen head: jit prunes the dk carry nobody reads, and its product with it
+    assert products(jax.grad(f, argnums=0)) == 2
+    dots = _dots_by_scan(jax.make_jaxpr(f)(hidden, kernel).jaxpr)
+    assert sorted(dots.values()) == [1] and None not in dots, dots
+    assert products(f) == 1
+
+
+def _ce_on_a_mesh(devices8, mesh_config, kernel_spec):
+    """Loss, dx and dk under a mesh against the one-device numbers; dk comes
+    out sharded as the kernel went in."""
+    from determined_tpu.ops.cross_entropy import fused_cross_entropy
+
+    mesh = make_mesh(mesh_config, devices8)
+    hidden, kernel, targets = _ce_inputs(2, 8, 16, 8, 64)
+    targets = targets.at[3, 4:].set(-1)
+    f = lambda h, k: fused_cross_entropy(  # noqa: E731
+        h, k, targets, chunk_size=16, compute_dtype=jnp.float32, batch_shards=mesh.shape["data"])
+    ref_l, ref_g = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(hidden, kernel)
     hs = jax.device_put(hidden, NamedSharding(mesh, P("data")))
-    ks = jax.device_put(kernel, NamedSharding(mesh, P()))
+    ks = jax.device_put(kernel, NamedSharding(mesh, kernel_spec))
     with mesh:
-        out = jax.jit(lambda h, k: fused_cross_entropy(
-            h, k, targets, chunk_size=16, compute_dtype=jnp.float32))(hs, ks)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
+        out_l, out_g = jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(hs, ks)
+    np.testing.assert_allclose(np.asarray(out_l), np.asarray(ref_l), rtol=1e-5)
+    for a, e in zip(out_g, ref_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-6, rtol=1e-4)
+    assert out_g[1].sharding.is_equivalent_to(ks.sharding, kernel.ndim), out_g[1].sharding
+
+
+def _case_batch_sharded(devices8):
+    """Fused CE under a dp-sharded hidden: same value and gradients as unsharded."""
+    _ce_on_a_mesh(devices8, MeshConfig(data=8), P())
+
+
+def _case_vocabulary_sharded(devices8):
+    """The kernel sharded over ``tensor`` by its vocabulary, hidden over
+    ``data``: the log-sum-exp is summed over the shards a chunk, dk stays
+    vocabulary-sharded and is summed over the batch axis."""
+    _ce_on_a_mesh(devices8, MeshConfig(data=2, tensor=4), P(None, "tensor"))
+
+
+_CHUNKED_CE_CASES = [
+    _case_matches_naive,
+    _case_ignores_masked_tokens,
+    _case_batch_sharded,
+    _case_vocabulary_sharded,
+    _case_padded_last_chunk_and_an_all_masked_chunk,
+    _case_cotangent_3,
+    _case_cotangent_a_quarter,
+    _case_tied_kernel,
+    _case_bf16_against_autodiff_of_the_chunk_body,
+    _case_three_products_a_chunk_and_none_in_the_backward_pass,
+]
+
+
+@pytest.mark.parametrize("case", _CHUNKED_CE_CASES, ids=lambda c: c.__name__[len("_case_"):])
+def test_fused_cross_entropy_chunked(case, devices8):
+    """The chunked mode of ``fused_cross_entropy``, whose forward scan makes
+    dx and dk beside a chunk's logits (ops/cross_entropy.py ``_scan_ce``)."""
+    case(devices8)
 
 
 def test_fused_ce_bf16_residual_grads_close():

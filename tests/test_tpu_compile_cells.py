@@ -553,13 +553,23 @@ def _train_step_compiled(one, cell_name: str, batch=None):
     return jax.jit(step, donate_argnums=(0, 1)).lower(jax.tree.map(on_chip, params), jax.tree.map(on_chip, opt_state), tokens).compile()
 
 
+def _loss_products(text: str) -> list:
+    """The products (XLA's ``convolution``) a compiled step holds under the
+    scope ``loss.ce``, by their results' shapes."""
+    return re.findall(r"= (\w+\[[\d,]+\])\S* convolution\(.*op_name=\"[^\"]*loss\.ce[^\"]*\"", text)
+
+
 def test_the_zaya_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_devices):
     """ZAYA1-8B's cell: five CCA layers at 8 over 2 heads of 128 and 8,192
     keys, the MLP router, top-1 into 8 held experts of 2048 x 2048, a tied head
     of 32,784 rows (16 x 2,049: no multiple of 128) through fused CE, fused
     AdamW over 601,744,730 parameters, at the configuration's batch: the
     flash kernels forward and backward a layer, the grouped products, the
-    sweeps; state and scratch inside the chip's 15.75 GiB."""
+    sweeps; state and scratch inside the chip's 15.75 GiB.  Since PR 54 the
+    fused CE's scan makes dx and dk beside a chunk's logits and keeps them
+    (96 and 256 MiB) where a remat'd scan kept the hidden rows: 6.73 GiB of
+    state + 8.57 of scratch = 15.29 GiB at three sequences (15.31 before),
+    with three products under ``loss.ce`` where four ran."""
     compiled = _train_step_compiled(SingleDeviceSharding(tpu_devices[0]), "train-zaya1-8b-l5-ep2-seq8k")
     mem, text = compiled.memory_analysis(), compiled.as_text()
     state = mem.argument_size_in_bytes
@@ -567,4 +577,25 @@ def test_the_zaya_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_
     assert state + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes < 15.75 * 2**30
     # a layer: flash forward + its two backward kernels, 3 + 6 grouped products and the rows' movements; the sweeps on top
     assert _kernels(text) >= 5 * (3 + 9)
+    assert len(_loss_products(text)) == 3, _loss_products(text)             # logits, dk, dx (a remat'd scan: the logits twice)
     print("args", state, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes, "kernels", _kernels(text))
+
+
+def test_the_mistral_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_devices):
+    """Mistral-7B-v0.3's cell: two layers at 32 over 8 heads of 128, an
+    untied head of 32,768 rows through the fused CE's scan (a tile of 4 x
+    4,096 x 32,768 float32 is 2 GiB a step: over the 1.6 GB at which it takes
+    the scan), fused AdamW, at the configuration's four sequences.  The scan
+    makes dx and dk beside a chunk's logits and keeps them (128 and 512 MiB)
+    from the forward pass's end to the backward pass's start: 7.88 GiB of
+    state + 6.21 of scratch = 14.08 GiB of the chip's 15.75 (14.10 with the
+    remat'd scan; PR 23's compile read 14.45), and the compiled text holds
+    three products under ``loss.ce`` where four ran."""
+    compiled = _train_step_compiled(SingleDeviceSharding(tpu_devices[0]), "train-mistral7b-l2-seq4k")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    state = mem.argument_size_in_bytes
+    total = state + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print("args", state, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes, "total GiB", total / 2**30, "kernels", _kernels(text))
+    assert total < 15.75 * 2**30
+    assert _kernels(text) >= 2 * 3                                          # a layer: flash forward + its two backward kernels
+    assert len(_loss_products(text)) == 3, _loss_products(text)             # logits, dk, dx
