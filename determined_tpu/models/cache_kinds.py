@@ -229,13 +229,19 @@ def _paged_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], 
     return (kv_cache_shape(cfg, sizes.num_blocks, sizes.block_size),) * (1 if cfg.latent else 2)
 
 
+def _rows_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
+    """``rows_per_token``: the rows a token owns in the pool over all layers, said
+    where a block has more than one attention sublayer (``shortcut_block``: two a block)."""
+    return {"rows_per_token": cfg.paged_layers} if cfg.attn_sublayers > 1 else {}
+
+
 def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``attn_products``: what a tile of the GQA decode kernel multiplies at this
     model's heads (``ops/paged_attention.py``); absent for latent layers, whose
     heads all share a row, and where no layer reads K and V."""
     if cfg.latent or len(cfg.retention_layers) == cfg.n_layers:
         return {}
-    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads)}
+    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads), **_rows_report(cfg)}
 
 
 def _ring_step(cfg: TransformerConfig, rows: Rows, cache: Dict[str, jax.Array], table: bool = False):
@@ -653,7 +659,7 @@ class CacheKind:
     table: Callable
     wide: Optional[Callable]
     no_prefix_cache: Optional[str] = None
-    #: the subtree of a block whose leaves its mixer reads
+    #: the subtree of a block whose leaves its mixer reads (a block's second attention sublayer: ``<params>_1``)
     params: str = "attn"
     #: what a decode step counts for it, by name, and ``count(cfg, active [b], pos [b])`` -> float32, one each
     counters: Tuple[str, ...] = ()
@@ -669,7 +675,9 @@ class CacheKind:
     setup: Callable = lambda cfg, sizes: {}
 
     def layers(self, cfg: TransformerConfig) -> Tuple[int, ...]:
-        """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its arrays."""
+        """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its
+        arrays, or with ``cfg.attn_sublayers`` attention sublayers a block the rows ``j * attn_sublayers ..``, one
+        a sublayer (:func:`layer_kinds`)."""
         if cfg.latent != self.latent:
             return ()
         return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) in self.layer_types)
@@ -710,6 +718,7 @@ PAGED_LATENT = CacheKind(
     walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk))),
     table=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows))),
     wide=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_local(cfg)),
+    report=_rows_report, setup=_rows_report,
 )
 
 STATE_SLOT = CacheKind(
@@ -771,10 +780,15 @@ def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:
     return tuple(kind for kind in CACHE_KINDS if kind.layers(cfg))
 
 
-def layer_kinds(cfg: TransformerConfig, i: int) -> Tuple[Tuple[CacheKind, int], ...]:
-    """Layer ``i``'s kinds in the table's order, each with the layer's place among the layers of that kind: its
-    row in the kind's arrays."""
-    return tuple((kind, kind.layers(cfg).index(i)) for kind in CACHE_KINDS if i in kind.layers(cfg))
+def layer_kinds(cfg: TransformerConfig, i: int, sub: int = 0) -> Tuple[Tuple[CacheKind, int, str], ...]:
+    """The kinds of layer ``i``'s attention sublayer ``sub`` (of ``cfg.attn_sublayers``) in the table's order, each
+    with the sublayer's row in the kind's arrays (the layer's place among the layers of that kind, times the
+    sublayers a block, and ``sub``) and the subtree of the block whose leaves its mixer reads: the kind's
+    ``params``, for a block's second sublayer ``<params>_1``.  One kind, two rows, two subtrees."""
+    return tuple(
+        (kind, kind.layers(cfg).index(i) * cfg.attn_sublayers + sub, kind.params + (f"_{sub}" if sub else ""))
+        for kind in CACHE_KINDS if i in kind.layers(cfg)
+    )
 
 
 def pool_block_size(cfg: TransformerConfig, cache: Dict[str, jax.Array]) -> Optional[int]:

@@ -377,9 +377,13 @@ class RoutedExperts(nn.Module):
     ``intermediates/live_rows`` (rows of the buffer in tiles a group owns: what
     the kernels touch, the held picks and each group's padding to a tile).
 
-    Every router but ``"mlp"`` renormalises its picks' weights to a constant
-    sum: at ``top_k`` 1 the one weight is that constant, no gradient reaches
-    the router through the experts, and the layer refuses the pair by name.
+    Every router but ``"mlp"`` and ``"softmax_bias"`` renormalises its picks'
+    weights to a constant sum: at ``top_k`` 1 the one weight is that constant, no
+    gradient reaches the router through the experts, and the layer refuses the
+    pair by name.  Under ``"softmax_bias"`` (:func:`route_softmax_bias`) the
+    router has ``zero_experts`` more outputs than experts: a pick on one of
+    those adds ``w x`` (:func:`_identity_part`) and is never ``held``, so it
+    owns no row of the buffer.
     Under ``"mlp"`` the call takes the layer before's router state and returns
     ``(y, aux, state)``: :func:`route_mlp`.
     """
@@ -406,6 +410,9 @@ class RoutedExperts(nn.Module):
     shared_experts: int = 0
     # "mean": the shared experts' sum over their number (Cohere's "average")
     shared_combine: str = "sum"
+    # identity experts, the router's outputs ``num_experts ..``: a pick there adds
+    # ``w x`` and owns no row (:func:`_identity_part`); under "softmax_bias"
+    zero_experts: int = 0
     param_dtype: Any = jnp.float32
 
     @nn.compact
@@ -417,6 +424,7 @@ class RoutedExperts(nn.Module):
 
         b, s, d = x.shape
         tokens, e, k = b * s, self.num_experts, self.top_k
+        outputs = e + self.zero_experts  # the router's width: the real experts, then the identity experts
         mlp = self.router_kind == "mlp"
         if k == 1 and not mlp:
             raise ValueError(
@@ -447,14 +455,18 @@ class RoutedExperts(nn.Module):
                 for name, (shape, logical, init) in _mlp_router_shapes(d, self.router_hidden, e).items()
             }
         else:
-            router = param("router", (d, e), ("embed", None))
+            router = param("router", (d, outputs), ("embed", None))
             p = {"router": router}
         w_gate = param("w_gate", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_up = param("w_up", (count, d, self.d_ff), ("expert", "embed", "mlp"))
         w_down = param("w_down", (count, self.d_ff, d), ("expert", "mlp", "embed"))
 
-        if mlp or self.router_kind == "sigmoid_grouped":
-            p["router_bias"] = self.param("router_bias", nn.initializers.normal(0.01), (e,), jnp.float32)
+        if mlp or self.router_kind in ("sigmoid_grouped", "softmax_bias"):
+            # a fresh bias is small against the scores it is added to: 0.01 beside a sigmoid's 0.5 or a few experts'
+            # probabilities; a softmax over hundreds of outputs scores 1 / outputs on average, and a bias of 0.01
+            # would pick the same outputs for every token whatever the router says: a quarter of the mean score
+            spread = 0.25 / outputs if self.router_kind == "softmax_bias" else 0.01
+            p["router_bias"] = self.param("router_bias", nn.initializers.normal(spread), (outputs,), jnp.float32)
         if self.shared_experts:
             wide = self.shared_experts * self.d_ff
             p["shared_w_gate"] = param("shared_w_gate", (d, wide), ("embed", "mlp"))
@@ -498,6 +510,9 @@ class RoutedExperts(nn.Module):
         if self.expert_axis_name is not None:
             with jax.named_scope("moe.combine"):
                 y = jax.lax.psum(y, self.expert_axis_name)
+        if self.zero_experts:
+            with jax.named_scope("moe.identity"):
+                y = y + _identity_part(weights, picks, e, outputs, xf)[0].astype(y.dtype)
         if self.shared_experts:
             with jax.named_scope("moe.shared"):
                 y = y + _shared_experts(p, xf.astype(self.dtype), self.shared_experts, self.shared_combine).astype(y.dtype)
@@ -579,6 +594,27 @@ def route_sigmoid(logits: jax.Array, *, top_k: int) -> Tuple[jax.Array, jax.Arra
     return top / jnp.sum(top, axis=-1, keepdims=True), picks
 
 
+def route_softmax_bias(logits: jax.Array, bias: jax.Array, *, top_k: int, scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """The router LongCat-Flash publishes, on float32 ``logits [T, E + Z]``
+    (real experts, then identity experts): scores ``softmax(logits)`` over all
+    outputs; the ``top_k`` largest ``scores + bias`` are the picks (the bias
+    picks and never weighs); ``weights = scores[picks] * scaling``, NOT
+    renormalised.  Returns (weights [T, k] float32, picks [T, k])."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, picks = jax.lax.top_k(scores + bias.astype(jnp.float32)[None, :], top_k)
+    return jnp.take_along_axis(scores, picks, axis=1) * scaling, picks
+
+
+def _identity_part(weights: jax.Array, picks: jax.Array, first: int, end: int, xf: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """What a token's picks on identity experts (the router's outputs ``first ..
+    end - 1``) add: ``x`` times the sum of those picks' weights, float32 ``[T,
+    d]``; and which picks those are ``[T, k]``.  No row of any buffer, no
+    matrix: the experts that cost nothing."""
+    zero = (picks >= first) & (picks < end)
+    weight = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1)
+    return weight[:, None] * xf.astype(jnp.float32), zero
+
+
 def _route(
     p: Any, xf: jax.Array, *, kind: str, top_k: int, n_group: int, topk_group: int, scaling: float
 ) -> Tuple[jax.Array, jax.Array]:
@@ -594,6 +630,8 @@ def _route(
         )
     if kind == "sigmoid":
         return route_sigmoid(logits, top_k=top_k)
+    if kind == "softmax_bias":
+        return route_softmax_bias(logits, p["router_bias"], top_k=top_k, scaling=scaling)
     top_p, picks = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return top_p / jnp.sum(top_p, axis=-1, keepdims=True), picks
 
@@ -622,7 +660,8 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
     """``RoutedExperts``' forward over its unboxed leaves ``p``, for the
     serving programs (``models/serving.py _serve_layer``; ``cfg`` is the
     ``TransformerConfig``): ``x [b, s, d]`` -> (``y [b, s, d]``, (picks that
-    landed on a held expert, held experts with at least one row)).  Tokens
+    landed on a held expert, held experts with at least one row; with identity
+    experts also the picks that landed on one: ``w x`` each, no row)).  Tokens
     that ``live [b, s]`` does not mark (idle lanes, a prompt's padding) take no
     expert's rows.  No backward pass follows, so an expert without rows owns
     no tile of the buffer and its matrices are not read (``_sorted_rows``)."""
@@ -637,8 +676,9 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
             p, xf, kind=cfg.moe_router, top_k=cfg.moe_top_k, n_group=cfg.moe_n_group,
             topk_group=cfg.moe_topk_group, scaling=cfg.moe_routed_scaling,
         )
+        outputs = cfg.moe_experts + cfg.moe_zero_experts
         if live is not None:
-            picks = jnp.where(live.reshape(-1, 1), picks, cfg.moe_experts)  # no expert: never held
+            picks = jnp.where(live.reshape(-1, 1), picks, outputs)  # no expert: never held, and no identity expert
         rows = _sorted_rows(picks, first, count, serving=True)
     with jax.named_scope("serve.moe.experts"):
         layout = rows.layout
@@ -653,4 +693,9 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
         with jax.named_scope("serve.moe.shared"):
             y = y + _shared_experts(p, xf, cfg.moe_shared_experts, cfg.moe_shared_combine).astype(y.dtype)
     counted = (jnp.sum(rows.load), jnp.sum(rows.load > 0))
+    if cfg.moe_zero_experts:
+        with jax.named_scope("serve.moe.identity"):
+            added, zero = _identity_part(weights, picks, cfg.moe_experts, outputs, xf)
+            y = y + added.astype(y.dtype)
+        counted += (jnp.sum(zero),)
     return y.astype(x.dtype).reshape(b, s, d), counted

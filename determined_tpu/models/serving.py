@@ -115,13 +115,16 @@ def _head(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array, row: Opt
 #: each summed over the expert layers: picks that landed on a held expert, and
 #: held experts that got at least one row (whose matrices the step had to read)
 SERVE_COUNTERS = ("serve.moe.held_picks", "serve.moe.experts_hit")
+#: and, where the router has identity experts (``moe_zero_experts``), the picks
+#: of live tokens that landed on one: each adds ``w x`` and costs no row
+ZERO_PICKS = "serve.moe.zero_picks"
 
 
 def serve_counters(cfg: TransformerConfig) -> Tuple[str, ...]:
     """The names of what ``transformer_decode(counters=True)`` counts, in the
     row's order: each cache kind's, in the table's order, then the experts'."""
     kinds = sum((kind.counters for kind in cache_kinds(cfg)), ())
-    return kinds + (SERVE_COUNTERS if cfg.moe_experts else ())
+    return kinds + (SERVE_COUNTERS if cfg.moe_experts else ()) + ((ZERO_PICKS,) if cfg.moe_zero_experts else ())
 
 
 def serve_gauges(cfg: TransformerConfig) -> Tuple[str, ...]:
@@ -141,25 +144,42 @@ def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=Non
     a layer of two kinds); then the MLP or the
     experts held here (which count the tokens ``live`` [b, s] marks) and its
     residual; under ``parallel_block`` the MLP or experts read the one norm
-    attention read.
+    attention read.  Under ``shortcut_block`` the block has two attention
+    sublayers (two rows of the kind's arrays, two subtrees) and two dense MLPs;
+    its experts read the first sublayer's second norm and join the stream after
+    the second MLP.
     Returns (x, cache, what an expert layer counted or None)."""
-    h = _norm_apply(cfg, x, blk["ln1"]["scale"])
-    for kind, j in layer_kinds(cfg, i):
-        x, cache = mixers[kind.name](blk[kind.params], x, h, cache, j)
-    if not cfg.parallel_block:  # else the one norm: what attention read
-        h = _norm_apply(cfg, x, blk["ln2"]["scale"])
-    if not cfg.use_moe(i):
+
+    def attend(sub: int, x, cache):
+        """Attention sublayer ``sub``: (the stream with it, the norm its MLP or experts read, the cache)."""
+        tail = f"_{sub}" if sub else ""
+        h = _norm_apply(cfg, x, blk["ln1" + tail]["scale"])
+        for kind, j, subtree in layer_kinds(cfg, i, sub):
+            x, cache = mixers[kind.name](blk[subtree], x, h, cache, j)
+        if not cfg.parallel_block:  # else the one norm: what attention read
+            h = _norm_apply(cfg, x, blk["ln2" + tail]["scale"])
+        return x, h, cache
+
+    def mlp(name: str, x, h):
         with jax.named_scope("serve.mlp"):
-            return x + _mlp_apply(blk["mlp"], h, cfg.dtype, cfg.mlp_multipliers), cache, None
+            return x + _mlp_apply(blk[name], h, cfg.dtype, cfg.mlp_multipliers)
+
+    x, h, cache = attend(0, x, cache)
+    if not cfg.use_moe(i):
+        return mlp("mlp", x, h), cache, None
     from determined_tpu.models.moe import serve_routed_experts
 
     y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
+    if cfg.shortcut_block:
+        x, h, cache = attend(1, mlp("mlp", x, h), cache)
+        x = mlp("mlp_1", x, h)
     return x + y, cache, counted
 
 
 def _serve_layers(cfg, params, x, mixers: Mapping[str, Callable], cache, live=None):
-    """Every layer; the last value is SERVE_COUNTERS' sums over the expert
-    layers, [2] float32, or None for a model without them."""
+    """Every layer; the last value is what the expert layers counted
+    (``SERVE_COUNTERS``, and ``ZERO_PICKS`` where there are identity experts),
+    summed over them, float32, or None for a model without them."""
     counted = []
     for i in range(cfg.n_layers):
         x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, mixers, cache, live)

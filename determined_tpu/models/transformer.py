@@ -21,6 +21,9 @@ MeshConfig alone, with:
   device holds (models/moe.py); under the "mlp" router a layer hands the next
   its router's state, a second value beside the residual stream;
 - ``residual_scaling``: a learned scale and bias on both terms of each merge;
+- ``shortcut_block``: two attention sublayers and two dense MLPs a block round
+  an expert branch that joins the stream after the second MLP (LongCat-Flash's
+  double layer), with identity experts behind a biased softmax router;
 - attention dispatch: ring attention when the mesh has a "seq" axis,
   Pallas flash attention on TPU otherwise, reference for tiny seqs;
 - bf16 compute with f32 params, per-block remat for long-context memory.
@@ -128,6 +131,14 @@ class TransformerConfig:
     # shared experts' outputs combine: "sum", or "mean" (their sum over their
     # number)
     moe_shared_combine: str = "sum"
+    # "softmax_bias" (LongCat-Flash's): softmax scores over moe_experts +
+    # moe_zero_experts outputs, the top-k of scores + a selection bias (which
+    # picks and never weighs), weights the picks' scores times
+    # moe_routed_scaling, NOT renormalised.  The last moe_zero_experts outputs
+    # are identity experts: a pick there adds ``w x`` and costs no row of any
+    # buffer (``moe_experts`` stays the count of real experts, the range
+    # ``moe_experts_held`` lies in)
+    moe_zero_experts: int = 0
     # The block's norms: "rms" (no mean taken) or "layernorm" (mean and variance
     # over the features in float32, a weight and no bias), with norm_eps.
     # parallel_block: ONE norm a block, attention and the MLP or experts both
@@ -137,10 +148,18 @@ class TransformerConfig:
     # residual_scaling: both merges of a sequential block are ``(x + br) * ar
     # + (f + bf) * af`` with four learned vectors of d_model each (scales one,
     # biases zero at the start) in place of ``x + f``.
+    # shortcut_block (LongCat-Flash's shortcut-connected experts): TWO attention
+    # sublayers and two dense MLPs a block, each with its own weights and norms
+    # (``attn``, ``mlp``, ``ln1``, ``ln2`` and ``attn_1``, ``mlp_1``, ``ln1_1``,
+    # ``ln2_1``), and every block's experts read the FIRST sublayer's second norm
+    # and join the stream after the second MLP: ``a0 = x + Attn0(LN(x)); u =
+    # LN(a0); m = Experts(u); b0 = a0 + MLP0(u); a1 = b0 + Attn1(LN(b0)); b1 = a1
+    # + MLP1(LN(a1)); out = b1 + m``.  Serving keeps two rows a token a block.
     norm: str = "rms"
     norm_eps: float = 1e-6
     parallel_block: bool = False
     residual_scaling: bool = False
+    shortcut_block: bool = False
     tie_embeddings: bool = False
     logit_scale: float = 1.0
     # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
@@ -156,6 +175,10 @@ class TransformerConfig:
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
     softmax_scale: Optional[float] = None
+    # constants on the two normed latents, before their up-projections (the
+    # cached row holds the scaled one): LongCat-Flash's (d_model / rank) ** 0.5
+    q_latent_scale: float = 1.0
+    kv_latent_scale: float = 1.0
     # A cca layer (``CompressedAttention``): q and k pass a depthwise causal
     # convolution over cca_time0 tokens and one over cca_time1 tokens that
     # mixes each head's channels; rotary turns the first partial_rotary_factor
@@ -275,7 +298,7 @@ class TransformerConfig:
             if layer_type not in LAYER_TYPES or dict(params).get("rope_type", "default") not in ("default", "yarn", "none"):
                 raise ValueError(f"rope_parameters: unknown layer type or rope_type in {layer_type}: {dict(params)}")
         if self.moe_top_k:
-            if not 1 <= self.moe_top_k <= self.moe_experts:
+            if not 1 <= self.moe_top_k <= self.moe_experts + self.moe_zero_experts:
                 raise ValueError(
                     f"moe_top_k={self.moe_top_k} needs 1 <= moe_top_k <= moe_experts ({self.moe_experts})"
                 )
@@ -289,8 +312,22 @@ class TransformerConfig:
                     )
         elif self.moe_experts_held is not None or self.moe_intermediate_size is not None:
             raise ValueError("moe_experts_held and moe_intermediate_size belong to moe_top_k > 0")
-        if self.moe_router not in ("softmax", "sigmoid", "sigmoid_grouped", "mlp"):
-            raise ValueError(f"moe_router is softmax, sigmoid, sigmoid_grouped or mlp (got {self.moe_router!r})")
+        if self.moe_router not in ("softmax", "softmax_bias", "sigmoid", "sigmoid_grouped", "mlp"):
+            raise ValueError(f"moe_router is softmax, softmax_bias, sigmoid, sigmoid_grouped or mlp (got {self.moe_router!r})")
+        if self.moe_zero_experts < 0 or (self.moe_zero_experts and self.moe_router != "softmax_bias"):
+            raise ValueError("moe_zero_experts (identity experts after the real ones) belong to moe_router softmax_bias")
+        if self.shortcut_block and (
+            not self.moe_top_k or self.moe_every != 1 or self.dense_prefix or self.parallel_block or self.residual_scaling
+            or self.moe_router == "mlp" or set(self.layer_types or (FULL,)) != {FULL}
+            or self.seq_axis_name is not None or self.expert_axis_name is not None
+        ):
+            raise ValueError(
+                "shortcut_block runs dropless experts in EVERY block (moe_top_k > 0, moe_every 1, no dense_prefix) of "
+                "full-attention layers, without parallel_block, residual_scaling or moe_router mlp, and outside pipeline "
+                "stages (a `seq` or `expert` axis): a stage would have to hand the expert branch on beside the stream"
+            )
+        if (self.q_latent_scale != 1.0 or self.kv_latent_scale != 1.0) and not self.latent:
+            raise ValueError("q_latent_scale and kv_latent_scale belong to latent attention (kv_lora_rank)")
         if (self.moe_router == "mlp") != (self.router_hidden_size is not None) or (
             self.moe_router == "mlp" and self.expert_axis_name is not None
         ):
@@ -392,9 +429,14 @@ class TransformerConfig:
         return self.ssm_width + 2 * self.ssm_groups * self.ssm_state
 
     @property
+    def attn_sublayers(self) -> int:
+        """Attention sublayers a block: each keeps its own row a token in the serving cache."""
+        return 2 if self.shortcut_block else 1
+
+    @property
     def paged_layers(self) -> int:
-        """How many layers keep a token's rows in the paged pool."""
-        return self.n_layers - len(self.window_layers) - len(self.retention_layers)
+        """How many rows of the paged pool a token owns: one an attention sublayer of each layer that keeps its rows there."""
+        return (self.n_layers - len(self.window_layers) - len(self.retention_layers)) * self.attn_sublayers
 
     def rope(self, layer_type: str) -> Optional["Rope"]:
         """How a layer of this type rotates q and k; None: it does not."""
@@ -979,6 +1021,7 @@ class Block(nn.Module):
                     routed_scaling=cfg.moe_routed_scaling,
                     shared_experts=cfg.moe_shared_experts,
                     shared_combine=cfg.moe_shared_combine,
+                    zero_experts=cfg.moe_zero_experts,
                     param_dtype=cfg.param_dtype,
                     router_hidden=cfg.router_hidden_size or 0,
                     norm_eps=cfg.norm_eps,
@@ -999,20 +1042,31 @@ class Block(nn.Module):
                 )(h), state
             return MLP(cfg, self.mesh, name="mlp")(h), jnp.zeros((), jnp.float32), state
 
+        def attend(name: str, h: jax.Array) -> jax.Array:
+            """What the attention sublayer ``name`` adds, from the normed input."""
+            if cfg.latent:
+                return LatentAttention(cfg, name=name)(h)
+            if self.layer_type == RETENTION:
+                return Retention(cfg, name=name)(h)
+            if self.layer_type == CCA:
+                return CompressedAttention(cfg, self.mesh, name=name)(h)
+            if self.layer_type == HYBRID:
+                # attention heads and Mamba-2 heads read the one norm side by side
+                att = Attention(cfg, self.mesh, self.layer_type, name=name)(_times(h, cfg.attention_in_multiplier))
+                return _times(att, cfg.attention_out_multiplier) + Mamba2(cfg, name="ssm")(h)
+            return Attention(cfg, self.mesh, self.layer_type, name=name)(h)
+
         h = norm("ln1")(x)
-        if cfg.latent:
-            att = LatentAttention(cfg, name="attn")(h)
-        elif self.layer_type == RETENTION:
-            att = Retention(cfg, name="attn")(h)
-        elif self.layer_type == CCA:
-            att = CompressedAttention(cfg, self.mesh, name="attn")(h)
-        elif self.layer_type == HYBRID:
-            # attention heads and Mamba-2 heads read the one norm side by side
-            att = Attention(cfg, self.mesh, self.layer_type, name="attn")(_times(h, cfg.attention_in_multiplier))
-            att = _times(att, cfg.attention_out_multiplier) + Mamba2(cfg, name="ssm")(h)
-        else:
-            att = Attention(cfg, self.mesh, self.layer_type, name="attn")(h)
-        if cfg.parallel_block:
+        att = attend("attn", h)
+        if cfg.shortcut_block:
+            # the experts read the first sublayer's second norm and join the stream after the second MLP
+            x = x + att
+            u = norm("ln2")(x)
+            y, aux, handed = ffn(u)
+            x = x + MLP(cfg, self.mesh, name="mlp")(u)
+            x = x + attend("attn_1", norm("ln1_1")(x))
+            x = x + MLP(cfg, self.mesh, name="mlp_1")(norm("ln2_1")(x)) + y
+        elif cfg.parallel_block:
             # one norm: the MLP or the experts read what attention read
             y, aux, handed = ffn(h)
             x = x + att + y
@@ -1312,7 +1366,7 @@ def kv_bytes_per_token(cfg: TransformerConfig) -> int:
     layer a token owns them only while it is inside the window; a retention
     layer caches no token: ``state_bytes_per_slot``)."""
     values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
-    return (cfg.n_layers - len(cfg.retention_layers)) * values * jnp.dtype(cfg.dtype).itemsize
+    return (cfg.n_layers - len(cfg.retention_layers)) * cfg.attn_sublayers * values * jnp.dtype(cfg.dtype).itemsize
 
 
 #: the dtype of a state a lane holds (a retention layer's and its normaliser, a Mamba-2 layer's): sums over a whole context
@@ -1367,12 +1421,13 @@ def _rms_apply(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def _latent_project(cfg, p, h, positions, rope):
-    """A latent layer's projections of the normed input ``h`` [b, s, d]."""
+    """A latent layer's projections of the normed input ``h`` [b, s, d]; both
+    latents times their scale after their norm (``c_kv`` is what serving caches)."""
     dt, r = cfg.dtype, cfg.kv_lora_rank
-    c_q = _rms_apply(h @ p["wq_a"].astype(dt), p["q_norm"])
+    c_q = _times(_rms_apply(h @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps), cfg.q_latent_scale)
     q = jnp.einsum("bsr,rhk->bhsk", c_q, p["wq_b"].astype(dt))
     kv = h @ p["wkv_a"].astype(dt)
-    c_kv = _rms_apply(kv[..., :r], p["kv_norm"])
+    c_kv = _times(_rms_apply(kv[..., :r], p["kv_norm"], cfg.norm_eps), cfg.kv_latent_scale)
     k_r = _rope(kv[:, None, :, r:], positions, rope)[:, 0]
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], _rope(q[..., cfg.qk_nope_head_dim:], positions, rope)
     return q_nope, q_rope, c_kv, k_r
@@ -1427,7 +1482,10 @@ class LMTrial(JaxTrial):
     count]; moe_aux_weight; norm (rms / layernorm) with norm_eps,
     parallel_block, tie_embeddings with logit_scale, moe_shared_combine;
     layer type cca with cca_time0, cca_time1 and partial_rotary_factor;
-    moe_router mlp with router_hidden_size; residual_scaling.
+    moe_router mlp with router_hidden_size; residual_scaling; shortcut_block
+    (two attention sublayers and two dense MLPs a block round the experts),
+    moe_router softmax_bias with moe_zero_experts (identity experts),
+    q_latent_scale and kv_latent_scale.
 
     When the context mesh has a ``pipe`` axis of size P > 1, the trial
     restructures its params into stacked pipeline stages and trains through
@@ -1527,6 +1585,7 @@ class LMTrial(JaxTrial):
             what for what, there in (
                 ("a cca layer", CCA in (layer_types or ())), ("moe_router mlp", g("moe_router", "softmax") == "mlp"),
                 ("residual_scaling", bool(g("residual_scaling", False))),
+                ("shortcut_block (its expert branch beside it)", bool(g("shortcut_block", False))),
             ) if there
         ]
         if pipe > 1 and carried:
@@ -1578,6 +1637,10 @@ class LMTrial(JaxTrial):
             moe_routed_scaling=float(g("moe_routed_scaling", 1.0)),
             moe_shared_experts=int(g("moe_shared_experts", 0)),
             moe_shared_combine=str(g("moe_shared_combine", "sum")),
+            moe_zero_experts=int(g("moe_zero_experts", 0)),
+            shortcut_block=bool(g("shortcut_block", False)),
+            q_latent_scale=float(g("q_latent_scale", 1.0)),
+            kv_latent_scale=float(g("kv_latent_scale", 1.0)),
             norm=str(g("norm", "rms")),
             norm_eps=float(g("norm_eps", 1e-6)),
             parallel_block=bool(g("parallel_block", False)),
@@ -1646,21 +1709,22 @@ class LMTrial(JaxTrial):
             )
             width = cfg.n_heads * (qk + cfg.v_head_dim) // 2
         n_params, seen = cfg.vocab_size * d, 0
-        router = d * cfg.moe_experts
+        outputs = cfg.moe_experts + cfg.moe_zero_experts
+        router = d * outputs
         if cfg.moe_router == "mlp":
             r = cfg.router_hidden_size
             router = d * r + 2 * r * r + r * cfg.moe_experts
         for i in range(cfg.n_layers):
-            n_params += attn
+            n_params += attn * cfg.attn_sublayers
             if cfg.layer_type(i) == CCA:  # the two convolutions' products
                 n_params += (cfg.n_heads + cfg.kv_heads) * cfg.head_dim * (cfg.cca_time0 + cfg.cca_time1 * cfg.head_dim)
             if cfg.use_moe(i):
                 held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
-                active = cfg.moe_top_k * held / cfg.moe_experts if cfg.moe_top_k else 2
+                active = cfg.moe_top_k * held / outputs if cfg.moe_top_k else 2
                 n_params += router + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)
-            else:
-                n_params += 3 * d * cfg.ff_dim
-            seen += min(cfg.window(cfg.layer_type(i)) or cfg.max_seq_len, cfg.max_seq_len)
+            if cfg.shortcut_block or not cfg.use_moe(i):  # a shortcut block's two dense MLPs stand beside its experts
+                n_params += cfg.attn_sublayers * 3 * d * cfg.ff_dim
+            seen += cfg.attn_sublayers * min(cfg.window(cfg.layer_type(i)) or cfg.max_seq_len, cfg.max_seq_len)
         return float(6 * n_params + 12 * seen * width)
 
     def build_model(self) -> TransformerLM:

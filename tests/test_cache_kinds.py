@@ -1,6 +1,6 @@
 """The table of cache kinds (``models/cache_kinds.py``) is where the forward,
 the kernels and the engine take what they know of a model's cache: over the
-benchmark's five serving architectures at their tiny sizes, the cache is the
+benchmark's six serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_kinds, layer_kinds
-from determined_tpu.models.serving import SERVE_COUNTERS, init_kv_cache, serve_counters
+from determined_tpu.models.serving import SERVE_COUNTERS, ZERO_PICKS, init_kv_cache, serve_counters
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import DecodeKernels, ServeEngine
 
@@ -30,6 +30,7 @@ ARCHS = {
     "cohere2_moe": ("paged_kv", "window_ring"),
     "power_retention": ("state_slot",),
     "falcon_h1": ("paged_kv", "ssm_slot"),
+    "longcat_scmoe": ("paged_latent",),
 }
 
 
@@ -49,14 +50,18 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
     cfg, params, serve_cfg, form = _tiny(arch_name)
     kinds = cache_kinds(cfg)
     assert tuple(kind.name for kind in kinds) == ARCHS[arch_name] and set(kinds) <= set(CACHE_KINDS)
-    # every layer is of one kind or of several in the table's order, at the next row of each kind's arrays
+    # every attention sublayer of a layer (one, or under shortcut_block two) is of one kind or of several in the
+    # table's order, at the next row of each kind's arrays, and reads its own subtree of the block
     rows = {kind.name: 0 for kind in kinds}
+    assert cfg.attn_sublayers == (2 if arch_name == "longcat_scmoe" else 1)
     for i in range(cfg.n_layers):
-        mine = layer_kinds(cfg, i)
-        assert mine and [kind for kind, _ in mine] == [kind for kind in CACHE_KINDS if i in kind.layers(cfg)]
-        for kind, j in mine:
-            assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j] == i
-            rows[kind.name] += 1
+        for sub in range(cfg.attn_sublayers):
+            mine = layer_kinds(cfg, i, sub)
+            assert mine and [kind for kind, _, _ in mine] == [kind for kind in CACHE_KINDS if i in kind.layers(cfg)]
+            for kind, j, subtree in mine:
+                assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j // cfg.attn_sublayers] == i
+                assert subtree == kind.params + ("_1" if sub else "") and subtree in params[f"block_{i}"]
+                rows[kind.name] += 1
     assert (len(layer_kinds(cfg, 0)) == 2) == (arch_name == "falcon_h1")
 
     # the cache: the union of the kinds' leaves, each of the kind's shape and dtype, a row a layer of the kind
@@ -64,10 +69,11 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, sizes.num_blocks, sizes.block_size, sizes.max_batch, sizes.prefill_chunk))
     want = {leaf: (shape, jnp.dtype(dtype)) for kind in kinds for leaf, shape, dtype in zip(kind.leaves, kind.shapes(cfg, sizes), kind.dtypes(cfg))}
     assert {leaf: (a.shape, a.dtype) for leaf, a in cache.items()} == want
-    assert all(want[leaf][0][0] == len(kind.layers(cfg)) for kind in kinds for leaf in kind.leaves)
+    assert all(want[leaf][0][0] == len(kind.layers(cfg)) * cfg.attn_sublayers for kind in kinds for leaf in kind.leaves)
 
     # the counters: the kinds' in the table's order, then the experts'
-    assert serve_counters(cfg) == sum((kind.counters for kind in kinds), ()) + (SERVE_COUNTERS if cfg.moe_experts else ())
+    experts = (SERVE_COUNTERS if cfg.moe_experts else ()) + ((ZERO_PICKS,) if cfg.moe_zero_experts else ())
+    assert serve_counters(cfg) == sum((kind.counters for kind in kinds), ()) + experts
 
     # the engine: prefix_cache is refused iff some kind is held by the lane, with that kind's sentence ...
     held = {BLOCKS: [k for k in kinds if k.holds == BLOCKS], LANE: [k for k in kinds if k.holds == LANE]}
@@ -107,3 +113,4 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
         said = kind.report(cfg, sizes, 0)
         assert {key: stats[key] for key in said} == said
         assert kind in kinds or said in ({}, {"window_store": {}})
+    assert stats.get("rows_per_token") == (cfg.paged_layers if cfg.attn_sublayers > 1 else None)

@@ -172,7 +172,7 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
         "wq": (48, 4, 16), "wk": (48, 2, 16), "wv": (48, 2, 16), "wg": (48, 2), "q_norm": (16,), "k_norm": (16,), "wo": (4, 16, 48),
     }
     assert cfg.retention_layers == STATE_SLOT.layers(cfg) == (0, 1) and cfg.paged_layers == 0
-    assert [layer_kinds(cfg, i) for i in range(2)] == [((STATE_SLOT, 0),), ((STATE_SLOT, 1),)]
+    assert [layer_kinds(cfg, i) for i in range(2)] == [((STATE_SLOT, 0, "attn"),), ((STATE_SLOT, 1, "attn"),)]
     got = jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(tokens))
     np.testing.assert_allclose(np.asarray(got), want, atol=5e-5)
     half = dataclasses.replace(cfg, param_dtype=jnp.bfloat16)

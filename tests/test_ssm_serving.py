@@ -252,7 +252,7 @@ def test_the_mixer_carries_a_state_and_a_tail_over_chunks_of_any_size(model, chu
 def test_a_layer_is_of_two_kinds_and_the_cache_holds_both(model):
     cfg, _, _, _ = model
     assert cache_kinds.cache_kinds(cfg) == (PAGED_KV, SSM_SLOT) and (PAGED_KV.holds, SSM_SLOT.holds) == (BLOCKS, LANE)
-    assert [layer_kinds(cfg, i) for i in range(2)] == [((PAGED_KV, 0), (SSM_SLOT, 0)), ((PAGED_KV, 1), (SSM_SLOT, 1))]
+    assert [layer_kinds(cfg, i) for i in range(2)] == [((PAGED_KV, 0, "attn"), (SSM_SLOT, 0, "ssm")), ((PAGED_KV, 1, "attn"), (SSM_SLOT, 1, "ssm"))]
     assert (PAGED_KV.params, SSM_SLOT.params) == ("attn", "ssm") and PAGED_KV.layer_types == ("full_attention", HYBRID)
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 40, 4, lanes=3, chunk_tokens=16))
     assert {k: (v.shape, str(v.dtype)) for k, v in cache.items()} == {

@@ -167,6 +167,64 @@ def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, mo
     assert len(named) == 5, scopes["serve.moe.experts"]
 
 
+# -- two latent rows a token a block, identity experts: the LongCat-Flash-Omni cell's shapes --
+
+
+def _longcat_cfg(n_layers: int):
+    from determined_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=16384, d_model=6144, n_layers=n_layers, n_heads=64, d_ff=12288, max_seq_len=5120, rope_theta=1e7,
+        norm_eps=1e-5, shortcut_block=True, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, q_latent_scale=2.0, kv_latent_scale=12.0 ** 0.5, moe_experts=512, moe_zero_experts=256, moe_every=1,
+        moe_top_k=12, moe_intermediate_size=2048, moe_experts_held=(0, 16), moe_router="softmax_bias",
+        moe_routed_scaling=6.0, param_dtype=jnp.bfloat16,
+    )
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, monkeypatch, which):
+    """The LongCat-Flash-Omni cell's programs at its widths, lanes, pool (10,240
+    blocks) and table (320 columns: 5,120 positions), bfloat16 leaves, depth cut
+    to ONE double layer: two rows of the latent pool and two latent kernels a
+    block at 64 heads, the expert layer's two row movements and three grouped
+    products, and an identity part that is no kernel and no row; every Mosaic
+    call keeps its name under its scope."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.serving import transformer_decode
+    from determined_tpu.models.transformer import TransformerLM, kv_cache_shape
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = _longcat_cfg(1)
+    assert kv_cache_shape(cfg, 10240, 16) == (2, 10240, 16, 640)
+    pool_bytes = 2 * 10240 * 16 * 640 * 2
+    if which == "prefill":
+        compiled = _walk_compiled(one, cfg, num_blocks=10240, max_prompt_len=3072, table_width=320)
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 0.5 * 1024**3 and mem.alias_size_in_bytes >= pool_bytes
+        assert _arrays_with_dims(text, (64, 256, 5120)) == [] and _kernels(text) == 5
+        return
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cache = {"kv": aval(kv_cache_shape(cfg, 10240, 16), cfg.dtype)}
+    fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+    compiled = fn.lower(params, aval((64,)), aval((64,)), aval((64, 320)), cache).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 2 + 5
+    assert mem.temp_size_in_bytes < 256 * 1024**2 and mem.alias_size_in_bytes >= pool_bytes   # the pool is donated
+    scopes = program_scopes(text)
+    assert {"serve.mla", "serve.mla.attend", "serve.mlp", "serve.moe.route", "serve.moe.experts", "serve.moe.identity"} <= set(scopes)
+    assert sum("paged_latent_attention" in n for n in scopes["serve.mla.attend"]) == 2
+    named = [n for n in scopes["serve.moe.experts"] if re.match(r"(moe_gmm|moe_rows_of_tokens|moe_tokens_of_rows)", n)]
+    assert len(named) == 5, scopes["serve.moe.experts"]
+    assert not any(re.match(r"(moe_|paged_)", n) for n in scopes["serve.moe.identity"])  # a weighted add, no kernel
+
+
 # -- sliding-window layers served from a ring a lane: the Command A+ cell's shapes --
 
 

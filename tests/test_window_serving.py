@@ -110,7 +110,7 @@ def test_the_full_forward_builds_the_published_block_and_matches_the_reference(m
     assert params["block_0"]["moe"]["shared_w_gate"].shape == (64, SHARED * 32)
     assert cfg.rope(FULL) is None and cfg.rope(SLIDING).theta == 50000.0
     assert cfg.window_layers == WINDOW_RING.layers(cfg) == (0, 1, 2) and PAGED_KV.layers(cfg) == (3,)
-    assert [layer_kinds(cfg, i) for i in range(4)] == [((WINDOW_RING, 0),), ((WINDOW_RING, 1),), ((WINDOW_RING, 2),), ((PAGED_KV, 0),)]
+    assert [layer_kinds(cfg, i) for i in range(4)] == [((WINDOW_RING, 0, "attn"),), ((WINDOW_RING, 1, "attn"),), ((WINDOW_RING, 2, "attn"),), ((PAGED_KV, 0, "attn"),)]
     got = _forward(cfg)(params, jnp.asarray(tokens[:, :64]))
     np.testing.assert_allclose(np.asarray(got), want[:, :64], atol=3e-5)
     # the hidden state times the table, times logit_scale, is what a fused loss contracts
