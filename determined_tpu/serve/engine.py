@@ -26,8 +26,11 @@ sent again (``docs/serving.md`` "A step, in order").  A lane's block table is
 an int32 row made once at admission; the engine keeps one matrix of them and
 the device's copy of it, sent again only after a lane joined or retired.  A
 step's tokens are the ids the sampler left on the device the step before,
-unless a lane joined since.  The lanes' uniforms are drawn, and sent, between
-the decode call's two stamps (``DecodeKernels.during_wait``).
+unless a lane joined since.  Between the decode call's two stamps
+(``DecodeKernels.during_wait``, which is handed the logits the call has just
+enqueued) the lanes' uniforms are drawn and sent, and the sampler's call and
+the copies of its ids and counters are queued behind the decode program: the
+host hears from the device ONCE a step, and by then the ids are on their way.
 
 The engine times itself.  One set of ``time.monotonic()`` stamps — a
 dozen a step, one a token — is taken always and feeds two sinks.  The first
@@ -329,9 +332,11 @@ class DecodeKernels:
         self.last_decode_stamps: Optional[Tuple[float, float, float]] = None
         #: what the engine left for the next ``decode`` to run on this thread
         #: while the device runs the step: called once between the call's two
-        #: stamps, and cleared by the call (an attribute and no parameter:
-        #: whatever wraps ``decode`` from outside passes its three arrays on)
-        self.during_wait: Optional[Callable[[], None]] = None
+        #: stamps with the logits the call has just enqueued (a pending array:
+        #: to be handed to another program, never read on the host there), and
+        #: cleared by the call (an attribute and no parameter: whatever wraps
+        #: ``decode`` from outside passes its three arrays on)
+        self.during_wait: Optional[Callable[[Any], None]] = None
         #: a model with expert layers or a cache kind that counts: the decode
         #: program returns one more row of logits, whose first entries are these
         #: counts of the step (``transformer_decode``), in this order; the
@@ -443,7 +448,10 @@ class DecodeKernels:
         in ``last_decode_stamps``: whatever times this call from outside
         holds the device's whole step.  Between the two, while the device
         runs the step, ``during_wait`` is run, once, where the engine left
-        one."""
+        one, with the logits as they are then: enqueued and not yet ready.
+        The engine queues its sampler behind them there, so the sampler's
+        operations close the step's burst on the device and may start
+        before this call returns."""
         t0 = mono()
         logits, self.cache = self._decode(
             self.params, tokens, positions, tables, self.cache
@@ -451,7 +459,7 @@ class DecodeKernels:
         t1 = mono()
         work, self.during_wait = self.during_wait, None
         if work is not None:
-            work()
+            work(logits)
         logits.block_until_ready()
         self.last_decode_stamps = (t0, t1, mono())
         return logits
@@ -504,9 +512,10 @@ class ServeEngine:
         #: step's tokens as they lie.  None once a lane joined (its first
         #: token is the host's draw) and where the caller's own sampler chose
         self._ids_on_device: Any = None
-        #: decode steps, those whose table was sent again, and those whose
-        #: tokens were the device's ids (``/stats`` ``step_inputs``)
-        self._step_inputs = {"decode_steps": 0, "table_sent": 0, "tokens_from_device": 0}
+        #: decode steps, those whose table was sent again, those whose tokens
+        #: were the device's ids, and those whose sampler was queued inside the
+        #: decode call's wait (``/stats`` ``step_inputs``)
+        self._step_inputs = {"decode_steps": 0, "table_sent": 0, "tokens_from_device": 0, "sampler_in_wait": 0}
         from determined_tpu.models.cache_kinds import BLOCKS
 
         #: whether a request holds blocks of some kind of the kernels' cache
@@ -852,9 +861,10 @@ class ServeEngine:
             # engine was made: every phase of the clock, which add up to
             # ``uptime`` (the seconds the reading covers), and the four sums
             # that were here before it: waiting for the decode program (the
-            # lanes' uniforms are drawn and sent inside that wait), sampling
-            # (the launch to the last lane's stamp), of which copying the ids
-            # and the counters to the host, admitting (prefill and first sample)
+            # lanes' uniforms are drawn and sent and the sampler is queued
+            # inside that wait), sampling (the decode call's return to the
+            # last lane's stamp), of which copying the ids and the counters
+            # to the host, admitting (prefill and first sample)
             "step_seconds": {
                 "decode_wait": round(phases[DECODE_WAIT], 6),
                 "d2h": round(phases[D2H], 6),
@@ -870,8 +880,9 @@ class ServeEngine:
             "step_counters": step_counters,
             # how often a decode step had to send its inputs: the block table
             # goes to the device again only after a lane joined or retired,
-            # and the tokens are the ids the sampler left there unless a lane
-            # joined since
+            # the tokens are the ids the sampler left there unless a lane
+            # joined since, and the sampler is queued inside the decode call's
+            # wait unless the kernels are a stand-in that never runs the hook
             "step_inputs": step_inputs,
             "queue_depth": self.queue.depth(),
             # static queue bound: the router's saturation signal — at
@@ -1055,16 +1066,33 @@ class ServeEngine:
             self._recent.append((req.ttft_s, req.tpot_s, req.queue_wait_s, req.tpot_split_s))
         self._record_request(req)
 
+    def _launch_sampler(self, logits: Any, draws: Any) -> Tuple[Any, Any, Tuple[float, float]]:
+        """Queue the step's ONE sampler call (``sample_lanes``) on the logits,
+        ready or not, and the copies of its ids and counters to the host
+        behind it.  The ids and the counted row as the device holds them, and
+        the launch's two stamps."""
+        t0 = mono()
+        n_counted = len(self._counters) + len(self._gauges)
+        ids, counted = lane_sampler(n_counted)(logits, draws)
+        # queued behind the program at once: waiting for the ids first and
+        # only then asking for them costs a host round trip
+        ids.copy_to_host_async()
+        if n_counted:
+            counted.copy_to_host_async()
+        return ids, counted, (t0, mono())
+
     def _decode_batch(
         self, lanes: List[Optional[ActiveSeq]]
-    ) -> Tuple[Any, np.ndarray, Any, Dict[str, int], Tuple[float, float], Tuple[float, float]]:
+    ) -> Tuple[Any, np.ndarray, Any, Dict[str, int], Tuple[float, float], Tuple[float, float], Optional[Tuple[Any, Any, Tuple[float, float]]]]:
         """One jitted decode step over the full (static) lane table, and
-        inside its wait what the step's sampler needs that no id decides.
-        Returns the logits as the kernels handed them back (on the device,
-        ready), the lanes' positions (-1: idle), the lanes' temperatures
-        over their uniforms as the device holds them, which of the step's
-        inputs had to be sent (``step_inputs``), the call's two ends and the
-        prepared work's.
+        inside its wait what the step's sampler needs that no id decides AND
+        the sampler's launch.  Returns the logits as the kernels handed them
+        back (on the device, ready), the lanes' positions (-1: idle), the
+        lanes' temperatures over their uniforms as the device holds them,
+        which of the step's inputs had to be sent (``step_inputs``), the
+        call's two ends, the prepared work's, and what the sampler's launch
+        inside the wait left (``_launch_sampler``; None where it was not
+        launched there).
 
         The table goes to the device only where a row changed since it last
         went; the tokens are the ids the last step's sampler left there,
@@ -1072,12 +1100,15 @@ class ServeEngine:
         ``next_token`` has them).  The positions are the host's array.
 
         The prepared work runs on this thread between the kernels' two
-        stamps (``DecodeKernels.during_wait``), and here, after the call, under
-        a stand-in for the kernels that never runs it: a sampled lane's
-        uniform is ONE ``rng.random()`` of its request's generator, drawn in
-        lane order (a greedy lane draws nothing), the ``[2, lanes]`` draws go
-        to the device, and the positions of the step after are made (a NEW
-        array: whoever wraps the call reads this step's after it returns)."""
+        stamps (``DecodeKernels.during_wait``): a sampled lane's uniform is
+        ONE ``rng.random()`` of its request's generator, drawn in lane order
+        (a greedy lane draws nothing), the ``[2, lanes]`` draws go to the
+        device, the positions of the step after are made (a NEW array:
+        whoever wraps the call reads this step's after it returns), and the
+        sampler is queued on the logits the kernels hand the hook, which are
+        not ready yet: the device runs it the moment the decode program ends.
+        Under a stand-in for the kernels that never runs the hook the draws
+        are made here, after the call, and the launch is left to the caller."""
         import jax
 
         b = self.cfg.max_batch
@@ -1094,7 +1125,7 @@ class ServeEngine:
                     tokens[i] = seq.next_token
         prepared: List[Any] = []
 
-        def prepare() -> None:
+        def prepare(pending: Any = None) -> None:
             t_prepare = mono()
             draws = np.zeros((2, b), np.float32)  # temperatures over uniforms; an idle lane: argmax, ignored
             for i, seq in enumerate(lanes):
@@ -1103,7 +1134,7 @@ class ServeEngine:
                     draws[1, i] = seq.rng.random()
             on_device = jax.device_put(draws)
             self._positions = np.where(positions >= 0, positions + 1, positions)
-            prepared.extend((on_device, (t_prepare, mono())))
+            prepared.extend((on_device, (t_prepare, mono()), None if pending is None else self._launch_sampler(pending, on_device)))
 
         kernels = self.kernels
         kernels.during_wait = prepare
@@ -1113,38 +1144,54 @@ class ServeEngine:
         if not prepared:
             kernels.during_wait = None
             prepare()
-        return logits, positions, prepared[0], sent, (t0, t1), prepared[1]
+        draws, t_prepared, launched = prepared
+        return logits, positions, draws, sent, (t0, t1), t_prepared, launched
 
     def _decode_and_sample(self, lanes: List[Optional[ActiveSeq]], step: int) -> int:
-        """One decode step over the lane table, then one call that draws
-        every lane's token on the device (``sample_lanes``) from the logits
-        where they lie, with the draws the device already holds; what comes
-        to the host is the ids and the step's counters.  Each live lane then
-        takes its token, in lane order, the step's counts go into the stats
-        under ONE hold of their lock, and the sequences that finished are
-        retired, in this step (a response's counts are in ``stats()`` before
-        it is complete).  Returns how many finished.
+        """One decode step over the lane table, whose wait also queues the
+        one call that draws every lane's token on the device
+        (``sample_lanes``) from the logits where they lie, with the draws the
+        device already holds; what comes to the host is the ids and the
+        step's counters.  Each live lane then takes its token, in lane order,
+        the step's counts go into the stats under ONE hold of their lock, and
+        the sequences that finished are retired, in this step (a response's
+        counts are in ``stats()`` before it is complete).  Returns how many
+        finished.
 
-        ``serve.sample`` runs from the sampler's launch to the last lane's
-        token stamp, in four parts end to end: ``serve.sample.launch`` (the
-        call and the two copies are queued), ``serve.sample.wait`` (the ids
-        are ready on the device), ``serve.decode.d2h`` (they are on the
-        host) and ``serve.lanes`` (each lane has its token).  ``serve.decode``
-        has ended before: a reader that takes the device's step from it counts
-        no operation of the sampler.  ``serve.step.prepare`` is the prepared
-        work, inside ``serve.decode.wait`` where the kernels ran it.
-        ``serve.retire`` follows where a sequence finished.  The phase clock
-        is fed from the same stamps, after the lanes' loop."""
-        logits, positions, draws, sent, (t_call, t_back), prepare = self._decode_batch(lanes)
-        t0 = mono()
+        The step's order: the decode program is launched
+        (``serve.decode.dispatch``); inside ``serve.decode.wait`` the draws
+        are made and sent (``serve.step.prepare``) and the sampler's call and
+        its two copies are queued behind the decode program
+        (``serve.sample.launch``); the call returns when the logits are ready.
+        ``serve.sample`` runs from that return to the last lane's token stamp,
+        which is what of the sampling is still in series with the device, in
+        three parts end to end: ``serve.sample.wait`` (the ids are ready on
+        the device: at once or nearly, the sampler ran while the host was
+        being told of the logits), ``serve.decode.d2h`` (they are on the
+        host) and ``serve.lanes`` (each lane has its token).  The sampler's
+        operations close the step's burst on the device and may start inside
+        ``serve.decode``: a reader that takes the device's step from that span
+        or from the burst counts them in it.  ``serve.retire`` follows where a
+        sequence finished.  The phase clock is fed from the same stamps,
+        after the lanes' loop; the launch inside the wait is the device's
+        busy time and stays under ``decode.wait``.
+
+        Where nothing launched the sampler inside the wait (a stand-in for
+        the kernels that never runs the hook, a ``_decode_batch`` of a
+        caller's own) it is launched here, after the call, on the same inputs:
+        ``serve.sample`` then starts at the launch, which is its first part
+        and the clock's ``sample.launch``.  ``sampler_in_wait`` (1 or 0) on
+        ``serve.decode`` and in ``step_inputs`` says which a step took."""
+        logits, positions, draws, sent, (t_call, t_back), prepare, launched = self._decode_batch(lanes)
+        in_wait = launched is not None
+        if not in_wait:
+            launched = self._launch_sampler(logits, draws)
+        ids, counted, (t_launch, t_launched) = launched
+        sent["sampler_in_wait"] = int(in_wait)
+        # the sampling that is still in series with the device: from the
+        # call's return, or from a launch that had to follow it
+        t0, t_wait = (t_back, t_back) if in_wait else (t_launch, t_launched)
         names = self._counters + self._gauges
-        ids, counted = lane_sampler(len(names))(logits, draws)
-        # queued behind the program at once: waiting for the ids first and
-        # only then asking for them costs a host round trip
-        ids.copy_to_host_async()
-        if names:
-            counted.copy_to_host_async()
-        t_launched = mono()
         ids.block_until_ready()
         t_ready = mono()
         tokens = np.asarray(ids).tolist()
@@ -1187,8 +1234,8 @@ class ServeEngine:
         if stamps is not None:
             clock.to(DECODE_WAIT, stamps[1])
             clock.to(DECODE_DISPATCH, stamps[2])
-        clock.to(SAMPLE_LAUNCH, t0)
-        clock.to(SAMPLE_WAIT, t_launched)
+        clock.to(SAMPLE_LAUNCH, t0)  # no time of it where the launch lay inside the wait
+        clock.to(SAMPLE_WAIT, t_wait)
         clock.to(D2H, t_ready)
         clock.to(LANES, t_host)
         clock.to(REST, t1)
@@ -1231,8 +1278,8 @@ class ServeEngine:
                 tracer.record_span("serve.decode.dispatch", "serve", stamps[0], stamps[1], at)
                 tracer.record_span("serve.decode.wait", "serve", stamps[1], stamps[2], at)
             tracer.record_span("serve.step.prepare", "serve", *prepare, at)
-            tracer.record_span("serve.sample.launch", "serve", t0, t_launched, at)
-            tracer.record_span("serve.sample.wait", "serve", t_launched, t_ready, at)
+            tracer.record_span("serve.sample.launch", "serve", t_launch, t_launched, at)
+            tracer.record_span("serve.sample.wait", "serve", t_wait, t_ready, at)
             tracer.record_span("serve.decode.d2h", "serve", t_ready, t_host, at)
             tracer.record_span("serve.lanes", "serve", t_host, t1, at)
             tracer.record_span(

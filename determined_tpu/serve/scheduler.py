@@ -47,9 +47,11 @@ PHASES = (
 ) = range(len(PHASES))
 
 #: the four parts of a request's time a token, each a sum of phases, together
-#: all of them: waiting for the device's step, the step's sampling (launch to
-#: the last lane's stamp), admissions (other requests': its own ended with its
-#: first token), and what is left of the host's loop
+#: all of them: waiting for the device's step (the sampler is queued inside
+#: that wait), what of the step's sampling follows the decode call (its return
+#: to the last lane's stamp; the launch too where it could not lie in the
+#: wait), admissions (other requests': its own ended with its first token),
+#: and what is left of the host's loop
 TPOT_PARTS: Dict[str, Tuple[int, ...]] = {
     "decode_wait": (DECODE_WAIT,),
     "sample": (SAMPLE_LAUNCH, SAMPLE_WAIT, D2H, LANES),

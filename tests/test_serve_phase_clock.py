@@ -21,6 +21,7 @@ from determined_tpu.serve.scheduler import (
     IDLE,
     PHASES,
     REST,
+    SAMPLE_LAUNCH,
     TPOT_PARTS,
     GenRequest,
     PhaseClock,
@@ -108,8 +109,9 @@ def test_the_phases_add_up_to_the_threads_time(kernels):
     now = time.monotonic()
     totals = clock.read(now)
     assert sum(totals) == pytest.approx(now - clock.started_at, abs=1e-6)
-    # every phase took some of it, the admissions exactly what the requests' own stamps say
-    assert all(v > 0 for v in totals), dict(zip(PHASES, totals))
+    # every phase took some of it (but the sampler's launch, which lies inside the decode call's wait and is
+    # counted there), the admissions exactly what the requests' own stamps say
+    assert all((v > 0) == (i != SAMPLE_LAUNCH) for i, v in enumerate(totals)), dict(zip(PHASES, totals))
     assert sum(totals[i] for i in ADMISSION) == pytest.approx(sum(admission_seconds(r) for r in reqs), abs=1e-9)
     assert totals[IDLE] >= 0.04                                      # the wait's 50 ms
     # /stats reports the reading the engine published last, and it is closed too
@@ -234,8 +236,18 @@ def test_the_engines_thread_counts_its_waits_as_idle_and_publishes_them(kernels)
     assert sum(last["phases"].values()) == pytest.approx(last["uptime"], abs=2e-5)
 
 
-def test_the_new_spans_tile_the_sampling_and_a_step_says_where_its_time_went(kernels, tracer):
-    eng = ServeEngine(kernels)
+class _SwallowsTheHook(_NoStamps):
+    """Kernels that never run what the engine left for the call's wait (it is
+    set on THIS object), with the stamps of the kernels underneath handed on."""
+
+    @property
+    def last_decode_stamps(self):
+        return self._k.last_decode_stamps
+
+
+@pytest.mark.parametrize("in_wait", [True, False], ids=["sampler_in_wait", "sampler_after_call"])
+def test_the_new_spans_tile_the_sampling_and_a_step_says_where_its_time_went(kernels, tracer, in_wait):
+    eng = ServeEngine(kernels if in_wait else _SwallowsTheHook(kernels))
     for i in range(3):
         eng.submit([1 + i, 2, 3], max_new_tokens=3 + i)
     while eng.step_once():
@@ -245,9 +257,16 @@ def test_the_new_spans_tile_the_sampling_and_a_step_says_where_its_time_went(ker
     steps, samples = by_step("serve.step"), by_step("serve.sample")
     launch, wait, d2h, lanes = (by_step(n) for n in ("serve.sample.launch", "serve.sample.wait", "serve.decode.d2h", "serve.lanes"))
     assert sorted(samples) == sorted(launch) == sorted(wait) == sorted(d2h) == sorted(lanes) and len(samples) >= 3
+    waits = by_step("serve.decode.wait")
     for step, s in samples.items():
-        # end to end, from the same stamps (events round to 0.1 us)
-        parts = [launch[step], wait[step], d2h[step], lanes[step]]
+        # end to end, from the same stamps (events round to 0.1 us); the launch is the first part only where it
+        # had to follow the decode call: the kernels run it inside their wait, and the sampling starts at the call's end
+        parts = [wait[step], d2h[step], lanes[step]]
+        if in_wait:
+            assert waits[step]["ts"] - 0.2 <= launch[step]["ts"] and launch[step]["ts"] + launch[step]["dur"] <= waits[step]["ts"] + waits[step]["dur"] + 0.2
+            assert launch[step]["dur"] > 0 and launch[step]["args"] == {"step": step}
+        else:
+            parts.insert(0, launch[step])
         assert parts[0]["ts"] == pytest.approx(s["ts"], abs=0.11)
         for before, after in zip(parts, parts[1:]):
             assert after["ts"] == pytest.approx(before["ts"] + before["dur"], abs=0.21)
@@ -268,7 +287,8 @@ def test_the_new_spans_tile_the_sampling_and_a_step_says_where_its_time_went(ker
         assert sum(split.values()) == pytest.approx(e["dur"] / 1e3, abs=0.001 * len(split))
         assert ("retire" in split) == (step in retires)
         assert ("admission.prefill" in split) == bool(e["args"]["admitted"])
-        assert split["decode.wait"] == pytest.approx(by_step("serve.decode.wait")[step]["dur"] / 1e3, abs=0.002)
+        assert split["decode.wait"] == pytest.approx(waits[step]["dur"] / 1e3, abs=0.002)
+        assert ("sample.launch" in split) == (not in_wait)
         assert split["lanes"] == pytest.approx(lanes[step]["dur"] / 1e3, abs=0.002)
 
 

@@ -932,8 +932,8 @@ def test_step_spans_nest_and_carry_their_step(kernels, tracer):
     for part in ("dispatch", "wait"):
         for e in _spans(tracer, "serve.decode." + part):
             assert _inside(e, decodes[e["args"]["step"]]) and e["dur"] > 0
-    # the copy that is left (ids, counters) lies in the sampling; the decode
-    # call has ended before the sampler is launched
+    # the copy that is left (ids, counters) lies in the sampling, which is
+    # what follows the decode call (the sampler was queued inside its wait)
     copies = _spans(tracer, "serve.decode.d2h")
     assert len(copies) == len(decodes)
     for e in copies:
@@ -1027,7 +1027,8 @@ def test_a_sampler_of_the_callers_own_is_handed_each_live_lanes_float32_row(kern
 class _HostRebuiltEngine(ServeEngine):
     """The path before PR 49, as the reference: every step makes the table
     and the tokens anew on the host from each lane's own state, hands the
-    kernels three numpy arrays, and draws the uniforms after the call."""
+    kernels three numpy arrays, draws the uniforms after the call and leaves
+    the sampler's launch to the engine (nothing is handed to the call's wait)."""
 
     def _decode_batch(self, lanes):
         b, t = self.cfg.max_batch, self.cfg.blocks_per_seq
@@ -1043,7 +1044,7 @@ class _HostRebuiltEngine(ServeEngine):
         for i, seq in enumerate(lanes):
             if seq is not None and seq.request.temperature > 0.0:
                 draws[:, i] = seq.request.temperature, seq.rng.random()
-        return logits, positions, draws, {"table_sent": 1, "tokens_from_device": 0}, (t0, t1), (t1, t1)
+        return logits, positions, draws, {"table_sent": 1, "tokens_from_device": 0}, (t0, t1), (t1, t1), None
 
 
 def _mixed_traffic(seed=11, n=12):
@@ -1220,7 +1221,9 @@ def test_the_table_is_sent_after_a_join_or_a_retirement_and_the_devices_ids_go_i
         sent += again
         from_device += joined == 0
     assert 0 < sent < len(moved) and 0 < from_device < len(moved)
-    assert eng.stats()["step_inputs"] == {"decode_steps": len(moved), "table_sent": sent, "tokens_from_device": from_device}
+    assert eng.stats()["step_inputs"] == {
+        "decode_steps": len(moved), "table_sent": sent, "tokens_from_device": from_device, "sampler_in_wait": len(moved),
+    }
     spans = sorted(_spans(tracer, "serve.decode"), key=lambda e: e["args"]["step"])
     assert [e["args"]["table_sent"] for e in spans] == [int(n == 0 or changed > 0) for n, (_, changed) in enumerate(moved)]
     assert [e["args"]["tokens_from_device"] for e in spans] == [int(joined == 0) for joined, _ in moved]
@@ -1256,6 +1259,168 @@ def test_the_prepared_work_lies_inside_the_decode_calls_wait(kernels, tracer):
     for step, e in prepared.items():
         assert _inside(e, waits[step]) and _inside(waits[step], decodes[step]) and e["dur"] > 0
         assert samples[step]["ts"] >= decodes[step]["ts"] + decodes[step]["dur"] - 0.2
+
+
+# ---------------------------------------------------------------------------
+# the sampler is queued behind the decode program inside the call's wait (PR 56)
+# ---------------------------------------------------------------------------
+
+
+class _KernelsThatSwallowTheHook(_KernelsThatNeverPrepare):
+    """The same stand-in with the kernels' stamps handed on: the engine's
+    phases and spans are whole, only the hook is never run."""
+
+    def __init__(self, kernels):
+        super().__init__(kernels, host=False)
+
+    @property
+    def last_decode_stamps(self):
+        return self._kernels.last_decode_stamps
+
+
+def _engine_on(path, kernels):
+    """An engine on each path a step can take: the sampler queued inside the
+    decode call's wait (the real kernels), or launched after the call
+    because nothing ran the hook."""
+    if path == "in_wait":
+        return ServeEngine(kernels)
+    if path == "host_rebuilt":
+        return _HostRebuiltEngine(kernels)
+    if path == "swallowed":
+        return ServeEngine(_KernelsThatSwallowTheHook(kernels))
+    return ServeEngine(_KernelsThatNeverPrepare(kernels, host=path == "swallowed_host_logits"))
+
+
+def _traffic_at(temperature, seed=15, n=8):
+    """``_mixed_traffic`` with every request at one temperature, seeds kept."""
+    return [(at, prompt, {**kw, "temperature": temperature}) for at, prompt, kw in _mixed_traffic(seed=seed, n=n)]
+
+
+def test_the_sampler_is_launched_on_the_pending_logits_before_decode_returns(kernels):
+    """(a) With the real kernels the hook is handed the logits the call has
+    just enqueued (the very array the call then returns), the sampler's
+    launch lies between the call's second and third stamp, and every step is
+    counted as one whose sampler was queued inside the wait."""
+    eng = ServeEngine(kernels)
+    inner, launch = kernels.decode, eng._launch_sampler
+    handed, returned, stamps, launches = [], [], [], []
+
+    def decode(tokens, positions, tables):
+        hook = kernels.during_wait
+        assert hook is not None
+
+        def recording(pending):
+            handed.append(pending)
+            hook(pending)
+            assert len(launches) == len(handed)  # launched by the hook itself, inside the call
+
+        kernels.during_wait = recording
+        out = inner(tokens, positions, tables)
+        assert len(launches) == len(handed) == len(returned) + 1  # before decode returned
+        returned.append(out)
+        stamps.append(kernels.last_decode_stamps)
+        return out
+
+    def recorded_launch(logits, draws):
+        launches.append((logits, launch(logits, draws)))
+        return launches[-1][1]
+
+    kernels.decode, eng._launch_sampler = decode, recorded_launch
+    try:
+        got = _drive(eng, _traffic_at(0.8))
+    finally:
+        del kernels.decode
+    assert len(handed) == len(returned) == len(launches) > 10 and kernels.during_wait is None
+    for pending, out, (_call, enqueued, ready), (logits, (ids, _counted, (t_launch, t_launched))) in zip(handed, returned, stamps, launches):
+        assert isinstance(pending, jax.Array) and pending.shape == (4, 64) and pending is out and logits is pending
+        assert enqueued <= t_launch <= t_launched <= ready
+        assert isinstance(ids, jax.Array) and ids.shape == (4,) and ids.dtype == np.int32
+    inputs = eng.stats()["step_inputs"]
+    assert inputs["sampler_in_wait"] == inputs["decode_steps"] == len(handed)
+    assert all(len(r.output) == r.max_new_tokens for r in got)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "t0.8"])
+@pytest.mark.parametrize("path", ["swallowed_device_logits", "swallowed_host_logits", "host_rebuilt"])
+def test_a_sampler_launched_after_the_call_draws_the_same_tokens(kernels, path, temperature):
+    """(b) Where nothing runs the hook, the engine sees that the sampler was
+    not launched and launches it after the call returns, on the same inputs
+    in the same order: no step counts as ``sampler_in_wait`` and every
+    request's tokens are those of the launch inside the wait, bit for bit,
+    greedy and sampled (one ``rng.random()`` a sampled lane in lane order)."""
+    plan = _traffic_at(temperature)
+    first = ServeEngine(kernels)
+    want = [r.output for r in _drive(first, plan)]
+    inputs = first.stats()["step_inputs"]
+    assert inputs["sampler_in_wait"] == inputs["decode_steps"] > 10
+    eng = _engine_on(path, kernels)
+    got = _drive(eng, plan)
+    assert [r.output for r in got] == want and any(len(r.output) > 5 for r in got)
+    inputs = eng.stats()["step_inputs"]
+    assert inputs["sampler_in_wait"] == 0 and inputs["decode_steps"] > 10
+    assert kernels.during_wait is None
+
+
+@pytest.mark.parametrize("path", ["in_wait", "swallowed", "host_rebuilt"])
+def test_a_requests_phases_are_its_time_a_token_wherever_the_sampler_was_launched(kernels, path):
+    """(c) The phase clock on both paths: each finished request's four parts
+    are its ``tpot_s`` (the clock's gain between its first token's stamp and
+    its last), the phases are the thread's time, and ``sample.launch`` holds
+    time only where the launch had to follow the call."""
+    eng = _engine_on(path, kernels)
+    reqs = _drive(eng, _traffic_at(0.8, seed=16))
+    now = time.monotonic()
+    clock = eng._clock
+    totals = dict(zip(PHASES, clock.read(now)))
+    assert sum(totals.values()) == pytest.approx(now - clock.started_at, abs=1e-6)
+    several = [r for r in reqs if len(r.output) >= 2]
+    assert len(several) >= 4
+    for r in several:
+        split = r.tpot_split_s
+        assert sum(split.values()) == pytest.approx(r.tpot_s, abs=1e-9) and all(v >= 0.0 for v in split.values())
+        assert (r.token_at[-1] - r.token_at[0]) / (len(r.output) - 1) <= r.tpot_s
+        assert split["sample"] > 0 and split["host"] > 0
+    assert (totals["sample.launch"] == 0.0) == (path == "in_wait")
+    assert all(totals[k] > 0 for k in ("decode.wait", "sample.wait", "d2h", "lanes", "decode.dispatch"))
+    inputs = eng.stats()["step_inputs"]
+    assert inputs["sampler_in_wait"] == (inputs["decode_steps"] if path == "in_wait" else 0)
+
+
+@pytest.mark.parametrize("path", ["in_wait", "swallowed"])
+def test_a_traced_steps_sampling_starts_where_the_decode_call_ends(kernels, tracer, path):
+    """(d) A traced step's spans.  The sampler queued inside the wait:
+    ``serve.sample.launch`` lies in ``serve.decode.wait`` behind the prepared
+    work, ``serve.sample`` starts where ``serve.decode`` ends and is tiled by
+    the wait for the ids, their copy and the lanes' loop.  Launched after the
+    call: the launch is ``serve.sample``'s first part, after the decode call
+    and the draws.  ``serve.decode`` says which (``sampler_in_wait``)."""
+    eng = _engine_on(path, kernels)
+    reqs = [eng.submit([3 + i, 4], max_new_tokens=5, temperature=0.8 * (i % 2), seed=i) for i in range(3)]
+    while eng.step_once():
+        pass
+    assert all(r.error is None and len(r.output) == 5 for r in reqs)
+    by_step = lambda name: {e["args"]["step"]: e for e in _spans(tracer, name)}  # noqa: E731
+    names = ("serve.decode", "serve.decode.wait", "serve.step.prepare", "serve.sample", "serve.sample.launch",
+             "serve.sample.wait", "serve.decode.d2h", "serve.lanes")
+    decodes, waits, prepared, samples, launches, ready, copies, books = (by_step(n) for n in names)
+    assert all(sorted(spans) == sorted(decodes) for spans in (waits, prepared, samples, launches, ready, copies, books))
+    assert len(decodes) == 4
+    end = lambda e: e["ts"] + e["dur"]  # noqa: E731
+    for step, sample in samples.items():
+        assert decodes[step]["args"]["sampler_in_wait"] == int(path == "in_wait")
+        assert sample["ts"] >= end(decodes[step]) - 0.2
+        parts = [ready[step], copies[step], books[step]]
+        if path == "in_wait":
+            assert _inside(launches[step], waits[step]) and launches[step]["ts"] >= end(prepared[step]) - 0.2
+            assert sample["ts"] == pytest.approx(end(decodes[step]), abs=0.11)
+        else:
+            assert prepared[step]["ts"] >= end(decodes[step]) - 0.2 and launches[step]["ts"] >= end(prepared[step]) - 0.2
+            parts.insert(0, launches[step])
+        assert parts[0]["ts"] == pytest.approx(sample["ts"], abs=0.11)
+        for before, after in zip(parts, parts[1:]):
+            assert after["ts"] == pytest.approx(end(before), abs=0.21)
+        assert end(parts[-1]) == pytest.approx(end(sample), abs=0.21)
+        assert all(p["dur"] > 0 for p in parts + [launches[step]])
 
 
 def test_no_kind_of_step_compiles_or_lowers_a_program_after_the_kernels_are_built(lm_setup):
@@ -1373,8 +1538,9 @@ def test_stats_carries_latency_and_step_seconds(kernels, tracer):
     # the four that were there are sums of the clock's phases, which are a
     # closed set: together they are the seconds the reading covers
     phases = ss["phases"]
-    assert tuple(phases) == PHASES and all(v > 0 for v in phases.values())
-    assert sum(phases.values()) == pytest.approx(ss["uptime"], abs=2e-5) and ss["uptime"] <= st["uptime_s"]
+    # (the sampler's launch lies inside the decode call's wait and is counted there)
+    assert tuple(phases) == PHASES and all((v > 0) == (k != "sample.launch") for k, v in phases.items())
+    assert sum(phases.values()) == pytest.approx(ss["uptime"], abs=2e-5) and ss["uptime"] <= st["uptime_s"] + 0.0005  # rounded to a millisecond
     assert ss["decode_wait"] == phases["decode.wait"] and ss["d2h"] == phases["d2h"]
     assert ss["sample"] == pytest.approx(sum(phases[k] for k in ("sample.launch", "sample.wait", "d2h", "lanes")), abs=5e-6)
     assert ss["admission"] == pytest.approx(sum(v for k, v in phases.items() if k.startswith("admission.")), abs=5e-6)
