@@ -10,7 +10,10 @@ mixer's float32 state and its convolution's tail a decode lane);
 ``docs/serving.md`` "What a request holds" has them side by side.  A layer is
 of every kind whose ``layer_types`` name its type: an ``attention_mamba2`` layer
 is of ``paged_kv`` AND ``ssm_slot``, and its mixers run one after the other on
-the one norm, each adding its output to the stream.  The forward's layer function calls every
+the one norm, each adding its output to the stream; under ``mixer_block`` a
+``mamba2`` layer is of ``ssm_slot`` alone, a ``full_attention`` layer of
+``paged_kv`` alone, and an ``experts`` layer of NO kind (it keeps nothing of
+the past, owns no row of any array and :func:`layer_kinds` is empty).  The forward's layer function calls every
 kind's mixer the same way, and the engine (``serve/engine.py``) derives
 admission, its refusals and ``/stats`` from the records: neither names a leaf
 or asks which kind a model has, and nothing outside this file adds to the table.
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 
 from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
-    FULL, HYBRID, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
+    FULL, HYBRID, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
     _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, kv_bytes_per_token, kv_cache_shape, ssm_bytes_per_slot,
     recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
 )
@@ -235,13 +238,26 @@ def _rows_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauge
     return {"rows_per_token": cfg.paged_layers} if cfg.attn_sublayers > 1 else {}
 
 
+def layers_by_kind(cfg: TransformerConfig) -> Dict[str, int]:
+    """How many layers are of each kind of the model, by the kind's name, and
+    under ``none`` how many are of no kind (expert layers alone keep nothing of the past)."""
+    counts = {kind.name: len(kind.layers(cfg)) for kind in cache_kinds(cfg)}
+    return {**counts, "none": sum(1 for i in range(cfg.n_layers) if not layer_kinds(cfg, i))}
+
+
+def _layers_report(cfg: TransformerConfig) -> Dict[str, Any]:
+    """``layers_by_kind``, said by the kinds of a model whose layers are not alike (``mixer_block``)."""
+    return {"layers_by_kind": layers_by_kind(cfg)} if cfg.mixer_block else {}
+
+
 def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``attn_products``: what a tile of the GQA decode kernel multiplies at this
     model's heads (``ops/paged_attention.py``); absent for latent layers, whose
-    heads all share a row, and where no layer reads K and V."""
-    if cfg.latent or len(cfg.retention_layers) == cfg.n_layers:
+    heads all share a row, and where no layer reads K and V.  Under
+    ``mixer_block`` also ``layers_by_kind``."""
+    if cfg.latent or len(cfg.rowless_layers) == cfg.n_layers:
         return {}
-    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads), **_rows_report(cfg)}
+    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads), **_rows_report(cfg), **_layers_report(cfg)}
 
 
 def _ring_step(cfg: TransformerConfig, rows: Rows, cache: Dict[str, jax.Array], table: bool = False):
@@ -545,17 +561,21 @@ def _state_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
 
 
 def _ssm_mixer(cfg: TransformerConfig, conv, scan):
-    """A Mamba-2 mixer reads the norm the layer's attention heads read, writes
-    no row a token and adds its own output to the stream."""
+    """A Mamba-2 mixer reads the norm the layer's attention heads read (under
+    ``mixer_block`` it is the layer's only mixer), writes no row a token and
+    adds its own output to the stream.  Its scopes are ``serve.ssm.*`` beside
+    attention heads and ``serve.mamba2.*`` where it is a layer alone: a share
+    by scope then tells the two block forms' cells apart by its rule."""
+    scope = "serve.mamba2" if cfg.mixer_block else "serve.ssm"
 
     def mix(p, x, h, cache, j):
-        with jax.named_scope("serve.ssm.in"):  # projection, multipliers, convolution, the step
+        with jax.named_scope(scope + ".in"):  # projection, multipliers, convolution, the step
             z, xbc, dt = _ssm_project(cfg, p, h)
             xbc, cache = conv(p, xbc, cache, j)
             parts = _ssm_split(cfg, xbc, dt, p)
-        with jax.named_scope("serve.ssm.state"):  # decay, update, read-out
+        with jax.named_scope(scope + ".state"):  # decay, update, read-out
             y, cache = scan(*parts, cache, j)
-        with jax.named_scope("serve.ssm.out"):  # gated norm, the out-projection
+        with jax.named_scope(scope + ".out"):  # gated norm, the out-projection
             return x + _ssm_out(cfg, p, y, z), cache
 
     return mix
@@ -624,12 +644,14 @@ def _ssm_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any =
     of state one holds over the Mamba-2 layers."""
     if not cfg.ssm_layers:
         return {}
-    return {"ssm": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg)}}
+    slots = {"slots": sizes.max_batch, "live": live, "bytes_per_slot": len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg)}
+    return {"ssm": slots, **_layers_report(cfg)}
 
 
 def _ssm_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
     slots = _ssm_report(cfg, sizes)["ssm"]
-    return {"ssm_slots": slots["slots"], "ssm_bytes_per_slot": slots["bytes_per_slot"], "ssm_pool_bytes": _nbytes(SSM_SLOT, cfg, sizes)}
+    said = {"ssm_slots": slots["slots"], "ssm_bytes_per_slot": slots["bytes_per_slot"], "ssm_pool_bytes": _nbytes(SSM_SLOT, cfg, sizes)}
+    return {**said, **_layers_report(cfg)}
 
 
 # -- the table ----------------------------------------------------------------------
@@ -755,7 +777,7 @@ WINDOW_RING = CacheKind(
 )
 
 SSM_SLOT = CacheKind(
-    name="ssm_slot", layer_types=(HYBRID,), latent=False, leaves=("ssm", "conv"), shapes=_ssm_shapes, holds=LANE,
+    name="ssm_slot", layer_types=(HYBRID, MAMBA2), latent=False, leaves=("ssm", "conv"), shapes=_ssm_shapes, holds=LANE,
     params="ssm",
     # the state where it is stated (the benchmark's check sets another there); the tail as the convolution reads it
     dtypes=lambda cfg: (transformer.STATE_DTYPE, cfg.dtype),

@@ -279,7 +279,8 @@ def _held_experts(
     """``y[t] = sum over t's held picks of weights[t, j] * W_down,e (silu(W_gate,e
     x[t]) * W_up,e x[t])``, float32, through the tile-aligned buffer that
     ``gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)`` lays out
-    (``tile_rows [tiles]``: the rows of each tile that a pick owns)."""
+    (``tile_rows [tiles]``: the rows of each tile that a pick owns).  ``w_gate``
+    None: the two-matrix expert, ``W_down,e relu(W_up,e x[t])^2``."""
     return _held_experts_fwd(
         x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held, tile_rows,
         group_start, tile_group, live_tiles, rows, tile,
@@ -287,10 +288,26 @@ def _held_experts(
 
 
 def _hidden(gate, up, scale):
-    """(silu(gate) * up in float32, the same times a row's routing weight in
-    the compute dtype: the down projection's input)."""
-    act = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    return act, (act * scale[:, None]).astype(gate.dtype)
+    """(an expert's hidden values in float32: ``silu(gate) * up``, or
+    ``relu(up)^2`` for the two-matrix expert, whose ``gate`` is None; the same
+    times a row's routing weight in the compute dtype: the down projection's input)."""
+    if gate is None:
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
+    else:
+        act = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return act, (act * scale[:, None]).astype(up.dtype)
+
+
+def _expert_products(mm, xr, w_gate, w_up, w_down, scale):
+    """The ONE statement of the held experts on their rows ``xr [rows, d]``,
+    under the training layer and the serving forward alike: ``mm(lhs, rhs)`` is
+    the grouped product each of them runs.  Returns (the rows' outputs with
+    the routing weight inside, the hidden products a backward pass keeps)."""
+    dt = xr.dtype
+    gate = None if w_gate is None else mm(xr, w_gate.astype(dt))
+    up = mm(xr, w_up.astype(dt))
+    # the routing weight goes in before the down projection: the combine is then a plain sum
+    return mm(_hidden(gate, up, scale)[1], w_down.astype(dt)), gate, up
 
 
 def _held_experts_fwd(
@@ -300,16 +317,12 @@ def _held_experts_fwd(
     from determined_tpu.ops import expert_rows, grouped_matmul as gm
 
     layout = gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)
-    dt = x.dtype
     row_token = row_pick // weights.shape[1]
     with jax.named_scope("moe.dispatch"):
         xr = expert_rows.rows_of_tokens(x, row_token, tile_rows, layout)
         scale = _row_weights(weights, row_pick, row_live)
     with jax.named_scope("moe.experts"):
-        gate = gm.gmm(xr, w_gate.astype(dt), layout)
-        up = gm.gmm(xr, w_up.astype(dt), layout)
-        # the routing weight goes in before the down projection: the combine is then a plain sum
-        out = gm.gmm(_hidden(gate, up, scale)[1], w_down.astype(dt), layout)
+        out, gate, up = _expert_products(lambda lhs, rhs: gm.gmm(lhs, rhs, layout), xr, w_gate, w_up, w_down, scale)
     with jax.named_scope("moe.combine"):
         y = expert_rows.tokens_of_rows(out, row_token, tile_rows, layout, x.shape[0])
     # kept for the backward pass: the two hidden products.  The rows are
@@ -326,7 +339,7 @@ def _held_experts_bwd(rows, tile, res, d_y):
     (x, w_gate, w_up, w_down, weights, row_pick, row_live, pick_row, pick_held, tile_rows,
      group_start, tile_group, live_tiles, gate, up) = res
     layout = gm.TileLayout(group_start, tile_group, live_tiles, rows, tile)
-    dt, count = x.dtype, w_gate.shape[0]
+    dt, count = x.dtype, w_up.shape[0]
     row_token = row_pick // weights.shape[1]
     with jax.named_scope("moe.combine"):
         d_out = expert_rows.rows_of_tokens(d_y.astype(dt), row_token, tile_rows, layout)
@@ -339,15 +352,19 @@ def _held_experts_bwd(rows, tile, res, d_y):
         d_hidden = gm.gmm(d_out, w_down.astype(dt), layout, transpose_rhs=True).astype(jnp.float32)
         d_scale = jnp.sum(d_hidden * act, axis=-1)                      # [rows]
         d_act = d_hidden * scale[:, None]
-        g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
-        sig = jax.nn.sigmoid(g32)
-        d_gate = (d_act * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dt)
-        d_up = (d_act * g32 * sig).astype(dt)
-        d_w_gate = gm.tgmm(xr, d_gate, layout, count).astype(w_gate.dtype)
+        if gate is None:  # the two-matrix expert: relu(up)^2
+            d_w_gate = None
+            d_up = (d_act * 2.0 * jax.nn.relu(up.astype(jnp.float32))).astype(dt)
+        else:
+            g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
+            sig = jax.nn.sigmoid(g32)
+            d_gate = (d_act * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dt)
+            d_up = (d_act * g32 * sig).astype(dt)
+            d_w_gate = gm.tgmm(xr, d_gate, layout, count).astype(w_gate.dtype)
         d_w_up = gm.tgmm(xr, d_up, layout, count).astype(w_up.dtype)
-        d_xr = gm.gmm(d_gate, w_gate.astype(dt), layout, transpose_rhs=True) + gm.gmm(
-            d_up, w_up.astype(dt), layout, transpose_rhs=True
-        )
+        d_xr = gm.gmm(d_up, w_up.astype(dt), layout, transpose_rhs=True) if gate is None else gm.gmm(
+            d_gate, w_gate.astype(dt), layout, transpose_rhs=True
+        ) + gm.gmm(d_up, w_up.astype(dt), layout, transpose_rhs=True)
     with jax.named_scope("moe.dispatch"):
         d_x = expert_rows.tokens_of_rows(d_xr, row_token, tile_rows, layout, x.shape[0]).astype(dt)
     with jax.named_scope("moe.combine"):
@@ -386,6 +403,13 @@ class RoutedExperts(nn.Module):
     owns no row of the buffer.
     Under ``"mlp"`` the call takes the layer before's router state and returns
     ``(y, aux, state)``: :func:`route_mlp`.
+
+    ``expert_act`` "relu2": an expert is ``W_down,e relu(W_up,e x)^2``, two
+    matrices and no gate (Nemotron-H's).  ``latent_size``: the held experts read
+    ``x W_latent_in`` and their weighted sum goes through ``W_latent_out``, two
+    projections of ``d x latent_size`` all experts share (Nemotron-3's latent
+    experts); the router and the shared experts (``shared_d_ff`` wide in all)
+    still read the full-width ``x``.
     """
 
     num_experts: int
@@ -413,6 +437,14 @@ class RoutedExperts(nn.Module):
     # identity experts, the router's outputs ``num_experts ..``: a pick there adds
     # ``w x`` and owns no row (:func:`_identity_part`); under "softmax_bias"
     zero_experts: int = 0
+    # "relu2": an expert (routed or shared) is TWO matrices, ``W_down relu(W_up x)^2``, and the layer has no
+    # ``w_gate`` / ``shared_w_gate``.  latent_size: the routed experts' input and output width, between
+    # ``w_latent_in`` [d, latent] and ``w_latent_out`` [latent, d] that all of them share (the router and the
+    # shared experts read the full-width input).  shared_d_ff: the shared experts' whole width (None:
+    # ``shared_experts * d_ff``)
+    expert_act: str = "swiglu"
+    latent_size: Any = None
+    shared_d_ff: Any = None
     param_dtype: Any = jnp.float32
 
     @nn.compact
@@ -457,9 +489,14 @@ class RoutedExperts(nn.Module):
         else:
             router = param("router", (d, outputs), ("embed", None))
             p = {"router": router}
-        w_gate = param("w_gate", (count, d, self.d_ff), ("expert", "embed", "mlp"))
-        w_up = param("w_up", (count, d, self.d_ff), ("expert", "embed", "mlp"))
-        w_down = param("w_down", (count, self.d_ff, d), ("expert", "mlp", "embed"))
+        gated = self.expert_act != "relu2"
+        d_in = self.latent_size or d  # the width the routed experts work in
+        w_gate = param("w_gate", (count, d_in, self.d_ff), ("expert", "embed", "mlp")) if gated else None
+        w_up = param("w_up", (count, d_in, self.d_ff), ("expert", "embed", "mlp"))
+        w_down = param("w_down", (count, self.d_ff, d_in), ("expert", "mlp", "embed"))
+        if self.latent_size:
+            p["w_latent_in"] = param("w_latent_in", (d, d_in), ("embed", None))
+            p["w_latent_out"] = param("w_latent_out", (d_in, d), (None, "embed"))
 
         if mlp or self.router_kind in ("sigmoid_grouped", "softmax_bias"):
             # a fresh bias is small against the scores it is added to: 0.01 beside a sigmoid's 0.5 or a few experts'
@@ -468,8 +505,9 @@ class RoutedExperts(nn.Module):
             spread = 0.25 / outputs if self.router_kind == "softmax_bias" else 0.01
             p["router_bias"] = self.param("router_bias", nn.initializers.normal(spread), (outputs,), jnp.float32)
         if self.shared_experts:
-            wide = self.shared_experts * self.d_ff
-            p["shared_w_gate"] = param("shared_w_gate", (d, wide), ("embed", "mlp"))
+            wide = self.shared_d_ff or self.shared_experts * self.d_ff
+            if gated:
+                p["shared_w_gate"] = param("shared_w_gate", (d, wide), ("embed", "mlp"))
             p["shared_w_up"] = param("shared_w_up", (d, wide), ("embed", "mlp"))
             p["shared_w_down"] = param("shared_w_down", (wide, d), ("mlp", "embed"))
 
@@ -503,13 +541,20 @@ class RoutedExperts(nn.Module):
         self.sow("intermediates", "load", rows.load)
         self.sow("intermediates", "live_rows", rows.layout.live_tiles[0] * rows.layout.tile)
 
+        xe = xf.astype(self.dtype)
+        if self.latent_size:
+            with jax.named_scope("moe.latent"):
+                xe = xe @ p["w_latent_in"].astype(self.dtype)
         y = _held_experts(
-            xf.astype(self.dtype), w_gate, w_up, w_down, weights,
+            xe, w_gate, w_up, w_down, weights,
             rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, rows.tile_rows, *rows.layout,
         )
         if self.expert_axis_name is not None:
-            with jax.named_scope("moe.combine"):
+            with jax.named_scope("moe.combine"):  # in the latent, where there is one: the narrower payload
                 y = jax.lax.psum(y, self.expert_axis_name)
+        if self.latent_size:
+            with jax.named_scope("moe.latent"):
+                y = _latent_out(p, y, self.dtype)
         if self.zero_experts:
             with jax.named_scope("moe.identity"):
                 y = y + _identity_part(weights, picks, e, outputs, xf)[0].astype(y.dtype)
@@ -637,12 +682,23 @@ def _route(
 
 
 def _shared_experts(p: Any, xf: jax.Array, count: int = 1, combine: str = "sum") -> jax.Array:
-    """The shared experts of ``xf [T, d]``: one SwiGLU as wide as all
-    ``count`` of them, which is their sum; their mean under ``combine`` "mean"."""
+    """The shared experts of ``xf [T, d]``: one expert as wide as all ``count``
+    of them, which is their sum (a SwiGLU; without a ``shared_w_gate`` the
+    two-matrix ``relu(.)^2`` expert); their mean under ``combine`` "mean"."""
     dt = xf.dtype
-    hidden = nn.silu(xf @ p["shared_w_gate"].astype(dt)) * (xf @ p["shared_w_up"].astype(dt))
+    if "shared_w_gate" in p:
+        hidden = nn.silu(xf @ p["shared_w_gate"].astype(dt)) * (xf @ p["shared_w_up"].astype(dt))
+    else:
+        hidden = jnp.square(jax.nn.relu(xf @ p["shared_w_up"].astype(dt)))
     out = hidden @ p["shared_w_down"].astype(dt)
     return out / count if combine == "mean" else out
+
+
+def _latent_out(p: Any, y: jax.Array, dtype: Any) -> jax.Array:
+    """The held experts' float32 sum ``y [T, latent]`` back at the model's
+    width: one product for all of a token's picks (``w_latent_out`` is linear
+    and has no bias, so the shares of a layer's experts add up)."""
+    return jnp.dot(y.astype(dtype), p["w_latent_out"].astype(dtype), preferred_element_type=jnp.float32)
 
 
 # Each Mosaic call of the serving forward sits in a jitted function of its own
@@ -680,15 +736,20 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
         if live is not None:
             picks = jnp.where(live.reshape(-1, 1), picks, outputs)  # no expert: never held, and no identity expert
         rows = _sorted_rows(picks, first, count, serving=True)
+    xe = xf
+    if cfg.moe_latent_size:
+        with jax.named_scope("serve.moe.latent"):
+            xe = xf @ p["w_latent_in"].astype(dt)
     with jax.named_scope("serve.moe.experts"):
         layout = rows.layout
         row_token = rows.row_pick // weights.shape[1]
-        xr = expert_rows.rows_of_tokens(xf, row_token, rows.tile_rows, layout)
+        xr = expert_rows.rows_of_tokens(xe, row_token, rows.tile_rows, layout)
         scale = _row_weights(weights, rows.row_pick, rows.row_live)
-        gate = _gmm(xr, p["w_gate"].astype(dt), *layout)
-        up = _gmm(xr, p["w_up"].astype(dt), *layout)
-        out = _gmm(_hidden(gate, up, scale)[1], p["w_down"].astype(dt), *layout)
+        out, _, _ = _expert_products(lambda lhs, rhs: _gmm(lhs, rhs, *layout), xr, p.get("w_gate"), p["w_up"], p["w_down"], scale)
         y = expert_rows.tokens_of_rows(out, row_token, rows.tile_rows, layout, xf.shape[0])
+    if cfg.moe_latent_size:
+        with jax.named_scope("serve.moe.latent"):
+            y = _latent_out(p, y, dt)
     if cfg.moe_shared_experts:
         with jax.named_scope("serve.moe.shared"):
             y = y + _shared_experts(p, xf, cfg.moe_shared_experts, cfg.moe_shared_combine).astype(y.dtype)

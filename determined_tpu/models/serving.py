@@ -147,7 +147,8 @@ def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=Non
     attention read.  Under ``shortcut_block`` the block has two attention
     sublayers (two rows of the kind's arrays, two subtrees) and two dense MLPs;
     its experts read the first sublayer's second norm and join the stream after
-    the second MLP.
+    the second MLP.  Under ``mixer_block`` a layer is the norm and ONE of these:
+    the mixer of its one cache kind, or the experts (a layer of no kind).
     Returns (x, cache, what an expert layer counted or None)."""
 
     def attend(sub: int, x, cache):
@@ -164,6 +165,17 @@ def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=Non
         with jax.named_scope("serve.mlp"):
             return x + _mlp_apply(blk[name], h, cfg.dtype, cfg.mlp_multipliers)
 
+    if cfg.mixer_block:
+        # one norm, ONE mixer, one residual: the experts (a layer of no cache kind), or the one kind's mixer
+        h = _norm_apply(cfg, x, blk["ln1"]["scale"])
+        if cfg.use_moe(i):
+            from determined_tpu.models.moe import serve_routed_experts
+
+            y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
+            return x + y, cache, counted
+        for kind, j, subtree in layer_kinds(cfg, i):
+            x, cache = mixers[kind.name](blk[subtree], x, h, cache, j)
+        return x, cache, None
     x, h, cache = attend(0, x, cache)
     if not cfg.use_moe(i):
         return mlp("mlp", x, h), cache, None

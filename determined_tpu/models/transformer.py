@@ -24,6 +24,9 @@ MeshConfig alone, with:
 - ``shortcut_block``: two attention sublayers and two dense MLPs a block round
   an expert branch that joins the stream after the second MLP (LongCat-Flash's
   double layer), with identity experts behind a biased softmax router;
+- ``mixer_block``: a layer is ONE norm and ONE mixer, a Mamba-2 mixer, attention
+  or the experts alone (Nemotron-H's block); its experts may be two-matrix
+  squared-ReLU ones that work in a latent between two shared projections;
 - attention dispatch: ring attention when the mesh has a "seq" axis,
   Pallas flash attention on TPU otherwise, reference for tiny seqs;
 - bf16 compute with f32 params, per-block remat for long-context memory.
@@ -58,7 +61,10 @@ HYBRID = "attention_mamba2"
 #: compressed convolutional attention: full causal attention over q and k that two causal
 #: convolutions mixed over time inside their latent (``CompressedAttention``)
 CCA = "cca"
-LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA)
+#: under ``mixer_block`` a layer is ONE mixer (Nemotron-H's block): a Mamba-2 mixer alone, the dropless experts
+#: alone, or (``full_attention``) attention alone
+MAMBA2, EXPERTS = "mamba2", "experts"
+LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA, MAMBA2, EXPERTS)
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
@@ -139,6 +145,16 @@ class TransformerConfig:
     # buffer (``moe_experts`` stays the count of real experts, the range
     # ``moe_experts_held`` lies in)
     moe_zero_experts: int = 0
+    # An expert's form (the dropless layer's routed and shared experts alike): "swiglu", three matrices, ``W_down
+    # (silu(W_gate x) * W_up x)``; "relu2", TWO, ``W_down relu(W_up x)^2`` (no ``w_gate`` leaf).
+    # moe_latent_size: the routed experts work in a latent of that width between two projections all of them
+    # share (leaves ``w_latent_in`` [d_model, latent] and ``w_latent_out`` [latent, d_model], no bias):
+    # ``(sum over held picks of w_e Expert_e(x W_in)) W_out``; the router and the shared experts read the
+    # full-width ``x``.  moe_shared_intermediate_size: the width of all shared experts together (None:
+    # moe_shared_experts x an expert's)
+    moe_expert_act: str = "swiglu"
+    moe_latent_size: Optional[int] = None
+    moe_shared_intermediate_size: Optional[int] = None
     # The block's norms: "rms" (no mean taken) or "layernorm" (mean and variance
     # over the features in float32, a weight and no bias), with norm_eps.
     # parallel_block: ONE norm a block, attention and the MLP or experts both
@@ -155,11 +171,17 @@ class TransformerConfig:
     # and join the stream after the second MLP: ``a0 = x + Attn0(LN(x)); u =
     # LN(a0); m = Experts(u); b0 = a0 + MLP0(u); a1 = b0 + Attn1(LN(b0)); b1 = a1
     # + MLP1(LN(a1)); out = b1 + m``.  Serving keeps two rows a token a block.
+    # mixer_block (Nemotron-H's block): a layer is ONE norm (``ln1``), ONE mixer and one residual, ``x +
+    # Mixer(LN(x))``, the mixer by the layer's type, a tuple a layer: ``full_attention`` (attention alone, subtree
+    # ``attn``), ``mamba2`` (a Mamba-2 mixer alone, ``ssm``) or ``experts`` (the dropless experts alone, ``moe``:
+    # such a layer keeps nothing of the past).  ``layer_types`` says which layers hold experts (``moe_every`` and
+    # ``dense_prefix`` say nothing here) and no layer has a dense MLP.
     norm: str = "rms"
     norm_eps: float = 1e-6
     parallel_block: bool = False
     residual_scaling: bool = False
     shortcut_block: bool = False
+    mixer_block: bool = False
     tie_embeddings: bool = False
     logit_scale: float = 1.0
     # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
@@ -262,13 +284,37 @@ class TransformerConfig:
         setattr_("mlp_multipliers", tuple(float(m) for m in self.mlp_multipliers))
         if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
             raise ValueError("ssm_multipliers has one scalar for each of z, x, B, C, dt and mlp_multipliers two")
+        types = set(self.layer_types or (FULL,))
+        if self.mixer_block:
+            refused = [
+                what for what, there in (
+                    ("parallel_block", self.parallel_block), ("shortcut_block", self.shortcut_block),
+                    ("residual_scaling", self.residual_scaling), ("latent attention (kv_lora_rank)", self.latent),
+                    ("pipeline stages (a `seq` or `expert` axis)", self.seq_axis_name is not None or self.expert_axis_name is not None),
+                    ("a layer type other than full_attention, mamba2 or experts", not types <= {FULL, MAMBA2, EXPERTS}),
+                    ("moe_router mlp", self.moe_router == "mlp"),
+                ) if there
+            ]
+            if refused:
+                raise ValueError(
+                    "mixer_block is one norm and ONE mixer a layer (full_attention, mamba2 or experts, by layer_types); "
+                    "it does not run with " + ", ".join(refused)
+                )
+            if (EXPERTS in types) != (self.moe_experts > 0) or (EXPERTS in types and not self.moe_top_k):
+                raise ValueError(
+                    "mixer_block: the experts layers are those layer_types names `experts`, and they are dropless "
+                    f"(moe_experts > 0 and moe_top_k > 0 with them, moe_experts 0 without; got {self.moe_experts} experts, "
+                    f"top-{self.moe_top_k}, layer_types {self.layer_types})"
+                )
+        elif types & {MAMBA2, EXPERTS}:
+            raise ValueError("a mamba2 or an experts layer is a layer of ONE mixer: it belongs to mixer_block")
         if self.ssm_layers and (
             min(self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_groups) < 1 or self.ssm_conv < 2
             or self.ssm_heads % self.ssm_groups or self.latent or self.parallel_block or self.seq_axis_name is not None
         ):
             raise ValueError(
                 "an attention_mamba2 layer needs ssm_heads (whole groups of them), ssm_head_dim, ssm_state and "
-                "ssm_conv >= 2, in a sequential block, without latent attention or a `seq` axis"
+                "ssm_conv >= 2, in a sequential block, without latent attention or a `seq` axis (a mamba2 layer likewise)"
             )
         if CCA in (self.layer_types or ()) and (
             self.latent or self.parallel_block or self.seq_axis_name is not None or self.n_heads % self.kv_heads
@@ -337,6 +383,16 @@ class TransformerConfig:
             )
         if (self.moe_router != "softmax" or self.moe_shared_experts) and not self.moe_top_k:
             raise ValueError("moe_router and moe_shared_experts belong to moe_top_k > 0")
+        if self.moe_expert_act not in ("swiglu", "relu2") or (
+            (self.moe_expert_act != "swiglu" or self.moe_latent_size is not None) and not self.moe_top_k
+        ) or (self.moe_latent_size is not None and int(self.moe_latent_size) < 1) or (
+            self.moe_shared_intermediate_size is not None and (not self.moe_shared_experts or int(self.moe_shared_intermediate_size) < 1)
+        ):
+            raise ValueError(
+                "moe_expert_act is swiglu or relu2; it and moe_latent_size >= 1 belong to moe_top_k > 0, and "
+                f"moe_shared_intermediate_size >= 1 to moe_shared_experts (got {self.moe_expert_act!r}, "
+                f"{self.moe_latent_size}, {self.moe_shared_intermediate_size})"
+            )
         if self.moe_shared_combine not in ("sum", "mean") or self.norm not in ("rms", "layernorm"):
             raise ValueError(
                 f"moe_shared_combine is sum or mean and norm is rms or layernorm "
@@ -378,7 +434,9 @@ class TransformerConfig:
 
     def use_moe(self, i: int) -> bool:
         """Whether block ``i`` holds experts: after the dense prefix, every
-        ``moe_every``-th block."""
+        ``moe_every``-th block; under ``mixer_block`` the layers of type ``experts``."""
+        if self.mixer_block:
+            return self.layer_type(i) == EXPERTS
         j = i - self.dense_prefix
         return self.moe_experts > 0 and j >= 0 and (j % self.moe_every) == self.moe_every - 1
 
@@ -416,8 +474,14 @@ class TransformerConfig:
     def ssm_layers(self) -> Tuple[int, ...]:
         """The layers with a Mamba-2 mixer, in order: serving keeps a state and
         a convolution tail a decode lane for each (``models/cache_kinds.py``),
-        beside what their attention heads keep."""
-        return tuple(i for i in range(self.n_layers) if self.layer_type(i) == HYBRID)
+        beside what their attention heads keep (a ``mamba2`` layer has none)."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) in (HYBRID, MAMBA2))
+
+    @property
+    def rowless_layers(self) -> Tuple[int, ...]:
+        """The layers that keep no row a token in any pool or ring: power
+        retention's, and under ``mixer_block`` a Mamba-2 mixer or experts alone."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) in (RETENTION, MAMBA2, EXPERTS))
 
     @property
     def ssm_width(self) -> int:
@@ -436,7 +500,7 @@ class TransformerConfig:
     @property
     def paged_layers(self) -> int:
         """How many rows of the paged pool a token owns: one an attention sublayer of each layer that keeps its rows there."""
-        return (self.n_layers - len(self.window_layers) - len(self.retention_layers)) * self.attn_sublayers
+        return (self.n_layers - len(self.window_layers) - len(self.rowless_layers)) * self.attn_sublayers
 
     def rope(self, layer_type: str) -> Optional["Rope"]:
         """How a layer of this type rotates q and k; None: it does not."""
@@ -1022,6 +1086,9 @@ class Block(nn.Module):
                     shared_experts=cfg.moe_shared_experts,
                     shared_combine=cfg.moe_shared_combine,
                     zero_experts=cfg.moe_zero_experts,
+                    expert_act=cfg.moe_expert_act,
+                    latent_size=cfg.moe_latent_size,
+                    shared_d_ff=cfg.moe_shared_intermediate_size,
                     param_dtype=cfg.param_dtype,
                     router_hidden=cfg.router_hidden_size or 0,
                     norm_eps=cfg.norm_eps,
@@ -1057,6 +1124,17 @@ class Block(nn.Module):
             return Attention(cfg, self.mesh, self.layer_type, name=name)(h)
 
         h = norm("ln1")(x)
+        if cfg.mixer_block:
+            # one norm, ONE mixer, one residual: the layer's type says which
+            aux, handed = jnp.zeros((), jnp.float32), state
+            if self.layer_type == EXPERTS:
+                y, aux, handed = ffn(h)
+            else:
+                y = Mamba2(cfg, name="ssm")(h) if self.layer_type == MAMBA2 else attend("attn", h)
+            x = x + y
+            if cfg.partition_params:
+                x = with_sharding_constraint(x, ("batch", "length", "embed"), mesh=self.mesh)
+            return x, aux, handed
         att = attend("attn", h)
         if cfg.shortcut_block:
             # the experts read the first sublayer's second norm and join the stream after the second MLP
@@ -1364,9 +1442,10 @@ def kv_bytes_per_token(cfg: TransformerConfig) -> int:
     """Bytes of cache a token owns over all layers that cache tokens, as
     attention reads them (a latent row's padding is not counted; in a window
     layer a token owns them only while it is inside the window; a retention
-    layer caches no token: ``state_bytes_per_slot``)."""
+    layer caches no token: ``state_bytes_per_slot``; nor does a Mamba-2 mixer or
+    an expert layer alone, under ``mixer_block``)."""
     values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
-    return (cfg.n_layers - len(cfg.retention_layers)) * cfg.attn_sublayers * values * jnp.dtype(cfg.dtype).itemsize
+    return (cfg.n_layers - len(cfg.rowless_layers)) * cfg.attn_sublayers * values * jnp.dtype(cfg.dtype).itemsize
 
 
 #: the dtype of a state a lane holds (a retention layer's and its normaliser, a Mamba-2 layer's): sums over a whole context
@@ -1566,6 +1645,19 @@ class LMTrial(JaxTrial):
         layer_types = g("layer_types", None)
         if pipe > 1 and bool(g("tie_embeddings", False)):
             raise ValueError("tie_embeddings: the embedding and the head sit on different pipeline stages")
+        carried = [
+            what for what, there in (
+                ("a cca layer", CCA in (layer_types or ())), ("moe_router mlp", g("moe_router", "softmax") == "mlp"),
+                ("residual_scaling", bool(g("residual_scaling", False))),
+                ("shortcut_block (its expert branch beside it)", bool(g("shortcut_block", False))),
+                ("mixer_block (its layers are not alike: a stage stacks one leaf a layer)", bool(g("mixer_block", False))),
+            ) if there
+        ]
+        if pipe > 1 and carried:
+            raise ValueError(
+                f"pipe={pipe}: {', '.join(carried)} not run inside pipeline stages: the stage function hands on the "
+                "residual stream alone, not the router's state beside it"
+            )
         if pipe > 1 and (int(g("moe_experts", 0)) > 0 or layer_types):
             # MoE and layer types compose with pipe when every chunk sees the
             # same layer pattern: their periods must divide layers-per-chunk
@@ -1581,18 +1673,6 @@ class LMTrial(JaxTrial):
                     f"pipe={pipe} needs the period of layer_types to divide "
                     f"layers-per-chunk ({lps}): layer j of every chunk is one stacked leaf"
                 )
-        carried = [
-            what for what, there in (
-                ("a cca layer", CCA in (layer_types or ())), ("moe_router mlp", g("moe_router", "softmax") == "mlp"),
-                ("residual_scaling", bool(g("residual_scaling", False))),
-                ("shortcut_block (its expert branch beside it)", bool(g("shortcut_block", False))),
-            ) if there
-        ]
-        if pipe > 1 and carried:
-            raise ValueError(
-                f"pipe={pipe}: {', '.join(carried)} not run inside pipeline stages: the stage function hands on the "
-                "residual stream alone, not the router's state beside it"
-            )
         mesh = self.context.mesh
         if int(g("moe_top_k", 0)) and pipe <= 1 and mesh is not None and mesh.size > 1:
             raise ValueError(
@@ -1639,6 +1719,10 @@ class LMTrial(JaxTrial):
             moe_shared_combine=str(g("moe_shared_combine", "sum")),
             moe_zero_experts=int(g("moe_zero_experts", 0)),
             shortcut_block=bool(g("shortcut_block", False)),
+            mixer_block=bool(g("mixer_block", False)),
+            moe_expert_act=str(g("moe_expert_act", "swiglu")),
+            moe_latent_size=g("moe_latent_size", None),
+            moe_shared_intermediate_size=g("moe_shared_intermediate_size", None),
             q_latent_scale=float(g("q_latent_scale", 1.0)),
             kv_latent_scale=float(g("kv_latent_scale", 1.0)),
             norm=str(g("norm", "rms")),
@@ -1715,17 +1799,34 @@ class LMTrial(JaxTrial):
             r = cfg.router_hidden_size
             router = d * r + 2 * r * r + r * cfg.moe_experts
         for i in range(cfg.n_layers):
+            if cfg.mixer_block and cfg.layer_type(i) != FULL:  # ONE mixer: no attention here, and no dense MLP anywhere
+                if cfg.layer_type(i) == MAMBA2:
+                    n_params += d * (2 * cfg.ssm_width + 2 * cfg.ssm_groups * cfg.ssm_state + cfg.ssm_heads) + cfg.ssm_width * d
+                else:
+                    n_params += self._expert_layer_params(cfg, router)
+                continue
             n_params += attn * cfg.attn_sublayers
             if cfg.layer_type(i) == CCA:  # the two convolutions' products
                 n_params += (cfg.n_heads + cfg.kv_heads) * cfg.head_dim * (cfg.cca_time0 + cfg.cca_time1 * cfg.head_dim)
             if cfg.use_moe(i):
-                held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
-                active = cfg.moe_top_k * held / outputs if cfg.moe_top_k else 2
-                n_params += router + active * 3 * d * (cfg.moe_intermediate_size or cfg.ff_dim)
-            if cfg.shortcut_block or not cfg.use_moe(i):  # a shortcut block's two dense MLPs stand beside its experts
+                n_params += self._expert_layer_params(cfg, router)
+            if not cfg.mixer_block and (cfg.shortcut_block or not cfg.use_moe(i)):  # a shortcut block's two dense MLPs stand beside its experts
                 n_params += cfg.attn_sublayers * 3 * d * cfg.ff_dim
             seen += cfg.attn_sublayers * min(cfg.window(cfg.layer_type(i)) or cfg.max_seq_len, cfg.max_seq_len)
         return float(6 * n_params + 12 * seen * width)
+
+    @staticmethod
+    def _expert_layer_params(cfg: TransformerConfig, router: int) -> float:
+        """What a token multiplies with in an expert layer: the router, its
+        expected picks among the held experts (at the latent width where the
+        experts work in one, with the two projections round them), the shared experts."""
+        d, width = cfg.d_model, cfg.moe_intermediate_size or cfg.ff_dim
+        mats = 2 if cfg.moe_expert_act == "relu2" else 3  # an expert's matrices
+        held = (cfg.moe_experts_held or (0, cfg.moe_experts))[1]
+        active = cfg.moe_top_k * held / (cfg.moe_experts + cfg.moe_zero_experts) if cfg.moe_top_k else 2
+        shared = cfg.moe_shared_intermediate_size or cfg.moe_shared_experts * width
+        latent = 2 * d * cfg.moe_latent_size if cfg.moe_latent_size else 0
+        return router + active * mats * (cfg.moe_latent_size or d) * width + latent + mats * d * shared
 
     def build_model(self) -> TransformerLM:
         return TransformerLM(self._cfg(), mesh=self.context.mesh)

@@ -16,8 +16,10 @@ and XLA lays no second copy of the pool out); decode lane ``l`` owns slot
 is sent to: see :func:`ssm_decode`).
 
 * :func:`ssm_decode`: one token a lane, in place.  The Pallas kernel's grid is
-  (group, lane): a program reads the ``heads / groups`` states that share the
-  group's ``B`` and ``C`` once, decays each, adds the token (a column of ``dt
+  (groups of heads, lane): a program reads the ``heads / groups`` states that
+  share a group's ``B`` and ``C`` once (of as many groups as make
+  ``_PROGRAM_BYTES`` of state: one at Falcon-H1's heads of 128 x 256, four at
+  Nemotron-H's of 64 x 128), decays each, adds the token (a column of ``dt
   x`` times the row ``B``), reads it out (``C`` times the new state's
   transpose, on the MXU) and writes the state back into the pool's own buffer
   (``input_output_aliases``).  An idle lane's program is pointed at the
@@ -48,6 +50,9 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 #: are 2 MB, held twice coming in and twice going out, beside what the unrolled
 #: heads spill
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+#: the state one program of the decode kernel aims to move each way: a grid step costs ~0.35 us whatever it does,
+#: and 2 MB take 2.6 us at the chip's 819 GB/s
+_PROGRAM_BYTES = 2 * 1024 * 1024
 
 
 def _on_tpu() -> bool:
@@ -62,12 +67,22 @@ def state_shape(layers: int, lanes: int, heads: int, head_dim: int, d_state: int
 
 
 def kernel_takes(heads: int, groups: int, head_dim: int, d_state: int, state_dtype) -> bool:
-    """Whether the decode kernel runs these shapes: a head's width and its
-    state's are whole lane tiles, a group's heads whole sublane tiles."""
+    """Whether the decode kernel runs these shapes: a head's state is whole
+    tiles (its width whole sublane tiles of either dtype, its state values whole
+    lane tiles: a head of 64 is half a lane tile in ``y`` alone, which is a
+    row's masked store), a group's heads whole sublane tiles."""
     return (
-        head_dim % 128 == 0 and d_state % 128 == 0 and heads % groups == 0 and (heads // groups) % 8 == 0
+        head_dim % 64 == 0 and d_state % 128 == 0 and heads % groups == 0 and (heads // groups) % 8 == 0
         and jnp.dtype(state_dtype).itemsize in (2, 4)
     )
+
+
+def groups_a_program(groups: int, heads_a_group: int, head_dim: int, d_state: int, state_dtype) -> int:
+    """How many groups' heads one program of the decode kernel takes, from the
+    shapes: the most that divide ``groups`` and whose states stay within
+    ``_PROGRAM_BYTES`` (at least one)."""
+    group_bytes = heads_a_group * head_dim * d_state * jnp.dtype(state_dtype).itemsize
+    return max(n for n in range(1, groups + 1) if groups % n == 0 and (n == 1 or n * group_bytes <= _PROGRAM_BYTES))
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +172,7 @@ def ssm_decode(
         impl = "kernel" if _on_tpu() and takes else "jnp"
     if impl != "jnp" and not takes:
         raise ValueError(
-            f"the ssm kernel needs a head and a state of whole 128-wide tiles and 8 heads a group or a multiple "
+            f"the ssm kernel needs a head of whole 64-wide and a state of whole 128-wide tiles and 8 heads a group or a multiple "
             f"(got {heads} heads over {groups} groups, P {p}, N {n}, {state.dtype})"
         )
     return _ssm_decode(x, B, C, dt, A, Dskip, state, jnp.asarray(layer, jnp.int32), live, impl=impl)
@@ -189,22 +204,25 @@ def _ssm_decode_jnp(enters, kept, B, C, state, layer, live):
     return y, jax.lax.dynamic_update_slice(state, s1[None], (layer, 0, 0, 0, 0))
 
 
-def _ssm_kernel(layer_ref, live_ref, enters_ref, kept_ref, b_ref, c_ref, s_ref, y_ref, s_out, *, heads):
-    """One (group, lane): ``enters_ref`` [P, heads] holds ``dt x`` of the group's
-    heads as columns, ``kept_ref`` [P, heads] each head's decay down its column,
-    ``b_ref`` / ``c_ref`` [1, N] the group's B and C, ``s_ref`` [heads, P, N] the
-    heads' states: the lane's own, or the scratch slot's where it is idle."""
+def _ssm_kernel(layer_ref, live_ref, enters_ref, kept_ref, b_ref, c_ref, s_ref, y_ref, s_out, *, heads, groups):
+    """One (``groups`` groups of ``heads`` heads each, lane): ``enters_ref`` [P,
+    groups * heads] holds ``dt x`` of the heads as columns, ``kept_ref`` alike
+    each head's decay down its column, ``b_ref`` / ``c_ref`` [groups, N] the
+    groups' B and C, ``s_ref`` [groups * heads, P, N] the heads' states: the
+    lane's own, or the scratch slot's where it is idle."""
     f32 = jnp.float32
 
     @pl.when(live_ref[pl.program_id(1)] > 0)
     def _update():
-        b_row = b_ref[...]
-        c_rows = jnp.broadcast_to(c_ref[...], (8, c_ref.shape[-1]))  # a whole sublane tile; row 0 is read
-        for h in range(heads):
-            new = kept_ref[:, h:h + 1] * s_ref[h].astype(f32) + enters_ref[:, h:h + 1] * b_row  # [P, N]
-            s_out[h] = new.astype(s_out.dtype)
-            read = jax.lax.dot_general(c_rows, new, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
-            y_ref[h:h + 1, :] = read[0:1, :]
+        for g in range(groups):
+            b_row = b_ref[...] if groups == 1 else b_ref[g:g + 1, :]
+            c_row = c_ref[...] if groups == 1 else c_ref[g:g + 1, :]
+            c_rows = jnp.broadcast_to(c_row, (8, c_ref.shape[-1]))  # a whole sublane tile; row 0 is read
+            for h in range(g * heads, (g + 1) * heads):
+                new = kept_ref[:, h:h + 1] * s_ref[h].astype(f32) + enters_ref[:, h:h + 1] * b_row  # [P, N]
+                s_out[h] = new.astype(s_out.dtype)
+                read = jax.lax.dot_general(c_rows, new, (((1,), (1,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
+                y_ref[h:h + 1, :] = read[0:1, :]
 
     @pl.when(live_ref[pl.program_id(1)] <= 0)
     def _idle():  # ``s_out`` is left unwritten: whatever goes back lands in the scratch slot, which nobody reads
@@ -213,8 +231,9 @@ def _ssm_kernel(layer_ref, live_ref, enters_ref, kept_ref, b_ref, c_ref, s_ref, 
 
 def _ssm_decode_pallas(enters, kept, B, C, state, layer, live, *, interpret: bool):
     lanes, h, p = enters.shape
-    g, n = B.shape[1], B.shape[2]
-    r = h // g
+    n = B.shape[2]
+    per = groups_a_program(B.shape[1], h // B.shape[1], p, n, state.dtype)
+    g, r = B.shape[1] // per, h // B.shape[1] * per  # the grid's blocks of heads, and the heads of one
     scratch = state.shape[1] - 1
     at_heads = lambda gi, li, *_: (li, gi, 0)  # noqa: E731
     at_group = lambda gi, li, *_: (li, gi, 0, 0)  # noqa: E731
@@ -222,16 +241,17 @@ def _ssm_decode_pallas(enters, kept, B, C, state, layer, live, *, interpret: boo
     # fast axis, so that idle lanes in a row ask for the same block and it is moved once for all of them
     at_slot = lambda gi, li, lay, alive: (lay[0], jnp.where(alive[li] > 0, li, scratch), gi, 0, 0)  # noqa: E731
     columns = lambda t: t.reshape(lanes, g, r, p).transpose(0, 1, 3, 2)  # noqa: E731  (a head's P values down a column)
+    rows = lambda t: t[:, :, None, :] if per == 1 else t.reshape(lanes, g, per, n)  # noqa: E731  (a program's groups' B or C)
     y, state = pl.pallas_call(
-        functools.partial(_ssm_kernel, heads=r),
+        functools.partial(_ssm_kernel, heads=r // per, groups=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(g, lanes),
             in_specs=[
                 pl.BlockSpec((None, None, p, r), at_group),
                 pl.BlockSpec((None, None, p, r), at_group),
-                pl.BlockSpec((None, None, 1, n), at_group),
-                pl.BlockSpec((None, None, 1, n), at_group),
+                pl.BlockSpec((None, None, per, n), at_group),
+                pl.BlockSpec((None, None, per, n), at_group),
                 pl.BlockSpec((None, None, r, p, n), at_slot),
             ],
             out_specs=[
@@ -249,6 +269,6 @@ def _ssm_decode_pallas(enters, kept, B, C, state, layer, live, *, interpret: boo
         name="ssm_decode",
     )(
         layer.reshape(1), live.astype(jnp.int32),
-        columns(enters), columns(jnp.broadcast_to(kept[..., None], enters.shape)), B[:, :, None, :], C[:, :, None, :], state,
+        columns(enters), columns(jnp.broadcast_to(kept[..., None], enters.shape)), rows(B), rows(C), state,
     )
     return y, state

@@ -1,6 +1,6 @@
 """The table of cache kinds (``models/cache_kinds.py``) is where the forward,
 the kernels and the engine take what they know of a model's cache: over the
-benchmark's six serving architectures at their tiny sizes, the cache is the
+benchmark's seven serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
@@ -31,6 +31,7 @@ ARCHS = {
     "power_retention": ("state_slot",),
     "falcon_h1": ("paged_kv", "ssm_slot"),
     "longcat_scmoe": ("paged_latent",),
+    "nemotron_h": ("paged_kv", "ssm_slot"),
 }
 
 
@@ -51,13 +52,15 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
     kinds = cache_kinds(cfg)
     assert tuple(kind.name for kind in kinds) == ARCHS[arch_name] and set(kinds) <= set(CACHE_KINDS)
     # every attention sublayer of a layer (one, or under shortcut_block two) is of one kind or of several in the
-    # table's order, at the next row of each kind's arrays, and reads its own subtree of the block
+    # table's order, at the next row of each kind's arrays, and reads its own subtree of the block; under mixer_block
+    # a layer is of ONE kind, or (its expert layers) of none
     rows = {kind.name: 0 for kind in kinds}
     assert cfg.attn_sublayers == (2 if arch_name == "longcat_scmoe" else 1)
     for i in range(cfg.n_layers):
         for sub in range(cfg.attn_sublayers):
             mine = layer_kinds(cfg, i, sub)
-            assert mine and [kind for kind, _, _ in mine] == [kind for kind in CACHE_KINDS if i in kind.layers(cfg)]
+            assert (len(mine) == (0 if cfg.use_moe(i) else 1)) if cfg.mixer_block else mine
+            assert [kind for kind, _, _ in mine] == [kind for kind in CACHE_KINDS if i in kind.layers(cfg)]
             for kind, j, subtree in mine:
                 assert kind in kinds and j == rows[kind.name] and kind.layers(cfg)[j // cfg.attn_sublayers] == i
                 assert subtree == kind.params + ("_1" if sub else "") and subtree in params[f"block_{i}"]

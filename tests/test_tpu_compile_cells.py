@@ -501,6 +501,92 @@ def test_the_falcon_cells_programs_compile_over_a_layer_of_two_kinds(tpu_devices
     print(which, "args", mem.argument_size_in_bytes, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes)
 
 
+def test_the_ssm_decode_kernel_compiles_at_the_nemotron_cells_shape(tpu_devices):
+    """64 lanes x 128 Mamba-2 heads of 64 over 8 groups of 128 state values
+    against a float32 state pool of five layers and 65 slots: one kernel, four
+    groups' 64 heads a program (2 MB of state each way), the pool updated where
+    it lies (aliased, no scratch the size of a layer's state)."""
+    ssm_mod = importlib.import_module("determined_tpu.ops.ssm")
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    state = ssm_mod.state_shape(5, 64, 128, 64, 128)
+    assert state == (5, 65, 128, 64, 128) and ssm_mod.kernel_takes(128, 8, 64, 128, jnp.float32)
+    assert ssm_mod.groups_a_program(8, 16, 64, 128, jnp.float32) == 4
+
+    def fn(x, b, c, dt, a, skip, pool, live):
+        return ssm_mod.ssm_decode(x, b, c, dt, a, skip, pool, 3, live)
+
+    compiled = jax.jit(fn, donate_argnums=(6,)).lower(
+        aval((64, 128, 64), jnp.bfloat16), aval((64, 8, 128), jnp.bfloat16), aval((64, 8, 128), jnp.bfloat16),
+        aval((64, 128), jnp.float32), aval((128,), jnp.float32), aval((128,), jnp.float32), aval(state, jnp.float32),
+        aval((64,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == 1 and "ssm_decode" in text
+    assert mem.alias_size_in_bytes >= 4 * math.prod(state) and mem.temp_size_in_bytes < 16 * 1024**2
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_nemotron_cells_programs_compile_over_layers_of_one_mixer_each(tpu_devices, monkeypatch, which):
+    """The cell's decode step and prefill walk at its widths, lanes, pool and
+    state pool, bfloat16 leaves, all eleven layers (five Mamba-2, five expert,
+    one attention): the weights, both pools and the program's scratch fit the
+    chip's 15.75 GiB; the cache is donated and no second copy of a pool is
+    held; each mixer keeps its scopes, the state kernel its name under its own
+    (five of them), the expert layers their grouped products under theirs and
+    the two latent projections under ``serve.moe.latent``; the paged kernel
+    multiplies 16 queries a KV head."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.cache_kinds import PAGED_KV, SSM_SLOT, cache_kinds, layers_by_kind
+    from determined_tpu.models.serving import transformer_decode, transformer_prefill_chunked
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, ssm_pool_shapes
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
+    one = SingleDeviceSharding(tpu_devices[0])
+    letters = {"M": "mamba2", "*": "full_attention", "E": "experts"}
+    cfg = TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=11, n_heads=32, n_kv_heads=2, head_dim=128, max_seq_len=12288, norm_eps=1e-5,
+        mixer_block=True, layer_types=tuple(letters[c] for c in "MEMEMEMEM*E"), rope_parameters={"full_attention": {"rope_type": "none"}},
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8, ssm_conv=4, ssm_chunk=128, param_dtype=jnp.bfloat16,
+        moe_experts=512, moe_top_k=22, moe_intermediate_size=2688, moe_experts_held=(0, 128), moe_router="sigmoid_grouped",
+        moe_routed_scaling=5.0, moe_shared_experts=1, moe_shared_intermediate_size=5376, moe_expert_act="relu2", moe_latent_size=1024,
+    )
+    assert cache_kinds(cfg) == (PAGED_KV, SSM_SLOT) and layers_by_kind(cfg) == {"paged_kv": 1, "ssm_slot": 5, "none": 5}
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    pool, (state, tail) = kv_cache_shape(cfg, 32769, 16), ssm_pool_shapes(cfg, 64)
+    assert pool == (1, 32769, 16, 256) and state == (5, 65, 128, 64, 128) and tail == (5, 64, 3, 10240)
+    cache = {"k": aval(pool, jnp.bfloat16), "v": aval(pool, jnp.bfloat16), "ssm": aval(state, jnp.float32), "conv": aval(tail, jnp.bfloat16)}
+    if which == "decode":
+        fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+        args = (params, aval((64,)), aval((64,)), aval((64, 768)), cache)
+    else:
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg, chunk_tokens=256), donate_argnums=(5,))
+        args = (params, aval((1, 8192)), aval((1,)), aval((1,)), aval((1, 768)), cache, aval((1,)))
+    compiled = fn.lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache_bytes = 2 * 2 * math.prod(pool) + 4 * math.prod(state) + 2 * math.prod(tail)
+    assert mem.alias_size_in_bytes >= cache_bytes                                    # the cache is donated: no second copy of a pool
+    assert mem.argument_size_in_bytes >= 2 * 4_648_163_712 + cache_bytes
+    # what the chip must hold at once: the arguments (the weights, both pools), what is not aliased of the output, the scratch
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.75 * 1024**3
+    assert mem.temp_size_in_bytes < (256 if which == "decode" else 1024) * 1024**2
+    scopes = program_scopes(text)
+    assert {"serve.attn.qkv", "serve.kv.write", "serve.attn.attend", "serve.attn.out", "serve.mamba2.in", "serve.mamba2.state",
+            "serve.mamba2.out", "serve.moe.route", "serve.moe.latent", "serve.moe.experts", "serve.moe.shared", "serve.embed",
+            "serve.head"} <= set(scopes) and not {"serve.mlp", "serve.ssm.state"} & set(scopes)
+    if which == "decode":   # a Mamba-2 layer: the state kernel; the attention layer: the paged kernel; an expert layer: rows in, two products, rows out
+        assert _kernels(text) == 5 + 1 + 5 * 4 and len({n for n in scopes["serve.mamba2.state"] if n.startswith("ssm_decode")}) == 5
+        assert len({n for n in scopes["serve.moe.experts"] if n.startswith("moe_gmm")}) == 10
+        assert paged_mod.attn_products(16) == "per_kv_head"
+    print(which, "args", mem.argument_size_in_bytes, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes)
+
+
 # -- the decode step's sampler: one call over all lanes, at the serving cells' logits ------
 
 
