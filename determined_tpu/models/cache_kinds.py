@@ -47,7 +47,7 @@ from determined_tpu.models.transformer import (
 )
 from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
 from determined_tpu.ops.paged_attention import (
-    attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
+    COPY_SCHEDULE, attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
 )
 from determined_tpu.ops.retention import FOLD_EVERY, retention_chunk, retention_decode
 from determined_tpu.ops.ssm import ssm_chunk, ssm_decode
@@ -252,12 +252,19 @@ def _layers_report(cfg: TransformerConfig) -> Dict[str, Any]:
 
 def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """``attn_products``: what a tile of the GQA decode kernel multiplies at this
-    model's heads (``ops/paged_attention.py``); absent for latent layers, whose
-    heads all share a row, and where no layer reads K and V.  Under
-    ``mixer_block`` also ``layers_by_kind``."""
+    model's heads (``ops/paged_attention.py``), and how its tiles are copied
+    (``tile_copies``, ``lane_prefetch``: that file's ``COPY_SCHEDULE``); absent
+    for latent layers, whose heads all share a row, and where no layer reads K
+    and V.  Under ``mixer_block`` also ``layers_by_kind``."""
     if cfg.latent or len(cfg.rowless_layers) == cfg.n_layers:
         return {}
-    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads), **_rows_report(cfg), **_layers_report(cfg)}
+    return {"attn_products": attn_products(cfg.n_heads // cfg.kv_heads), **COPY_SCHEDULE, **_rows_report(cfg), **_layers_report(cfg)}
+
+
+def _latent_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
+    """How the latent decode kernel's tiles are copied (``COPY_SCHEDULE``), where a
+    layer keeps latent rows; and ``rows_per_token``."""
+    return {**COPY_SCHEDULE, **_rows_report(cfg)} if cfg.latent and cfg.paged_layers else {}
 
 
 def _ring_step(cfg: TransformerConfig, rows: Rows, cache: Dict[str, jax.Array], table: bool = False):
@@ -690,6 +697,9 @@ class CacheKind:
     #: float32, one each: the newest step's value for ``report``, never summed and no step counter
     gauges: Tuple[str, ...] = ()
     gauge: Optional[Callable] = None
+    #: ``walked(cfg)`` -> (rows, window): the rows of its arrays a paged decode kernel walks a step, one call
+    #: each, and the window it walks under (``ops/paged_attention.py walk_counts``); None: no kernel walks it
+    walked: Optional[Callable] = None
     #: ``report(cfg, sizes, live lanes, the newest step's gauges by name)``: what it adds to ``/stats``, asked of
     #: EVERY kind of the table (one without layers in the model says so itself: nothing, or an empty entry);
     #: ``setup(cfg, sizes)``: what a kind of the model adds to the ``serve.setup.kv_pool`` span
@@ -730,6 +740,7 @@ PAGED_KV = CacheKind(
     table=lambda cfg, rows, cache: _kv_mixer(
         cfg, PAGED_KV, rows, rows.where, _attend_table(cfg, rows.block_tables, _table_mask(rows))),
     wide=lambda cfg, rows, cache: _kv_mixer(cfg, PAGED_KV, rows, rows.where, _attend_local),
+    walked=lambda cfg: (cfg.paged_layers, None),
     report=_kv_report, setup=_kv_report,
 )
 
@@ -740,7 +751,8 @@ PAGED_LATENT = CacheKind(
     walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk))),
     table=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows))),
     wide=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_local(cfg)),
-    report=_rows_report, setup=_rows_report,
+    walked=lambda cfg: (cfg.paged_layers, None),
+    report=_latent_report, setup=_latent_report,
 )
 
 STATE_SLOT = CacheKind(
@@ -773,6 +785,7 @@ WINDOW_RING = CacheKind(
     step=_ring_step, walk=_ring_walk, table=functools.partial(_ring_step, table=True), wide=None,
     # the cached tokens the step's attention reads, summed over the lanes, in the full and in the window layers
     counters=("serve.kv.full_tokens", "serve.kv.window_tokens"), count=_ring_count,
+    walked=lambda cfg: (len(cfg.window_layers), cfg.sliding_window),
     report=_ring_report, setup=_ring_setup,
 )
 

@@ -14,11 +14,16 @@ and accumulator in float32, K and V in the pool's dtype) over tiles of
   program a lane.  Block tables, lengths and the layer arrive as
   scalar-prefetch arguments; a lane walks its own
   ``ceil(length / tile_tokens)`` tiles (an empty lane none, a short lane
-  does not wait for the longest), copying each tile's blocks HBM -> VMEM
-  with the next tile's copies in flight.  The ``n_rep`` query heads of a KV
-  head are served from ONE copy of its K/V, in one of two layouts of the
-  tile's two products, chosen from ``n_rep`` (:func:`attn_products`), never
-  by the caller:
+  does not wait for the longest).  How a tile reaches VMEM is ONE schedule
+  for this kernel, its window form and the latent kernel
+  (:func:`_copy_schedule`, by name :data:`COPY_SCHEDULE`): a tile copies
+  the blocks that hold a token the query sees and no others, and a copy is
+  in flight whenever a tile is multiplied, the next lane's first tile while
+  a lane's last is (:func:`walk_counts` states the same in integers for the
+  engine's ``/stats``).  The ``n_rep`` query heads of a KV head are served
+  from ONE copy of its K/V, in one of two layouts of the tile's two
+  products, chosen from ``n_rep`` (:func:`attn_products`), never by the
+  caller:
 
   - *a KV head at a time*, where a KV head's rows of the float32 scores
     fill whole sublane tiles (``n_rep`` a multiple of 8: Command A+'s 16).
@@ -61,19 +66,21 @@ whatever the context.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from determined_tpu.ops.flash_attention import NEG_INF
 
 # Tokens a tile aims for.  A tile is one trip of the walk: its matmuls,
-# its softmax update and its 2 * tile_blocks block copies.  Wide tiles
-# amortize the serial chain wait -> QK -> max -> exp -> PV of a trip; the
-# last tile of a lane is copied whole, so the width also bounds the waste.
+# its softmax update and the copies of its live blocks (K and V each).  Wide
+# tiles amortize the serial chain wait -> QK -> max -> exp -> PV of a trip;
+# a lane's last tile is MULTIPLIED whole (its dead rows masked), though only
+# its live blocks are copied, so the width still bounds that waste.
 # On a v5e at InternLM2's shapes (16 x 128 heads, 8 KV, blocks of 16, ~27 k
 # live tokens over 32 lanes) 24 layers took 6.9 / 5.2 / 5.1 / 5.9 / 7.6 ms at
 # 64 / 128 / 256 / 512 / 1024 tokens a tile (my chip run, PR 25).  Both
@@ -277,6 +284,172 @@ def _paged_attention_jnp(
 
 
 # ---------------------------------------------------------------------------
+# The copy schedule of the three Pallas kernels
+# ---------------------------------------------------------------------------
+
+#: the schedule by name: what ``CacheKind.report`` says on ``serve.setup.kv_pool``
+#: and in ``/stats`` beside ``attn_products``
+COPY_SCHEDULE = {"tile_copies": "live_blocks", "lane_prefetch": True}
+#: blocks whose descriptors one trip of a tile's start loop issues.  On a v5e the
+#: latent kernel (32 blocks a tile, one stream) took 8.1 / 6.7 / 6.5 / 6.5 us a
+#: lane at DSV3's shape at 1 / 4 / 8 / 16 a trip, the GQA kernel the same at
+#: any; the kernel's text at 8 is shorter than with a whole tile unrolled (my
+#: chip runs and compiles, PR 58: PERF.md section 5)
+COPY_GROUP = 8
+
+
+def _live_blocks(length, block_size: int, window: Optional[int], xp=jnp):
+    """``(start, lo, hi)`` of a lane whose query sees ``length`` tokens: the
+    oldest position it sees (``length - window`` under a window, not below 0)
+    and the logical blocks ``[lo, hi)`` of the lane's context that hold a token
+    it sees, which are the blocks a walk copies.  In integers alone, so that the
+    kernels (``xp`` ``jax.numpy``, a lane's scalar) and the host's count
+    (:func:`walk_counts`: ``numpy``, every lane at once) state it once."""
+    start = 0 if window is None else xp.maximum(length - window, 0)
+    return start, start // block_size, (length + block_size - 1) // block_size
+
+
+class WalkCounts(NamedTuple):
+    """A decode step's walk over ONE layer, summed over the lanes."""
+
+    live_tokens: int    # tokens some lane's query sees
+    copied_tokens: int  # tokens of the blocks the kernel copies: the live blocks, whole
+    lanes: int          # lanes that walk a tile
+    lanes_in_flight: int  # of them, those whose first tile the lane before had started
+
+
+def walk_counts(positions, block_size: int, window: Optional[int] = None) -> WalkCounts:
+    """What the kernels' copy schedule does with a step's lanes, on the host:
+    ``positions`` [lanes] as :func:`paged_decode_attention` takes them (-1 an
+    idle lane).  ``copied_tokens / live_tokens`` is what a step reads over what
+    it must; a lane's first tile is in flight when it begins wherever the lane
+    before it walked a tile."""
+    lengths = np.maximum(np.asarray(positions, np.int64) + 1, 0)
+    start, lo, hi = _live_blocks(lengths, block_size, window, xp=np)
+    walks = lengths > 0
+    return WalkCounts(
+        int(np.sum(lengths - start)), int(np.sum(hi - lo)) * block_size,
+        int(np.sum(walks)), int(np.sum(walks[1:] & walks[:-1])),
+    )
+
+
+def _copy_schedule(
+    lengths_ref, tables_ref, state_ref, streams: Sequence[Tuple[Any, Any, Any]], layer,
+    *, tile_blocks: int, block_size: int, table_width: int, window: Optional[int],
+):
+    """The ONE statement of how the kernels bring a lane's tiles into VMEM, for
+    the program of lane ``pl.program_id(0)``.  ``streams``: an ``(hbm pool, VMEM
+    buffer [2, tile_tokens, width], DMA semaphores [2])`` each (K and V; the
+    latent rows alone).  Returns ``(length, start, first, n_tiles, trip)``: the
+    lane walks the tiles ``first .. first + n_tiles - 1`` and ``trip(i)`` returns
+    the buffers' slot that holds tile ``first + i``, copied.
+
+    * *A tile copies the blocks that hold a token the query sees*
+      (:func:`_live_blocks`), and no others: not the columns past the lane's
+      last block, not a ring's blocks older than the window.  Rows of a buffer
+      no copy wrote are masked in the scores, and ``p`` is exactly 0 there; so
+      that ``0 x row`` is 0 in the second product the buffers are ZEROED once, by
+      the call's first program, after which a row only ever holds zeros or a
+      pool's row (selecting the dead rows away would cost a pass over V every
+      tile; the zeroing is a few hundred vector stores a call).
+    * *A copy is in flight whenever a tile is multiplied, across lanes*: while
+      tile ``i`` is multiplied the lane's tile ``i + 1`` is on its way into the
+      other slot, and after the lane's LAST tile the FIRST tile of the next lane
+      (scratch and semaphores outlive a grid step; every lane's length and table
+      are in SMEM).  The slot a lane starts in and whether its first tile is in
+      flight are carried in ``state_ref`` (SMEM, two words).  A lane starts its
+      own first tile only where nobody could have: program 0, and after a lane
+      that walked no tile.  Nothing is started for a lane that walks no tile or
+      past the last program, so every copy is waited for inside the call.
+
+    The start and the wait of a tile are the two halves of ``copies``, made
+    from one range of blocks: the start a loop over them, ``COPY_GROUP`` a trip
+    (the kernel's text holds that many descriptors a site, not ``tile_blocks``),
+    the wait their count in bytes."""
+    b, n_lanes = pl.program_id(0), pl.num_programs(0)
+
+    def walk(lane):
+        """``(length, start, lo, hi, first, n_tiles)`` of ``lane``; no tile past the last program."""
+        length = jnp.where(lane < n_lanes, lengths_ref[jnp.minimum(lane, n_lanes - 1)], 0)
+        start, lo, hi = _live_blocks(length, block_size, window)
+        first = lo // tile_blocks
+        return length, start, lo, hi, first, (hi + tile_blocks - 1) // tile_blocks - first
+
+    def copies(lane, tile, lo, hi, slot):
+        """``(start, wait)`` of the copies of ``lane``'s blocks ``[lo, hi)`` that lie in ``tile``, into ``slot``."""
+        base = tile * tile_blocks
+        lo, hi = jnp.maximum(lo, base), jnp.minimum(hi, base + tile_blocks)
+        n = jnp.maximum(hi - lo, 0)
+
+        def start_block(c, _=None):
+            # a ring holds logical block c in column c % T; a table's c is below T wherever the lane fits it
+            col = c % table_width if window is not None else jnp.minimum(c, table_width - 1)
+            blk = tables_ref[lane * table_width + col]
+            rows = pl.ds(pl.multiple_of((c - base) * block_size, block_size), block_size)
+            for hbm, buf, sems in streams:
+                pltpu.make_async_copy(hbm.at[layer, blk], buf.at[slot, rows], sems.at[slot]).start()
+
+        def start():
+            # COPY_GROUP blocks a trip, so that their table reads and addresses overlap; then the rest one by one
+            group = min(COPY_GROUP, tile_blocks)
+
+            def start_group(g, _):
+                for j in range(group):
+                    start_block(lo + g * group + j)
+
+            jax.lax.fori_loop(0, n // group, start_group, None)
+            jax.lax.fori_loop(lo + n // group * group, hi, start_block, None)
+
+        def wait():
+            # a DMA semaphore counts bytes and every block's copy is as long: n of them are waited
+            # for as the powers of two in n, so a full tile is ONE wait, whatever its blocks
+            for k in range(tile_blocks.bit_length()):
+                rows = pl.ds(0, block_size << k)
+
+                @pl.when((n >> k) & 1 == 1)
+                def _blocks():
+                    for _, buf, sems in streams:
+                        pltpu.make_async_copy(buf.at[slot, rows], buf.at[slot, rows], sems.at[slot]).wait()
+
+        return start, wait
+
+    length, start, lo, hi, first, n_tiles = walk(b)
+    _, _, next_lo, next_hi, next_first, next_tiles = walk(b + 1)
+
+    @pl.when(b == 0)
+    def _call_begins():
+        state_ref[0] = 0  # the slot the lane starts in
+        state_ref[1] = 0  # whether its first tile is in flight
+        for _, buf, _ in streams:
+            buf[...] = jnp.zeros_like(buf)
+
+    slot0, in_flight = state_ref[0], state_ref[1]
+    state_ref[0] = (slot0 + n_tiles) % 2
+    state_ref[1] = ((n_tiles > 0) & (next_tiles > 0)).astype(jnp.int32)
+
+    @pl.when(in_flight == 0)
+    def _own_first():
+        start_first, _ = copies(b, first, lo, hi, slot0)
+        start_first()
+
+    def trip(i):
+        slot = (slot0 + i) % 2
+        # what is in flight while tile i is multiplied: the lane's next tile,
+        # after its last the next lane's first (an empty range where that lane walks none)
+        last = i + 1 == n_tiles
+        start_next, _ = copies(
+            jnp.where(last, b + 1, b), jnp.where(last, next_first, first + i + 1),
+            jnp.where(last, next_lo, lo), jnp.where(last, next_hi, hi), 1 - slot,
+        )
+        _, wait_this = copies(b, first + i, lo, hi, slot)
+        start_next()
+        wait_this()
+        return slot
+
+    return length, start, first, n_tiles, trip
+
+
+# ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 
@@ -301,50 +474,18 @@ def _paged_attention_kernel(
     layer_ref, lengths_ref, tables_ref,           # scalar prefetch (SMEM)
     q_ref, k_hbm, v_hbm,                          # inputs
     o_ref,                                        # output
-    k_buf, v_buf, sems,                           # scratch
+    k_buf, v_buf, sems, state_ref,                # scratch
     *, scale: float, tile_blocks: int, block_size: int, table_width: int,
     kv_heads: int, head_dim: int, n_rep: int, per_kv_head: bool, window: Optional[int] = None,
 ):
-    b = pl.program_id(0)
-    layer = layer_ref[0]
-    length = lengths_ref[b]
     tile_tokens = tile_blocks * block_size
-    n_tiles = (length + tile_tokens - 1) // tile_tokens
     rows = o_ref.shape[0]
-    if window is None:
-        tile_of = lambda i: i  # noqa: E731 (trip i of the walk is tile i)
-    else:
-        # the table is a ring: the walk starts at the tile of the oldest
-        # position the query still sees, which always holds one it does see
-        start = jnp.maximum(length - window, 0)
-        first = start // tile_tokens
-        n_tiles = n_tiles - first
-        tile_of = lambda i: first + i  # noqa: E731
-
-    def copies(tile, slot):
-        """The tile's 2 * tile_blocks block copies into buffer ``slot``.
-        Every tile is copied whole: columns past the lane's last block hold
-        block ids all the same (the allocator's scratch block 0, or the
-        table's last column; a ring's older blocks), and their scores are
-        masked."""
-        out = []
-        for j in range(tile_blocks):
-            if window is None:
-                col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
-            else:
-                col = (tile * tile_blocks + j) % table_width
-            blk = tables_ref[b * table_width + col]
-            dst = pl.ds(j * block_size, block_size)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[layer, blk], k_buf.at[slot, dst], sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, blk], v_buf.at[slot, dst], sems.at[1, slot]))
-        return out
-
-    @pl.when(n_tiles > 0)
-    def _first():
-        for c in copies(tile_of(0), 0):
-            c.start()
+    # under ``window`` the table is a ring and the walk starts at the tile of the
+    # oldest position the query still sees, which always holds one it does see
+    length, start, first, n_tiles, trip = _copy_schedule(
+        lengths_ref, tables_ref, state_ref, ((k_hbm, k_buf, sems.at[0]), (v_hbm, v_buf, sems.at[1])), layer_ref[0],
+        tile_blocks=tile_blocks, block_size=block_size, table_width=table_width, window=window,
+    )
 
     if not per_kv_head:
         q = q_ref[...]                                # [rows, kv_heads*head_dim]
@@ -362,15 +503,7 @@ def _paged_attention_kernel(
 
     def body(i, carry):
         m, l, acc = carry
-        slot = i % 2
-
-        @pl.when(i + 1 < n_tiles)
-        def _next():
-            for c in copies(tile_of(i + 1), 1 - slot):
-                c.start()
-
-        for c in copies(tile_of(i), slot):
-            c.wait()
+        slot = trip(i)
         if per_kv_head:
             s = jnp.concatenate([
                 jax.lax.dot_general(
@@ -384,7 +517,7 @@ def _paged_attention_kernel(
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             ) * scale                                 # [rows, tile_tokens]
-        k_idx = tile_of(i) * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_idx = (first + i) * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         if window is None:
             s = jnp.where(k_idx < length, s, NEG_INF)
         else:
@@ -476,6 +609,7 @@ def _paged_attention_pallas(
                 pltpu.VMEM((2, tile_tokens, kvd), k_pool.dtype),
                 pltpu.VMEM((2, tile_tokens, kvd), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, rows, head_dim), jnp.float32),
@@ -613,46 +747,22 @@ def _paged_latent_kernel(
     layer_ref, lengths_ref, tables_ref,           # scalar prefetch (SMEM)
     q_ref, pool_hbm,                              # inputs
     o_ref,                                        # output
-    buf, sems, acc_ref,                           # scratch
+    buf, sems, acc_ref, state_ref,                # scratch
     *, scale: float, tile_blocks: int, block_size: int, table_width: int, value_dim: int,
 ):
-    b = pl.program_id(0)
-    layer = layer_ref[0]
-    length = lengths_ref[b]
     tile_tokens = tile_blocks * block_size
-    n_tiles = (length + tile_tokens - 1) // tile_tokens
     rows = q_ref.shape[0]
-
-    def copies(tile, slot):
-        """The tile's block copies into buffer ``slot``, whole (see the GQA kernel)."""
-        out = []
-        for j in range(tile_blocks):
-            col = jnp.minimum(tile * tile_blocks + j, table_width - 1)
-            blk = tables_ref[b * table_width + col]
-            out.append(pltpu.make_async_copy(
-                pool_hbm.at[layer, blk], buf.at[slot, pl.ds(j * block_size, block_size)], sems.at[slot]))
-        return out
-
-    @pl.when(n_tiles > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
+    length, _, _, n_tiles, trip = _copy_schedule(
+        lengths_ref, tables_ref, state_ref, ((pool_hbm, buf, sems),), layer_ref[0],
+        tile_blocks=tile_blocks, block_size=block_size, table_width=table_width, window=None,
+    )
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q = q_ref[...]                                    # [rows, width]
 
     def body(i, carry):
         m, l = carry
-        slot = i % 2
-
-        @pl.when(i + 1 < n_tiles)
-        def _next():
-            for c in copies(i + 1, 1 - slot):
-                c.start()
-
-        for c in copies(i, slot):
-            c.wait()
-        tile = buf[slot]                              # [tile_tokens, width]
+        tile = buf[trip(i)]                              # [tile_tokens, width]
         s = jax.lax.dot_general(
             q, tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                     # [rows, tile_tokens]
@@ -699,6 +809,7 @@ def _paged_latent_pallas(q, pool, layer, block_tables, lengths, scale, value_dim
                 pltpu.VMEM((2, tile_tokens, width), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((rows, value_dim), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, rows, value_dim), jnp.float32),

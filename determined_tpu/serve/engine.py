@@ -513,10 +513,18 @@ class ServeEngine:
         #: token is the host's draw) and where the caller's own sampler chose
         self._ids_on_device: Any = None
         #: decode steps, those whose table was sent again, those whose tokens
-        #: were the device's ids, and those whose sampler was queued inside the
-        #: decode call's wait (``/stats`` ``step_inputs``)
-        self._step_inputs = {"decode_steps": 0, "table_sent": 0, "tokens_from_device": 0, "sampler_in_wait": 0}
+        #: were the device's ids, those whose sampler was queued inside the
+        #: decode call's wait, and what the paged decode kernels' walks had to
+        #: read and what their copies brought, in tokens over every walked row
+        #: of the cache (``/stats`` ``step_inputs``)
+        self._step_inputs = {
+            "decode_steps": 0, "table_sent": 0, "tokens_from_device": 0, "sampler_in_wait": 0,
+            "paged_live_tokens": 0, "paged_copied_tokens": 0,
+        }
         from determined_tpu.models.cache_kinds import BLOCKS
+
+        #: (rows, window) of each kind a paged decode kernel walks a step
+        self._walked = [kind.walked(kernels.model_cfg) for kind in kernels.kinds if kind.walked is not None]
 
         #: whether a request holds blocks of some kind of the kernels' cache
         #: (``models/cache_kinds.py``).  Where none does, a request holds the
@@ -1108,12 +1116,21 @@ class ServeEngine:
         sampler is queued on the logits the kernels hand the hook, which are
         not ready yet: the device runs it the moment the decode program ends.
         Under a stand-in for the kernels that never runs the hook the draws
-        are made here, after the call, and the launch is left to the caller."""
+        are made here, after the call, and the launch is left to the caller.
+        There too the step's walks are counted from the positions (``walk_counts``
+        of ``ops/paged_attention.py``, each walked row of the cache alike):
+        ``paged_live_tokens`` the kernels' queries see, ``paged_copied_tokens``
+        their copies bring."""
         import jax
+
+        from determined_tpu.ops.paged_attention import walk_counts
 
         b = self.cfg.max_batch
         positions = self._positions
-        sent = {"table_sent": int(self._tables_on_device is None), "tokens_from_device": int(self._ids_on_device is not None)}
+        sent = {
+            "table_sent": int(self._tables_on_device is None), "tokens_from_device": int(self._ids_on_device is not None),
+            "paged_live_tokens": 0, "paged_copied_tokens": 0,
+        }
         if self._tables_on_device is None:
             # a copy: the matrix is written again while the device's buffer is still held
             self._tables_on_device = jax.device_put(self._tables.copy())
@@ -1134,6 +1151,10 @@ class ServeEngine:
                     draws[1, i] = seq.rng.random()
             on_device = jax.device_put(draws)
             self._positions = np.where(positions >= 0, positions + 1, positions)
+            for rows, window in self._walked:
+                walk = walk_counts(positions, self.cfg.block_size, window)
+                sent["paged_live_tokens"] += rows * walk.live_tokens
+                sent["paged_copied_tokens"] += rows * walk.copied_tokens
             prepared.extend((on_device, (t_prepare, mono()), None if pending is None else self._launch_sampler(pending, on_device)))
 
         kernels = self.kernels

@@ -78,6 +78,59 @@ def retention_chunk_step(impl):
     return jax.jit(functools.partial(retention.retention_chunk, impl=impl))
 
 
+# -- the paged decode kernels' copy schedule at a lane's edges (tests/test_paged_attention.py,
+# -- tests/test_window_kernels.py, tests/test_latent_serving.py) --
+
+#: a call's lanes by the tokens each one's query sees (0: an idle lane), at blocks of
+#: 16 tokens and tiles of 2 blocks, by what the list pins for the walk's two edges
+PAGED_EDGES = {
+    "a_token_a_block_a_tile_a_tile_and_one": (1, 16, 32, 33),
+    "idle_first": (0, 40, 7),
+    "idle_in_the_middle": (40, 0, 7),
+    "idle_last": (40, 7, 0),
+    "two_idle_in_a_row": (70, 0, 0, 40),
+    "every_lane_idle": (0, 0, 0),
+}
+
+
+def blocks_seen(tables, contexts, block, window=None):
+    """The pool's blocks that hold a token some lane's query sees, token by token:
+    a lane of ``n`` tokens sees the positions ``max(0, n - window) .. n - 1``, and
+    position ``j`` lies in column ``(j // block) % T`` of its row of the table
+    (a ring's column; a table's own where the lane fits it)."""
+    tables = np.asarray(tables)
+    return {
+        int(tables[b, (j // block) % tables.shape[1]])
+        for b, n in enumerate(contexts) for j in range(max(0, n - window) if window else 0, n)
+    }
+
+
+def check_copy_schedule(run, pools, layer, tables, contexts, block, window=None):
+    """``run(*pools)``: one of the paged decode kernels in the interpreter over
+    lanes of ``contexts`` tokens.  Returns its result, after the poison case:
+    with NaN in every block of every layer of the pools but ``layer``'s blocks
+    that hold a token some query sees, the result is finite and the same bit for
+    bit (a tile copied whole brings NaN rows to ``p @ V``, ``0 x NaN``; the
+    interpreter's scratch starts as NaN, so a row no copy wrote must not reach
+    it either).  The blocks are found token by token here, and
+    ``walk_counts``, the schedule's own count, must count the same."""
+    got = np.asarray(run(*pools))
+    seen = sorted(blocks_seen(tables, contexts, block, window))
+    counts = paged_mod.walk_counts(np.asarray(contexts) - 1, block, window)
+    assert counts.copied_tokens == block * len(seen)
+    assert counts.live_tokens == sum(min(n, window or n) for n in contexts) and counts.lanes == sum(n > 0 for n in contexts)
+
+    def poisoned(pool):
+        out = np.full(pool.shape, np.nan, np.float32)
+        out[layer, seen] = np.asarray(pool, np.float32)[layer, seen]
+        return jnp.asarray(out, pool.dtype)
+
+    again = np.asarray(run(*(poisoned(pool) for pool in pools)))
+    assert np.isfinite(again).all()
+    np.testing.assert_array_equal(again, got)
+    return got
+
+
 # -- compiling for a described chip (tests/test_tpu_compile.py, tests/test_tpu_compile_cells.py) --
 
 flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")

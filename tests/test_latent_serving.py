@@ -31,7 +31,7 @@ from determined_tpu.models.transformer import (
     kv_cache_shape,
 )
 from determined_tpu.ops import grouped_matmul as gm, paged_attention as paged
-from tests.model_cases import reference_module
+from tests.model_cases import PAGED_EDGES, check_copy_schedule, reference_module
 
 reference = reference_module("deepseek_mla_moe")
 
@@ -169,6 +169,35 @@ def test_the_latent_kernel_and_the_jnp_walk_match_a_dense_softmax_over_ragged_la
     assert paged.latent_kernel_takes(256, 128, 16, dtype) and not paged.latent_kernel_takes(576, 512, 16, dtype)
     with pytest.raises(ValueError, match="width % 128"):
         paged.paged_latent_attention(q[..., :200], pool[..., :200], 1, tables, pos, scale=0.1, value_dim=128, impl="kernel_interpret")
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-6), (jnp.bfloat16, 4e-3)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lanes", list(PAGED_EDGES))
+def test_the_latent_kernels_copy_schedule_at_a_lanes_edges(lanes, dtype, atol):
+    """The latent kernel over the lanes of ``PAGED_EDGES`` (a token, a block, a
+    tile, a tile and one; idle lanes first, in the middle, last, two in a row,
+    all), tiles of 2 blocks: against a dense softmax and the ``jax.numpy`` walk,
+    then the poison case (``check_copy_schedule``).  The kernel multiplies a
+    whole tile, so a row no copy wrote must be zeros or a pool's row."""
+    contexts = PAGED_EDGES[lanes]
+    layers, blocks, block, width, values, heads, cols = 2, 32, 16, 256, 128, 6, 6
+    keys = jax.random.split(jax.random.key(3), 2)
+    pool = jax.random.normal(keys[0], (layers, blocks, block, width), jnp.float32).astype(dtype)
+    q = (jax.random.normal(keys[1], (len(contexts), heads, width), jnp.float32) * 0.3).astype(dtype)
+    tables = jnp.asarray(np.random.default_rng(3).permutation(np.arange(1, blocks))[: len(contexts) * cols].reshape(-1, cols), jnp.int32)
+    pos = jnp.asarray(contexts, jnp.int32) - 1
+
+    def run(pool, impl="kernel_interpret"):
+        return paged.paged_latent_attention(q, pool, 1, tables, pos, scale=0.1, value_dim=values, impl=impl, tile_blocks=2)
+
+    got = check_copy_schedule(run, (pool,), 1, tables, contexts, block)
+    rows = pool[1][tables].reshape(len(contexts), cols * block, width).astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32), rows) * 0.1
+    s = jnp.where(jnp.arange(cols * block)[None, None, :] <= pos[:, None, None], s, -1e30)
+    want = jnp.einsum("bht,btc->bhc", jax.nn.softmax(s, -1), rows[..., :values]) * (pos >= 0)[:, None, None]
+    np.testing.assert_allclose(got, np.asarray(want), atol=atol)
+    np.testing.assert_allclose(got, np.asarray(run(pool, "jnp")), atol=2e-6, rtol=2e-5)
+    assert not got[[n == 0 for n in contexts]].any()
 
 
 def test_suffix_prefill_from_a_shared_prefix_matches_the_reference_and_a_cold_start(model):

@@ -159,14 +159,16 @@ def test_a_layer_of_no_kind_and_the_caches_leaves_bytes_and_report():
     assert cache_kinds._ssm_report(cfg, sizes, 2) == {"ssm": {"slots": 3, "live": 2, "bytes_per_slot": 5 * 8 * 16 * 8 * 4},
                                                       "layers_by_kind": {"paged_kv": 1, "ssm_slot": 5, "none": 5}}
     assert cache_kinds.PAGED_LATENT.report(cfg, sizes, 0) == {} == cache_kinds.STATE_SLOT.report(cfg, sizes, 0)   # a kind without layers here
-    assert cache_kinds._kv_report(cfg) == {"attn_products": "block_diagonal", "layers_by_kind": {"paged_kv": 1, "ssm_slot": 5, "none": 5}}
+    assert cache_kinds._kv_report(cfg) == {"attn_products": "block_diagonal", "tile_copies": "live_blocks", "lane_prefetch": True,
+                                           "layers_by_kind": {"paged_kv": 1, "ssm_slot": 5, "none": 5}}
+    assert cache_kinds.PAGED_KV.walked(cfg) == (1, None) and cache_kinds.SSM_SLOT.walked is None  # ONE layer's rows are walked a step
     assert cache_kinds._ssm_setup(cfg, sizes) == {
         "ssm_slots": 3, "ssm_bytes_per_slot": 5 * 4096, "ssm_pool_bytes": 5 * 4 * 4096 + 5 * 3 * 3 * 160 * 4,
         "layers_by_kind": {"paged_kv": 1, "ssm_slot": 5, "none": 5},
     }
     # a model of the other block forms says nothing of the kind
     plain = TransformerConfig(vocab_size=96, d_model=64, n_layers=2, n_heads=4, max_seq_len=64)
-    assert cache_kinds._kv_report(plain) == {"attn_products": cache_kinds._kv_report(plain)["attn_products"]}
+    assert set(cache_kinds._kv_report(plain)) == {"attn_products", "tile_copies", "lane_prefetch"}
 
 
 def test_the_engine_says_the_layers_by_kind_at_set_up_and_in_stats(model):

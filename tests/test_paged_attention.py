@@ -19,7 +19,7 @@ from determined_tpu.models import cache_kinds
 from determined_tpu.models.serving import init_kv_cache, transformer_decode, transformer_prefill
 from determined_tpu.models.transformer import TransformerConfig, TransformerLM
 from determined_tpu.ops import paged_attention as pa
-from tests.model_cases import causal_forward
+from tests.model_cases import PAGED_EDGES, causal_forward, check_copy_schedule
 
 # lanes of the ragged batch, by what each one pins (block_size 16, table 6):
 # empty; position 0; last slot of a block (the walk ends exactly on a block
@@ -88,6 +88,60 @@ def test_paged_attention_matches_numpy_over_ragged_lanes(impl, dtype, n_rep, til
     assert got.dtype == jnp.float32 and got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-6, rtol=2e-5)
     assert not np.asarray(got)[list(RAGGED).index("empty")].any()
+
+
+@pytest.mark.parametrize("dtype, n_rep", [(jnp.float32, 2), (jnp.bfloat16, 8)], ids=["f32-block_diagonal", "bf16-per_kv_head"])
+@pytest.mark.parametrize("lanes", list(PAGED_EDGES))
+def test_the_kernels_copy_schedule_at_a_lanes_edges(lanes, dtype, n_rep):
+    """Tiles of 2 blocks of 16: lanes of a token, a block, a tile, a tile and
+    one; an idle lane first (the next starts its own first tile), in the
+    middle, last (nothing is started for it), two in a row, every lane idle.
+    The kernel against the plain attention and its ``jax.numpy`` form, then the
+    poison case (``check_copy_schedule``): only the blocks that hold a token a
+    query sees are copied, and a row no copy wrote is zeros."""
+    contexts = PAGED_EDGES[lanes]
+    q, k_pool, v_pool, tables, positions = _pool_case(dtype, n_rep, 128, 16, [n - 1 for n in contexts])
+    scale = 128 ** -0.5
+
+    def run(k_pool, v_pool, impl="kernel_interpret"):
+        return pa.paged_decode_attention(
+            q, k_pool, v_pool, 1, jnp.asarray(tables), jnp.asarray(positions), scale=scale, tile_blocks=2, impl=impl)
+
+    got = check_copy_schedule(run, (k_pool, v_pool), 1, tables, contexts, 16)
+    np.testing.assert_allclose(got, _numpy_attention(q, k_pool, v_pool, 1, tables, positions, scale), atol=2e-6, rtol=2e-5)
+    np.testing.assert_allclose(got, np.asarray(run(k_pool, v_pool, "jnp")), atol=2e-6, rtol=2e-5)
+    assert not got[[n == 0 for n in contexts]].any()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_walk_counts_is_the_walk_tile_by_tile_and_block_by_block(seed):
+    """The schedule in integers (``walk_counts``, whose block formula the kernels
+    share) against a walk made the slow way over random lanes, windows, block
+    sizes and tile widths: a lane walks the tiles from the one that holds the
+    oldest token its query sees to the one that holds the newest, a tile copies
+    its blocks that hold such a token, and a lane's first tile was in flight
+    where the lane before it walked one.  The tile's width moves none of it."""
+    rng = np.random.default_rng(seed)
+    block, tile_blocks = int(rng.choice([4, 16, 32])), int(rng.integers(1, 9))
+    window = [None, 5, 40, 200][seed % 4]
+    positions = rng.integers(0, 700, size=int(rng.integers(1, 12)))
+    positions[rng.random(positions.shape) < 0.3] = -1
+    live = copied = lanes = in_flight = 0
+    walked_before = False
+    for pos in positions:
+        n = int(pos) + 1
+        sees = set(range(max(0, n - window) if window else 0, n))
+        tiles = sorted({j // (block * tile_blocks) for j in sees})
+        assert tiles == list(range(tiles[0], tiles[-1] + 1)) if tiles else n == 0
+        for tile in tiles:
+            for blk in range(tile * tile_blocks, (tile + 1) * tile_blocks):
+                copied += block * bool(sees & set(range(blk * block, (blk + 1) * block)))
+        live += len(sees)
+        lanes += bool(tiles)
+        in_flight += bool(tiles) and walked_before
+        walked_before = bool(tiles)
+    assert pa.walk_counts(positions, block, window) == (live, copied, lanes, in_flight)
+    assert pa.walk_counts(np.full(3, -1), block, window) == (0, 0, 0, 0)
 
 
 @pytest.mark.parametrize("head_dim,block_size", [(8, 4), (64, 16), (128, 4)])

@@ -435,7 +435,8 @@ def test_generate_is_the_references_argmax_and_a_reused_lane_starts_afresh(model
     assert stats["state"] == {"slots": 2, "live": 0, "bytes_per_slot": LAYERS * state_bytes_per_slot(cfg), "fold_every": EVERY, "pending_rows": 0}
     assert stats["block_ids_address_nothing"] is True and stats["kv_cache"]["used"] == 0
     assert stats["step_counters"]["serve.state.live_lanes"] == 6 + 4 + 3 - 3
-    assert "attn_products" not in stats and stats["window_store"] == {}
+    assert "attn_products" not in stats and "tile_copies" not in stats and stats["window_store"] == {}
+    assert stats["step_inputs"]["paged_copied_tokens"] == 0 and STATE_SLOT.walked is None  # no layer holds rows: no paged kernel walks
 
 
 def test_admission_is_by_slots_and_never_by_blocks(model):

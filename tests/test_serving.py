@@ -1221,10 +1221,18 @@ def test_the_table_is_sent_after_a_join_or_a_retirement_and_the_devices_ids_go_i
         sent += again
         from_device += joined == 0
     assert 0 < sent < len(moved) and 0 < from_device < len(moved)
+    # what the paged kernel's walks had to read and what their copies brought (whole blocks), over every layer
+    layers, block = kernels.model_cfg.n_layers, SERVE_CFG.block_size
+    lengths = [positions[positions >= 0] + 1 for _, positions, _, _ in seen.calls]
+    live = [layers * int(n.sum()) for n in lengths]
+    copied = [layers * int((-(-n // block) * block).sum()) for n in lengths]
+    assert sum(live) < sum(copied)
     assert eng.stats()["step_inputs"] == {
         "decode_steps": len(moved), "table_sent": sent, "tokens_from_device": from_device, "sampler_in_wait": len(moved),
+        "paged_live_tokens": sum(live), "paged_copied_tokens": sum(copied),
     }
     spans = sorted(_spans(tracer, "serve.decode"), key=lambda e: e["args"]["step"])
+    assert [(e["args"]["paged_live_tokens"], e["args"]["paged_copied_tokens"]) for e in spans] == list(zip(live, copied))
     assert [e["args"]["table_sent"] for e in spans] == [int(n == 0 or changed > 0) for n, (_, changed) in enumerate(moved)]
     assert [e["args"]["tokens_from_device"] for e in spans] == [int(joined == 0) for joined, _ in moved]
 

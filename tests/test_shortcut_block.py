@@ -21,6 +21,7 @@ from flax.core import meta
 
 from determined_tpu.models import moe
 from determined_tpu.models.cache_kinds import PAGED_KV, PAGED_LATENT, layer_kinds
+from determined_tpu.ops.paged_attention import COPY_SCHEDULE
 from determined_tpu.models.serving import (
     SERVE_COUNTERS,
     ZERO_PICKS,
@@ -243,12 +244,12 @@ def test_a_layer_owns_two_rows_of_one_kind_each_with_its_own_subtree():
     assert layer_kinds(cfg, 0) == ((PAGED_LATENT, 0, "attn"),) and layer_kinds(cfg, 0, 1) == ((PAGED_LATENT, 1, "attn_1"),)
     assert layer_kinds(cfg, 1) == ((PAGED_LATENT, 2, "attn"),) and layer_kinds(cfg, 1, 1) == ((PAGED_LATENT, 3, "attn_1"),)
     sizes = type("S", (), {"num_blocks": 24, "block_size": 8, "max_batch": 2, "prefill_chunk": 8})
-    assert PAGED_LATENT.shapes(cfg, sizes) == ((4, 24, 8, 128),) and PAGED_LATENT.report(cfg, sizes, 0) == {"rows_per_token": 4}
-    assert PAGED_LATENT.setup(cfg, sizes) == {"rows_per_token": 4}
+    assert PAGED_LATENT.shapes(cfg, sizes) == ((4, 24, 8, 128),) and PAGED_LATENT.report(cfg, sizes, 0) == {"rows_per_token": 4, **COPY_SCHEDULE}
+    assert PAGED_LATENT.setup(cfg, sizes) == {"rows_per_token": 4, **COPY_SCHEDULE} and PAGED_LATENT.walked(cfg) == (4, None)  # four calls of the kernel a step
     assert serve_counters(cfg) == SERVE_COUNTERS + (ZERO_PICKS,)
-    # a sequential block says nothing new: one row a layer, the reports as they were
+    # a sequential block says nothing new: one row a layer, no ``rows_per_token`` (the kernel's copy schedule alone)
     plain = tiny(shortcut_block=False)
-    assert layer_kinds(plain, 1) == ((PAGED_LATENT, 1, "attn"),) and PAGED_LATENT.report(plain, sizes, 0) == {} and plain.paged_layers == 2
+    assert layer_kinds(plain, 1) == ((PAGED_LATENT, 1, "attn"),) and PAGED_LATENT.report(plain, sizes, 0) == COPY_SCHEDULE and plain.paged_layers == 2
     # the form over GQA: two K and two V rows a block
     gqa = TransformerConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=48, shortcut_block=True,
                             moe_experts=4, moe_every=1, moe_top_k=2, moe_intermediate_size=16)

@@ -39,6 +39,25 @@ def _arrays_with_dims(text: str, dims) -> list:
     return sorted(found)
 
 
+def _kernel_scratch(fn, *avals) -> dict:
+    """Bytes of scratch the ONE Pallas call of ``fn`` asks for, by memory space
+    (``vmem``, ``smem``; semaphores aside), off the call's own equation."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(inner)
+
+    (call,) = calls(jax.make_jaxpr(fn)(*avals).jaxpr)
+    scratch = call.params["jaxpr"].invars[-call.params["grid_mapping"].num_scratch_operands:]
+    sizes = {}
+    for ref in (v.aval for v in scratch):
+        if str(ref.memory_space) != "semaphore_mem":
+            sizes[str(ref.memory_space)] = sizes.get(str(ref.memory_space), 0) + math.prod(ref.shape) * ref.dtype.itemsize
+    return sizes
+
+
 def _walk_compiled(one, cfg, *, num_blocks, max_prompt_len, table_width):
     from flax.core import meta as flax_meta
 
@@ -105,6 +124,33 @@ def test_the_prefill_walk_at_internlm2s_widths_keeps_no_second_copy_of_the_model
     assert not re.search(r"= bf16\[(2048,8192|8192,2048)\]\S* (convert|fusion)\(", entry)
 
 
+def test_the_internlm2_decode_program_finds_its_kernel_in_its_scope(tpu_devices):
+    """The decode program of the InternLM2 cells at their widths, lanes, pool
+    and table, float32 leaves, 2 of 24 layers: one Mosaic call a layer, under
+    the name and in the scope a trace's reader asks for, whatever the copy
+    schedule inside it."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.serving import transformer_decode
+    from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = TransformerConfig(
+        vocab_size=92544, d_model=2048, n_layers=2, n_heads=16, n_kv_heads=8, d_ff=8192, max_seq_len=2048, rope_theta=1e6,
+    )
+    boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    shape = kv_cache_shape(cfg, 3500, 16)
+    cache = {"k": aval(shape, cfg.dtype), "v": aval(shape, cfg.dtype)}
+    fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1), donate_argnums=(4,))
+    text = fn.lower(params, aval((32,)), aval((32,)), aval((32, 128)), cache).compile().as_text()
+    assert _kernels(text) == cfg.n_layers
+    assert sum("paged_decode_attention" in n for n in program_scopes(text)["serve.attn.attend"]) == cfg.n_layers
+
+
 # -- latent attention and served experts: the DeepSeek-V3 cell's shapes --------
 
 
@@ -124,6 +170,10 @@ def test_the_latent_decode_kernel_compiles_at_the_dsv3_cells_shape(tpu_devices):
     )
     assert _kernels(text) == 1 and "paged_latent_attention" in text
     assert paged_mod.latent_kernel_takes(640, 512, 16, jnp.bfloat16) and not paged_mod.latent_kernel_takes(576, 512, 16, jnp.bfloat16)
+    # the tile's two slots and the accumulator, as before the copies crossed lanes; the schedule's two words are SMEM
+    scratch = _kernel_scratch(
+        fn, aval((64, 128, 640), jnp.bfloat16), aval((5, 24576, 16, 640), jnp.bfloat16), aval((64, 448), jnp.int32), aval((64,), jnp.int32))
+    assert scratch == {"vmem": 2 * 512 * 640 * 2 + 128 * 512 * 4, "smem": 8} and scratch["vmem"] <= paged_mod.TILE_BUFFER_BYTES
 
 
 def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, monkeypatch):
@@ -241,6 +291,8 @@ def test_the_window_decode_kernel_compiles_at_the_command_cells_shape(tpu_device
     store = aval((3, 32 * 272, 16, 1024), jnp.bfloat16)
     text = _compile(fn, aval((32, 128, 128), jnp.bfloat16), store, store, aval((32, 272), jnp.int32), aval((32,), jnp.int32))
     assert _kernels(text) == 1 and "paged_window_attention" in text
+    scratch = _kernel_scratch(fn, aval((32, 128, 128), jnp.bfloat16), store, store, aval((32, 272), jnp.int32), aval((32,), jnp.int32))
+    assert scratch == {"vmem": 2 * 2 * 256 * 1024 * 2, "smem": 8} and scratch["vmem"] <= paged_mod.TILE_BUFFER_BYTES
     # 16 query heads a KV head: the kernel multiplies a KV head's own queries, and no block-diagonal query is built
     assert paged_mod.attn_products(16) == "per_kv_head" and _arrays_with_dims(text, (32, 128, 1024)) == []
 
@@ -266,6 +318,9 @@ def test_the_decode_kernel_compiles_in_both_layouts_of_its_products(tpu_devices,
     text = _compile(fn, aval((32, heads, 128), jnp.bfloat16), pool, pool, aval((32, 128), jnp.int32), aval((32,), jnp.int32))
     assert _kernels(text) == 1 and "paged_decode_attention" in text
     assert paged_mod.attn_products(heads // 8) == products
+    # K and V, two slots of a 256-token tile each: what the kernel took before its copies crossed lanes
+    scratch = _kernel_scratch(fn, aval((32, heads, 128), jnp.bfloat16), pool, pool, aval((32, 128), jnp.int32), aval((32,), jnp.int32))
+    assert scratch == {"vmem": 2 * 2 * 256 * 1024 * 2, "smem": 8} and scratch["vmem"] <= paged_mod.TILE_BUFFER_BYTES
     assert bool(_arrays_with_dims(text, (32, heads, 1024))) == (products == "block_diagonal")
 
 

@@ -16,7 +16,7 @@ from flax.core import meta
 from determined_tpu.models import moe
 from determined_tpu.models.transformer import FULL, SLIDING, TransformerConfig, TransformerLM
 from determined_tpu.ops import paged_attention as paged
-from tests.model_cases import reference_module
+from tests.model_cases import PAGED_EDGES, check_copy_schedule, reference_module
 
 reference = reference_module("cohere2_moe")
 
@@ -28,12 +28,13 @@ BLOCK = 4
 # ---------------------------------------------------------------------------
 
 
-def _ring_case(dtype, lanes=3, window=40, ring_blocks=8, block=16, kv_heads=2, n_rep=4, head_dim=128, seed=0):
+def _ring_case(dtype, lanes=3, window=40, ring_blocks=8, block=16, kv_heads=2, n_rep=4, head_dim=128, seed=0, contexts=None):
     """Lanes whose rings hold their newest tokens by position; contexts inside
     the window, past it, past the ring's end, and an idle lane."""
     rng = np.random.default_rng(seed)
     ring = ring_blocks * block
-    contexts = [17, 90, 5 * ring + 3][:lanes]
+    contexts = [17, 90, 5 * ring + 3][:lanes] if contexts is None else list(contexts)
+    lanes = len(contexts)
     store = rng.standard_normal((2, lanes * ring_blocks, block, kv_heads * head_dim)).astype(np.float32)
     k_pool, v_pool = jnp.asarray(store, dtype), jnp.asarray(rng.standard_normal(store.shape).astype(np.float32), dtype)
     q = jnp.asarray(rng.standard_normal((lanes, kv_heads * n_rep, head_dim)).astype(np.float32), dtype)
@@ -90,6 +91,32 @@ def test_the_window_kernel_and_its_jnp_form_read_the_window_and_nothing_older(dt
     again = paged.paged_decode_attention(q, jnp.asarray(poisoned_k, dtype), jnp.asarray(poisoned_v, dtype), 1, tables, positions,
                                          scale=0.09, window=window, tile_blocks=tile_blocks, impl=impl)
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+#: beside ``PAGED_EDGES`` (a window of 40 over rings of 128 tokens: a lane of 70 sees from position 30, inside a
+#: block): rings that have wrapped, the oldest token seen inside a block, the newest at a block's and a tile's edge
+RING_EDGES = {**PAGED_EDGES, "wrapped_rings_that_start_inside_a_block": (5 * 128 + 3, 128 + 16, 300, 2 * 128)}
+
+
+@pytest.mark.parametrize("dtype, n_rep", [(jnp.float32, 4), (jnp.bfloat16, 16)], ids=["f32-block_diagonal", "bf16-per_kv_head"])
+@pytest.mark.parametrize("lanes", list(RING_EDGES))
+def test_the_window_kernels_copy_schedule_at_a_lanes_edges(lanes, dtype, n_rep):
+    """The window kernel over the lanes of ``RING_EDGES``, tiles of 2 blocks:
+    against the plain window attention and the ``jax.numpy`` form, then the
+    poison case (``check_copy_schedule``): of a ring only the blocks from the
+    oldest position the query sees to the newest are copied, none older in the
+    walk's first tile and none past the newest in its last."""
+    contexts = RING_EDGES[lanes]
+    q, k_pool, v_pool, tables, positions, window, _ = _ring_case(dtype, n_rep=n_rep, contexts=contexts)
+
+    def run(k_pool, v_pool, impl="kernel_interpret"):
+        return paged.paged_decode_attention(q, k_pool, v_pool, 1, tables, positions, scale=0.09, window=window, tile_blocks=2, impl=impl)
+
+    got = check_copy_schedule(run, (k_pool, v_pool), 1, tables, contexts, 16, window)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, _plain_window_attention(q, k_pool, v_pool, 1, tables, positions, window, 0.09), atol=tol, rtol=tol)
+    np.testing.assert_allclose(got, np.asarray(run(k_pool, v_pool, "jnp")), atol=2e-6, rtol=2e-5)
+    assert not got[[n == 0 for n in contexts]].any()
 
 
 def test_a_window_wider_than_its_ring_is_refused():
@@ -175,7 +202,8 @@ def test_the_shared_experts_are_averaged_not_summed():
 )
 def test_the_engine_says_what_a_tile_of_its_decode_kernel_multiplies(heads, want):
     """``serve.setup.kv_pool`` and ``/stats`` name the layout of the GQA kernel's
-    products, from the function the kernel's wrapper asks; a latent model has none."""
+    products, from the function the kernel's wrapper asks; a latent model has none.
+    Both kinds name the copy schedule their kernels share (``COPY_SCHEDULE``)."""
     from determined_tpu.observability import get_tracer
     from determined_tpu.serve.config import ServeConfig
     from determined_tpu.serve.engine import DecodeKernels, ServeEngine
@@ -197,8 +225,13 @@ def test_the_engine_says_what_a_tile_of_its_decode_kernel_multiplies(heads, want
 
     # the kind says it once, to the set-up span and to /stats
     assert kernels.kinds == ((PAGED_KV,) if want else (PAGED_LATENT,))
-    assert PAGED_KV.report(cfg, None, 0) == ({"attn_products": want} if want else {})
+    schedule = {"tile_copies": "live_blocks", "lane_prefetch": True}
+    assert paged.COPY_SCHEDULE == schedule
+    assert PAGED_KV.report(cfg, None, 0) == ({"attn_products": want, **schedule} if want else {})
+    assert PAGED_LATENT.report(cfg, None, 0) == ({} if want else schedule)
     assert pool["args"].get("attn_products") == want and stats.get("attn_products") == want
+    assert {k: pool["args"][k] for k in schedule} == schedule == {k: stats[k] for k in schedule}
+    assert stats["step_inputs"]["paged_live_tokens"] == stats["step_inputs"]["paged_copied_tokens"] == 0  # no step yet
 
 
 # ---------------------------------------------------------------------------

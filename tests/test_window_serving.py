@@ -371,6 +371,13 @@ def test_two_lanes_of_unequal_length_through_the_engine_match_the_full_forward(e
     assert stats["window_store"] == {"window_store_bytes": 2 * 3 * 3 * 264 * 32 * 4, "ring_tokens": 264}
     assert set(stats["step_counters"]) == set(WINDOW_RING.counters + SERVE_COUNTERS)
     assert stats["step_counters"]["serve.kv.full_tokens"] > stats["step_counters"]["serve.kv.window_tokens"] / 3 > 0
+    # the host's count of the kernels' walks (``walk_counts``: the full layer's whole context, the three window layers'
+    # newest 8 tokens) reads what the device counted, and the copies brought whole blocks: more, and never a whole tile
+    walked = stats["step_inputs"]
+    assert [kind.walked(cfg) for kind in kernels.kinds] == [(1, None), (3, 8)]
+    assert walked["paged_live_tokens"] == stats["step_counters"]["serve.kv.full_tokens"] + stats["step_counters"]["serve.kv.window_tokens"]
+    assert walked["paged_live_tokens"] < walked["paged_copied_tokens"] < walked["paged_live_tokens"] + walked["decode_steps"] * 2 * (1 + 3 * 2) * BLOCK
+    assert stats["tile_copies"] == "live_blocks" and stats["lane_prefetch"] is True
     assert stats["kv_cache"]["used"] == 0                            # the allocator counts the full layer's blocks, all freed
 
 
