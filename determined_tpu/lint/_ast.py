@@ -10,7 +10,8 @@ walker computes the two pieces of context every rule needs:
   ``jax.jit``-style decorator or named ``train_step``/``eval_step`` (the
   Trainer's own convention); nested functions inherit the property.
 - **traced names** — which local names hold traced values inside a step
-  function: its parameters (minus ``self``/``model``) seeded, then a cheap
+  function: its parameters (minus ``self``/``model`` and those its jit
+  decorator names in ``static_argnames``/``static_argnums``) seeded, then a cheap
   two-pass forward taint (``x = f(batch)`` makes ``x`` traced).  Attribute
   reads of static metadata (``.shape``/``.dtype``/``.ndim``) break the
   taint, so shape-based Python branching stays legal.
@@ -270,16 +271,42 @@ def _taint_function(node: ast.AST, seed: Set[str]) -> Set[str]:
     return traced
 
 
-def _has_jit_decorator(node: ast.AST) -> bool:
+def _jit_decorators(node: ast.AST) -> List[ast.AST]:
+    """The decorators of ``node`` that jit it: ``@jax.jit``, ``@jit(...)``,
+    ``@functools.partial(jax.jit, ...)``."""
+    found: List[ast.AST] = []
     for dec in getattr(node, "decorator_list", []):
         names: List[Optional[str]] = [dotted_name(dec)]
         if isinstance(dec, ast.Call):
             names.append(dotted_name(dec.func))
             names.extend(dotted_name(a) for a in dec.args)
-        for name in names:
-            if name and (name == "jit" or name.endswith(".jit")):
-                return True
-    return False
+        if any(name and (name == "jit" or name.endswith(".jit")) for name in names):
+            found.append(dec)
+    return found
+
+
+def _jit_static_params(node: ast.AST) -> Set[str]:
+    """Parameters a jit decorator of ``node`` declares static, by name
+    (``static_argnames``) or by position (``static_argnums``): Python values
+    the trace is made FOR, not traced arrays, so branching on them is how a
+    jitted function picks its form."""
+    args = getattr(node, "args", None)
+    positional = [] if args is None else [a.arg for a in list(args.posonlyargs) + list(args.args)]
+    static: Set[str] = set()
+    for dec in _jit_decorators(node):
+        for kw in getattr(dec, "keywords", []):
+            if kw.arg not in ("static_argnames", "static_argnums"):
+                continue
+            try:
+                value = ast.literal_eval(kw.value)
+            except ValueError:
+                continue  # computed: nothing to read
+            for item in value if isinstance(value, (tuple, list)) else (value,):
+                if kw.arg == "static_argnames" and isinstance(item, str):
+                    static.add(item)
+                elif kw.arg == "static_argnums" and isinstance(item, int) and -len(positional) <= item < len(positional):
+                    static.add(positional[item])
+    return static
 
 
 def _is_trial_classdef(node: ast.ClassDef, assume: Set[str]) -> bool:
@@ -325,7 +352,7 @@ class _Walker(ast.NodeVisitor):
         is_step = (
             (in_trial_method and name in STEP_METHODS)
             or name in STEP_FUNCTION_NAMES
-            or _has_jit_decorator(node)
+            or bool(_jit_decorators(node))
             or ctx.in_step  # nested in a step function
         )
         traced: Set[str] = set()
@@ -341,7 +368,7 @@ class _Walker(ast.NodeVisitor):
                 for extra in (args.vararg, args.kwarg):
                     if extra is not None:
                         params.append(extra.arg)
-                traced = {p for p in params if p not in UNTRACED_PARAMS}
+                traced = {p for p in params if p not in UNTRACED_PARAMS} - _jit_static_params(node)
             traced |= ctx.traced_names()
             traced = _taint_function(node, traced)
         ctx.func_stack.append(FunctionScope(node, is_step, traced))

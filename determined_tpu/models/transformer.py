@@ -45,6 +45,7 @@ import optax
 from flax import linen as nn
 
 from determined_tpu.data import DataLoader, SyntheticDataset
+from determined_tpu.ops import kernel_form
 from determined_tpu.ops.attention import dot_product_attention, reference_attention
 from determined_tpu.ops.retention import recent_shapes, retention_quadratic, state_shapes
 from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
@@ -1524,7 +1525,7 @@ def _latent_attend_local(cfg: TransformerConfig):
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r[:, None], kv.shape[:3] + k_r.shape[-1:])], axis=-1)
         v = kv[..., nope:]
-        if _on_tpu() and q.shape[2] >= 256:
+        if kernel_form.on_tpu() and q.shape[2] >= 256:
             from determined_tpu.ops.flash_attention import flash_attention
 
             width = -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
@@ -1535,12 +1536,6 @@ def _latent_attend_local(cfg: TransformerConfig):
         return out.transpose(0, 2, 1, 3)
 
     return attend
-
-
-def _on_tpu() -> bool:
-    from determined_tpu.ops import paged_attention
-
-    return paged_attention._on_tpu()  # one switch for the serving forward's kernels (tests steer it)
 
 
 #: latent attention's hparams: passed to the config as they are (absent: GQA)
@@ -1845,7 +1840,7 @@ class LMTrial(JaxTrial):
         mu_dtype = jnp.bfloat16 if bool(g("adam_mu_bf16", False)) else None
         fused = g("fused_adamw", "auto")
         if fused == "auto":
-            fused = jax.default_backend() == "tpu"
+            fused = kernel_form.on_tpu()
         if fused:
             # single-sweep Pallas AdamW+clip (ops/fused_adamw.py): 8 HBM
             # passes vs optax's measured 9 on the bandwidth-bound update

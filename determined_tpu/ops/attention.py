@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from determined_tpu.ops import kernel_form
 from determined_tpu.parallel.mesh import MeshAxes
 
 NEG_INF = -1e30
@@ -95,8 +96,7 @@ def dot_product_attention(
     already inside a manual region (pipeline stages).
     """
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "flash" if on_tpu and q.shape[-2] >= 256 else "reference"
+        impl = "flash" if kernel_form.on_tpu() and q.shape[-2] >= 256 else "reference"
     if impl == "reference":
         return reference_attention(q, k, v, causal=causal, scale=scale, window=window)
     if impl == "flash":

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from determined_tpu.ops import grouped_matmul as gm
+from determined_tpu.ops import kernel_form
 from determined_tpu.ops.grouped_matmul import TileLayout
 
 #: bytes of the resident float32 block (Pallas keeps two of an output block)
@@ -233,7 +233,7 @@ def tokens_of_rows(
     assert rows.shape[0] == layout.rows
     return _tokens_of_rows(
         rows, row_token, tile_rows, layout.live_tiles,
-        rows=layout.rows, tile=layout.tile, tokens=tokens, interpret=gm._interpret(),
+        rows=layout.rows, tile=layout.tile, tokens=tokens, interpret=kernel_form.interpreted_off_chip(),
     )
 
 
@@ -242,7 +242,7 @@ def rows_of_tokens(x: jax.Array, row_token: jax.Array, tile_rows: jax.Array, lay
     row for every owned row, zeros for a live tile's other rows.  Rows of dead
     tiles are left as they are in memory: never read them."""
     return _rows_of_tokens(
-        x, row_token, tile_rows, layout.live_tiles, rows=layout.rows, tile=layout.tile, interpret=gm._interpret(),
+        x, row_token, tile_rows, layout.live_tiles, rows=layout.rows, tile=layout.tile, interpret=kernel_form.interpreted_off_chip(),
     )
 
 

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from determined_tpu.ops import kernel_form
+
 NEG_INF = -1e30
 # Measured on v5e (hd=128, bf16): 1024-blocks run the fwd+bwd sweep ~3.7x
 # faster than 128-blocks (36 vs 10 TFLOP/s at seq 1k, 49 vs 12 at seq 4k) —
@@ -82,10 +84,6 @@ SUB_TILE = 128
 # VPU multiply per logit from the kernel's bound resource (the VPU).  The
 # stored lse is base-2 (m + log2 l), consumed only by the bwd kernels.
 LOG2E = 1.4426950408889634
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -360,7 +358,7 @@ def _flash_fwd_call(
     q: jax.Array, k: jax.Array, v: jax.Array, scale: float, causal: bool,
     block_q: int, block_k: int, window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    return _fwd_program(q, k, v, scale, causal, block_q, block_k, window, SUB_TILE, _interpret())
+    return _fwd_program(q, k, v, scale, causal, block_q, block_k, window, SUB_TILE, kernel_form.interpreted_off_chip())
 
 
 # One trace a configuration, whoever asks and how often: a model's layers of
@@ -515,7 +513,7 @@ def _dkv_kernel(
 def _flash_bwd_call(
     q, k, v, do, out, lse, scale, causal, block_q, block_k, window=None
 ):
-    return _bwd_program(q, k, v, do, out, lse, scale, causal, block_q, block_k, window, SUB_TILE, _interpret())
+    return _bwd_program(q, k, v, do, out, lse, scale, causal, block_q, block_k, window, SUB_TILE, kernel_form.interpreted_off_chip())
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12), inline=True)   # as _fwd_program

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
+from determined_tpu.ops import kernel_form
+
 # Per-ref block budget.  7 refs (p/m/v/g in, p/m/v out) x double-buffered
 # must fit the 16 MiB scoped-VMEM budget; 1 MiB blocks measured 16.84M > 16M
 # on v5e (OOM), 768 KiB measured fastest of {512K, 768K}.
@@ -66,10 +68,6 @@ class FusedAdamWState(NamedTuple):
     count: jax.Array  # int32 step counter
     mu: Any           # first moment (param dtype or mu_dtype)
     nu: Any           # second moment (f32)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _adamw_kernel(b1, b2, eps, wd, scal_ref, p_ref, m_ref, v_ref, g_ref,
@@ -164,7 +162,7 @@ def _leaf_pallas(p, m, v, g, scalars, *, b1, b2, eps, wd):
         ],
         # in-place p/m/v (argument order: scalars, p, m, v, g)
         input_output_aliases={1: 0, 2: 1, 3: 2},
-        interpret=_interpret(),
+        interpret=kernel_form.interpreted_off_chip(),
     )(scalars, p, m, v, g)
     return po, mo, vo
 

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from determined_tpu.ops import kernel_form
+
 #: rows a tile: a weight block stays resident while a group's tiles pass.
 #: Every group wastes half a tile on average, so a finer tile is less padding
 #: to compute and a step time that follows the split of the rows less: at 512
@@ -45,10 +47,6 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_TILE = 256
 #: two buffers of a [2304, 896] float32 block and the tiles beside them
 _VMEM_LIMIT = 96 * 1024 * 1024
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 class TileLayout(NamedTuple):
@@ -162,7 +160,7 @@ def gmm(
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=kernel_form.interpreted_off_chip(),
         name="moe_gmm",
     )(layout.tile_group, layout.live_tiles, lhs, rhs)
 
@@ -186,7 +184,7 @@ def tgmm(lhs: jax.Array, grads: jax.Array, layout: TileLayout, groups: int) -> j
         ),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), jnp.float32),
         compiler_params=_params(),
-        interpret=_interpret(),
+        interpret=kernel_form.interpreted_off_chip(),
         name="moe_tgmm",
     )(layout.tile_group, layout.live_tiles, lhs, grads)
 

@@ -74,6 +74,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from determined_tpu.ops import kernel_form
 from determined_tpu.ops.flash_attention import NEG_INF
 
 # Tokens a tile aims for.  A tile is one trip of the walk: its matmuls,
@@ -89,10 +90,6 @@ from determined_tpu.ops.flash_attention import NEG_INF
 TILE_TOKENS = 256
 # VMEM the kernel's four tile buffers (K and V, two slots each) may take
 TILE_BUFFER_BYTES = 4 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _tile_blocks(block_size: int, table_width: int, token_bytes: int) -> int:
@@ -162,52 +159,24 @@ def paged_decode_attention(
         tile_blocks = _tile_blocks(
             block_size, block_tables.shape[1], k_pool.shape[3] * k_pool.dtype.itemsize
         )
-    tiles = kernel_takes(head_dim, block_size, k_pool.dtype)
-    if impl is None:
-        impl = "kernel" if _on_tpu() and tiles else "jnp"
-    if impl != "jnp" and not tiles:
-        raise ValueError(
-            f"the paged-attention kernel needs head_dim % 128 == 0 and whole "
-            f"sublane tiles a block (got head_dim={head_dim}, "
-            f"block_size={block_size}, {k_pool.dtype})"
-        )
-    if window is not None:
-        if window < 1 or block_tables.shape[1] * block_size < window:
-            raise ValueError(f"a window of {window} tokens needs a ring of at least as many (got {block_tables.shape[1]} blocks of {block_size})")
-        return _paged_window_attention(
-            q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
-            scale=scale, tile_blocks=tile_blocks, impl=impl, window=window,
-        )
+    impl = kernel_form.resolve_impl(
+        impl, kernel_takes(head_dim, block_size, k_pool.dtype),
+        f"the paged-attention kernel needs head_dim % 128 == 0 and whole "
+        f"sublane tiles a block (got head_dim={head_dim}, "
+        f"block_size={block_size}, {k_pool.dtype})",
+    )
+    if window is not None and (window < 1 or block_tables.shape[1] * block_size < window):
+        raise ValueError(f"a window of {window} tokens needs a ring of at least as many (got {block_tables.shape[1]} blocks of {block_size})")
     return _paged_attention(
         q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
-        scale=scale, tile_blocks=tile_blocks, impl=impl,
+        scale=scale, tile_blocks=tile_blocks, impl=impl, window=window,
     )
 
 
-# The layer is an ARGUMENT of one jitted function, so a model's layers share
-# one trace and one lowering of it: lowering the kernel to Mosaic takes the
-# host ~0.2 s, and a 24-layer decode program that inlined it paid that 24
-# times at every start, cached program or not (3.4 s of ``setup_s`` on the
-# chip: my chip run, PR 25).
-@functools.partial(jax.jit, static_argnames=("scale", "tile_blocks", "impl"))
-def _paged_attention(
-    q, k_pool, v_pool, layer, block_tables, positions, *, scale, tile_blocks, impl
-):
-    lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
-    if impl == "jnp":
-        return _paged_attention_jnp(
-            q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks
-        )
-    return _paged_attention_pallas(
-        q, k_pool, v_pool, layer, block_tables, lengths, scale, tile_blocks,
-        interpret=impl == "kernel_interpret",
-    )
-
-
-# the window layers' call: a jitted function of its own, so that its kernel
-# keeps its name and its scope in the optimized program (PERF.md, PR 33)
+# one jitted function, the layer an argument (``ops/kernel_form.py`` says why);
+# ``window`` None: a full layer
 @functools.partial(jax.jit, static_argnames=("scale", "tile_blocks", "impl", "window"))
-def _paged_window_attention(
+def _paged_attention(
     q, k_pool, v_pool, layer, block_tables, positions, *, scale, tile_blocks, impl, window
 ):
     lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
@@ -614,7 +583,7 @@ def _paged_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, rows, head_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=pltpu.InterpretParams() if interpret else False,
+        interpret=kernel_form.interpret_params(interpret),
         name="paged_decode_attention" if window is None else "paged_window_attention",
     )(
         layer.reshape(1),
@@ -682,24 +651,19 @@ def paged_latent_attention(
     if tile_blocks is None:
         tokens = min(LATENT_TILE_TOKENS, TILE_BUFFER_BYTES // (2 * width * pool.dtype.itemsize))
         tile_blocks = max(1, min(block_tables.shape[1], tokens // block_size))
-    tiles = latent_kernel_takes(width, value_dim, block_size, pool.dtype)
-    if impl is None:
-        impl = "kernel" if _on_tpu() and tiles else "jnp"
-    if impl != "jnp" and not tiles:
-        raise ValueError(
-            f"the latent paged-attention kernel needs width % 128 == 0, value_dim % 128 == 0 and "
-            f"whole sublane tiles a block (got width={width}, value_dim={value_dim}, "
-            f"block_size={block_size}, {pool.dtype})"
-        )
+    impl = kernel_form.resolve_impl(
+        impl, latent_kernel_takes(width, value_dim, block_size, pool.dtype),
+        f"the latent paged-attention kernel needs width % 128 == 0, value_dim % 128 == 0 and "
+        f"whole sublane tiles a block (got width={width}, value_dim={value_dim}, "
+        f"block_size={block_size}, {pool.dtype})",
+    )
     return _paged_latent(
         q.astype(pool.dtype), pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
         scale=scale, value_dim=value_dim, tile_blocks=tile_blocks, impl=impl,
     )
 
 
-# one trace and one lowering for every layer, as ``_paged_attention``; and a
-# Pallas call inside a jitted function of its own keeps its name in the
-# optimized program, which is how a trace's reader finds it (``jit.scopes``)
+# as ``_paged_attention``
 @functools.partial(jax.jit, static_argnames=("scale", "value_dim", "tile_blocks", "impl"))
 def _paged_latent(q, pool, layer, block_tables, positions, *, scale, value_dim, tile_blocks, impl):
     lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
@@ -814,7 +778,7 @@ def _paged_latent_pallas(q, pool, layer, block_tables, lengths, scale, value_dim
         ),
         out_shape=jax.ShapeDtypeStruct((b, rows, value_dim), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=pltpu.InterpretParams() if interpret else False,
+        interpret=kernel_form.interpret_params(interpret),
         name="paged_latent_attention",
     )(layer.reshape(1), lengths, block_tables.reshape(-1).astype(jnp.int32), q, pool)
     return out[:, :n_heads, :]

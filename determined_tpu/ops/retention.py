@@ -67,6 +67,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from determined_tpu.ops import kernel_form
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: how the decode kernel multiplies phi(q) with the float32 state on the MXU
 QUERY_PRECISION = _HIGHEST
@@ -79,12 +81,6 @@ _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 #: 8.19 at 16, 8.00 at 32, 8.20 at 64, 8.70 at 128 (the write falls as 1 / C,
 #: the rows a program copies and multiplies grow with C; PERF.md section 5)
 FOLD_EVERY = 32
-
-
-def _on_tpu() -> bool:
-    from determined_tpu.ops import paged_attention
-
-    return paged_attention._on_tpu()  # one switch for the serving forward's kernels (tests steer it)
 
 
 def phi_rows(head_dim: int) -> int:
@@ -165,10 +161,10 @@ def retention_chunk(
     b, h, s, d = q.shape
     g = k.shape[1]
     n = h // g
-    if impl is None:
-        impl = "kernel" if _on_tpu() and chunk_kernel_takes(n * s, s, d, state.dtype) else "jnp"
-    if impl != "jnp" and not chunk_kernel_takes(n * s, s, d, state.dtype):
-        raise ValueError(f"the retention chunk kernel does not take {n * s} query rows of {s} tokens at head_dim {d}, {state.dtype}")
+    impl = kernel_form.resolve_impl(
+        impl, chunk_kernel_takes(n * s, s, d, state.dtype),
+        f"the retention chunk kernel does not take {n * s} query rows of {s} tokens at head_dim {d}, {state.dtype}",
+    )
     f32 = jnp.float32
     qf, kf, vf = q.astype(f32).reshape(b, g, n, s, d), k.astype(f32), v.astype(f32)
     vf = jnp.where(valid[:, None, :, None], vf, 0.0)
@@ -183,8 +179,10 @@ def retention_chunk(
     # what came before it, and the chunk into it: each key decayed from its place to the chunk's end
     total = cum[..., -1]
     left = jnp.where(valid[:, None, :], jnp.exp(total[..., None] - cum), 0.0)  # [b, g, s]
-    against = _chunk_state_jnp if impl == "jnp" else functools.partial(_chunk_state_pallas, interpret=impl == "kernel_interpret")
-    num0, den0, s1, z1 = against(qf, kf, vf, left, jnp.exp(total), state, norm)
+    if impl == "jnp":
+        num0, den0, s1, z1 = _chunk_state_jnp(qf, kf, vf, left, jnp.exp(total), state, norm)
+    else:
+        num0, den0, s1, z1 = _chunk_state_pallas(qf, kf, vf, left, jnp.exp(total), state, norm, interpret=impl == "kernel_interpret")
     since = jnp.exp(cum)[:, :, None, :]  # the state at the chunk's start, decayed up to each query
     num, den = num + since[..., None] * num0, den + since * den0
     out = num / jnp.where(den == 0.0, 1.0, den)[..., None]
@@ -291,7 +289,7 @@ def _chunk_state_pallas(qf, kf, vf, left, kept, state, norm, *, interpret: bool)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
-        interpret=pltpu.InterpretParams() if interpret else False,
+        interpret=kernel_form.interpret_params(interpret),
         name="retention_chunk",
     )(
         qf.reshape(b, g, m, d), kf, left[..., None], vf.transpose(0, 1, 3, 2), kept[..., None, None], state, norm,
@@ -335,20 +333,20 @@ def retention_decode(
     the kernel on a TPU when :func:`kernel_takes` the shapes.
     """
     d = q.shape[-1]
-    if impl is None:
-        impl = "kernel" if _on_tpu() and kernel_takes(d, state.dtype) else "jnp"
-    if impl != "jnp" and not kernel_takes(d, state.dtype):
-        raise ValueError(f"the retention kernel needs head_dim 128 and a 2- or 4-byte state (got {d}, {state.dtype})")
+    impl = kernel_form.resolve_impl(
+        impl, kernel_takes(d, state.dtype), f"the retention kernel needs head_dim 128 and a 2- or 4-byte state (got {d}, {state.dtype})"
+    )
     return _retention_decode(q, k, v, log_g, state, norm, tuple(recent), jnp.asarray(layer, jnp.int32), live, impl=impl)
 
 
-# the layer is an ARGUMENT of one jitted function: a model's layers share one
-# lowering of the kernel (as ``ops/paged_attention.py _paged_attention``)
+# one jitted function, the layer an argument (``ops/kernel_form.py`` says why)
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _retention_decode(q, k, v, log_g, state, norm, recent, layer, live, *, impl):
     recent, rows = _append(k, v, log_g, recent, layer, live)
-    against = _retention_decode_jnp if impl == "jnp" else functools.partial(_retention_decode_pallas, interpret=impl == "kernel_interpret")
-    out, state, norm = against(q, *recent[:2], *rows, state, norm, layer, live)
+    if impl == "jnp":
+        out, state, norm = _retention_decode_jnp(q, *recent[:2], *rows, state, norm, layer, live)
+    else:
+        out, state, norm = _retention_decode_pallas(q, *recent[:2], *rows, state, norm, layer, live, interpret=impl == "kernel_interpret")
     return out, state, norm, recent
 
 
@@ -539,7 +537,7 @@ def _retention_decode_pallas(q, keys, vals, left, kept, due, state, norm, layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
-        interpret=pltpu.InterpretParams() if interpret else False,
+        interpret=kernel_form.interpret_params(interpret),
         name="retention_decode",
     )(
         layer.reshape(1), live.astype(jnp.int32), due.astype(jnp.int32), *_held_blocks(live, g), *_held_blocks(due, g),

@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from determined_tpu.ops import kernel_form
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: VMEM the decode kernel may take: a group's 16 states of [128, 256] float32
 #: are 2 MB, held twice coming in and twice going out, beside what the unrolled
@@ -53,12 +55,6 @@ _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 #: the state one program of the decode kernel aims to move each way: a grid step costs ~0.35 us whatever it does,
 #: and 2 MB take 2.6 us at the chip's 819 GB/s
 _PROGRAM_BYTES = 2 * 1024 * 1024
-
-
-def _on_tpu() -> bool:
-    from determined_tpu.ops import paged_attention
-
-    return paged_attention._on_tpu()  # one switch for the serving forward's kernels (tests steer it)
 
 
 def state_shape(layers: int, lanes: int, heads: int, head_dim: int, d_state: int) -> Tuple[int, ...]:
@@ -167,19 +163,15 @@ def ssm_decode(
     the kernel on a TPU when :func:`kernel_takes` the shapes.
     """
     heads, groups, (p, n) = x.shape[1], B.shape[1], state.shape[-2:]
-    takes = kernel_takes(heads, groups, p, n, state.dtype)
-    if impl is None:
-        impl = "kernel" if _on_tpu() and takes else "jnp"
-    if impl != "jnp" and not takes:
-        raise ValueError(
-            f"the ssm kernel needs a head of whole 64-wide and a state of whole 128-wide tiles and 8 heads a group or a multiple "
-            f"(got {heads} heads over {groups} groups, P {p}, N {n}, {state.dtype})"
-        )
+    impl = kernel_form.resolve_impl(
+        impl, kernel_takes(heads, groups, p, n, state.dtype),
+        f"the ssm kernel needs a head of whole 64-wide and a state of whole 128-wide tiles and 8 heads a group or a multiple "
+        f"(got {heads} heads over {groups} groups, P {p}, N {n}, {state.dtype})",
+    )
     return _ssm_decode(x, B, C, dt, A, Dskip, state, jnp.asarray(layer, jnp.int32), live, impl=impl)
 
 
-# the layer is an ARGUMENT of one jitted function: a model's layers share one
-# lowering of the kernel (as ``ops/retention.py _retention_decode``)
+# one jitted function, the layer an argument (``ops/kernel_form.py`` says why)
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _ssm_decode(x, B, C, dt, A, Dskip, state, layer, live, *, impl):
     f32 = jnp.float32
@@ -265,7 +257,7 @@ def _ssm_decode_pallas(enters, kept, B, C, state, layer, live, *, interpret: boo
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
-        interpret=pltpu.InterpretParams() if interpret else False,
+        interpret=kernel_form.interpret_params(interpret),
         name="ssm_decode",
     )(
         layer.reshape(1), live.astype(jnp.int32),

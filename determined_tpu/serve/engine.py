@@ -497,6 +497,15 @@ class ServeEngine:
         self.kernels = kernels
         self.cfg = kernels.serve_cfg
         self.lanes = LaneTable(self.cfg.max_batch)
+        # THE STEP'S OWN STATE: ``_tables``, ``_tables_on_device``,
+        # ``_positions`` and ``_ids_on_device`` belong to whichever thread runs
+        # ``step_once``: the engine's own after ``start()``, a test's before
+        # it, never both.  Every read and write of the four is under
+        # ``step_once`` (``_admit_one``, ``_decode_and_sample`` over
+        # ``_decode_batch``, ``_retire_lane``); ``stats()``, ``submit()``,
+        # ``stop()`` and ``drain()`` read none of them.  One writer, no lock:
+        # each write below is marked ``lint-ok[unlocked-shared-state]`` on this
+        # argument, and a use of one of them outside the step breaks it.
         #: every lane's block table, for the engine's life: a lane that joins
         #: writes its row, a lane that retires gets the scratch block 0 again
         #: (an idle lane's row never names a block the allocator may have
@@ -1133,7 +1142,7 @@ class ServeEngine:
         }
         if self._tables_on_device is None:
             # a copy: the matrix is written again while the device's buffer is still held
-            self._tables_on_device = jax.device_put(self._tables.copy())
+            self._tables_on_device = jax.device_put(self._tables.copy())  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
         tokens = self._ids_on_device
         if tokens is None:
             tokens = np.zeros(b, np.int32)
@@ -1150,7 +1159,7 @@ class ServeEngine:
                     draws[0, i] = seq.request.temperature
                     draws[1, i] = seq.rng.random()
             on_device = jax.device_put(draws)
-            self._positions = np.where(positions >= 0, positions + 1, positions)
+            self._positions = np.where(positions >= 0, positions + 1, positions)  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
             for rows, window in self._walked:
                 walk = walk_counts(positions, self.cfg.block_size, window)
                 sent["paged_live_tokens"] += rows * walk.live_tokens
@@ -1226,7 +1235,7 @@ class ServeEngine:
         if stamps is not None and stamps[0] < t_call:
             stamps = None
         own = self._advance_lane
-        self._ids_on_device = ids if own is None else None
+        self._ids_on_device = ids if own is None else None  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
         finished: List[Tuple[int, ActiveSeq]] = []
         live = 0
         for i, seq in enumerate(lanes):
@@ -1329,20 +1338,20 @@ class ServeEngine:
             return True
         if seq is not None:
             self.lanes.join(seq, seq.lane)
-            self._positions[seq.lane] = seq.pos
-            self._ids_on_device = None  # its first token is the host's draw
+            self._positions[seq.lane] = seq.pos  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
+            self._ids_on_device = None  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__): its first token is the host's draw
             if seq.blocks:
-                self._tables[seq.lane] = seq.block_table
-                self._tables_on_device = None
+                self._tables[seq.lane] = seq.block_table  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
+                self._tables_on_device = None  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
         return True
 
     def _retire_lane(self, lane: int, seq: ActiveSeq) -> None:
         self.lanes.retire(lane)
-        self._positions[lane] = -1
+        self._positions[lane] = -1  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
         if seq.blocks:
             # before the blocks are free again: an idle lane names the scratch block alone
-            self._tables[lane] = 0
-            self._tables_on_device = None
+            self._tables[lane] = 0  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
+            self._tables_on_device = None  # dtpu: lint-ok[unlocked-shared-state] the step's own (__init__)
         self._retire_seq(seq)
 
     def step_once(self) -> bool:

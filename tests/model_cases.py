@@ -138,6 +138,7 @@ adamw_mod = importlib.import_module("determined_tpu.ops.fused_adamw")
 paged_mod = importlib.import_module("determined_tpu.ops.paged_attention")
 grouped_mod = importlib.import_module("determined_tpu.ops.grouped_matmul")
 rows_mod = importlib.import_module("determined_tpu.ops.expert_rows")
+form_mod = importlib.import_module("determined_tpu.ops.kernel_form")
 
 TOPOLOGY = "v5e:2x2"
 
@@ -161,10 +162,7 @@ def real_kernels_no_cache(monkeypatch):
     without one, and the next run would warn)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
-    monkeypatch.setattr(adamw_mod, "_interpret", lambda: False)
-    monkeypatch.setattr(grouped_mod, "_interpret", lambda: False)
-    monkeypatch.setattr(paged_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(form_mod, "on_tpu", lambda: True)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()

@@ -361,6 +361,64 @@ def test_clean_fixture_passes(rule):
     assert diags == [], [d.format() for d in diags]
 
 
+#: a jitted kernel wrapper as ``ops/`` writes them: ``impl`` / ``window`` pick the form the trace is made for
+_STATIC_ARGS = {
+    "static_argnames": ("""
+import functools, jax
+@functools.partial(jax.jit, static_argnames=("scale", "impl"))
+def _decode(q, pool, layer, *, scale, impl):
+    if impl == "jnp":
+        return q * scale
+    return q + pool[layer]
+""", []),
+    "static_argnums": ("""
+import functools, jax
+@functools.partial(jax.jit, static_argnums=(2, 3), inline=True)
+def _program(q, k, window, interpret):
+    if window is not None:
+        q = q[..., -window:]
+    return q @ k
+""", []),
+    "traced_beside_static": ("""
+import functools, jax
+@functools.partial(jax.jit, static_argnames=("impl",))
+def _decode(q, live, *, impl):
+    if impl == "jnp":
+        q = q * 2
+    if live > 0:
+        q = q + 1
+    return q
+""", [7]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STATIC_ARGS))
+def test_a_jit_decorators_static_arguments_are_not_traced(case):
+    source, lines = _STATIC_ARGS[case]
+    found = [d.line for d in analyze_source(source, "k.py") if d.rule == "traced-control-flow"]
+    assert found == lines
+
+
+def test_the_lint_gate_is_green(monkeypatch, capsys):
+    """What ``scripts/lint.sh`` runs, argument for argument, in this process:
+    any finding fails tier-1 and is printed."""
+    import shlex
+    import sys
+
+    from determined_tpu.cli.main import main as cli_main
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "scripts", "lint.sh"), encoding="utf-8") as f:
+        command = f.read().split("exec python -m determined_tpu.cli ", 1)[1]
+    argv = [a for a in shlex.split(command.replace("\\\n", " ")) if a != "$@"]
+    assert argv[:3] == ["lint", "--strict", "--native"] and argv[-3:] == ["determined_tpu", "examples", "scripts"]
+    monkeypatch.chdir(repo)
+    monkeypatch.setattr(sys, "path", list(sys.path))  # ``lint`` puts the working directory on it
+    rc = cli_main(argv)
+    out = capsys.readouterr().out
+    assert rc == 0, out
+
+
 def test_diagnostics_carry_anchor_and_severity():
     diags = analyze_source(textwrap.dedent(BAD["host-sync"]), "anchored.py")
     assert diags, "expected findings"

@@ -18,7 +18,7 @@ from flax.core import meta as flax_meta
 from determined_tpu.models import cache_kinds
 from determined_tpu.models.serving import init_kv_cache, transformer_decode, transformer_prefill
 from determined_tpu.models.transformer import TransformerConfig, TransformerLM
-from determined_tpu.ops import paged_attention as pa
+from determined_tpu.ops import kernel_form, paged_attention as pa
 from tests.model_cases import PAGED_EDGES, causal_forward, check_copy_schedule
 
 # lanes of the ragged batch, by what each one pins (block_size 16, table 6):
@@ -150,7 +150,7 @@ def test_shapes_the_kernel_does_not_take_run_the_jnp_form(head_dim, block_size, 
     choice is made from shapes, even on a TPU, and asking for the kernel
     by name says why it cannot be had."""
     assert not pa.kernel_takes(head_dim, block_size, jnp.bfloat16)
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kernel_form, "on_tpu", lambda: True)
     positions = [-1, 0, block_size - 1, 6 * block_size - 1]
     q, k_pool, v_pool, tables, positions = _pool_case(
         jnp.bfloat16, 2, head_dim, block_size, positions
@@ -169,13 +169,13 @@ def test_kernel_is_the_choice_on_a_tpu_when_the_shapes_tile(monkeypatch):
     taken = []
     monkeypatch.setattr(
         pa, "_paged_attention_pallas",
-        lambda *a, interpret: taken.append(interpret) or jnp.zeros(a[0].shape, jnp.float32),
+        lambda *a, interpret, window: taken.append(interpret) or jnp.zeros(a[0].shape, jnp.float32),
     )
     q, k_pool, v_pool, tables, positions = _pool_case(jnp.bfloat16, 2, 128, 16, [5, 20])
     args = (q, k_pool, v_pool, 0, jnp.asarray(tables), jnp.asarray(positions))
     pa.paged_decode_attention(*args, scale=1.0)  # CPU: the jnp form
     assert taken == []
-    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kernel_form, "on_tpu", lambda: True)
     pa.paged_decode_attention(*args, scale=1.0)
     assert taken == [False]
 

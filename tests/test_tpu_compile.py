@@ -16,7 +16,7 @@ be automatically partitioned", while every virtual-CPU-mesh test passed
 (the interpreter lowers the kernels to plain ops, which partition).
 
 Code that asks ``jax.default_backend()`` still sees the CPU here, so the
-kernels' ``_interpret`` is steered from the test (``real_kernels_no_cache``,
+kernels' one switch (``ops/kernel_form.py on_tpu``) is steered from the test (``real_kernels_no_cache``,
 with the described devices in tests/model_cases.py).  The serving cells'
 programs at their published widths are tests/test_tpu_compile_cells.py.
 """
@@ -36,6 +36,7 @@ from tests.model_cases import (  # noqa: F401  (fixture reuse)
     adamw_mod,
     compile_text as _compile,
     flash_mod,
+    form_mod,
     grouped_mod,
     mosaic_calls as _kernels,
     paged_mod,
@@ -389,7 +390,7 @@ def test_decode_step_holds_no_copy_of_a_layers_pool(tpu_devices, monkeypatch, fo
     from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
     from determined_tpu.serve.config import ServeConfig
 
-    monkeypatch.setattr(paged_mod, "_on_tpu", lambda: form == "kernel")
+    monkeypatch.setattr(form_mod, "on_tpu", lambda: form == "kernel")
     one = SingleDeviceSharding(tpu_devices[0])
     # a pool much larger than anything else in the program (weights,
     # logits), so that size alone tells a copy of it from other work

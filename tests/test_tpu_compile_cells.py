@@ -75,7 +75,7 @@ def _walk_compiled(one, cfg, *, num_blocks, max_prompt_len, table_width):
     return fn.lower(params, aval((1, max_prompt_len)), aval((1,)), aval((1,)), aval((1, table_width)), cache).compile()
 
 
-def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(tpu_devices, monkeypatch):
+def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(tpu_devices):
     """The walk at DeepSeek-V3's published widths, the cell's pool, table and
     ``max_prompt_len`` 4,096, depth cut to the dense layer and one expert
     layer: its scratch is a fraction of the wide pass's (1.32 GiB at this
@@ -85,7 +85,6 @@ def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(
     the largest the attention builds."""
     from determined_tpu.models.transformer import TransformerConfig
 
-    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
     cfg = TransformerConfig(
         vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
         q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
@@ -176,7 +175,7 @@ def test_the_latent_decode_kernel_compiles_at_the_dsv3_cells_shape(tpu_devices):
     assert scratch == {"vmem": 2 * 512 * 640 * 2 + 128 * 512 * 4, "smem": 8} and scratch["vmem"] <= paged_mod.TILE_BUFFER_BYTES
 
 
-def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, monkeypatch):
+def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices):
     """The decode program of the cell at its widths, lanes and pool, bfloat16
     leaves, depth cut to the dense layer and one expert layer: the latent
     kernel a layer, and in the expert layer the two row movements and three
@@ -189,7 +188,6 @@ def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices, mo
     from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape
     from determined_tpu.utils.compilation_cache import program_scopes
 
-    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
     one = SingleDeviceSharding(tpu_devices[0])
     cfg = TransformerConfig(
         vocab_size=16160, d_model=7168, n_layers=2, n_heads=128, d_ff=18432, max_seq_len=7168,
@@ -233,7 +231,7 @@ def _longcat_cfg(n_layers: int):
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, monkeypatch, which):
+def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, which):
     """The LongCat-Flash-Omni cell's programs at its widths, lanes, pool (10,240
     blocks) and table (320 columns: 5,120 positions), bfloat16 leaves, depth cut
     to ONE double layer: two rows of the latent pool and two latent kernels a
@@ -246,7 +244,6 @@ def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, m
     from determined_tpu.models.transformer import TransformerLM, kv_cache_shape
     from determined_tpu.utils.compilation_cache import program_scopes
 
-    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
     one = SingleDeviceSharding(tpu_devices[0])
     cfg = _longcat_cfg(1)
     assert kv_cache_shape(cfg, 10240, 16) == (2, 10240, 16, 640)
@@ -325,7 +322,7 @@ def test_the_decode_kernel_compiles_in_both_layouts_of_its_products(tpu_devices,
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_devices, monkeypatch, which):
+def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_devices, which):
     """The cell's decode step and prefill walk at its widths, lanes, pool and
     window store, bfloat16 leaves, depth cut to one window layer and the full
     layer: weights, both kinds of cache and the program's scratch fit the chip;
@@ -346,7 +343,6 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
     )
     from determined_tpu.utils.compilation_cache import program_scopes
 
-    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
     one = SingleDeviceSharding(tpu_devices[0])
     cfg = TransformerConfig(
         vocab_size=32768, d_model=4096, n_layers=2, n_heads=128, n_kv_heads=8, head_dim=128, max_seq_len=20480,
@@ -582,7 +578,7 @@ def test_the_ssm_decode_kernel_compiles_at_the_nemotron_cells_shape(tpu_devices)
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_the_nemotron_cells_programs_compile_over_layers_of_one_mixer_each(tpu_devices, monkeypatch, which):
+def test_the_nemotron_cells_programs_compile_over_layers_of_one_mixer_each(tpu_devices, which):
     """The cell's decode step and prefill walk at its widths, lanes, pool and
     state pool, bfloat16 leaves, all eleven layers (five Mamba-2, five expert,
     one attention): the weights, both pools and the program's scratch fit the
@@ -598,7 +594,6 @@ def test_the_nemotron_cells_programs_compile_over_layers_of_one_mixer_each(tpu_d
     from determined_tpu.models.transformer import TransformerConfig, TransformerLM, kv_cache_shape, ssm_pool_shapes
     from determined_tpu.utils.compilation_cache import program_scopes
 
-    monkeypatch.setattr(rows_mod.gm, "_interpret", lambda: False)
     one = SingleDeviceSharding(tpu_devices[0])
     letters = {"M": "mamba2", "*": "full_attention", "E": "experts"}
     cfg = TransformerConfig(
