@@ -229,46 +229,105 @@ class SortedRows(NamedTuple):
     layout: Any            # gm.TileLayout
 
 
+def _owners_by_tile(place: jax.Array, flat: jax.Array, layout: Any) -> jax.Array:
+    """``[tiles, tile]``: the flat pick that owns each row PLUS ONE, 0 where none
+    does, a TILE at a time: a tile is one expert's, so its rows are compared with
+    that expert's column of ``place [T, count]`` (a token's row on each expert,
+    -1: not picked; ``flat``: its flat pick there): ``rows x T`` compares."""
+    tile_place = jnp.take(place.T, layout.tile_group, axis=0)          # [tiles, T] whole rows, one a tile
+    tile_flat = jnp.take(flat.T, layout.tile_group, axis=0)
+    row = jnp.arange(layout.rows).reshape(-1, layout.tile)
+    owns = tile_place[:, None, :] == row[:, :, None]                   # [tiles, tile, T] at most one token a row
+    return jnp.sum(jnp.where(owns, tile_flat[:, None, :] + 1, 0), axis=-1)
+
+
+def _owners_by_block(place: jax.Array, flat: jax.Array, before: jax.Array, layout: Any) -> jax.Array:
+    """The same a BLOCK of ``tile`` tokens at a time, for few experts and many
+    tokens: a block's picks on one expert are at most ``tile`` consecutive rows,
+    which lie in TWO tiles, the first of them known from ``before [T, count]``
+    (an expert's picks before a token).  So a (block, expert) pair compares its
+    tokens with two tiles' rows (``T x count x 2 tile`` in all), and a tile
+    collects the pairs that name it (``tiles x pairs x 2 tile``: a row is
+    written by one pair at most)."""
+    tokens, count = place.shape
+    tile, tiles = layout.tile, layout.rows // layout.tile
+    blocks = -(-tokens // tile)
+    pad = ((0, blocks * tile - tokens), (0, 0))
+    by_block = lambda a: jnp.pad(a, pad, constant_values=-1).T.reshape(count, blocks, tile)          # noqa: E731
+    first_tile = (layout.group_start[:, None] + jax.lax.slice(before, (0, 0), before.shape, (tile, 1)).T) // tile   # [count, blocks]
+    at = by_block(place) - (first_tile * tile)[:, :, None]             # a token's row in its pair's two tiles; < 0: no pick
+    owns = at[:, :, None, :] == jnp.arange(2 * tile)[None, None, :, None]                            # [count, blocks, 2 tile, tile]
+    pair_pick = jnp.sum(jnp.where(owns, by_block(flat)[:, :, None, :] + 1, 0), axis=-1).reshape(-1, tile)
+    pair_tile = (first_tile[:, :, None] + jnp.arange(2)).reshape(-1)                                 # [pairs x 2]
+    named = pair_tile[None, :] == jnp.arange(tiles)[:, None]                                         # [tiles, pairs x 2]
+    return jnp.sum(jnp.where(named[:, :, None], pair_pick[None], 0), axis=1)
+
+
 def _sorted_rows(picks: jax.Array, first: Any, count: int, *, serving: bool = False) -> SortedRows:
     """``picks [T, k]`` (experts, distinct a token) -> the held ones sorted by
     expert into a tile-aligned buffer sized for the worst case.  ``serving``
     (no backward pass): an expert without rows owns no tile, so its matrices
-    are not read, and a tile is at least the 16 rows a bf16 sublane tile packs."""
+    are not read, and a tile is at least the 16 rows a bf16 sublane tile packs.
+
+    Counted, not sorted: a token picks an expert at most once, so an expert's
+    rows are its tokens in token order and a pick's place among them is a
+    running count (``[T, count]``); which pick owns a row is found by comparing
+    rows with those counts, a tile or a block of tokens at a time, whichever
+    the static shapes make fewer compares (many experts and few tokens, a
+    serving step: by tile; few experts and many tokens, a training step: by
+    block).  No sort, and no gather with one index a pick or a row.  A row
+    that no pick owns holds ``row_pick`` 0."""
     from determined_tpu.ops import grouped_matmul as gm
 
     tokens, k = picks.shape
     local = picks - first
     pick_held = (local >= 0) & (local < count)
-    key = jnp.where(pick_held, local, count).reshape(-1)               # [T*k]
-    # held picks first, by expert; gathers both ways, no scatter
-    order = jnp.argsort(key, stable=True)                              # sorted place -> pick
-    place = jnp.argsort(order)                                         # pick -> sorted place
-    load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    on = local[:, :, None] == jnp.arange(count)                        # [T, k, count] a pick's held expert (none: not held)
+    hit = jnp.any(on, axis=1)                                          # [T, count] whether the token picked it
+    upto = jnp.cumsum(hit, axis=0, dtype=jnp.int32)                    # its picks up to and with this token's
+    load = upto[-1]
     # a token picks a held expert at most min(k, count) times
     max_rows = tokens * min(k, count)
     tile = min(gm.DEFAULT_TILE, max(16 if serving else 8, _round_up_pow2(max_rows // count)))
     layout = gm.tile_layout(load, max_rows, tile, empty_groups_own_tile=not serving)
-    sorted_start = jnp.cumsum(load) - load                             # [count]
-    group = jnp.minimum(key, count - 1)
+    place = jnp.where(hit, layout.group_start[None, :] + upto - 1, -1)  # [T, count] the row of the token's pick on an expert
     pick_row = jnp.where(
-        key < count,
-        jnp.take(layout.group_start, group) + place - jnp.take(sorted_start, group),
-        layout.rows - 1,                                               # not held: never read
-    ).astype(jnp.int32).reshape(tokens, k)
-    row = jnp.arange(layout.rows)
-    group = jnp.take(layout.tile_group, row // tile)
-    offset = row - jnp.take(layout.group_start, group)
-    row_live = gm.live_rows_mask(layout) & (offset < jnp.take(load, group))
-    row_pick = jnp.take(
-        order, jnp.clip(jnp.take(sorted_start, group) + offset, 0, tokens * k - 1)
+        pick_held, jnp.sum(jnp.where(on, place[:, None, :], 0), axis=-1), layout.rows - 1   # not held: never read
     ).astype(jnp.int32)
-    tile_rows = jnp.sum(row_live.reshape(-1, tile), axis=1, dtype=jnp.int32)
-    return SortedRows(pick_held, pick_row, row_live, row_pick, tile_rows, load, layout)
+    flat = jnp.arange(tokens)[:, None] * k + jnp.sum(jnp.where(on, jnp.arange(k)[None, :, None], 0), axis=1)
+    pairs = -(-tokens // tile) * count
+    if pairs * 2 * tile * (tile + layout.rows // tile) < layout.rows * tokens:
+        owner = _owners_by_block(place, flat, upto - hit, layout)
+    else:
+        owner = _owners_by_tile(place, flat, layout)
+    row_live = owner > 0
+    row_pick = jnp.maximum(owner - 1, 0).astype(jnp.int32)
+    tile_rows = jnp.sum(row_live, axis=1, dtype=jnp.int32)
+    return SortedRows(pick_held, pick_row, row_live.reshape(-1), row_pick.reshape(-1), tile_rows, load, layout)
 
 
-def _row_weights(weights: jax.Array, row_pick: jax.Array, row_live: jax.Array) -> jax.Array:
-    """The routing weight ``[T, k]`` of each row's pick, zero for rows no pick owns."""
-    return jnp.where(row_live, jnp.take(weights.reshape(-1), row_pick), 0.0)
+#: tokens up to which a row's weight is found by comparing the row's owner with every token: one scalar gather a
+#: row costs a v5e ~7 ns whatever the sizes, a compare ~1 ps, so the two meet near 7,000 tokens (PERF.md, PR 60)
+_COMPARE_TOKENS = 4096
+
+
+def _row_weights(
+    weights: jax.Array, row_pick: jax.Array, row_live: jax.Array, pick_row: jax.Array, pick_held: jax.Array, tile: int
+) -> jax.Array:
+    """The routing weight ``[T, k]`` of each row's pick, zero for rows no pick
+    owns.  By compares, as the layout, while the tokens are few (a serving
+    step): a tile is one expert's and a token picks it once, so a token brings
+    ONE weight into a tile (``[tiles, T]``), and a row takes its owner's.
+    Past ``_COMPARE_TOKENS`` (a training step) by one gather a row."""
+    tokens, k = weights.shape
+    if tokens > _COMPARE_TOKENS:
+        return jnp.where(row_live, jnp.take(weights.reshape(-1), row_pick), 0.0)
+    tiles = row_pick.shape[0] // tile
+    here = pick_held[None] & ((pick_row // tile)[None] == jnp.arange(tiles)[:, None, None])   # [tiles, T, k]
+    brought = jnp.sum(jnp.where(here, weights[None], 0.0), axis=-1)                           # [tiles, T]
+    owner = jnp.where(row_live, row_pick // k, -1).reshape(tiles, tile)
+    mine = owner[:, :, None] == jnp.arange(tokens)                                            # [tiles, tile, T]
+    return jnp.sum(jnp.where(mine, brought[:, None, :], 0.0), axis=-1).reshape(-1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(13, 14))
@@ -320,7 +379,7 @@ def _held_experts_fwd(
     row_token = row_pick // weights.shape[1]
     with jax.named_scope("moe.dispatch"):
         xr = expert_rows.rows_of_tokens(x, row_token, tile_rows, layout)
-        scale = _row_weights(weights, row_pick, row_live)
+        scale = _row_weights(weights, row_pick, row_live, pick_row, pick_held, tile)
     with jax.named_scope("moe.experts"):
         out, gate, up = _expert_products(lambda lhs, rhs: gm.gmm(lhs, rhs, layout), xr, w_gate, w_up, w_down, scale)
     with jax.named_scope("moe.combine"):
@@ -345,7 +404,7 @@ def _held_experts_bwd(rows, tile, res, d_y):
         d_out = expert_rows.rows_of_tokens(d_y.astype(dt), row_token, tile_rows, layout)
     with jax.named_scope("moe.dispatch"):
         xr = expert_rows.rows_of_tokens(x, row_token, tile_rows, layout)
-        scale = _row_weights(weights, row_pick, row_live)
+        scale = _row_weights(weights, row_pick, row_live, pick_row, pick_held, tile)
     with jax.named_scope("moe.experts"):
         act, hidden = _hidden(gate, up, scale)
         d_w_down = gm.tgmm(hidden, d_out, layout, count).astype(w_down.dtype)
@@ -744,7 +803,7 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
         layout = rows.layout
         row_token = rows.row_pick // weights.shape[1]
         xr = expert_rows.rows_of_tokens(xe, row_token, rows.tile_rows, layout)
-        scale = _row_weights(weights, rows.row_pick, rows.row_live)
+        scale = _row_weights(weights, rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, layout.tile)
         out, _, _ = _expert_products(lambda lhs, rhs: _gmm(lhs, rhs, *layout), xr, p.get("w_gate"), p["w_up"], p["w_down"], scale)
         y = expert_rows.tokens_of_rows(out, row_token, rows.tile_rows, layout, xf.shape[0])
     if cfg.moe_latent_size:

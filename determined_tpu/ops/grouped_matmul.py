@@ -83,9 +83,12 @@ def tile_layout(
         tiles = jnp.where((jnp.arange(groups) == 0) & (jnp.sum(tiles) == 0), 1, tiles)
     ends = jnp.cumsum(tiles)
     live = ends[-1]
-    tile_group = jnp.searchsorted(ends, jnp.arange(rows // tile), side="right")
+    index = jnp.arange(rows // tile)
+    # a tile's group: the groups that end at or before it, counted (no search, no gather)
+    tile_group = jnp.sum(ends[None, :] <= index[:, None], axis=1)
     if not empty_groups_own_tile:
-        tile_group = jnp.where(jnp.arange(rows // tile) < live, tile_group, tile_group[live - 1])
+        # the last live tile's group is the last group that owns a tile
+        tile_group = jnp.where(index < live, tile_group, jnp.max(jnp.where(tiles > 0, jnp.arange(groups), 0)))
     return TileLayout(
         group_start=((ends - tiles) * tile).astype(jnp.int32),
         tile_group=jnp.minimum(tile_group, groups - 1).astype(jnp.int32),

@@ -62,6 +62,65 @@ def routed_layer(held=None, experts=8, k=3, **kw):
     return RoutedExperts(num_experts=experts, top_k=k, d_ff=12, held=held, dtype=jnp.float32, partition=False, **kw)
 
 
+def sorted_rows_by_argsort(picks, first, count, *, serving=False):
+    """The layout ``models/moe.py _sorted_rows`` computed until PR 60, kept as the plain reference of the one it
+    computes by counting: two stable ``argsort``s over the ``T * k`` picks, ``searchsorted`` for a tile's group and
+    one scalar gather a row of the worst-case buffer.  Rows that no pick owns hold whatever the clipped gathers
+    find: compare ``row_pick`` where ``row_live``."""
+    from determined_tpu.models.moe import SortedRows, _round_up_pow2
+    from determined_tpu.ops import grouped_matmul as gm
+
+    tokens, k = picks.shape
+    local = picks - first
+    pick_held = (local >= 0) & (local < count)
+    key = jnp.where(pick_held, local, count).reshape(-1)               # [T*k]
+    order = jnp.argsort(key, stable=True)                              # sorted place -> pick
+    place = jnp.argsort(order)                                         # pick -> sorted place
+    load = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    max_rows = tokens * min(k, count)
+    tile = min(gm.DEFAULT_TILE, max(16 if serving else 8, _round_up_pow2(max_rows // count)))
+    groups = load.shape[0]
+    rows = gm.buffer_rows(max_rows, groups, tile)
+    if serving:  # an expert without rows owns no tile; one tile stays live whatever the sizes
+        tiles = -(-load // tile)
+        tiles = jnp.where((jnp.arange(groups) == 0) & (jnp.sum(tiles) == 0), 1, tiles)
+    else:
+        tiles = jnp.maximum(-(-load // tile), 1)
+    ends = jnp.cumsum(tiles)
+    live = ends[-1]
+    tile_group = jnp.searchsorted(ends, jnp.arange(rows // tile), side="right")
+    if serving:
+        tile_group = jnp.where(jnp.arange(rows // tile) < live, tile_group, tile_group[live - 1])
+    layout = gm.TileLayout(
+        group_start=((ends - tiles) * tile).astype(jnp.int32),
+        tile_group=jnp.minimum(tile_group, groups - 1).astype(jnp.int32),
+        live_tiles=live.astype(jnp.int32)[None],
+        rows=rows,
+        tile=tile,
+    )
+    sorted_start = jnp.cumsum(load) - load                             # [count]
+    group = jnp.minimum(key, count - 1)
+    pick_row = jnp.where(
+        key < count,
+        jnp.take(layout.group_start, group) + place - jnp.take(sorted_start, group),
+        layout.rows - 1,                                               # not held: never read
+    ).astype(jnp.int32).reshape(tokens, k)
+    row = jnp.arange(layout.rows)
+    group = jnp.take(layout.tile_group, row // tile)
+    offset = row - jnp.take(layout.group_start, group)
+    row_live = gm.live_rows_mask(layout) & (offset < jnp.take(load, group))
+    row_pick = jnp.take(
+        order, jnp.clip(jnp.take(sorted_start, group) + offset, 0, tokens * k - 1)
+    ).astype(jnp.int32)
+    tile_rows = jnp.sum(row_live.reshape(-1, tile), axis=1, dtype=jnp.int32)
+    return SortedRows(pick_held, pick_row, row_live, row_pick, tile_rows, load, layout)
+
+
+def row_weights_by_gather(weights, row_pick, row_live):
+    """``models/moe.py _row_weights`` as one scalar gather a row: its plain reference."""
+    return jnp.where(row_live, jnp.take(weights.reshape(-1), row_pick), 0.0)
+
+
 # -- power retention (tests/test_retention_serving.py, tests/test_retention_chunk_kernel.py) --
 
 

@@ -11,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from determined_tpu.models import moe
 from determined_tpu.models.moe import RoutedExperts, _sorted_rows
 from determined_tpu.ops import expert_rows, grouped_matmul as gm
-from tests.model_cases import dense_experts as _dense_experts, routed_layer as _layer
+from tests.model_cases import dense_experts as _dense_experts, routed_layer as _layer, sorted_rows_by_argsort
 
 
 # ---------------------------------------------------------------------------
@@ -75,6 +76,82 @@ def _sorted(case):
     if case == "a token with no held pick":
         assert not held[::3].any()
     return picks, rows
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+#: the shapes the cells run, at fewer tokens but their own k and count (and so their own choice between the layout's
+#: two passes): (tokens, the router's outputs, k, first, count, tokens the serving forward masks as not live)
+CELL_SHAPES = {
+    "k 22 of 512, 128 held (Nemotron-3)": (24, 512, 22, 128, 128, 0),
+    "k 12 of 96 and identity picks, 32 held, idle lanes (LongCat)": (20, 96, 12, 32, 32, 5),
+    "k 8 of 64, 16 held (Mellum2)": (1024, 64, 8, 16, 16, 0),
+    "k 1 of 16, 8 held (ZAYA1)": (3072, 16, 1, 0, 8, 0),
+}
+
+
+def _cell_picks(shape):
+    tokens, outputs, k, first, count, idle = CELL_SHAPES[shape]
+    rng = np.random.default_rng(11)
+    picks = np.argsort(-rng.random((tokens, outputs)), axis=1)[:, :k].astype(np.int32)
+    if "LongCat" in shape:
+        assert (picks >= 64).any()                      # outputs 64.. are identity experts: never held
+        picks[rng.choice(tokens, idle, replace=False)] = outputs   # what `live` makes of an idle lane's picks
+    return picks, first, count
+
+
+@pytest.mark.parametrize("serving", [False, True], ids=["training", "serving"])
+@pytest.mark.parametrize("case", CASES + list(CELL_SHAPES))
+def test_the_counted_layout_is_the_sorted_one_value_for_value(case, serving):
+    """``_sorted_rows`` (no sort, no gather a row) against the argsort form it
+    replaced (``tests/model_cases.py``): every field on every pick, tile and
+    expert, and ``row_pick`` on every row a pick owns (the others are masked
+    by ``row_live`` or lie past a tile's ``tile_rows``: any in-range pick)."""
+    picks, first, count = _cell_picks(case) if case in CELL_SHAPES else _picks(case)
+    got = jax.jit(lambda p: _sorted_rows(p, first, count, serving=serving))(jnp.asarray(picks))
+    want = jax.jit(lambda p: sorted_rows_by_argsort(p, first, count, serving=serving))(jnp.asarray(picks))
+    for field in ("pick_held", "pick_row", "row_live", "tile_rows", "load"):
+        np.testing.assert_array_equal(getattr(got, field), getattr(want, field), err_msg=field)
+        assert getattr(got, field).dtype == getattr(want, field).dtype, field
+    for field in ("group_start", "tile_group", "live_tiles"):
+        np.testing.assert_array_equal(getattr(got.layout, field), getattr(want.layout, field), err_msg=field)
+        assert getattr(got.layout, field).dtype == jnp.int32
+    assert (got.layout.rows, got.layout.tile) == (want.layout.rows, want.layout.tile)
+    live = np.asarray(want.row_live)
+    np.testing.assert_array_equal(np.asarray(got.row_pick)[live], np.asarray(want.row_pick)[live])
+    assert got.row_pick.dtype == jnp.int32 and 0 <= int(got.row_pick.min()) and int(got.row_pick.max()) < picks.size
+    held = (picks >= first) & (picks < first + count)
+    assert int(got.load.sum()) == held.sum() and (case not in CELL_SHAPES or held.sum() > 0)
+
+
+@pytest.mark.parametrize("cell, tokens, k, count, serving, rows, by", [
+    ("Nemotron-3 decode", 64, 22, 128, True, 3456, "tile"), ("Nemotron-3 walk", 256, 22, 128, True, 13824, "tile"),
+    ("LongCat decode", 64, 12, 32, True, 1792, "tile"), ("DSV3 decode", 64, 8, 16, True, 1024, "tile"),
+    ("Mellum2 step", 8192, 8, 16, False, 69632, "block"), ("ZAYA1 step", 24576, 1, 8, False, 26624, "block"),
+])
+def test_the_pass_the_layout_takes_at_a_cells_own_shape(cell, tokens, k, count, serving, rows, by, monkeypatch):
+    """Traced at the cells' real shapes, nothing computed: many experts and few
+    tokens compare a tile's rows with its expert's tokens, few experts and many
+    tokens a block's tokens with two tiles' rows (PERF.md section 5 "PR 60" has
+    both passes' times at these shapes), and neither sorts or gathers a row."""
+    took = []
+    for name in ("tile", "block"):
+        fn = getattr(moe, f"_owners_by_{name}")
+        monkeypatch.setattr(moe, f"_owners_by_{name}", lambda *a, _fn=fn, _name=name: (took.append(_name), _fn(*a))[1])
+    jaxpr = jax.make_jaxpr(lambda p: _sorted_rows(p, 0, count, serving=serving))(jax.ShapeDtypeStruct((tokens, k), jnp.int32))
+    assert took == [by] and jaxpr.out_avals[3].shape == (rows,)
+    text = str(jaxpr)
+    assert " sort[" not in text and "argsort" not in text and "while[" not in text
+    # no gather with an index a row or a pick: by tile, two of WHOLE rows ``[T]``, one a tile; by block, none that wide
+    tiles = jaxpr.out_avals[4].shape[0]
+    wide = [e.outvars[0].aval.shape for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "gather" and e.outvars[0].aval.size > tiles]
+    assert wide == ([(tiles, tokens)] * 2 if by == "tile" else [])
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -148,10 +225,8 @@ def test_the_layer_and_its_gradients_match_a_dense_loop_whatever_the_picks(case)
 
 def _avals(jaxpr):
     """Every array a (closed) jaxpr holds, those of its nested jaxprs too."""
-    for eqn in jaxpr.eqns:
+    for eqn in _eqns(jaxpr):
         yield from (v.aval for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape"))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _avals(sub)
 
 
 def test_no_array_of_tokens_times_k_rows_exists_in_the_layer_or_its_gradient():

@@ -11,7 +11,11 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from determined_tpu.ops import grouped_matmul as gm
-from tests.model_cases import dense_experts as _dense_experts, routed_layer as _layer
+from determined_tpu.models import moe
+from determined_tpu.models.transformer import TransformerConfig
+from tests.model_cases import (
+    dense_experts as _dense_experts, routed_layer as _layer, row_weights_by_gather, sorted_rows_by_argsort,
+)
 
 # ---------------------------------------------------------------------------
 # the grouped product
@@ -94,6 +98,105 @@ def test_routed_experts_and_their_gradients_match_a_dense_loop(held):
     got = jax.grad(scalar(lambda p, x: layer.apply({"params": p}, x)), (0, 1))(params, x)
     ref = jax.grad(scalar(lambda p, x: _dense_experts(x.reshape(-1, 16), p, 3, first, count)), (0, 1))(params, x)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b.reshape(a.shape), atol=2e-6), got, ref)
+
+
+#: (tokens, the router's width, k, first, count, a gated expert?, the pass the layout takes at that shape)
+LAYOUTS = {
+    "k 8 of 64, 16 held": (96, 64, 8, 16, 16, True, "tile"),
+    "k 4 of 16, 8 held, two matrices": (40, 16, 4, 4, 8, False, "tile"),
+    "k 1 of 4, 2 held": (1024, 4, 1, 1, 2, True, "block"),
+    "k 2 of 8, 4 held, two matrices": (1024, 8, 2, 2, 4, False, "block"),
+}
+
+
+@pytest.mark.parametrize("case", list(LAYOUTS))
+def test_the_held_experts_and_their_gradients_are_the_same_bits_under_the_counted_layout(case, monkeypatch):
+    """``_held_experts`` forward and backward through ``_sorted_rows`` against
+    the same through the argsort form it replaced (``tests/model_cases.py``):
+    the layout is the same integers, so float32 outputs and gradients are the
+    same BITS; and ``_row_weights`` (compares, or one gather past
+    ``_COMPARE_TOKENS``) against its plain gather."""
+    tokens, outputs, k, first, count, gated, by = LAYOUTS[case]
+    took = []
+    for name in ("tile", "block"):
+        fn = getattr(moe, f"_owners_by_{name}")
+        monkeypatch.setattr(moe, f"_owners_by_{name}", lambda *a, _fn=fn, _name=name: (took.append(_name), _fn(*a))[1])
+    d, d_ff = 16, 12
+    keys = jax.random.split(jax.random.key(3), 7)
+    picks = jnp.argsort(-jax.random.uniform(keys[0], (tokens, outputs)), axis=1)[:, :k].astype(jnp.int32)
+    weights = jax.random.uniform(keys[1], (tokens, k), jnp.float32, 0.1, 1.0)
+    x = jax.random.normal(keys[2], (tokens, d))
+    w_gate = jax.random.normal(keys[3], (count, d, d_ff)) * 0.3 if gated else None
+    w_up, w_down = jax.random.normal(keys[4], (count, d, d_ff)) * 0.3, jax.random.normal(keys[5], (count, d_ff, d)) * 0.3
+    cot = jax.random.normal(keys[6], (tokens, d))
+
+    def run(layout_of):
+        rows = layout_of(picks, first, count)
+
+        def out(x, w_gate, w_up, w_down, weights):
+            return moe._held_experts(
+                x, w_gate, w_up, w_down, weights,
+                rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, rows.tile_rows, *rows.layout,
+            )
+
+        y, vjp = jax.vjp(out, x, w_gate, w_up, w_down, weights)
+        return rows, y, vjp(cot)
+
+    rows, y, grads = jax.jit(lambda: run(moe._sorted_rows))()
+    want_rows, want_y, want_grads = jax.jit(lambda: run(sorted_rows_by_argsort))()
+    assert took == [by] and int(rows.load.sum()) > 0 and float(jnp.abs(y).max()) > 0
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+    for name, got, want in zip(("x", "w_gate", "w_up", "w_down", "weights"), grads, want_grads):
+        if got is not None:
+            assert float(jnp.abs(want).max()) > 0, name
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
+    scale = moe._row_weights(weights, rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, rows.layout.tile)
+    np.testing.assert_array_equal(np.asarray(scale), np.asarray(row_weights_by_gather(weights, want_rows.row_pick, want_rows.row_live)))
+
+
+def test_the_rows_weights_are_the_gathers_bits_on_either_side_of_the_line(monkeypatch):
+    """``_row_weights`` takes its compares up to ``_COMPARE_TOKENS`` tokens and
+    one gather a row past them: both sides of the line at one shape."""
+    tokens, k, first, count = 300, 3, 0, 6
+    picks = jnp.argsort(-jax.random.uniform(jax.random.key(0), (tokens, 8)), axis=1)[:, :k].astype(jnp.int32)
+    weights = jax.random.uniform(jax.random.key(1), (tokens, k), jnp.float32, 0.1, 1.0)
+    rows = moe._sorted_rows(picks, first, count)
+    told = (weights, rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, rows.layout.tile)
+    want = row_weights_by_gather(weights, rows.row_pick, rows.row_live)
+    assert tokens <= moe._COMPARE_TOKENS and "gather" not in str(jax.make_jaxpr(lambda: moe._row_weights(*told))())
+    np.testing.assert_array_equal(np.asarray(moe._row_weights(*told)), np.asarray(want))
+    monkeypatch.setattr(moe, "_COMPARE_TOKENS", tokens - 1)
+    assert "gather" in str(jax.make_jaxpr(lambda: moe._row_weights(*told))())
+    np.testing.assert_array_equal(np.asarray(moe._row_weights(*told)), np.asarray(want))
+
+
+@pytest.mark.parametrize("idle", [False, True], ids=["every lane live", "idle lanes"])
+def test_the_serving_experts_at_k_22_in_a_latent_are_the_same_bits_under_the_counted_layout(idle, monkeypatch):
+    """``serve_routed_experts`` at Nemotron-3's form (top-22 of 64 sigmoid-routed
+    two-matrix experts in a latent, 32 held, a shared expert: tests/test_mixer_block.py's
+    sizes at the cell's k) through the counted layout and through the argsort form."""
+    cfg = TransformerConfig(
+        vocab_size=96, d_model=64, n_layers=1, n_heads=4, max_seq_len=64, dtype=jnp.float32, partition_params=False,
+        moe_experts=64, moe_top_k=22, moe_intermediate_size=24, moe_experts_held=(16, 32), moe_router="sigmoid_grouped",
+        moe_routed_scaling=5.0, moe_shared_experts=1, moe_shared_intermediate_size=40, moe_expert_act="relu2", moe_latent_size=32,
+    )
+    layer = moe.RoutedExperts(
+        num_experts=64, top_k=22, d_ff=24, held=(16, 32), dtype=jnp.float32, partition=False, router_kind="sigmoid_grouped",
+        routed_scaling=5.0, shared_experts=1, shared_d_ff=40, expert_act="relu2", latent_size=32,
+    )
+    x = jax.random.normal(jax.random.key(5), (4, 10, 64), jnp.float32)
+    p = jax.jit(layer.init)(jax.random.key(1), x)["params"]
+    live = jnp.ones((4, 10), bool).at[1].set(False).at[3, 4:].set(False) if idle else None
+    got, counted = jax.jit(lambda p, x: moe.serve_routed_experts(cfg, p, x, live))(p, x)
+    monkeypatch.setattr(moe, "_sorted_rows", sorted_rows_by_argsort)
+    want, want_counted = jax.jit(lambda p, x: moe.serve_routed_experts(cfg, p, x, live))(p, x)
+    assert int(counted[0]) == int(want_counted[0]) > 0 and int(counted[1]) == int(want_counted[1])
+    assert float(jnp.abs(want).max()) > 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the training layer states the same experts: same params, same tokens, close to the serving forward
+    if not idle:
+        trained = jax.jit(lambda p, x: layer.apply({"params": p}, x)[0])(p, x)
+        np.testing.assert_allclose(np.asarray(trained), np.asarray(got), rtol=2e-5, atol=2e-6)
 
 
 def test_the_four_shares_add_up_to_the_whole_layer_in_a_loop_and_under_shard_map():
