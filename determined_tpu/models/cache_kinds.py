@@ -4,6 +4,8 @@ cache kinds under the serving forward (``models/serving.py``).
 A kind is what a layer keeps and how it is addressed, ONE record
 (:class:`CacheKind`) in :data:`CACHE_KINDS`: ``paged_kv`` (K and V rows a token
 in the paged pool), ``paged_latent`` (one latent row a token in the pool),
+``paged_indexed`` (a latent row a token and, in the layers that hold an indexer,
+an index key a token beside it: attention reads the rows the indexer picks),
 ``state_slot`` (a float32 state a decode lane), ``window_ring`` (K and V rows
 of the newest tokens in a ring a decode lane) and ``ssm_slot`` (a Mamba-2
 mixer's float32 state and its convolution's tail a decode lane);
@@ -22,7 +24,10 @@ A mixer is ``mix(p, x, h, cache, j) -> (x, cache)``: ``p`` the leaves of the
 layer's subtree the kind names (``params``), ``x`` [b, s, d] the residual stream, ``h`` its norm, ``j`` the layer's row
 in the kind's arrays.  It projects, writes this call's rows (or folds them into
 the state), attends so that a token sees itself, and adds the output projection
-to the stream.  A kind builds one from the rows of a call (:class:`Rows`) in
+to the stream.  A kind whose layers hand something on beside the stream inside one call (``hands``: the picks of
+the newest layer that holds an indexer, which the layers after it attend over) has mixers of one more argument and
+one more result, ``mix(p, x, h, cache, j, handed) -> (x, cache, handed)``, ``handed`` None at the first layer.
+A kind builds one from the rows of a call (:class:`Rows`) in
 each form an entry point may pick: ``step`` (a decode step by the paged path),
 ``walk`` (a chunk of the prefill walk) and the tests' reference forms ``table``
 (a decode step over gathered rows) and ``wide`` (the whole prompt in one pass).
@@ -41,13 +46,15 @@ import jax.numpy as jnp
 
 from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
-    FULL, HYBRID, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _latent_attend_local, _latent_project, _rms, _rope,
+    FULL, HYBRID, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _index_project, _latent_attend_local, _latent_project,
+    _rms, _rope,
     _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, kv_bytes_per_token, kv_cache_shape, ssm_bytes_per_slot,
     recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
 )
 from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
 from determined_tpu.ops.paged_attention import (
-    COPY_SCHEDULE, attn_products, paged_chunk_attention, paged_decode_attention, paged_latent_attention,
+    COPY_SCHEDULE, attn_products, index_scores, index_topk, index_topk_mask, paged_chunk_attention, paged_decode_attention, paged_index_scores,
+    paged_latent_attention, paged_picked_attention,
 )
 from determined_tpu.ops.retention import FOLD_EVERY, retention_chunk, retention_decode
 from determined_tpu.ops.ssm import ssm_chunk, ssm_decode
@@ -264,7 +271,7 @@ def _kv_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges:
 def _latent_report(cfg: TransformerConfig, sizes: Any = None, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
     """How the latent decode kernel's tiles are copied (``COPY_SCHEDULE``), where a
     layer keeps latent rows; and ``rows_per_token``."""
-    return {**COPY_SCHEDULE, **_rows_report(cfg)} if cfg.latent and cfg.paged_layers else {}
+    return {**COPY_SCHEDULE, **_rows_report(cfg)} if cfg.latent and cfg.paged_layers and not cfg.indexer_types else {}
 
 
 def _ring_step(cfg: TransformerConfig, rows: Rows, cache: Dict[str, jax.Array], table: bool = False):
@@ -360,24 +367,82 @@ def _ring_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
 # same mathematics, and one row a token serves every head's scores and values).
 
 
-def _latent_mixer(cfg: TransformerConfig, rows: Rows, attend):
+def _latent_mixer(cfg: TransformerConfig, rows: Rows, attend, select=None):
     """Latent attention's projections, this call's rows ``[c_kv after its norm |
     k_r after rope | zeros]`` into the pool at ``rows.where``, ``attend``
-    against the pool that now holds them, and the output projection."""
-    (phys, slots), (leaf,) = rows.where, PAGED_LATENT.leaves
+    against the pool that now holds them, and the output projection.  Under
+    ``indexer_types`` (``select``: :func:`_indexer`) the mixer is of the kind
+    that hands on: a layer that holds an indexer first makes its own picks,
+    every layer attends over the picks it holds or was handed, and returns them."""
+    (phys, slots), leaf = rows.where, PAGED_LATENT.leaves[0]
     rope = cfg.rope(FULL)
 
-    def mix(p, x, h, cache, j):
+    def mix(p, x, h, cache, j, picks=None):
         with jax.named_scope("serve.mla"):
-            q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, h, rows.positions, rope)
+            q_nope, q_rope, c_kv, k_r, c_q = _latent_project(cfg, p, h, rows.positions, rope)
             with jax.named_scope("serve.kv.write"):
                 row = jnp.concatenate([c_kv, k_r], axis=-1)
                 row = jnp.pad(row, ((0, 0), (0, 0), (0, cache[leaf].shape[-1] - row.shape[-1])))
                 cache = {**cache, leaf: cache[leaf].at[j, phys, slots].set(row.reshape(*phys.shape, -1))}
-            att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, j)
-            return x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(cfg.dtype)), cache
+        row = cfg.index_layer(j)  # every layer keeps latent rows: row j of the pool is layer j's
+        if row is not None:
+            with jax.named_scope("serve.dsa"):
+                cache, picks = select(p, c_q, h, cache, row)
+        with jax.named_scope("serve.mla"):
+            att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, j, picks)
+            return x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(cfg.dtype)), cache, picks
 
-    return mix
+    return mix if select is not None else lambda p, x, h, cache, j: mix(p, x, h, cache, j)[:2]
+
+
+def _indexer(cfg: TransformerConfig, rows: Rows, score, keys: Optional[int] = None, as_mask: bool = True):
+    """The indexer of a layer that holds one, stated once under every form of
+    the serving forward: ``select(p, c_q, h, cache, r) -> (cache, picks)`` with
+    ``r`` the layer's row in the index array.  Its projections
+    (``_index_project``), this call's index keys into the array at
+    ``rows.where`` (where the tokens' latent rows went), ``score(q, w, k, keys
+    array, r)`` -> the float32 scores [b, s, keys] of this call's queries
+    against the lanes' keys (the form's own read: ``_index_paged``,
+    ``_index_gathered``, ``_index_local``), and the exact top ``index_topk``
+    among the ``keys`` positions of a lane's context (absent: the table's) that
+    a query may see, those up to its own: the picks are positions of the lane's
+    own context.  What a layer hands on is the picks themselves (a decode step's
+    paged form turns them into places in the pool) or, ``as_mask``, the same
+    selection as a mask ``[b, s, keys]``, made once for the layers that share it."""
+    (phys, slots), leaf = rows.where, PAGED_INDEXED.leaves[1]
+    rope = cfg.rope(FULL)
+    keys = keys or rows.block_tables.shape[1] * rows.block_size
+    with jax.named_scope("serve.dsa.topk"):
+        seen = jnp.arange(keys) <= rows.positions[..., None]  # [(b,) s, keys]
+        if rows.live.ndim == 1:  # a decode step's idle lanes see none
+            seen = seen & rows.live[:, None, None]
+
+    def select(p, c_q, h, cache, r):
+        with jax.named_scope("serve.dsa.project"):
+            q, w, k = _index_project(cfg, p, c_q, h, rows.positions, rope)
+        with jax.named_scope("serve.dsa.write"):
+            cache = {**cache, leaf: cache[leaf].at[r, phys, slots].set(k.reshape(*phys.shape, -1))}
+        with jax.named_scope("serve.dsa.index"):  # the score pass alone: what its roofline share times
+            scores = score(q, w, k, cache[leaf], r)
+        with jax.named_scope("serve.dsa.topk"):
+            return cache, (index_topk_mask if as_mask else index_topk)(scores, seen, cfg.index_topk)
+
+    return select
+
+
+def _index_paged(rows: Rows):
+    """One query a lane against the lane's live index keys, read where they lie (``ops/paged_attention.py``)."""
+    return lambda q, w, k, keys, r: paged_index_scores(q[:, 0], w[:, 0], keys, r, rows.block_tables, rows.lane_positions)[:, None]
+
+
+def _index_gathered(rows: Rows):
+    """This call's queries against every key of every table column, gathered: the walk's read, and the decode oracle's."""
+    return lambda q, w, k, keys, r: index_scores(q, w, keys[r, rows.block_tables].reshape(q.shape[0], -1, keys.shape[-1]))
+
+
+def _index_local(q, w, k, keys, r):
+    """Against this call's own keys: the wide prefill's prompts start at position 0."""
+    return index_scores(q, w, k)
 
 
 def _latent_split(cfg, wkv_b, q_nope):
@@ -388,17 +453,23 @@ def _latent_split(cfg, wkv_b, q_nope):
 
 def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positions: jax.Array):
     """One query a lane against the lane's live latent rows, read where they
-    lie in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle."""
+    lie in the pool (``ops/paged_attention.py``); ``positions`` [b], -1 = idle.
+    Under ``picks`` against the rows those name alone."""
     (leaf,) = PAGED_LATENT.leaves
 
-    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j, picks=None):
         q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
         q = jnp.concatenate([q_lat, q_rope], axis=-1)[:, :, 0]
         q = jnp.pad(q, ((0, 0), (0, 0), (0, cache[leaf].shape[-1] - q.shape[-1])))
-        with jax.named_scope("serve.mla.attend"):  # the kernel alone: what its roofline share times
-            out = paged_latent_attention(
-                q, cache[leaf], j, block_tables, positions, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+        if picks is not None:  # under the op's own scopes: serve.mla.gather, serve.mla.attend
+            out = paged_picked_attention(
+                q, cache[leaf], j, block_tables, picks[0][:, 0], picks[1][:, 0], scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
             )
+        else:
+            with jax.named_scope("serve.mla.attend"):  # the kernel alone: what its roofline share times
+                out = paged_latent_attention(
+                    q, cache[leaf], j, block_tables, positions, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+                )
         return jnp.einsum("bhc,chv->bhv", out.astype(cfg.dtype), w_v)[:, None]
 
     return attend
@@ -407,16 +478,17 @@ def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positi
 def _latent_attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
     """The prefill walk's read (``_attend_chunk``) in the latent space: every
     head's queries ``[q_lat | q_rope | zeros]`` against the pool's rows, whose
-    first ``kv_lora_rank`` columns are the values."""
+    first ``kv_lora_rank`` columns are the values; under ``mask`` (an indexer's
+    picks, ``[b, s, keys]``) a query sees the keys it marks alone."""
     (leaf,) = PAGED_LATENT.leaves
 
-    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j, mask=None):
         q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
         q = jnp.concatenate([q_lat, q_rope], axis=-1)
         q = jnp.pad(q, ((0, 0),) * 3 + ((0, cache[leaf].shape[-1] - q.shape[-1]),))
         with jax.named_scope("serve.mla.attend"):
             out = paged_chunk_attention(
-                q[:, None], cache[leaf], None, j, block_tables, chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank
+                q[:, None], cache[leaf], None, j, block_tables, chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank, mask=mask
             )
         return jnp.einsum("bhsc,chv->bshv", out[:, 0].astype(cfg.dtype), w_v)
 
@@ -425,11 +497,12 @@ def _latent_attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk:
 
 def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: jax.Array):
     """Queries against every row of every table column, gathered from the
-    pool, under ``mask`` (as ``_attend_table``'s) and a float32 softmax:
+    pool, under ``mask`` (as ``_attend_table``'s; and under ``picked``, an
+    indexer's picks as a mask, the keys it marks alone) and a float32 softmax:
     decode without the paged path, the oracle that path is tested against."""
     (leaf,) = PAGED_LATENT.leaves
 
-    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j):
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j, picked=None):
         b, t = block_tables.shape
         r = cfg.kv_lora_rank
         found = cache[leaf][j, block_tables].reshape(b, t * cache[leaf].shape[2], -1)
@@ -438,11 +511,45 @@ def _latent_attend_table(cfg: TransformerConfig, block_tables: jax.Array, mask: 
         logits = jnp.einsum("bhsc,bkc->bhsk", q_lat, lat, preferred_element_type=jnp.float32)
         logits = logits + jnp.einsum("bhsr,bkr->bhsk", q_rope, rot, preferred_element_type=jnp.float32)
         seen = mask[None, None] if mask.ndim == 2 else mask[:, None]
+        if picked is not None:
+            seen = seen & picked[:, None]
         probs = jax.nn.softmax(jnp.where(seen, logits * cfg.attn_scale, NEG_INF), axis=-1)
         out = jnp.einsum("bhsk,bkc->bhsc", probs.astype(lat.dtype), lat)
         return jnp.einsum("bhsc,chv->bshv", out, w_v)
 
     return attend
+
+
+def _indexed_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    """The latent pool, and the index keys of the layers that hold an indexer under the same block ids."""
+    return _paged_shapes(cfg, sizes) + ((len(cfg.index_layers), sizes.num_blocks, sizes.block_size, cfg.index_head_dim),)
+
+
+def _indexed_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
+    """Summed over the lanes: the cached tokens the step's indexers score (the
+    live context in each layer that holds one), the rows its attention reads
+    (``min(context, index_topk)`` in each layer) and the rows a program that
+    read every live row would have."""
+    lens = jnp.where(active, pos + 1, 0).astype(jnp.float32)
+    live = jnp.sum(lens)
+    return jnp.stack([live * len(cfg.index_layers), jnp.sum(jnp.minimum(lens, cfg.index_topk)) * cfg.paged_layers, live * cfg.paged_layers])
+
+
+def _index_array(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    """The index array: the layers that own a row of it, a token's bytes over them, its bytes."""
+    per_token = len(cfg.index_layers) * cfg.index_head_dim * jnp.dtype(cfg.dtype).itemsize
+    return {"layers": len(cfg.index_layers), "bytes_per_token": per_token, "bytes": _nbytes(PAGED_INDEXED, cfg, sizes, slice(1, 2))}
+
+
+def _indexed_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
+    """``index_keys``: the second array of a model whose attention reads the keys an indexer picks, and ``index_topk``."""
+    if not cfg.index_layers:
+        return {}
+    return {"index_keys": {**_index_array(cfg, sizes), "index_topk": cfg.index_topk}, **COPY_SCHEDULE, **_rows_report(cfg)}
+
+
+def _indexed_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    return {**{"index_" + k: v for k, v in _index_array(cfg, sizes).items()}, "index_topk": cfg.index_topk, **COPY_SCHEDULE}
 
 
 # -- a state a lane ---------------------------------------------------------------
@@ -705,12 +812,15 @@ class CacheKind:
     #: ``setup(cfg, sizes)``: what a kind of the model adds to the ``serve.setup.kv_pool`` span
     report: Callable = lambda cfg, sizes, live, gauges=None: {}
     setup: Callable = lambda cfg, sizes: {}
+    #: its layers are those of a model whose attention reads the keys an indexer picks (``indexer_types``), or is not;
+    #: such layers hand their picks on: ``mix(p, x, h, cache, j, handed) -> (x, cache, handed)``
+    indexed: bool = False
 
     def layers(self, cfg: TransformerConfig) -> Tuple[int, ...]:
         """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its
         arrays, or with ``cfg.attn_sublayers`` attention sublayers a block the rows ``j * attn_sublayers ..``, one
         a sublayer (:func:`layer_kinds`)."""
-        if cfg.latent != self.latent:
+        if cfg.latent != self.latent or bool(cfg.indexer_types) != self.indexed:
             return ()
         return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) in self.layer_types)
 
@@ -753,6 +863,30 @@ PAGED_LATENT = CacheKind(
     wide=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_local(cfg)),
     walked=lambda cfg: (cfg.paged_layers, None),
     report=_latent_report, setup=_latent_report,
+)
+
+PAGED_INDEXED = CacheKind(
+    # the latent kind's row a token and its forms; beside it ONE index key a token in each layer that holds an indexer
+    # (row ``cfg.index_layer(i)`` of the second array), written where the token's latent row is: both are held as blocks,
+    # and a key depends on its own token and position alone, so a shared prefix serves both
+    name="paged_indexed", layer_types=(FULL,), latent=True, indexed=True, holds=BLOCKS,
+    leaves=("kv", "ik"), shapes=_indexed_shapes, dtypes=_compute_dtype(2),
+    step=lambda cfg, rows, cache: _latent_mixer(
+        cfg, rows, _latent_attend_paged(cfg, rows.block_tables, rows.lane_positions),
+        _indexer(cfg, rows, _index_paged(rows), as_mask=False)),
+    walk=_every_chunk(lambda cfg, rows: _latent_mixer(
+        cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk),
+        _indexer(cfg, rows, _index_gathered(rows)))),
+    table=lambda cfg, rows, cache: _latent_mixer(
+        cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows)),
+        _indexer(cfg, rows, _index_gathered(rows))),
+    wide=lambda cfg, rows, cache: _latent_mixer(
+        cfg, rows, _latent_attend_local(cfg), _indexer(cfg, rows, _index_local, keys=rows.positions.shape[-1])),
+    # what the step's indexers score, what its attention reads and what a program without selection would have read
+    counters=("serve.dsa.index_tokens", "serve.dsa.selected_tokens", "serve.dsa.live_tokens"), count=_indexed_count,
+    # the kernel that walks a lane's live blocks is the indexers' score pass, over the index array's rows
+    walked=lambda cfg: (len(cfg.index_layers), None),
+    report=_indexed_report, setup=_indexed_setup,
 )
 
 STATE_SLOT = CacheKind(
@@ -807,7 +941,7 @@ SSM_SLOT = CacheKind(
 )
 
 #: every kind, in the order a layer's mixers run, a decode step's counters and a walk's chunk take them
-CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, STATE_SLOT, WINDOW_RING, SSM_SLOT)
+CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, PAGED_INDEXED, STATE_SLOT, WINDOW_RING, SSM_SLOT)
 
 
 def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:

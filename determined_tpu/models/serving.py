@@ -134,7 +134,7 @@ def serve_gauges(cfg: TransformerConfig) -> Tuple[str, ...]:
     return sum((kind.gauges for kind in cache_kinds(cfg)), ())
 
 
-def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=None):
+def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=None, handed=None):
     """Layer ``i`` of the serving forward, stated once under the three entry
     points below: norm; the mixer of each of the layer's cache kinds, in the
     table's order (``mixers``: one a kind of the model, built by the entry point
@@ -149,14 +149,21 @@ def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=Non
     its experts read the first sublayer's second norm and join the stream after
     the second MLP.  Under ``mixer_block`` a layer is the norm and ONE of these:
     the mixer of its one cache kind, or the experts (a layer of no kind).
-    Returns (x, cache, what an expert layer counted or None)."""
+    ``handed``: what the layers of a kind that hands something on beside the
+    stream take from the layer before and leave for the next, inside one call
+    (``CacheKind.indexed``: a latent layer's picks where an indexer selects its keys).
+    Returns (x, cache, what an expert layer counted or None, handed)."""
 
     def attend(sub: int, x, cache):
         """Attention sublayer ``sub``: (the stream with it, the norm its MLP or experts read, the cache)."""
+        nonlocal handed
         tail = f"_{sub}" if sub else ""
         h = _norm_apply(cfg, x, blk["ln1" + tail]["scale"])
         for kind, j, subtree in layer_kinds(cfg, i, sub):
-            x, cache = mixers[kind.name](blk[subtree], x, h, cache, j)
+            if kind.indexed:
+                x, cache, handed = mixers[kind.name](blk[subtree], x, h, cache, j, handed)
+            else:
+                x, cache = mixers[kind.name](blk[subtree], x, h, cache, j)
         if not cfg.parallel_block:  # else the one norm: what attention read
             h = _norm_apply(cfg, x, blk["ln2" + tail]["scale"])
         return x, h, cache
@@ -172,29 +179,29 @@ def _serve_layer(cfg, i, blk, x, mixers: Mapping[str, Callable], cache, live=Non
             from determined_tpu.models.moe import serve_routed_experts
 
             y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
-            return x + y, cache, counted
-        for kind, j, subtree in layer_kinds(cfg, i):
+            return x + y, cache, counted, handed
+        for kind, j, subtree in layer_kinds(cfg, i):  # no kind of a mixer_block model hands anything on
             x, cache = mixers[kind.name](blk[subtree], x, h, cache, j)
-        return x, cache, None
+        return x, cache, None, handed
     x, h, cache = attend(0, x, cache)
     if not cfg.use_moe(i):
-        return mlp("mlp", x, h), cache, None
+        return mlp("mlp", x, h), cache, None, handed
     from determined_tpu.models.moe import serve_routed_experts
 
     y, counted = serve_routed_experts(cfg, blk["moe"], h, live)
     if cfg.shortcut_block:
         x, h, cache = attend(1, mlp("mlp", x, h), cache)
         x = mlp("mlp_1", x, h)
-    return x + y, cache, counted
+    return x + y, cache, counted, handed
 
 
 def _serve_layers(cfg, params, x, mixers: Mapping[str, Callable], cache, live=None):
     """Every layer; the last value is what the expert layers counted
     (``SERVE_COUNTERS``, and ``ZERO_PICKS`` where there are identity experts),
     summed over them, float32, or None for a model without them."""
-    counted = []
+    counted, handed = [], None
     for i in range(cfg.n_layers):
-        x, cache, c = _serve_layer(cfg, i, params[f"block_{i}"], x, mixers, cache, live)
+        x, cache, c, handed = _serve_layer(cfg, i, params[f"block_{i}"], x, mixers, cache, live, handed)
         if c is not None:
             counted.append(jnp.stack(c).astype(jnp.float32))
     return x, cache, sum(counted) if counted else None

@@ -46,7 +46,8 @@ from flax import linen as nn
 
 from determined_tpu.data import DataLoader, SyntheticDataset
 from determined_tpu.ops import kernel_form
-from determined_tpu.ops.attention import dot_product_attention, reference_attention
+from determined_tpu.ops.attention import NEG_INF, dot_product_attention, reference_attention
+from determined_tpu.ops.paged_attention import index_scores, index_topk_mask
 from determined_tpu.ops.retention import recent_shapes, retention_quadratic, state_shapes
 from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
 from determined_tpu.ops.ring_attention import ring_attention
@@ -66,6 +67,9 @@ CCA = "cca"
 #: alone, or (``full_attention``) attention alone
 MAMBA2, EXPERTS = "mamba2", "experts"
 LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA, MAMBA2, EXPERTS)
+#: what ``indexer_types`` says of a layer: it holds an indexer and attends over the keys that picks, or it
+#: holds none and attends over the picks of the nearest ``full`` layer before it
+INDEX_FULL, INDEX_SHARED = "full", "shared"
 _YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow")
 
 
@@ -202,6 +206,22 @@ class TransformerConfig:
     # cached row holds the scaled one): LongCat-Flash's (d_model / rank) ** 0.5
     q_latent_scale: float = 1.0
     kv_latent_scale: float = 1.0
+    # Learned sparse selection of the keys latent attention reads (DeepSeek
+    # Sparse Attention's lightning indexer; GLM-5.2 shares a layer's picks with
+    # the layers after it).  indexer_types states a layer as layer_types does:
+    # a "full" layer holds an indexer (index_n_heads heads of index_head_dim on
+    # the normed query latent, ONE key of index_head_dim a token from the
+    # layer's normed input under a LayerNorm with a bias, rotary on the first
+    # qk_rope_head_dim of both, a weight a head from the normed input) and
+    # attends, query by query, over the index_topk earlier tokens it scores
+    # highest (``_index_project``, ``ops/paged_attention.py index_scores``); a
+    # "shared" layer holds none and attends over the picks of the nearest full
+    # layer before it.  None: every layer attends over all earlier tokens.
+    # Serving caches a full layer's index key a token beside the latent row
+    indexer_types: Optional[Tuple[str, ...]] = None
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # A cca layer (``CompressedAttention``): q and k pass a depthwise causal
     # convolution over cca_time0 tokens and one over cca_time1 tokens that
     # mixes each head's channels; rotary turns the first partial_rotary_factor
@@ -421,6 +441,31 @@ class TransformerConfig:
                 )
             if self.quantized_matmul != "none" or self.seq_axis_name is not None:
                 raise ValueError("latent attention runs without quantized_matmul and outside a `seq` axis")
+        if self.indexer_types is not None:
+            setattr_("indexer_types", tuple(self.indexer_types))
+            refused = [
+                what for what, there in (
+                    ("without latent attention (kv_lora_rank): the indexer reads the normed query latent", not self.latent),
+                    ("under parallel_block", self.parallel_block), ("under shortcut_block", self.shortcut_block),
+                    ("under mixer_block", self.mixer_block), ("under a `seq` axis", self.seq_axis_name is not None),
+                    ("inside pipeline stages (an `expert` axis: a stage hands on the stream alone, not the picks)", self.expert_axis_name is not None),
+                    ("with moe_router mlp (one value is handed from layer to layer, and the router's state is it)", self.moe_router == "mlp"),
+                ) if there
+            ]
+            if refused:
+                raise ValueError("an indexer (indexer_types) does not run " + "; ".join(refused))
+            sizes = (self.index_n_heads, self.index_head_dim, self.index_topk)
+            if (
+                len(self.indexer_types) != self.n_layers or set(self.indexer_types) - {INDEX_FULL, INDEX_SHARED}
+                or self.indexer_types[0] != INDEX_FULL or min(sizes) < 1 or not self.qk_rope_head_dim <= self.index_head_dim
+            ):
+                raise ValueError(
+                    f"indexer_types needs `{INDEX_FULL}` or `{INDEX_SHARED}` for each of the {self.n_layers} layers, the first "
+                    f"`{INDEX_FULL}` (a shared layer reads the picks of a layer before it), and index_n_heads, index_topk >= 1 with "
+                    f"index_head_dim >= qk_rope_head_dim, whose first part rotary turns (got {self.indexer_types}, {sizes})"
+                )
+        elif self.index_n_heads or self.index_head_dim or self.index_topk:
+            raise ValueError("index_n_heads, index_head_dim and index_topk belong to indexer_types")
 
     @property
     def kv_heads(self) -> int:
@@ -502,6 +547,16 @@ class TransformerConfig:
     def paged_layers(self) -> int:
         """How many rows of the paged pool a token owns: one an attention sublayer of each layer that keeps its rows there."""
         return (self.n_layers - len(self.window_layers) - len(self.rowless_layers)) * self.attn_sublayers
+
+    @property
+    def index_layers(self) -> Tuple[int, ...]:
+        """The layers that hold an indexer, in order: serving keeps their index
+        keys a token in an array of its own (``models/cache_kinds.py``)."""
+        return tuple(i for i, kind in enumerate(self.indexer_types or ()) if kind == INDEX_FULL)
+
+    def index_layer(self, i: int) -> Optional[int]:
+        """Layer ``i``'s own place among ``index_layers``; None: it holds no indexer."""
+        return self.index_layers.index(i) if i in self.index_layers else None
 
     def rope(self, layer_type: str) -> Optional["Rope"]:
         """How a layer of this type rotates q and k; None: it does not."""
@@ -890,27 +945,60 @@ def _latent_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, .
     }
 
 
+def _index_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
+    """An indexer's leaves, beside latent attention's in the subtree of a layer
+    that holds one: queries from the normed query latent, ONE key a token and a
+    weight a head from the layer's normed input, the key's LayerNorm (weight and bias)."""
+    d, h, k = cfg.d_model, cfg.index_n_heads, cfg.index_head_dim
+    kernel = nn.initializers.lecun_normal()
+    return {
+        "index_wq_b": ((cfg.q_lora_rank, h, k), (None, None, None), kernel),
+        "index_wk": ((d, k), ("embed", None), kernel),
+        "index_k_norm": ((k,), (None,), nn.initializers.ones),
+        "index_k_bias": ((k,), (None,), nn.initializers.zeros),
+        "index_w": ((d, h), ("embed", None), kernel),
+    }
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention over the whole sequence (training, and
     what ``init`` builds for serving): the projections of ``_latent_project``
     and the expanded, causal form of ``_latent_attend_local``; the serving
-    forward below reads the same leaves."""
+    forward below reads the same leaves.  Under ``indexer_types`` a query
+    attends over the keys ``picked`` marks (a mask [b, s, s]; ``indexer``
+    "full": the layer's own picks, which it also hands on; "shared": those it
+    was handed), once the sequence is longer than ``index_topk``; up to there
+    nothing is left out and nothing is scored.  Returns (what attention adds,
+    the mask)."""
 
     cfg: TransformerConfig
+    indexer: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, picked: Any = None) -> Tuple[jax.Array, Any]:
         cfg = self.cfg
+        shapes = {**_latent_param_shapes(cfg), **(_index_param_shapes(cfg) if self.indexer == INDEX_FULL else {})}
         p = {
             name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
-            for name, (shape, logical, init) in _latent_param_shapes(cfg).items()
+            for name, (shape, logical, init) in shapes.items()
         }
+        s = x.shape[1]
         with jax.named_scope("attn.qkv"):
-            q_nope, q_rope, c_kv, k_r = _latent_project(cfg, p, x, jnp.arange(x.shape[1]), cfg.rope(FULL))
+            q_nope, q_rope, c_kv, k_r, c_q = _latent_project(cfg, p, x, jnp.arange(s), cfg.rope(FULL))
+        if self.indexer == INDEX_FULL:
+            picked = None
+            if s > cfg.index_topk:
+                with jax.named_scope("dsa"):
+                    with jax.named_scope("dsa.project"):
+                        q_i, w, k_i = _index_project(cfg, p, c_q, x, jnp.arange(s), cfg.rope(FULL))
+                    with jax.named_scope("dsa.index"):
+                        scores = index_scores(q_i, w, k_i)
+                    with jax.named_scope("dsa.topk"):
+                        picked = index_topk_mask(scores, jnp.tril(jnp.ones((s, s), bool)), cfg.index_topk)
         with jax.named_scope("attn.full"):
-            out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0)
+            out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0, picked)
         with jax.named_scope("attn.out"):
-            return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype))
+            return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype)), picked
 
 
 def _times(x: jax.Array, scalar: float) -> jax.Array:
@@ -1047,13 +1135,15 @@ class Block(nn.Module):
     mesh: Any = None
     use_moe: bool = False
     layer_type: str = FULL
+    indexer: Optional[str] = None  # what ``indexer_types`` says of this layer
 
     @nn.compact
     def __call__(self, x: jax.Array, state: Any = None) -> Tuple[jax.Array, jax.Array, Any]:
         """``x`` [b, s, d] -> (``x``, the auxiliary loss, what the block hands
         the next beside the stream: under the "mlp" router its router's state
         [b, s, router_hidden_size], from the last expert block's ``state``;
-        None under every other router)."""
+        under ``indexer_types`` the picks of the newest layer that holds an
+        indexer, as a mask; else None)."""
         cfg = self.cfg
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name=name
@@ -1112,8 +1202,11 @@ class Block(nn.Module):
 
         def attend(name: str, h: jax.Array) -> jax.Array:
             """What the attention sublayer ``name`` adds, from the normed input."""
+            nonlocal state
             if cfg.latent:
-                return LatentAttention(cfg, name=name)(h)
+                out, picked = LatentAttention(cfg, self.indexer, name=name)(h, state if self.indexer else None)
+                state = picked if self.indexer else state
+                return out
             if self.layer_type == RETENTION:
                 return Retention(cfg, name=name)(h)
             if self.layer_type == CCA:
@@ -1192,7 +1285,8 @@ class TransformerLM(nn.Module):
         aux_total = jnp.zeros((), jnp.float32)
         state = None  # what a layer hands the next beside the stream (the "mlp" router's state)
         for i in range(cfg.n_layers):
-            x, aux, state = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), name=f"block_{i}")(x, state)
+            indexer = cfg.indexer_types[i] if cfg.indexer_types else None
+            x, aux, state = block_cls(cfg, self.mesh, cfg.use_moe(i), cfg.layer_type(i), indexer, name=f"block_{i}")(x, state)
             aux_total = aux_total + aux
         x = RMSNorm(
             eps=cfg.norm_eps, partition=cfg.partition_params, param_dtype=cfg.param_dtype, kind=cfg.norm, name="ln_f"
@@ -1510,22 +1604,45 @@ def _latent_project(cfg, p, h, positions, rope):
     c_kv = _times(_rms_apply(kv[..., :r], p["kv_norm"], cfg.norm_eps), cfg.kv_latent_scale)
     k_r = _rope(kv[:, None, :, r:], positions, rope)[:, 0]
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], _rope(q[..., cfg.qk_nope_head_dim:], positions, rope)
-    return q_nope, q_rope, c_kv, k_r
+    return q_nope, q_rope, c_kv, k_r, c_q
+
+
+def _index_project(cfg, p, c_q, h, positions, rope):
+    """An indexer's projections, stated once under the whole-sequence form and
+    the serving forward: its queries ``[b, s, heads, index_head_dim]`` from the
+    normed query latent ``c_q``, its ONE key a token ``[b, s, index_head_dim]``
+    from the layer's normed input ``h`` under a LayerNorm with a bias (what
+    serving caches), rotary on the first ``qk_rope_head_dim`` of both, and a
+    head's weight ``[b, s, heads]`` float32 from ``h``, times ``heads ** -0.5
+    * index_head_dim ** -0.5``."""
+    dt, r = cfg.dtype, cfg.qk_rope_head_dim
+    q = jnp.einsum("bsr,rhk->bhsk", c_q, p["index_wq_b"].astype(dt))
+    q = jnp.concatenate([_rope(q[..., :r], positions, rope), q[..., r:]], axis=-1).transpose(0, 2, 1, 3)
+    k = _layer_norm(h @ p["index_wk"].astype(dt), p["index_k_norm"], cfg.norm_eps) + p["index_k_bias"].astype(dt)
+    k = jnp.concatenate([_rope(k[:, None, :, :r], positions, rope)[:, 0], k[..., r:]], axis=-1)
+    w = (h @ p["index_w"].astype(dt)).astype(jnp.float32) * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    return q, w, k
 
 
 def _latent_attend_local(cfg: TransformerConfig):
     """Causal, over this call's own rows, keys and values expanded a head:
     the wide prefill (prompts start at position 0) and the training forward.
     On a TPU at a length worth tiling the flash kernel runs it, q, k and v
-    padded with zeros to one width; no ``[heads, s, s]`` array is built."""
+    padded with zeros to one width; no ``[heads, s, s]`` array is built.
+    ``picked`` [b, s, s] (an indexer's picks a query, as a mask): the flash form
+    masks by position alone, so a set a query takes the plain masked softmax."""
 
-    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i):
+    def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, i, picked=None):
         dt, nope = cfg.dtype, cfg.qk_nope_head_dim
         kv = jnp.einsum("bsc,chk->bhsk", c_kv, wkv_b.astype(dt))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r[:, None], kv.shape[:3] + k_r.shape[-1:])], axis=-1)
         v = kv[..., nope:]
-        if kernel_form.on_tpu() and q.shape[2] >= 256:
+        if picked is not None:
+            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * cfg.attn_scale
+            probs = jax.nn.softmax(jnp.where(picked[:, None], logits, NEG_INF), axis=-1)
+            out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+        elif kernel_form.on_tpu() and q.shape[2] >= 256:
             from determined_tpu.ops.flash_attention import flash_attention
 
             width = -(-max(q.shape[-1], v.shape[-1]) // 128) * 128
@@ -1540,6 +1657,8 @@ def _latent_attend_local(cfg: TransformerConfig):
 
 #: latent attention's hparams: passed to the config as they are (absent: GQA)
 _LATENT_HPARAMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "softmax_scale")
+#: an indexer's: passed as they are too (absent: no layer selects its keys)
+_INDEX_HPARAMS = ("indexer_types", "index_n_heads", "index_head_dim", "index_topk")
 
 
 class LMTrial(JaxTrial):
@@ -1646,6 +1765,7 @@ class LMTrial(JaxTrial):
                 ("residual_scaling", bool(g("residual_scaling", False))),
                 ("shortcut_block (its expert branch beside it)", bool(g("shortcut_block", False))),
                 ("mixer_block (its layers are not alike: a stage stacks one leaf a layer)", bool(g("mixer_block", False))),
+                ("indexer_types (the picks a layer hands the next)", g("indexer_types", None) is not None),
             ) if there
         ]
         if pipe > 1 and carried:
@@ -1726,6 +1846,7 @@ class LMTrial(JaxTrial):
             tie_embeddings=bool(g("tie_embeddings", False)),
             logit_scale=float(g("logit_scale", 1.0)),
             **{k: g(k, None) for k in _LATENT_HPARAMS},
+            **{k: g(k, None if k == "indexer_types" else 0) for k in _INDEX_HPARAMS},
             quantized_matmul=self._quant_mode(),
         )
 

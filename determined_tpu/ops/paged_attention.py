@@ -785,6 +785,211 @@ def _paged_latent_pallas(q, pool, layer, block_tables, lengths, scale, value_dim
 
 
 # ---------------------------------------------------------------------------
+# Learned sparse selection: an indexer's scores, its exact top-k, and latent
+# attention over the rows it picked
+# ---------------------------------------------------------------------------
+#
+# DeepSeek Sparse Attention's lightning indexer: a query's score of a cached
+# token is ``sum_j w_j relu(q_j . k)`` over the indexer's heads ``j``, against
+# ONE index key ``k`` a token, and latent attention reads the ``topk`` tokens of
+# largest score alone.  The index keys lie in a pool of their own, ``[indexer
+# layers, num_blocks, block_size, index_head_dim]``, under the latent pool's
+# block ids: a token's key is written where its latent row is.  The scores are
+# float32; the selection is exact (``jax.lax.top_k``; ties go to the lower
+# position), and what it picks are POSITIONS of the lane's own context, turned
+# into places in the pool through the lane's block table.
+
+
+def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
+    """The indexer's scores, the one statement of them: ``q`` [b, s, heads, dim]
+    and ``keys`` [b, k, dim] in the compute dtype, ``w`` [b, s, heads] float32 ->
+    ``sum_j w[..., j] relu(q[..., j, :] . keys)`` [b, s, k] float32.  A head at a
+    time, so that the largest array is the result's own."""
+
+    def add_head(acc, head):
+        q_j, w_j = head  # [b, s, dim], [b, s]
+        dots = jnp.einsum("bsd,bkd->bsk", q_j, keys, preferred_element_type=jnp.float32)
+        return acc + jnp.maximum(dots, 0.0) * w_j[..., None], None
+
+    init = jnp.zeros((*q.shape[:2], keys.shape[1]), jnp.float32)
+    return jax.lax.scan(add_head, init, (q.transpose(2, 0, 1, 3), w.astype(jnp.float32).transpose(2, 0, 1)))[0]
+
+
+def index_topk(scores: jax.Array, seen: jax.Array, topk: int) -> Tuple[jax.Array, jax.Array]:
+    """The exact selection: for each query of ``scores`` [b, s, k] the ``min(topk,
+    k)`` keys of largest score among those it may see (``seen``, broadcast
+    against ``scores``), ties to the lower position.  Returns (the picks [b, s,
+    topk] int32, positions among the ``k`` keys; which of them are picks at all
+    [b, s, topk]: a query that sees fewer than ``topk`` keys picks them all and
+    the rest of its row is not valid)."""
+    vals, picks = jax.lax.top_k(jnp.where(seen, scores, NEG_INF), min(topk, scores.shape[-1]))
+    return picks.astype(jnp.int32), vals > NEG_INF / 2
+
+
+def index_topk_mask(scores: jax.Array, seen: jax.Array, topk: int) -> jax.Array:
+    """:func:`index_topk`'s selection as a mask ``[b, s, k]`` (True where a query
+    picked the key), pick for pick and tie for tie, without its sort and without
+    a scatter: what a form that reads every key under a mask asks for (the walk,
+    the oracles, the whole-sequence form; a decode step that gathers rows needs
+    the positions, and calls :func:`index_topk`).  A float32 score's bits, its
+    sign folded, order as the scores do; 32 halvings of that range find the
+    ``topk``-th largest value a query sees, every key above it is a pick, and of
+    the keys AT it the lowest positions fill what is left.  On a v5e a walk
+    chunk's ``[1, 256, 24576]`` takes 0.68 ms this way and 6.5 ms by ``top_k``
+    and a scatter (my chip run, PR 61)."""
+    k = min(topk, scores.shape[-1])
+    seen = seen & (scores > NEG_INF / 2)  # as ``index_topk``: a score of NEG_INF is no pick
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32) + 0.0, jnp.int32)  # + 0.0: -0.0 ties with 0.0
+    ordered = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    u = jax.lax.bitcast_convert_type(ordered, jnp.uint32) ^ jnp.uint32(0x80000000)
+    u = jnp.where(seen, u, jnp.uint32(0))  # below every score a query may see
+    lo = jnp.zeros((*u.shape[:-1], 1), jnp.uint32)
+
+    def halve(_, bounds):  # the largest t with at least k keys >= t
+        lo, hi = bounds
+        mid = lo + (hi - lo) // 2 + ((hi - lo) & 1)
+        enough = jnp.sum(u >= mid, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1)
+
+    t, _ = jax.lax.fori_loop(0, 32, halve, (lo, jnp.full_like(lo, 0xFFFFFFFF)))
+    above, at = u > t, u == t
+    left = k - jnp.sum(above, axis=-1, keepdims=True)
+    return (above | (at & (jnp.cumsum(at, axis=-1) <= left))) & seen
+
+
+#: tokens a tile of the index-score kernel aims for: a key is a fifth of a latent row
+INDEX_TILE_TOKENS = 1024
+
+
+def index_kernel_takes(dim: int, block_size: int, dtype) -> bool:
+    """Whether the index keys' shapes tile for the Pallas kernel."""
+    return jnp.dtype(dtype).itemsize in (2, 4) and dim % 128 == 0 and block_size % _sublane_packing(dtype) == 0
+
+
+def paged_index_scores(
+    q: jax.Array, w: jax.Array, pool: jax.Array, layer, block_tables: jax.Array, positions: jax.Array, *,
+    tile_blocks: Optional[int] = None, impl: Optional[str] = None,
+) -> jax.Array:
+    """An indexer's scores of one decode step over the paged index keys: ``q``
+    [b, heads, dim] and ``w`` [b, heads] (float32) of each lane's query, ``pool``
+    ``[indexer layers, num_blocks, block_size, dim]``; ``layer``,
+    ``block_tables``, ``positions``, ``impl`` and ``tile_blocks`` as
+    :func:`paged_decode_attention`'s.  Returns ``[b, T * block_size]`` float32:
+    :func:`index_scores` of the lane's query against the key of each position of
+    its table, ``NEG_INF`` past the lane's own position (every position of an
+    idle lane).  The kernel walks a lane's live blocks by
+    :func:`_copy_schedule`, as the attention kernels do."""
+    block_size, dim = pool.shape[2], pool.shape[3]
+    if tile_blocks is None:
+        tile_blocks = max(1, min(block_tables.shape[1], INDEX_TILE_TOKENS // block_size))
+    impl = kernel_form.resolve_impl(
+        impl, index_kernel_takes(dim, block_size, pool.dtype),
+        f"the index-score kernel needs index_head_dim % 128 == 0 and whole sublane tiles a block "
+        f"(got index_head_dim={dim}, block_size={block_size}, {pool.dtype})",
+    )
+    return _paged_index(
+        q.astype(pool.dtype), w.astype(jnp.float32), pool, jnp.asarray(layer, jnp.int32), block_tables, positions,
+        tile_blocks=tile_blocks, impl=impl,
+    )
+
+
+# as ``_paged_attention``
+@functools.partial(jax.jit, static_argnames=("tile_blocks", "impl"))
+def _paged_index(q, w, pool, layer, block_tables, positions, *, tile_blocks, impl):
+    lengths = jnp.maximum(positions.astype(jnp.int32) + 1, 0)
+    b, t = block_tables.shape
+    tokens = t * pool.shape[2]
+    if impl == "jnp":
+        scores = index_scores(q[:, None], w[:, None], pool[layer, block_tables].reshape(b, tokens, -1))[:, 0]
+        return jnp.where(jnp.arange(tokens)[None, :] < lengths[:, None], scores, NEG_INF)
+    return _paged_index_pallas(q, w, pool, layer, block_tables, lengths, tile_blocks, interpret=impl == "kernel_interpret")
+
+
+def _paged_index_kernel(
+    layer_ref, lengths_ref, tables_ref,           # scalar prefetch (SMEM)
+    q_ref, w_ref, pool_hbm,                       # inputs
+    o_ref,                                        # output [tiles, tile_tokens]
+    buf, sems, state_ref,                         # scratch
+    *, tile_blocks: int, block_size: int, table_width: int,
+):
+    tile_tokens = tile_blocks * block_size
+    length, _, _, n_tiles, trip = _copy_schedule(
+        lengths_ref, tables_ref, state_ref, ((pool_hbm, buf, sems),), layer_ref[0],
+        tile_blocks=tile_blocks, block_size=block_size, table_width=table_width, window=None,
+    )
+    o_ref[...] = jnp.full(o_ref.shape, NEG_INF, jnp.float32)   # the tiles the lane does not walk
+    q, w = q_ref[...], w_ref[...]                               # [heads, dim], [heads, 1]
+
+    def body(i, _):
+        tile = buf[trip(i)]                                     # [tile_tokens, dim]
+        dots = jax.lax.dot_general(q, tile, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        scores = jnp.sum(jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)   # [1, tile_tokens]
+        k_idx = i * tile_tokens + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        o_ref[pl.ds(i, 1), :] = jnp.where(k_idx < length, scores, NEG_INF)
+        return None
+
+    jax.lax.fori_loop(0, n_tiles, body, None)
+
+
+def _paged_index_pallas(q, w, pool, layer, block_tables, lengths, tile_blocks, *, interpret: bool):
+    b, heads, dim = q.shape
+    block_size = pool.shape[2]
+    t = block_tables.shape[1]
+    tile_tokens = tile_blocks * block_size
+    tiles = -(-t // tile_blocks)
+    kernel = functools.partial(_paged_index_kernel, tile_blocks=tile_blocks, block_size=block_size, table_width=t)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, heads, dim), lambda bi, *_: (bi, 0, 0)),
+                pl.BlockSpec((None, heads, 1), lambda bi, *_: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, tiles, tile_tokens), lambda bi, *_: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tile_tokens, dim), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, tiles, tile_tokens), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=kernel_form.interpret_params(interpret),
+        name="paged_index_scores",
+    )(layer.reshape(1), lengths, block_tables.reshape(-1).astype(jnp.int32), q, w[..., None], pool)
+    return out.reshape(b, tiles * tile_tokens)[:, : t * block_size]
+
+
+def paged_picked_attention(
+    q: jax.Array, pool: jax.Array, layer, block_tables: jax.Array, picks: jax.Array, valid: jax.Array, *,
+    scale: float, value_dim: int,
+) -> jax.Array:
+    """Latent attention of one decode step of one layer over the rows an
+    indexer picked: ``q``, ``pool``, ``layer``, ``block_tables`` and the result
+    as :func:`paged_latent_attention`'s; ``picks`` [b, k] the positions of the
+    lane's context its query reads and ``valid`` [b, k] which of them are picks
+    at all (:func:`index_topk`; none of an idle lane's: zeros).  A pick becomes
+    a place in the pool through the lane's block table, here.  The picked rows
+    are copied out of the pool, a row a copy (scope ``serve.mla.gather``), and
+    attended in one dense product (``serve.mla.attend``): what is read does not
+    grow with the context (reading every live row under a mask wins only while
+    a lane holds under ~6 times the picks: PERF.md section 5)."""
+    block_size = pool.shape[2]
+    with jax.named_scope("serve.mla.gather"):
+        places = jnp.take_along_axis(block_tables, jnp.where(valid, picks // block_size, 0), axis=1)
+        rows = pool[layer, places, picks % block_size]          # [b, k, width]
+    with jax.named_scope("serve.mla.attend"):
+        s = jnp.einsum("bhw,bkw->bhk", q.astype(pool.dtype), rows, preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, :], s, NEG_INF)
+        p = jnp.where(valid[:, None, :], jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)  # an idle lane: zeros
+        out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype), rows[..., :value_dim], preferred_element_type=jnp.float32)
+        return out / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+
+
+# ---------------------------------------------------------------------------
 # A chunk of queries a lane: the prefill walk's read
 # ---------------------------------------------------------------------------
 
@@ -800,6 +1005,7 @@ def paged_chunk_attention(
     scale: float,
     value_dim: Optional[int] = None,
     window: Optional[int] = None,
+    mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Causal attention of one CHUNK of queries a lane over the paged pool:
     the decode forms' mathematics with a block of queries, in ``jax.numpy``.
@@ -824,6 +1030,9 @@ def paged_chunk_attention(
     ring (see the module's text), at least ``window + s`` tokens long.  A query
     sees ``q_pos - window < k_pos <= q_pos``; the tiles walked are those from
     the chunk's first query's oldest key on, at most ``window / s + 2``.
+
+    ``mask`` [b, s, T * block_size] (an indexer's picks a query): a query sees
+    the keys it marks alone, of those its position lets it see.
     """
     b, g, r, s, width = q.shape
     block_size = k_pool.shape[2]
@@ -846,10 +1055,14 @@ def paged_chunk_attention(
         seen = k_pos[None, :] <= q_pos[:, None]  # [s, s]; all of it before the last tile
         if window is not None:
             seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+        if mask is not None:  # [b, 1, 1, s, s] against the scores' [b, g, r, s, s]; a tile past the table's end marks nothing
+            start = jnp.minimum(i * s, mask.shape[2] - s)
+            seen = (seen & (i * s < mask.shape[2]))[None] & jax.lax.dynamic_slice_in_dim(mask, start, s, axis=2)
+            seen = seen[:, None, None]
         sc = jnp.where(seen, sc, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         p = jnp.exp(sc - m_new)  # masked: exp(NEG_INF - m) = 0
-        if window is not None:
+        if window is not None or mask is not None:
             p = jnp.where(seen, p, 0.0)  # a late query sees nothing of the walk's first tile: its m is still NEG_INF
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
@@ -865,4 +1078,4 @@ def paged_chunk_attention(
     )
     first_tile = 0 if window is None else jnp.maximum(chunk * s - window + 1, 0) // s
     _, l, acc = jax.lax.fori_loop(first_tile, chunk + 1, body, init)
-    return acc / l
+    return acc / (l if mask is None else jnp.maximum(l, 1e-30))  # under a mask a padded query may see nothing

@@ -1,6 +1,6 @@
 """The table of cache kinds (``models/cache_kinds.py``) is where the forward,
 the kernels and the engine take what they know of a model's cache: over the
-benchmark's seven serving architectures at their tiny sizes, the cache is the
+benchmark's eight serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
@@ -32,6 +32,7 @@ ARCHS = {
     "falcon_h1": ("paged_kv", "ssm_slot"),
     "longcat_scmoe": ("paged_latent",),
     "nemotron_h": ("paged_kv", "ssm_slot"),
+    "glm_moe_dsa": ("paged_indexed",),
 }
 
 
@@ -72,7 +73,9 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, sizes.num_blocks, sizes.block_size, sizes.max_batch, sizes.prefill_chunk))
     want = {leaf: (shape, jnp.dtype(dtype)) for kind in kinds for leaf, shape, dtype in zip(kind.leaves, kind.shapes(cfg, sizes), kind.dtypes(cfg))}
     assert {leaf: (a.shape, a.dtype) for leaf, a in cache.items()} == want
-    assert all(want[leaf][0][0] == len(kind.layers(cfg)) * cfg.attn_sublayers for kind in kinds for leaf in kind.leaves)
+    # (the index keys aside: a row a layer that holds an indexer, not a row a layer of the kind)
+    owners = lambda kind, leaf: len(cfg.index_layers) if leaf == "ik" else len(kind.layers(cfg)) * cfg.attn_sublayers  # noqa: E731
+    assert all(want[leaf][0][0] == owners(kind, leaf) for kind in kinds for leaf in kind.leaves)
 
     # the counters: the kinds' in the table's order, then the experts'
     experts = (SERVE_COUNTERS if cfg.moe_experts else ()) + ((ZERO_PICKS,) if cfg.moe_zero_experts else ())

@@ -644,6 +644,91 @@ _ARRAY = re.compile(r"\b(pred|s8|s16|s32|u8|u16|u32|bf16|f16|f32|f64)\[([\d,]*)\
 _WIDTH = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4, "f64": 8}
 
 
+# -- latent rows an indexer picks, an index key a token beside them: the GLM-5.2 cell's shapes --
+
+
+def _glm_cfg(n_layers: int = 3):
+    """The cell's widths, depth cut to the dense layer (its own indexer), one
+    expert layer that shares its picks and one with an indexer of its own."""
+    from determined_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=19360, d_model=6144, n_layers=n_layers, n_heads=64, d_ff=12288, max_seq_len=24576, rope_theta=8e6, norm_eps=1e-5,
+        q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        indexer_types=("full", "shared", "full")[:n_layers], index_n_heads=32, index_head_dim=128, index_topk=2048,
+        dense_prefix=1, moe_experts=256, moe_every=1, moe_top_k=8, moe_intermediate_size=2048, moe_experts_held=(0, 16),
+        moe_router="sigmoid_grouped", moe_n_group=1, moe_topk_group=1, moe_routed_scaling=2.5, moe_shared_experts=1,
+        param_dtype=jnp.bfloat16,
+    )
+
+
+def test_the_index_score_kernel_compiles_at_the_glm_cells_shape(tpu_devices):
+    """The indexers' score pass at the cell's lanes, table (1,536 columns:
+    24,576 positions), pool and 32 heads of 128: one Mosaic call under its name."""
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    text = _compile(
+        lambda q, w, pool, tables, pos: paged_mod.paged_index_scores(q, w, pool, 1, tables, pos),
+        aval((16, 32, 128), jnp.bfloat16), aval((16, 32), jnp.float32), aval((2, 28673, 16, 128), jnp.bfloat16),
+        aval((16, 1536)), aval((16,)),
+    )
+    assert _kernels(text) == 1 and "paged_index_scores" in text
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_glm_cells_programs_compile_over_rows_an_indexer_picks(tpu_devices, which):
+    """The GLM-5.2 cell's programs at its widths, lanes (16), pool (28,673
+    blocks) and table (1,536 columns), bfloat16 leaves, 3 of 5 layers: both
+    arrays of the cache are donated; the decode step scores with the index
+    kernel in the two layers that hold an indexer, picks an exact top-2,048
+    and attends over gathered rows in all three (no latent kernel walks a
+    lane); the walk builds a chunk's
+    scores against the table and no [heads, chunk, table] array."""
+    from flax.core import meta as flax_meta
+
+    from determined_tpu.models.serving import transformer_decode
+    from determined_tpu.utils.compilation_cache import program_scopes
+
+    one = SingleDeviceSharding(tpu_devices[0])
+    cfg = _glm_cfg()
+    pool_bytes = 28673 * 16 * (3 * 640 + 2 * 128) * 2
+    if which == "prefill":
+        from determined_tpu.models.serving import transformer_prefill_chunked
+
+        boxed = jax.eval_shape(lambda: TransformerLM_init(cfg))
+        params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), flax_meta.unbox(boxed)["params"])
+        aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+        cache = {"kv": aval((3, 28673, 16, 640), cfg.dtype), "ik": aval((2, 28673, 16, 128), cfg.dtype)}
+        fn = jax.jit(functools.partial(transformer_prefill_chunked, cfg), donate_argnums=(5,))
+        compiled = fn.lower(params, aval((1, 24576)), aval((1,)), aval((1,)), aval((1, 1536)), cache).compile()
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 1.5 * 1024**3
+        assert _arrays_with_dims(text, (64, 256, 24576)) == [] and _arrays_with_dims(text, (32, 256, 24576)) == []
+        scopes = program_scopes(text)
+        assert {"serve.dsa.project", "serve.dsa.write", "serve.dsa.index", "serve.dsa.topk", "serve.mla.attend"} <= set(scopes)
+        return
+    boxed = jax.eval_shape(lambda: TransformerLM_init(cfg))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), flax_meta.unbox(boxed)["params"])
+    aval = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    cache = {"kv": aval((3, 28673, 16, 640), cfg.dtype), "ik": aval((2, 28673, 16, 128), cfg.dtype)}
+    fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
+    compiled = fn.lower(params, aval((16,)), aval((16,)), aval((16, 1536)), cache).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 512 * 1024**2
+    assert _kernels(text) == 2 + 2 * 5  # the index kernel in two layers; two expert layers' row movements and grouped products
+    scopes = program_scopes(text)
+    assert {"serve.dsa", "serve.dsa.project", "serve.dsa.write", "serve.dsa.index", "serve.dsa.topk", "serve.mla.gather",
+            "serve.mla.attend", "serve.moe.experts"} <= set(scopes)
+    assert sum("paged_index_scores" in n for n in scopes["serve.dsa.index"]) == 2
+    assert not any("paged_latent_attention" in n for n in scopes["serve.mla.attend"])
+
+
+def TransformerLM_init(cfg):
+    from determined_tpu.models.transformer import TransformerLM
+
+    return TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+
+
 def _hbm_bytes(text: str) -> int:
     """Bytes the program's top-level instructions move to and from HBM, by
     the optimized module's own layouts: an array whose layout names a memory
