@@ -80,10 +80,47 @@ class Rows:
     # a decode step's: the position of the token each lane consumes [b], -1 idle; the same, an idle lane at 0
     lane_positions: Optional[jax.Array] = None
     pos: Optional[jax.Array] = None
-    # the walk's: this chunk's index, the index of the walk's first, a token's place in its chunk [chunk]
+    # the walk's: this chunk's index among chunks of its width, the index the call's first chunk has there (or none
+    # has), a token's place in its chunk [chunk], and the narrow chunks it holds (a wide chunk: several)
     chunk: Any = 0
     first_chunk: Any = 0
     offsets: Optional[jax.Array] = None
+    parts: int = 1
+
+
+def _narrow_chunks(rows: Rows, step: Callable, carry: Any, xs: Tuple[jax.Array, ...], axes: Tuple[int, ...], out_axes: Tuple[int, ...]):
+    """``step(carry, rows, *xs) -> (carry, ys)`` over the narrow chunks of a walk's chunk, in order: what a layer
+    keeps of the past is written and read a narrow chunk at a time, as that chunk's own iteration would have, while
+    the products either side take the call's rows whole.  ``xs`` are cut along ``axes`` (the call's token axis in
+    each), the part's ``rows`` are those of a narrow chunk's own iteration, and ``ys`` come back side by side along
+    ``out_axes``.  A narrow chunk is its own one part: ``step`` is called on what came, and no operation is added.
+    A wide chunk's parts are a ``scan``: one loop a layer whatever the parts, its body the narrow iteration's."""
+    if rows.parts == 1:
+        return step(carry, rows, *xs)
+    parts, n = rows.parts, rows.offsets.shape[0] // rows.parts
+
+    def cut(a, axis):  # [.., parts * n, ..] -> [parts, .., n, ..]
+        axis %= a.ndim
+        return jnp.moveaxis(a.reshape(a.shape[:axis] + (parts, n) + a.shape[axis + 1:]), axis, 0)
+
+    def join(a, axis):  # and back
+        axis %= a.ndim - 1
+        a = jnp.moveaxis(a, 0, axis)
+        return a.reshape(a.shape[:axis] + (parts * n,) + a.shape[axis + 2:])
+
+    where = None if rows.where is None else tuple(cut(a, 1) for a in rows.where)
+
+    def body(carry, part):
+        i, positions, live, where, part_xs = part
+        part_rows = dataclasses.replace(
+            rows, positions=positions, live=live, where=where, chunk=rows.chunk * parts + i, first_chunk=None,
+            offsets=rows.offsets[:n], parts=1,
+        )
+        return step(carry, part_rows, *part_xs)
+
+    every = (jnp.arange(parts), cut(rows.positions, 0), cut(rows.live, 1), where, tuple(cut(x, axis) for x, axis in zip(xs, axes)))
+    carry, ys = jax.lax.scan(body, carry, every)
+    return carry, tuple(join(y, axis) for y, axis in zip(ys, out_axes))
 
 
 # -- K and V rows: the paged pool's and the window ring's ---------------------
@@ -104,14 +141,18 @@ def _pool_rows(x: jax.Array, lead: Tuple[int, ...]) -> jax.Array:
     return x.transpose(0, 2, 1, 3).reshape(*lead, x.shape[1] * x.shape[3])
 
 
-def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, attend, drop: bool = False):
+def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, attend, drop: bool = False, walk: bool = False):
     """The mixer of a kind that keeps K and V rows: q/k/v and rope, this call's
     rows into the kind's two leaves at ``where`` = (block, slot), each [b, s] (or
     [b] where s is 1; under ``drop`` a block id past the store's end drops the
     row: idle lanes, padding), then ``attend(q, k, v, cache, j)`` against the
     store that now holds them, with q [b, n_heads, s, head_dim] and this call's
-    own k, v [b, kv_heads, s, head_dim], to [b, n_heads, s, head_dim]."""
-    (phys, slots), (keys, vals) = where, kind.leaves
+    own k, v [b, kv_heads, s, head_dim], to [b, n_heads, s, head_dim].
+    ``walk``: ``attend`` is ``rows -> attend`` of a narrow chunk's rows, and a
+    wide chunk's narrow chunks are written and attend one after the other
+    (``_narrow_chunks``: a ring holds ONE narrow chunk more than its window),
+    under the one projection either side."""
+    keys, vals = kind.leaves
     rope, dt, mode = cfg.rope(kind.layer_types[0]), cfg.dtype, "drop" if drop else None
     # a cache of two kinds: the device trace tells them apart
     scope = "serve.attn.window" if kind.layer_types[0] == SLIDING else "serve.attn.full"
@@ -121,15 +162,20 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
         with jax.named_scope("serve.attn.qkv"):
             q, k, v = _attn_proj(p, _times(h, cfg.attention_in_multiplier), dt)
             q, k = _rope(q, rows.positions, rope), _rope(_times(k, cfg.key_multiplier), rows.positions, rope)
-        with jax.named_scope("serve.kv.write"):
-            cache = {
-                **cache,
-                keys: cache[keys].at[j, phys, slots].set(_pool_rows(k, phys.shape), mode=mode),
-                vals: cache[vals].at[j, phys, slots].set(_pool_rows(v, phys.shape), mode=mode),
-            }
-        with jax.named_scope("serve.attn.attend"):  # whichever form the entry point picked
-            with split():
-                att = attend(q, k, v, cache, j)
+
+        def chunk(store, rows, q, k, v, phys, slots):
+            with jax.named_scope("serve.kv.write"):
+                store = (
+                    store[0].at[j, phys, slots].set(_pool_rows(k, phys.shape), mode=mode),
+                    store[1].at[j, phys, slots].set(_pool_rows(v, phys.shape), mode=mode),
+                )
+            with jax.named_scope("serve.attn.attend"):  # whichever form the entry point picked
+                with split():
+                    return store, ((attend(rows) if walk else attend)(q, k, v, {**cache, keys: store[0], vals: store[1]}, j),)
+
+        store, (att,) = _narrow_chunks(rows, chunk, (cache[keys], cache[vals]), (q, k, v, *where), (2, 2, 2, 1, 1), (2,))
+        cache = {**cache, keys: store[0], vals: store[1]}
+        with jax.named_scope("serve.attn.attend"):
             att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
         with jax.named_scope("serve.attn.out"):
             out = jnp.einsum("bshk,hkD->bsD", att, p["wo"]["kernel"].astype(dt))
@@ -296,7 +342,8 @@ def _ring_walk(cfg: TransformerConfig, cache: Dict[str, jax.Array], lanes: jax.A
     in.  A chunk's rows go to the slots of their positions, and its queries read
     the ring back to ``window - 1`` positions before each of them: the ring is
     one chunk longer than the window, so no row a query of the chunk still sees
-    is overwritten."""
+    is overwritten; a wide chunk's narrow chunks are written and read one after
+    the other, as their own iterations would have."""
     _, store, block_size, _ = cache[WINDOW_RING.leaves[0]].shape
     ring_blocks = window_ring_blocks(cfg, block_size, chunk_tokens)
     if store % ring_blocks:
@@ -310,10 +357,10 @@ def _ring_walk(cfg: TransformerConfig, cache: Dict[str, jax.Array], lanes: jax.A
 
     def at_chunk(rows: Rows):
         with jax.named_scope("serve.kv.write"):  # rows outside [start, len) are dropped
-            cols = (rows.chunk * (chunk_tokens // block_size) + rows.offsets // block_size) % ring_blocks
+            cols = (rows.chunk * (rows.parts * chunk_tokens // block_size) + rows.offsets // block_size) % ring_blocks
             phys = jnp.where(rows.live, first[:, None] + cols[None, :], store)
-        attend = _attend_chunk(cfg, WINDOW_RING, rings, rows.chunk, cfg.sliding_window)
-        return _kv_mixer(cfg, WINDOW_RING, rows, (phys, rows.where[1]), attend, drop=True)
+        attend = lambda rows: _attend_chunk(cfg, WINDOW_RING, rings, rows.chunk, cfg.sliding_window)  # noqa: E731
+        return _kv_mixer(cfg, WINDOW_RING, rows, (phys, rows.where[1]), attend, drop=True, walk=True)
 
     return at_chunk
 
@@ -408,7 +455,8 @@ def _indexer(cfg: TransformerConfig, rows: Rows, score, keys: Optional[int] = No
     a query may see, those up to its own: the picks are positions of the lane's
     own context.  What a layer hands on is the picks themselves (a decode step's
     paged form turns them into places in the pool) or, ``as_mask``, the same
-    selection as a mask ``[b, s, keys]``, made once for the layers that share it."""
+    selection as a mask ``[b, s, keys]``, made once for the layers that share it
+    (of the walk's wide chunk a narrow chunk's queries at a time: ``_narrow_chunks``)."""
     (phys, slots), leaf = rows.where, PAGED_INDEXED.leaves[1]
     rope = cfg.rope(FULL)
     keys = keys or rows.block_tables.shape[1] * rows.block_size
@@ -422,10 +470,14 @@ def _indexer(cfg: TransformerConfig, rows: Rows, score, keys: Optional[int] = No
             q, w, k = _index_project(cfg, p, c_q, h, rows.positions, rope)
         with jax.named_scope("serve.dsa.write"):
             cache = {**cache, leaf: cache[leaf].at[r, phys, slots].set(k.reshape(*phys.shape, -1))}
-        with jax.named_scope("serve.dsa.index"):  # the score pass alone: what its roofline share times
-            scores = score(q, w, k, cache[leaf], r)
-        with jax.named_scope("serve.dsa.topk"):
-            return cache, (index_topk_mask if as_mask else index_topk)(scores, seen, cfg.index_topk)
+
+        def chunk(_, rows, q, w, seen):  # a wide chunk's scores and its mask a narrow chunk's queries at a time: [b, chunk, keys]
+            with jax.named_scope("serve.dsa.index"):  # the score pass alone: what its roofline share times
+                scores = score(q, w, k, cache[leaf], r)
+            with jax.named_scope("serve.dsa.topk"):
+                return None, ((index_topk_mask if as_mask else index_topk)(scores, seen, cfg.index_topk),)
+
+        return cache, _narrow_chunks(rows, chunk, None, (q, w, seen), (1, 1, -2), (1,))[1][0]  # a decode step's picks: one part
 
     return select
 
@@ -475,21 +527,28 @@ def _latent_attend_paged(cfg: TransformerConfig, block_tables: jax.Array, positi
     return attend
 
 
-def _latent_attend_chunk(cfg: TransformerConfig, block_tables: jax.Array, chunk: jax.Array):
+def _latent_attend_chunk(cfg: TransformerConfig, rows: Rows):
     """The prefill walk's read (``_attend_chunk``) in the latent space: every
     head's queries ``[q_lat | q_rope | zeros]`` against the pool's rows, whose
     first ``kv_lora_rank`` columns are the values; under ``mask`` (an indexer's
-    picks, ``[b, s, keys]``) a query sees the keys it marks alone."""
+    picks, ``[b, s, keys]``) a query sees the keys it marks alone.  The call's
+    rows are all in the pool by then; each narrow chunk of them reads the tiles
+    up to its own end (``_narrow_chunks``)."""
     (leaf,) = PAGED_LATENT.leaves
 
     def attend(q_nope, q_rope, c_kv, k_r, wkv_b, cache, j, mask=None):
         q_lat, w_v = _latent_split(cfg, wkv_b, q_nope)
         q = jnp.concatenate([q_lat, q_rope], axis=-1)
         q = jnp.pad(q, ((0, 0),) * 3 + ((0, cache[leaf].shape[-1] - q.shape[-1]),))
+
+        def chunk(_, rows, q, mask=None):
+            return None, (paged_chunk_attention(
+                q[:, None], cache[leaf], None, j, rows.block_tables, rows.chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank, mask=mask
+            ),)
+
         with jax.named_scope("serve.mla.attend"):
-            out = paged_chunk_attention(
-                q[:, None], cache[leaf], None, j, block_tables, chunk, scale=cfg.attn_scale, value_dim=cfg.kv_lora_rank, mask=mask
-            )
+            xs, axes = ((q,), (2,)) if mask is None else ((q, mask), (2, 1))
+            _, (out,) = _narrow_chunks(rows, chunk, None, xs, axes, (3,))
         return jnp.einsum("bhsc,chv->bshv", out[:, 0].astype(cfg.dtype), w_v)
 
     return attend
@@ -700,7 +759,9 @@ def _ssm_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     (nothing, in the walk's first chunk: a sequence starts from a zeroed slot
     and a zeroed tail), and into them: the prefill walk's chunk, and the wide
     prefill as one chunk.  A token that does not exist (``rows.live``: the
-    padded end of a prompt) advances neither."""
+    padded end of a prompt) advances neither.  The convolution takes the call's
+    rows whole; the scan takes a wide chunk's narrow chunks one after the other,
+    the state in hand, and the slots are read and written once a chunk."""
     state_leaf, tail_leaf = SSM_SLOT.leaves
     lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
     fresh = rows.chunk == rows.first_chunk
@@ -715,7 +776,12 @@ def _ssm_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
 
     def scan(x, b, c, dt, a, skip, cache, j):
         state = jnp.where(fresh, 0.0, cache[state_leaf][j, lanes])
-        y, state = ssm_chunk(x, b, c, dt, a, skip, state, rows.live)
+
+        def chunk(state, rows, x, b, c, dt):
+            y, state = ssm_chunk(x, b, c, dt, a, skip, state, rows.live)
+            return state, (y,)
+
+        state, (y,) = _narrow_chunks(rows, chunk, state, (x, b, c, dt), (1, 1, 1, 1), (1,))
         return y, {**cache, state_leaf: cache[state_leaf].at[j, lanes].set(state)}
 
     return _ssm_mixer(cfg, conv, scan)
@@ -812,6 +878,10 @@ class CacheKind:
     #: ``setup(cfg, sizes)``: what a kind of the model adds to the ``serve.setup.kv_pool`` span
     report: Callable = lambda cfg, sizes, live, gauges=None: {}
     setup: Callable = lambda cfg, sizes: {}
+    #: the prefill walk may take its layers a WIDE chunk at a time (``models/serving.py PREFILL_WIDE_TOKENS``), what
+    #: the kind keeps written and read a narrow chunk at a time inside it (``_narrow_chunks``); False: a model with
+    #: such a layer is walked in narrow chunks alone, whatever its prompts' length
+    wide_walk: bool = True
     #: its layers are those of a model whose attention reads the keys an indexer picks (``indexer_types``), or is not;
     #: such layers hand their picks on: ``mix(p, x, h, cache, j, handed) -> (x, cache, handed)``
     indexed: bool = False
@@ -846,7 +916,7 @@ PAGED_KV = CacheKind(
     step=lambda cfg, rows, cache: _kv_mixer(
         cfg, PAGED_KV, rows, rows.where, _attend_paged(cfg, PAGED_KV, rows.block_tables, rows.lane_positions)),
     walk=_every_chunk(lambda cfg, rows: _kv_mixer(
-        cfg, PAGED_KV, rows, rows.where, _attend_chunk(cfg, PAGED_KV, rows.block_tables, rows.chunk))),
+        cfg, PAGED_KV, rows, rows.where, lambda rows: _attend_chunk(cfg, PAGED_KV, rows.block_tables, rows.chunk), walk=True)),
     table=lambda cfg, rows, cache: _kv_mixer(
         cfg, PAGED_KV, rows, rows.where, _attend_table(cfg, rows.block_tables, _table_mask(rows))),
     wide=lambda cfg, rows, cache: _kv_mixer(cfg, PAGED_KV, rows, rows.where, _attend_local),
@@ -858,7 +928,7 @@ PAGED_LATENT = CacheKind(
     name="paged_latent", layer_types=(FULL,), latent=True, holds=BLOCKS,
     leaves=("kv",), shapes=_paged_shapes, dtypes=_compute_dtype(1),
     step=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_paged(cfg, rows.block_tables, rows.lane_positions)),
-    walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk))),
+    walk=_every_chunk(lambda cfg, rows: _latent_mixer(cfg, rows, _latent_attend_chunk(cfg, rows))),
     table=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows))),
     wide=lambda cfg, rows, cache: _latent_mixer(cfg, rows, _latent_attend_local(cfg)),
     walked=lambda cfg: (cfg.paged_layers, None),
@@ -875,8 +945,7 @@ PAGED_INDEXED = CacheKind(
         cfg, rows, _latent_attend_paged(cfg, rows.block_tables, rows.lane_positions),
         _indexer(cfg, rows, _index_paged(rows), as_mask=False)),
     walk=_every_chunk(lambda cfg, rows: _latent_mixer(
-        cfg, rows, _latent_attend_chunk(cfg, rows.block_tables, rows.chunk),
-        _indexer(cfg, rows, _index_gathered(rows)))),
+        cfg, rows, _latent_attend_chunk(cfg, rows), _indexer(cfg, rows, _index_gathered(rows)))),
     table=lambda cfg, rows, cache: _latent_mixer(
         cfg, rows, _latent_attend_table(cfg, rows.block_tables, _table_mask(rows)),
         _indexer(cfg, rows, _index_gathered(rows))),
@@ -902,6 +971,10 @@ STATE_SLOT = CacheKind(
         "nobody keeps). Set prefix_cache: false"
     ),
     step=_state_step, walk=_every_chunk(_state_chunk), table=_state_step, wide=_state_chunk,
+    # a chunk of the walk is its own arithmetic here (2.4 ms + 32 us a token on a v5e: a wide chunk took 17 % off a walk),
+    # and the chunk kernel is most of the prefill program: a second loop with its own five copies of it took 13 s longer
+    # to load, which a replica pays at every start (PERF.md section 5 "PR 62")
+    wide_walk=False,
     # the lanes whose state the step updated, and the bytes of state those hold over the retention layers
     counters=("serve.state.live_lanes", "serve.state.bytes"), count=_state_count,
     gauges=("serve.state.pending_rows",), gauge=_state_gauge,

@@ -27,6 +27,7 @@ the scatter shape static without masking arithmetic inside the kernel.
 
 from __future__ import annotations
 
+import functools
 import math
 import types
 from typing import Any, Callable, Dict, Mapping, Optional, Tuple
@@ -331,22 +332,56 @@ def transformer_decode(
     return logits, cache
 
 
-#: tokens an iteration of the prefill walk aims for.  An iteration sweeps every
-#: weight once, so its time is the sweep's until the chunk's own arithmetic
-#: passes it: at 256 tokens the sweep still bounds it at InternLM2's and at
-#: DeepSeek-V3's widths (PERF.md section 5), and a prompt pays for at most 255
-#: tokens it did not ask for.
+#: tokens a NARROW iteration of the prefill walk aims for, the width of a
+#: prompt's tail.  An iteration sweeps every weight once, and that sweep is a
+#: cost a chunk whatever its tokens: at 256 tokens it is 41-47 % of a chunk's
+#: time at the widths of the models with routed experts (PERF.md section 5
+#: "PR 62"), and a prompt pays for at most 255 tokens it did not ask for.
 PREFILL_CHUNK_TOKENS = 256
+#: tokens a WIDE iteration aims for: whole narrow chunks, the width of a long
+#: prompt's body, one sweep for four times the tokens.  Chosen on a v5e from the
+#: walk alone at 256 / 512 / 1,024 / 2,048 (PERF.md section 5 "PR 62"): a chunk
+#: of n tokens takes 5.3 ms + 29 us a token at Nemotron-3-Super's widths (12.8 /
+#: 20.4 / 35.4 / 63.7 ms) and ~7.5 ms + 31 us at Command A+'s (15.9 / 25.2 / 41.6
+#: / 75.4), so 1,024 tokens is 51 -> 35 and 64 -> 42 ms of a prompt's every 1,024:
+#: the part a chunk costs whatever its tokens is down to a sixth of it, four
+#: fifths of all a single chunk could save.  2,048 takes 8-10 % more off the
+#: longest prompts, leaves a prompt under 2,048 tokens (a third of the
+#: Nemotron cell's) without a wide chunk and its tail up to seven narrow ones
+#: (a 5,120-token Command A+ prompt: 199 ms at 1,024, 208 at 2,048), and holds
+#: 0.26-0.31 GB more scratch.
+PREFILL_WIDE_TOKENS = 1024
 
 
 def prefill_chunk_tokens(block_size: int, prompt_tokens: int) -> int:
-    """Tokens a chunk of :func:`transformer_prefill_chunked`, from the shapes:
-    ``PREFILL_CHUNK_TOKENS`` in whole blocks and whole 128-wide tiles, or the
-    longest prompt in whole blocks where that is shorter.  A caller pads its
+    """Tokens a narrow chunk of :func:`transformer_prefill_chunked`, from the
+    shapes: ``PREFILL_CHUNK_TOKENS`` in whole blocks and whole 128-wide tiles, or
+    the longest prompt in whole blocks where that is shorter.  A caller pads its
     prompts to a multiple of it."""
     unit = math.lcm(block_size, 128)
     chunk = -(-PREFILL_CHUNK_TOKENS // unit) * unit
     return min(chunk, -(-prompt_tokens // block_size) * block_size)
+
+
+def prefill_wide_chunks(cfg: TransformerConfig, chunk: int, prompt_tokens: int) -> int:
+    """Narrow chunks of ``chunk`` tokens a wide chunk of the walk holds
+    (``PREFILL_WIDE_TOKENS`` in whole narrow chunks), from the shapes; 1 where the
+    walk has no wide loop: one is built only where it can engage, for prompts
+    padded to ``prompt_tokens`` that hold at least eight wide chunks, and where
+    every cache kind of the model's layers takes one (``CacheKind.wide_walk``)."""
+    per_wide = max(PREFILL_WIDE_TOKENS // chunk, 1)
+    takes = all(kind.wide_walk for kind in cache_kinds(cfg))
+    return per_wide if takes and prompt_tokens >= 8 * per_wide * chunk else 1
+
+
+def prefill_walk_chunks(per_wide: int, first: int, end: int) -> Tuple[int, int]:
+    """(wide, narrow) iterations the walk makes over the narrow chunks ``first ..
+    end - 1`` of a prompt, on the host: from chunk 0 each whole group of
+    ``per_wide`` is one wide chunk and the ``per_wide - 1`` at most after the last
+    are walked one by one; from a later chunk (a warm start) all are.  The walk's
+    own bounds, stated for the engine's counts."""
+    wide = end // per_wide if per_wide > 1 and first == 0 else 0
+    return wide, end - first - wide * per_wide
 
 
 def transformer_prefill_chunked(
@@ -358,7 +393,7 @@ def transformer_prefill_chunked(
     the serving engine's one prefill program, for a cold prompt (``start`` 0)
     and for the un-cached suffix of one whose prefix is cached alike.
 
-    ``tokens`` [B, S] is the FULL prompt padded to a multiple of the chunk
+    ``tokens`` [B, S] is the FULL prompt padded to a multiple of the narrow chunk
     (``prefill_chunk_tokens(block_size, S)``); ``start_lens`` [B] how many
     leading tokens already sit in cache blocks mapped into ``block_tables``
     (block-aligned by construction: only full blocks are shared);
@@ -366,17 +401,41 @@ def transformer_prefill_chunked(
     the logits at ``prompt_len - 1`` each lane samples its first token from,
     and the updated cache).
 
-    The walk is one chunk of C tokens an iteration of a dynamic-trip-count
-    ``fori_loop``, chunks at the absolute positions ``[c * C, (c + 1) * C)``
-    for ``c`` in ``start // C .. ceil(len / C)``: the compute and the single
-    compiled trace follow the tokens ASKED, not the padded width, and a
+    The walk covers the narrow chunks of C tokens at the absolute positions
+    ``[c * C, (c + 1) * C)`` for ``c`` in ``start // C .. ceil(len / C)``, an
+    iteration of a dynamic-trip-count ``fori_loop`` each: the compute and the
+    single compiled trace follow the tokens ASKED, not the padded width, and a
     70%-shared system prompt pays for its unique tail only.  An iteration
-    reads every weight once for C tokens.  Queries attend against keys READ
+    reads every weight once for its tokens, and at C tokens that read is what it
+    costs.  So where S holds at least eight wide chunks
+    (:func:`prefill_wide_chunks`) the walk has TWO widths: of a call that starts
+    at 0, every whole group of ``PREFILL_WIDE_TOKENS / C`` narrow chunks is ONE
+    iteration of a second loop, one read of the weights for four times the
+    tokens, and only what is left (at most three narrow chunks after the last
+    wide one) is walked narrow: a prompt computes the tokens it computed, in
+    ``ceil(P / 1024) + 3`` sweeps at most where it took ``ceil(P / 256)``.  The
+    two loops run one after the other inside this one program (wide, narrow),
+    the cache their carry; a call from a warm start runs none of the first.
+    Inside a wide iteration what a layer keeps of the past is still
+    written and read a narrow chunk at a time (``cache_kinds._narrow_chunks``: the attention's
+    tiles, a ring one narrow chunk longer than its window, the quadratic forms of
+    a state's chunk, an indexer's mask), so a wide iteration computes, row for
+    row, what its narrow chunks would have.  Queries attend against keys READ
     FROM THE CACHE up to the chunk's end (prefix blocks written by whoever
     prefilled them first, the chunk's own written just before attending),
     masked ``k_pos <= q_pos``, which makes a warm start and a cold ``start=0``
     run of the same prompt bitwise identical, wherever ``start`` falls in its
-    chunk: the parity the prefix-cache admission tests pin.  Positions outside
+    chunk, as far as both compute a row under the same width: the parity the
+    prefix-cache admission tests pin (one width: bit for bit).  Across widths
+    a product's rows are the same numbers to the compute dtype's rounding, not to
+    the bit: the compiler tiles a product of 1,024 rows otherwise than one of
+    256 (on a v5e the logits of a prompt walked two-width differ from the
+    narrow walk's by 0.01-0.2 in bfloat16, PERF.md section 5 "PR 62").  So a
+    warm start is walked narrow throughout, every row of it under ONE width
+    whatever the cached prefix, and the program holds two loops, not three
+    (a third, for the narrow chunks before a warm start's first wide one, was a
+    third of a program's size again and of the time a replica takes to load it).
+    Positions outside
     ``[start, len)`` write to scratch block 0 and take no expert's rows;
     since keys come from the cache rather than the local projection, garbage
     padding columns cannot leak into valid ones.  The head runs once, on the
@@ -387,8 +446,8 @@ def transformer_prefill_chunked(
     of the decode lane it will run in: ``lanes`` [B] (absent: row ``b`` is lane
     ``b``).  Such a prompt starts at 0 (no block holds what such a layer keeps),
     and its walk computes every chunk.  A model of such layers alone has no pool
-    to take the block size from: ``chunk_tokens`` states the chunk (with a pool
-    it follows from the shapes, whatever is passed).
+    to take the block size from: ``chunk_tokens`` states the narrow chunk (with a
+    pool it follows from the shapes, whatever is passed).
     """
     _check_decodable(cfg)
     kinds = cache_kinds(cfg)
@@ -406,29 +465,36 @@ def transformer_prefill_chunked(
             f"chunked prefill needs tokens padded to whole chunks (got S={s}, "
             f"chunk={chunk}, block_size={block_size})"
         )
+    per_wide = prefill_wide_chunks(cfg, chunk, s)
     lanes = jnp.arange(b, dtype=jnp.int32) if lanes is None else lanes.astype(jnp.int32)
-    blocks, t = chunk // block_size, block_tables.shape[1]
+    t = block_tables.shape[1]
     at_chunk = {kind.name: kind.walk(cfg, cache, lanes, chunk) for kind in kinds}  # what a kind prepares once a call
-    with jax.named_scope("serve.walk"):  # the trip count; the loop below is under it too, around its layers' scopes
+    with jax.named_scope("serve.walk"):  # the trip counts; the loops below are under it too, around their layers' scopes
         c_lo = jnp.min(start_lens) // chunk
         c_hi = (jnp.max(prompt_lens) + chunk - 1) // chunk
         offsets = jnp.arange(chunk)
+        if per_wide > 1:  # a call from 0: its whole groups of narrow chunks, [0, w_hi) in wide chunks; a warm start: none
+            w_hi = jnp.where(c_lo == 0, c_hi // per_wide, 0)
+            wide_offsets = jnp.arange(per_wide * chunk)
 
-    def body(c, carry):
+    def body(parts, offsets, first_chunk, c, carry):
+        """One iteration over ``parts`` narrow chunks, ``c`` its index among the chunks of its own width."""
         cache, last = carry
+        width = parts * chunk
+        blocks = width // block_size
         with jax.named_scope("serve.embed"):
-            toks = jax.lax.dynamic_slice(tokens, (0, c * chunk), (b, chunk))
-        p = c * chunk + offsets  # absolute positions [chunk]
+            toks = jax.lax.dynamic_slice(tokens, (0, c * width), (b, width))
+        p = c * width + offsets  # absolute positions [width]
         with jax.named_scope("serve.kv.write"):
-            valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, chunk]
+            valid = (p[None, :] >= start_lens[:, None]) & (p[None, :] < prompt_lens[:, None])  # [b, width]
         where = None
         if paged:
             with jax.named_scope("serve.kv.write"):
                 # a padded prompt may be wider than the table: those columns hold no valid row
                 cols = jnp.minimum(c * blocks + offsets // block_size, t - 1)
                 phys = jnp.where(valid, jnp.take(block_tables, cols, axis=1), 0)
-                where = (phys, jnp.broadcast_to((offsets % block_size)[None, :], (b, chunk)))
-        rows = Rows(p, block_tables, valid, where, block_size, lanes, chunk=c, first_chunk=c_lo, offsets=offsets)
+                where = (phys, jnp.broadcast_to((offsets % block_size)[None, :], (b, width)))
+        rows = Rows(p, block_tables, valid, where, block_size, lanes, chunk=c, first_chunk=first_chunk, offsets=offsets, parts=parts)
         mixers = {name: at(rows) for name, at in at_chunk.items()}
         # a leaf stored wider than the compute dtype is read as it lies and
         # converted on its way into each product, every iteration.  The
@@ -443,11 +509,17 @@ def transformer_prefill_chunked(
         x = _times(_embed_rows(params, toks, cfg.dtype), cfg.embedding_multiplier)
         x, cache, _ = _serve_layers(cfg, layers, x, mixers, cache, valid if cfg.moe_experts else None)
         with jax.named_scope("serve.head"):  # the one row the head will read
-            sel = prompt_lens - 1 - c * chunk  # [b]
-            row = jnp.take_along_axis(x, jnp.clip(sel, 0, chunk - 1)[:, None, None], axis=1)
-            return cache, jnp.where(((sel >= 0) & (sel < chunk))[:, None, None], row, last)
+            sel = prompt_lens - 1 - c * width  # [b]
+            row = jnp.take_along_axis(x, jnp.clip(sel, 0, width - 1)[:, None, None], axis=1)
+            return cache, jnp.where(((sel >= 0) & (sel < width))[:, None, None], row, last)
 
+    # ``first_chunk``: the index the CALL's first chunk has among a loop's chunks (a kind that a request holds by its
+    # lane starts from an empty store there, and nowhere else): wide chunk 0 where any runs, else narrow chunk ``c_lo``
     with jax.named_scope("serve.walk"):
-        init = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
-        cache, last = jax.lax.fori_loop(c_lo, c_hi, body, init)
+        carry = (cache, jnp.zeros((b, 1, cfg.d_model), cfg.dtype))
+        narrow_lo = c_lo
+        if per_wide > 1:
+            carry = jax.lax.fori_loop(0, w_hi, functools.partial(body, per_wide, wide_offsets, 0), carry)
+            narrow_lo = jnp.maximum(c_lo, w_hi * per_wide)
+        cache, last = jax.lax.fori_loop(narrow_lo, c_hi, functools.partial(body, 1, offsets, c_lo), carry)
     return _head(cfg, params, last, row=0), cache

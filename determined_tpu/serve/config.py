@@ -10,7 +10,7 @@ same compiled program.  ``docs/serving.md`` explains how to size the cache
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 from determined_tpu.config.experiment import InvalidExperimentConfig
 
@@ -117,9 +117,25 @@ class ServeConfig:
         return prefill_chunk_tokens(self.block_size, self.max_prompt_len)
 
     def prefill_chunks(self, prompt_tokens: int, cached_tokens: int = 0) -> int:
-        """Chunks the prefill of a prompt walks, past ``cached_tokens`` of it."""
+        """Narrow chunks the prefill of a prompt computes, past ``cached_tokens`` of it."""
         chunk = self.prefill_chunk
         return -(-prompt_tokens // chunk) - cached_tokens // chunk
+
+    def prefill_wide(self, model_cfg: Any) -> int:
+        """Narrow chunks a WIDE iteration of the walk of ``model_cfg`` takes at
+        once; 1 where the walk has no wide loop (``max_prompt_len`` too short
+        for one, or a layer of the model of a cache kind that takes none)."""
+        from determined_tpu.models.serving import prefill_wide_chunks
+
+        return prefill_wide_chunks(model_cfg, self.prefill_chunk, self.prefill_chunks(self.max_prompt_len) * self.prefill_chunk)
+
+    def prefill_walk(self, per_wide: int, prompt_tokens: int, cached_tokens: int = 0) -> Tuple[int, int]:
+        """(wide, narrow) iterations of the walk over those chunks, ``per_wide``
+        of them a wide one (``prefill_wide``): each sweeps the weights once."""
+        from determined_tpu.models.serving import prefill_walk_chunks
+
+        chunk = self.prefill_chunk
+        return prefill_walk_chunks(per_wide, cached_tokens // chunk, -(-prompt_tokens // chunk))
 
     @property
     def usable_blocks(self) -> int:

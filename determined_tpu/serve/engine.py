@@ -348,6 +348,9 @@ class DecodeKernels:
         #: the prefill's token width: the longest prompt in whole chunks
         #: (one trace; the walk's trip count follows each prompt)
         self._prompt_pad = serve_cfg.prefill_chunks(serve_cfg.max_prompt_len) * serve_cfg.prefill_chunk
+        #: narrow chunks a wide iteration of this model's walk takes at once (1:
+        #: its program has no wide loop): what the engine counts an admission's sweeps by
+        self.prefill_wide = serve_cfg.prefill_wide(model_cfg)
         sentinel = get_retrace_sentinel()
         # cold requests run it with start=0, warm requests from the chunk
         # of their first un-cached block; either way it is the SAME trace
@@ -597,6 +600,8 @@ class ServeEngine:
         #: cache held) and tokens they computed (whole chunks), cumulative
         self._prefill_tokens_asked = 0
         self._prefill_tokens_computed = 0
+        #: of those, the tokens computed in wide iterations of the walk
+        self._prefill_wide_tokens = 0
         #: what the decode steps of a model with expert layers counted,
         #: cumulative, by the counter's name (``/stats`` ``step_counters``)
         self._step_counters: Dict[str, float] = {}
@@ -835,6 +840,9 @@ class ServeEngine:
                 # beyond the prompts (1.0: every prompt ended on a chunk's edge)
                 "prefill_tokens_asked": self._prefill_tokens_asked,
                 "prefill_tokens_computed": self._prefill_tokens_computed,
+                # of those, the tokens computed under each of the walk's two widths
+                "prefill_wide_tokens": self._prefill_wide_tokens,
+                "prefill_narrow_tokens": self._prefill_tokens_computed - self._prefill_wide_tokens,
                 "errored": self._errored,
                 "http_5xx": self._http_5xx,
                 "latency_ms_avg": round(
@@ -997,10 +1005,15 @@ class ServeEngine:
         table = self._padded_table(blocks)
         # the lane is known before the prefill: a kind held by the lane writes its store
         lane = self.lanes.free_lane()
-        # the walk's trip count: whole chunks, from the one the first
-        # un-cached token lies in (0 cached when nothing matched)
-        chunks = self.cfg.prefill_chunks(len(req.prompt), cached_tokens)
-        computed = chunks * self.cfg.prefill_chunk
+        # the walk's trip counts: whole narrow chunks, from the one the first
+        # un-cached token lies in (0 cached when nothing matched), each whole
+        # aligned group of them one wide iteration where the walk has a wide loop
+        # (kernels wrapped from outside may not pass the attribute on: such a walk is counted narrow)
+        per_wide = getattr(self.kernels, "prefill_wide", 1)
+        wide, narrow = self.cfg.prefill_walk(per_wide, len(req.prompt), cached_tokens)
+        narrow_tokens = narrow * self.cfg.prefill_chunk
+        wide_tokens = wide * per_wide * self.cfg.prefill_chunk
+        chunks, computed = wide + narrow, wide_tokens + narrow_tokens
         t_prefill = mono()
         try:
             logits = self.kernels.prefill_suffix(req.prompt, table, cached_tokens, lane)
@@ -1014,6 +1027,7 @@ class ServeEngine:
                 {
                     "request": req.id, "step": step,
                     "cached_tokens": cached_tokens, "chunks": chunks, "computed_tokens": computed,
+                    "wide_tokens": wide_tokens, "narrow_tokens": narrow_tokens,
                 },
             )
         clock.to(ADMISSION_PREFILL, t_prefill)
@@ -1040,6 +1054,7 @@ class ServeEngine:
             self._tokens_generated += 1
             self._prefill_tokens_asked += len(req.prompt) - cached_tokens
             self._prefill_tokens_computed += computed
+            self._prefill_wide_tokens += wide_tokens
         if tracer.enabled:
             tracer.record_span(
                 "serve.first_sample", "serve", t_sample, t_first, {"request": req.id}

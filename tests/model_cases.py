@@ -193,6 +193,56 @@ def check_copy_schedule(run, pools, layer, tables, contexts, block, window=None)
 # -- compiling for a described chip (tests/test_tpu_compile.py, tests/test_tpu_compile_cells.py) --
 
 flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")
+#: the benchmark's served architectures at their tiny sizes (``tests/benchmark/tiny/<arch>.json``) -> the cache
+#: kinds their layers are of (``models/cache_kinds.py``): between them every kind of the table
+SERVED_ARCHS = {
+    "dense_decoder": ("paged_kv",),
+    "deepseek_mla_moe": ("paged_latent",),
+    "cohere2_moe": ("paged_kv", "window_ring"),
+    "power_retention": ("state_slot",),
+    "falcon_h1": ("paged_kv", "ssm_slot"),
+    "longcat_scmoe": ("paged_latent",),
+    "nemotron_h": ("paged_kv", "ssm_slot"),
+    "glm_moe_dsa": ("paged_indexed",),
+}
+
+
+def tiny_served(arch_name: str, **serve_engine):
+    """(model config, parameters, ServeConfig, the form) of a served architecture's tiny form, through its
+    adapter as the benchmark builds it; ``serve_engine`` replaces sizes of the form's engine."""
+    import json
+    import sys
+
+    from determined_tpu.serve.config import ServeConfig
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(repo, "benchmark")
+    if bench not in sys.path:  # an architecture's adapter imports the harness beside it
+        sys.path.insert(0, bench)
+    from benchlib import model as bench_model
+
+    with open(os.path.join(repo, "tests", "benchmark", "tiny", arch_name + ".json")) as f:
+        form = json.load(f)
+    arch = bench_model.load_file(os.path.join(bench, "archs", arch_name + ".py"), arch_name)
+    serve_cfg = ServeConfig(**{**form["serve_engine"], **serve_engine})
+    cfg = arch.model_config(form["config"], serve_cfg.max_seq_len)
+    return cfg, arch.init_params(cfg, 0), serve_cfg, form
+
+
+@pytest.fixture()
+def tracer():
+    """The process tracer, empty and on; left as a fresh process has it."""
+    from determined_tpu.observability import _tracer as tracer_mod, get_tracer
+
+    t = get_tracer()
+    t.reset()
+    t.configure(enabled=True)
+    yield t
+    t.close()  # stops a shipper a test left running, closes an export
+    t.configure(enabled=True, flush_interval=tracer_mod.DEFAULT_FLUSH_INTERVAL)
+    t.reset()
+
+
 adamw_mod = importlib.import_module("determined_tpu.ops.fused_adamw")
 paged_mod = importlib.import_module("determined_tpu.ops.paged_attention")
 grouped_mod = importlib.import_module("determined_tpu.ops.grouped_matmul")

@@ -4,10 +4,6 @@ benchmark's eight serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,34 +13,9 @@ from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_k
 from determined_tpu.models.serving import SERVE_COUNTERS, ZERO_PICKS, init_kv_cache, serve_counters
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import DecodeKernels, ServeEngine
+from tests.model_cases import SERVED_ARCHS, tiny_served
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "benchmark")
-if BENCH not in sys.path:  # an architecture's adapter imports the harness beside it
-    sys.path.insert(0, BENCH)
-
-#: the architecture's tiny form -> the kinds its layers are of
-ARCHS = {
-    "dense_decoder": ("paged_kv",),
-    "deepseek_mla_moe": ("paged_latent",),
-    "cohere2_moe": ("paged_kv", "window_ring"),
-    "power_retention": ("state_slot",),
-    "falcon_h1": ("paged_kv", "ssm_slot"),
-    "longcat_scmoe": ("paged_latent",),
-    "nemotron_h": ("paged_kv", "ssm_slot"),
-    "glm_moe_dsa": ("paged_indexed",),
-}
-
-
-def _tiny(arch_name):
-    with open(os.path.join(REPO, "tests", "benchmark", "tiny", arch_name + ".json")) as f:
-        form = json.load(f)
-    from benchlib import model as bench_model
-
-    arch = bench_model.load_file(os.path.join(BENCH, "archs", arch_name + ".py"), arch_name)
-    serve_cfg = ServeConfig(**form["serve_engine"])
-    cfg = arch.model_config(form["config"], serve_cfg.max_seq_len)
-    return cfg, arch.init_params(cfg, 0), serve_cfg, form
+ARCHS, _tiny = SERVED_ARCHS, tiny_served
 
 
 @pytest.mark.parametrize("arch_name", list(ARCHS))

@@ -381,8 +381,13 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
         assert not any("paged_" in n for n in set(scopes["serve.attn.window"]) & set(scopes["serve.attn.full"]))
         assert _arrays_with_dims(text, (32, 20480)) == [] and _arrays_with_dims(text, (32, 4352, 1024)) == []
     else:
-        assert _kernels(text) == 2 * 5
+        # 14,336 tokens hold eight wide chunks: the walk has its wide loop and its narrow one (prompts start at 0: no
+        # third), each with the experts' five kernels a layer; a wide chunk's attention still goes a narrow chunk's
+        # tile at a time (no [heads, 1024, 1024] scores, no ring written 1,024 rows at once), and no pool or ring is copied
+        assert _kernels(text) == 2 * 2 * 5
         assert _arrays_with_dims(text, (128, 256, 20480)) == [] and _arrays_with_dims(text, (256, 4352)) == []
+        assert _arrays_with_dims(text, (128, 1024, 1024)) == [] and _arrays_with_dims(text, (1024, 4352)) == []
+        assert not re.search(r"bf16\[(1,)?(24576|8704),16,1024\]\S* copy\(", text)
 
 
 # -- power-retention layers served from a state a lane: the Brumby cell's shapes --
@@ -469,7 +474,8 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_decode")}) == 2
         assert "conditional(" not in text
         assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
-    else:                                                                            # the chunk's pass over the state, a layer
+    else:   # the chunk's pass over the state, a layer: ONE loop although 22,528 tokens hold eight wide chunks (the kind takes no
+        # wide chunk, ``CacheKind.wide_walk``: a second loop's copies of this kernel are 13 s of a replica's start on a v5e)
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
 
 
