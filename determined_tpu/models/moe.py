@@ -349,24 +349,48 @@ def _held_experts(
 def _hidden(gate, up, scale):
     """(an expert's hidden values in float32: ``silu(gate) * up``, or
     ``relu(up)^2`` for the two-matrix expert, whose ``gate`` is None; the same
-    times a row's routing weight in the compute dtype: the down projection's input)."""
+    times a row's routing weight ``scale [rows, 1]`` in the compute dtype: the
+    down projection's input).  The ONE statement of the activation and of the
+    routing weight's place: the kernels' bodies below call it on a tile."""
     if gate is None:
         act = jnp.square(jax.nn.relu(up.astype(jnp.float32)))
     else:
         act = nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    return act, (act * scale[:, None]).astype(up.dtype)
+    return act, (act * scale).astype(up.dtype)
 
 
-def _expert_products(mm, xr, w_gate, w_up, w_down, scale):
+def _hidden_rows(gate, up, scale):
+    """The down projection's input alone, as a kernel's body returns it (``ops/expert_rows.py over_live_tiles``)."""
+    return (_hidden(gate, up, scale)[1],)
+
+
+def _hidden_grads(d_hidden, gate, up, scale):
+    """:func:`_hidden`'s derivative, the one statement of it: ``d_hidden``, the
+    gradient to the down projection's input -> (to ``gate`` unless it is None,
+    to ``up``, to ``scale``: float32 ``[rows, 1]``)."""
+    dt, d_hidden = up.dtype, d_hidden.astype(jnp.float32)
+    d_scale = jnp.sum(d_hidden * _hidden(gate, up, scale)[0], axis=-1, keepdims=True)
+    d_act = d_hidden * scale
+    if gate is None:  # the two-matrix expert: relu(up)^2
+        return (d_act * 2.0 * jax.nn.relu(up.astype(jnp.float32))).astype(dt), d_scale
+    g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
+    sig = jax.nn.sigmoid(g32)
+    return (d_act * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dt), (d_act * g32 * sig).astype(dt), d_scale
+
+
+def _expert_products(mm, xr, w_gate, w_up, w_down, scale, layout):
     """The ONE statement of the held experts on their rows ``xr [rows, d]``,
     under the training layer and the serving forward alike: ``mm(lhs, rhs)`` is
     the grouped product each of them runs.  Returns (the rows' outputs with
     the routing weight inside, the hidden products a backward pass keeps)."""
+    from determined_tpu.ops import expert_rows
+
     dt = xr.dtype
     gate = None if w_gate is None else mm(xr, w_gate.astype(dt))
     up = mm(xr, w_up.astype(dt))
     # the routing weight goes in before the down projection: the combine is then a plain sum
-    return mm(_hidden(gate, up, scale)[1], w_down.astype(dt)), gate, up
+    hidden, = expert_rows.over_live_tiles(_hidden_rows, (gate, up), scale, layout)
+    return mm(hidden, w_down.astype(dt)), gate, up
 
 
 def _held_experts_fwd(
@@ -381,7 +405,7 @@ def _held_experts_fwd(
         xr = expert_rows.rows_of_tokens(x, row_token, tile_rows, layout)
         scale = _row_weights(weights, row_pick, row_live, pick_row, pick_held, tile)
     with jax.named_scope("moe.experts"):
-        out, gate, up = _expert_products(lambda lhs, rhs: gm.gmm(lhs, rhs, layout), xr, w_gate, w_up, w_down, scale)
+        out, gate, up = _expert_products(lambda lhs, rhs: gm.gmm(lhs, rhs, layout), xr, w_gate, w_up, w_down, scale, layout)
     with jax.named_scope("moe.combine"):
         y = expert_rows.tokens_of_rows(out, row_token, tile_rows, layout, x.shape[0])
     # kept for the backward pass: the two hidden products.  The rows are
@@ -406,24 +430,18 @@ def _held_experts_bwd(rows, tile, res, d_y):
         xr = expert_rows.rows_of_tokens(x, row_token, tile_rows, layout)
         scale = _row_weights(weights, row_pick, row_live, pick_row, pick_held, tile)
     with jax.named_scope("moe.experts"):
-        act, hidden = _hidden(gate, up, scale)
+        # nothing between two grouped products visits a dead tile either (ops/expert_rows.py over_live_tiles)
+        hidden, = expert_rows.over_live_tiles(_hidden_rows, (gate, up), scale, layout)
         d_w_down = gm.tgmm(hidden, d_out, layout, count).astype(w_down.dtype)
-        d_hidden = gm.gmm(d_out, w_down.astype(dt), layout, transpose_rhs=True).astype(jnp.float32)
-        d_scale = jnp.sum(d_hidden * act, axis=-1)                      # [rows]
-        d_act = d_hidden * scale[:, None]
-        if gate is None:  # the two-matrix expert: relu(up)^2
-            d_w_gate = None
-            d_up = (d_act * 2.0 * jax.nn.relu(up.astype(jnp.float32))).astype(dt)
-        else:
-            g32, u32 = gate.astype(jnp.float32), up.astype(jnp.float32)
-            sig = jax.nn.sigmoid(g32)
-            d_gate = (d_act * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dt)
-            d_up = (d_act * g32 * sig).astype(dt)
-            d_w_gate = gm.tgmm(xr, d_gate, layout, count).astype(w_gate.dtype)
+        d_hidden = gm.gmm(d_out, w_down.astype(dt), layout, transpose_rhs=True)
+        grads = expert_rows.over_live_tiles(_hidden_grads, (d_hidden, gate, up), scale, layout, sums=True)
+        d_up, d_scale = grads[-2:]
+        d_w_gate = d_xr = None
+        if gate is not None:  # the gated expert.  Its product to the rows first: the other is added into it where it lies
+            d_w_gate = gm.tgmm(xr, grads[0], layout, count).astype(w_gate.dtype)
+            d_xr = gm.gmm(grads[0], w_gate.astype(dt), layout, transpose_rhs=True)
         d_w_up = gm.tgmm(xr, d_up, layout, count).astype(w_up.dtype)
-        d_xr = gm.gmm(d_up, w_up.astype(dt), layout, transpose_rhs=True) if gate is None else gm.gmm(
-            d_gate, w_gate.astype(dt), layout, transpose_rhs=True
-        ) + gm.gmm(d_up, w_up.astype(dt), layout, transpose_rhs=True)
+        d_xr = gm.gmm(d_up, w_up.astype(dt), layout, transpose_rhs=True, add=d_xr)
     with jax.named_scope("moe.dispatch"):
         d_x = expert_rows.tokens_of_rows(d_xr, row_token, tile_rows, layout, x.shape[0]).astype(dt)
     with jax.named_scope("moe.combine"):
@@ -449,9 +467,11 @@ class RoutedExperts(nn.Module):
     One algorithm for every expert count and top-k; the tile of the grouped
     product follows the rows an expert can expect.  ``sow``s
     ``intermediates/picks [T, k]`` (the experts each token chose),
-    ``intermediates/load [count]`` (picks that landed on each held expert) and
+    ``intermediates/load [count]`` (picks that landed on each held expert),
     ``intermediates/live_rows`` (rows of the buffer in tiles a group owns: what
-    the kernels touch, the held picks and each group's padding to a tile).
+    the kernels touch, the held picks and each group's padding to a tile) and
+    ``intermediates/buffer_rows`` (all its rows: the worst case, which costs
+    memory and no time).
 
     Every router but ``"mlp"`` and ``"softmax_bias"`` renormalises its picks'
     weights to a constant sum: at ``top_k`` 1 the one weight is that constant, no
@@ -599,6 +619,7 @@ class RoutedExperts(nn.Module):
             rows = _sorted_rows(picks, first, count)
         self.sow("intermediates", "load", rows.load)
         self.sow("intermediates", "live_rows", rows.layout.live_tiles[0] * rows.layout.tile)
+        self.sow("intermediates", "buffer_rows", jnp.asarray(rows.layout.rows))
 
         xe = xf.astype(self.dtype)
         if self.latent_size:
@@ -804,7 +825,9 @@ def serve_routed_experts(cfg: Any, p: Any, x: jax.Array, live: Any = None) -> Tu
         row_token = rows.row_pick // weights.shape[1]
         xr = expert_rows.rows_of_tokens(xe, row_token, rows.tile_rows, layout)
         scale = _row_weights(weights, rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, layout.tile)
-        out, _, _ = _expert_products(lambda lhs, rhs: _gmm(lhs, rhs, *layout), xr, p.get("w_gate"), p["w_up"], p["w_down"], scale)
+        out, _, _ = _expert_products(
+            lambda lhs, rhs: _gmm(lhs, rhs, *layout), xr, p.get("w_gate"), p["w_up"], p["w_down"], scale, layout
+        )
         y = expert_rows.tokens_of_rows(out, row_token, rows.tile_rows, layout, xf.shape[0])
     if cfg.moe_latent_size:
         with jax.named_scope("serve.moe.latent"):

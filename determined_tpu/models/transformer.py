@@ -1853,8 +1853,8 @@ class LMTrial(JaxTrial):
     #: step metrics the Trainer also pushes as tracer counters at each report
     #: (train/_trainer.py): what the dropless expert layers saw
     step_counters = (
-        "moe.held_picks", "moe.picks", "moe.live_rows", "moe.expert_load_max", "moe.expert_load_mean",
-        "moe.pick_weight", "moe_aux_loss",
+        "moe.held_picks", "moe.picks", "moe.live_rows", "moe.buffer_rows", "moe.expert_load_max",
+        "moe.expert_load_mean", "moe.pick_weight", "moe_aux_loss",
     )
 
     @staticmethod
@@ -1862,17 +1862,18 @@ class LMTrial(JaxTrial):
         """``model.apply`` and, where the model has dropless experts, a
         step's expert load from what the layers ``sow`` (no second forward):
         picks that landed on a held expert, all picks, the rows of the buffer
-        the kernels touch (held picks and each group's padding to a tile), and
-        the fullest held expert against the mean, over the layers; under the
+        the kernels touch (held picks and each group's padding to a tile)
+        beside all its rows, and the fullest held expert against the mean,
+        over the layers; under the
         "mlp" router also the mean weight of a token's one pick (its
         probability: the router's gradient dies where it goes to 1 / experts or 1)."""
         if not model.cfg.moe_top_k:
             return model.apply(params, inputs, **kw), {}
         out, state = model.apply(params, inputs, mutable=["intermediates"], **kw)
         sown = jax.tree_util.tree_leaves_with_path(state["intermediates"])
-        load, live_rows = (
+        load, live_rows, buffer_rows = (
             jnp.stack([x for path, x in sown if name in jax.tree_util.keystr(path)]).astype(jnp.float32)
-            for name in ("load", "live_rows")
+            for name in ("load", "live_rows", "buffer_rows")
         )
         picks = load.shape[0] * inputs.size * model.cfg.moe_top_k
         weight = [x for path, x in sown if "pick_weight" in jax.tree_util.keystr(path)]
@@ -1881,6 +1882,7 @@ class LMTrial(JaxTrial):
             "moe.held_picks": jnp.sum(load),
             "moe.picks": jnp.asarray(picks, jnp.float32),
             "moe.live_rows": jnp.sum(live_rows),
+            "moe.buffer_rows": jnp.sum(buffer_rows),
             "moe.expert_load_max": jnp.max(load),
             "moe.expert_load_mean": jnp.mean(load),
         }

@@ -30,7 +30,7 @@ why this file exists.  Off a TPU the kernels run in interpret mode.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,13 +103,16 @@ def tile_layout(
 # ---------------------------------------------------------------------------
 
 
-def _gmm_kernel(tile_group, live_tiles, lhs_ref, rhs_ref, out_ref, *, transpose_rhs: bool):
+def _gmm_kernel(tile_group, live_tiles, lhs_ref, rhs_ref, *refs, transpose_rhs: bool):
+    *add_ref, out_ref = refs  # with a tile to add to: it lies where the result goes
+
     @pl.when(pl.program_id(0) < live_tiles[0])
     def _compute():
         contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
-        out_ref[...] = jax.lax.dot_general(
-            lhs_ref[...], rhs_ref[0], contract, preferred_element_type=jnp.float32
-        ).astype(out_ref.dtype)
+        product = jax.lax.dot_general(lhs_ref[...], rhs_ref[0], contract, preferred_element_type=jnp.float32)
+        for ref in add_ref:
+            product += ref[...].astype(jnp.float32)
+        out_ref[...] = product.astype(out_ref.dtype)
 
 
 def _tgmm_kernel(tile_group, live_tiles, lhs_ref, grad_ref, out_ref):
@@ -145,27 +148,34 @@ def _params() -> pltpu.CompilerParams:
 
 
 def gmm(
-    lhs: jax.Array, rhs: jax.Array, layout: TileLayout, *, transpose_rhs: bool = False
+    lhs: jax.Array, rhs: jax.Array, layout: TileLayout, *, transpose_rhs: bool = False,
+    add: Optional[jax.Array] = None,
 ) -> jax.Array:
     """``lhs [rows, K] @ rhs[group] [K, N]`` (``transpose_rhs``: ``rhs [G, N,
     K]``, contracted over its last dim) -> ``[rows, N]`` in ``lhs``'s dtype.
+    ``add [rows, N]`` (another product's result): the product is added to it in
+    float32, tile by live tile, and the result takes its place in memory: the
+    sum of two products costs no pass of its own.
     Rows of dead tiles are left as they are in memory: never read them."""
     rows, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     assert rows == layout.rows and rhs.shape[2 if transpose_rhs else 1] == k
+    assert add is None or (add.shape, add.dtype) == ((rows, n), lhs.dtype)
+    added = [] if add is None else [add]
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows // layout.tile,),
-            in_specs=[_row_tile(layout.tile, k), _group_block(*rhs.shape[1:])],
+            in_specs=[_row_tile(layout.tile, k), _group_block(*rhs.shape[1:])] + [_row_tile(layout.tile, n)] * len(added),
             out_specs=_row_tile(layout.tile, n),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
+        input_output_aliases={4: 0} if added else {},   # operands are counted from the two prefetched tables
         compiler_params=_params(),
         interpret=kernel_form.interpreted_off_chip(),
         name="moe_gmm",
-    )(layout.tile_group, layout.live_tiles, lhs, rhs)
+    )(layout.tile_group, layout.live_tiles, lhs, rhs, *added)
 
 
 def tgmm(lhs: jax.Array, grads: jax.Array, layout: TileLayout, groups: int) -> jax.Array:

@@ -25,7 +25,7 @@ reach it through the module and never import the function by name.
 The unit a kernel is written in is **a Mosaic call inside a jitted function of
 its own, the layer it works on an argument** (``_paged_attention``,
 ``_paged_latent``, ``_ssm_decode``, ``_retention_decode``,
-``_chunk_state_pallas``, ``ops/expert_rows.py``'s two, the flash programs),
+``_chunk_state_pallas``, ``ops/expert_rows.py``'s three, the flash programs),
 never a bare ``pallas_call`` in its caller's trace, because
 
 1. its ``op_name`` survives: inside a jitted function of its own the call

@@ -365,6 +365,7 @@ def test_the_trainer_pushes_the_expert_layers_load_as_counters(tmp_path):
     # rows the kernels touch: whole tiles of 64 (192 rows at most over 4 held experts), never fewer than the held picks
     live = by_name["moe.live_rows"][-1]
     assert live % 64 == 0 and held <= live <= 2 * 2 * gm.buffer_rows(192, 4, 64)
+    assert by_name["moe.buffer_rows"][-1] == 2 * 2 * gm.buffer_rows(192, 4, 64)   # all its rows: the worst case
 
 
 # ---------------------------------------------------------------------------

@@ -154,6 +154,95 @@ def test_the_held_experts_and_their_gradients_are_the_same_bits_under_the_counte
     np.testing.assert_array_equal(np.asarray(scale), np.asarray(row_weights_by_gather(weights, want_rows.row_pick, want_rows.row_live)))
 
 
+def _held_experts_as_xla_stated_them(x, w_gate, w_up, w_down, weights, picks, first):
+    """``_held_experts`` as the layer stated it before any kernel (no buffer, no
+    layout): every pick's expert on the pick's token, the activation and the
+    routing weight as XLA's elementwise passes had them, a masked sum a token.
+    float32; its gradients are autodiff's."""
+    count = w_up.shape[0]
+    held = (picks >= first) & (picks < first + count)
+    e = jnp.clip(picks - first, 0, count - 1)                            # [T, k]
+    up = jnp.einsum("td,tkdf->tkf", x, w_up[e])
+    act = jnp.square(jax.nn.relu(up)) if w_gate is None else jax.nn.silu(jnp.einsum("td,tkdf->tkf", x, w_gate[e])) * up
+    out = jnp.einsum("tkf,tkfd->tkd", act * weights[:, :, None], w_down[e])
+    return jnp.sum(jnp.where(held[:, :, None], out, 0.0), axis=1)
+
+
+#: the two training cells' expert layers at few tokens and narrow widths, their own k, held share and expert; and
+#: Nemotron's two-matrix expert
+CELL_LAYERS = {
+    "k 8 of 64, 16 held (Mellum2)": (96, 64, 8, 0, 16, True),
+    "k 1 of 16, 8 held (ZAYA1)": (640, 16, 1, 0, 8, True),
+    "k 4 of 16, 8 held, two matrices": (40, 16, 4, 4, 8, False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(CELL_LAYERS))
+def test_the_held_experts_and_all_five_gradients_are_the_plain_statements(case, dtype):
+    """``_held_experts`` through its kernels (the row movements, the grouped
+    products, hidden and its derivative over live tiles, the second product to
+    the rows added into the first) against the plain statement above: the
+    value and the gradients to x, the three matrices and the routing weights."""
+    tokens, outputs, k, first, count, gated = CELL_LAYERS[case]
+    d, d_ff = 16, 12
+    keys = jax.random.split(jax.random.key(5), 7)
+    picks = jnp.argsort(-jax.random.uniform(keys[0], (tokens, outputs)), axis=1)[:, :k].astype(jnp.int32)
+    weights = jax.random.uniform(keys[1], (tokens, k), jnp.float32, 0.1, 1.0)
+    x = jax.random.normal(keys[2], (tokens, d)).astype(dtype)
+    w_gate = jax.random.normal(keys[3], (count, d, d_ff)) * 0.3 if gated else None
+    w_up, w_down = jax.random.normal(keys[4], (count, d, d_ff)) * 0.3, jax.random.normal(keys[5], (count, d_ff, d)) * 0.3
+    cot = jax.random.normal(keys[6], (tokens, d))
+    rows = moe._sorted_rows(picks, first, count)
+    assert 0 < int(rows.layout.live_tiles[0]) < rows.layout.rows // rows.layout.tile    # dead tiles beside the live ones
+
+    def through_kernels(x, w_gate, w_up, w_down, weights):
+        return moe._held_experts(
+            x, w_gate, w_up, w_down, weights,
+            rows.row_pick, rows.row_live, rows.pick_row, rows.pick_held, rows.tile_rows, *rows.layout,
+        )
+
+    def plain(x, w_gate, w_up, w_down, weights):
+        return _held_experts_as_xla_stated_them(x.astype(jnp.float32), w_gate, w_up, w_down, weights, picks, first)
+
+    operands = (x, w_gate, w_up, w_down, weights)
+    y, vjp = jax.jit(lambda *a: jax.vjp(through_kernels, *a))(*operands)
+    want_y, want_vjp = jax.jit(lambda *a: jax.vjp(plain, *a))(*operands)
+    # float32: sums in another order; bfloat16: the products' operands and results rounded, against a scale of ~1
+    close = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 else dict(rtol=0.05, atol=0.08)
+    assert y.dtype == jnp.float32 and float(jnp.abs(want_y).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), **close)
+    for name, got, want in zip(("x", "w_gate", "w_up", "w_down", "weights"), vjp(cot), want_vjp(cot)):
+        assert (got is None) == (want is None) == (name == "w_gate" and not gated), name
+        if got is not None:
+            assert got.dtype == want.dtype and float(jnp.abs(want).max()) > 0.1, name
+            scale = 1.0 if dtype == jnp.float32 else float(jnp.abs(want).max())   # a matrix's gradient sums hundreds of rows
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=close["rtol"], atol=close["atol"] * scale, err_msg=name
+            )
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+def test_the_serving_experts_logits_are_the_training_layers(gated):
+    """``serve_routed_experts`` (its own layout: an expert without rows owns no
+    tile, tiles of 16) through the same ``_expert_products`` as the training
+    layer, softmax-routed top-8 of 64 with 16 held: the same params and tokens
+    give the training layer's output."""
+    act = "swiglu" if gated else "relu2"
+    cfg = TransformerConfig(
+        vocab_size=96, d_model=32, n_layers=1, n_heads=4, max_seq_len=64, dtype=jnp.float32, partition_params=False,
+        moe_experts=64, moe_top_k=8, moe_intermediate_size=24, moe_experts_held=(16, 16), moe_expert_act=act,
+    )
+    layer = moe.RoutedExperts(num_experts=64, top_k=8, d_ff=24, held=(16, 16), dtype=jnp.float32, partition=False, expert_act=act)
+    x = jax.random.normal(jax.random.key(6), (3, 20, 32), jnp.float32)
+    p = jax.jit(layer.init)(jax.random.key(2), x)["params"]
+    assert ("w_gate" in p) == gated
+    got, (held, hit) = jax.jit(lambda p, x: moe.serve_routed_experts(cfg, p, x))(p, x)
+    want = jax.jit(lambda p, x: layer.apply({"params": p}, x)[0])(p, x)
+    assert 0 < int(hit) <= 16 and int(held) >= int(hit) and float(jnp.abs(want).max()) > 0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
 def test_the_rows_weights_are_the_gathers_bits_on_either_side_of_the_line(monkeypatch):
     """``_row_weights`` takes its compares up to ``_COMPARE_TOKENS`` tokens and
     one gather a row past them: both sides of the line at one shape."""

@@ -10,7 +10,9 @@ heaviest compile of the suite is here (the Brumby walk's two chunk kernels,
 
 import functools
 import importlib
+import json
 import math
+import os
 import re
 
 import jax
@@ -100,7 +102,7 @@ def test_the_prefill_walk_at_the_dsv3_cells_widths_holds_a_chunk_not_the_prompt(
     assert mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2  # the pool is donated
     assert _arrays_with_dims(text, (128, 256, 7168)) == [] and _arrays_with_dims(text, (128, 256, 4096)) == []
     assert _arrays_with_dims(text, (128, 256, 256)) != []
-    assert _kernels(text) == 5  # the expert layer's two row movements and three grouped products
+    assert _kernels(text) == 6  # the expert layer's two row movements, three grouped products and hidden over live tiles
 
 
 def test_the_prefill_walk_at_internlm2s_widths_keeps_no_second_copy_of_the_model(tpu_devices):
@@ -206,7 +208,7 @@ def test_the_dsv3_decode_program_compiles_with_its_kernels_named(tpu_devices):
     fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
     compiled = fn.lower(params, aval((64,)), aval((64,)), aval((64, 448)), cache).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert _kernels(text) == 2 + 5
+    assert _kernels(text) == 2 + 6
     assert mem.temp_size_in_bytes < 256 * 1024**2 and mem.alias_size_in_bytes >= 2 * 24576 * 16 * 640 * 2   # the pool is donated
     scopes = program_scopes(text)
     assert {"serve.mla", "serve.mla.attend", "serve.moe.route", "serve.moe.experts", "serve.moe.shared"} <= set(scopes)
@@ -252,7 +254,7 @@ def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, w
         compiled = _walk_compiled(one, cfg, num_blocks=10240, max_prompt_len=3072, table_width=320)
         text, mem = compiled.as_text(), compiled.memory_analysis()
         assert mem.temp_size_in_bytes < 0.5 * 1024**3 and mem.alias_size_in_bytes >= pool_bytes
-        assert _arrays_with_dims(text, (64, 256, 5120)) == [] and _kernels(text) == 5
+        assert _arrays_with_dims(text, (64, 256, 5120)) == [] and _kernels(text) == 6
         return
     boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
@@ -262,7 +264,7 @@ def test_the_longcat_cells_programs_compile_over_two_rows_a_block(tpu_devices, w
     fn = jax.jit(functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True), donate_argnums=(4,))
     compiled = fn.lower(params, aval((64,)), aval((64,)), aval((64, 320)), cache).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert _kernels(text) == 2 + 5
+    assert _kernels(text) == 2 + 6
     assert mem.temp_size_in_bytes < 256 * 1024**2 and mem.alias_size_in_bytes >= pool_bytes   # the pool is donated
     scopes = program_scopes(text)
     assert {"serve.mla", "serve.mla.attend", "serve.mlp", "serve.moe.route", "serve.moe.experts", "serve.moe.identity"} <= set(scopes)
@@ -375,16 +377,16 @@ def test_the_command_cells_programs_compile_over_a_cache_of_two_kinds(tpu_device
     assert {"serve.attn.window", "serve.attn.full", "serve.attn.attend", "serve.kv.write", "serve.moe.route", "serve.moe.experts",
             "serve.moe.shared"} <= set(scopes)
     if which == "decode":
-        assert _kernels(text) == 2 + 2 * 5                                          # an attention kernel and five of the experts a layer
+        assert _kernels(text) == 2 + 2 * 6                                          # an attention kernel and six of the experts a layer
         assert sum("paged_window_attention" in n for n in scopes["serve.attn.window"]) == 1
         assert sum("paged_decode_attention" in n for n in scopes["serve.attn.full"]) == 1
         assert not any("paged_" in n for n in set(scopes["serve.attn.window"]) & set(scopes["serve.attn.full"]))
         assert _arrays_with_dims(text, (32, 20480)) == [] and _arrays_with_dims(text, (32, 4352, 1024)) == []
     else:
         # 14,336 tokens hold eight wide chunks: the walk has its wide loop and its narrow one (prompts start at 0: no
-        # third), each with the experts' five kernels a layer; a wide chunk's attention still goes a narrow chunk's
+        # third), each with the experts' six kernels a layer; a wide chunk's attention still goes a narrow chunk's
         # tile at a time (no [heads, 1024, 1024] scores, no ring written 1,024 rows at once), and no pool or ring is copied
-        assert _kernels(text) == 2 * 2 * 5
+        assert _kernels(text) == 2 * 2 * 6
         assert _arrays_with_dims(text, (128, 256, 20480)) == [] and _arrays_with_dims(text, (256, 4352)) == []
         assert _arrays_with_dims(text, (128, 1024, 1024)) == [] and _arrays_with_dims(text, (1024, 4352)) == []
         assert not re.search(r"bf16\[(1,)?(24576|8704),16,1024\]\S* copy\(", text)
@@ -636,9 +638,10 @@ def test_the_nemotron_cells_programs_compile_over_layers_of_one_mixer_each(tpu_d
     assert {"serve.attn.qkv", "serve.kv.write", "serve.attn.attend", "serve.attn.out", "serve.mamba2.in", "serve.mamba2.state",
             "serve.mamba2.out", "serve.moe.route", "serve.moe.latent", "serve.moe.experts", "serve.moe.shared", "serve.embed",
             "serve.head"} <= set(scopes) and not {"serve.mlp", "serve.ssm.state"} & set(scopes)
-    if which == "decode":   # a Mamba-2 layer: the state kernel; the attention layer: the paged kernel; an expert layer: rows in, two products, rows out
-        assert _kernels(text) == 5 + 1 + 5 * 4 and len({n for n in scopes["serve.mamba2.state"] if n.startswith("ssm_decode")}) == 5
+    if which == "decode":   # a Mamba-2 layer: the state kernel; the attention layer: the paged kernel; an expert layer: rows in, two products with hidden between them, rows out
+        assert _kernels(text) == 5 + 1 + 5 * 5 and len({n for n in scopes["serve.mamba2.state"] if n.startswith("ssm_decode")}) == 5
         assert len({n for n in scopes["serve.moe.experts"] if n.startswith("moe_gmm")}) == 10
+        assert len({n for n in scopes["serve.moe.experts"] if n.startswith("moe_hidden_rows")}) == 5   # timed with the experts
         assert paged_mod.attn_products(16) == "per_kv_head"
     print(which, "args", mem.argument_size_in_bytes, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes)
 
@@ -721,7 +724,7 @@ def test_the_glm_cells_programs_compile_over_rows_an_indexer_picks(tpu_devices, 
     compiled = fn.lower(params, aval((16,)), aval((16,)), aval((16, 1536)), cache).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 512 * 1024**2
-    assert _kernels(text) == 2 + 2 * 5  # the index kernel in two layers; two expert layers' row movements and grouped products
+    assert _kernels(text) == 2 + 2 * 6  # the index kernel in two layers; two expert layers' row movements, grouped products and hidden
     scopes = program_scopes(text)
     assert {"serve.dsa", "serve.dsa.project", "serve.dsa.write", "serve.dsa.index", "serve.dsa.topk", "serve.mla.gather",
             "serve.mla.attend", "serve.moe.experts"} <= set(scopes)
@@ -844,6 +847,44 @@ def _loss_products(text: str) -> list:
     return re.findall(r"= (\w+\[[\d,]+\])\S* convolution\(.*op_name=\"[^\"]*loss\.ce[^\"]*\"", text)
 
 
+def _calls_a_reader_takes(text: str) -> dict:
+    """How many of a compiled step's Mosaic calls each of the benchmark's
+    readers that tell calls apart by RESULT SHAPE would take for its own
+    (the patterns are the metric files'; an instruction is named as a trace
+    names it), and how many none of them takes."""
+    results = re.findall(r"= (\(?\w+\[[\d,]*\]\{[^=]*) custom-call\(.*custom_call_target=\"tpu_custom_call\"", text)
+    taken = {}
+    for metric in ("moe_grouped_matmul_roofline", "adamw_hbm_roofline", "mixed_attn_roofline"):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "metrics", metric + ".json")) as f:
+            pattern = re.compile(json.load(f)["args"]["pattern"])
+        taken[metric] = sum(bool(pattern.search(f"%tpu_custom_call.1 = {r}")) for r in results)
+    return dict(taken, none=len(results) - sum(taken.values()))
+
+
+def test_the_mellum_cells_step_compiles_and_each_reader_finds_the_calls_it_found(tpu_devices):
+    """Mellum2's cell: four layers, 16 held experts of 2,304 x 896 under a
+    worst-case buffer of 69,632 rows, one sequence of 8,192.  A layer's nine
+    grouped products are nine calls with the results they had (2-D bf16, 3-D
+    float32), the attention kernels' first result is 4-D bf16 and AdamW's a
+    tuple led by float32: what PR 33 counted on the chip (36, 22 and 12
+    instructions).  The other calls are a layer's five row movements and,
+    since PR 63, hidden (forward and backward) and its derivative over live
+    tiles: 3-D results in the compute dtype (a tuple led by one), which no
+    reader takes."""
+    compiled = _train_step_compiled(SingleDeviceSharding(tpu_devices[0]), "train-mellum2-l4-ep4-seq8k")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes < 15.75 * 2**30
+    taken = _calls_a_reader_takes(text)
+    assert taken == {"moe_grouped_matmul_roofline": 4 * 9, "adamw_hbm_roofline": 22, "mixed_attn_roofline": 4 * 3, "none": 4 * (5 + 3)}, taken
+    assert sum(taken.values()) == _kernels(text)
+    for name in ("moe_hidden_rows", "moe_hidden_grads", "moe_rows_of_tokens", "moe_tokens_of_rows"):
+        assert name in text, name
+    # nothing elementwise sweeps the whole buffer under the experts' scope any more
+    swept = re.findall(r"= \w+\[69632,(?:896|2304)\]\S* fusion\(.*op_name=\"[^\"]*moe\.experts", text)
+    assert not swept, swept
+    print("temp", mem.temp_size_in_bytes, "kernels", _kernels(text), taken)
+
+
 def test_the_zaya_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_devices):
     """ZAYA1-8B's cell: five CCA layers at 8 over 2 heads of 128 and 8,192
     keys, the MLP router, top-1 into 8 held experts of 2048 x 2048, a tied head
@@ -862,6 +903,10 @@ def test_the_zaya_cells_step_compiles_at_the_published_widths_and_its_batch(tpu_
     assert state + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes < 15.75 * 2**30
     # a layer: flash forward + its two backward kernels, 3 + 6 grouped products and the rows' movements; the sweeps on top
     assert _kernels(text) >= 5 * (3 + 9)
+    # by result shape, as the benchmark's readers tell them apart: the products, attention, the sweeps; and the row
+    # movements with the passes over live tiles (PR 63), which none of them takes
+    taken = _calls_a_reader_takes(text)
+    assert (taken["moe_grouped_matmul_roofline"], taken["mixed_attn_roofline"], taken["none"]) == (5 * 9, 5 * 3, 5 * (5 + 3)), taken
     assert len(_loss_products(text)) == 3, _loss_products(text)             # logits, dk, dx (a remat'd scan: the logits twice)
     print("args", state, "out", mem.output_size_in_bytes, "alias", mem.alias_size_in_bytes, "temp", mem.temp_size_in_bytes, "kernels", _kernels(text))
 
