@@ -7,8 +7,13 @@ in the paged pool), ``paged_latent`` (one latent row a token in the pool),
 ``paged_indexed`` (a latent row a token and, in the layers that hold an indexer,
 an index key a token beside it: attention reads the rows the indexer picks),
 ``state_slot`` (a float32 state a decode lane), ``window_ring`` (K and V rows
-of the newest tokens in a ring a decode lane) and ``ssm_slot`` (a Mamba-2
-mixer's float32 state and its convolution's tail a decode lane);
+of the newest tokens in a ring a decode lane), ``ssm_slot`` (a Mamba-2
+mixer's float32 state and its convolution's tail a decode lane) and
+``delta_slot`` (a Gated-DeltaNet mixer's float32 delta-rule state and its
+convolution's tail a decode lane: a seventh record and not ``ssm_slot`` under
+another recurrence, because its leaves' shapes, its projections and its kernel
+are its own; what the two share, a lane's tail and the counting of live lanes,
+is stated once below, ``_tail_walk`` / ``_tail_step`` / ``_lanes_count``);
 ``docs/serving.md`` "What a request holds" has them side by side.  A layer is
 of every kind whose ``layer_types`` name its type: an ``attention_mamba2`` layer
 is of ``paged_kv`` AND ``ssm_slot``, and its mixers run one after the other on
@@ -46,11 +51,13 @@ import jax.numpy as jnp
 
 from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
-    FULL, HYBRID, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _index_project, _latent_attend_local, _latent_project,
-    _rms, _rope,
-    _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, kv_bytes_per_token, kv_cache_shape, ssm_bytes_per_slot,
-    recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks, window_store_shape,
+    FULL, HYBRID, LINEAR, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _gated, _gdn_conv, _gdn_out, _gdn_project,
+    _gdn_split, _index_project, _latent_attend_local, _latent_project, _rms, _rope, _rope_first,
+    _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, gdn_bytes_per_slot, gdn_pool_shapes, kv_bytes_per_token, kv_cache_shape,
+    ssm_bytes_per_slot, recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks,
+    window_store_shape,
 )
+from determined_tpu.ops.gated_delta import gdn_chunk, gdn_decode
 from determined_tpu.ops.attention import NEG_INF, _repeat_kv, reference_attention
 from determined_tpu.ops.paged_attention import (
     COPY_SCHEDULE, attn_products, index_scores, index_topk, index_topk_mask, paged_chunk_attention, paged_decode_attention, paged_index_scores,
@@ -148,6 +155,10 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
     row: idle lanes, padding), then ``attend(q, k, v, cache, j)`` against the
     store that now holds them, with q [b, n_heads, s, head_dim] and this call's
     own k, v [b, kv_heads, s, head_dim], to [b, n_heads, s, head_dim].
+    As ``Attention`` states them: under ``attn_output_gate`` a head of ``wq`` is
+    ``[query | gate]`` and what the head attended to is multiplied by
+    ``sigmoid(gate)`` before ``wo``; under ``qk_norm`` q and k pass an RMSNorm a
+    head; rotary turns the first ``partial_rotary_factor`` of a head.
     ``walk``: ``attend`` is ``rows -> attend`` of a narrow chunk's rows, and a
     wide chunk's narrow chunks are written and attend one after the other
     (``_narrow_chunks``: a ring holds ONE narrow chunk more than its window),
@@ -161,7 +172,12 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
     def mix(p, x, h, cache, j):
         with jax.named_scope("serve.attn.qkv"):
             q, k, v = _attn_proj(p, _times(h, cfg.attention_in_multiplier), dt)
-            q, k = _rope(q, rows.positions, rope), _rope(_times(k, cfg.key_multiplier), rows.positions, rope)
+            if cfg.attn_output_gate:
+                q, gate = q[..., : cfg.head_dim], q[..., cfg.head_dim:]
+            if cfg.qk_norm:
+                q, k = _rms(q, p["q_norm"], cfg.norm_eps), _rms(k, p["k_norm"], cfg.norm_eps)
+            q = _rope_first(q, rows.positions, rope, cfg.partial_rotary_factor)
+            k = _rope_first(_times(k, cfg.key_multiplier), rows.positions, rope, cfg.partial_rotary_factor)
 
         def chunk(store, rows, q, k, v, phys, slots):
             with jax.named_scope("serve.kv.write"):
@@ -175,6 +191,9 @@ def _kv_mixer(cfg: TransformerConfig, kind: "CacheKind", rows: Rows, where, atte
 
         store, (att,) = _narrow_chunks(rows, chunk, (cache[keys], cache[vals]), (q, k, v, *where), (2, 2, 2, 1, 1), (2,))
         cache = {**cache, keys: store[0], vals: store[1]}
+        if cfg.attn_output_gate:
+            with jax.named_scope("serve.attn.gate"):
+                att = _gated(att, gate)
         with jax.named_scope("serve.attn.attend"):
             att = att.transpose(0, 2, 1, 3)  # [b, s, h, hd]
         with jax.named_scope("serve.attn.out"):
@@ -733,6 +752,32 @@ def _state_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
 # through the lanes' states and returns (y [b, s, heads, P] float32, the cache).
 
 
+def _tail_walk(cache: Dict[str, jax.Array], leaf: str, j, lanes: jax.Array, fresh, rows: Rows, new: jax.Array):
+    """A walk's chunk of a convolution's input ``new`` [b, s, channels] after the
+    tails of the rows' lanes (zeros in the call's first chunk): (the window the
+    convolution reads [b, s + taps - 1, channels], the lanes' new tails: the rows
+    before the first token that does not exist)."""
+    tail = jnp.where(fresh, 0, cache[leaf][j, lanes])
+    window = jnp.concatenate([tail, new], axis=1)
+    last = jnp.sum(rows.live, axis=1)[:, None] + jnp.arange(tail.shape[1])[None, :]
+    return window, jnp.take_along_axis(window, last[..., None], axis=1)
+
+
+def _tail_step(cache: Dict[str, jax.Array], leaf: str, j, rows: Rows, new: jax.Array):
+    """A decode step's row ``new`` [lanes, 1, channels] after each lane's tail,
+    which moves on by a row where the lane is live: (the window, the new tails)."""
+    tail = cache[leaf][j]
+    window = jnp.concatenate([tail, new], axis=1)
+    return window, jnp.where(rows.live[:, None, None], window[:, 1:], tail)
+
+
+def _lanes_count(layers: int, bytes_per_slot: int, active: jax.Array) -> jax.Array:
+    """What a kind that holds a state a lane in ``layers`` layers counts a step: the
+    lanes whose state the step updated, and the bytes of state they hold."""
+    lanes = jnp.sum(active.astype(jnp.float32))
+    return jnp.stack([lanes, lanes * (layers * bytes_per_slot)])
+
+
 def _ssm_mixer(cfg: TransformerConfig, conv, scan):
     """A Mamba-2 mixer reads the norm the layer's attention heads read (under
     ``mixer_block`` it is the layer's only mixer), writes no row a token and
@@ -767,11 +812,7 @@ def _ssm_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     fresh = rows.chunk == rows.first_chunk
 
     def conv(p, xbc, cache, j):
-        tail = jnp.where(fresh, 0, cache[tail_leaf][j, lanes])
-        window = jnp.concatenate([tail, xbc], axis=1)
-        # the new tail: the rows before the first token that does not exist
-        last = jnp.sum(rows.live, axis=1)[:, None] + jnp.arange(cfg.ssm_conv - 1)[None, :]
-        tail = jnp.take_along_axis(window, last[..., None], axis=1)
+        window, tail = _tail_walk(cache, tail_leaf, j, lanes, fresh, rows, xbc)
         return _ssm_conv(cfg, p, window), {**cache, tail_leaf: cache[tail_leaf].at[j, lanes].set(tail)}
 
     def scan(x, b, c, dt, a, skip, cache, j):
@@ -795,9 +836,7 @@ def _ssm_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     state_leaf, tail_leaf = SSM_SLOT.leaves
 
     def conv(p, xbc, cache, j):
-        tail = cache[tail_leaf][j]
-        window = jnp.concatenate([tail, xbc], axis=1)
-        tail = jnp.where(rows.live[:, None, None], window[:, 1:], tail)
+        window, tail = _tail_step(cache, tail_leaf, j, rows, xbc)
         return _ssm_conv(cfg, p, window), {**cache, tail_leaf: cache[tail_leaf].at[j].set(tail)}
 
     def scan(x, b, c, dt, a, skip, cache, j):
@@ -814,9 +853,7 @@ def _ssm_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ..
 
 
 def _ssm_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
-    """The lanes whose state this step updated, and the bytes of state they hold."""
-    lanes = jnp.sum(active.astype(jnp.float32))
-    return jnp.stack([lanes, lanes * (len(cfg.ssm_layers) * ssm_bytes_per_slot(cfg))])
+    return _lanes_count(len(cfg.ssm_layers), ssm_bytes_per_slot(cfg), active)
 
 
 def _ssm_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
@@ -832,6 +869,101 @@ def _ssm_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
     slots = _ssm_report(cfg, sizes)["ssm"]
     said = {"ssm_slots": slots["slots"], "ssm_bytes_per_slot": slots["bytes_per_slot"], "ssm_pool_bytes": _nbytes(SSM_SLOT, cfg, sizes)}
     return {**said, **_layers_report(cfg)}
+
+
+# -- a Gated-DeltaNet mixer's delta-rule state and its convolution's tail a lane ---
+#
+# Its forms are two functions of a call's rows, as the Mamba-2 mixer's: ``conv(p,
+# qkv, cache, j)`` -> (the convolution's output, the cache) and ``rule(q, k, v, g,
+# beta, cache, j)`` -> (o [b, s, value heads, V] float32, the cache).
+
+
+def _gdn_mixer(cfg: TransformerConfig, conv, rule):
+    """A Gated-DeltaNet mixer is its layer's only mixer before the experts: it
+    reads the layer's first norm, writes no row a token and adds its output to
+    the stream."""
+
+    def mix(p, x, h, cache, j):
+        with jax.named_scope("serve.gdn.proj"):  # the two in-projections
+            qkv, z, b, a = _gdn_project(cfg, p, h)
+        with jax.named_scope("serve.gdn.conv"):  # the tail, the convolution, the heads' norms, decay and write strength
+            qkv, cache = conv(p, qkv, cache, j)
+            parts = _gdn_split(cfg, qkv, b, a, p)
+        with jax.named_scope("serve.gdn.state"):  # decay, S^T k, the corrected update, the read-out
+            o, cache = rule(*parts, cache, j)
+        with jax.named_scope("serve.gdn.out"):  # the gated norm, the out-projection
+            return x + _gdn_out(cfg, p, o, z), cache
+
+    return mix
+
+
+def _gdn_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """``s`` tokens a row after what the slots and tails of the rows' lanes hold
+    (nothing, in the walk's first chunk), and into them: the prefill walk's chunk,
+    and the wide prefill as one chunk (``_ssm_walk`` says the same of its own).
+    The chunked form takes a wide chunk's narrow chunks one after the other, the
+    state in hand, and the slots are read and written once a chunk."""
+    state_leaf, tail_leaf = DELTA_SLOT.leaves
+    lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
+    fresh = rows.chunk == rows.first_chunk
+
+    def conv(p, qkv, cache, j):
+        window, tail = _tail_walk(cache, tail_leaf, j, lanes, fresh, rows, qkv)
+        return _gdn_conv(p, window), {**cache, tail_leaf: cache[tail_leaf].at[j, lanes].set(tail)}
+
+    def rule(q, k, v, g, beta, cache, j):
+        state = jnp.where(fresh, 0.0, cache[state_leaf][j, lanes])
+
+        def chunk(state, rows, q, k, v, g, beta):
+            with jax.named_scope("serve.gdn.chunk"):
+                o, state = gdn_chunk(q, k, v, g, beta, state, rows.live, chunk=cfg.linear_chunk)
+            return state, (o,)
+
+        state, (o,) = _narrow_chunks(rows, chunk, state, (q, k, v, g, beta), (1,) * 5, (1,))
+        return o, {**cache, state_leaf: cache[state_leaf].at[j, lanes].set(state)}
+
+    return _gdn_mixer(cfg, conv, rule)
+
+
+def _gdn_step(cfg: TransformerConfig, rows: Rows, cache: Any = None):
+    """One token a lane, row ``b`` of the batch IS lane ``b``: the tail moves on
+    by a row, the slot is decayed, corrected by the token and answers it
+    (``ops/gated_delta.py gdn_decode``: the Pallas kernel on a TPU, in place); a
+    lane that is not live leaves both alone."""
+    state_leaf, tail_leaf = DELTA_SLOT.leaves
+
+    def conv(p, qkv, cache, j):
+        window, tail = _tail_step(cache, tail_leaf, j, rows, qkv)
+        return _gdn_conv(p, window), {**cache, tail_leaf: cache[tail_leaf].at[j].set(tail)}
+
+    def rule(q, k, v, g, beta, cache, j):
+        o, state = gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], cache[state_leaf], j, rows.live)
+        return o[:, None], {**cache, state_leaf: state}
+
+    return _gdn_mixer(cfg, conv, rule)
+
+
+def _gdn_shapes(cfg: TransformerConfig, sizes: Any) -> Tuple[Tuple[int, ...], ...]:
+    if sizes.max_batch is None:
+        raise ValueError("a model with linear_attention layers needs its lanes to size the state pool")
+    return gdn_pool_shapes(cfg, sizes.max_batch)
+
+
+def _gdn_count(cfg: TransformerConfig, active: jax.Array, pos: jax.Array) -> jax.Array:
+    return _lanes_count(len(cfg.linear_layers), gdn_bytes_per_slot(cfg), active)
+
+
+def _gdn_report(cfg: TransformerConfig, sizes: Any, live: int = 0, gauges: Any = None) -> Dict[str, Any]:
+    """``gdn``: the slots (one a lane), how many hold a sequence, and the bytes
+    of state one holds over the Gated-DeltaNet layers."""
+    if not cfg.linear_layers:
+        return {}
+    return {"gdn": {"slots": sizes.max_batch, "live": live, "bytes_per_slot": len(cfg.linear_layers) * gdn_bytes_per_slot(cfg)}}
+
+
+def _gdn_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
+    slots = _gdn_report(cfg, sizes)["gdn"]
+    return {"gdn_slots": slots["slots"], "gdn_bytes_per_slot": slots["bytes_per_slot"], "gdn_pool_bytes": _nbytes(DELTA_SLOT, cfg, sizes)}
 
 
 # -- the table ----------------------------------------------------------------------
@@ -1013,8 +1145,25 @@ SSM_SLOT = CacheKind(
     report=_ssm_report, setup=_ssm_setup,
 )
 
+DELTA_SLOT = CacheKind(
+    name="delta_slot", layer_types=(LINEAR,), latent=False, leaves=("gdn", "gconv"), shapes=_gdn_shapes, holds=LANE,
+    params="gdn",
+    # the state where it is stated (the benchmark's check sets another there); the tail as the convolution reads it
+    dtypes=lambda cfg: (transformer.STATE_DTYPE, cfg.dtype),
+    no_prefix_cache=(
+        "prefix_cache shares a prompt's full blocks between requests, and a shared block holds no state: a "
+        "Gated-DeltaNet (linear_attention) layer keeps a request's whole context in its own lane's delta-rule state "
+        "slot and convolution tail, and a prefill from the first un-cached token would need both as they stood at "
+        "that block's edge (a snapshot nobody keeps). Set prefix_cache: false"
+    ),
+    step=_gdn_step, walk=_every_chunk(_gdn_walk), table=_gdn_step, wide=_gdn_walk,
+    # the lanes whose state the step updated, and the bytes of state those hold over the Gated-DeltaNet layers
+    counters=("serve.gdn.live_lanes", "serve.gdn.bytes"), count=_gdn_count,
+    report=_gdn_report, setup=_gdn_setup,
+)
+
 #: every kind, in the order a layer's mixers run, a decode step's counters and a walk's chunk take them
-CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, PAGED_INDEXED, STATE_SLOT, WINDOW_RING, SSM_SLOT)
+CACHE_KINDS: Tuple[CacheKind, ...] = (PAGED_KV, PAGED_LATENT, PAGED_INDEXED, STATE_SLOT, WINDOW_RING, SSM_SLOT, DELTA_SLOT)
 
 
 def cache_kinds(cfg: TransformerConfig) -> Tuple[CacheKind, ...]:

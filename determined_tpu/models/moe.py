@@ -513,6 +513,8 @@ class RoutedExperts(nn.Module):
     shared_experts: int = 0
     # "mean": the shared experts' sum over their number (Cohere's "average")
     shared_combine: str = "sum"
+    # what the shared experts add is multiplied by ``sigmoid(x . shared_gate)``, one learned vector of d_model
+    shared_gate: bool = False
     # identity experts, the router's outputs ``num_experts ..``: a pick there adds
     # ``w x`` and owns no row (:func:`_identity_part`); under "softmax_bias"
     zero_experts: int = 0
@@ -589,6 +591,9 @@ class RoutedExperts(nn.Module):
                 p["shared_w_gate"] = param("shared_w_gate", (d, wide), ("embed", "mlp"))
             p["shared_w_up"] = param("shared_w_up", (d, wide), ("embed", "mlp"))
             p["shared_w_down"] = param("shared_w_down", (wide, d), ("mlp", "embed"))
+            if self.shared_gate:
+                init = _maybe_partition(self.partition, nn.initializers.normal(d ** -0.5), ("embed",))
+                p["shared_gate"] = self.param("shared_gate", init, (d,), self.param_dtype)
 
         xf = x.reshape(tokens, d)
         with jax.named_scope("moe.route"):
@@ -764,13 +769,17 @@ def _route(
 def _shared_experts(p: Any, xf: jax.Array, count: int = 1, combine: str = "sum") -> jax.Array:
     """The shared experts of ``xf [T, d]``: one expert as wide as all ``count``
     of them, which is their sum (a SwiGLU; without a ``shared_w_gate`` the
-    two-matrix ``relu(.)^2`` expert); their mean under ``combine`` "mean"."""
+    two-matrix ``relu(.)^2`` expert); their mean under ``combine`` "mean"; with
+    a ``shared_gate`` leaf, times ``sigmoid(xf . shared_gate)`` a token."""
     dt = xf.dtype
     if "shared_w_gate" in p:
         hidden = nn.silu(xf @ p["shared_w_gate"].astype(dt)) * (xf @ p["shared_w_up"].astype(dt))
     else:
         hidden = jnp.square(jax.nn.relu(xf @ p["shared_w_up"].astype(dt)))
     out = hidden @ p["shared_w_down"].astype(dt)
+    if "shared_gate" in p:  # a scalar a token, its sigmoid taken in float32
+        gate = jnp.dot(xf, p["shared_gate"].astype(dt), preferred_element_type=jnp.float32)
+        out = out * jax.nn.sigmoid(gate)[:, None].astype(dt)
     return out / count if combine == "mean" else out
 
 

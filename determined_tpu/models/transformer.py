@@ -47,6 +47,7 @@ from flax import linen as nn
 from determined_tpu.data import DataLoader, SyntheticDataset
 from determined_tpu.ops import kernel_form
 from determined_tpu.ops.attention import NEG_INF, dot_product_attention, reference_attention
+from determined_tpu.ops.gated_delta import gdn_chunk, l2_heads, state_shape as gdn_pool_shape
 from determined_tpu.ops.paged_attention import index_scores, index_topk_mask
 from determined_tpu.ops.retention import recent_shapes, retention_quadratic, state_shapes
 from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
@@ -66,7 +67,10 @@ CCA = "cca"
 #: under ``mixer_block`` a layer is ONE mixer (Nemotron-H's block): a Mamba-2 mixer alone, the dropless experts
 #: alone, or (``full_attention``) attention alone
 MAMBA2, EXPERTS = "mamba2", "experts"
-LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA, MAMBA2, EXPERTS)
+#: a Gated-DeltaNet mixer in attention's place (``GatedDeltaNet``; the published ``layer_types`` value): a
+#: delta-rule state a value head, no K and V a token
+LINEAR = "linear_attention"
+LAYER_TYPES = (FULL, SLIDING, RETENTION, HYBRID, CCA, MAMBA2, EXPERTS, LINEAR)
 #: what ``indexer_types`` says of a layer: it holds an indexer and attends over the keys that picks, or it
 #: holds none and attends over the picks of the nearest ``full`` layer before it
 INDEX_FULL, INDEX_SHARED = "full", "shared"
@@ -97,8 +101,13 @@ class TransformerConfig:
     sliding_window: Optional[int] = None
     retention_gate_bias: float = 0.0
     # RMSNorm over each head of q and k before rotary, one learned weight of
-    # head_dim each shared by the heads (Qwen3's); power_retention layers run it
+    # head_dim each shared by the heads (Qwen3's): power_retention layers run
+    # it, or GQA's attention layers do (``Attention``), in a model of no
+    # retention layer.  attn_output_gate (Qwen3-Next's gated attention): ``wq``
+    # is twice as wide, a head's ``[query | gate]``, and what the head attends
+    # to is multiplied by ``sigmoid(gate)`` before ``wo``
     qk_norm: bool = False
+    attn_output_gate: bool = False
     # rotary parameters per layer type (the published `rope_parameters`
     # group): {"full_attention": {"rope_type": "yarn", "rope_theta": ...,
     # "factor": ..., ...}, "sliding_attention": {"rope_type": "default",
@@ -137,6 +146,9 @@ class TransformerConfig:
     moe_topk_group: int = 1
     moe_routed_scaling: float = 1.0
     moe_shared_experts: int = 0
+    # moe_shared_gate: what the shared experts add is multiplied by ``sigmoid(x . w_g)``, a scalar a token
+    # from one learned vector of d_model (the leaf ``shared_gate``; Qwen3-Next's)
+    moe_shared_gate: bool = False
     # "sigmoid" is the plain form of the grouped router: sigmoid scores, the
     # top-k largest, weights normalised to one; no groups, no bias.  How the
     # shared experts' outputs combine: "sum", or "mean" (their sum over their
@@ -225,7 +237,8 @@ class TransformerConfig:
     # A cca layer (``CompressedAttention``): q and k pass a depthwise causal
     # convolution over cca_time0 tokens and one over cca_time1 tokens that
     # mixes each head's channels; rotary turns the first partial_rotary_factor
-    # of every head (of every layer type: 1 turns the whole head)
+    # of every head (1 turns the whole head), in cca layers and in GQA's
+    # attention layers (``Attention``: full, sliding, beside a Mamba-2 mixer)
     cca_time0: int = 2
     cca_time1: int = 2
     partial_rotary_factor: float = 1.0
@@ -239,6 +252,17 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # A linear_attention layer's Gated-DeltaNet mixer (``GatedDeltaNet`` below): linear_value_heads value heads
+    # of linear_value_head_dim, each with a delta-rule state of linear_key_head_dim x linear_value_head_dim;
+    # linear_key_heads heads of q and k, each serving linear_value_heads / linear_key_heads consecutive value
+    # heads; a causal depthwise convolution over linear_conv tokens on q, k and v (no bias), and linear_chunk
+    # tokens a sub-chunk of the chunked form (``ops/gated_delta.py``)
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv: int = 4
+    linear_chunk: int = 64
     # muP's scalars, constants of the forward and no leaves (1: not there):
     # on the embedding's rows; on k, on attention's input and output; on the
     # Mamba-2 mixer's input, on the five segments z, x, B, C, dt of its
@@ -312,7 +336,8 @@ class TransformerConfig:
                     ("parallel_block", self.parallel_block), ("shortcut_block", self.shortcut_block),
                     ("residual_scaling", self.residual_scaling), ("latent attention (kv_lora_rank)", self.latent),
                     ("pipeline stages (a `seq` or `expert` axis)", self.seq_axis_name is not None or self.expert_axis_name is not None),
-                    ("a layer type other than full_attention, mamba2 or experts", not types <= {FULL, MAMBA2, EXPERTS}),
+                    ("a layer type other than full_attention, mamba2 or experts (a linear_attention layer sits in a "
+                     "sequential block, before its experts)", not types <= {FULL, MAMBA2, EXPERTS}),
                     ("moe_router mlp", self.moe_router == "mlp"),
                 ) if there
             ]
@@ -337,6 +362,17 @@ class TransformerConfig:
                 "an attention_mamba2 layer needs ssm_heads (whole groups of them), ssm_head_dim, ssm_state and "
                 "ssm_conv >= 2, in a sequential block, without latent attention or a `seq` axis (a mamba2 layer likewise)"
             )
+        if self.linear_layers and (
+            min(self.linear_key_heads, self.linear_value_heads, self.linear_key_head_dim, self.linear_value_head_dim, self.linear_chunk) < 1
+            or self.linear_conv < 2 or self.linear_value_heads % self.linear_key_heads
+            or self.latent or self.parallel_block or self.shortcut_block or self.seq_axis_name is not None
+        ):
+            raise ValueError(
+                "a linear_attention layer needs linear_value_heads (whole groups a linear_key_heads head), linear_key_head_dim, "
+                "linear_value_head_dim, linear_chunk >= 1 and linear_conv >= 2, in a sequential block: it does not run under "
+                "parallel_block or shortcut_block, with latent attention (kv_lora_rank) or under a `seq` axis (its state is "
+                "carried along the sequence)"
+            )
         if CCA in (self.layer_types or ()) and (
             self.latent or self.parallel_block or self.seq_axis_name is not None or self.n_heads % self.kv_heads
             or self.kv_heads % 2 or min(self.cca_time0, self.cca_time1) < 1
@@ -349,12 +385,22 @@ class TransformerConfig:
         rotary = self.head_dim * self.partial_rotary_factor
         if not 0 < self.partial_rotary_factor <= 1 or rotary != int(rotary) or int(rotary) % 2:
             raise ValueError(f"partial_rotary_factor={self.partial_rotary_factor} must leave an even count of head_dim={self.head_dim} to turn")
-        if self.partial_rotary_factor != 1 and set(self.layer_types or (FULL,)) != {CCA}:
-            raise ValueError("partial_rotary_factor < 1 runs in cca layers only: every layer must be one")
+        if self.partial_rotary_factor != 1 and (self.latent or self.retention_layers):
+            raise ValueError(
+                "partial_rotary_factor < 1 runs in cca layers and in GQA's attention layers: not with latent attention "
+                "(kv_lora_rank), whose rotary part is its own width, nor in a power_retention layer"
+            )
         if self.residual_scaling and (self.parallel_block or self.expert_axis_name is not None):
             raise ValueError("residual_scaling runs in a sequential block, outside pipeline stages")
-        if self.qk_norm and len(self.retention_layers) != self.n_layers:
-            raise ValueError("qk_norm runs in power_retention layers only: every layer must be one")
+        if self.qk_norm and (self.latent or CCA in types or 0 < len(self.retention_layers) < self.n_layers):
+            raise ValueError(
+                "qk_norm runs in power_retention layers only (every layer must be one), or in GQA's attention layers in a "
+                "model of no retention layer: not with latent attention (kv_lora_rank) or a cca layer"
+            )
+        if self.attn_output_gate and (self.latent or types & {RETENTION, CCA}):
+            raise ValueError("attn_output_gate gates GQA's attention layers: not latent attention (kv_lora_rank), a power_retention or a cca layer")
+        if self.moe_shared_gate and not self.moe_shared_experts:
+            raise ValueError("moe_shared_gate gates the shared experts: it belongs to moe_shared_experts")
         if isinstance(self.rope_parameters, Mapping):
             # hashable, so that the config can stay a static argument
             setattr_(
@@ -524,10 +570,30 @@ class TransformerConfig:
         return tuple(i for i in range(self.n_layers) if self.layer_type(i) in (HYBRID, MAMBA2))
 
     @property
+    def linear_layers(self) -> Tuple[int, ...]:
+        """The layers with a Gated-DeltaNet mixer, in order: serving keeps a
+        delta-rule state and a convolution tail a decode lane for each
+        (``models/cache_kinds.py``), and no token's keys or values."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) == LINEAR)
+
+    @property
     def rowless_layers(self) -> Tuple[int, ...]:
         """The layers that keep no row a token in any pool or ring: power
-        retention's, and under ``mixer_block`` a Mamba-2 mixer or experts alone."""
-        return tuple(i for i in range(self.n_layers) if self.layer_type(i) in (RETENTION, MAMBA2, EXPERTS))
+        retention's, Gated DeltaNet's, and under ``mixer_block`` a Mamba-2 mixer or experts alone."""
+        return tuple(i for i in range(self.n_layers) if self.layer_type(i) in (RETENTION, MAMBA2, EXPERTS, LINEAR))
+
+    @property
+    def linear_key_width(self) -> int:
+        return self.linear_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_width(self) -> int:
+        return self.linear_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_channels(self) -> int:
+        """What a Gated-DeltaNet mixer's convolution runs over: q, k and v."""
+        return 2 * self.linear_key_width + self.linear_value_width
 
     @property
     def ssm_width(self) -> int:
@@ -630,6 +696,15 @@ def _rope(x: jax.Array, positions: jax.Array, rope: Optional[Rope]) -> jax.Array
     return jnp.stack([rx1, rx2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _rope_first(x: jax.Array, positions: jax.Array, rope: Optional[Rope], factor: float) -> jax.Array:
+    """Rotary embeddings on the first ``factor`` of each head of ``x`` [b, h, s,
+    d] (the frequencies those of a head that wide), the rest as it is."""
+    if factor == 1.0:
+        return _rope(x, positions, rope)
+    turned = int(x.shape[-1] * factor)
+    return jnp.concatenate([_rope(x[..., :turned], positions, rope), x[..., turned:]], axis=-1)
+
+
 def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     """RMSNorm's numerics, stated once: float32 mean of squares, the rest in x's dtype."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
@@ -642,6 +717,11 @@ def _layer_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
     return (centred * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def _gated(att: jax.Array, gate: jax.Array) -> jax.Array:
+    """What a head attended to, times ``sigmoid`` of its gate (taken in float32), in ``att``'s dtype."""
+    return att * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(att.dtype)
 
 
 def _maybe_partition(partition: bool, init, names):
@@ -697,10 +777,17 @@ class Attention(nn.Module):
             name=name,
         )
         with jax.named_scope("attn.qkv"):
-            q = dense((cfg.n_heads, hd), ("embed", "heads", "head_dim"), "wq")(x)
+            # under ``attn_output_gate`` a head of ``wq`` is [query | gate]
+            q = dense((cfg.n_heads, hd * (2 if cfg.attn_output_gate else 1)), ("embed", "heads", "head_dim"), "wq")(x)
             k = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wk")(x)
             v = dense((cfg.kv_heads, hd), ("embed", "kv", "head_dim"), "wv")(x)
             k = _times(k, cfg.key_multiplier)
+            if cfg.attn_output_gate:
+                q, gate = q[..., :hd], q[..., hd:]
+            if cfg.qk_norm:
+                ones = _maybe_partition(cfg.partition_params, nn.initializers.ones, ("head_dim",))
+                q = _rms(q, self.param("q_norm", ones, (hd,), cfg.param_dtype), cfg.norm_eps)
+                k = _rms(k, self.param("k_norm", ones, (hd,), cfg.param_dtype), cfg.norm_eps)
             # [b, s, h, d] -> [b, h, s, d]
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
 
@@ -710,8 +797,8 @@ class Attention(nn.Module):
                 # length; rope positions are global (contiguous assignment)
                 positions = positions + jax.lax.axis_index(cfg.seq_axis_name) * s
             rope = cfg.rope(self.layer_type)
-            q = _rope(q, positions, rope)
-            k = _rope(k, positions, rope)
+            q = _rope_first(q, positions, rope, cfg.partial_rotary_factor)
+            k = _rope_first(k, positions, rope, cfg.partial_rotary_factor)
         window = cfg.window(self.layer_type)
 
         impl = cfg.attention_impl
@@ -746,6 +833,9 @@ class Attention(nn.Module):
                 out = dot_product_attention(
                     q, k, v, causal=True, impl=impl, mesh=self.mesh, window=window
                 )
+        if cfg.attn_output_gate:
+            with jax.named_scope("attn.gate"):
+                out = _gated(out, gate.transpose(0, 2, 1, 3))
         with jax.named_scope("attn.out"):
             out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
             return nn.DenseGeneral(
@@ -859,8 +949,8 @@ class CompressedAttention(nn.Module):
             k = _l2_heads(k, math.sqrt(hd) * jnp.exp(p["tau"].astype(jnp.float32)))
         with jax.named_scope("attn.qkv"):
             q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [b, h, s, d]
-            positions, rope, turned = jnp.arange(u.shape[1]), cfg.rope(CCA), int(hd * cfg.partial_rotary_factor)
-            q, k = (jnp.concatenate([_rope(t[..., :turned], positions, rope), t[..., turned:]], axis=-1) for t in (q, k))
+            positions, rope = jnp.arange(u.shape[1]), cfg.rope(CCA)
+            q, k = (_rope_first(t, positions, rope, cfg.partial_rotary_factor) for t in (q, k))
         with jax.named_scope("attn.full"):
             out = dot_product_attention(q, k, v, causal=True, impl=cfg.attention_impl, mesh=self.mesh)
         with jax.named_scope("attn.out"):
@@ -1011,6 +1101,12 @@ def _uniform(low: float, high: float, of=lambda v: v):
     return lambda key, shape, dtype=jnp.float32: of(jax.random.uniform(key, shape, jnp.float32, low, high)).astype(dtype)
 
 
+#: a head's step bias and decay rate as Mamba-2 draws them: a step log-uniform in (0.001, 0.1) through the softplus's
+#: inverse, ``A`` in (1, 16)
+_STEP_BIAS_INIT = _uniform(math.log(1e-3), math.log(0.1), lambda u: jnp.exp(u) + jnp.log(-jnp.expm1(-jnp.exp(u))))
+_DECAY_LOG_INIT = _uniform(1.0, 16.0, jnp.log)
+
+
 def _ssm_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
     """The Mamba-2 mixer's leaves: name -> (shape, logical axes, initialiser).
     ``A_log`` and ``dt_bias`` as Mamba-2 draws them (``A`` in (1, 16), a step
@@ -1023,8 +1119,8 @@ def _ssm_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...]
         "w_in": ((d, 2 * width + 2 * cfg.ssm_groups * cfg.ssm_state + h), ("embed", "mlp"), kernel),
         "conv_w": ((cfg.ssm_conv, ch), (None, "mlp"), _uniform(-bound, bound)),
         "conv_b": ((ch,), ("mlp",), _uniform(-bound, bound)),
-        "dt_bias": ((h,), (None,), _uniform(math.log(1e-3), math.log(0.1), lambda u: jnp.exp(u) + jnp.log(-jnp.expm1(-jnp.exp(u))))),
-        "A_log": ((h,), (None,), _uniform(1.0, 16.0, jnp.log)),
+        "dt_bias": ((h,), (None,), _STEP_BIAS_INIT),
+        "A_log": ((h,), (None,), _DECAY_LOG_INIT),
         "D": ((h,), (None,), ones),
         "norm": ((width,), ("mlp",), ones),
         "w_out": ((width, d), ("mlp", "embed"), kernel),
@@ -1047,10 +1143,18 @@ def _ssm_conv(cfg: TransformerConfig, p: Dict[str, Any], rows: jax.Array) -> jax
     """The causal depthwise convolution and its SiLU: ``rows`` [b, s + ssm_conv
     - 1, channels] is what came before (zeros before a sequence's start) and then
     the ``s`` tokens; token ``t`` is ``silu(sum_i w_i rows[t + i] + bias)``."""
-    s = rows.shape[1] - cfg.ssm_conv + 1
-    w = p["conv_w"].astype(cfg.dtype)
-    out = sum(w[i] * rows[:, i: i + s] for i in range(cfg.ssm_conv))
-    return nn.silu(out + p["conv_b"].astype(cfg.dtype))
+    return _causal_conv(p["conv_w"], p["conv_b"], rows, cfg.dtype)
+
+
+def _causal_conv(w: jax.Array, bias: Optional[jax.Array], rows: jax.Array, dtype: Any) -> jax.Array:
+    """A causal depthwise convolution of ``w`` [taps, channels] (and its bias, or
+    None), taken in ``dtype``, over ``rows`` [b, s + taps - 1, channels], and its
+    SiLU: [b, s, channels]."""
+    taps = w.shape[0]
+    s = rows.shape[1] - taps + 1
+    w = w.astype(dtype)
+    out = sum(w[i] * rows[:, i: i + s] for i in range(taps))
+    return nn.silu(out if bias is None else out + bias.astype(dtype))
 
 
 def _ssm_split(cfg: TransformerConfig, xbc: jax.Array, dt: jax.Array, p: Dict[str, Any]):
@@ -1098,6 +1202,95 @@ class Mamba2(nn.Module):
             xbc = _ssm_conv(cfg, p, jnp.pad(xbc, ((0, 0), (cfg.ssm_conv - 1, 0), (0, 0))))
             x, b, c, step, a, skip = _ssm_split(cfg, xbc, dt, p)
             return _ssm_out(cfg, p, ssm_scan(x, b, c, step, a, skip, cfg.ssm_chunk), z)
+
+
+def _gdn_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
+    """The Gated-DeltaNet mixer's leaves: name -> (shape, logical axes,
+    initialiser).  ``w_in``'s columns are ``[q | k | v | z]`` and ``w_ba``'s ``[b |
+    a]``, each segment head after head (the published matrices order theirs key
+    head by key head: a fixed permutation); ``A_log`` and ``dt_bias`` as the
+    Mamba-2 mixer's are drawn (``_ssm_param_shapes``: a fresh head remembers
+    between ~1 and ~1,000 tokens), the convolution as a depthwise ``Conv1d`` is
+    (no bias), the gated norm's weight ONE head's width, shared by the heads."""
+    d, hv = cfg.d_model, cfg.linear_value_heads
+    kernel, bound = nn.initializers.lecun_normal(), cfg.linear_conv ** -0.5
+    return {
+        "w_in": ((d, cfg.linear_channels + cfg.linear_value_width), ("embed", "mlp"), kernel),
+        "w_ba": ((d, 2 * hv), ("embed", None), kernel),
+        "conv_w": ((cfg.linear_conv, cfg.linear_channels), (None, "mlp"), _uniform(-bound, bound)),
+        "dt_bias": ((hv,), (None,), _STEP_BIAS_INIT), "A_log": ((hv,), (None,), _DECAY_LOG_INIT),
+        "norm": ((cfg.linear_value_head_dim,), (None,), nn.initializers.ones),
+        "w_out": ((cfg.linear_value_width, d), ("mlp", "embed"), kernel),
+    }
+
+
+def _gdn_project(cfg: TransformerConfig, p: Dict[str, Any], u: jax.Array):
+    """The two in-projections of the normed input ``u`` [..., d]: what the
+    convolution takes (q, k, v side by side) [..., channels], the output gate
+    ``z`` [..., value width], and ``b`` and ``a`` [..., value heads] (the write
+    strength and the decay before their sigmoid and softplus)."""
+    out, ba = u @ p["w_in"].astype(cfg.dtype), u @ p["w_ba"].astype(cfg.dtype)
+    ch, hv = cfg.linear_channels, cfg.linear_value_heads
+    return out[..., :ch], out[..., ch:], ba[..., :hv], ba[..., hv:]
+
+
+def _gdn_conv(p: Dict[str, Any], rows: jax.Array) -> jax.Array:
+    """The mixer's causal depthwise convolution (no bias) and its SiLU over ``rows``
+    [b, s + linear_conv - 1, channels], in float32 whatever the rows' dtype: what
+    it feeds (the l2 norms, the rule) is float32, so the taps' products and sums
+    are not rounded to the compute dtype on their way there."""
+    return _causal_conv(p["conv_w"], None, rows.astype(jnp.float32), jnp.float32)
+
+
+def _gdn_split(cfg: TransformerConfig, qkv: jax.Array, b: jax.Array, a: jax.Array, p: Dict[str, Any]):
+    """The convolution's output [..., channels] as each VALUE head's q and k
+    [..., value heads, K] (float32, at unit length, q times ``K ** -0.5``; a key
+    head serves its consecutive value heads) and v [..., value heads, V]; the
+    decay's logarithm ``g = -exp(A_log) softplus(a + dt_bias)`` and the write
+    strength ``beta = sigmoid(b)``, float32 [..., value heads]."""
+    f32, kw, lead = jnp.float32, cfg.linear_key_width, qkv.shape[:-1]
+    hk, hv, dk = cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim
+    q = l2_heads(qkv[..., :kw].reshape(*lead, hk, dk)) * dk ** -0.5
+    k = l2_heads(qkv[..., kw: 2 * kw].reshape(*lead, hk, dk))
+    v = qkv[..., 2 * kw:].reshape(*lead, hv, cfg.linear_value_head_dim)
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + p["dt_bias"].astype(f32))
+    return jnp.repeat(q, hv // hk, axis=-2), jnp.repeat(k, hv // hk, axis=-2), v, g, jax.nn.sigmoid(b.astype(f32))
+
+
+def _gdn_out(cfg: TransformerConfig, p: Dict[str, Any], o: jax.Array, z: jax.Array) -> jax.Array:
+    """``o`` [..., value heads, V] float32 under RMSNorm over each head's
+    values, times the norm's weight, THEN times ``silu(z)`` (the norm before the
+    gate, all in float32), and the out-projection."""
+    f32, lead = jnp.float32, z.shape[:-1]
+    normed = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * p["norm"].astype(f32)
+    gated = normed * nn.silu(z.astype(f32)).reshape(*lead, cfg.linear_value_heads, -1)
+    return gated.reshape(*lead, -1).astype(cfg.dtype) @ p["w_out"].astype(cfg.dtype)
+
+
+class GatedDeltaNet(nn.Module):
+    """A Gated-DeltaNet mixer (Yang et al., arXiv:2412.06464, as Qwen3-Next runs
+    it) over the whole sequence, in its chunked form (``ops/gated_delta.py
+    gdn_chunk``): what ``init`` builds for serving, and the wide oracle of the
+    serving forward (``models/cache_kinds.py``), which reads the same leaves
+    through the same functions and carries a state and the convolution's tail
+    between its calls."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        p = {
+            name: self.param(name, _maybe_partition(cfg.partition_params, init, logical), shape, cfg.param_dtype)
+            for name, (shape, logical, init) in _gdn_param_shapes(cfg).items()
+        }
+        with jax.named_scope("attn.gdn"):
+            qkv, z, b, a = _gdn_project(cfg, p, u)
+            qkv = _gdn_conv(p, jnp.pad(qkv, ((0, 0), (cfg.linear_conv - 1, 0), (0, 0))))
+            q, k, v, g, beta = _gdn_split(cfg, qkv, b, a, p)
+            empty = jnp.zeros((u.shape[0], cfg.linear_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim), jnp.float32)
+            o, _ = gdn_chunk(q, k, v, g, beta, empty, jnp.ones(u.shape[:2], bool), chunk=cfg.linear_chunk)
+            return _gdn_out(cfg, p, o, z)
 
 
 class MLP(nn.Module):
@@ -1176,6 +1369,7 @@ class Block(nn.Module):
                     routed_scaling=cfg.moe_routed_scaling,
                     shared_experts=cfg.moe_shared_experts,
                     shared_combine=cfg.moe_shared_combine,
+                    shared_gate=cfg.moe_shared_gate,
                     zero_experts=cfg.moe_zero_experts,
                     expert_act=cfg.moe_expert_act,
                     latent_size=cfg.moe_latent_size,
@@ -1211,6 +1405,8 @@ class Block(nn.Module):
                 return Retention(cfg, name=name)(h)
             if self.layer_type == CCA:
                 return CompressedAttention(cfg, self.mesh, name=name)(h)
+            if self.layer_type == LINEAR:
+                return GatedDeltaNet(cfg, name="gdn")(h)
             if self.layer_type == HYBRID:
                 # attention heads and Mamba-2 heads read the one norm side by side
                 att = Attention(cfg, self.mesh, self.layer_type, name=name)(_times(h, cfg.attention_in_multiplier))
@@ -1537,8 +1733,9 @@ def kv_bytes_per_token(cfg: TransformerConfig) -> int:
     """Bytes of cache a token owns over all layers that cache tokens, as
     attention reads them (a latent row's padding is not counted; in a window
     layer a token owns them only while it is inside the window; a retention
-    layer caches no token: ``state_bytes_per_slot``; nor does a Mamba-2 mixer or
-    an expert layer alone, under ``mixer_block``)."""
+    layer caches no token: ``state_bytes_per_slot``; nor does a Gated-DeltaNet
+    layer: ``gdn_bytes_per_slot``; nor does a Mamba-2 mixer or an expert layer
+    alone, under ``mixer_block``)."""
     values = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) if cfg.latent else 2 * cfg.kv_heads * cfg.head_dim
     return (cfg.n_layers - len(cfg.rowless_layers)) * cfg.attn_sublayers * values * jnp.dtype(cfg.dtype).itemsize
 
@@ -1577,6 +1774,23 @@ def ssm_pool_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ...]
         ssm_pool_shape(layers, lanes, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
         (layers, lanes, cfg.ssm_conv - 1, cfg.ssm_channels),
     )
+
+
+def gdn_pool_shapes(cfg: TransformerConfig, lanes: int) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The Gated-DeltaNet layers' state pool (``ops/gated_delta.py state_shape``:
+    a slot a decode lane a layer and one scratch slot) and the convolution's
+    tails: the last ``linear_conv - 1`` rows of its input a lane a layer."""
+    layers = len(cfg.linear_layers)
+    return (
+        gdn_pool_shape(layers, lanes, cfg.linear_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+        (layers, lanes, cfg.linear_conv - 1, cfg.linear_channels),
+    )
+
+
+def gdn_bytes_per_slot(cfg: TransformerConfig) -> int:
+    """Bytes of ONE Gated-DeltaNet layer's state in one lane: what a decode step
+    reads and writes of it, whatever the context (the tail's 48 KB are not counted)."""
+    return cfg.linear_value_heads * cfg.linear_key_head_dim * cfg.linear_value_head_dim * jnp.dtype(STATE_DTYPE).itemsize
 
 
 def ssm_bytes_per_slot(cfg: TransformerConfig) -> int:

@@ -161,9 +161,9 @@ def paged_decode_attention(
         )
     impl = kernel_form.resolve_impl(
         impl, kernel_takes(head_dim, block_size, k_pool.dtype),
-        f"the paged-attention kernel needs head_dim % 128 == 0 and whole "
-        f"sublane tiles a block (got head_dim={head_dim}, "
-        f"block_size={block_size}, {k_pool.dtype})",
+        f"the paged-attention kernel takes heads of whole 128-wide lane tiles (128, 256, ...) and whole "
+        f"sublane tiles a block; it refuses a head of {head_dim} (head_dim % 128 == {head_dim % 128}) or a block of "
+        f"{block_size} tokens in {k_pool.dtype} (got head_dim={head_dim}, block_size={block_size}, {k_pool.dtype})",
     )
     if window is not None and (window < 1 or block_tables.shape[1] * block_size < window):
         raise ValueError(f"a window of {window} tokens needs a ring of at least as many (got {block_tables.shape[1]} blocks of {block_size})")

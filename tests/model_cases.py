@@ -204,6 +204,7 @@ SERVED_ARCHS = {
     "longcat_scmoe": ("paged_latent",),
     "nemotron_h": ("paged_kv", "ssm_slot"),
     "glm_moe_dsa": ("paged_indexed",),
+    "qwen3_next": ("paged_kv", "delta_slot"),
 }
 
 

@@ -99,8 +99,8 @@ def test_keys_and_queries_enter_attention_at_a_learned_length_and_half_a_head_tu
     np.testing.assert_allclose(jnp.linalg.norm(per_head, axis=-1), jnp.broadcast_to(jnp.array([1.0, 2.0, 3.0, 4.0]), (2, 7, 4)), rtol=1e-5)
     with pytest.raises(ValueError, match="partial_rotary_factor"):
         _tiny(partial_rotary_factor=0.3)                  # 4.8 values of 16: no even count
-    with pytest.raises(ValueError, match="cca layers only"):
-        _tiny(layer_types=(FULL,) * 3, moe_router="softmax", router_hidden_size=None, moe_top_k=2)
+    with pytest.raises(ValueError, match="partial_rotary_factor < 1 runs in cca layers and in GQA's attention layers"):
+        _tiny(layer_types=("power_retention",) * 3, moe_router="softmax", router_hidden_size=None, moe_top_k=2)
 
 
 @pytest.mark.parametrize("kw,says", [
