@@ -668,7 +668,10 @@ def _state_chunk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     """``s`` tokens a row after what the slots of the rows' lanes hold (nothing,
     in the walk's first chunk: a sequence starts from a zeroed slot), and into
     them: the prefill walk's chunk, and the wide prefill as one chunk.  A chunk
-    is folded whole: it leaves its lanes no recent row pending."""
+    is folded whole: it leaves its lanes no recent row pending.  A wide chunk's
+    narrow chunks are answered and folded one after the other, the state in
+    hand (``_narrow_chunks``: the chunk kernel sees a narrow chunk's tokens a
+    call either way), and the slots are read and written once a chunk."""
     state_leaf, norm_leaf, *_, pending_leaf = STATE_SLOT.leaves
     lanes = jnp.arange(rows.live.shape[0]) if rows.lanes is None else rows.lanes
     fresh = rows.chunk == rows.first_chunk
@@ -676,7 +679,12 @@ def _state_chunk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
     def retain(q, k, v, log_g, cache, j):
         state, norm = cache[state_leaf][j, lanes], cache[norm_leaf][j, lanes]
         state, norm = jnp.where(fresh, 0.0, state), jnp.where(fresh, 0.0, norm)
-        out, state, norm = retention_chunk(q, k, v, log_g, state, norm, rows.live)
+
+        def chunk(held, rows, q, k, v, log_g):
+            out, *held = retention_chunk(q, k, v, log_g, *held, rows.live)
+            return tuple(held), (out,)
+
+        (state, norm), (out,) = _narrow_chunks(rows, chunk, (state, norm), (q, k, v, log_g), (2, 2, 2, 2), (2,))
         return out.astype(cfg.dtype), {
             **cache, state_leaf: cache[state_leaf].at[j, lanes].set(state), norm_leaf: cache[norm_leaf].at[j, lanes].set(norm),
             pending_leaf: cache[pending_leaf].at[j, lanes].set(0),
@@ -1010,10 +1018,6 @@ class CacheKind:
     #: ``setup(cfg, sizes)``: what a kind of the model adds to the ``serve.setup.kv_pool`` span
     report: Callable = lambda cfg, sizes, live, gauges=None: {}
     setup: Callable = lambda cfg, sizes: {}
-    #: the prefill walk may take its layers a WIDE chunk at a time (``models/serving.py PREFILL_WIDE_TOKENS``), what
-    #: the kind keeps written and read a narrow chunk at a time inside it (``_narrow_chunks``); False: a model with
-    #: such a layer is walked in narrow chunks alone, whatever its prompts' length
-    wide_walk: bool = True
     #: its layers are those of a model whose attention reads the keys an indexer picks (``indexer_types``), or is not;
     #: such layers hand their picks on: ``mix(p, x, h, cache, j, handed) -> (x, cache, handed)``
     indexed: bool = False
@@ -1103,10 +1107,6 @@ STATE_SLOT = CacheKind(
         "nobody keeps). Set prefix_cache: false"
     ),
     step=_state_step, walk=_every_chunk(_state_chunk), table=_state_step, wide=_state_chunk,
-    # a chunk of the walk is its own arithmetic here (2.4 ms + 32 us a token on a v5e: a wide chunk took 17 % off a walk),
-    # and the chunk kernel is most of the prefill program: a second loop with its own five copies of it took 13 s longer
-    # to load, which a replica pays at every start (PERF.md section 5 "PR 62")
-    wide_walk=False,
     # the lanes whose state the step updated, and the bytes of state those hold over the retention layers
     counters=("serve.state.live_lanes", "serve.state.bytes"), count=_state_count,
     gauges=("serve.state.pending_rows",), gauge=_state_gauge,

@@ -363,15 +363,15 @@ def prefill_chunk_tokens(block_size: int, prompt_tokens: int) -> int:
     return min(chunk, -(-prompt_tokens // block_size) * block_size)
 
 
-def prefill_wide_chunks(cfg: TransformerConfig, chunk: int, prompt_tokens: int) -> int:
+def prefill_wide_chunks(chunk: int, prompt_tokens: int) -> int:
     """Narrow chunks of ``chunk`` tokens a wide chunk of the walk holds
     (``PREFILL_WIDE_TOKENS`` in whole narrow chunks), from the shapes; 1 where the
     walk has no wide loop: one is built only where it can engage, for prompts
-    padded to ``prompt_tokens`` that hold at least eight wide chunks, and where
-    every cache kind of the model's layers takes one (``CacheKind.wide_walk``)."""
+    padded to ``prompt_tokens`` that hold at least eight wide chunks.  One rule
+    for every model: each cache kind takes a wide chunk (what it keeps written
+    and read a narrow chunk at a time inside it, ``cache_kinds._narrow_chunks``)."""
     per_wide = max(PREFILL_WIDE_TOKENS // chunk, 1)
-    takes = all(kind.wide_walk for kind in cache_kinds(cfg))
-    return per_wide if takes and prompt_tokens >= 8 * per_wide * chunk else 1
+    return per_wide if prompt_tokens >= 8 * per_wide * chunk else 1
 
 
 def prefill_walk_chunks(per_wide: int, first: int, end: int) -> Tuple[int, int]:
@@ -465,7 +465,7 @@ def transformer_prefill_chunked(
             f"chunked prefill needs tokens padded to whole chunks (got S={s}, "
             f"chunk={chunk}, block_size={block_size})"
         )
-    per_wide = prefill_wide_chunks(cfg, chunk, s)
+    per_wide = prefill_wide_chunks(chunk, s)
     lanes = jnp.arange(b, dtype=jnp.int32) if lanes is None else lanes.astype(jnp.int32)
     t = block_tables.shape[1]
     at_chunk = {kind.name: kind.walk(cfg, cache, lanes, chunk) for kind in kinds}  # what a kind prepares once a call

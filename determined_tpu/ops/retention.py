@@ -51,7 +51,14 @@ of the tokens a decode step has not folded into the slot yet, at most
   is a second Pallas kernel, grid (row, KV head): ``phi`` of the chunk's
   queries and keys is built a feature row at a time in VMEM, each row of the
   state is read once, answers the queries as it comes, takes the keys and is
-  written back.  Its ``jax.numpy`` form holds ``phi`` of the queries whole.
+  written back.  The feature rows are a LOOP (``fori_loop``, five rows a
+  trip) whose body is stated once: ``phi``'s row ``r`` is a lane rotation by
+  ``r`` (a dynamic shift), its weight a compare on ``r``, the state's row a
+  slice at ``r * d``.  Written out 65 times the same arithmetic was 293 KB of
+  serialized Mosaic body and 41 s of compile a call; the loop is 30 KB and
+  under 2 s, small enough to stand in both loops of the walk, and 11 % faster
+  a call (PERF.md section 5 "PR 65").
+  Its ``jax.numpy`` form holds ``phi`` of the queries whole.
 * :func:`retention_quadratic`: the masked quadratic form over a whole
   sequence: the training forward, and the oracle of both forms above.
 """
@@ -137,8 +144,9 @@ def retention_quadratic(q: jax.Array, k: jax.Array, v: jax.Array, log_g: jax.Arr
 
 def chunk_kernel_takes(query_rows: int, tokens: int, head_dim: int, state_dtype) -> bool:
     """Whether the chunk kernel runs these shapes: a head is one lane tile, the
-    chunk's rows are whole sublane tiles, and a KV head's queries fit VMEM
-    beside its state (the walk's 256 tokens x 5 query heads do)."""
+    chunk's rows are whole sublane tiles, and a KV head's queries and their
+    answers fit VMEM beside its state (the walk's 256 tokens x 5 query heads
+    are 1,280 rows; 4,096 compile for a v5e under ``_VMEM_LIMIT_BYTES``)."""
     return kernel_takes(head_dim, state_dtype) and tokens % 8 == 0 and tokens <= 512 and query_rows <= 4096
 
 
@@ -226,33 +234,56 @@ def _query_state(pq, s_old):
     return _nt(a, b) + (_nt(a, b_lo) + _nt(a_lo, b))
 
 
-def _retention_chunk_kernel(q_ref, k_ref, left_ref, vt_ref, kept_ref, s_ref, z_ref, num_ref, den_ref, s_out, z_out, *, d, block):
+#: feature rows a trip of the chunk kernel's loop takes (65 = 13 x 5).  One row a trip stalls between trips: the next
+#: row's loads and rotations cannot start under this row's products (0.604 ms a call at Brumby's shape on a v5e where
+#: the 65 rows written out took 0.590); five rows a trip give the scheduler that room (0.525), thirteen gain no more
+#: (0.536) and the body grows with the factor (13 / 30 / 64 KB serialized at 1 / 5 / 13: PERF.md section 5 "PR 65")
+_CHUNK_ROWS_A_TRIP = 5
+
+
+def _retention_chunk_kernel(q_ref, k_ref, left_ref, vt_ref, kept_ref, s_ref, z_ref, num_ref, den_ref, s_out, z_out, z32, *, d, block):
     """One (row of the batch, KV head): ``q_ref`` [m, d] the KV head's queries
     (query head by token), ``k_ref`` [s, d] the keys, ``left_ref`` [s, 1] each
     key's decay to the chunk's end (0: no such token), ``vt_ref`` [d, s] the
     values as columns, ``kept_ref`` [1, 1]; ``s_ref`` [rows * d, d] and
-    ``z_ref`` [rows, d] the head's state.  The queries read the state AS IT
-    COMES, ``block`` rows at a time; the keys and values enter it after."""
+    ``z_ref`` [rows, d] the head's state; ``z32`` [rows, d] float32 scratch.
+    The feature rows are a LOOP whose body is stated once: row ``r`` of the
+    state answers the queries AS IT COMES, ``block`` rows at a time, then takes
+    the keys and values and is written back."""
     f32 = jnp.float32
     key, vt, kept = k_ref[...], vt_ref[...], kept_ref[...]
     weighed = key * left_ref[...]
     num_ref[...] = jnp.zeros(num_ref.shape, f32)
     den_ref[...] = jnp.zeros(den_ref.shape, f32)
-    for r in range(phi_rows(d)):
-        w = _weight(r, d)
-        rows = pl.ds(r * d, d)
+    # the normaliser is read whole before any row is written, and a row of it at a dynamic index comes from 32-bit
+    # rows alone (a bfloat16 state's lie two a sublane)
+    z32[...] = z_ref[...].astype(f32)
+
+    def feature_row(r):
+        w = jnp.where(jnp.logical_or(r == 0, r == d // 2), 1.0, math.sqrt(2.0)).astype(f32)  # ``_weight``
+        shift = jax.lax.rem(d - r, d)  # rolled by it, column c holds x[:, (c + r) % d]
+        rows = pl.ds(pl.multiple_of(r * d, d), d)
         s_old = s_ref[rows, :].astype(f32)  # [d (value), d (feature column)]
-        z_old = z_ref[r:r + 1, :].astype(f32)
+        z_old = z32[pl.ds(r, 1), :]
         for lo in range(0, q_ref.shape[0], block):
             q = q_ref[lo:lo + block, :]
-            pq = q * (q if r == 0 else pltpu.roll(q, d - r, 1)) * w  # phi's row r of the queries: column c holds q[:, (c + r) % d]
+            pq = q * pltpu.roll(q, shift, 1) * w  # phi's row r of the queries
             num_ref[lo:lo + block, :] += _query_state(pq, s_old)
             den_ref[lo:lo + block, :] += jnp.sum(pq * z_old, axis=1, keepdims=True)
-        pk = weighed * (key if r == 0 else pltpu.roll(key, d - r, 1)) * w
+        pk = weighed * pltpu.roll(key, shift, 1) * w
         # [d, s] x [s, d] at HIGHEST agrees with the jax.numpy form to 7.6e-6 on the chip (the queries' product does not: _query_state)
         entered = jax.lax.dot_general(vt, pk, (((1,), (0,)), ((), ())), precision=_HIGHEST, preferred_element_type=f32)
         s_out[rows, :] = (kept * s_old + entered).astype(s_out.dtype)
-        z_out[r:r + 1, :] = (kept * z_old + jnp.sum(pk, axis=0, keepdims=True)).astype(z_out.dtype)
+        z32[pl.ds(r, 1), :] = kept * z_old + jnp.sum(pk, axis=0, keepdims=True)
+
+    def trip(i, carry):
+        for j in range(_CHUNK_ROWS_A_TRIP):
+            feature_row(i * _CHUNK_ROWS_A_TRIP + j)
+        return carry
+
+    assert phi_rows(d) % _CHUNK_ROWS_A_TRIP == 0  # ``kernel_takes``: a head of 128, 65 rows
+    jax.lax.fori_loop(0, phi_rows(d) // _CHUNK_ROWS_A_TRIP, trip, 0)
+    z_out[...] = z32[...].astype(z_out.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -262,7 +293,8 @@ def _chunk_state_pallas(qf, kf, vf, left, kept, state, norm, *, interpret: bool)
     rows, m = phi_rows(d), n * s
     at = lambda bi, gi: (bi, gi, 0, 0)  # noqa: E731
     num0, den0, s1, z1 = pl.pallas_call(
-        functools.partial(_retention_chunk_kernel, d=d, block=s),  # a query head's rows at a time
+        # a query head's rows at a time: a product of all 1,280 rows read slower on a v5e, its operands stream through VMEM
+        functools.partial(_retention_chunk_kernel, d=d, block=s),
         grid=(b, g),
         in_specs=[
             pl.BlockSpec((None, None, m, d), at),
@@ -285,6 +317,7 @@ def _chunk_state_pallas(qf, kf, vf, left, kept, state, norm, *, interpret: bool)
             jax.ShapeDtypeStruct(state.shape, state.dtype),
             jax.ShapeDtypeStruct(norm.shape, norm.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
         input_output_aliases={5: 2, 6: 3},  # the lanes' slots as the walk gathered them: updated where they lie
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT_BYTES

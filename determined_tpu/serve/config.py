@@ -121,13 +121,13 @@ class ServeConfig:
         chunk = self.prefill_chunk
         return -(-prompt_tokens // chunk) - cached_tokens // chunk
 
-    def prefill_wide(self, model_cfg: Any) -> int:
-        """Narrow chunks a WIDE iteration of the walk of ``model_cfg`` takes at
-        once; 1 where the walk has no wide loop (``max_prompt_len`` too short
-        for one, or a layer of the model of a cache kind that takes none)."""
+    @property
+    def prefill_wide(self) -> int:
+        """Narrow chunks a WIDE iteration of the walk takes at once; 1 where the
+        walk has no wide loop (``max_prompt_len`` too short for one)."""
         from determined_tpu.models.serving import prefill_wide_chunks
 
-        return prefill_wide_chunks(model_cfg, self.prefill_chunk, self.prefill_chunks(self.max_prompt_len) * self.prefill_chunk)
+        return prefill_wide_chunks(self.prefill_chunk, self.prefill_chunks(self.max_prompt_len) * self.prefill_chunk)
 
     def prefill_walk(self, per_wide: int, prompt_tokens: int, cached_tokens: int = 0) -> Tuple[int, int]:
         """(wide, narrow) iterations of the walk over those chunks, ``per_wide``

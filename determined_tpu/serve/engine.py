@@ -348,9 +348,9 @@ class DecodeKernels:
         #: the prefill's token width: the longest prompt in whole chunks
         #: (one trace; the walk's trip count follows each prompt)
         self._prompt_pad = serve_cfg.prefill_chunks(serve_cfg.max_prompt_len) * serve_cfg.prefill_chunk
-        #: narrow chunks a wide iteration of this model's walk takes at once (1:
+        #: narrow chunks a wide iteration of the walk takes at once (1:
         #: its program has no wide loop): what the engine counts an admission's sweeps by
-        self.prefill_wide = serve_cfg.prefill_wide(model_cfg)
+        self.prefill_wide = serve_cfg.prefill_wide
         sentinel = get_retrace_sentinel()
         # cold requests run it with start=0, warm requests from the chunk
         # of their first un-cached block; either way it is the SAME trace

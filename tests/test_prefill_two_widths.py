@@ -9,7 +9,9 @@ warm start, on or off a wide chunk's edge, is walked narrow throughout and
 leaves the cold walk's; the host's count of the walk's iterations is the walk's
 own; and a program whose prompts cannot hold eight wide chunks has no wide loop."""
 
+import dataclasses
 import functools
+import inspect
 from unittest import mock
 
 import jax
@@ -39,12 +41,12 @@ def scaled(wide_tokens=WIDE):
 
 
 @functools.lru_cache(maxsize=None)
-def walks(arch_name):
-    """(cfg, params, an empty cache, the walk compiled two-width, the walk compiled narrow throughout)."""
-    cfg, params, _, _ = tiny_served(arch_name, max_prompt_len=PAD, max_new_tokens=8, num_blocks=1 + LANES * (PAD // BLOCK + 2))
-    cache = init_kv_cache(cfg, 1 + LANES * (PAD // BLOCK + 2), BLOCK, lanes=LANES, chunk_tokens=NARROW)
+def walks(arch_name, pad=PAD):
+    """(cfg, params, an empty cache, the walk compiled two-width, the walk compiled narrow throughout) at prompts padded to ``pad``."""
+    cfg, params, _, _ = tiny_served(arch_name, max_prompt_len=pad, max_new_tokens=8, num_blocks=1 + LANES * (pad // BLOCK + 2))
+    cache = init_kv_cache(cfg, 1 + LANES * (pad // BLOCK + 2), BLOCK, lanes=LANES, chunk_tokens=NARROW)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    avals = (params, i32(2, PAD), i32(2), i32(2), i32(2, PAD // BLOCK + 2), cache, i32(2))
+    avals = (params, i32(2, pad), i32(2), i32(2), i32(2, pad // BLOCK + 2), cache, i32(2))
     compiled = []
     for wide_tokens in (WIDE, NARROW):
         narrow_chunk, wide_chunk = scaled(wide_tokens)
@@ -54,12 +56,12 @@ def walks(arch_name):
     return cfg, params, cache, compiled[0], compiled[1]
 
 
-def prompts(seed, lens):
+def prompts(seed, lens, pad=PAD):
     rng = np.random.default_rng(seed)
-    tokens = np.zeros((2, PAD), np.int32)
+    tokens = np.zeros((2, pad), np.int32)
     for row, n in enumerate(lens):
         tokens[row, :n] = rng.integers(1, 250, n)
-    width = PAD // BLOCK + 2
+    width = pad // BLOCK + 2
     tables = 1 + np.arange(2 * width, dtype=np.int32).reshape(2, width)  # block 0 is the scratch block
     return tokens, tables
 
@@ -128,6 +130,24 @@ def test_a_warm_start_on_or_off_a_wide_edge_is_the_cold_start(arch_name, start, 
     same_cache(cfg, warm, cold, f"{arch_name} from {start}, the prefix alone", exact=True)
 
 
+def test_a_model_of_retention_layers_alone_is_walked_two_width():
+    """Brumby's block: no pool, so the walk is told its chunk (``chunk_tokens``); a cold prompt of eight wide chunks and
+    a tail (one narrow chunk and 3 tokens of the next) beside a short one: the last rows' logits, the state and the
+    normaliser are the narrow walk's, and the two-width program does hold a wide loop (until PR 65 the kind opted out)."""
+    cfg, params, cache, two_width, narrow = walks("power_retention", PAD + WIDE)
+    assert [kind.name for kind in cache_kinds(cfg)] == ["state_slot"]
+    assert f"[2,{WIDE}]" in two_width.as_text() and f"[2,{WIDE}]" not in narrow.as_text()  # a wide chunk's tokens
+    lens = np.asarray([PAD + NARROW + 3, 2 * WIDE + 1], np.int32)
+    tokens, tables = prompts(65, lens, PAD + WIDE)
+    args = (params, tokens, np.zeros(2, np.int32), lens, tables, cache, np.asarray([1, 2], np.int32))
+    want_logits, want = narrow(*args)
+    got_logits, got = two_width(*args)
+    assert np.isfinite(np.asarray(want_logits)).all() and np.abs(np.asarray(want["rs"])[:, 1:]).max() > 0
+    same(got_logits, want_logits)
+    same_cache(cfg, got, want, "power_retention, eight wide chunks and a tail")
+    assert not np.asarray(got["rs"])[:, 0].any() and not np.asarray(got["rn"]).any()  # a lane no row walked; no row left pending
+
+
 def test_an_indexers_picks_of_a_wide_chunk_are_its_narrow_chunks_picks():
     """GLM-5.2's block: the mask a layer that holds an indexer hands on is made a narrow chunk's queries at a time,
     and side by side the parts are the masks the narrow chunks' own iterations make."""
@@ -181,15 +201,15 @@ def test_the_wide_loop_is_built_where_the_prompts_hold_eight_wide_chunks():
     """A rule on the shapes: the narrow chunk and the padded width decide; a short width lowers to the one loop."""
     assert serving.PREFILL_WIDE_TOKENS == 4 * serving.PREFILL_CHUNK_TOKENS == 1024
     cfg, params, cache, _, _ = walks("dense_decoder")
-    assert [prefill_wide_chunks(cfg, 256, pad) for pad in (512, 4096, 8192 - 256, 8192, 24576)] == [1, 1, 1, 4, 4]
-    assert prefill_wide_chunks(cfg, 384, 8 * 768) == 2 and prefill_wide_chunks(cfg, 384, 8 * 768 - 384) == 1  # a 48-token block
-    assert prefill_wide_chunks(cfg, 1024, 65536) == 1  # a narrow chunk as wide as the wide one: nothing to widen
-    # and the kinds decide: a model with a retention layer is walked narrow whatever its prompts hold (its chunk
-    # kernel twice in a program is seconds of every start), every other kind takes a wide chunk
-    assert {kind.name for kind in CACHE_KINDS if not kind.wide_walk} == {"state_slot"}
-    assert [prefill_wide_chunks(walks(name)[0], 256, 24576) for name in SERVED_ARCHS] == [1 if name == "power_retention" else 4 for name in SERVED_ARCHS]
+    assert [prefill_wide_chunks(256, pad) for pad in (512, 4096, 8192 - 256, 8192, 24576)] == [1, 1, 1, 4, 4]
+    assert prefill_wide_chunks(384, 8 * 768) == 2 and prefill_wide_chunks(384, 8 * 768 - 384) == 1  # a 48-token block
+    assert prefill_wide_chunks(1024, 65536) == 1  # a narrow chunk as wide as the wide one: nothing to widen
+    # and the shapes ALONE decide: the rule is handed no model and no cache kind has a say (the retention layers' chunk
+    # kernel is a loop since PR 65, small enough to stand in both loops of a program)
+    assert list(inspect.signature(prefill_wide_chunks).parameters) == ["chunk", "prompt_tokens"]
+    assert "wide_walk" not in {field.name for field in dataclasses.fields(CACHE_KINDS[0])}
     for serve, per_wide in ((dict(max_prompt_len=4096), 1), (dict(max_prompt_len=8192), 4), (dict(max_prompt_len=8000), 4)):
-        assert ServeConfig(block_size=16, num_blocks=1024, max_new_tokens=64, **serve).prefill_wide(cfg) == per_wide
+        assert ServeConfig(block_size=16, num_blocks=1024, max_new_tokens=64, **serve).prefill_wide == per_wide
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
 
     def loops(pad):

@@ -5,8 +5,9 @@ case is one compile at a cell's published widths, its lanes, pool and table,
 depth alone cut: DeepSeek-V3's walk, decode program and latent kernel,
 InternLM2's walk and decode kernel, Command A+'s programs and window kernel,
 Brumby's programs and retention kernel.  Cut from that file because the
-heaviest compile of the suite is here (the Brumby walk's two chunk kernels,
-65 unrolled feature rows each), and ``--dist loadfile`` balances by the file."""
+heaviest compiles of the suite are here, and ``--dist loadfile`` balances by
+the file (until PR 65 the Brumby walk's chunk kernel was the heaviest, 65
+unrolled feature rows a copy: it is a loop now, and the walk holds it twice)."""
 
 import functools
 import importlib
@@ -427,6 +428,32 @@ def test_the_retention_decode_kernel_compiles_at_the_brumby_cells_shape(tpu_devi
     assert mem.alias_size_in_bytes >= pool_bytes + row_bytes and mem.temp_size_in_bytes < 16 * 1024**2
 
 
+@pytest.mark.parametrize("heads,tokens,state_dtype", [(5, 256, jnp.float32), (5, 256, jnp.bfloat16), (8, 512, jnp.float32)], ids=["cell", "bfloat16_state", "most_rows"])
+def test_the_retention_chunk_kernel_compiles_as_a_loop_over_its_feature_rows(tpu_devices, heads, tokens, state_dtype):
+    """The walk's chunk at the cell's shape (8 KV heads x 5 query heads x 256 tokens: 1,280 query rows a program), with
+    the state the check's control sets (a bfloat16 row cannot be read at a dynamic index: the normaliser is held in a
+    float32 scratch), and at the most rows ``chunk_kernel_takes`` admits (4,096: they and their answers fit VMEM beside
+    the state's blocks): the feature rows as a loop, a dynamic lane rotation and a dynamic row of the state are what
+    Mosaic has to take, and interpret mode shows none of it."""
+    retention_mod = importlib.import_module("determined_tpu.ops.retention")
+    one = SingleDeviceSharding(tpu_devices[0])
+    aval = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    b, g, d = 1, 8 if tokens == 256 else 2, 128
+    assert retention_mod.chunk_kernel_takes(heads * tokens, tokens, d, state_dtype)
+    assert tokens == 256 or not retention_mod.chunk_kernel_takes(heads * tokens + 8, tokens, d, state_dtype)  # the bound itself
+    rows = retention_mod.phi_rows(d)
+    fn = jax.jit(functools.partial(retention_mod._chunk_state_pallas, interpret=False), donate_argnums=(5, 6))
+    lowered = fn.lower(
+        aval((b, g, heads, tokens, d)), aval((b, g, tokens, d)), aval((b, g, tokens, d)), aval((b, g, tokens)), aval((b, g)),
+        aval((b, g, rows * d, d), state_dtype), aval((b, g, rows, d), state_dtype),
+    )
+    (body,) = re.findall(r'backend_config = "([^"]*)"', lowered.as_text())
+    assert len(body) < (32 if heads == 5 else 48) * 1024                             # five rows a trip; the 65 rows written out were 293 KB at the cell's shape
+    compiled = lowered.compile()
+    assert _kernels(compiled.as_text()) == 1 and "retention_chunk" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024**2                   # the state is updated where it lies
+
+
 @pytest.mark.parametrize("which", ["decode", "prefill"])
 def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, which):
     """The cell's decode step and prefill walk at its widths, lanes and state
@@ -476,9 +503,16 @@ def test_the_brumby_cells_programs_compile_over_a_state_pool_alone(tpu_devices, 
         assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_decode")}) == 2
         assert "conditional(" not in text
         assert _arrays_with_dims(text, (32, 1792)) == []                             # the block tables are read by nothing
-    else:   # the chunk's pass over the state, a layer: ONE loop although 22,528 tokens hold eight wide chunks (the kind takes no
-        # wide chunk, ``CacheKind.wide_walk``: a second loop's copies of this kernel are 13 s of a replica's start on a v5e)
-        assert _kernels(text) == 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 2
+    else:
+        # 22,528 tokens hold eight wide chunks: the walk has its wide loop and its narrow one, ONE chunk kernel a layer in
+        # each (a wide chunk's narrow chunks pass through it one after the other under a scan: it sees 256 tokens a call
+        # either way); the kernel's feature rows are a loop, so its serialized Mosaic body is ~30 KB where the 65 unrolled
+        # copies were 293 KB a call (and 45 s of this compile); the walk gathers its lane's slots and holds no second pool
+        assert _kernels(text) == 2 * 2 and len({n for n in scopes["serve.retention.state"] if n.startswith("retention_chunk")}) == 4
+        assert text.count(" while(") == 2 + 2                                         # the two loops of the walk; a scan a layer inside the wide one
+        bodies = re.findall(r'stablehlo.custom_call @tpu_custom_call.*?backend_config = "([^"]*)"', fn.lower(*args).as_text())
+        assert bodies and max(len(body) for body in bodies) < 32 * 1024             # one jitted function, called by every layer of both loops
+        assert mem.temp_size_in_bytes < 32 * 34_344_960 // 2                          # no scratch the size of a layer's state: a wide chunk's slot stays in hand through its scan
 
 
 def test_the_ssm_decode_kernel_compiles_at_the_falcon_cells_shape(tpu_devices):

@@ -67,7 +67,7 @@ def test_the_qwen3_next_cells_programs_compile_over_linear_and_full_layers(tpu_d
         param_dtype=jnp.bfloat16, moe_experts=512, moe_every=1, moe_top_k=10, moe_intermediate_size=512, moe_experts_held=(0, 64),
         moe_shared_experts=1, moe_shared_intermediate_size=512, moe_shared_gate=True,
     )
-    assert cache_kinds(cfg) == (PAGED_KV, DELTA_SLOT) and prefill_wide_chunks(cfg, 256, 12288) == 4
+    assert cache_kinds(cfg) == (PAGED_KV, DELTA_SLOT) and prefill_wide_chunks(256, 12288) == 4
     boxed = jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
     params = jax.tree.map(on_chip, flax_meta.unbox(boxed)["params"])

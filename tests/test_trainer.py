@@ -387,6 +387,7 @@ def test_drain_healthy_ranks_finalize_and_emit_stall_span(tmp_path, monkeypatch)
 
     tracer = get_tracer()
     tracer.reset()
+    tracer.configure(enabled=True)  # whatever an earlier file of this worker left it as (a server set up without a trace directory turns it off)
     trainer = _trainer_with_pending_save(tmp_path, monkeypatch)
     fake = _FakeDist(peer_flags=[False])
     trainer.core.distributed = fake
