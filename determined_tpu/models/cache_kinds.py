@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from determined_tpu.models import transformer
 from determined_tpu.models.transformer import (
     FULL, HYBRID, LINEAR, MAMBA2, RETENTION, SLIDING, TransformerConfig, _gate_log, _gated, _gdn_conv, _gdn_out, _gdn_project,
-    _gdn_split, _index_project, _latent_attend_local, _latent_project, _rms, _rope, _rope_first,
+    _gdn_split, _head_gated, _index_project, _latent_attend_local, _latent_project, _rms, _rope, _rope_first,
     _ssm_conv, _ssm_out, _ssm_project, _ssm_split, _times, gdn_bytes_per_slot, gdn_pool_shapes, kv_bytes_per_token, kv_cache_shape,
     ssm_bytes_per_slot, recent_rows_shapes, ssm_pool_shapes, state_bytes_per_slot, state_pool_shapes, window_ring_blocks,
     window_store_shape,
@@ -436,7 +436,8 @@ def _ring_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
 def _latent_mixer(cfg: TransformerConfig, rows: Rows, attend, select=None):
     """Latent attention's projections, this call's rows ``[c_kv after its norm |
     k_r after rope | zeros]`` into the pool at ``rows.where``, ``attend``
-    against the pool that now holds them, and the output projection.  Under
+    against the pool that now holds them, under ``attn_output_gate`` the gate a
+    head, and the output projection.  Under
     ``indexer_types`` (``select``: :func:`_indexer`) the mixer is of the kind
     that hands on: a layer that holds an indexer first makes its own picks,
     every layer attends over the picks it holds or was handed, and returns them."""
@@ -450,12 +451,15 @@ def _latent_mixer(cfg: TransformerConfig, rows: Rows, attend, select=None):
                 row = jnp.concatenate([c_kv, k_r], axis=-1)
                 row = jnp.pad(row, ((0, 0), (0, 0), (0, cache[leaf].shape[-1] - row.shape[-1])))
                 cache = {**cache, leaf: cache[leaf].at[j, phys, slots].set(row.reshape(*phys.shape, -1))}
-        row = cfg.index_layer(j)  # every layer keeps latent rows: row j of the pool is layer j's
+        row = cfg.index_layer(j) if select is not None else None  # under an indexer every layer keeps latent rows: row j of the pool is layer j's
         if row is not None:
             with jax.named_scope("serve.dsa"):
                 cache, picks = select(p, c_q, h, cache, row)
         with jax.named_scope("serve.mla"):
             att = attend(q_nope, q_rope, c_kv, k_r, p["wkv_b"], cache, j, picks)
+            if cfg.attn_output_gate:
+                with jax.named_scope("serve.mla.gate"):  # one value a head from the normed input
+                    att = _head_gated(cfg, p, h, att)
             return x + jnp.einsum("bshv,hvD->bsD", att, p["wo"].astype(cfg.dtype)), cache, picks
 
     return mix if select is not None else lambda p, x, h, cache, j: mix(p, x, h, cache, j)[:2]
@@ -889,17 +893,21 @@ def _ssm_setup(cfg: TransformerConfig, sizes: Any) -> Dict[str, Any]:
 def _gdn_mixer(cfg: TransformerConfig, conv, rule):
     """A Gated-DeltaNet mixer is its layer's only mixer before the experts: it
     reads the layer's first norm, writes no row a token and adds its output to
-    the stream."""
+    the stream.  Its scopes are ``serve.gdn.*`` under a decay a head and
+    ``serve.kda.*`` under a decay a channel (Kimi Delta Attention's form of it): a
+    share by scope then tells the two forms' cells apart by its rule, as
+    ``_ssm_mixer``'s; the kind, its leaves and its counters are one."""
+    scope = "serve.kda" if cfg.linear_channel_decay else "serve.gdn"
 
     def mix(p, x, h, cache, j):
-        with jax.named_scope("serve.gdn.proj"):  # the two in-projections
+        with jax.named_scope(scope + ".proj"):  # the in-projections (two; a decay a channel has a third of its own)
             qkv, z, b, a = _gdn_project(cfg, p, h)
-        with jax.named_scope("serve.gdn.conv"):  # the tail, the convolution, the heads' norms, decay and write strength
+        with jax.named_scope(scope + ".conv"):  # the tail, the convolution, the heads' norms, decay and write strength
             qkv, cache = conv(p, qkv, cache, j)
             parts = _gdn_split(cfg, qkv, b, a, p)
-        with jax.named_scope("serve.gdn.state"):  # decay, S^T k, the corrected update, the read-out
+        with jax.named_scope(scope + ".state"):  # decay, S^T k, the corrected update, the read-out
             o, cache = rule(*parts, cache, j)
-        with jax.named_scope("serve.gdn.out"):  # the gated norm, the out-projection
+        with jax.named_scope(scope + ".out"):  # the gated norm, the out-projection
             return x + _gdn_out(cfg, p, o, z), cache
 
     return mix
@@ -923,7 +931,7 @@ def _gdn_walk(cfg: TransformerConfig, rows: Rows, cache: Any = None):
         state = jnp.where(fresh, 0.0, cache[state_leaf][j, lanes])
 
         def chunk(state, rows, q, k, v, g, beta):
-            with jax.named_scope("serve.gdn.chunk"):
+            with jax.named_scope("serve.gdn.chunk"):  # under either form's ``.state``
                 o, state = gdn_chunk(q, k, v, g, beta, state, rows.live, chunk=cfg.linear_chunk)
             return state, (o,)
 
@@ -982,11 +990,12 @@ class CacheKind:
     """One kind of cache: everything the forward and the engine know of it."""
 
     name: str
-    #: its layers in a config: those of one of ``layer_types`` in a model whose attention is ``latent`` (or is
-    #: not).  A type that several kinds name makes a layer of several kinds.  The first is the type whose
-    #: rotary parameters and scope the kind's mixer takes
+    #: its layers in a config: those of one of ``layer_types`` in a model whose full layers' attention is
+    #: ``latent`` (or is not; None: the kind's layers keep no attention row, and are its layers beside either).
+    #: A type that several kinds name makes a layer of several kinds.  The first is the type whose rotary
+    #: parameters and scope the kind's mixer takes
     layer_types: Tuple[str, ...]
-    latent: bool
+    latent: Optional[bool]
     #: the arrays it owns in the cache, ``shapes(cfg, sizes)`` -> a shape each (``sizes``: a ``ServeConfig``'s
     #: ``num_blocks``, ``block_size``, ``max_batch``, ``prefill_chunk``) and ``dtypes(cfg)`` -> a dtype each
     leaves: Tuple[str, ...]
@@ -1026,7 +1035,7 @@ class CacheKind:
         """The layers of ``cfg`` that are of this kind, in order: layer ``layers(cfg)[j]`` owns row ``j`` of its
         arrays, or with ``cfg.attn_sublayers`` attention sublayers a block the rows ``j * attn_sublayers ..``, one
         a sublayer (:func:`layer_kinds`)."""
-        if cfg.latent != self.latent or bool(cfg.indexer_types) != self.indexed:
+        if self.latent not in (None, cfg.latent) or bool(cfg.indexer_types) != self.indexed:
             return ()
         return tuple(i for i in range(cfg.n_layers) if cfg.layer_type(i) in self.layer_types)
 
@@ -1146,7 +1155,7 @@ SSM_SLOT = CacheKind(
 )
 
 DELTA_SLOT = CacheKind(
-    name="delta_slot", layer_types=(LINEAR,), latent=False, leaves=("gdn", "gconv"), shapes=_gdn_shapes, holds=LANE,
+    name="delta_slot", layer_types=(LINEAR,), latent=None, leaves=("gdn", "gconv"), shapes=_gdn_shapes, holds=LANE,
     params="gdn",
     # the state where it is stated (the benchmark's check sets another there); the tail as the convolution reads it
     dtypes=lambda cfg: (transformer.STATE_DTYPE, cfg.dtype),

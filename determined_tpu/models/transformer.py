@@ -47,7 +47,7 @@ from flax import linen as nn
 from determined_tpu.data import DataLoader, SyntheticDataset
 from determined_tpu.ops import kernel_form
 from determined_tpu.ops.attention import NEG_INF, dot_product_attention, reference_attention
-from determined_tpu.ops.gated_delta import gdn_chunk, l2_heads, state_shape as gdn_pool_shape
+from determined_tpu.ops.gated_delta import DECAY_FLOOR, gdn_chunk, l2_heads, state_shape as gdn_pool_shape
 from determined_tpu.ops.paged_attention import index_scores, index_topk_mask
 from determined_tpu.ops.retention import recent_shapes, retention_quadratic, state_shapes
 from determined_tpu.ops.ssm import ssm_scan, state_shape as ssm_pool_shape
@@ -105,7 +105,9 @@ class TransformerConfig:
     # it, or GQA's attention layers do (``Attention``), in a model of no
     # retention layer.  attn_output_gate (Qwen3-Next's gated attention): ``wq``
     # is twice as wide, a head's ``[query | gate]``, and what the head attends
-    # to is multiplied by ``sigmoid(gate)`` before ``wo``
+    # to is multiplied by ``sigmoid(gate)`` before ``wo``; on LATENT attention
+    # (Ling-3.0's) the gate is ONE value a head from a projection of its own
+    # (the leaf ``w_gate`` [d_model, n_heads]), times the head's whole output
     qk_norm: bool = False
     attn_output_gate: bool = False
     # rotary parameters per layer type (the published `rope_parameters`
@@ -202,7 +204,11 @@ class TransformerConfig:
     tie_embeddings: bool = False
     logit_scale: float = 1.0
     # Latent attention (MLA): kv_lora_rank set swaps every block's GQA for
-    # it.  Queries come through a q_lora_rank bottleneck as n_heads heads of
+    # it: every full_attention layer's, the other types keeping their own
+    # mixers (a linear_attention layer beside it keeps a state a lane while
+    # the full layers keep a latent row a token).  Queries come through a
+    # q_lora_rank bottleneck (None: straight from the stream, ONE matrix
+    # ``wq`` [d_model, n_heads, qk]) as n_heads heads of
     # [qk_nope_head_dim | qk_rope_head_dim]; keys and values are expanded from
     # ONE latent row a token, [kv_lora_rank | qk_rope_head_dim] (what serving
     # caches), to n_heads heads of [qk_nope_head_dim | v_head_dim], with the
@@ -256,13 +262,18 @@ class TransformerConfig:
     # of linear_value_head_dim, each with a delta-rule state of linear_key_head_dim x linear_value_head_dim;
     # linear_key_heads heads of q and k, each serving linear_value_heads / linear_key_heads consecutive value
     # heads; a causal depthwise convolution over linear_conv tokens on q, k and v (no bias), and linear_chunk
-    # tokens a sub-chunk of the chunked form (``ops/gated_delta.py``)
+    # tokens a sub-chunk of the chunked form (``ops/gated_delta.py``).  linear_decay_floor None: Gated DeltaNet's
+    # decay, ONE a value head a token, ``g = -exp(A_log) softplus(a + dt_bias)``, and an output gate ``silu(z)``.
+    # Set (Kimi Delta Attention's bounded gate, ``kda_lower_bound``, as Ling-3.0 runs it): a decay a CHANNEL of the
+    # key from a full projection of its own (``w_decay`` [d_model, value heads x K], ``dt_bias`` as wide), ``g =
+    # floor * sigmoid(exp(A_log) * (a + dt_bias))`` in (floor, 0), and an output gate ``sigmoid(z)``
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv: int = 4
     linear_chunk: int = 64
+    linear_decay_floor: Optional[float] = None
     # muP's scalars, constants of the forward and no leaves (1: not there):
     # on the embedding's rows; on k, on attention's input and output; on the
     # Mamba-2 mixer's input, on the five segments z, x, B, C, dt of its
@@ -365,13 +376,18 @@ class TransformerConfig:
         if self.linear_layers and (
             min(self.linear_key_heads, self.linear_value_heads, self.linear_key_head_dim, self.linear_value_head_dim, self.linear_chunk) < 1
             or self.linear_conv < 2 or self.linear_value_heads % self.linear_key_heads
-            or self.latent or self.parallel_block or self.shortcut_block or self.seq_axis_name is not None
+            or self.parallel_block or self.shortcut_block or self.seq_axis_name is not None or self.indexer_types is not None
         ):
             raise ValueError(
                 "a linear_attention layer needs linear_value_heads (whole groups a linear_key_heads head), linear_key_head_dim, "
                 "linear_value_head_dim, linear_chunk >= 1 and linear_conv >= 2, in a sequential block: it does not run under "
-                "parallel_block or shortcut_block, with latent attention (kv_lora_rank) or under a `seq` axis (its state is "
-                "carried along the sequence)"
+                "parallel_block or shortcut_block, beside an indexer (indexer_types: every layer would keep a latent row) or "
+                "under a `seq` axis (its state is carried along the sequence)"
+            )
+        if self.linear_decay_floor is not None and not (self.linear_layers and DECAY_FLOOR <= self.linear_decay_floor < 0):
+            raise ValueError(
+                "linear_decay_floor (a decay a channel under the bounded gate) belongs to linear_attention layers and lies in "
+                f"[{DECAY_FLOOR}, 0): the chunked form's two-sided scaling holds no smaller one inside float32 (got {self.linear_decay_floor})"
             )
         if CCA in (self.layer_types or ()) and (
             self.latent or self.parallel_block or self.seq_axis_name is not None or self.n_heads % self.kv_heads
@@ -397,8 +413,11 @@ class TransformerConfig:
                 "qk_norm runs in power_retention layers only (every layer must be one), or in GQA's attention layers in a "
                 "model of no retention layer: not with latent attention (kv_lora_rank) or a cca layer"
             )
-        if self.attn_output_gate and (self.latent or types & {RETENTION, CCA}):
-            raise ValueError("attn_output_gate gates GQA's attention layers: not latent attention (kv_lora_rank), a power_retention or a cca layer")
+        if self.attn_output_gate and (types & {RETENTION, CCA} or (self.latent and self.indexer_types is not None)):
+            raise ValueError(
+                "attn_output_gate gates GQA's attention layers (a gate a value) or latent attention's (a gate a head): not a "
+                "power_retention or a cca layer, nor latent attention under an indexer"
+            )
         if self.moe_shared_gate and not self.moe_shared_experts:
             raise ValueError("moe_shared_gate gates the shared experts: it belongs to moe_shared_experts")
         if isinstance(self.rope_parameters, Mapping):
@@ -479,11 +498,14 @@ class TransformerConfig:
         if not 0 <= self.dense_prefix <= self.n_layers:
             raise ValueError(f"dense_prefix={self.dense_prefix} must lie in [0, n_layers]")
         if self.kv_lora_rank is not None:
-            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
-            if any(v is None or int(v) < 1 for v in sizes) or self.qk_rope_head_dim % 2:
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if any(v is None or int(v) < 1 for v in sizes) or self.qk_rope_head_dim % 2 or (
+                self.q_lora_rank is not None and int(self.q_lora_rank) < 1
+            ) or (self.q_lora_rank is None and (self.q_latent_scale != 1.0 or self.indexer_types is not None)):
                 raise ValueError(
-                    "latent attention needs q_lora_rank, kv_lora_rank, qk_nope_head_dim, an even "
-                    f"qk_rope_head_dim and v_head_dim (got {sizes})"
+                    "latent attention needs kv_lora_rank, qk_nope_head_dim, an even qk_rope_head_dim and v_head_dim, and "
+                    "q_lora_rank >= 1 or None (queries straight from the stream: no query latent to scale, none for an "
+                    f"indexer to read) (got {(self.q_lora_rank,) + sizes})"
                 )
             if self.quantized_matmul != "none" or self.seq_axis_name is not None:
                 raise ValueError("latent attention runs without quantized_matmul and outside a `seq` axis")
@@ -589,6 +611,11 @@ class TransformerConfig:
     @property
     def linear_value_width(self) -> int:
         return self.linear_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_channel_decay(self) -> bool:
+        """Whether a linear layer's decay is a value a channel of the key (Kimi Delta Attention's form of the mixer)."""
+        return self.linear_decay_floor is not None
 
     @property
     def linear_channels(self) -> int:
@@ -1020,14 +1047,20 @@ class Retention(nn.Module):
 
 
 def _latent_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple[Any, ...], Any]]:
-    """Latent attention's leaves: name -> (shape, logical axes, initialiser)."""
+    """Latent attention's leaves: name -> (shape, logical axes, initialiser):
+    the queries' bottleneck (or ONE matrix ``wq`` without one), under
+    ``attn_output_gate`` the head-wise gate's ``w_gate``, and the rest."""
     d, h = cfg.d_model, cfg.n_heads
     qk, rope = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.qk_rope_head_dim
     kernel, ones = nn.initializers.lecun_normal(), nn.initializers.ones
-    return {
+    queries = {"wq": ((d, h, qk), ("embed", "heads", "head_dim"), kernel)} if cfg.q_lora_rank is None else {
         "wq_a": ((d, cfg.q_lora_rank), ("embed", None), kernel),
         "q_norm": ((cfg.q_lora_rank,), (None,), ones),
         "wq_b": ((cfg.q_lora_rank, h, qk), (None, "heads", "head_dim"), kernel),
+    }
+    return {
+        **queries,
+        **({"w_gate": ((d, h), ("embed", "heads"), kernel)} if cfg.attn_output_gate else {}),
         "wkv_a": ((d, cfg.kv_lora_rank + rope), ("embed", None), kernel),
         "kv_norm": ((cfg.kv_lora_rank,), (None,), ones),
         "wkv_b": ((cfg.kv_lora_rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim), (None, "heads", "head_dim"), kernel),
@@ -1087,6 +1120,9 @@ class LatentAttention(nn.Module):
                         picked = index_topk_mask(scores, jnp.tril(jnp.ones((s, s), bool)), cfg.index_topk)
         with jax.named_scope("attn.full"):
             out = _latent_attend_local(cfg)(q_nope, q_rope, c_kv, k_r, p["wkv_b"], None, 0, picked)
+        if cfg.attn_output_gate:
+            with jax.named_scope("attn.gate"):
+                out = _head_gated(cfg, p, x, out)
         with jax.named_scope("attn.out"):
             return jnp.einsum("bshv,hvD->bsD", out, p["wo"].astype(cfg.dtype)), picked
 
@@ -1214,11 +1250,15 @@ def _gdn_param_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...]
     (no bias), the gated norm's weight ONE head's width, shared by the heads."""
     d, hv = cfg.d_model, cfg.linear_value_heads
     kernel, bound = nn.initializers.lecun_normal(), cfg.linear_conv ** -0.5
+    # a decay a channel: ``w_ba`` holds ``b`` alone, and the decay's own projection and bias are a key's width a head
+    channel = cfg.linear_channel_decay
+    decay = hv * cfg.linear_key_head_dim if channel else hv
     return {
         "w_in": ((d, cfg.linear_channels + cfg.linear_value_width), ("embed", "mlp"), kernel),
-        "w_ba": ((d, 2 * hv), ("embed", None), kernel),
+        "w_ba": ((d, hv if channel else 2 * hv), ("embed", None), kernel),
+        **({"w_decay": ((d, decay), ("embed", "mlp"), kernel)} if channel else {}),
         "conv_w": ((cfg.linear_conv, cfg.linear_channels), (None, "mlp"), _uniform(-bound, bound)),
-        "dt_bias": ((hv,), (None,), _STEP_BIAS_INIT), "A_log": ((hv,), (None,), _DECAY_LOG_INIT),
+        "dt_bias": ((decay,), (None,), _STEP_BIAS_INIT), "A_log": ((hv,), (None,), _DECAY_LOG_INIT),
         "norm": ((cfg.linear_value_head_dim,), (None,), nn.initializers.ones),
         "w_out": ((cfg.linear_value_width, d), ("mlp", "embed"), kernel),
     }
@@ -1228,9 +1268,12 @@ def _gdn_project(cfg: TransformerConfig, p: Dict[str, Any], u: jax.Array):
     """The two in-projections of the normed input ``u`` [..., d]: what the
     convolution takes (q, k, v side by side) [..., channels], the output gate
     ``z`` [..., value width], and ``b`` and ``a`` [..., value heads] (the write
-    strength and the decay before their sigmoid and softplus)."""
+    strength and the decay before their sigmoid and softplus); a decay a channel
+    is a THIRD projection, ``a`` [..., value heads x K]."""
     out, ba = u @ p["w_in"].astype(cfg.dtype), u @ p["w_ba"].astype(cfg.dtype)
     ch, hv = cfg.linear_channels, cfg.linear_value_heads
+    if cfg.linear_channel_decay:
+        return out[..., :ch], out[..., ch:], ba, u @ p["w_decay"].astype(cfg.dtype)
     return out[..., :ch], out[..., ch:], ba[..., :hv], ba[..., hv:]
 
 
@@ -1247,29 +1290,37 @@ def _gdn_split(cfg: TransformerConfig, qkv: jax.Array, b: jax.Array, a: jax.Arra
     [..., value heads, K] (float32, at unit length, q times ``K ** -0.5``; a key
     head serves its consecutive value heads) and v [..., value heads, V]; the
     decay's logarithm ``g = -exp(A_log) softplus(a + dt_bias)`` and the write
-    strength ``beta = sigmoid(b)``, float32 [..., value heads]."""
+    strength ``beta = sigmoid(b)``, float32 [..., value heads]; a decay a channel
+    ``g = linear_decay_floor * sigmoid(exp(A_log) (a + dt_bias))`` [..., value
+    heads, K], ``A_log`` a head's."""
     f32, kw, lead = jnp.float32, cfg.linear_key_width, qkv.shape[:-1]
     hk, hv, dk = cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_head_dim
     q = l2_heads(qkv[..., :kw].reshape(*lead, hk, dk)) * dk ** -0.5
     k = l2_heads(qkv[..., kw: 2 * kw].reshape(*lead, hk, dk))
     v = qkv[..., 2 * kw:].reshape(*lead, hv, cfg.linear_value_head_dim)
-    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + p["dt_bias"].astype(f32))
+    if cfg.linear_channel_decay:
+        a = (a.astype(f32) + p["dt_bias"].astype(f32)).reshape(*lead, hv, dk)
+        g = cfg.linear_decay_floor * jax.nn.sigmoid(jnp.exp(p["A_log"].astype(f32))[:, None] * a)
+    else:
+        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a.astype(f32) + p["dt_bias"].astype(f32))
     return jnp.repeat(q, hv // hk, axis=-2), jnp.repeat(k, hv // hk, axis=-2), v, g, jax.nn.sigmoid(b.astype(f32))
 
 
 def _gdn_out(cfg: TransformerConfig, p: Dict[str, Any], o: jax.Array, z: jax.Array) -> jax.Array:
     """``o`` [..., value heads, V] float32 under RMSNorm over each head's
     values, times the norm's weight, THEN times ``silu(z)`` (the norm before the
-    gate, all in float32), and the out-projection."""
+    gate, all in float32; under a decay a channel the gate is ``sigmoid(z)``), and
+    the out-projection."""
     f32, lead = jnp.float32, z.shape[:-1]
     normed = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * p["norm"].astype(f32)
-    gated = normed * nn.silu(z.astype(f32)).reshape(*lead, cfg.linear_value_heads, -1)
+    gated = normed * (jax.nn.sigmoid if cfg.linear_channel_decay else nn.silu)(z.astype(f32)).reshape(*lead, cfg.linear_value_heads, -1)
     return gated.reshape(*lead, -1).astype(cfg.dtype) @ p["w_out"].astype(cfg.dtype)
 
 
 class GatedDeltaNet(nn.Module):
     """A Gated-DeltaNet mixer (Yang et al., arXiv:2412.06464, as Qwen3-Next runs
-    it) over the whole sequence, in its chunked form (``ops/gated_delta.py
+    it; under ``linear_decay_floor`` Kimi Delta Attention's, arXiv:2510.26692,
+    as Ling-3.0 runs it) over the whole sequence, in its chunked form (``ops/gated_delta.py
     gdn_chunk``): what ``init`` builds for serving, and the wide oracle of the
     serving forward (``models/cache_kinds.py``), which reads the same leaves
     through the same functions and carries a state and the convolution's tail
@@ -1397,6 +1448,8 @@ class Block(nn.Module):
         def attend(name: str, h: jax.Array) -> jax.Array:
             """What the attention sublayer ``name`` adds, from the normed input."""
             nonlocal state
+            if self.layer_type == LINEAR:
+                return GatedDeltaNet(cfg, name="gdn")(h)
             if cfg.latent:
                 out, picked = LatentAttention(cfg, self.indexer, name=name)(h, state if self.indexer else None)
                 state = picked if self.indexer else state
@@ -1405,8 +1458,6 @@ class Block(nn.Module):
                 return Retention(cfg, name=name)(h)
             if self.layer_type == CCA:
                 return CompressedAttention(cfg, self.mesh, name=name)(h)
-            if self.layer_type == LINEAR:
-                return GatedDeltaNet(cfg, name="gdn")(h)
             if self.layer_type == HYBRID:
                 # attention heads and Mamba-2 heads read the one norm side by side
                 att = Attention(cfg, self.mesh, self.layer_type, name=name)(_times(h, cfg.attention_in_multiplier))
@@ -1812,13 +1863,24 @@ def _latent_project(cfg, p, h, positions, rope):
     """A latent layer's projections of the normed input ``h`` [b, s, d]; both
     latents times their scale after their norm (``c_kv`` is what serving caches)."""
     dt, r = cfg.dtype, cfg.kv_lora_rank
-    c_q = _times(_rms_apply(h @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps), cfg.q_latent_scale)
-    q = jnp.einsum("bsr,rhk->bhsk", c_q, p["wq_b"].astype(dt))
+    if cfg.q_lora_rank is None:  # no query latent: the heads straight from the stream
+        c_q, q = None, jnp.einsum("bsd,dhk->bhsk", h, p["wq"].astype(dt))
+    else:
+        c_q = _times(_rms_apply(h @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps), cfg.q_latent_scale)
+        q = jnp.einsum("bsr,rhk->bhsk", c_q, p["wq_b"].astype(dt))
     kv = h @ p["wkv_a"].astype(dt)
     c_kv = _times(_rms_apply(kv[..., :r], p["kv_norm"], cfg.norm_eps), cfg.kv_latent_scale)
     k_r = _rope(kv[:, None, :, r:], positions, rope)[:, 0]
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], _rope(q[..., cfg.qk_nope_head_dim:], positions, rope)
     return q_nope, q_rope, c_kv, k_r, c_q
+
+
+def _head_gated(cfg, p, h, att):
+    """Latent attention's head-wise output gate: what each head attended to, ``att``
+    [b, s, heads, v], times ``sigmoid`` (float32) of ONE value a head from the
+    normed input ``h``'s own projection ``w_gate``."""
+    gate = jax.nn.sigmoid((h @ p["w_gate"].astype(cfg.dtype)).astype(jnp.float32))
+    return (att * gate[..., None]).astype(att.dtype)
 
 
 def _index_project(cfg, p, c_q, h, positions, rope):
@@ -2120,8 +2182,9 @@ class LMTrial(JaxTrial):
         attn = d * cfg.head_dim * (2 * cfg.n_heads + 2 * cfg.kv_heads)
         if cfg.latent:
             qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-            attn = d * (cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_head_dim) + cfg.n_heads * (
-                cfg.q_lora_rank * qk + cfg.kv_lora_rank * (cfg.qk_nope_head_dim + cfg.v_head_dim) + cfg.v_head_dim * d
+            q_rank = cfg.q_lora_rank or 0  # 0: queries straight from the stream
+            attn = d * (q_rank + cfg.kv_lora_rank + cfg.qk_rope_head_dim) + cfg.n_heads * (
+                (q_rank or d) * qk + cfg.kv_lora_rank * (cfg.qk_nope_head_dim + cfg.v_head_dim) + cfg.v_head_dim * d
             )
             width = cfg.n_heads * (qk + cfg.v_head_dim) // 2
         n_params, seen = cfg.vocab_size * d, 0

@@ -40,6 +40,17 @@ sent to, as ``ops/ssm.py``'s).
   ``V' = U - W S``, ``O = (exp(G) q) S + tril(q k^T exp(G_i - G_j)) V'`` and
   ``S <- exp(G_last) S + (exp(G_last - G) k)^T V'``.  ``jax.numpy`` on every
   backend, float32 at the highest matmul precision.
+
+**A decay a CHANNEL** (Kimi Delta Attention, "Kimi Linear", arXiv:2510.26692
+section 3): ``g`` of one more axis, ``[..., heads, K]``, and ``exp(g_t)`` a vector
+down the state's key axis in the scalar's place, ``S <- Diag(exp(g_t)) S``.  The
+rule, the pool, the decode kernel (whose decay already lies ``[K, heads]`` down a
+column) and the solve are the same; what changes is the chunk form's pairwise
+decay: ``A[i, j] = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)`` no longer takes a
+scalar out of the dot product, so both sides are scaled round a reference row
+before it (:func:`_pairwise_channel`), in blocks of ``_DECAY_BLOCK`` rows whose
+exponents stay inside float32 while ``g >= DECAY_FLOOR`` a token (the bounded
+gate's ``kda_lower_bound`` -5 is inside it).
 """
 
 from __future__ import annotations
@@ -99,39 +110,51 @@ def gdn_chunk(
     """``s`` tokens a row of the batch after the ones ``state`` [b, heads, K, V]
     already holds.  ``q`` / ``k`` [b, s, heads, K] (a head's own, normalised and
     scaled by the caller), ``v`` [b, s, heads, V], ``g`` (the decay's logarithm)
-    and ``beta`` [b, s, heads] float32, ``live`` [b, s] marks the tokens that
-    exist: the others neither decay the state nor enter it, and what they are
-    answered is not read.  Sub-chunks of ``chunk`` tokens (one of ``s`` where
-    ``chunk`` does not divide it).  Returns (o [b, s, heads, V] float32, the
-    state after the tokens in its own dtype)."""
+    [b, s, heads], or [b, s, heads, K] a decay a channel (no smaller than
+    ``DECAY_FLOOR`` a token), and ``beta`` [b, s, heads] float32, ``live`` [b, s]
+    marks the tokens that exist: the others neither decay the state nor enter it,
+    and what they are answered is not read.  Sub-chunks of ``chunk`` tokens (one
+    of ``s`` where ``chunk`` does not divide it).  Returns (o [b, s, heads, V]
+    float32, the state after the tokens in its own dtype)."""
     f32 = jnp.float32
     b, s, h, dk = k.shape
     dv = v.shape[-1]
     c = chunk if s % chunk == 0 else s
     n = s // c
+    channel = g.ndim == q.ndim
     cut = lambda t: t.astype(f32).reshape(b, n, c, h, -1).transpose(1, 0, 3, 2, 4)  # noqa: E731  [n, b, h, c, .]
     qf, kf, vf = cut(q), cut(k), cut(v)
-    gate = cut(jnp.where(live[..., None], g.astype(f32), 0.0))[..., 0]  # [n, b, h, c]
+    gate = cut(jnp.where(live[(...,) + (None,) * (g.ndim - 2)], g.astype(f32), 0.0))  # [n, b, h, c, K], or [n, b, h, c] a head
+    gate = gate if channel else gate[..., 0]
     write = cut(jnp.where(live[..., None], beta.astype(f32), 0.0))  # [n, b, h, c, 1]: a token that is not writes nothing
-    cum = jnp.cumsum(gate, axis=-1)  # G: the decay's logarithm from the sub-chunk's start up to each token
+    cum = jnp.cumsum(gate, axis=-2 if channel else -1)  # G: the decay's logarithm from the sub-chunk's start up to each token
     seen = jnp.tril(jnp.ones((c, c), bool))
-    since = jnp.exp(jnp.where(seen, cum[..., :, None] - cum[..., None, :], 0.0))  # exp(G_i - G_j), i >= j
+    # ``lower()`` / ``inside()``: beta_i sum_c k_ic k_jc exp(G_i - G_j) and the same sum of the queries against the keys, each
+    # made where the head's form made it (the program's text is a cache key); ``at(G)``: exp(G) against a row of K values
+    if channel:
+        kk, qk = _pairwise_channel(kf, qf, cum)
+        lower, inside, at = (lambda: write * kk), (lambda: qk), jnp.exp
+    else:
+        since = jnp.exp(jnp.where(seen, cum[..., :, None] - cum[..., None, :], 0.0))  # exp(G_i - G_j), i >= j
+        kk = jnp.einsum("nbhik,nbhjk->nbhij", kf, kf, precision=_HIGHEST)
+        lower = lambda: write * kk * since  # noqa: E731
+        inside = lambda: jnp.einsum("nbhik,nbhjk->nbhij", qf, kf, precision=_HIGHEST) * since  # noqa: E731
+        at = lambda t: jnp.exp(t)[..., None]  # noqa: E731
     # the system a sub-chunk solves: it depends on no state, so every sub-chunk's is solved at once
-    kk = jnp.einsum("nbhik,nbhjk->nbhij", kf, kf, precision=_HIGHEST)
-    inverse = _unit_lower_inverse(jnp.where(jnp.tril(seen, -1), write * kk * since, 0.0))
-    rhs = jnp.concatenate([write * vf, write * jnp.exp(cum)[..., None] * kf], axis=-1)
+    inverse = _unit_lower_inverse(jnp.where(jnp.tril(seen, -1), lower(), 0.0))
+    rhs = jnp.concatenate([write * vf, write * at(cum) * kf], axis=-1)
     solved = jnp.einsum("nbhij,nbhjx->nbhix", inverse, rhs, precision=_HIGHEST)
     u, w = solved[..., :dv], solved[..., dv:]
-    inside = jnp.where(seen, jnp.einsum("nbhik,nbhjk->nbhij", qf, kf, precision=_HIGHEST) * since, 0.0)
-    q_in = qf * jnp.exp(cum)[..., None]  # a query against the carried state, decayed up to its own token
-    total = cum[..., -1]
-    k_out = kf * jnp.exp(total[..., None] - cum)[..., None]  # a key decayed to the sub-chunk's end
+    inside = jnp.where(seen, inside(), 0.0)
+    q_in = qf * at(cum)  # a query against the carried state, decayed up to its own token
+    total = cum[..., -1, :] if channel else cum[..., -1]
+    k_out = kf * at((total[..., None, :] if channel else total[..., None]) - cum)  # a key decayed to the sub-chunk's end
 
     def body(s0, part):
         u, w, inside, q_in, k_out, total = part
         fresh = u - jnp.einsum("bhik,bhkv->bhiv", w, s0, precision=_HIGHEST)  # V': what each token really writes
         out = jnp.einsum("bhik,bhkv->bhiv", q_in, s0, precision=_HIGHEST) + jnp.einsum("bhij,bhjv->bhiv", inside, fresh, precision=_HIGHEST)
-        s1 = jnp.exp(total)[..., None, None] * s0 + jnp.einsum("bhik,bhiv->bhkv", k_out, fresh, precision=_HIGHEST)
+        s1 = _down_keys(jnp.exp(total), channel) * s0 + jnp.einsum("bhik,bhiv->bhkv", k_out, fresh, precision=_HIGHEST)
         return s1, out
 
     parts = (u, w, inside, q_in, k_out, total)
@@ -141,6 +164,39 @@ def gdn_chunk(
     else:
         s1, out = jax.lax.scan(body, state.astype(f32), parts)
     return out.transpose(1, 0, 3, 2, 4).reshape(b, s, h, dv), s1.astype(state.dtype)
+
+
+#: rows of a block of :func:`_pairwise_channel`, and the least decay's logarithm a token it takes: round a block's
+#: middle row a factor's exponent reaches 8 rows x 5.5 = 44 either way, and the product of two ABOVE the diagonal (a
+#: pair the caller masks) 15 rows x 5.5 = 82.5 < ln(float32 max) = 88.7: no masked pair is inf, so none is NaN in a gradient
+_DECAY_BLOCK = 16
+DECAY_FLOOR = -5.5
+
+
+def _pairwise_channel(kf: jax.Array, qf: jax.Array, cum: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``sum_c x_ic k_jc exp(G_ic - G_jc)`` for ``x`` the keys and the queries
+    [..., c, K] under a running decay ``cum`` (``G``) a channel, [..., c, c] each;
+    what lies above the diagonal is the caller's to mask (it is finite).  The
+    exponential does not leave the dot product as a scalar, so it is split round
+    a reference row ``m``: ``(x_i exp(G_i - G_m)) . (k_j exp(G_m - G_j))``, the
+    middle row of ``i``'s block of ``_DECAY_BLOCK`` rows.  A key of an EARLIER
+    block is scaled by no more than one (``G`` only falls), one of the block
+    itself and a row of it by ``exp(8 rows' decay)`` at most either way; the keys
+    of later blocks are masked, so their exponent is set to 0.  A row before the
+    middle one is scaled round a LATER row: what it is answered moves by a
+    rounding (1e-7) with the tokens after it in its block, and by nothing more."""
+    c, dk = kf.shape[-2:]
+    m = _DECAY_BLOCK
+    size = -(-c // m) * m  # whole blocks: the rows past ``c`` are zeros under the last row's decay
+    pad = lambda t, mode: jnp.pad(t, [(0, 0)] * (t.ndim - 2) + [(0, size - c), (0, 0)], mode=mode)  # noqa: E731
+    kf, qf, cum = pad(kf, "constant"), pad(qf, "constant"), pad(cum, "edge")
+    blocks = lambda t: t.reshape(t.shape[:-2] + (size // m, m, dk))  # noqa: E731
+    middle = blocks(cum)[..., m // 2, :]  # [..., blocks, K]
+    left = jnp.exp(blocks(cum) - middle[..., None, :])  # a block's rows round its own middle row
+    behind = (jnp.arange(size) // m)[None, :] <= jnp.arange(size // m)[:, None]  # [blocks, size]: key j not after block I
+    right = kf[..., None, :, :] * jnp.exp(jnp.where(behind[..., None], middle[..., None, :] - cum[..., None, :, :], 0.0))
+    pair = lambda x: jnp.einsum("...Iik,...Ijk->...Iij", blocks(x) * left, right, precision=_HIGHEST).reshape(x.shape[:-2] + (size, size))[..., :c, :c]  # noqa: E731
+    return pair(kf), pair(qf)
 
 
 #: rows of a diagonal block :func:`_unit_lower_inverse` inverts by substitution (the published kernels' 16)
@@ -182,14 +238,14 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
 
 
 def gdn_recurrence(q, k, v, g, beta, state, live) -> Tuple[jax.Array, jax.Array]:
-    """:func:`gdn_chunk`'s arguments through the rule as it is stated, one token
-    at a time under a ``scan``: what the chunked form and the decode step are
-    held to in tests."""
+    """:func:`gdn_chunk`'s arguments (a decay a head or a channel) through the
+    rule as it is stated, one token at a time under a ``scan``: what the chunked
+    form and the decode step are held to in tests."""
     f32 = jnp.float32
 
     def token(s0, at):
         q_t, k_t, v_t, g_t, b_t, live_t = at  # [b, h, .]; [b, h]; [b]
-        dec = jnp.exp(g_t)[..., None, None] * s0
+        dec = _down_keys(jnp.exp(g_t), g_t.ndim == k_t.ndim) * s0
         r = jnp.sum(dec * k_t[..., :, None], axis=-2)
         s1 = dec + k_t[..., :, None] * (b_t[..., None] * (v_t - r))[..., None, :]
         s1 = jnp.where(live_t[:, None, None, None], s1, s0)
@@ -198,6 +254,11 @@ def gdn_recurrence(q, k, v, g, beta, state, live) -> Tuple[jax.Array, jax.Array]
     seq = lambda t: jnp.moveaxis(t.astype(f32), 1, 0)  # noqa: E731
     s1, out = jax.lax.scan(token, state.astype(f32), (seq(q), seq(k), seq(v), seq(g), seq(beta), jnp.moveaxis(live, 1, 0)))
     return jnp.moveaxis(out, 0, 1), s1.astype(state.dtype)
+
+
+def _down_keys(kept: jax.Array, channel: bool) -> jax.Array:
+    """A decay ``kept`` a head ``[..., heads]``, or under ``channel`` ``[..., heads, K]``, against a state ``[..., heads, K, V]``."""
+    return kept[..., None] if channel else kept[..., None, None]
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +273,8 @@ def gdn_decode(
     """One decode step of one layer over the state pool, in place.
 
     ``q`` / ``k`` [lanes, heads, K] (a value head's own, normalised and scaled),
-    ``v`` [lanes, heads, V], ``g`` / ``beta`` [lanes, heads] float32, ``state``
+    ``v`` [lanes, heads, V], ``g`` [lanes, heads] (or [lanes, heads, K]: a decay a
+    channel) and ``beta`` [lanes, heads] float32, ``state``
     the whole pool (:func:`state_shape`), ``layer`` the layer to update, ``live``
     [lanes] bool: an idle lane's slot is left as it is (neither read nor
     written) and its output is zeros.  Returns (o [lanes, heads, V] float32,
@@ -245,7 +307,7 @@ def _gdn_decode(q, k, v, g, beta, state, layer, live, *, impl):
 def _gdn_decode_jnp(q, k, v, kept, beta, state, layer, live):
     lanes = q.shape[0]
     s0 = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)[:lanes]
-    dec = kept[..., None, None] * s0.astype(jnp.float32)
+    dec = _down_keys(kept, kept.ndim == k.ndim) * s0.astype(jnp.float32)
     r = jnp.sum(dec * k[..., :, None], axis=-2)
     s1 = dec + k[..., :, None] * (beta[..., None] * (v - r))[..., None, :]
     out = jnp.sum(s1 * q[..., :, None], axis=-2)
@@ -317,7 +379,7 @@ def _gdn_decode_pallas(q, k, v, kept, beta, state, layer, live, *, interpret: bo
         name="gdn_decode",
     )(
         layer.reshape(1), live.astype(jnp.int32),
-        columns(q), columns(k), columns(jnp.broadcast_to(kept[..., None], q.shape)),
+        columns(q), columns(k), columns(kept if kept.ndim == q.ndim else jnp.broadcast_to(kept[..., None], q.shape)),
         rows(v), rows(jnp.broadcast_to(beta[..., None], v.shape)), state,
     )
     return y, state

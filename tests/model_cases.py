@@ -205,6 +205,7 @@ SERVED_ARCHS = {
     "nemotron_h": ("paged_kv", "ssm_slot"),
     "glm_moe_dsa": ("paged_indexed",),
     "qwen3_next": ("paged_kv", "delta_slot"),
+    "ling_kda_mla": ("paged_latent", "delta_slot"),
 }
 
 

@@ -303,8 +303,11 @@ def test_a_model_is_of_two_kinds_and_the_cache_holds_both(model):
 @pytest.mark.parametrize("kw,says", [
     (dict(linear_value_heads=3), "a linear_attention layer needs linear_value_heads"),
     (dict(parallel_block=True), "it does not run under parallel_block or shortcut_block"),
-    (dict(kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, qk_norm=False, attn_output_gate=False, partial_rotary_factor=1.0),
-     "with latent attention"),
+    (dict(kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, qk_norm=False, attn_output_gate=False, partial_rotary_factor=1.0,
+          layer_types=(FULL,) * 4, indexer_types=("full",) * 4, index_n_heads=2, index_head_dim=8, index_topk=4, linear_decay_floor=-5.0),
+     "linear_decay_floor .* belongs to linear_attention layers"),
+    (dict(kv_lora_rank=8, q_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, qk_norm=False, attn_output_gate=False, partial_rotary_factor=1.0,
+          indexer_types=("full",) * 4, index_n_heads=2, index_head_dim=8, index_topk=4), "beside an indexer"),
     (dict(seq_axis_name="seq"), "under a `seq` axis"),
     (dict(mixer_block=True), "a linear_attention layer sits in a sequential block"),
     (dict(layer_types=("power_retention",) * 4, qk_norm=False, partial_rotary_factor=1.0), "attn_output_gate gates GQA's attention layers"),
