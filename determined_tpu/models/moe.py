@@ -694,6 +694,42 @@ def route_mlp(p: Any, xf: jax.Array, before: Any, eps: float) -> Tuple[jax.Array
     return jax.nn.softmax(dot(h, f32("router_w3")), axis=-1), r
 
 
+def _two_largest_sum(values: jax.Array) -> jax.Array:
+    """The sum of the two largest of ``values`` along the last axis, to the bit
+    what ``sum(top_k(values, 2)[0])`` gives, by two ``max`` passes: the largest,
+    then the largest of the rest, which is the largest again where it stands
+    twice (``top_k`` leaves the second copy in).  A ``top_k`` over the last of
+    three axes is a whole sort of every row on the chip."""
+    largest = jnp.max(values, axis=-1)
+    is_largest = values == largest[..., None]
+    rest = jnp.max(jnp.where(is_largest, -jnp.inf, values), axis=-1)
+    return largest + jnp.where(jnp.sum(is_largest, axis=-1) > 1, largest, rest)
+
+
+def _among_largest(values: jax.Array, count: int) -> jax.Array:
+    """Which of a row's ``values [T, n]`` are among its ``count`` largest,
+    ``[T, n]`` bool with ``count`` set a row: those that fewer than ``count``
+    others beat, where ``j`` beats ``i`` if it is larger, or equal and
+    ``j < i``: ``top_k``'s own rule on a tie (the lower index first), by
+    ``n x n`` compares and a count instead of a sort."""
+    index = jnp.arange(values.shape[-1])
+    theirs, mine = values[:, None, :], values[:, :, None]
+    beats = (theirs > mine) | ((theirs == mine) & (index[None, None, :] < index[None, :, None]))
+    return jnp.sum(beats, axis=-1) < count
+
+
+def _picked(scores: jax.Array, picks: jax.Array) -> jax.Array:
+    """``scores[t, picks[t, j]]`` as ``[T, k]``, to the bit what
+    ``take_along_axis`` gives, by compares and a ``max`` over the experts: a
+    gather of one index an element costs the chip ~10 ns an index
+    (``_COMPARE_TOKENS``' note; 10.6 of a 28 us route at 128 lanes x 8 picks:
+    PERF.md section 5 "PR 67"), the compares under 1 us.  A ``max`` and not a
+    sum: XLA folds a sum over the experts into the sum over the picks that
+    follows it and adds in another order."""
+    hit = picks[:, :, None] == jnp.arange(scores.shape[-1])[None, None, :]
+    return jnp.max(jnp.where(hit, scores[:, None, :], -jnp.inf), axis=-1)
+
+
 def route_sigmoid_grouped(
     logits: jax.Array, bias: jax.Array, *, top_k: int, n_group: int, topk_group: int, scaling: float
 ) -> Tuple[jax.Array, jax.Array]:
@@ -703,16 +739,19 @@ def route_sigmoid_grouped(
     sum of its two largest selection values and the ``topk_group`` best groups
     stay; the ``top_k`` largest selection values inside them are the picks;
     ``weights = scores[picks] / (their sum + 1e-20) * scaling``.  Returns
-    (weights [T, k] float32, picks [T, k])."""
+    (weights [T, k] float32, picks [T, k]).  The selection is exact and
+    stable on a tie (the lower index first, as ``top_k`` has it), and the
+    groups are chosen without a sort; where every group stays (one group, as
+    Nemotron-H and GLM-5.2 publish it) no group is scored at all."""
     tokens, e = logits.shape
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     select = scores + bias.astype(jnp.float32)[None, :]
-    group_score = jnp.sum(jax.lax.top_k(select.reshape(tokens, n_group, e // n_group), 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(group_score, topk_group)                                    # [T, topk_group]
-    group_kept = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)  # [T, n_group]
-    inside = jnp.where(jnp.repeat(group_kept, e // n_group, axis=1), select, -jnp.inf)
+    inside = select
+    if topk_group < n_group:
+        group_kept = _among_largest(_two_largest_sum(select.reshape(tokens, n_group, e // n_group)), topk_group)
+        inside = jnp.where(jnp.repeat(group_kept, e // n_group, axis=1), select, -jnp.inf)
     _, picks = jax.lax.top_k(inside, top_k)
-    top = jnp.take_along_axis(scores, picks, axis=1)
+    top = _picked(scores, picks)
     return top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scaling, picks
 
 

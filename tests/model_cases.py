@@ -6,6 +6,7 @@ balances by the file: tests/conftest.py) keeps its helpers here, once."""
 import functools
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -190,7 +191,7 @@ def check_copy_schedule(run, pools, layer, tables, contexts, block, window=None)
     return got
 
 
-# -- compiling for a described chip (tests/test_tpu_compile.py, tests/test_tpu_compile_cells.py) --
+# -- compiling for a described chip (tests/test_tpu_compile*.py) --
 
 flash_mod = importlib.import_module("determined_tpu.ops.flash_attention")
 #: the benchmark's served architectures at their tiny sizes (``tests/benchmark/tiny/<arch>.json``) -> the cache
@@ -209,9 +210,9 @@ SERVED_ARCHS = {
 }
 
 
-def tiny_served(arch_name: str, **serve_engine):
-    """(model config, parameters, ServeConfig, the form) of a served architecture's tiny form, through its
-    adapter as the benchmark builds it; ``serve_engine`` replaces sizes of the form's engine."""
+def tiny_form(arch_name: str, **serve_engine):
+    """(the adapter, model config, ServeConfig, the form) of a served architecture's tiny form, as the benchmark
+    builds it; ``serve_engine`` replaces sizes of the form's engine.  No parameter is made."""
     import json
     import sys
 
@@ -227,7 +228,13 @@ def tiny_served(arch_name: str, **serve_engine):
         form = json.load(f)
     arch = bench_model.load_file(os.path.join(bench, "archs", arch_name + ".py"), arch_name)
     serve_cfg = ServeConfig(**{**form["serve_engine"], **serve_engine})
-    cfg = arch.model_config(form["config"], serve_cfg.max_seq_len)
+    return arch, arch.model_config(form["config"], serve_cfg.max_seq_len), serve_cfg, form
+
+
+def tiny_served(arch_name: str, **serve_engine):
+    """(model config, parameters, ServeConfig, the form) of a served architecture's tiny form, through its
+    adapter as the benchmark builds it; ``serve_engine`` replaces sizes of the form's engine."""
+    arch, cfg, serve_cfg, form = tiny_form(arch_name, **serve_engine)
     return cfg, arch.init_params(cfg, 0), serve_cfg, form
 
 
@@ -295,3 +302,14 @@ def compile_text(fn, *avals) -> str:
 
 def mosaic_calls(text: str) -> int:
     return text.count('custom_call_target="tpu_custom_call"')
+
+
+def arrays_with_dims(text: str, dims) -> list:
+    """Array shapes of an optimized HLO module (results and operands alike,
+    inside fusions too) that have every one of ``dims`` among their dimensions."""
+    found = set()
+    for m in re.finditer(r"\b\w+\[([\d,]+)\]", text):
+        shape = [int(d) for d in m.group(1).split(",")]
+        if all(shape.count(d) >= list(dims).count(d) for d in dims):
+            found.add(m.group(0))
+    return sorted(found)

@@ -4,6 +4,8 @@ benchmark's eight serving architectures at their tiny sizes, the cache is the
 union of the kinds' leaves, what the engine does at admission follows from what
 a request holds of each kind, and the decode step's counters are the kinds'."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +15,7 @@ from determined_tpu.models.cache_kinds import BLOCKS, CACHE_KINDS, LANE, cache_k
 from determined_tpu.models.serving import SERVE_COUNTERS, ZERO_PICKS, init_kv_cache, serve_counters
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import DecodeKernels, ServeEngine
-from tests.model_cases import SERVED_ARCHS, tiny_served
+from tests.model_cases import SERVED_ARCHS, tiny_form, tiny_served
 
 ARCHS, _tiny = SERVED_ARCHS, tiny_served
 
@@ -91,3 +93,44 @@ def test_the_cache_the_engine_and_the_counters_follow_from_the_kinds(arch_name):
         assert {key: stats[key] for key in said} == said
         assert kind in kinds or said in ({}, {"window_store": {}})
     assert stats.get("rows_per_token") == (cfg.paged_layers if cfg.attn_sublayers > 1 else None)
+
+
+def _equations(jaxpr, outer=""):
+    """(the scopes an equation stands under, the equation) of a jaxpr and of every jaxpr nested in it."""
+    for eqn in jaxpr.eqns:
+        scopes = outer + "/" + str(eqn.source_info.name_stack)
+        yield scopes, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, scopes)
+
+
+@pytest.mark.parametrize("arch_name", list(ARCHS))
+def test_a_decode_step_sorts_no_group_to_route_and_only_a_grouped_router_enters_the_grouped_function(arch_name, monkeypatch):
+    """The decode step of every served tiny form, traced: under ``serve.moe.route`` a model of the grouped sigmoid
+    router (DeepSeek-V3, Nemotron-H, GLM-5.2, Ling-3.0) holds ONE ``top_k`` an expert layer, the picks' over ``[lanes,
+    experts]``: a group's score and the kept groups are made without one (on the chip a ``top_k`` over a third axis is a
+    whole sort of every group), nothing there sorts, and no gather reads the scores (one index an element: a third of
+    what the sort left of a route); a model of another router never enters ``route_sigmoid_grouped``, so what is done
+    to that function leaves its programs the text they were."""
+    from determined_tpu.models import moe
+    from determined_tpu.models.serving import transformer_decode
+
+    arch, cfg, serve_cfg, _ = tiny_form(arch_name)
+    grouped = bool(cfg.moe_experts) and cfg.moe_router == "sigmoid_grouped"
+    assert grouped == (arch_name in ("deepseek_mla_moe", "nemotron_h", "glm_moe_dsa", "ling_kda_mla"))
+    if not grouped:
+        monkeypatch.setattr(moe, "route_sigmoid_grouped", lambda *a, **kw: pytest.fail("a router of another kind entered it"))
+    lanes = serve_cfg.max_batch
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    params = jax.eval_shape(lambda: arch.init_params(cfg, 0))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, serve_cfg.num_blocks, serve_cfg.block_size, lanes, serve_cfg.prefill_chunk))
+    step = functools.partial(transformer_decode, cfg, chunk_blocks=1, counters=True)
+    jaxpr = jax.make_jaxpr(step)(params, i32(lanes), i32(lanes), i32(lanes, serve_cfg.blocks_per_seq), cache).jaxpr
+    routed = [eqn for scopes, eqn in _equations(jaxpr) if "serve.moe.route" in scopes.split("/")]
+    expert_layers = sum(cfg.use_moe(i) for i in range(cfg.n_layers)) if cfg.moe_experts else 0
+    assert bool(routed) == bool(expert_layers)
+    if grouped:
+        assert not [eqn for eqn in routed if eqn.primitive.name in ("sort", "argsort")]
+        picks = [tuple(eqn.invars[0].aval.shape) for eqn in routed if eqn.primitive.name == "top_k"]
+        assert picks == [(lanes, cfg.moe_experts)] * expert_layers
+        assert (lanes, cfg.moe_experts) not in [tuple(eqn.invars[0].aval.shape) for eqn in routed if eqn.primitive.name == "gather"]

@@ -1,6 +1,8 @@
 """MoE layer + expert parallelism (no reference counterpart — SURVEY §2.10
 lists EP/MoE as absent upstream; TPU-first capability)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,3 +137,84 @@ def test_lm_with_moe_trains(tmp_path):
     last = reported[-1][1]
     assert np.isfinite(last["loss"]) and np.isfinite(last["moe_aux_loss"])
     assert last["loss"] < reported[0][1]["loss"]
+
+
+# ---------------------------------------------------------------------------
+# the grouped sigmoid router selects without a sort, and as ``top_k`` would
+# ---------------------------------------------------------------------------
+
+
+def _route_sigmoid_grouped_by_top_k(logits, bias, *, top_k, n_group, topk_group, scaling):
+    """The oracle: the router as three ``top_k`` and a gather state it (a
+    group's score the sum of its two largest, the groups kept, the picks and
+    their scores), whose rule on a tie (the lower index first) the function
+    under test has to keep."""
+    tokens, e = logits.shape
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    select = scores + bias.astype(jnp.float32)[None, :]
+    group_score = jnp.sum(jax.lax.top_k(select.reshape(tokens, n_group, e // n_group), 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    group_kept = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    inside = jnp.where(jnp.repeat(group_kept, e // n_group, axis=1), select, -jnp.inf)
+    _, picks = jax.lax.top_k(inside, top_k)
+    top = jnp.take_along_axis(scores, picks, axis=1)
+    return top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scaling, picks
+
+
+def _router_inputs(ties: str, tokens: int, e: int, n_group: int):
+    """(logits [T, E], bias [E]) that tie where ``ties`` says: equal logits
+    under equal biases are equal selection values to the bit."""
+    rng = np.random.default_rng([tokens, e, n_group, len(ties)])
+    size = e // n_group
+    logits = rng.normal(size=(tokens, n_group, size)).astype(np.float32)
+    bias = (rng.normal(size=e) * 0.1).astype(np.float32)
+    if ties == "none":
+        pass
+    elif ties == "two_largest_of_a_group":  # every group's largest value twice, at two random places
+        bias[:] = 0.0
+        for t in range(tokens):
+            for g in range(n_group):
+                a, b = rng.choice(size, 2, replace=False)
+                logits[t, g, a] = logits[t, g, b] = logits[t, g].max() + 0.5
+    elif ties == "groups_at_the_cut":  # groups are copies of three rows: one high, most equal, the last low
+        bias = np.tile(bias[:size], n_group)
+        high, mid, low = rng.normal(size=(3, tokens, size)).astype(np.float32) + np.array([2.0, 0.0, -2.0], np.float32)[:, None, None]
+        for t in range(tokens):
+            order = rng.permutation(n_group)
+            logits[t] = mid[t]
+            logits[t, order[0]], logits[t, order[-1]] = high[t], low[t]
+    elif ties == "large_negative_bias":  # values a float32 apart collapse under the bias; some experts are out at -inf
+        bias = np.where(rng.random(e) < 0.7, -1e4, bias).astype(np.float32)
+        bias[rng.random(e) < 0.1] = -np.inf
+        if n_group > 1:
+            bias[:size] = -np.inf  # a whole group out
+    elif ties == "all_equal":
+        logits[:], bias[:] = 0.25, 0.0
+    else:
+        raise ValueError(ties)
+    return jnp.asarray(logits.reshape(tokens, e)), jnp.asarray(bias)
+
+
+@pytest.mark.parametrize("ties", ["none", "two_largest_of_a_group", "groups_at_the_cut", "large_negative_bias", "all_equal"])
+@pytest.mark.parametrize(
+    "e,n_group,topk_group,top_k,tokens",
+    [(512, 8, 4, 8, 128), (256, 8, 4, 8, 64), (512, 8, 4, 8, 1024), (512, 1, 1, 22, 64), (64, 4, 4, 6, 32), (96, 4, 1, 5, 7)],
+    ids=["ling", "dsv3", "ling_walk", "one_group", "every_group_kept", "one_group_kept"],
+)
+def test_the_grouped_router_selects_bit_for_bit_as_three_top_k_would(e, n_group, topk_group, top_k, tokens, ties):
+    """``route_sigmoid_grouped`` makes a group's score and the kept groups
+    without a sort and reads the picked scores without a gather; its weights
+    and picks are those of the ``top_k`` form to the bit, ties included (the
+    lower index first)."""
+    from determined_tpu.models.moe import route_sigmoid_grouped
+
+    logits, bias = _router_inputs(ties, tokens, e, n_group)
+    kw = dict(top_k=top_k, n_group=n_group, topk_group=topk_group, scaling=2.5)
+    want_w, want_p = jax.jit(functools.partial(_route_sigmoid_grouped_by_top_k, **kw))(logits, bias)
+    got_w, got_p = jax.jit(functools.partial(route_sigmoid_grouped, **kw))(logits, bias)
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+    assert got_p.dtype == want_p.dtype and got_w.dtype == want_w.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got_w).view(np.uint32), np.asarray(want_w).view(np.uint32))
+    if ties != "none":  # the input does tie somewhere a selection is made
+        select = np.sort(np.asarray(jax.nn.sigmoid(logits)) + np.asarray(bias)[None], axis=1)
+        assert (select[:, 1:] == select[:, :-1]).any()

@@ -18,7 +18,9 @@ be automatically partitioned", while every virtual-CPU-mesh test passed
 Code that asks ``jax.default_backend()`` still sees the CPU here, so the
 kernels' one switch (``ops/kernel_form.py on_tpu``) is steered from the test (``real_kernels_no_cache``,
 with the described devices in tests/model_cases.py).  The serving cells'
-programs at their published widths are tests/test_tpu_compile_cells.py.
+programs at their published widths are tests/test_tpu_compile_cells.py and
+tests/test_tpu_compile_lane_cells.py, the training cells' whole steps
+tests/test_tpu_compile_train_cells.py.
 """
 
 import functools

@@ -112,6 +112,10 @@ def test_the_ling_cells_programs_compile_over_kda_and_latent_layers(tpu_devices,
     # no pool is laid out anew round a loop: a copy of a whole pool would be 1.6 GB (the state's) or 2.7 GB (the rows')
     copies = [line for line in text.splitlines() if " copy(" in line and ("[6,129,32,128,128]" in line or f"[1,{BLOCKS},16,640]" in line)]
     assert not copies, copies[:2]
+    # the router sorts once an expert layer (and a loop of the walk), for its picks: a group's score and the kept groups
+    # come without (PR 67: ``sort f32[128,8,64]`` was 3.4 % of this cell's decode step)
+    sorts = {n for n in scopes["serve.moe.route"] if n.startswith("sort")}
+    assert len(sorts) == (6 if which == "decode" else 12), sorted(sorts)
     if which == "decode":   # a KDA layer: the state kernel; the latent layer: the paged latent kernel; six layers' experts
         assert len({n for n in scopes["serve.kda.state"] if n.startswith("gdn_decode")}) == 6
         assert len({n for n in scopes["serve.moe.experts"] if n.startswith("moe_gmm")}) == 3 * 6
