@@ -2,6 +2,10 @@
 runner (trials dispatched through the master), gang scheduler, and the
 crash-recovery journal."""
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the span ``import.determined_tpu.experiment``: from here to this file's last line
+
 from determined_tpu.experiment.cluster import (
     ClusterExperiment,
     run_cluster_experiment,
@@ -45,3 +49,7 @@ __all__ = [
     "run_cluster_experiment",
     "run_experiment",
 ]
+
+from determined_tpu.observability import get_tracer as _get_tracer  # noqa: E402
+
+_get_tracer().record_span("import.determined_tpu.experiment", "setup", _IMPORT_T0, _time.monotonic())

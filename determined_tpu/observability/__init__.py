@@ -18,6 +18,11 @@ from determined_tpu.observability._goodput import (
     format_ledger_text,
     load_trace_events,
 )
+from determined_tpu.observability._setup import (
+    format_setup_line,
+    log_setup_line,
+    setup_parts,
+)
 from determined_tpu.observability._tracer import Tracer, get_tracer
 
 __all__ = [
@@ -28,8 +33,11 @@ __all__ = [
     "compute_ledger",
     "export_experiment_trace",
     "format_ledger_text",
+    "format_setup_line",
     "get_tracer",
     "load_trace_events",
+    "log_setup_line",
+    "setup_parts",
 ]
 
 

@@ -56,6 +56,23 @@ DEFAULT_FLUSH_INTERVAL = 0.5
 DEFAULT_MAX_EVENTS = 1_000_000
 
 
+def _process_start() -> Optional[float]:
+    """``time.monotonic()`` at which the OS created this process, or None
+    where it cannot be read (no ``/proc``, no ``CLOCK_BOOTTIME``).  Linux
+    keeps a process's start in clock ticks since boot (``/proc/self/stat``,
+    field 22: good to one tick, 10 ms); its age on ``CLOCK_BOOTTIME`` is laid
+    back from the monotonic clock, which counts the same seconds."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the command's name may hold spaces and brackets: count from the last ")"
+            fields = f.read().rsplit(b")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.monotonic() - age if age >= 0.0 else None
+
+
 class _Ring:
     """Single-producer / single-consumer ring of event tuples.
 
@@ -177,6 +194,21 @@ class Tracer:
         self._shipper: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._pid = os.getpid()
+        # the origin of the set-up timeline (``_setup.py``): what lies before
+        # the epoch (the interpreter, whatever the entry point imported first)
+        # is then a length and not a guess
+        started = _process_start()
+        self._process_start = self._epoch if started is None else min(started, self._epoch)
+        self._process_start_source = "tracer_epoch" if started is None else "proc_stat"
+
+    def mark_process_start(self) -> None:
+        """Leave the ``process.start`` instant: the process's tracer does,
+        once, when it is made (a private tracer of a test's has no use for
+        one, and ``reset()`` drops it with everything else)."""
+        if self.enabled:
+            self._ring().push((
+                "I", "process.start", "setup", self._process_start, {"source": self._process_start_source},
+            ))
 
     # -- configuration -----------------------------------------------------
 
@@ -449,6 +481,12 @@ class Tracer:
         trace's clock."""
         return self._epoch
 
+    @property
+    def process_start(self) -> float:
+        """``time.monotonic()`` at which the OS created the process (the
+        ``process.start`` instant); the epoch where that cannot be read."""
+        return self._process_start
+
     def chrome_events(self) -> List[Dict[str, Any]]:
         """Snapshot of all drained events (drains first)."""
         self.drain()
@@ -526,6 +564,7 @@ class Tracer:
 # Process-global tracer: trainer, prefetch workers, scheduler, journal and
 # supervisor all record here; the experiment runner owns its lifecycle.
 _tracer = Tracer()
+_tracer.mark_process_start()
 
 
 def get_tracer() -> Tracer:

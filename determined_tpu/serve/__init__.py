@@ -27,9 +27,14 @@ cells ``serve-internlm2-decode`` and ``serve-internlm2-chat`` of
 
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the span ``import.determined_tpu.serve``: from here to this file's last line
+
 import logging
 from typing import Any, Dict, Optional
 
+from determined_tpu.observability import get_tracer, log_setup_line
 from determined_tpu.serve.config import ServeConfig
 from determined_tpu.serve.engine import (
     DecodeKernels,
@@ -127,6 +132,8 @@ class ServeWorker:
                 on_drain=self._on_master_drain,
             ).start()
         logger.info("serving replica up at %s", self.http.url)
+        # where the tracer is on (``trace_dir``): how the start went, once a process
+        log_setup_line(logger, "replica ready")
         return self.http.url
 
     def _on_master_drain(self, info: Dict[str, Any]) -> None:
@@ -164,3 +171,6 @@ class ServeWorker:
         if self.replica is not None:
             out["replica_id"] = self.replica.replica_id
         return out
+
+
+get_tracer().record_span("import.determined_tpu.serve", "setup", _IMPORT_T0, _time.monotonic())

@@ -705,6 +705,7 @@ class ServeEngine:
 
     def start(self) -> "ServeEngine":
         if not self._thread.is_alive() and not self._finished.is_set():
+            t0 = mono()
             if self._tracer.enabled and not self._tracer.shipping:
                 # a step writes ten spans: without the shipper the
                 # engine thread's ring fills within minutes and every later
@@ -712,7 +713,8 @@ class ServeEngine:
                 # itself (dtpu serve with trace_dir, a trial) keeps it.
                 self._tracer.start()
                 self._owns_shipper = True
-            self._thread.start()
+            self._thread.start()  # returns once the step thread is up
+            self._tracer.record_span("serve.engine.start", "serve", t0, mono())
         return self
 
     def _release_shipper(self) -> None:

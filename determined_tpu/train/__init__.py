@@ -1,5 +1,9 @@
 """Training engine: JaxTrial + Trainer boundary loop + serialization."""
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the span ``import.determined_tpu.train``: from here to this file's last line
+
 from determined_tpu.train._jit_cache import (
     clear_step_cache,
     get_step_cache,
@@ -31,3 +35,7 @@ __all__ = [
     "run_with_restarts",
     "serialization",
 ]
+
+from determined_tpu.observability import get_tracer as _get_tracer  # noqa: E402
+
+_get_tracer().record_span("import.determined_tpu.train", "setup", _IMPORT_T0, _time.monotonic())

@@ -34,7 +34,7 @@ from determined_tpu.config.experiment import ExperimentConfig, Length
 from determined_tpu.core import _context as core_context_mod
 from determined_tpu.data._loader import DataLoader, to_global
 from determined_tpu.data._prefetch import EpochFeed, InputPipeline
-from determined_tpu.observability import chip_peak_flops, get_tracer
+from determined_tpu.observability import chip_peak_flops, get_tracer, log_setup_line
 from determined_tpu.parallel.mesh import MeshAxes, MeshConfig, make_mesh
 from determined_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -225,6 +225,12 @@ class Trainer:
     # -- setup -------------------------------------------------------------
 
     def _setup(self) -> None:
+        # the stages below, as children of ``trainer.setup``: .build (model,
+        # optimizer, loaders, the first host batch), .shapes (the abstract
+        # traces and the sharding plan), .init (the sharded initialiser,
+        # optimizer state, placement on the mesh), .steps (wrapping the
+        # jitted steps)
+        stamps = [time.monotonic()]
         ctx = self.context
         self.model = self.trial.build_model()
         self.tx = self.trial.build_optimizer()
@@ -249,6 +255,7 @@ class Trainer:
         self._sample_host_batch = sample
 
         # ---- parameter shapes + logical specs (no real init yet) --------
+        stamps.append(time.monotonic())
         abstract_raw_boxed = jax.eval_shape(
             lambda r: self.trial.init_params(self.model, r, sample), init_rng
         )
@@ -277,6 +284,7 @@ class Trainer:
             metric_keys = metric_keys + ("lr",)
 
         # ---- sharded init --------------------------------------------------
+        stamps.append(time.monotonic())
         # 1. init params, then commit them to their planned mesh shardings;
         # 2. build opt_state under jit from the *committed* params so XLA
         #    propagates the param shardings into mirror leaves (adam mu/nu);
@@ -362,6 +370,7 @@ class Trainer:
         self._restore_template = serialization.abstract_like(self._array_state())
 
         # ---- jitted steps -------------------------------------------------
+        stamps.append(time.monotonic())
         trial, model, tx = self.trial, self.model, self.tx
         agg = opt.aggregation_frequency if opt else 1
         average_grads = opt.average_aggregated_gradients if opt else True
@@ -579,6 +588,9 @@ class Trainer:
             else None
         )
         tracer = get_tracer()
+        stamps.append(time.monotonic())
+        for stage, t0, t1 in zip(("build", "shapes", "init", "steps"), stamps, stamps[1:]):
+            tracer.record_span("trainer.setup." + stage, "setup", t0, t1)
         if tracer.enabled:
             dev = self.mesh.devices.flat[0]
             # default=0: an unknown chip (CPU tests) reports no roofline
@@ -1372,6 +1384,8 @@ class Trainer:
                     logger.info(
                         "step %d/%d: %s", self.steps_completed, max_steps, _fmt(metrics)
                     )
+                    # once a process: how its start went, from the tracer's events
+                    log_setup_line(logger, "first report")
                 for cb in self.callbacks.values():
                     cb.on_training_workload_end(self.steps_completed, metrics)
 

@@ -374,3 +374,98 @@ def test_the_programs_stay_scoped(which, tmp_path):
         assert want <= set(found.scopes), (program, sorted(want - set(found.scopes)))
         named = 1.0 - len(found.unnamed) / found.listable
         assert named >= LEAST_NAMED, (program, named, found.unnamed[:30])
+
+
+# ---------------------------------------------------------------------------
+# what a first call holds: jax's own events as spans, and two children
+# ---------------------------------------------------------------------------
+
+
+def _long_to_trace(x, w):
+    for _ in range(60):  # a trace of well over ``_TRACE_FLOOR_S``
+        x = jnp.tanh(x @ w) + x
+    return jnp.sum(x)
+
+
+@pytest.fixture()
+def tracer():
+    from determined_tpu.observability import get_tracer
+
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.configure(enabled=True)
+    tracer.reset()
+    cc._listen_to_xla()
+    yield tracer
+    tracer.configure(enabled=was)
+    tracer.reset()
+
+
+def _inside(child, parent, eps=1.0):
+    return parent["ts"] - eps <= child["ts"] and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + eps
+
+
+def test_a_first_call_holds_jaxs_own_events_and_the_two_children(tracer):
+    step = cc.timed_first_call(jax.jit(_long_to_trace), "jit.compile.test_first_call")
+    step(X, W)
+    spans = [e for e in tracer.chrome_events() if e["ph"] == "X"]
+    (parent,) = [e for e in spans if e["name"] == "jit.compile.test_first_call"]
+    mine = lambda name: [  # noqa: E731
+        e for e in spans if e["name"] == name and "_long_to_trace" in str((e.get("args") or {}).get("fun_name"))
+    ]
+    trace, lower = mine("xla.trace"), mine("xla.lower")
+    load = mine("xla.compile") or [e for e in spans if e["name"] == "xla.cache_load"]
+    assert trace and lower and load
+    assert trace[0]["args"]["fun_name"] == "_long_to_trace" and lower[0]["args"]["fun_name"] == "jit(_long_to_trace)"
+    assert all(e["cat"] == "compile" and _inside(e, parent) for e in trace + lower + load)
+    # the lowering traces, then lowers, then compiles: a span ends where jax's listener was called
+    assert trace[0]["ts"] + trace[0]["dur"] <= lower[0]["ts"] + lower[0]["dur"] <= load[0]["ts"] + load[0]["dur"]
+    (inspect,) = [e for e in spans if e["name"] == "jit.compile.test_first_call.inspect"]
+    (first_run,) = [e for e in spans if e["name"] == "jit.compile.test_first_call.first_run"]
+    assert _inside(inspect, parent) and _inside(first_run, parent) and inspect["args"]["text_bytes"] > 1000
+    assert inspect["ts"] + inspect["dur"] <= first_run["ts"] + 1.0  # the two do not overlap
+    assert load[0]["ts"] + load[0]["dur"] <= inspect["ts"] + 1.0    # the inspection reads what was compiled
+    # the counter that said the parent's seconds again is gone
+    assert "jit_cache.compile_s" not in tracer.counters()
+    # a later call is no first call; a forced retrace (another shape) yields jax's events again, same name
+    before = len(spans)
+    step(X, W)
+    assert len([e for e in tracer.chrome_events() if e["ph"] == "X"]) == before
+    step(jnp.ones((4, 16)), W)
+    again = [e for e in tracer.chrome_events() if e["ph"] == "X"][before:]
+    names = {(e["name"], (e.get("args") or {}).get("fun_name")) for e in again}
+    assert {("xla.trace", "_long_to_trace"), ("xla.lower", "jit(_long_to_trace)"), ("xla.compile", "jit(_long_to_trace)")} <= names
+    assert not any(e["name"].startswith("jit.compile.") for e in again)
+
+
+def test_a_short_trace_is_left_out_and_a_program_no_first_call_wraps_is_in(tracer):
+    jax.jit(lambda x: x + 1.0)(jnp.ones((3, 5)))  # wrapped by nothing: lowered and compiled all the same
+    spans = [e for e in tracer.chrome_events() if e["ph"] == "X"]
+    assert {"xla.lower", "xla.compile"} <= {e["name"] for e in spans}
+    assert all(e["dur"] >= cc._TRACE_FLOOR_S * 1e6 - 1.0 for e in spans if e["name"] == "xla.trace")
+
+
+def test_the_listeners_are_registered_once_and_a_disabled_tracer_records_none_of_it(tracer, tmp_path, monkeypatch):
+    from jax._src import monitoring
+
+    n_durations, n_events = len(monitoring._event_duration_secs_listeners), len(monitoring._event_listeners)
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    try:
+        for k in range(3):
+            monkeypatch.setattr(cc, "_configured", None)  # as a new process has it
+            cc.setup_compilation_cache(str(tmp_path / f"xla-{k}"))
+            cc.setup_compilation_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    assert len(monitoring._event_duration_secs_listeners) == n_durations
+    assert len(monitoring._event_listeners) == n_events
+    # each first application of a directory left its mark, with what it found there
+    marks = [e for e in tracer.chrome_events() if e["name"] == "setup.cache_configured"]
+    assert [e["args"] for e in marks] == [{"path": str(tmp_path / f"xla-{k}"), "entries": 0} for k in range(3)]
+    assert all(e["ph"] == "i" and e["cat"] == "setup" for e in marks)
+    tracer.configure(enabled=False)
+    tracer.reset()
+    step = cc.timed_first_call(jax.jit(_long_to_trace), "jit.compile.test_disabled")
+    step(jnp.ones((2, 16)), W)
+    assert tracer.stats()["events"] == 0

@@ -515,3 +515,104 @@ def test_a_profiling_window_carries_the_hosts_clock_at_both_ends(tmp_path):
     start_ns = (tracer.epoch_monotonic + work["ts"] / 1e6) * 1e9 + offset
     assert min(s for s, _ in marks) <= start_ns <= max(s for s, _ in marks)
     tracer.reset()
+
+
+# ---------------------------------------------------------------------------
+# a start, second by second (observability/_setup.py, docs/observability.md "Reading a start")
+# ---------------------------------------------------------------------------
+
+_A_START = """
+import json, sys, time
+sys.path.insert(0, {repo!r})
+import determined_tpu.serve, determined_tpu.experiment      # the four packages that carry an import span:
+import determined_tpu.models, determined_tpu.train          # .serve and .experiment bring the other two
+import determined_tpu.serve, importlib
+importlib.import_module("determined_tpu.models")            # a second import runs no module again
+from determined_tpu.observability import get_tracer
+t = get_tracer()
+print("A_START " + json.dumps({{"epoch_wall": t.epoch_wall, "epoch_monotonic": t.epoch_monotonic,
+                               "process_start": t.process_start, "events": t.chrome_events()}}))
+"""
+
+
+def test_process_start_lies_before_the_epoch_at_the_childs_creation_and_an_import_span_appears_once():
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = time.time()
+    child = subprocess.Popen([sys.executable, "-c", _A_START.format(repo=repo)], stdout=subprocess.PIPE, text=True)
+    created = time.time()  # Popen returns once the child exists
+    out, _ = child.communicate(timeout=170)
+    assert child.returncode == 0
+    got = json.loads(next(x for x in out.splitlines() if x.startswith("A_START "))[len("A_START "):])
+    starts = [e for e in got["events"] if e["name"] == "process.start"]
+    assert len(starts) == 1 and starts[0]["ph"] == "i" and starts[0]["cat"] == "setup"
+    assert starts[0]["args"] == {"source": "proc_stat"}
+    # before the tracer's epoch (the interpreter came first), and on its clock
+    assert starts[0]["ts"] < 0
+    assert got["epoch_monotonic"] + starts[0]["ts"] / 1e6 == pytest.approx(got["process_start"], abs=1e-6)
+    # on the wall's clock: within 50 ms of the creation the parent measured
+    # (the kernel keeps a start to a tick of 10 ms)
+    started_wall = got["epoch_wall"] - (got["epoch_monotonic"] - got["process_start"])
+    assert before - 0.05 <= started_wall <= created + 0.05
+    # each package's import span once, from its first line to its last, with what it imported inside
+    spans = [e for e in got["events"] if e["ph"] == "X" and e["name"].startswith("import.")]
+    assert sorted(e["name"] for e in spans) == [
+        "import.determined_tpu.experiment", "import.determined_tpu.models",
+        "import.determined_tpu.serve", "import.determined_tpu.train",
+    ]
+    assert all(e["cat"] == "setup" and e["dur"] > 0 for e in spans)
+
+
+def test_where_the_start_cannot_be_read_the_instant_is_the_epoch_and_says_so(monkeypatch):
+    from determined_tpu.observability import _tracer as tracer_mod
+
+    monkeypatch.setattr(tracer_mod, "_process_start", lambda: None)
+    tracer = Tracer()
+    assert tracer.chrome_events() == []  # only the process's tracer leaves the instant by itself
+    tracer.mark_process_start()
+    (start,) = [e for e in tracer.chrome_events() if e["ph"] == "i"]
+    assert start["name"] == "process.start" and start["ts"] == 0 and start["args"] == {"source": "tracer_epoch"}
+    assert tracer.process_start == tracer.epoch_monotonic
+    # a disabled tracer leaves none, and reset() drops it with everything else
+    off = Tracer()
+    off.configure(enabled=False)
+    off.mark_process_start()
+    assert off.chrome_events() == []
+    tracer.reset()
+    assert tracer.chrome_events() == []
+
+
+def test_the_operators_line_is_made_once_a_process_from_the_tracers_events(monkeypatch, caplog):
+    import logging
+
+    from determined_tpu.observability import _setup, log_setup_line, setup_parts
+
+    tracer = get_tracer()
+    tracer.reset()
+    log = logging.getLogger("determined_tpu.test_start")
+    monkeypatch.setattr(_setup, "_logged", False)
+    # no process.start (reset() since): nothing to say, and said once all the same
+    with caplog.at_level("INFO", logger=log.name):
+        assert log_setup_line(log, "replica ready") is None
+    monkeypatch.setattr(_setup, "_logged", False)
+    tracer.mark_process_start()
+    now = time.monotonic()
+    tracer.record_span("import.determined_tpu.serve", "setup", now - 3.0, now - 2.0)
+    tracer.record_span("serve.setup", "serve", now - 2.0, now - 0.5)
+    tracer.record_span("xla.trace", "compile", now - 1.5, now - 1.0, {"fun_name": "zeros"})
+    tracer.record_span("serve.step", "serve", now - 0.25, now + 60.0)  # not over yet: left out
+    with caplog.at_level("INFO", logger=log.name):
+        line = log_setup_line(log, "replica ready")
+        assert log_setup_line(log, "replica ready") is None  # once a process
+    assert [r.getMessage() for r in caplog.records] == [line]
+    assert line.startswith("replica ready in ") and "imports 1.0, programs 1.5 (trace and lower 0.5," in line
+    assert "first work 0.0" in line
+    parts = setup_parts(tracer.chrome_events(), now - tracer.epoch_monotonic)
+    assert parts["whole"] == pytest.approx(now - tracer.process_start, abs=1e-4)
+    assert sum(v for k, v in parts.items() if k != "whole") == pytest.approx(parts["whole"], abs=1e-9)
+    # a disabled tracer makes no line
+    monkeypatch.setattr(_setup, "_logged", False)
+    tracer.configure(enabled=False)
+    assert log_setup_line(log, "replica ready") is None

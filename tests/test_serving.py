@@ -843,8 +843,10 @@ def test_building_the_kernels_makes_each_programs_first_call(lm_setup, tracer):
     sentinel = get_retrace_sentinel()
     sentinel.reset()
     kernels = DecodeKernels(cfg, variables, SERVE_CFG)
+    programs = ["jit.compile.serve.decode", "jit.compile.serve.prefill", "jit.compile.serve.sample"]
     first = [e["name"] for e in _spans(tracer) if e["name"].startswith("jit.compile.")]
-    assert sorted(first) == ["jit.compile.serve.decode", "jit.compile.serve.prefill", "jit.compile.serve.sample"]
+    # each first call with its two children: the inspection of the program's text, and the run
+    assert sorted(first) == sorted(p + tail for p in programs for tail in ("", ".inspect", ".first_run"))
     assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
     for pool in kernels.cache.values():
         assert not np.asarray(pool[:, 1:]).any()
@@ -855,7 +857,8 @@ def test_building_the_kernels_makes_each_programs_first_call(lm_setup, tracer):
     while not req.done.is_set():
         assert eng.step_once()
     eng.stop()
-    assert len([e for e in _spans(tracer) if e["name"].startswith("jit.compile.")]) == 3
+    assert len([e for e in _spans(tracer) if e["name"] in programs]) == 3
+    assert len([e for e in _spans(tracer) if e["name"].startswith("jit.compile.")]) == 9
     assert {r.label: r.traces for r in sentinel.records()} == {"serve.prefill_step": 1, "serve.decode_step": 1}
     sentinel.reset()
 
